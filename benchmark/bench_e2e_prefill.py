@@ -28,6 +28,7 @@ from jax.sharding import Mesh
 from triton_distributed_tpu.models import ModelConfig
 from triton_distributed_tpu.models.qwen import Qwen3
 from triton_distributed_tpu.utils.benchmarking import measure_ops
+from triton_distributed_tpu.utils.platform import device_record
 
 
 def main():
@@ -79,7 +80,8 @@ def main():
         ratios = sorted(t / f for t, f in zip(slopes[1], fused_pairs))
         pinned = b == 1 and not args.layers
         print(json.dumps({
-            "bench": "e2e_prefill", "B": b, "S": s,
+            "bench": "e2e_prefill", "device": device_record(),
+            "B": b, "S": s,
             "layers": cfg.num_layers,
             "regime": (f"pinned-B1-L{cfg.num_layers}-S{s}" if pinned
                        else "custom"),

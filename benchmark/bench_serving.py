@@ -68,6 +68,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from triton_distributed_tpu.utils.platform import device_record
+
 
 def make_schedule(seed: int, n: int, load: float, buckets, vocab: int):
     """Deterministic offered trace: (arrival_s, prompt, seed) per
@@ -378,6 +380,9 @@ def emit(mode, load, args, res, extra=None, trace=None,
         base["steps_per_sync"] = (args.steps_per_sync
                                   if steps_per_sync is None
                                   else steps_per_sync)
+    if args.model == "qwen":
+        # the real model's rows are device numbers: say which device
+        base["device"] = device_record()
     if trace is not None:
         # identity dimension: shared-prefix rows never match the
         # default-trace rows in the regression gate

@@ -390,6 +390,24 @@ def main() -> int:
                 rank += 1
 
     world = args.nproc * args.nnodes
+    # One process per chip.  Every child inherits this environment, so
+    # without a CPU pin each one initialises the TPU runtime — and a
+    # chip belongs to one process at a time: the second fails or hangs.
+    # The router owns no model and always starts on the CPU; more than
+    # one model-owning process on this host is refused here, with the
+    # reason, rather than left to hang.
+    on_cpu = args.cpu or os.environ.get("JAX_PLATFORMS") == "cpu"
+    local_ranks = range(args.node_rank * args.nproc,
+                        (args.node_rank + 1) * args.nproc)
+    chip_procs = sum(1 for r in local_ranks
+                     if role_of is None or role_of[r][0] != "router")
+    if not on_cpu and chip_procs > 1:
+        print(f"launch: {chip_procs} model-owning processes on this "
+              f"host would each initialise the TPU runtime, and a chip "
+              f"belongs to one process at a time.  Pass --cpu (test "
+              f"harness), or start one such process per host and let "
+              f"it drive every local chip.", file=sys.stderr)
+        return 2
     # --roles launches get the rank-directory server: role processes
     # rendezvous here (net/rendezvous.py) before opening their data
     # plane, and a rank dying mid-handshake aborts the whole launch
@@ -467,7 +485,8 @@ def main() -> int:
         if args.trace_dir:
             env["TDT_TRACE_DIR"] = args.trace_dir
             env["TDT_HEARTBEAT_DIR"] = hb_dir
-        if args.cpu:
+        if args.cpu or (role_of is not None
+                        and role_of[rank][0] == "router"):
             env["JAX_PLATFORMS"] = "cpu"
         base_port = os.environ.get("TDT_METRICS_PORT")
         if base_port and world > 1:

@@ -1012,11 +1012,7 @@ try:
     from triton_distributed_tpu.kernels.moe_reduce_rs import (
         MoEReduceRSContext, moe_reduce_rs_fused)
     from jax.sharding import Mesh, PartitionSpec as P
-    if hasattr(jax, "shard_map"):
-        smap = functools.partial(jax.shard_map, check_vma=False)
-    else:
-        from jax.experimental.shard_map import shard_map
-        smap = functools.partial(shard_map, check_rep=False)
+    smap = functools.partial(jax.shard_map, check_vma=False)
     buckets = jax.random.normal(jax.random.fold_in(key, 3),
                                 (world, e, cap, k), jnp.float32) / 8
     wdown = jax.random.normal(jax.random.fold_in(key, 4), (e, k, n),

@@ -8,6 +8,9 @@ remote DMA and semaphores.
 
 import os
 
+# Children (launch.py workers, chip_smoke rehearsals) stay on the CPU too.
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
 # Must happen before the JAX backend is initialised.
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
@@ -18,6 +21,11 @@ if "xla_force_host_platform_device_count" not in _flags:
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
+# The package places a persistent compile cache at import.  This
+# harness compiles thousands of tiny programs once each, and its
+# interpret-mode kernels carry host callbacks JAX never persists:
+# writing the rest to disk costs tier-1 time and saves none.
+jax.config.update("jax_enable_compilation_cache", False)
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402

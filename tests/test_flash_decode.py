@@ -205,25 +205,6 @@ def test_sp_flash_decode_int8(sp4_mesh):
 # Paged (page-table-indexed) decode kernel
 # ---------------------------------------------------------------------------
 
-def _pallas_runnable() -> bool:
-    """Can this environment execute Pallas TPU kernels at all?  (TPU:
-    Mosaic; elsewhere: TPU interpret mode — absent from older jax
-    builds, where EVERY pallas_call in the suite fails at the same
-    AttributeError.)  New paged-kernel tests skip rather than re-adding
-    that known environment failure."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    from triton_distributed_tpu.utils.platform import is_tpu
-    return is_tpu() or (hasattr(pltpu, "InterpretParams")
-                        and hasattr(pltpu, "CompilerParams"))
-
-
-requires_pallas = pytest.mark.skipif(
-    not _pallas_runnable(),
-    reason="Pallas TPU kernels not runnable here (no Mosaic, no "
-           "interpret mode in this jax)")
-
-
 def _paged_pools(k, v, page_size, num_extra_pages=3, seed=99,
                  scales=None):
     """Chop a dense (B, Hkv, S, D) cache into pages scattered at a
@@ -260,7 +241,6 @@ def _paged_pools(k, v, page_size, num_extra_pages=3, seed=99,
 
 
 @pytest.mark.parametrize("gqa", [1, 4])
-@requires_pallas
 def test_flash_decode_paged_matches_dense(gqa):
     """The page-table indirection is the ONLY difference: on the same
     logical KV (physically permuted into pages) the paged kernel must
@@ -283,7 +263,6 @@ def test_flash_decode_paged_matches_dense(gqa):
                     name=f"paged-lse-g{gqa}")
 
 
-@requires_pallas
 def test_flash_decode_paged_null_page_tail():
     """Logical pages at/beyond kv_len mapped to NULL page 0 (the
     allocator's convention for not-yet-allocated pages): the masked
@@ -310,7 +289,6 @@ def test_flash_decode_paged_null_page_tail():
                     name="paged-null-vs-ref")
 
 
-@requires_pallas
 def test_flash_decode_paged_int8():
     from triton_distributed_tpu.kernels.flash_decode import (
         flash_decode_paged, quantize_kv)
@@ -330,7 +308,6 @@ def test_flash_decode_paged_int8():
     assert_allclose(out, ref, atol=1e-6, rtol=1e-6, name="paged-int8")
 
 
-@requires_pallas
 def test_sp_flash_decode_paged(sp4_mesh):
     """Distributed paged decode: each rank's shard lives in a local
     page pool; the combined result matches dense reference attention

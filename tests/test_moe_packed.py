@@ -244,18 +244,6 @@ def test_static_block_budget_bound():
         assert (cap // block) <= t
 
 
-def _shard_map_compat(fn, mesh, in_specs, out_specs):
-    """jax.shard_map where available, the experimental entry point
-    otherwise (this container's jax predates the public alias)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 def test_moe_mlp_xla_path_world2(devices):
     """The rewritten XLA golden path (gather combine — no dense
     one-hot) on a real 2-device mesh matches a hand-computed
@@ -272,10 +260,10 @@ def test_moe_mlp_xla_path_world2(devices):
                           jnp.float32) / 4
     params = layer.init_params(jax.random.key(31), dtype=jnp.float32)
 
-    fn = _shard_map_compat(
-        lambda xx, pp: layer(xx, pp), mesh,
+    fn = jax.shard_map(
+        lambda xx, pp: layer(xx, pp), mesh=mesh,
         in_specs=(P("tp", None), layer.global_param_specs()),
-        out_specs=P("tp", None))
+        out_specs=P("tp", None), check_vma=False)
     got = jax.jit(fn)(x, params)
 
     # Hand-rolled reference: same routing/capacity semantics, the

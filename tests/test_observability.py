@@ -191,8 +191,6 @@ def test_instrumented_kernel_emits_event_with_byte_counts():
     gemm_rs entry points emits launch-metadata events whose byte
     counts match the shard sizes.  Entry points must run inside
     shard_map (axis_index), so this needs the full harness."""
-    if not hasattr(jax, "shard_map"):
-        pytest.skip("jax.shard_map unavailable in this environment")
     from jax.sharding import Mesh, PartitionSpec as P
 
     from triton_distributed_tpu.kernels.allgather import (
@@ -368,8 +366,7 @@ def test_moe_fused_vmem_guard_and_combine_dtype(monkeypatch):
 
     calls = {}
     monkeypatch.setattr(mrs.pl, "pallas_call", _fake_pallas(calls))
-    # This jax build predates pltpu.CompilerParams; the fake pallas_call
-    # never consumes the params anyway.
+    # the fake pallas_call never consumes the compiler params
     monkeypatch.setattr(mrs, "comm_compiler_params",
                         lambda *a, **k: None)
     monkeypatch.setattr(mrs, "default_interpret", lambda *a, **k: True)
@@ -407,8 +404,6 @@ def test_moe_two_phase_numerics(monkeypatch):
     """The two-phase fallback kernel must compute the same result as
     the staged composition — forced at a small shape by shrinking
     COMM_VMEM_LIMIT (interpret-mode harness; target toolchain)."""
-    if not hasattr(jax, "shard_map"):
-        pytest.skip("jax.shard_map unavailable in this environment")
     import functools
 
     from jax.sharding import Mesh, PartitionSpec as P

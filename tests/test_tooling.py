@@ -86,6 +86,35 @@ def test_gemm_perf_model():
     assert not gemm_is_compute_bound(8, 128, 128)
 
 
+def test_perf_model_tables_keyed_by_device_kind():
+    """The tables are keyed by the strings `device_kind` really
+    returns; the CPU maps explicitly to the generation the interpreter
+    simulates; any other kind is an error, never a default."""
+    import types
+
+    import pytest
+
+    from triton_distributed_tpu.kernels.gemm_perf_model import (
+        get_chip_spec)
+
+    def dev(kind):
+        return types.SimpleNamespace(device_kind=kind)
+
+    v5e = get_chip_spec(dev("TPU v5 lite"))
+    assert (v5e.bf16_tflops, v5e.int8_tops, v5e.hbm_gbps) == (
+        197.0, 393.0, 819.0)
+    assert get_chip_spec(dev("cpu")) == v5e
+    assert get_chip_spec() == v5e              # this harness: the CPU
+    assert get_chip_spec(dev("TPU v5")).bf16_tflops == 459.0   # v5p
+    assert get_ici_spec(dev("cpu")) == get_ici_spec(dev("TPU v5 lite"))
+    assert get_ici_spec(dev("TPU v6 lite")).link_gbps == 100.0
+    for unknown in ("TPU v9", "v5e", "tpu v5 lite", ""):
+        with pytest.raises(KeyError, match="device_kind"):
+            get_chip_spec(dev(unknown))
+        with pytest.raises(KeyError, match="device_kind"):
+            get_ici_spec(dev(unknown))
+
+
 def test_fast_allgather(tp8_mesh):
     world, m, n = 8, 8, 128
     x = jax.random.normal(jax.random.key(4), (world * m, n))

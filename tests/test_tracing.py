@@ -382,10 +382,10 @@ def test_flight_dump_includes_open_spans_and_heartbeat(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# group_profile (satellite: rank-aware + graceful no-op)
+# group_profile (satellite: rank-aware; a broken profiler raises)
 # ---------------------------------------------------------------------------
 
-def test_group_profile_rank_aware_and_graceful(tmp_path, monkeypatch):
+def test_group_profile_rank_aware_and_raises(tmp_path, monkeypatch):
     from triton_distributed_tpu.utils import profiling
 
     # Multi-process: each rank writes its own subdirectory, no
@@ -396,16 +396,16 @@ def test_group_profile_rank_aware_and_graceful(tmp_path, monkeypatch):
         pass
     assert (tmp_path / "unit" / "rank-1").is_dir()
 
-    # A missing/broken profiler plugin degrades to an unprofiled
-    # region, not a crash.
+    # A profiler that cannot start is an error, not an untraced run.
     def broken(*a, **k):
         raise RuntimeError("profiler plugin unavailable")
 
     monkeypatch.setattr(profiling.jax.profiler, "start_trace", broken)
     ran = []
-    with profiling.group_profile("unit2", trace_dir=str(tmp_path)):
-        ran.append(1)
-    assert ran == [1]
+    with pytest.raises(RuntimeError, match="plugin unavailable"):
+        with profiling.group_profile("unit2", trace_dir=str(tmp_path)):
+            ran.append(1)
+    assert ran == []
 
     # Single-process keeps the flat layout (back-compat).
     monkeypatch.undo()

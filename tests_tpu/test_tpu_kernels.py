@@ -421,3 +421,111 @@ def test_w8a8_ragged_small_m_hardware(m):
     ref = ((aq.astype(jnp.float32) * sa[:, None])
            @ (bq.astype(jnp.float32) * sb[None, :]))
     assert _rel_err(out, ref) < 2e-2
+
+
+# ---------------------------------------------------------------------------
+# The two things the serving path compiles that this sweep never had:
+# the paged decode kernel, and one layer at Qwen3-8B widths.
+# ---------------------------------------------------------------------------
+
+def _to_pool(x, table, ps):
+    """Dense (B, Hkv, S, ...) KV or scales -> page pool: row b's logical
+    page j lands at physical page ``table[b, j]``; page 0 (the reserved
+    NULL page) and any unmapped page stay zero."""
+    b, hkv, s = x.shape[:3]
+    t = s // ps
+    pages = x.reshape(b, hkv, t, ps, *x.shape[3:])
+    pages = jnp.moveaxis(pages, 2, 1).reshape(b * t, hkv, ps,
+                                              *x.shape[3:])
+    pool = jnp.zeros((1 + b * t,) + pages.shape[1:], x.dtype)
+    return pool.at[np.asarray(table).reshape(-1)].set(pages)
+
+
+@pytest.mark.parametrize("quantized,ps", [(False, 16), (True, 32)])
+def test_flash_decode_paged_scattered_pool(quantized, ps):
+    """`flash_decode_paged` against the dense kernel on the same logical
+    KV, physically scattered over a pool — bf16 pages of 16 rows, int8
+    pages of 32 (the int8 sublane tile), page table sized for
+    max_seq 4096 (T = 4096 / page)."""
+    from triton_distributed_tpu.kernels.flash_decode import (
+        flash_decode, flash_decode_paged, quantize_kv)
+
+    b, h, hkv, d, max_seq = 8, 32, 8, 128, 4096
+    t = max_seq // ps
+    lens = np.array([1, 17, 100, 511, 512, 1500, 4095, 4096], np.int32)
+    q = (jax.random.normal(jax.random.key(0), (b, h, d)) / 4
+         ).astype(jnp.bfloat16)
+    k = (jax.random.normal(jax.random.key(1), (b, hkv, max_seq, d)) / 4
+         ).astype(jnp.bfloat16)
+    v = (jax.random.normal(jax.random.key(2), (b, hkv, max_seq, d)) / 4
+         ).astype(jnp.bfloat16)
+    scales = None
+    if quantized:
+        k, v, ks, vs = quantize_kv(k, v)
+        scales = (ks, vs)
+
+    # Every logical page of every row gets its own physical page, at a
+    # seeded random place in a pool with the reserved NULL page 0.
+    table = np.random.default_rng(0).permutation(
+        np.arange(1, 1 + b * t)).reshape(b, t).astype(np.int32)
+
+    kw = {}
+    if quantized:
+        kw = dict(k_scale=_to_pool(scales[0], table, ps),
+                  v_scale=_to_pool(scales[1], table, ps))
+    out, lse = jax.jit(flash_decode_paged)(
+        q, _to_pool(k, table, ps), _to_pool(v, table, ps),
+        jnp.asarray(table), jnp.asarray(lens), **kw)
+    kw = dict(k_scale=scales[0], v_scale=scales[1]) if quantized else {}
+    ref, ref_lse = jax.jit(flash_decode)(q, k, v, jnp.asarray(lens), **kw)
+    # same math, different KV split (page vs 4096-row block): bf16
+    # rounding of p before the PV product is the only difference
+    assert _rel_err(out, ref) < 1e-2
+    assert float(jnp.abs(lse - ref_lse).max()) < 1e-2
+
+
+@pytest.mark.parametrize("seq", [128, 2048])
+def test_qwen3_8b_width_layer_fused_vs_xla(seq):
+    """One Qwen3-8B-width layer (hidden 4096, 32/8 heads x 128, ffn
+    12288, vocab 151936), prefill then one dense and one paged decode
+    step, mode="fused" against mode="xla" on the same weights."""
+    from triton_distributed_tpu.models.config import ModelConfig
+    from triton_distributed_tpu.models.qwen import Qwen3
+
+    cfg = ModelConfig.qwen3_8b()
+    cfg.num_layers = 1
+    mesh = Mesh(np.array(jax.devices()[:1]), ("tp",))
+    fused = Qwen3(cfg, mesh, mode="fused")
+    xla = Qwen3(cfg, mesh, mode="xla")
+    params = fused.init_params(jax.random.key(0))
+    b = 8
+    ids = jax.random.randint(jax.random.key(1), (b, seq), 0,
+                             cfg.vocab_size)
+
+    def close(got, ref, name):
+        rms = float(jnp.sqrt(jnp.mean(ref.astype(jnp.float32) ** 2)))
+        err = float(jnp.abs(got.astype(jnp.float32)
+                            - ref.astype(jnp.float32)).max()) / rms
+        assert err < 5e-2, (name, err)   # one bf16 layer, worst logit
+
+    cache = fused.create_cache(b)
+    lf, cache_f = jax.jit(fused.make_prefill_fn())(params, ids, cache)
+    lx, _ = jax.jit(xla.make_prefill_fn())(params, ids, cache)
+    close(lf, lx, "prefill")
+
+    tok = jnp.argmax(lx, axis=-1).astype(jnp.int32)
+    df, _ = jax.jit(fused.make_decode_fn())(params, tok, cache_f)
+    dx, _ = jax.jit(xla.make_decode_fn())(params, tok, cache_f)
+    close(df, dx, "decode")
+
+    # the same KV, paged: row r's logical page j lives at 1 + r*T + j
+    import dataclasses
+    ps, t = 16, cfg.max_seq_len // 16
+    table = 1 + np.arange(b * t, dtype=np.int32).reshape(b, t)
+    pool = dataclasses.replace(
+        fused.create_paged_cache(b, 1 + b * t, ps, t),
+        ks=[_to_pool(cache_f.ks[0], table, ps)],
+        vs=[_to_pool(cache_f.vs[0], table, ps)],
+        offset=cache_f.offset).with_page_table(table)
+    pf, _ = jax.jit(fused.make_paged_decode_fn(ps))(params, tok, pool)
+    close(pf, dx, "paged decode")

@@ -25,6 +25,12 @@ Parity map against the reference lives in SURVEY.md at the repo root.
 
 __version__ = "0.1.0"
 
+from triton_distributed_tpu.utils.platform import configure_compile_cache
+
+# The one place the compile cache is placed: every entry point
+# (server, benches, chip_smoke.py, tests_tpu) imports the package.
+configure_compile_cache()
+
 from triton_distributed_tpu.parallel.mesh import (  # noqa: F401
     MeshContext,
     get_mesh_context,

@@ -261,13 +261,8 @@ class AnalysisContext(contextlib.AbstractContextManager):
         self.machine = machine
         self._saved = []
 
-    _MISSING = object()
-
     def _patch(self, obj, attr, repl):
-        # Some names differ across jax versions (e.g. `jax.lax.axis_size`
-        # appeared after 0.4.37); install the shim regardless and remove
-        # it again on exit if the original didn't exist.
-        self._saved.append((obj, attr, getattr(obj, attr, self._MISSING)))
+        self._saved.append((obj, attr, getattr(obj, attr)))
         setattr(obj, attr, repl)
 
     def __enter__(self):
@@ -305,10 +300,7 @@ class AnalysisContext(contextlib.AbstractContextManager):
     def __exit__(self, *exc):
         global _CURRENT
         for obj, attr, orig in reversed(self._saved):
-            if orig is self._MISSING:
-                delattr(obj, attr)
-            else:
-                setattr(obj, attr, orig)
+            setattr(obj, attr, orig)
         self._saved.clear()
         _CURRENT = None
         return False

@@ -18,6 +18,10 @@ from typing import Optional
 
 import jax
 
+from triton_distributed_tpu.kernels.gemm_perf_model import (
+    lookup_device_kind,
+)
+
 
 @dataclasses.dataclass(frozen=True)
 class IciSpec:
@@ -26,24 +30,22 @@ class IciSpec:
     latency_us: float       # per-hop latency
 
 
-# Published per-chip interconnect characteristics.
+# Published per-chip interconnect characteristics, keyed by the exact
+# `device_kind` string (see `gemm_perf_model._CHIP_TABLE`).
+_V5E_ICI = IciSpec(link_gbps=50.0, num_links=4, latency_us=1.0)
 _ICI_TABLE = {
-    "v4": IciSpec(link_gbps=50.0, num_links=6, latency_us=1.0),
-    "v5e": IciSpec(link_gbps=50.0, num_links=4, latency_us=1.0),
-    "v5p": IciSpec(link_gbps=100.0, num_links=6, latency_us=1.0),
-    "v6e": IciSpec(link_gbps=100.0, num_links=4, latency_us=1.0),
+    "TPU v4": IciSpec(link_gbps=50.0, num_links=6, latency_us=1.0),
+    "TPU v5 lite": _V5E_ICI,
+    "TPU v5": IciSpec(link_gbps=100.0, num_links=6, latency_us=1.0),
+    "TPU v6 lite": IciSpec(link_gbps=100.0, num_links=4, latency_us=1.0),
+    "cpu": _V5E_ICI,    # interpret mode simulates the v5e
 }
 
 _DCN_GBPS = 25.0  # per host, typical
 
 
 def get_ici_spec(device=None) -> IciSpec:
-    device = device or jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    for key, spec in _ICI_TABLE.items():
-        if key in kind.replace(" ", ""):
-            return spec
-    return _ICI_TABLE["v5e"]
+    return lookup_device_kind(_ICI_TABLE, device)
 
 
 # Cache keyed on the visible device set: a process whose backend grows
@@ -59,18 +61,10 @@ def rings_closed() -> bool:
     every link along the line, roughly doubling the busiest link's
     load; unknown topologies (CPU simulation) assume closed."""
     from triton_distributed_tpu.parallel.mesh import node_topology
-    try:
-        devices = jax.devices()
-        key = (len(devices),
-               getattr(devices[0], "device_kind", ""),
-               jax.process_count())
-    except Exception:
-        return True
+    devices = jax.devices()
+    key = (len(devices), devices[0].device_kind, jax.process_count())
     if key not in _topo_cache:
-        try:
-            rc = node_topology(devices).rings_closed
-        except Exception:
-            rc = None
+        rc = node_topology(devices).rings_closed
         _topo_cache[key] = True if rc is None else rc
     return _topo_cache[key]
 

@@ -25,21 +25,40 @@ class ChipSpec:
     hbm_gbps: float
 
 
+#: Keyed by the exact string `jax.Device.device_kind` returns (v5e
+#: reports "TPU v5 lite", v5p "TPU v5", v6e "TPU v6 lite").  v5e from
+#: Google Cloud's "TPU v5e" page: 197 TFLOP/s bf16, 393 TOP/s int8,
+#: 819 GB/s HBM.
+_V5E = ChipSpec(bf16_tflops=197.0, int8_tops=393.0, hbm_gbps=819.0)
 _CHIP_TABLE = {
-    "v4": ChipSpec(bf16_tflops=275.0, int8_tops=275.0, hbm_gbps=1228.0),
-    "v5e": ChipSpec(bf16_tflops=197.0, int8_tops=394.0, hbm_gbps=819.0),
-    "v5p": ChipSpec(bf16_tflops=459.0, int8_tops=918.0, hbm_gbps=2765.0),
-    "v6e": ChipSpec(bf16_tflops=918.0, int8_tops=1836.0, hbm_gbps=1640.0),
+    "TPU v4": ChipSpec(bf16_tflops=275.0, int8_tops=275.0,
+                       hbm_gbps=1228.0),
+    "TPU v5 lite": _V5E,
+    "TPU v5": ChipSpec(bf16_tflops=459.0, int8_tops=918.0,
+                       hbm_gbps=2765.0),
+    "TPU v6 lite": ChipSpec(bf16_tflops=918.0, int8_tops=1836.0,
+                            hbm_gbps=1640.0),
+    # The CPU backend runs the kernels in TPU interpret mode, which
+    # simulates a v5 (`utils.platform._enable_cpu_simulation_shims`):
+    # method selection there must pick what it would pick on the v5e.
+    "cpu": _V5E,
 }
 
 
-def get_chip_spec(device=None) -> ChipSpec:
+def lookup_device_kind(table: dict, device=None):
+    """``table[device.device_kind]`` — an unknown device is an error,
+    never a default: a wrong peak silently skews every auto-select."""
     device = device or jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower().replace(" ", "")
-    for key, spec in _CHIP_TABLE.items():
-        if key in kind:
-            return spec
-    return _CHIP_TABLE["v5e"]
+    kind = device.device_kind
+    if kind not in table:
+        raise KeyError(
+            f"no perf-model entry for device_kind {kind!r} "
+            f"(known: {sorted(table)}) — add its published peaks")
+    return table[kind]
+
+
+def get_chip_spec(device=None) -> ChipSpec:
+    return lookup_device_kind(_CHIP_TABLE, device)
 
 
 def get_max_mxu_tflops(dtype=jnp.bfloat16, device=None) -> float:

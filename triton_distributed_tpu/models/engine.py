@@ -117,14 +117,22 @@ class Engine:
                                  top_k=self.top_k, top_p=self.top_p)
             tokens = [first]
             cur = first
-            # The warm-up step consumes a generation slot too.
-            n_prof = min(profile_decode_steps, max(gen_len - 2, 0))
+            # The two warm-up steps consume generation slots too.
+            n_prof = min(profile_decode_steps, max(gen_len - 3, 0))
             if n_prof > 0:
                 # Warm the step jit before tracing, then capture only
-                # steady-state steps.  When an outer trace is already
-                # active (profile=True) don't start a nested one.
-                cur, cache, key = self._step(params, cur, cache, key)
-                tokens.append(cur)
+                # steady-state steps.  TWO warm-ups: the first step's
+                # token, key and cache come from prefill and the host,
+                # every later step's from the step itself — committed,
+                # differently-sharded arguments, a second jit
+                # signature with its own compilation (seen on the v5e:
+                # a 3.5 s compile inside a one-warm-up window).  When
+                # an outer trace is already active (profile=True)
+                # don't start a nested one.
+                for _ in range(2):
+                    cur, cache, key = self._step(params, cur, cache, key)
+                    tokens.append(cur)
+                jax.block_until_ready(cur)
                 with group_profile("engine_decode_steps",
                                    do_prof=not profile):
                     for _ in range(n_prof):
@@ -135,6 +143,9 @@ class Engine:
                             cur, cache, key = self._step(
                                 params, cur, cache, key)
                         tokens.append(cur)
+                    # The device runs behind the host: a trace stopped
+                    # at dispatch holds no device event at all.
+                    jax.block_until_ready(cur)
             remaining = gen_len - len(tokens)
             if remaining > 0:
                 if self.scan_decode:
@@ -155,7 +166,8 @@ class Engine:
                     out = jnp.stack(tokens, axis=1)
             else:
                 out = jnp.stack(tokens, axis=1)
-        jax.block_until_ready(out)
+            # inside the (optional) whole-serve trace: see above
+            jax.block_until_ready(out)
         if obs:
             # Cold key includes the profile-steps knob: it shifts the
             # rollout's static `remaining` arg, which retraces and

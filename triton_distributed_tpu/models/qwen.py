@@ -16,11 +16,12 @@ pair maintains.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from triton_distributed_tpu.kernels.matmul import MatmulConfig
 from triton_distributed_tpu.layers.tp_attn import TPAttention, rms_norm
@@ -85,65 +86,66 @@ class Qwen3:
         self.mlp = dataclasses.replace(
             self.mlp, mode=mode if mode == "xla" else "fused")
 
+    def _named(self, specs):
+        """PartitionSpec tree -> NamedSharding tree on this mesh."""
+        return jax.tree.map(
+            lambda sp: NamedSharding(self.mesh, sp), specs,
+            is_leaf=lambda x: isinstance(x, P))
+
     def init_params(self, key):
-        """Global (mesh-sharded) parameter pytree."""
+        """Global parameter pytree, created ALREADY SHARDED to
+        `param_specs()`: every rank generates only its own shard
+        (jitted shard_maps), so no device ever holds a global weight
+        and the jitted prefill/decode programs take the params as they
+        lie.  The global layout is the concatenation of the per-rank
+        shards, which is exactly what the per-device forward bodies
+        expect.  One small program makes a layer and is run once per
+        layer (a single program for the whole depth took 43 s to
+        compile at 12 layers on the v5e)."""
         cfg = self.config
-        keys = jax.random.split(key, cfg.num_layers + 2)
         h = cfg.hidden_size
+        specs = self.param_specs()
 
         def one_layer(k):
+            r = jax.lax.axis_index(self.axis)
             k1, k2 = jax.random.split(k)
-            # build per-rank shards then concat → global layout matches
-            # per-device expectations exactly
-            attn_shards = [
-                self.attn.init_params(jax.random.fold_in(k1, r),
-                                      self.dtype)
-                for r in range(self.world)]
-            mlp_shards = [
-                self.mlp.init_params(jax.random.fold_in(k2, r),
-                                     self.dtype)
-                for r in range(self.world)]
+            attn_p = self.attn.init_params(jax.random.fold_in(k1, r),
+                                           self.dtype)
+            mlp_p = self.mlp.init_params(jax.random.fold_in(k2, r),
+                                         self.dtype)
             if cfg.is_moe:
-                mlp_p = {
-                    "router": mlp_shards[0]["router"],
-                    "gate_up": jnp.concatenate(
-                        [p["gate_up"] for p in mlp_shards], axis=2),
-                    "down": jnp.concatenate(
-                        [p["down"] for p in mlp_shards], axis=1),
-                }
-            else:
-                mlp_p = {
-                    "gate_up": jnp.concatenate(
-                        [p["gate_up"] for p in mlp_shards], axis=1),
-                    "down": jnp.concatenate(
-                        [p["down"] for p in mlp_shards], axis=0),
-                }
-            layer = {
-                "ln1": jnp.ones((h,), self.dtype),
-                "ln2": jnp.ones((h,), self.dtype),
-                "attn": {
-                    "wqkv": jnp.concatenate(
-                        [p["wqkv"] for p in attn_shards], axis=1),
-                    "wo": jnp.concatenate(
-                        [p["wo"] for p in attn_shards], axis=0),
-                },
-                "mlp": mlp_p,
-            }
-            if cfg.qk_norm:
-                layer["attn"]["q_norm"] = attn_shards[0]["q_norm"]
-                layer["attn"]["k_norm"] = attn_shards[0]["k_norm"]
-            return layer
+                # the router is replicated: every rank takes rank 0's
+                mlp_p["router"] = self.mlp.init_params(
+                    jax.random.fold_in(k2, 0), self.dtype)["router"]
+            return {"ln1": jnp.ones((h,), self.dtype),
+                    "ln2": jnp.ones((h,), self.dtype),
+                    "attn": attn_p, "mlp": mlp_p}
 
-        embed = (jax.random.normal(keys[-1], (cfg.vocab_size, h))
-                 * h ** -0.5).astype(self.dtype)
-        params = {
-            "embed": embed,
-            "layers": [one_layer(keys[i]) for i in range(cfg.num_layers)],
-            "ln_f": jnp.ones((h,), self.dtype),
-            "lm_head": (embed.T if cfg.tie_word_embeddings else
-                        (jax.random.normal(keys[-2], (h, cfg.vocab_size))
-                         * h ** -0.5).astype(self.dtype)),
-        }
+        def ends(k_embed, k_head):
+            r = jax.lax.axis_index(self.axis)
+            embed = (jax.random.normal(k_embed, (cfg.vocab_size, h))
+                     * h ** -0.5).astype(self.dtype)
+            lm_head = (embed.T if cfg.tie_word_embeddings else
+                       (jax.random.normal(k_head, (h, cfg.vocab_size))
+                        * h ** -0.5).astype(self.dtype))
+            v_loc = cfg.vocab_size // self.world
+            return {"embed": embed,
+                    "ln_f": jnp.ones((h,), self.dtype),
+                    "lm_head": jax.lax.dynamic_slice_in_dim(
+                        lm_head, r * v_loc, v_loc, 1)}
+
+        def sharded(fn, out_specs):
+            return jax.jit(jax.shard_map(
+                fn, mesh=self.mesh, in_specs=P(), out_specs=out_specs,
+                check_vma=False))
+
+        keys = jax.random.split(key, cfg.num_layers + 2)
+        make_layer = sharded(one_layer, specs["layers"][0])
+        params = sharded(ends, {k: specs[k] for k in
+                                ("embed", "ln_f", "lm_head")})(
+            keys[-1], keys[-2])
+        params["layers"] = [make_layer(keys[i])
+                            for i in range(cfg.num_layers)]
         return params
 
     def param_specs(self):
@@ -226,18 +228,45 @@ class Qwen3:
     # per-device forward bodies (called inside shard_map)
     # ------------------------------------------------------------------
 
-    def _layer_fwd_prefill(self, x, lp, batch, cache, li):
+    # Each layer body below is wrapped in ONE `jax.jit` per traced
+    # program (`_per_layer`): the Python loop over layers then traces
+    # and lowers the body — ring kernels, pipelines and all — once
+    # instead of once per layer (measured ~3 s of lowering per layer
+    # per prefill program at tp=4, paid again by every fresh process
+    # even when the persistent compile cache hits).  XLA inlines the
+    # calls, so the compiled program is unchanged.
+
+    @staticmethod
+    def _per_layer(fn, **static):
+        return jax.jit(functools.partial(fn, **static))
+
+    def _layer_fwd_prefill(self, x, lp, *, batch):
         cfg = self.config
         res = x
         h = rms_norm(x, lp["ln1"], cfg.rms_norm_eps)
-        h, (k, v) = self.attn.prefill(h, lp["attn"], batch)
+        h, kv = self.attn.prefill(h, lp["attn"], batch)
         x = res + h
         res = x
         h = rms_norm(x, lp["ln2"], cfg.rms_norm_eps)
         h = self.mlp(h, lp["mlp"])
+        return res + h, kv
+
+    def _layer_fwd_decode(self, x, lp, kv, scales, page_table, offset):
+        """One decode layer; ``page_table`` None = dense cache."""
+        cfg = self.config
+        res = x
+        h = rms_norm(x, lp["ln1"], cfg.rms_norm_eps)
+        if page_table is None:
+            h, kv, scales = self.attn.decode(
+                h, lp["attn"], kv, offset, kv_scales=scales)
+        else:
+            h, kv, scales = self.attn.decode_paged(
+                h, lp["attn"], kv, page_table, offset, kv_scales=scales)
         x = res + h
-        cache = cache.write_prefill(li, k, v) if cache is not None else None
-        return x, cache
+        res = x
+        h = rms_norm(x, lp["ln2"], cfg.rms_norm_eps)
+        h = self.mlp(h, lp["mlp"])
+        return res + h, kv, scales
 
     def prefill_shard(self, params, input_ids, cache: Optional[KVCache]):
         """Runs inside shard_map.  input_ids: (B, S) replicated.
@@ -250,8 +279,11 @@ class Qwen3:
         x = params["embed"][input_ids].reshape(m, -1)
         x = jax.lax.dynamic_slice_in_dim(x, my * m_loc, m_loc, 0)
 
+        layer = self._per_layer(self._layer_fwd_prefill, batch=b)
         for li, lp in enumerate(params["layers"]):
-            x, cache = self._layer_fwd_prefill(x, lp, b, cache, li)
+            x, (k, v) = layer(x, lp)
+            if cache is not None:
+                cache = cache.write_prefill(li, k, v)
 
         x = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
         # logits for the last position of each sequence
@@ -263,45 +295,13 @@ class Qwen3:
             cache = cache.set_offset(s)
         return logits, cache
 
-    def decode_paged_shard(self, params, tokens, cache):
-        """One PAGED decode step inside shard_map: the per-layer KV
-        pools are page-indexed (`models.kv_cache.PagedKVCache`,
-        KV heads sharded over tp like the dense cache), attention is
-        `flash_decode_paged`'s page-table-indirected split-KV kernel.
-        Mirrors `decode_shard` exactly otherwise."""
-        cfg = self.config
-        b = tokens.shape[0]
-        my = jax.lax.axis_index(self.axis)
-        b_loc = b // self.world
-        x = params["embed"][tokens]                 # (B, h)
-        x = jax.lax.dynamic_slice_in_dim(x, my * b_loc, b_loc, 0)
-
-        offset = cache.offset
-        for li, lp in enumerate(params["layers"]):
-            res = x
-            h = rms_norm(x, lp["ln1"], cfg.rms_norm_eps)
-            scales = ((cache.kss[li], cache.vss[li])
-                      if cache.quantized else None)
-            h, (nk, nv), nscales = self.attn.decode_paged(
-                h, lp["attn"], (cache.ks[li], cache.vs[li]),
-                cache.page_table, offset, kv_scales=scales)
-            cache = cache.set_layer(li, nk, nv,
-                                    *(nscales or (None, None)))
-            x = res + h
-            res = x
-            h = rms_norm(x, lp["ln2"], cfg.rms_norm_eps)
-            h = self.mlp(h, lp["mlp"])
-            x = res + h
-
-        x = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
-        x_full = jax.lax.all_gather(x, self.axis, tiled=True)  # (B, h)
-        logits = jnp.dot(x_full, params["lm_head"],
-                         preferred_element_type=jnp.float32)
-        return logits, cache.inc_offset(1)
-
-    def decode_shard(self, params, tokens, cache: KVCache):
+    def decode_shard(self, params, tokens, cache):
         """One decode step inside shard_map.  tokens: (B,) replicated.
-        Returns (logits_local (B, V/world), cache)."""
+        ``cache`` is a `KVCache` or — the serving-scale layout — a
+        `PagedKVCache`, whose per-layer pools are page-indexed (KV
+        heads sharded over tp like the dense cache) and whose
+        attention is `flash_decode_paged`'s page-table-indirected
+        split-KV kernel.  Returns (logits_local (B, V/world), cache)."""
         cfg = self.config
         b = tokens.shape[0]
         my = jax.lax.axis_index(self.axis)
@@ -309,22 +309,16 @@ class Qwen3:
         x = params["embed"][tokens]                 # (B, h)
         x = jax.lax.dynamic_slice_in_dim(x, my * b_loc, b_loc, 0)
 
-        offset = cache.offset
+        page_table = getattr(cache, "page_table", None)
+        layer = self._per_layer(self._layer_fwd_decode)
         for li, lp in enumerate(params["layers"]):
-            res = x
-            h = rms_norm(x, lp["ln1"], cfg.rms_norm_eps)
             scales = ((cache.kss[li], cache.vss[li])
                       if cache.quantized else None)
-            h, (nk, nv), nscales = self.attn.decode(
-                h, lp["attn"], (cache.ks[li], cache.vs[li]), offset,
-                kv_scales=scales)
+            x, (nk, nv), nscales = layer(
+                x, lp, (cache.ks[li], cache.vs[li]), scales, page_table,
+                cache.offset)
             cache = cache.set_layer(li, nk, nv,
                                     *(nscales or (None, None)))
-            x = res + h
-            res = x
-            h = rms_norm(x, lp["ln2"], cfg.rms_norm_eps)
-            h = self.mlp(h, lp["mlp"])
-            x = res + h
 
         x = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
         x_full = jax.lax.all_gather(x, self.axis, tiled=True)  # (B, h)
@@ -391,7 +385,7 @@ class Qwen3:
         cspecs = self._paged_cache_specs(page_size)
 
         def fn(params, tokens, cache):
-            return self.decode_paged_shard(params, tokens, cache)
+            return self.decode_shard(params, tokens, cache)
 
         return jax.shard_map(
             fn, mesh=self.mesh,
@@ -403,19 +397,27 @@ class Qwen3:
                            page_size: int, max_pages_per_seq: int):
         cfg = self.config
         # pool pages replicated in batch, KV heads sharded over tp —
-        # same head split as the dense cache, page axis shared.
-        return PagedKVCache.create(
+        # same head split as the dense cache, page axis shared.  Zeros
+        # are made under jit with the cache's own shardings: each
+        # device allocates its head shard and nothing else.
+        make = functools.partial(
+            PagedKVCache.create,
             cfg.num_layers, num_pages, batch, cfg.num_kv_heads,
             page_size, cfg.head_dim, max_pages_per_seq, self.dtype,
             quantized=cfg.quantize_kv_cache)
+        return jax.jit(make, out_shardings=self._named(
+            self._paged_cache_specs(page_size)))()
 
     def create_cache(self, batch: int, max_seq: Optional[int] = None):
         cfg = self.config
-        # global cache: kv heads sharded over tp
-        return KVCache.create(
+        # global cache: kv heads sharded over tp (see create_paged_cache)
+        make = functools.partial(
+            KVCache.create,
             cfg.num_layers, batch, cfg.num_kv_heads,
             max_seq or cfg.max_seq_len, cfg.head_dim, self.dtype,
             quantized=cfg.quantize_kv_cache)
+        return jax.jit(make, out_shardings=self._named(
+            self._cache_specs(None)))()
 
 
 def _interleave_gate_up(gate, up, world: int):

@@ -37,6 +37,9 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+# spans mirror into XLA traces when a profiler is attached
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from triton_distributed_tpu.observability.metrics import (
     observability_enabled,
 )
@@ -53,12 +56,6 @@ DEFAULT_RING = 16384
 #: exactly; cross-host skew is whatever NTP leaves, carried in the
 #: export metadata so the merge can report it).
 _CLOCK_BASE = time.time() - time.perf_counter()  # noqa: W001 (perf_counter epoch anchor, export metadata)
-
-try:  # spans mirror into XLA traces when a profiler is attached
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except Exception:  # pragma: no cover - jax-less / stripped installs
-    _TraceAnnotation = None
-
 
 class Span:
     """One timed region.  Context manager; reentrant use is a bug
@@ -81,20 +78,15 @@ class Span:
     def __enter__(self) -> "Span":
         self.tid = threading.get_ident()
         self.depth = self._tracer._push(self)
-        if _TraceAnnotation is not None:
-            try:
-                self._ann = _TraceAnnotation(self.name)
-                self._ann.__enter__()
-            except Exception:
-                self._ann = None
+        self._ann = _TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         self.ts = _CLOCK_BASE + self._t0
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = time.perf_counter()
-        if self._ann is not None:
-            self._ann.__exit__(exc_type, exc, tb)
+        self._ann.__exit__(exc_type, exc, tb)
         self.dur = t1 - self._t0
         if exc_type is not None:
             self.attrs["error"] = repr(exc_type.__name__)

@@ -3,8 +3,9 @@ bundle loader / C runtime.
 
 Reference analogue: the pybind'd native ops (`csrc/lib/op_pybind.cc` →
 `libtriton_distributed`) and the AOT C runtime.  We bind with ctypes
-(no pybind11 in the image) and degrade gracefully when the library
-hasn't been built (`make -C csrc`).
+(no pybind11 in the image).  The library is never committed: it is
+built from source on first use (`make -C csrc`, into the gitignored
+`csrc/build/`), and a failed build raises with the compiler's output.
 
 The MoE alignment/swizzle bindings (`tdt_moe_align_block_size`,
 `tdt_swizzle_*`) were DELETED in ISSUE 14 along with
@@ -22,7 +23,6 @@ import ctypes
 import functools
 import os
 import subprocess
-from typing import Optional
 
 _CSRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "csrc")
@@ -30,15 +30,14 @@ _LIB_PATH = os.path.join(_CSRC, "build", "libtdt.so")
 
 
 @functools.lru_cache(maxsize=None)
-def _load(build_if_missing: bool = True) -> Optional[ctypes.CDLL]:
-    if not os.path.exists(_LIB_PATH) and build_if_missing:
-        try:
-            subprocess.run(["make", "-C", _CSRC], check=True,
-                           capture_output=True, timeout=120)
-        except Exception:
-            return None
+def _load() -> ctypes.CDLL:
     if not os.path.exists(_LIB_PATH):
-        return None
+        res = subprocess.run(["make", "-C", _CSRC], capture_output=True,
+                             text=True, timeout=300)
+        if res.returncode != 0 or not os.path.exists(_LIB_PATH):
+            raise RuntimeError(
+                f"building {_LIB_PATH} failed (make -C {_CSRC}, "
+                f"rc={res.returncode}):\n{res.stderr}")
     lib = ctypes.CDLL(_LIB_PATH)
     lib.tdt_bundle_open.restype = ctypes.c_int
     lib.tdt_bundle_open.argtypes = [ctypes.c_char_p,
@@ -55,10 +54,6 @@ def _load(build_if_missing: bool = True) -> Optional[ctypes.CDLL]:
     lib.tdt_bundle_close.argtypes = [ctypes.c_void_p]
     lib.tdt_executable_free.argtypes = [ctypes.c_void_p]
     return lib
-
-
-def have_native() -> bool:
-    return _load() is not None
 
 
 # ---------------------------------------------------------------------------
@@ -111,8 +106,6 @@ def write_bundle_index(bundle_dir: str) -> None:
 def native_open_bundle(bundle_dir: str):
     """Open a bundle with the C runtime; returns (handle, names)."""
     lib = _load()
-    if lib is None:
-        raise RuntimeError("native library not built (make -C csrc)")
     h = ctypes.c_void_p()
     rc = lib.tdt_bundle_open(bundle_dir.encode(), ctypes.byref(h))
     if rc != 0:

@@ -10,9 +10,9 @@ needed.
 Multi-process discipline: each process writes into its own
 ``rank-<N>`` subdirectory — N processes tracing into ONE directory on a
 shared (or same-host) filesystem collide on the profiler's session
-files.  And a missing/broken profiler plugin (CPU-only containers,
-stripped installs) degrades to a logged no-op instead of killing the
-run: profiling is never load-bearing.
+files.  A profiler that was asked for and cannot start raises: a
+traced run that silently went on untraced yields per-layer numbers
+from nothing.
 """
 
 from __future__ import annotations
@@ -29,13 +29,10 @@ from triton_distributed_tpu.utils.debug import logger
 def _rank_subdir(path: str) -> str:
     """Per-process subdirectory under the trace path for multi-process
     runs (single-process keeps the flat layout unchanged)."""
-    try:
-        from triton_distributed_tpu.observability.metrics import (
-            _process_count, _process_index)
-        if _process_count() > 1:
-            return os.path.join(path, f"rank-{_process_index()}")
-    except Exception:
-        pass
+    from triton_distributed_tpu.observability.metrics import (
+        _process_count, _process_index)
+    if _process_count() > 1:
+        return os.path.join(path, f"rank-{_process_index()}")
     return path
 
 
@@ -55,33 +52,19 @@ def group_profile(
     Every process writes into `{trace_dir}/{name}` (multi-process:
     `{trace_dir}/{name}/rank-{i}`, so concurrent processes never
     collide on one session directory); open with TensorBoard (XProf)
-    to see the merged multi-host timeline.  When the profiler backend
-    is unavailable (no plugin, unsupported platform) the region runs
-    unprofiled with a warning — a graceful no-op, not a crash.
+    to see the merged multi-host timeline.
     """
     if not do_prof:
         yield
         return
     path = _rank_subdir(os.path.join(trace_dir, name or "trace"))
-    started = False
-    try:
-        os.makedirs(path, exist_ok=True)
-        jax.profiler.start_trace(path)
-        started = True
-    except Exception as e:  # profiler plugin missing/broken
-        logger.warning(
-            "group_profile(%s): jax.profiler unavailable (%s) — "
-            "running unprofiled", name or "trace", e)
+    os.makedirs(path, exist_ok=True)
+    jax.profiler.start_trace(path)
     try:
         yield
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-                logger.info("profile trace written to %s", path)
-            except Exception as e:
-                logger.warning("group_profile(%s): stop_trace failed: "
-                               "%s", name or "trace", e)
+        jax.profiler.stop_trace()
+        logger.info("profile trace written to %s", path)
 
 
 @contextlib.contextmanager
