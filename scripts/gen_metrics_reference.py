@@ -45,6 +45,8 @@ EXCLUDE_PARTS = {"__pycache__", ".git"}
 _METRIC_CALLS = {
     "counter": "counter",
     "count_metric": "counter",
+    # `serving/pages.py`'s lazy-import wrapper of `count_metric`
+    "_count_metric": "counter",
     "gauge": "gauge",
     "histogram": "histogram",
     "observe_metric": "histogram",

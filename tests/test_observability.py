@@ -27,6 +27,7 @@ from triton_distributed_tpu.observability import (
     format_report,
     get_flight_recorder,
     get_registry,
+    get_tracer,
     merge_snapshots,
 )
 from triton_distributed_tpu.observability.instrument import (
@@ -41,6 +42,17 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # ---------------------------------------------------------------------------
 # Metrics registry
 # ---------------------------------------------------------------------------
+
+
+@pytest.fixture(autouse=True)
+def _clean_global_tracer_and_recorder():
+    """Every test starts from an empty flight ring and process tracer:
+    a ring an earlier test on this worker filled (its length then no
+    longer grows) or spans one left open must not decide this one."""
+    get_flight_recorder().clear()
+    get_tracer().clear()
+    yield
+
 
 def test_counter_gauge_histogram_semantics():
     reg = MetricsRegistry()

@@ -565,3 +565,216 @@ def test_paged_requires_contract():
     with pytest.raises(ValueError, match="paged engine contract"):
         ContinuousBatchingScheduler(
             NoPaged(), {}, SchedulerConfig(kv_layout="paged"))
+
+
+# ---------------------------------------------------------------------------
+# step-phase spans and KV-boundary counters (ISSUE 24)
+# ---------------------------------------------------------------------------
+
+STEP_PHASES = ("serving.admit", "serving.pages", "serving.dispatch",
+               "serving.sync", "serving.commit", "serving.gauges")
+
+
+@pytest.fixture
+def tracer():
+    from triton_distributed_tpu.observability import get_tracer
+    tr = get_tracer()
+    tr.clear()
+    yield tr
+    tr.clear()
+
+
+def _by_name(spans):
+    out = {}
+    for s in spans:
+        out.setdefault(s.name, []).append(s)
+    return out
+
+
+@pytest.mark.parametrize("layout", ["paged", "slots"])
+def test_one_step_leaves_the_phase_spans(toy, tracer, layout):
+    model, params = toy
+    sched, _ = make_sched(model, params, layout)
+    prompts = rand_prompts(2, seed=3)
+    reqs = [Request(prompt=p, max_new_tokens=4) for p in prompts]
+    for r in reqs:
+        assert sched.submit(r)
+    out = sched.step()
+    assert out == {"admitted": 2, "active": 2, "retired": 0}
+    spans = _by_name(tracer.finished())
+    (step,) = spans["serving.step"]
+    assert step.parent is None
+    assert step.attrs == {"step": 1, "admitted": 2, "active": 2,
+                          "retired": 0}
+    expected = [p for p in STEP_PHASES
+                if layout == "paged" or p != "serving.pages"]
+    for name in expected:
+        (sp,) = spans[name]
+        assert sp.parent == step.id, name
+    (admit,) = spans["serving.admit"]
+    assert admit.attrs == {"queued": 2}
+    ones = spans["serving.admit.request"]
+    blocks = spans["serving.prefill.block"]
+    assert [s.parent for s in ones] == [admit.id] * 2
+    assert [s.parent for s in blocks] == [s.id for s in ones]
+    for one, block, req in zip(ones, blocks, reqs):
+        assert one.attrs["request_id"] == req.request_id
+        assert block.attrs == {"request_id": req.request_id}
+        assert one.attrs["prompt_len"] == req.prompt_len
+        assert one.attrs["bucket"] == req.bucket
+        assert one.attrs["mode"] == "local"
+        assert one.attrs["cached_tokens"] == 0
+    # the request's lifetime: open, caused by its admission, sharing
+    # its request_id
+    lives = [s for s in tracer.open_spans()
+             if s.name == "serving.request"]
+    assert [s.parent for s in lives] == [s.id for s in ones]
+    assert [s.attrs["request_id"] for s in lives] == [
+        r.request_id for r in reqs]
+    (dispatch,) = spans["serving.dispatch"]
+    assert dispatch.attrs == {"k": 1, "spec": False}
+    (commit,) = spans["serving.commit"]
+    assert commit.attrs == {"tokens": 2, "retired": 0}
+    if layout == "paged":
+        (pages,) = spans["serving.pages"]
+        assert pages.attrs["preempted"] == 0
+        assert pages.attrs["flushed_rows"] == sched.config.num_slots
+        assert pages.attrs["live_pages"] == sched.slots.live_pages > 0
+    # a later step with nothing to admit has no admit span
+    tracer.clear()
+    sched.step()
+    assert "serving.admit" not in _by_name(tracer.finished())
+    sched.drain()
+
+
+def test_step_self_time_is_duration_less_its_phases(toy, tracer):
+    model, params = toy
+    sched, _ = make_sched(model, params, "paged")
+    sched.run([Request(prompt=p, max_new_tokens=5)
+               for p in rand_prompts(4, seed=5)])
+    spans = tracer.finished()
+    steps = [s for s in spans if s.name == "serving.step"]
+    assert len(steps) >= 5
+    for step in steps:
+        kids = [s for s in spans if s.parent == step.id
+                and not s.detached]
+        assert {k.name for k in kids} <= set(STEP_PHASES)
+        inside = sum(k.dur for k in kids)
+        assert 0.0 <= step.dur - inside <= step.dur
+        # phases do not overlap: in order on the step's own clock
+        kids.sort(key=lambda s: s.t0)
+        for a, b in zip(kids, kids[1:]):
+            assert a.t0 + a.dur <= b.t0
+        assert step.t0 <= kids[0].t0
+        assert kids[-1].t0 + kids[-1].dur <= step.t0 + step.dur
+
+
+def test_request_span_survives_out_of_order_retirement(toy, tracer,
+                                                       monkeypatch):
+    """`serving.request` opens at admission and closes at retirement,
+    whatever the order, and never reaches the profiler."""
+    from triton_distributed_tpu.observability import tracing
+    entered = []
+
+    class Ann:
+        def __init__(self, name):
+            entered.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(tracing, "_TraceAnnotation", Ann)
+    model, params = toy
+    sched, _ = make_sched(model, params, "paged")
+    prompts = rand_prompts(3, seed=11)
+    # admitted together; the LAST admitted retires first
+    reqs = [Request(prompt=p, max_new_tokens=n)
+            for p, n in zip(prompts, (6, 4, 2))]
+    for r in reqs:
+        sched.submit(r)
+    sched.step()
+    open_ids = lambda: [s.attrs["request_id"]          # noqa: E731
+                        for s in tracer.open_spans()
+                        if s.name == "serving.request"]
+    assert open_ids() == [r.request_id for r in reqs]
+    sched.step()
+    assert open_ids() == [reqs[0].request_id, reqs[1].request_id]
+    sched.drain()
+    assert tracer.open_spans() == []
+    done = [s for s in tracer.finished() if s.name == "serving.request"]
+    assert [s.attrs["request_id"] for s in done] == [
+        r.request_id for r in reversed(reqs)]
+    assert "serving.request" not in entered
+    assert "serving.step" in entered and "serving.sync" in entered
+
+
+def test_dropped_scheduler_closes_its_request_spans(toy, tracer):
+    import gc
+    model, params = toy
+    sched, _ = make_sched(model, params, "paged")
+    sched.submit(Request(prompt=rand_prompts(1)[0], max_new_tokens=8))
+    sched.step()
+    assert [s.name for s in tracer.open_spans()] == ["serving.request"]
+    del sched
+    gc.collect()
+    assert tracer.open_spans() == []
+    assert [s.name for s in tracer.finished()
+            if s.detached] == ["serving.request"]
+
+
+def test_disabled_observability_allocates_no_span(toy, tracer,
+                                                  monkeypatch):
+    from triton_distributed_tpu.observability import tracing
+    model, params = toy
+    sched, _ = make_sched(model, params, "paged")
+    monkeypatch.setenv("TDT_OBSERVABILITY", "0")
+
+    def boom(*a, **kw):
+        raise AssertionError("a span was allocated")
+
+    monkeypatch.setattr(tracing.Span, "__init__", boom)
+    done = sched.run([Request(prompt=p, max_new_tokens=3)
+                      for p in rand_prompts(3, seed=2)])
+    assert len(done) == 3
+    assert len(tracer) == 0 and tracer.open_spans() == []
+
+
+def test_kv_counters_count_an_eviction_and_a_flush(toy, tracer):
+    """A pool too small to retain a finished prompt's pages: the next
+    admission's allocation evicts them, and every changed table is
+    re-shipped once."""
+    from triton_distributed_tpu.observability import get_registry
+    reg = get_registry()
+    reg.clear()
+    model, params = toy
+    sched, _ = make_sched(model, params, "paged", num_slots=1,
+                          page_size=8, num_pages=4)
+    rng = np.random.default_rng(0)
+    first = Request(prompt=list(rng.integers(1, 61, 20)),
+                    max_new_tokens=6)      # grows into a fourth page
+    sched.run([first])
+    kv = sched.slots
+    assert kv.mapped_pages == 1
+    # two full prompt pages stay cached; nobody holds them
+    assert kv.used_pages == 2 and kv.live_pages == 0
+    flushed = kv.flushed_rows
+    second = Request(prompt=list(rng.integers(1, 61, 26)),
+                     max_new_tokens=2)
+    tracer.clear()
+    sched.run([second])
+    snap = reg.snapshot()["counters"]
+    assert kv.radix.evict_calls >= 1 and kv.radix.freed_pages == 2
+    assert snap["serving_kv_evict_calls_total"] == kv.radix.evict_calls
+    assert snap["serving_kv_evicted_pages_total"] == 2
+    assert snap["serving_kv_pages_mapped_total"] == kv.mapped_pages
+    assert snap["serving_kv_table_rows_flushed_total"] == kv.flushed_rows
+    assert kv.flushed_rows > flushed
+    # the spans saw the same work, step by step
+    pages = [s for s in tracer.finished() if s.name == "serving.pages"]
+    assert sum(s.attrs["mapped"] for s in pages) <= kv.mapped_pages
+    assert max(s.attrs["live_pages"] for s in pages) == 4
+    assert sum(s.attrs["flushed_rows"] for s in pages) > 0
+    assert reg.snapshot()["gauges"]["serving_kv_pages_live"] == 0

@@ -51,6 +51,17 @@ from triton_distributed_tpu.observability.tracing import (
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+@pytest.fixture(autouse=True)
+def _clean_global_tracer_and_recorder():
+    """Every test starts from an empty process tracer and flight ring:
+    spans or events an earlier test on this worker left behind must
+    not decide this one."""
+    from triton_distributed_tpu.observability import get_flight_recorder
+    get_tracer().clear()
+    get_flight_recorder().clear()
+    yield
+
+
 # ---------------------------------------------------------------------------
 # Span tracer
 # ---------------------------------------------------------------------------
@@ -610,3 +621,274 @@ def test_launcher_timeout_names_stalled_rank(tmp_path):
     hb = json.load(open(trace_dir / "heartbeats"
                         / "heartbeat-rank-1.json"))
     assert hb["lineage"][0]["hop"] == "admit"
+
+
+# ---------------------------------------------------------------------------
+# Cause, lifetimes and clocks (ISSUE 24)
+# ---------------------------------------------------------------------------
+
+def test_span_records_id_and_parent():
+    tr = SpanTracer(capacity=16)
+    with tr.span("step") as step:
+        with tr.span("admit") as admit:
+            with tr.span("admit.request") as one:
+                pass
+        with tr.span("dispatch") as dispatch:
+            pass
+    assert step.parent is None and step.depth == 0
+    assert admit.parent == step.id and dispatch.parent == step.id
+    assert one.parent == admit.id and one.depth == 2
+    assert len({s.id for s in tr.finished()}) == 4
+    d = one.to_dict()
+    assert d["id"] == one.id and d["parent"] == admit.id
+    ev = one.chrome_event(rank=0)
+    assert ev["args"]["id"] == one.id
+    assert ev["args"]["parent"] == admit.id
+
+
+def test_self_time_is_duration_less_children():
+    tr = SpanTracer(capacity=16)
+    with tr.span("step") as step:
+        with tr.span("a"):
+            time.sleep(0.002)
+        time.sleep(0.003)
+        with tr.span("b"):
+            time.sleep(0.002)
+    kids = [s for s in tr.finished() if s.parent == step.id]
+    assert [k.name for k in kids] == ["a", "b"]
+    self_s = step.dur - sum(k.dur for k in kids)
+    assert 0.003 <= self_s < step.dur - 0.004 + 1e-9
+    # children lie inside their parent on the raw clock
+    for k in kids:
+        assert step.t0 <= k.t0 and k.t0 + k.dur <= step.t0 + step.dur
+
+
+def test_detached_span_is_a_lifetime_not_a_frame(monkeypatch):
+    from triton_distributed_tpu.observability import tracing
+    entered = []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(tracing, "_TraceAnnotation", Ann)
+    tr = SpanTracer(capacity=16)
+    with tr.span("step") as step:
+        a = tr.detached("request", request_id=1)
+        a.__enter__()
+        b = tr.detached("request", request_id=2)
+        b.__enter__()
+        with tr.span("dispatch") as dispatch:
+            pass
+    # caused by the step, never a parent, never shown to the profiler
+    assert a.parent == step.id and b.parent == step.id
+    assert dispatch.parent == step.id
+    assert entered == ["step", "dispatch"]
+    assert {s.attrs["request_id"] for s in tr.open_spans()} == {1, 2}
+    with tr.span("later") as later:
+        a.__exit__(None, None, None)      # out of order: a before b
+    assert later.parent is None and later.depth == 0
+    assert [s.attrs["request_id"] for s in tr.open_spans()] == [2]
+    b.__exit__(None, None, None)
+    assert tr.open_spans() == []
+    done = [s for s in tr.finished() if s.name == "request"]
+    assert [s.attrs["request_id"] for s in done] == [1, 2]
+    assert all(s.detached and s.dur is not None for s in done)
+    # an open lifetime is exported as open
+    c = tr.detached("request", request_id=3)
+    c.__enter__()
+    evs = [e for e in tr.chrome_trace()["traceEvents"]
+           if e.get("name") == "request"]
+    assert sum(bool(e["args"].get("open")) for e in evs) == 1
+    c.__exit__(None, None, None)
+
+
+def test_detached_disabled_is_the_shared_noop(monkeypatch):
+    monkeypatch.setenv("TDT_OBSERVABILITY", "0")
+    tr = SpanTracer(capacity=4)
+    assert tr.detached("request") is NULL_SPAN
+
+
+def test_monotonic_offset_places_spans_on_the_harness_clock():
+    from triton_distributed_tpu.observability.tracing import (
+        MONOTONIC_OFFSET)
+    tr = SpanTracer(capacity=4)
+    assert tr.monotonic_offset == MONOTONIC_OFFSET
+    before = time.monotonic()
+    with tr.span("x") as sp:
+        pass
+    after = time.monotonic()
+    start = sp.t0 + tr.monotonic_offset
+    assert before - 1e-3 <= start <= after + 1e-3
+    assert start + sp.dur <= after + 1e-3
+
+
+def test_ring_counts_what_it_drops():
+    from triton_distributed_tpu.observability import get_registry
+    c = get_registry().counter("trace_dropped_spans_total")
+    c0 = c.value
+    tr = SpanTracer(capacity=3)
+    for i in range(3):
+        with tr.span("s", i=i):
+            pass
+    assert tr.dropped == 0
+    for i in range(2):
+        with tr.span("s", i=3 + i):
+            pass
+    assert tr.dropped == 2 and c.value == c0 + 2
+    assert [s.attrs["i"] for s in tr.finished()] == [2, 3, 4]
+    tr.clear()
+    assert tr.dropped == 0
+
+
+def test_default_ring_holds_a_serving_run(monkeypatch):
+    monkeypatch.delenv("TDT_TRACE_RING", raising=False)
+    # ~2000 steps of 9 spans, with headroom
+    assert SpanTracer().capacity >= 3 * 2000 * 9
+
+
+def test_span_under_profiler_is_on_a_host_plane(tmp_path):
+    """One clock: a span entered while `jax.profiler` traces reaches
+    the xplane by name, stamped by the profiler itself; a detached
+    span does not."""
+    import glob
+
+    import jax
+    tr = SpanTracer(capacity=8)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with tr.span("probe.step"):
+            with tr.span("probe.sync"):
+                jnp.ones(4).block_until_ready()
+            life = tr.detached("probe.request")
+            life.__enter__()
+            life.__exit__(None, None, None)
+    finally:
+        jax.profiler.stop_trace()
+    found = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    assert found
+    data = jax.profiler.ProfileData.from_file(found[-1])
+    seen = {}
+    for plane in data.planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("probe."):
+                    seen[e.name] = (plane.name, e.start_ns,
+                                    e.duration_ns)
+    assert set(seen) == {"probe.step", "probe.sync"}
+    assert all(p.startswith("/host:") for p, _, _ in seen.values())
+    (_, s0, d0), (_, s1, d1) = seen["probe.step"], seen["probe.sync"]
+    assert s0 <= s1 and s1 + d1 <= s0 + d0       # nested on that clock
+
+
+# ---------------------------------------------------------------------------
+# Kernels carry a name into the jaxpr (and so into the device trace)
+# ---------------------------------------------------------------------------
+
+def _pallas_names(jaxpr):
+    """`name` of every pallas_call in ``jaxpr``, sub-jaxprs included."""
+    from jax._src import core
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"])
+        for sub in core.jaxprs_in_params(eqn.params):
+            out.extend(_pallas_names(sub))
+    return out
+
+
+@pytest.fixture(scope="module")
+def serving_kernel_names():
+    """Names of the Pallas kernels in the programs the scheduler runs
+    for the tiny Qwen3 in fused mode at tp=4: one bucketed prefill and
+    one paged decode step."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from triton_distributed_tpu.models import ModelConfig
+    from triton_distributed_tpu.models.qwen import Qwen3
+    mesh = Mesh(np.array(jax.devices()[:4]), ("tp",))
+    model = Qwen3(ModelConfig.tiny(dtype="float32"), mesh, mode="fused")
+    params = jax.eval_shape(model.init_params, jax.random.key(0))
+    names = []
+    row = jax.eval_shape(lambda: model.create_cache(1, max_seq=16))
+    ids = jax.ShapeDtypeStruct((1, 16), jnp.int32)
+    names += _pallas_names(jax.make_jaxpr(model.make_prefill_fn())(
+        params, ids, row).jaxpr)
+    cache = jax.eval_shape(lambda: model.create_paged_cache(4, 9, 16, 8))
+    toks = jax.ShapeDtypeStruct((4,), jnp.int32)
+    names += _pallas_names(jax.make_jaxpr(
+        model.make_paged_decode_fn(page_size=16))(
+            params, toks, cache).jaxpr)
+    return names
+
+
+@pytest.mark.parametrize("prefix", [
+    "flash_decode_paged", "flash_attention_fwd", "ag_gemm_", "gemm_rs_"])
+def test_serving_path_kernels_are_named(serving_kernel_names, prefix):
+    assert all(serving_kernel_names), serving_kernel_names
+    assert any(n.startswith(prefix) for n in serving_kernel_names), (
+        prefix, sorted(set(serving_kernel_names)))
+
+
+@pytest.mark.parametrize("op,method,name", [
+    ("ag_gemm", "ll", "ag_gemm_ll"),
+    ("ag_gemm", "fused", "ag_gemm_ring"),
+    ("gemm_rs", "ll", "gemm_rs_ll"),
+    ("gemm_rs", "fused", "gemm_rs_fused"),
+])
+def test_overlap_gemm_kernels_are_named_by_method(tp4_mesh, op, method,
+                                                  name):
+    import functools
+
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from triton_distributed_tpu.kernels.allgather_gemm import (
+        AllGatherGEMMContext, ag_gemm)
+    from triton_distributed_tpu.kernels.gemm_reduce_scatter import (
+        GEMMReduceScatterContext, gemm_rs)
+    from triton_distributed_tpu.ops import shard_map_op
+    if op == "ag_gemm":
+        ctx = AllGatherGEMMContext(axis="tp", world_size=4,
+                                   method=method)
+        fn = shard_map_op(functools.partial(ag_gemm, ctx=ctx), tp4_mesh,
+                          in_specs=(P("tp", None), P(None, "tp")),
+                          out_specs=P(None, "tp"))
+        shapes = ((64, 256), (256, 512))
+    else:
+        ctx = GEMMReduceScatterContext(axis="tp", world_size=4,
+                                       method=method)
+        fn = shard_map_op(functools.partial(gemm_rs, ctx=ctx), tp4_mesh,
+                          in_specs=(P(None, "tp"), P("tp", None)),
+                          out_specs=P("tp", None))
+        shapes = ((64, 512), (512, 256))
+    args = [jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes]
+    assert _pallas_names(jax.make_jaxpr(fn)(*args).jaxpr) == [name]
+
+
+def test_kernel_name_reaches_the_lowered_text():
+    """The name is what the device trace shows: on the CPU the lowered
+    (interpret-mode) program keeps it in its locations."""
+    import jax
+
+    from triton_distributed_tpu.kernels.flash_decode import (
+        flash_decode_paged)
+    S = jax.ShapeDtypeStruct
+    args = (S((2, 4, 128), jnp.float32), S((8, 2, 16, 128), jnp.float32),
+            S((8, 2, 16, 128), jnp.float32), S((2, 4), jnp.int32),
+            S((2,), jnp.int32))
+    jaxpr = jax.make_jaxpr(flash_decode_paged)(*args)
+    assert _pallas_names(jaxpr.jaxpr) == ["flash_decode_paged"]
+    assert "name=flash_decode_paged" in str(jaxpr)

@@ -283,6 +283,7 @@ def all_gather(x, ctx: AllGatherContext):
         out = pl.pallas_call(
             functools.partial(_bidir_ring_ag_kernel, ctx.axis, world,
                               ctx.straggler, ctx.for_correctness),
+            name="all_gather_bidir_ring",
             out_shape=jax.ShapeDtypeStruct((world, 2, m // 2, n), x.dtype),
             in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
@@ -301,6 +302,9 @@ def all_gather(x, ctx: AllGatherContext):
     out = pl.pallas_call(
         functools.partial(kernel, ctx.axis, world, ctx.straggler,
                           ctx.for_correctness),
+        name=("all_gather_push_all"
+              if method == AllGatherMethod.PUSH_ALL
+              else "all_gather_ring"),
         out_shape=jax.ShapeDtypeStruct((world, m, n), x.dtype),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),

@@ -328,6 +328,7 @@ def ag_gemm(a_shard, b, ctx, return_gathered: bool = False):
               else _ag_gemm_fused_kernel)
     gathered, out = pl.pallas_call(
         functools.partial(kernel, ctx, mp, n, k),
+        name="ag_gemm_ll" if method == "ll" else "ag_gemm_ring",
         out_shape=(
             jax.ShapeDtypeStruct((world, mp, k), a_shard.dtype),
             jax.ShapeDtypeStruct((world, mp, n), a_shard.dtype),
@@ -439,6 +440,7 @@ def ag_gemm_w8a8(a_shard, b_q, scale_b, ctx: AllGatherGEMMContext,
 
     gathered, out = pl.pallas_call(
         functools.partial(_ag_gemm_w8a8_kernel, ctx, cfg, mp, n, k),
+        name="ag_gemm_w8a8",
         out_shape=(
             jax.ShapeDtypeStruct((world, mp, k), jnp.int8),
             jax.ShapeDtypeStruct((world, mp, n), a_shard.dtype),

@@ -172,6 +172,7 @@ def ag_group_gemm(buckets, expert_weights, ctx: AGGroupGEMMContext,
     gathered, out = pl.pallas_call(
         functools.partial(_ag_group_gemm_kernel, ctx, cap, n, k,
                           has_counts),
+        name="ag_group_gemm",
         out_shape=(
             jax.ShapeDtypeStruct((world, e, cap, k), buckets.dtype),
             jax.ShapeDtypeStruct((world, e, cap, n), buckets.dtype),
@@ -297,6 +298,7 @@ def ag_group_gemm_w8a8(buckets, expert_weights_q, w_scales,
     gathered, out = pl.pallas_call(
         functools.partial(_ag_group_gemm_w8a8_kernel, ctx, cap, n, k,
                           has_counts),
+        name="ag_group_gemm_w8a8",
         out_shape=(
             jax.ShapeDtypeStruct((world, e, cap, k), jnp.int8),
             jax.ShapeDtypeStruct((world, e, cap, n), out_dtype),

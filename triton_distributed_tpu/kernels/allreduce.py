@@ -329,6 +329,7 @@ def all_reduce(x, ctx: AllReduceContext):
         mc = m // P
         out, _ = pl.pallas_call(
             functools.partial(_chain_kernel, ctx, P, mc, n),
+            name="all_reduce_chain",
             out_shape=(
                 jax.ShapeDtypeStruct((P, mc, n), x.dtype),
                 jax.ShapeDtypeStruct((P, mc, n), x.dtype),  # staging
@@ -351,6 +352,7 @@ def all_reduce(x, ctx: AllReduceContext):
         mc = m // world
         out, _ = pl.pallas_call(
             functools.partial(_two_shot_kernel, ctx, mc, n),
+            name="all_reduce_two_shot",
             out_shape=(
                 jax.ShapeDtypeStruct((world, mc, n), x.dtype),
                 jax.ShapeDtypeStruct((world, mc, n), x.dtype),
@@ -372,6 +374,7 @@ def all_reduce(x, ctx: AllReduceContext):
     # ONE_SHOT (also the fallback when shapes don't tile)
     out, _ = pl.pallas_call(
         functools.partial(_one_shot_kernel, ctx, m, n),
+        name="all_reduce_one_shot",
         out_shape=(
             jax.ShapeDtypeStruct((m, n), x.dtype),
             jax.ShapeDtypeStruct((world, m, n), x.dtype),

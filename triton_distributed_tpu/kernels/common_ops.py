@@ -51,6 +51,7 @@ def barrier_all_on_axis(x, axis: str, *, collective_id: int = cids.BARRIER,
                       dtype=x.dtype, hops="none")
     return pl.pallas_call(
         functools.partial(_barrier_kernel, axis),
+        name="barrier_all_on_axis",
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
@@ -90,6 +91,7 @@ def broadcast(x, root, axis: str, world_size: int, *,
     root_arr = jnp.asarray(root, jnp.int32).reshape(1)
     return pl.pallas_call(
         functools.partial(_broadcast_kernel, axis, world_size),
+        name="broadcast",
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pltpu.SMEM)],

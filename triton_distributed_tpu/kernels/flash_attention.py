@@ -564,6 +564,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
         res = pl.pallas_call(
             functools.partial(_flash_kernel_single_diag, scale, bq, bk,
                               return_lse, diag_sub),
+            name="flash_attention_fwd_single_diag",
             out_shape=tuple(out_shape),
             grid_spec=pl.GridSpec(
                 grid=(b, h),
@@ -614,6 +615,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
         res = pl.pallas_call(
             functools.partial(_flash_kernel_packed, sk, scale, bq, bk,
                               return_lse, diag_sub),
+            name="flash_attention_fwd_packed",
             out_shape=tuple(out_shape),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=4,
@@ -680,6 +682,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
     res = pl.pallas_call(
         functools.partial(_flash_kernel, nk, sk, causal, scale, bq, bk,
                           return_lse),
+        name="flash_attention_fwd",
         out_shape=tuple(out_shape),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -947,6 +950,7 @@ def _flash_backward(q, k, v, out, lse, do, dlse, *, causal, scale,
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, nk, sk, causal, bq, bk),
+        name="flash_attention_bwd_dq",
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -987,6 +991,7 @@ def _flash_backward(q, k, v, out, lse, do, dlse, *, causal, scale,
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, nq, sq, sk, causal,
                           bq, bk),
+        name="flash_attention_bwd_dkv",
         out_shape=(
             jax.ShapeDtypeStruct((b, h, sk, d), jnp.float32),
             jax.ShapeDtypeStruct((b, h, sk, d), jnp.float32),

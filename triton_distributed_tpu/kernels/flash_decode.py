@@ -195,6 +195,7 @@ def flash_decode(q, k_cache, v_cache, kv_len, *,
     out, lse = pl.pallas_call(
         functools.partial(_decode_kernel, nk, s, scale, bk, quantized,
                           q.dtype),
+        name="flash_decode",
         out_shape=(
             jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
             jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),
@@ -310,6 +311,7 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, kv_len, *,
     out, lse = pl.pallas_call(
         functools.partial(_paged_decode_kernel, nk, t * ps, scale, ps,
                           quantized, q.dtype),
+        name="flash_decode_paged",
         out_shape=(
             jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
             jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),

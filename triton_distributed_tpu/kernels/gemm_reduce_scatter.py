@@ -291,6 +291,7 @@ def gemm_rs(a, b, ctx):
     # Mosaic only allows vmem/smem/semaphore scratch.
     out, _, _ = pl.pallas_call(
         functools.partial(kernel, ctx, mcp, n, k),
+        name="gemm_rs_ll" if method == "ll" else "gemm_rs_fused",
         out_shape=(
             jax.ShapeDtypeStruct((mcp, n), a.dtype),
             jax.ShapeDtypeStruct((world, mcp, n), a.dtype),

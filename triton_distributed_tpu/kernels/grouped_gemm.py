@@ -70,6 +70,7 @@ def grouped_matmul(a, b, config: Optional[MatmulConfig] = None,
               jnp.float32)])
     return pl.pallas_call(
         functools.partial(_grouped_kernel, nk),
+        name="grouped_matmul",
         out_shape=jax.ShapeDtypeStruct((e, m, n), out_dtype),
         grid_spec=pl.GridSpec(
             grid=grid,
@@ -265,6 +266,7 @@ def grouped_matmul_w8a8(a_q, b_q, scale_a, scale_b, config=None,
     sb = scale_b.astype(jnp.float32).reshape(e, 1, n)
     return pl.pallas_call(
         functools.partial(_grouped_w8a8_kernel, nk),
+        name="grouped_matmul_w8a8",
         out_shape=jax.ShapeDtypeStruct((e, m, n), out_dtype),
         grid_spec=pl.GridSpec(
             grid=grid,

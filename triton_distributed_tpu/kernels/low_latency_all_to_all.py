@@ -210,6 +210,7 @@ def fast_all_to_all(send_tokens, send_counts, ctx: AllToAllContext,
 
     result = pl.pallas_call(
         body,
+        name="fast_all_to_all",
         out_shape=tuple(out_shapes),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(operands),
         out_specs=tuple(pl.BlockSpec(memory_space=pl.ANY)

@@ -137,6 +137,7 @@ def matmul(a, b, config: Optional[MatmulConfig] = None,
             limit=MATMUL_VMEM_LIMIT)
     return pl.pallas_call(
         functools.partial(_matmul_kernel, nk),
+        name="matmul",
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         grid_spec=pl.GridSpec(
             grid=grid,

@@ -395,6 +395,7 @@ def moe_reduce_rs_fused(buckets, expert_weights,
     rows = t_max * block
     res = pl.pallas_call(
         kern,
+        name="moe_reduce_rs_fused",
         out_shape=out_shape,
         in_specs=in_specs,
         out_specs=(pl.BlockSpec(memory_space=pl.ANY),) * len(out_shape),

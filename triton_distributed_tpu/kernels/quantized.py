@@ -119,6 +119,7 @@ def matmul_w8a8(a_q, b_q, scale_a, scale_b,
     sb = scale_b.astype(jnp.float32).reshape(1, n)
     return pl.pallas_call(
         functools.partial(_w8a8_kernel, nk),
+        name="matmul_w8a8",
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         grid_spec=pl.GridSpec(
             grid=grid,

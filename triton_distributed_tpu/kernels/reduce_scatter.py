@@ -294,6 +294,7 @@ def reduce_scatter(x, ctx: ReduceScatterContext):
     if method == ReduceScatterMethod.SCATTER_REDUCE:
         out, _ = pl.pallas_call(
             functools.partial(_scatter_reduce_kernel, ctx, m, n),
+            name="reduce_scatter_scatter_reduce",
             out_shape=(
                 jax.ShapeDtypeStruct((m, n), x.dtype),
                 jax.ShapeDtypeStruct((world, m, n), x.dtype),
@@ -313,6 +314,7 @@ def reduce_scatter(x, ctx: ReduceScatterContext):
     # RING
     out, _, _ = pl.pallas_call(
         functools.partial(_ring_rs_kernel, ctx, m, n),
+        name="reduce_scatter_ring",
         out_shape=(
             jax.ShapeDtypeStruct((m, n), x.dtype),
             jax.ShapeDtypeStruct((2, m, n), x.dtype),   # staging (recv)

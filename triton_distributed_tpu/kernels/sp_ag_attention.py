@@ -520,6 +520,7 @@ def sp_ag_attention_fused(q, k_shard, v_shard, axis: str, *,
     out, lse, *_ = pl.pallas_call(
         functools.partial(_sp_ag_attn_fused_kernel, axis, world, scale,
                           block_q, block_k, h // hkv, b, h, hkv, s_loc, d),
+        name="sp_ag_attention_fused",
         out_shape=(
             jax.ShapeDtypeStruct((b, h, s_loc, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, lrows, LSE_W), jnp.float32),

@@ -358,6 +358,7 @@ def all_gather_torus(x, ctx: TorusContext):
 
     out = pl.pallas_call(
         functools.partial(_torus_ag_kernel, ctx, axes, sizes),
+        name="all_gather_torus",
         out_shape=jax.ShapeDtypeStruct(sizes + (L, ms, n), x.dtype),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
@@ -612,6 +613,7 @@ def reduce_scatter_torus(x, ctx: TorusContext):
 
     out, *_ = pl.pallas_call(
         functools.partial(_torus_rs_kernel, ctx, axes, sizes, ms, n),
+        name="reduce_scatter_torus",
         out_shape=tuple(out_shapes),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=(pl.BlockSpec(memory_space=pl.ANY),) * len(out_shapes),
@@ -727,6 +729,7 @@ def ag_gemm_torus(a_shard, b, ctx: TorusContext,
     gathered, out = pl.pallas_call(
         functools.partial(_ag_gemm_torus_kernel, ctx, axes, sizes,
                           ms, n, k),
+        name="ag_gemm_torus",
         out_shape=(
             jax.ShapeDtypeStruct(sizes + (L, ms, k), a_shard.dtype),
             jax.ShapeDtypeStruct(sizes + (L, ms, n), a_shard.dtype),

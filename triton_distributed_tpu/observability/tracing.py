@@ -14,23 +14,46 @@ Three consumers, one record:
   (``export_chrome_trace``) loadable in Perfetto / ``chrome://tracing``
   and mergeable across ranks on a shared clock (:mod:`.timeline`);
 - the **XLA profiler**: every span also enters a
-  ``jax.profiler.TraceAnnotation``, so the same names appear on the
-  XProf timeline when a ``jax.profiler`` trace is active;
+  ``jax.profiler.TraceAnnotation``, so the same names appear on a host
+  plane of the XProf timeline when a ``jax.profiler`` trace is active;
 - the **flight recorder / heartbeat**: the currently-open span stack is
   queryable (``open_spans``), so a SIGTERM dump or a stale-rank report
   can say what the rank was doing when it stopped.
 
+One clock.  Inside a ``jax.profiler`` trace a span's
+``TraceAnnotation`` is stamped by the profiler itself, so spans and
+device operations share the trace's clock by construction — nothing
+is converted.  Outside one the ring is the record: every span keeps
+its raw ``time.perf_counter()`` start (``Span.t0``), and
+:data:`MONOTONIC_OFFSET` is the one offset from that clock to
+``time.monotonic()`` (the clock a serving harness hands the
+scheduler), so a reader can cut the ring by the harness's window.
+
+Cause.  A span records the span that was open on its thread when it
+started (``Span.parent``, an ``id``): self time of a span is its
+duration less its children's.  A *detached* span
+(:meth:`SpanTracer.detached`) is a lifetime, not something a thread
+is doing — a request from admission to retirement, closed in any
+order: it is in the ring and in ``open_spans``, but it is never a
+parent, and it does not enter a ``TraceAnnotation`` (the profiler's
+per-thread stack expects enter and exit in stack order).
+
 Cost discipline: with ``TDT_OBSERVABILITY=0`` the module-level
 :func:`span` returns one shared no-op context manager — no allocation,
 no lock, no clock read.  Enabled spans land in a bounded ring
-(``TDT_TRACE_RING``, default 16384 finished spans), so a long-running
-server never grows without bound.
+(``TDT_TRACE_RING``, default 65536 finished spans: a serving run of a
+few minutes — ~2000 scheduler steps of 9 spans — fits three times),
+so a long-running server never grows without bound; what the ring
+evicts is counted (``SpanTracer.dropped``,
+``trace_dropped_spans_total``) so no reader takes a truncated window
+for a whole one.
 """
 
 from __future__ import annotations
 
 import atexit
 import functools
+import itertools
 import json
 import os
 import threading
@@ -41,13 +64,14 @@ from typing import Any, Dict, List, Optional
 from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from triton_distributed_tpu.observability.metrics import (
+    get_registry,
     observability_enabled,
 )
 
 #: Env knobs (scripts/launch.py --trace-dir plumbs the first one).
 ENV_TRACE_DIR = "TDT_TRACE_DIR"
 ENV_TRACE_RING = "TDT_TRACE_RING"
-DEFAULT_RING = 16384
+DEFAULT_RING = 65536
 
 #: Unix-epoch base of ``time.perf_counter``, captured once per process:
 #: span timestamps are ``_CLOCK_BASE + perf_counter()``, i.e. monotonic
@@ -57,44 +81,57 @@ DEFAULT_RING = 16384
 #: export metadata so the merge can report it).
 _CLOCK_BASE = time.time() - time.perf_counter()  # noqa: W001 (perf_counter epoch anchor, export metadata)
 
+#: ``time.monotonic() - time.perf_counter()``, captured once: add it to
+#: a span's ``t0`` to place the span on ``time.monotonic``'s clock.
+MONOTONIC_OFFSET = time.monotonic() - time.perf_counter()  # noqa: W001 (the offset between two clocks, read once at import)
+
+
 class Span:
     """One timed region.  Context manager; reentrant use is a bug
     (enter creates state), nest by creating new spans."""
 
-    __slots__ = ("name", "attrs", "ts", "dur", "tid", "depth",
-                 "_tracer", "_t0", "_ann")
+    __slots__ = ("name", "attrs", "id", "parent", "t0", "ts", "dur",
+                 "tid", "depth", "detached", "_tracer", "_ann")
 
     def __init__(self, tracer: "SpanTracer", name: str,
-                 attrs: Optional[Dict[str, Any]] = None):
+                 attrs: Optional[Dict[str, Any]] = None,
+                 detached: bool = False):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs or {}
+        self.id = next(tracer._ids)
+        self.parent = None     # id of the span that caused this one
+        self.t0 = 0.0          # raw time.perf_counter() at enter
         self.ts = 0.0          # unix seconds at enter
         self.dur = None        # seconds; None while open
         self.tid = 0
         self.depth = 0
+        self.detached = detached
         self._ann = None
 
     def __enter__(self) -> "Span":
         self.tid = threading.get_ident()
-        self.depth = self._tracer._push(self)
-        self._ann = _TraceAnnotation(self.name)
-        self._ann.__enter__()
-        self._t0 = time.perf_counter()
-        self.ts = _CLOCK_BASE + self._t0
+        self._tracer._push(self)
+        if not self.detached:
+            self._ann = _TraceAnnotation(self.name)
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        self.ts = _CLOCK_BASE + self.t0
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = time.perf_counter()
-        self._ann.__exit__(exc_type, exc, tb)
-        self.dur = t1 - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        self.dur = t1 - self.t0
         if exc_type is not None:
             self.attrs["error"] = repr(exc_type.__name__)
         self._tracer._pop(self)
         return False
 
     def to_dict(self) -> dict:
-        return {"name": self.name, "ts": self.ts, "dur": self.dur,
+        return {"name": self.name, "id": self.id,
+                "parent": self.parent, "ts": self.ts, "dur": self.dur,
                 "tid": self.tid, "depth": self.depth,
                 "attrs": self.attrs}
 
@@ -103,7 +140,7 @@ class Span:
         """Chrome "complete" (ph=X) event, µs timestamps.  An open span
         reports its duration so far and ``args.open=true``."""
         dur = self.dur
-        args = dict(self.attrs)
+        args = dict(self.attrs, id=self.id, parent=self.parent)
         if dur is None:
             dur = max((now or time.time()) - self.ts, 0.0)  # noqa: W001 (default when no `now` injected)
             args["open"] = True
@@ -141,7 +178,13 @@ class SpanTracer:
         self._lock = threading.RLock()
         self._ring = collections.deque(maxlen=capacity)
         self._open: Dict[int, List[Span]] = {}
+        #: Open detached spans by id: lifetimes, closed in any order.
+        self._detached: Dict[int, Span] = {}
         self._last: Optional[Span] = None  # most recently *started*
+        self._ids = itertools.count(1)
+        #: Finished spans the full ring has evicted since the start
+        #: (or the last `clear`).
+        self.dropped = 0
 
     @property
     def capacity(self) -> int:
@@ -155,28 +198,55 @@ class SpanTracer:
             return NULL_SPAN
         return Span(self, name, attrs)
 
+    def detached(self, name: str, **attrs) -> Span:
+        """A lifetime span (a request from admission to retirement):
+        entered and exited by hand, in any order relative to other
+        spans.  Recorded like any span — ring, ``open_spans``, its
+        ``parent`` the span that started it — but never a parent
+        itself and never shown to the profiler."""
+        if not observability_enabled():
+            return NULL_SPAN
+        return Span(self, name, attrs, detached=True)
+
+    @property
+    def monotonic_offset(self) -> float:
+        """:data:`MONOTONIC_OFFSET`, for a reader that holds the
+        tracer and does not import this module."""
+        return MONOTONIC_OFFSET
+
     # -- Span plumbing ---------------------------------------------------
 
-    def _push(self, s: Span) -> int:
+    def _push(self, s: Span) -> None:
         with self._lock:
-            stack = self._open.setdefault(s.tid, [])
-            stack.append(s)
+            stack = self._open.get(s.tid)
+            if stack:
+                s.parent = stack[-1].id
+                s.depth = len(stack)
+            if s.detached:
+                self._detached[s.id] = s
+            elif stack is None:
+                self._open[s.tid] = [s]
+            else:
+                stack.append(s)
             self._last = s
-            return len(stack) - 1
 
     def _pop(self, s: Span) -> None:
         with self._lock:
-            stack = self._open.get(s.tid)
-            if stack and s in stack:
-                stack.remove(s)
+            if s.detached:
+                self._detached.pop(s.id, None)
+            else:
+                stack = self._open.get(s.tid)
+                if stack and stack[-1] is s:
+                    stack.pop()
+                elif stack and s in stack:
+                    stack.remove(s)
                 if not stack:
-                    del self._open[s.tid]
+                    self._open.pop(s.tid, None)
             if len(self._ring) == self._ring.maxlen:
+                self.dropped += 1
                 # Overflow must not be silent: a timeline merged from
                 # this ring is missing the evicted span, and a doctor
                 # report built on it should say so.
-                from triton_distributed_tpu.observability.metrics \
-                    import get_registry
                 get_registry().counter("trace_dropped_spans_total").inc()
             self._ring.append(s)
 
@@ -190,7 +260,8 @@ class SpanTracer:
         """Currently-open spans across every thread, outermost first
         per thread — "what is this rank doing right now"."""
         with self._lock:
-            return [s for stack in self._open.values() for s in stack]
+            return ([s for stack in self._open.values() for s in stack]
+                    + list(self._detached.values()))
 
     def last_span(self) -> Optional[Span]:
         """The innermost open span, else the most recently started one
@@ -205,7 +276,9 @@ class SpanTracer:
         with self._lock:
             self._ring.clear()
             self._open.clear()
+            self._detached.clear()
             self._last = None
+            self.dropped = 0
 
     # -- Chrome-trace export ---------------------------------------------
 
@@ -220,6 +293,7 @@ class SpanTracer:
             spans = list(self._ring)
             if include_open:
                 spans += [s for st in self._open.values() for s in st]
+                spans += list(self._detached.values())
         events = [{"ph": "M", "name": "process_name", "pid": rank,
                    "args": {"name": f"rank {rank}"}},
                   {"ph": "M", "name": "process_sort_index", "pid": rank,

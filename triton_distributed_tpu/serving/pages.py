@@ -254,6 +254,12 @@ class RadixCache:
         self.hit_tokens = 0
         self.miss_tokens = 0
         self.evicted_pages = 0
+        #: `evict` calls, and the pages they freed (destroyed OR
+        #: spilled: what `evicted_pages` leaves out) — mirrored as
+        #: ``serving_kv_evict_calls_total`` /
+        #: ``serving_kv_evicted_pages_total``.
+        self.evict_calls = 0
+        self.freed_pages = 0
         #: Spill-before-evict (optional): the host pool and the
         #: ``read_page(page) -> payload`` content reader (the owning
         #: `PagedKV` wires both when spill is enabled).
@@ -463,6 +469,11 @@ class RadixCache:
                     and self._frontier_leaf(parent)):
                 heapq.heappush(frontier,
                                (parent.last_use, id(parent), parent))
+        self.evict_calls += 1
+        self.freed_pages += freed
+        _count_metric("serving_kv_evict_calls_total")
+        if freed:
+            _count_metric("serving_kv_evicted_pages_total", freed)
         return freed
 
 
@@ -553,6 +564,12 @@ class PagedKV:
                                                    range(self.num_slots)]
         #: Logical pages currently mapped per slot.
         self._mapped = np.zeros(self.num_slots, np.int64)
+        #: Work at the KV boundary since the start: pages `ensure`
+        #: mapped, page-table rows `flush` uploaded — mirrored as
+        #: ``serving_kv_pages_mapped_total`` /
+        #: ``serving_kv_table_rows_flushed_total``.
+        self.mapped_pages = 0
+        self.flushed_rows = 0
         # `insert_fn` is an injection seam for the serving-state model
         # checker / fuzz harness (`analysis.serving_model`): the real
         # host-side page accounting runs against a recording insert
@@ -580,6 +597,15 @@ class PagedKV:
     @property
     def used_pages(self) -> int:
         return self.pool.used_pages
+
+    @property
+    def live_pages(self) -> int:
+        """Pages LIVE requests hold (private, or shared prefix pages
+        some request maps) — `used_pages` less the prefix pages the
+        radix cache merely retains, which the next allocation may
+        evict: memory in use, against memory reserved."""
+        return self.pool.used_pages - (
+            self.radix.evictable_pages() if self.radix else 0)
 
     @property
     def page_occupancy(self) -> float:
@@ -712,16 +738,21 @@ class PagedKV:
         """
         need = min(pages_for(need_positions, self.page_size),
                    self.pages_per_seq)
+        mapped = 0
         while self._mapped[slot] < need:
             ids = self._alloc(1)
             if not ids:
-                return False
+                break
             j = int(self._mapped[slot])
             self._table[slot, j] = ids[0]
             self._slot_pages[slot].append(ids[0])
             self._mapped[slot] = j + 1
             self._dirty = True
-        return True
+            mapped += 1
+        if mapped:
+            self.mapped_pages += mapped
+            _count_metric("serving_kv_pages_mapped_total", mapped)
+        return bool(self._mapped[slot] >= need)
 
     def rollback(self, slot: int, keep_positions: int) -> None:
         """Shrink slot ``slot``'s mapping to cover exactly KV
@@ -764,6 +795,9 @@ class PagedKV:
         if self._dirty:
             self.cache = self.cache.with_page_table(self._table)
             self._dirty = False
+            self.flushed_rows += self.num_slots
+            _count_metric("serving_kv_table_rows_flushed_total",
+                          self.num_slots)
 
     # -- lifecycle -------------------------------------------------------
 

@@ -34,9 +34,13 @@ Time comes from an injectable ``clock`` (+ optional ``clock_advance``
 for virtual time), so tests and `benchmark/bench_serving.py` replay
 deterministic arrival schedules.  Request-level observability rides
 the PR-1/2 stack: TTFT / TBT / queue-wait histograms, queue-depth /
-slot-occupancy / KV-budget gauges (all in the Prometheus export), and
-one `serving.request` span per request feeding the cross-rank
-timeline.  Metric names: docs/serving.md.
+slot-occupancy / KV-budget gauges (all in the Prometheus export), one
+detached `serving.request` span per request feeding the cross-rank
+timeline, and a span around each phase of a step (`serving.step` >
+`serving.admit` > `serving.admit.request` > `serving.prefill.block`;
+`serving.pages`, `serving.dispatch`, `serving.sync`, `serving.commit`,
+`serving.gauges`) that says where the host's time in a step went.
+Metric and span names: docs/serving.md, docs/observability.md.
 """
 
 from __future__ import annotations
@@ -50,6 +54,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from triton_distributed_tpu.observability.tracing import (
+    NULL_SPAN,
+    get_tracer,
+    span,
+)
 from triton_distributed_tpu.serving.engine_batched import (
     DEFAULT_PREFILL_BUCKETS,
     make_masked_block_fn,
@@ -312,10 +321,33 @@ class ContinuousBatchingScheduler:
         self._row_caches: Dict[int, object] = {}
         self._queue: Deque[Request] = collections.deque()
         self._by_slot: Dict[int, Request] = {}
+        #: Open `serving.request` spans by slot.
         self._spans: Dict[int, object] = {}
+        #: Scheduler iterations so far (`serving.step`'s ``step``).
+        self._steps = 0
         self._stopped = False
         self.finished: List[Request] = []
         self._update_gauges()
+
+    @property
+    def tracer(self):
+        """The tracer this scheduler's spans land in."""
+        return get_tracer()
+
+    def close(self) -> None:
+        """End the `serving.request` spans of requests still in their
+        slots: a scheduler dropped mid-run must not leave them open in
+        the process's tracer (idempotent; `stop()` retires them with
+        a reason instead)."""
+        while self._spans:
+            _, sp = self._spans.popitem()
+            sp.__exit__(None, None, None)
+
+    def __del__(self):
+        try:
+            self.close()
+        except Exception:       # interpreter shutdown
+            pass
 
     # -- submission / backpressure --------------------------------------
 
@@ -426,6 +458,14 @@ class ContinuousBatchingScheduler:
     def step(self) -> dict:
         """One scheduler iteration.  Returns counts for introspection:
         ``{"admitted", "active", "retired"}``."""
+        self._steps += 1
+        with span("serving.step", step=self._steps) as sp:
+            out = self._step_phases()
+            if sp is not NULL_SPAN:
+                sp.attrs.update(out)
+        return out
+
+    def _step_phases(self) -> dict:
         now = self.clock()
         admitted = self._admit(now)
         retired = 0
@@ -441,7 +481,8 @@ class ContinuousBatchingScheduler:
                 else:
                     time.sleep(min(dt, 0.001))
         if admitted or retired:
-            self._update_gauges()
+            with span("serving.gauges"):
+                self._update_gauges()
         return {"admitted": admitted, "active": active_n,
                 "retired": retired}
 
@@ -687,97 +728,119 @@ class ContinuousBatchingScheduler:
         return True
 
     def _admit(self, now: float) -> int:
-        from triton_distributed_tpu.observability import get_tracer
+        # Nothing to try: no arrived head, or no slot to put it in
+        # (both layouts refuse without a free slot) — and no span.
+        if (not self._queue or self._stopped
+                or self._queue[0].t_arrival > now
+                or not self.slots.free_slots):
+            return 0
         n = 0
-        while (self._queue and not self._stopped
-               and self._queue[0].t_arrival <= now
-               and self._can_admit_head()
-               and self._slo_gate(now)):
-            req = self._queue.popleft()
-            reg = self._registry()
-            had_ship = req.shipped_kv is not None
-            if self.paged:
-                admitted = self._admit_paged(req, now, reg)
-                if admitted is None:
-                    continue              # retired at admission
-                slot, bucket, tokens, mode = admitted
-            else:
-                tokens = req.prompt
-                mode = "local"
-                if req.shipped_kv is not None:
-                    row_cache, s, bucket = self._shipped_row(req, reg)
-                    mode = "shipped"
-                else:
-                    bucket = pick_bucket(req.prompt_len, self.buckets)
-                    assert bucket is not None  # submit() validated
-                    ids, s = pad_prompt(req.prompt, bucket,
-                                        self.config.pad_id)
-                    row_in = self._row_cache(bucket)
-                    t0 = time.perf_counter()
-                    _, row_cache = self._prefill(self.params, ids,
-                                                 row_in)
-                    if reg:
-                        # dispatch is async: block so the histogram
-                        # records prefill compute, not dispatch (as
-                        # Engine.serve does)
-                        jax.block_until_ready(row_cache.ks[0])
-                        ms = (time.perf_counter() - t0) * 1e3
-                        reg.histogram("serving_prefill_ms").observe(ms)
-                        _observe_prefill(bucket, ms)
-                        self._charge_device("prefill", ms * 1e3,
-                                            (req,))
-                slot = self.slots.insert_prefill(
-                    row_cache, s, self._request_key(req))
-            self._tokens[slot] = tokens[-1]
-            req.state = RequestState.RUNNING
-            req.slot = slot
-            req.bucket = bucket
-            req.t_admitted = now
-            self._by_slot[slot] = req
-            if self.drafter is not None and not self._spec_throttled:
-                # Admission (or resume) seeds the draft state from the
-                # full committed context — same tokens that seeded the
-                # slot's input above.  A throttled engine skips the
-                # upkeep entirely (draft prefills, reconcile
-                # dispatches): the throttle is for the scheduler's
-                # lifetime, so the draft cache will never be read.
-                self.drafter.start(req, tokens)
-            sp = get_tracer().span(
-                "serving.request", request_id=req.request_id,
-                prompt_len=req.prompt_len, slot=slot, bucket=bucket)
-            sp.__enter__()
-            self._spans[slot] = sp
-            if reg:
-                # A consumed shipment (`_shipped_row` clears the
-                # hook) ran NO local prefill — it has its own
-                # serving_shipped_inserts_total, and counting it here
-                # would desync this counter from the
-                # serving_prefill_ms histogram it pairs with.
-                if not (had_ship and req.shipped_kv is None):
-                    reg.counter("serving_prefills_total",
-                                bucket=str(bucket)).inc()
-                reg.histogram("serving_queue_wait_ms").observe(
-                    max(now - req.t_arrival, 0.0) * 1e3)
-                if (req.resume_tokens is not None or req.preemptions
-                        or req.resume_key is not None):
-                    # A preempt-and-requeue (or failover re-prefill)
-                    # resume: the "resume" half of the seam.  The
-                    # tokens recomputed by this admission are the
-                    # preemption's waste bill.
-                    self._charge_tokens("reprefill", req, len(tokens))
-                    self._hop(req, "admit", now, slot=slot,
-                              bucket=bucket, mode=mode, resumed=True)
-                else:
-                    self._hop(req, "admit", now, slot=slot,
-                              bucket=bucket, mode=mode)
-            n += 1
+        with span("serving.admit", queued=len(self._queue)):
+            while (self._queue and self._queue[0].t_arrival <= now
+                   and self._can_admit_head()
+                   and self._slo_gate(now)):
+                req = self._queue.popleft()
+                with span("serving.admit.request",
+                          request_id=req.request_id,
+                          prompt_len=req.prompt_len) as sp:
+                    n += self._admit_one(req, now, sp)
         return n
+
+    def _admit_one(self, req: Request, now: float, sp) -> int:
+        """Admit ``req`` (already off the queue) into a slot: prefix
+        match, prefill dispatch, insert dispatch.  Returns 1, or 0
+        when the request was retired at admission.  ``sp`` is the
+        `serving.admit.request` span (or the no-op one)."""
+        reg = self._registry()
+        had_ship = req.shipped_kv is not None
+        cached = 0
+        if self.paged:
+            admitted = self._admit_paged(req, now, reg)
+            if admitted is None:
+                return 0              # retired at admission
+            slot, bucket, tokens, mode, cached = admitted
+        else:
+            tokens = req.prompt
+            mode = "local"
+            if req.shipped_kv is not None:
+                row_cache, s, bucket = self._shipped_row(req, reg)
+                mode = "shipped"
+            else:
+                bucket = pick_bucket(req.prompt_len, self.buckets)
+                assert bucket is not None  # submit() validated
+                ids, s = pad_prompt(req.prompt, bucket,
+                                    self.config.pad_id)
+                row_in = self._row_cache(bucket)
+                t0 = time.perf_counter()
+                _, row_cache = self._prefill(self.params, ids,
+                                             row_in)
+                if reg:
+                    # dispatch is async: block so the histogram
+                    # records prefill compute, not dispatch (as
+                    # Engine.serve does)
+                    with span("serving.prefill.block",
+                              request_id=req.request_id):
+                        jax.block_until_ready(row_cache.ks[0])
+                    ms = (time.perf_counter() - t0) * 1e3
+                    reg.histogram("serving_prefill_ms").observe(ms)
+                    _observe_prefill(bucket, ms)
+                    self._charge_device("prefill", ms * 1e3,
+                                        (req,))
+            slot = self.slots.insert_prefill(
+                row_cache, s, self._request_key(req))
+        self._tokens[slot] = tokens[-1]
+        req.state = RequestState.RUNNING
+        req.slot = slot
+        req.bucket = bucket
+        req.t_admitted = now
+        self._by_slot[slot] = req
+        if self.drafter is not None and not self._spec_throttled:
+            # Admission (or resume) seeds the draft state from the
+            # full committed context — same tokens that seeded the
+            # slot's input above.  A throttled engine skips the
+            # upkeep entirely (draft prefills, reconcile
+            # dispatches): the throttle is for the scheduler's
+            # lifetime, so the draft cache will never be read.
+            self.drafter.start(req, tokens)
+        if sp is not NULL_SPAN:
+            sp.attrs.update(bucket=bucket, cached_tokens=cached,
+                            mode=mode, slot=slot)
+        life = get_tracer().detached(
+            "serving.request", request_id=req.request_id,
+            prompt_len=req.prompt_len, slot=slot, bucket=bucket)
+        life.__enter__()
+        self._spans[slot] = life
+        if reg:
+            # A consumed shipment (`_shipped_row` clears the
+            # hook) ran NO local prefill — it has its own
+            # serving_shipped_inserts_total, and counting it here
+            # would desync this counter from the
+            # serving_prefill_ms histogram it pairs with.
+            if not (had_ship and req.shipped_kv is None):
+                reg.counter("serving_prefills_total",
+                            bucket=str(bucket)).inc()
+            reg.histogram("serving_queue_wait_ms").observe(
+                max(now - req.t_arrival, 0.0) * 1e3)
+            if (req.resume_tokens is not None or req.preemptions
+                    or req.resume_key is not None):
+                # A preempt-and-requeue (or failover re-prefill)
+                # resume: the "resume" half of the seam.  The
+                # tokens recomputed by this admission are the
+                # preemption's waste bill.
+                self._charge_tokens("reprefill", req, len(tokens))
+                self._hop(req, "admit", now, slot=slot,
+                          bucket=bucket, mode=mode, resumed=True)
+            else:
+                self._hop(req, "admit", now, slot=slot,
+                          bucket=bucket, mode=mode)
+        return 1
 
     def _admit_paged(self, req: Request, now: float, reg):
         """Paged admission: radix prefix match, suffix-only prefill on
         a hit (near-zero-cost shared system prompts), paged insert.
-        Returns (slot, bucket, tokens, mode) — mode is the lineage
-        admission class (local / shipped / suffix) — or None when the
+        Returns (slot, bucket, tokens, mode, cached tokens) — mode is
+        the lineage admission class (local / shipped / suffix) — or
+        None when the
         request had to be retired at admission (a resumed stream that
         no longer fits any prefill bucket)."""
         tokens = req.resume_tokens or req.prompt
@@ -861,7 +924,9 @@ class ContinuousBatchingScheduler:
                                    self._row_cache(bucket))
             row_start = 0
         if reg:
-            jax.block_until_ready(row.ks[0])
+            with span("serving.prefill.block",
+                      request_id=req.request_id):
+                jax.block_until_ready(row.ks[0])
             if t0 is not None:
                 ms = (time.perf_counter() - t0) * 1e3
                 reg.histogram("serving_prefill_ms").observe(ms)
@@ -872,7 +937,7 @@ class ContinuousBatchingScheduler:
                 s - c)
         slot = self.slots.insert_prefill(row, tokens, s, key, shared,
                                          row_start=row_start)
-        return slot, bucket, tokens, mode
+        return slot, bucket, tokens, mode, c
 
     def _block_size(self) -> int:
         """Steps for this dispatch: the configured block, unless some
@@ -922,6 +987,30 @@ class ContinuousBatchingScheduler:
                          key=lambda sl: (self._by_slot[sl].t_admitted,
                                          self._by_slot[sl].request_id))
             self._preempt(victim)
+
+    def _pages_phase(self, writes: int, sp) -> None:
+        """Paged mode, the KV phase of a dispatch: map the pages it
+        writes (evicting, preempting), then re-ship the page table if
+        that changed it.  ``sp`` is the `serving.pages` span (or the
+        no-op one): it records what the phase did, and the pages live
+        requests hold once it is done."""
+        slots = self.slots
+
+        def work():
+            return (slots.mapped_pages, slots.flushed_rows,
+                    slots.radix.freed_pages if slots.radix else 0,
+                    -len(self._by_slot))
+
+        before = work() if sp is not NULL_SPAN else None
+        self._prepare_pages(writes)
+        if self._by_slot:
+            slots.flush()
+        if before is not None:
+            mapped, flushed, evicted, preempted = (
+                x - y for x, y in zip(work(), before))
+            sp.attrs.update(mapped=mapped, flushed_rows=flushed,
+                            evicted=evicted, preempted=preempted,
+                            live_pages=slots.live_pages)
 
     def _preempt(self, slot: int) -> None:
         req = self._by_slot.pop(slot)
@@ -1056,22 +1145,25 @@ class ContinuousBatchingScheduler:
         # writes: K proposals + the bonus position under speculation.
         writes = self.config.spec_k + 1 if spec is not None else k
         if self.paged:
-            self._prepare_pages(writes)
+            with span("serving.pages") as sp:
+                self._pages_phase(writes, sp)
             if not self._by_slot:      # defensive: all preempted
                 return 0
-            self.slots.flush()
         accept_host = n_draft = None
         if spec is not None:
             drafts, n_draft = spec
-            targets, accept, cache, keys = self._spec_fn(
-                self.params, jnp.asarray(self._tokens),
-                jnp.asarray(drafts), self.slots.cache,
-                self.slots.keys, self.slots.active_mask(),
-                jnp.asarray(n_draft))
-            self.slots.cache = cache
-            self.slots.keys = keys
-            toks_host = np.asarray(targets)   # THE host sync
-            accept_host = np.asarray(accept)
+            with span("serving.dispatch", k=self.config.spec_k,
+                      spec=True):
+                targets, accept, cache, keys = self._spec_fn(
+                    self.params, jnp.asarray(self._tokens),
+                    jnp.asarray(drafts), self.slots.cache,
+                    self.slots.keys, self.slots.active_mask(),
+                    jnp.asarray(n_draft))
+                self.slots.cache = cache
+                self.slots.keys = keys
+            with span("serving.sync"):
+                toks_host = np.asarray(targets)   # THE host sync
+                accept_host = np.asarray(accept)
             # Normalize the step metric by tokens COMMITTED, not
             # positions scanned: serving_decode_step_ms/us feed the
             # SLO admission baseline and the router's placement
@@ -1082,13 +1174,15 @@ class ContinuousBatchingScheduler:
                 accept_host[list(self._by_slot)])) + 1.0
         else:
             fn = self._block_fn if k > 1 else self._step
-            toks, cache, keys = fn(
-                self.params, jnp.asarray(self._tokens),
-                self.slots.cache, self.slots.keys,
-                self.slots.active_mask())
-            self.slots.cache = cache
-            self.slots.keys = keys
-            toks_host = np.asarray(toks)      # THE host sync
+            with span("serving.dispatch", k=k, spec=False):
+                toks, cache, keys = fn(
+                    self.params, jnp.asarray(self._tokens),
+                    self.slots.cache, self.slots.keys,
+                    self.slots.active_mask())
+                self.slots.cache = cache
+                self.slots.keys = keys
+            with span("serving.sync"):
+                toks_host = np.asarray(toks)      # THE host sync
             if k == 1:
                 toks_host = toks_host[:, None]
             steps = k
@@ -1139,10 +1233,14 @@ class ContinuousBatchingScheduler:
                 "spec_verify" if spec is not None else "decode",
                 elapsed_ms * 1e3, [r for _, r in rows])
             self._charge_kv_residency([r for _, r in rows], now)
-        if spec is not None:
-            self._spec_outcome(rows, accept_host, n_draft, now, reg)
-        retired, generated = self._commit_tokens(
-            rows, toks_host, accept_host, now, reg)
+        with span("serving.commit") as sp:
+            if spec is not None:
+                self._spec_outcome(rows, accept_host, n_draft, now,
+                                   reg)
+            retired, generated = self._commit_tokens(
+                rows, toks_host, accept_host, now, reg)
+            if sp is not NULL_SPAN:
+                sp.attrs.update(tokens=generated, retired=retired)
         if reg:
             reg.counter("serving_tokens_generated_total").inc(generated)
         return retired
@@ -1303,6 +1401,9 @@ class ContinuousBatchingScheduler:
         if self.paged:
             reg.gauge("serving_kv_pages_free").set(self.slots.free_pages)
             reg.gauge("serving_kv_pages_used").set(self.slots.used_pages)
+            # Pages live requests hold, apart from the prefix pages
+            # the radix cache merely retains: in use against reserved.
+            reg.gauge("serving_kv_pages_live").set(self.slots.live_pages)
             reg.gauge("serving_kv_page_occupancy").set(
                 self.slots.page_occupancy)
             reg.gauge("serving_prefix_cache_pages").set(

@@ -555,8 +555,11 @@ class BatchedDraftModelDrafter(Drafter):
             # outgrown streams): no cursor moved, nothing to ship —
             # skip the reconcile dispatch entirely.
             return
+        # `off` is the persistent host mirror, updated below while the
+        # dispatch may still be reading its operands: hand the device
+        # a copy (on the CPU backend `asarray` can alias the buffer).
         self.cache, self.keys = self._reconcile(
-            self.params, jnp.asarray(tf_tokens), jnp.asarray(off),
+            self.params, jnp.asarray(tf_tokens), jnp.array(off),
             self.cache, self.keys, jnp.asarray(tf_mask))
         # mirror reflects post-teacher-force cursors for next round
         off[tf_mask] += 1
