@@ -31,23 +31,24 @@ come out as not correct, and never a result.
 
 from __future__ import annotations
 
-import time
+import os
+import sys
 
-T_PROCESS_START = time.monotonic()
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+# first, so that its clock starts with the process
+from cellbench.clock import say, since_start   # noqa: E402
 
 import argparse            # noqa: E402
 import contextlib          # noqa: E402
 import dataclasses         # noqa: E402
 import importlib           # noqa: E402
 import json                # noqa: E402
-import os                  # noqa: E402
 import shutil              # noqa: E402
-import sys                 # noqa: E402
-
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(HERE)
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+import time                # noqa: E402
 
 from cellbench import (correctness, model_math, stats,   # noqa: E402
                        trace_reduce, traffic_gen)
@@ -56,16 +57,23 @@ EXIT_NO_CHIP, EXIT_COMPILED_IN_WINDOW = 4, 6
 
 #: Longest wait after the window for its requests to finish.
 DRAIN_S = 180.0
-#: Seconds of the window's end that a `--trace 1` run traces.
+#: A `--trace 1` run starts its trace this many seconds before the
+#: window's end ...
 TRACE_S = 20.0
+#: ... and stops it at the window's end or after this many scheduler
+#: steps, whichever comes first.  A trace costs by its events, not by
+#: its seconds (at tp=4, 36 layers: ~10,400 kept events a step over
+#: the four chips, ~20 us each to stop, read and reduce, on top of a
+#: fixed 16-22 s), so a bound in steps keeps a traced run as long
+#: however fast the program steps.  120: the trace's own cost at tp=4
+#: stays under 45 s and the whole run under 300 s (PERF.md sections
+#: 2, 3); the per-layer metrics are medians and sums per traced step
+#: and read the same off fewer.
+TRACE_STEPS = 120
 #: Most requests compared with the reference after a run (a cell's
 #: `check_requests` says otherwise): all the window finished, up to
 #: this many, the longest always among them.
 CHECK_REQUESTS = 16
-
-
-def say(**kw) -> None:
-    print(json.dumps(kw), flush=True)
 
 
 def load_json(*parts) -> dict:
@@ -189,7 +197,8 @@ class Drive:
     t0: float
     start: float           # window
     end: float
-    trace_span: tuple = None
+    trace_span: tuple = None     # (start, stop) of the traced steps
+    stop_trace_s: float = 0.0    # what writing the trace took
     wrapped: int = 0
 
     def sample(self):
@@ -230,19 +239,26 @@ def drive(system, plan, lead_in_s: float, seconds: float,
     t0 = mono()
     start, end = t0 + lead_in_s, t0 + lead_in_s + seconds
     trace_at = end - min(TRACE_S, seconds) if trace_dir else None
-    tracing, trace_span = False, None
+    tracing, trace_span, traced_steps, stop_trace_s = False, None, 0, 0.0
+
+    def stop_trace():
+        # the last step ended in a host sync: the device is idle.
+        # The span ends BEFORE the stop, which writes the file.
+        nonlocal tracing, trace_span, stop_trace_s
+        trace_span = (trace_span, mono())
+        jax.profiler.stop_trace()
+        stop_trace_s = mono() - trace_span[1]
+        tracing = False
+
     plan.start(t0)
     issuing = True
     while True:
         now = mono()
         if issuing and now >= end:
             issuing = False
-        if tracing and now >= end:
-            # the last step ended in a host sync: the device is idle.
-            # The span ends BEFORE the stop, which writes the file.
-            trace_span = (trace_span, mono())
-            jax.profiler.stop_trace()
-            tracing = False
+        if tracing and (now >= end or traced_steps >= TRACE_STEPS):
+            stop_trace()
+            now = mono()
         if trace_at is not None and now >= trace_at and issuing:
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
@@ -272,6 +288,7 @@ def drive(system, plan, lead_in_s: float, seconds: float,
             t = mono()
             steps.append((t, out["active"], out["admitted"],
                           system.used_pages(), live))
+            traced_steps += tracing
             while finished:
                 row = finished.pop()
                 row.done_at = row.times[-1]
@@ -290,9 +307,9 @@ def drive(system, plan, lead_in_s: float, seconds: float,
                        for r in inflight.values()):
                 break
     if tracing:
-        trace_span = (trace_span, mono())
-        jax.profiler.stop_trace()
+        stop_trace()
     return Drive(rows, steps, t0, start, end, trace_span=trace_span,
+                 stop_trace_s=stop_trace_s,
                  wrapped=getattr(plan, "wrapped", 0))
 
 
@@ -556,10 +573,9 @@ def run_cell(args) -> int:
         else args.weights)
     say(event="built", weight_bytes=system.weight_bytes,
         weights=args.weights, kv_budget_bytes=system.kv_budget_bytes,
-        usable_pages=system.usable_pages, buckets=list(system.buckets),
-        since_start_s=time.monotonic() - T_PROCESS_START)
+        usable_pages=system.usable_pages, buckets=list(system.buckets))
     warmed = warm_up(system, plan, args.seed)
-    setup_s = time.monotonic() - T_PROCESS_START
+    setup_s = since_start()
     say(event="warm", **warmed, setup_s=setup_s,
         compile=counters.snapshot())
 
@@ -612,26 +628,44 @@ def run_cell(args) -> int:
     e2e = end_to_end(d, setup_s)
     trace = None
     if args.trace:
+        events = read_s = reduce_s = file_bytes = None
         if not spec.rehearse:
-            planes = trace_reduce.read(
-                trace_reduce.find_xplane(trace_dir))
+            xplane = trace_reduce.find_xplane(trace_dir)
+            file_bytes = os.path.getsize(xplane)
+            t = time.monotonic()
+            planes = trace_reduce.read(xplane)
+            read_s = time.monotonic() - t
+            events = trace_reduce.count_events(planes)
             trace = trace_reduce.reduce_planes(planes)
+            reduce_s = time.monotonic() - t - read_s
             if args.keep_planes:
                 keep_planes(planes, args.keep_planes)
+            del planes
     view = RunView(spec, system, d, trace, peaks, model_math,
                    adapter.TRACE_MODULES)
+    if args.trace:
+        say(event="traced", steps=len(view.traced_steps()),
+            span_s=d.trace_span[1] - d.trace_span[0], events=events,
+            file_bytes=file_bytes, stop_trace_s=d.stop_trace_s,
+            read_s=read_s, reduce_s=reduce_s)
     layer = per_layer(view) if args.trace else {}
 
     checked = check_sample(spec, plan, d, args.seed)
-    correct, lines = correctness.judge(checked["program"],
-                                       spec.cell["correct"])
+    _, lines = correctness.judge(checked["program"],
+                                 spec.cell["correct"])
+    # every number `correct` rests on, beside its limit
+    lines += [{"compared": name, "value": value, "limit": limit,
+               "within": bool(within)} for name, value, limit, within in (
+        ("failed", failed, 0, failed == 0),
+        ("plan_wrapped", d.wrapped, 0, d.wrapped == 0),
+        ("tokens_checked_at_least", checked["tokens"], 1,
+         checked["tokens"] > 0))]
     for ln in lines:
         say(event="compared", **ln)
     say(event="checked", requests=checked["requests"],
         tokens=checked["tokens"], reference_s=checked["seconds"],
         plan_wrapped=d.wrapped)
-    correct = bool(correct and failed == 0 and d.wrapped == 0
-                   and checked["tokens"] > 0)
+    correct = all(ln["within"] for ln in lines)
 
     if args.trace:
         metrics = layer
@@ -654,10 +688,16 @@ def run_cell(args) -> int:
             modules={k: {"n": len(v), "sum_s": sum(v)}
                      for k, v in trace.modules.items()})
         shutil.rmtree(trace_dir, ignore_errors=True)
+    # what was compared comes last: on the line, and on standard error
+    result["compared"] = {ln["compared"]: {"value": ln["value"],
+                                           "limit": ln["limit"]}
+                          for ln in lines}
     if spec.rehearse or args.weights != "served":
         say(rehearsal=spec.rehearse, control=args.weights != "served",
             would_print=result)
         return 0
+    for ln in lines:
+        print(json.dumps(ln), file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -19,10 +19,10 @@ readers built on it then return None too.
 from __future__ import annotations
 
 import dataclasses
-import json
 from typing import Dict, List, Optional
 
 from cellbench import stats
+from cellbench.clock import say
 
 STEP = "serving.step"
 SYNC = "serving.sync"
@@ -32,10 +32,6 @@ PREFILL_BLOCK = "serving.prefill.block"
 #: The children of a `serving.step`, in the order they run.
 PHASES = ("serving.admit", PAGES, "serving.dispatch", SYNC,
           "serving.commit", "serving.gauges")
-
-
-def say(**kw) -> None:
-    print(json.dumps(kw), flush=True)
 
 
 @dataclasses.dataclass
@@ -141,19 +137,21 @@ def phase_report(steps: List[Step], longest: int = 3) -> dict:
 def device_ms_per_decode_step(run, metric: str, prefixes) -> Optional[float]:
     """Device milliseconds a traced decode step spends in operations
     whose reduced name starts with one of ``prefixes``: their rows of
-    `trace.top_ops` (the busiest chip) summed, over the decode
-    program's events on the same chip."""
+    `trace.per_op` (every operation of the busiest chip, not only the
+    ten the breakdown prints: a kernel stays read once it is fast)
+    summed, over the decode program's events on the same chip."""
     if run.trace is None:
         say(event="layer_metric_absent", metric=metric,
             why="no device trace (--trace 0, or a rehearsal)")
         return None
-    rows = [(n, s) for n, s in run.trace.top_ops
-            if n.startswith(tuple(prefixes))]
+    rows = sorted(((n, s) for n, s in run.trace.per_op.items()
+                   if n.startswith(tuple(prefixes))),
+                  key=lambda row: -row[1])
     n_steps = len(run.module("decode"))
     if not rows or not n_steps:
         say(event="layer_metric_absent", metric=metric,
             why=f"no operation named {'|'.join(prefixes)}* among the "
-                f"top device operations",
+                f"{len(run.trace.per_op)} device operations",
             top_ops=[n for n, _ in run.trace.top_ops])
         return None
     say(event="layer_metric_rows", metric=metric, decode_steps=n_steps,

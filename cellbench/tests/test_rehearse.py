@@ -37,17 +37,29 @@ def test_rehearsal_walks_the_run_and_prints_no_result(cell, trace):
     last = rows[-1]
     assert last["rehearsal"] is True and "correct" not in last
     would = last["would_print"]
-    assert set(would) == {"correct", "attempted", "failed", "metrics",
-                          "device"}
+    # the driver's keys in their order, what was compared last
+    assert list(would) == ["correct", "attempted", "failed", "metrics",
+                           "device", "compared"]
     assert would["correct"] is True and would["failed"] == 0
     assert would["attempted"] > 0
     compared = [r for r in rows if r.get("event") == "compared"]
-    assert {r["compared"] for r in compared} == {
-        "served_gap_max", "served_gap_mean"}
+    assert {r["compared"] for r in compared} == set(would["compared"]) == {
+        "served_gap_max", "served_gap_mean", "failed", "plan_wrapped",
+        "tokens_checked_at_least"}
+    assert all(set(v) == {"value", "limit"}
+               for v in would["compared"].values())
+    # the phase clock: every line says when, and the clock only rises
+    clock = [r["since_start_s"] for r in rows]
+    assert clock == sorted(clock) and clock[0] > 0
+    traced = [r for r in rows if r.get("event") == "traced"]
     if trace:
         assert "batch_occupancy" in would["metrics"]
+        (t,) = traced
+        assert 0 < t["steps"] <= 120 and t["span_s"] > 0
+        assert t["stop_trace_s"] > 0
+        assert t["events"] is None and t["read_s"] is None  # rehearsal
     else:
-        assert "setup_s" in would["metrics"]
+        assert "setup_s" in would["metrics"] and not traced
 
 
 def test_float8_weights_in_the_program_come_out_as_not_correct():

@@ -174,7 +174,7 @@ def reduced(top_ops, decode_events=10):
     return trace_reduce.Reduced(
         devices=1, busy_s_per_device=[1.0], busy_s=1.0,
         modules={"jit_body": [0.08] * decode_events, "jit_fn": [0.06]},
-        top_ops=top_ops, idle_gaps=[])
+        per_op=dict(top_ops), top_ops=top_ops[:10], idle_gaps=[])
 
 
 def test_decode_attention_ms_finds_the_kernel_by_name(capsys):
@@ -191,6 +191,46 @@ def test_decode_attention_ms_finds_the_kernel_by_name(capsys):
     whys = [r["why"] for r in said(capsys)
             if r["event"] == "layer_metric_absent"]
     assert len(whys) == 2 and "flash_decode_paged" in whys[0]
+
+
+def test_a_kernel_under_the_tenth_row_is_still_read(capsys):
+    """Planes built by hand: eleven operations take more device time
+    than `flash_decode_paged` and the fused GEMMs do.  The breakdown
+    prints ten rows; the readers sum over every row."""
+    ops, t = [], 0.0
+    for step in range(4):
+        for k in range(11):
+            ops.append((f"%big_{'abcdefghijk'[k]}.{step} = f32[8] "
+                        f"fusion(f32[8] %p)", t, 0.010))
+            t += 0.010
+        for layer in range(3):
+            ops.append((f'%flash_decode_paged.{layer} = bf16[8,32,128] '
+                        f'custom-call(bf16[8,32,128] %q), '
+                        f'custom_call_target="tpu_custom_call"',
+                        t, 0.002))
+            t += 0.002
+        ops.append(('%ag_gemm_ll.7 = bf16[8,4096] custom-call(bf16[8,1024]'
+                    ' %x), custom_call_target="tpu_custom_call"',
+                    t, 0.001))
+        t += 0.001
+    step_s = t / 4
+    planes = {"/device:TPU:0": {
+        "XLA Modules": [("jit_body(1)", i * step_s, step_s)
+                        for i in range(4)],
+        "XLA Ops": ops}}
+    trace = trace_reduce.reduce_planes(planes)
+    assert len(trace.top_ops) == 10 and len(trace.per_op) == 13
+    printed = [n for n, _ in trace.top_ops]
+    assert not any(n.startswith(("flash_decode_paged", "ag_gemm"))
+                   for n in printed)
+    by_time = sorted(trace.per_op, key=trace.per_op.get, reverse=True)
+    assert by_time[11].startswith("flash_decode_paged")       # twelfth
+    assert read("decode_attention_ms", view(None, trace)) == \
+        pytest.approx(6.0)
+    assert read("comm_gemm_ms", view(None, trace)) == pytest.approx(1.0)
+    rows = [r for r in said(capsys) if r["event"] == "layer_metric_rows"]
+    assert [r["metric"] for r in rows] == ["decode_attention_ms",
+                                           "comm_gemm_ms"]
 
 
 def test_comm_gemm_ms_sums_fused_kernels_and_xla_collectives():

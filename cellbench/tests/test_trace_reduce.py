@@ -49,6 +49,19 @@ def test_top_operations_share_a_name_across_instances():
     assert top["while while"] == pytest.approx(1.2)
 
 
+def test_the_whole_table_is_kept_and_ten_rows_are_printed():
+    p = planes()
+    p["/device:TPU:0"]["XLA Ops"] += [
+        (f"%op_{'abcdefghijkl'[k]}.1 = f32[8] add(f32[8] %x)",
+         5.0 + k, 0.001 * (k + 1)) for k in range(12)]
+    r = tr.reduce_planes(p)
+    assert len(r.per_op) == 15 and len(r.top_ops) == 10
+    assert r.per_op["op_a add"] == pytest.approx(0.001)       # the least
+    assert "op_a add" not in dict(r.top_ops)
+    assert dict(r.top_ops) == {n: r.per_op[n] for n, _ in r.top_ops}
+    assert tr.count_events(p) == 3 + 5 + 12 + 2
+
+
 def test_idle_gaps_go_to_the_host_span_that_holds_them():
     r = tr.reduce_planes(planes())
     gaps = dict(r.idle_gaps)

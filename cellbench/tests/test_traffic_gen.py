@@ -1,7 +1,9 @@
+import hashlib
 import json
 import os
 
 import numpy as np
+import pytest
 
 from cellbench import traffic_gen as tg
 
@@ -67,6 +69,53 @@ def test_closed_loop_sends_the_next_when_the_last_completes():
     nxt = plan.due(105.6)
     assert len(nxt) == 1 and nxt[0].client == 1 and nxt[0].due == 105.5
     assert nxt[0].index != first[1].index
+
+
+def walk(plan, n):
+    """The first ``n`` requests of a closed plan whose callers finish
+    in the order they were served."""
+    plan.start(0.0)
+    out, ready = [], plan.due(0.0)
+    while len(out) < n:
+        item = ready.pop(0)
+        out.append(item)
+        plan.on_finish(item, float(len(out)))
+        ready += plan.due(float(len(out)))
+    return out
+
+
+def batch_cell_plan(seed):
+    return tg.make_plan(mix("batch"), {"clients": 12, "rounds": 12},
+                        seed, 151936, 50.0)
+
+
+@pytest.mark.parametrize("seed,digest", [
+    (7, "8286e92339e80adc8c8bce49465e80c61e7633cc62ccb7093dc0d63212fb559c"),
+    (2 ** 31 + 26,
+     "120128532de46eb9cdd24825151f212a0c449b4ca3533a32bf4d5278e433506b"),
+])
+def test_the_first_block_is_what_it_was_before_the_plan_could_extend(
+        seed, digest):
+    """Recorded from PR 24's generator (which wrapped after clients x
+    rounds = 144 requests): every reading taken under it still stands
+    for the same requests."""
+    items = walk(batch_cell_plan(seed), 144)
+    text = json.dumps([[i.index, i.client, i.max_new, i.prompt]
+                       for i in items])
+    assert hashlib.sha256(text.encode()).hexdigest() == digest
+
+
+def test_a_closed_plan_extends_and_never_hands_a_request_out_twice():
+    plan = batch_cell_plan(11)
+    items = walk(plan, 400)
+    assert plan.wrapped == 0
+    assert len({i.index for i in items}) == 400
+    assert len({tuple(i.prompt) for i in items}) == 400
+    # a further block is stratified like the first: the same lengths
+    assert sorted(len(i.prompt) for i in items[144:288]) == sorted(
+        len(i.prompt) for i in items[:144])
+    assert all(i.client == items[k % 12].client
+               for k, i in enumerate(items))
 
 
 def test_a_plan_says_what_it_can_draw():

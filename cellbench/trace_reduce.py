@@ -34,7 +34,11 @@ class Reduced:
     #: program name (fingerprint stripped) -> durations in seconds, in
     #: time order, on the busiest device
     modules: Dict[str, List[float]]
-    #: [name, seconds] of the operations that took most device time
+    #: operation name (`op_name`) -> seconds on the busiest device,
+    #: EVERY operation: what the kernel metrics read
+    per_op: Dict[str, float]
+    #: [name, seconds] of the ten that took most time: what the
+    #: breakdown prints
     top_ops: List[Tuple[str, float]]
     #: [host span name, seconds] idle time of the busiest device by
     #: what the host was doing
@@ -143,8 +147,14 @@ def reduce_planes(planes: dict, top: int = 10) -> Reduced:
     vals = list(busy.values())
     return Reduced(devices=len(dev), busy_s_per_device=vals,
                    busy_s=sum(vals) / len(vals), modules=modules,
-                   top_ops=[(n, s) for n, s in top_ops],
+                   per_op=per_op, top_ops=[(n, s) for n, s in top_ops],
                    idle_gaps=[(n, s) for n, s in idle])
+
+
+def count_events(planes: dict) -> int:
+    """Events `read` kept: what a trace's cost goes with."""
+    return sum(len(evs) for lines in planes.values()
+               for evs in lines.values())
 
 
 def read(path: str) -> dict:

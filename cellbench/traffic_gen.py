@@ -174,40 +174,54 @@ class ClosedLoop(Lengths):
     completes.  The first request of each client is cut to a fraction
     of its output length (the fractions evenly spaced over the
     clients), so that the clients start out of step as they would be
-    in a job that has been running."""
+    in a job that has been running.
+
+    The plan cannot run out: ``load["rounds"]`` is the size of a block
+    (clients x rounds requests, their lengths stratified over the
+    block), and when a client has used up its list a further block is
+    drawn from the same generator — however many requests a fast
+    program completes, each is a fresh one.  ``wrapped`` counts
+    requests handed out a second time, which fails a run's `correct`:
+    this plan leaves it at 0."""
 
     def __init__(self, traffic: dict, load: dict, seed: int,
                  vocab: int, horizon_s: float):
         super().__init__(traffic)
-        rng = np.random.default_rng(int(seed))
+        self._traffic, self._vocab = traffic, vocab
+        self._rng = np.random.default_rng(int(seed))
         self.clients = int(load["clients"])
-        rounds = int(load["rounds"])
-        n = self.clients * rounds
-        prompts, outputs = _sizes(traffic, n, rng)
-        first_cut = rng.permutation(
-            (np.arange(self.clients) + 0.5) / self.clients)
-        self._lists = []
-        for c in range(self.clients):
-            items = []
-            for r in range(rounds):
-                i = r * self.clients + c
-                out = int(outputs[i])
-                if r == 0:
-                    out = max(int(out * first_cut[c]), 1)
-                items.append(Item(
-                    i, rng.integers(0, vocab, int(prompts[i])).tolist(),
-                    out, client=c))
-            self._lists.append(items)
+        self._rounds = int(load["rounds"])
+        self._lists: List[List[Item]] = [[] for _ in range(self.clients)]
+        self._extend()
         self._pos = [0] * self.clients
         self._ready: List[Item] = []
         self.wrapped = 0
 
+    def _extend(self) -> None:
+        """One more block on every client's list."""
+        rng, clients = self._rng, self.clients
+        n = clients * self._rounds
+        base = sum(len(items) for items in self._lists)
+        prompts, outputs = _sizes(self._traffic, n, rng)
+        first_cut = None
+        if base == 0:
+            first_cut = rng.permutation(
+                (np.arange(clients) + 0.5) / clients)
+        for c in range(clients):
+            for r in range(self._rounds):
+                i = r * clients + c
+                out = int(outputs[i])
+                if first_cut is not None and r == 0:
+                    out = max(int(out * first_cut[c]), 1)
+                self._lists[c].append(Item(
+                    base + i,
+                    rng.integers(0, self._vocab, int(prompts[i])).tolist(),
+                    out, client=c))
+
     def _take(self, c: int, due: float) -> None:
-        items = self._lists[c]
-        if self._pos[c] >= len(items):     # the run outlasted the plan
-            self._pos[c] = 1
-            self.wrapped += 1
-        src = items[self._pos[c]]
+        if self._pos[c] >= len(self._lists[c]):
+            self._extend()
+        src = self._lists[c][self._pos[c]]
         self._pos[c] += 1
         self._ready.append(dataclasses.replace(src, due=due))
 
