@@ -240,7 +240,7 @@ def _paged_pools(k, v, page_size, num_extra_pages=3, seed=99,
             jnp.asarray(table), s_pools)
 
 
-@pytest.mark.parametrize("gqa", [1, 4])
+@pytest.mark.parametrize("gqa", [1, 4, 8])
 def test_flash_decode_paged_matches_dense(gqa):
     """The page-table indirection is the ONLY difference: on the same
     logical KV (physically permuted into pages) the paged kernel must
@@ -348,3 +348,121 @@ def test_sp_flash_decode_paged(sp4_mesh):
                       jnp.array([total], jnp.int32))
     assert_allclose(out, ref, atol=3e-3, rtol=3e-3,
                     name="sp_decode_paged")
+
+
+# -- ragged batches: the kernel's work follows each row's live length --
+
+def _block_rows(ps, t, hkv, d, dtype=jnp.float32):
+    from triton_distributed_tpu.kernels.flash_decode import (
+        _pages_per_block)
+
+    return ps * _pages_per_block(t, hkv, ps, d, dtype)
+
+
+#: (page size, table width): both widths are NOT a multiple of the
+#: kernel's block (32 pages of 16 rows, 4 pages of 128).
+_RAGGED_GEOMETRY = {16: 40, 128: 6}
+
+_RAGGED_LENS = {
+    "one": lambda ps, blk, s: (1, 1),
+    "page-1": lambda ps, blk, s: (ps - 1, 1),
+    "page": lambda ps, blk, s: (ps, ps),
+    "page+1": lambda ps, blk, s: (ps + 1, ps),
+    "block": lambda ps, blk, s: (blk, blk - 1),
+    "block+1": lambda ps, blk, s: (blk + 1, blk),
+    "full": lambda ps, blk, s: (s, s - 1),
+    "one-and-full": lambda ps, blk, s: (1, s),
+}
+
+
+@pytest.mark.parametrize("case", list(_RAGGED_LENS))
+@pytest.mark.parametrize("ps", list(_RAGGED_GEOMETRY))
+def test_flash_decode_paged_ragged(ps, case):
+    """Lengths at every edge of a page and of a block, alone and mixed
+    with the maximum, against the dense kernel and the plain
+    reference."""
+    from triton_distributed_tpu.kernels.flash_decode import (
+        flash_decode_paged)
+
+    b, h, hkv, d, t = 2, 4, 2, 32, _RAGGED_GEOMETRY[ps]
+    s = t * ps
+    blk = _block_rows(ps, t, hkv, d)
+    assert ps < blk < s and s % blk != 0
+    q = jax.random.normal(jax.random.key(20), (b, h, d))
+    k = jax.random.normal(jax.random.key(21), (b, hkv, s, d))
+    v = jax.random.normal(jax.random.key(22), (b, hkv, s, d))
+    kv_len = jnp.array(_RAGGED_LENS[case](ps, blk, s), jnp.int32)
+    k_pool, v_pool, table, _ = _paged_pools(k, v, ps)
+    out, lse = flash_decode_paged(q, k_pool, v_pool, table, kv_len)
+    ref, ref_lse = flash_decode(q, k, v, kv_len, block_k=ps)
+    assert_allclose(out, ref, atol=2e-6, rtol=2e-6,
+                    name=f"ragged-{ps}-{case}")
+    assert_allclose(lse, ref_lse, atol=2e-6, rtol=2e-6,
+                    name=f"ragged-lse-{ps}-{case}")
+    assert_allclose(out, _decode_ref(q, k, v, kv_len), atol=2e-3,
+                    rtol=2e-3, name=f"ragged-ref-{ps}-{case}")
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+def test_flash_decode_paged_reads_nothing_past_length(quantized):
+    """Poison: every row of the pool that is not below the length of
+    the sequence owning it is NaN (int8 pools: 127 under a NaN scale)
+    — the unowned pages, the pages a table maps beyond `kv_len`, the
+    tail of a row's last page.  The output must not notice."""
+    from triton_distributed_tpu.kernels.flash_decode import (
+        flash_decode_paged, quantize_kv)
+
+    b, h, hkv, d, ps, t = 3, 4, 2, 32, 16, 40
+    s = t * ps
+    blk = _block_rows(ps, t, hkv, d,
+                      jnp.int8 if quantized else jnp.float32)
+    lens = np.array([1, blk + ps + 3, s - 5], np.int32)
+    q = jax.random.normal(jax.random.key(23), (b, h, d))
+    k = jax.random.normal(jax.random.key(24), (b, hkv, s, d))
+    v = jax.random.normal(jax.random.key(25), (b, hkv, s, d))
+    scales = None
+    if quantized:
+        k, v, ks, vs = quantize_kv(k, v)
+        scales = (ks, vs)
+    k_pool, v_pool, table, s_pools = _paged_pools(k, v, ps,
+                                                  scales=scales)
+
+    live = np.zeros((k_pool.shape[0], ps), bool)    # (page, row)
+    for bb in range(b):
+        for j in range(t):
+            live[table[bb, j]] = j * ps + np.arange(ps) < lens[bb]
+    dead = jnp.asarray(~live)
+    kw = {}
+    if quantized:
+        poison = lambda pool: jnp.where(dead[:, None, :, None], 127, pool)
+        kw = {name: jnp.where(dead[:, None, :], jnp.nan, sc)
+              for name, sc in zip(("k_scale", "v_scale"), s_pools)}
+    else:
+        poison = lambda pool: jnp.where(dead[:, None, :, None], jnp.nan,
+                                        pool)
+    out, lse = flash_decode_paged(q, poison(k_pool), poison(v_pool),
+                                  table, jnp.asarray(lens), **kw)
+    assert jnp.isfinite(out).all() and jnp.isfinite(lse).all()
+    kw = dict(k_scale=scales[0], v_scale=scales[1]) if quantized else {}
+    ref, ref_lse = flash_decode(q, k, v, jnp.asarray(lens), block_k=ps,
+                                **kw)
+    assert_allclose(out, ref, atol=2e-6, rtol=2e-6, name="poison")
+    assert_allclose(lse, ref_lse, atol=2e-6, rtol=2e-6,
+                    name="poison-lse")
+
+
+def test_flash_decode_paged_empty_row():
+    """A row with `kv_len` 0 (an empty shard of `sp_flash_decode_paged`)
+    reads nothing: zeros, and an lse `combine_partials` weighs at 0."""
+    from triton_distributed_tpu.kernels.flash_decode import (
+        NEG_INF, flash_decode_paged)
+
+    b, h, d, ps, t = 2, 4, 32, 16, 4
+    q = jax.random.normal(jax.random.key(26), (b, h, d))
+    k = jax.random.normal(jax.random.key(27), (b, h, t * ps, d))
+    k_pool, v_pool, table, _ = _paged_pools(k, k, ps)
+    kv_len = jnp.array([0, 20], jnp.int32)
+    out, lse = flash_decode_paged(q, k_pool, v_pool, table, kv_len)
+    assert (out[0] == 0).all() and (lse[0] < NEG_INF / 2).all()
+    assert_allclose(out[1:], _decode_ref(q, k, k, kv_len)[1:],
+                    atol=2e-3, rtol=2e-3, name="empty-row-neighbour")

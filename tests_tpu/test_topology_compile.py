@@ -423,3 +423,30 @@ def test_topo_sp_flash_decode():
              [(b, h, d), (b, h, WORLD * s_loc, d),
               (b, h, WORLD * s_loc, d), (WORLD, b)],
              [jnp.bfloat16, jnp.bfloat16, jnp.bfloat16, jnp.int32])
+
+
+@pytest.mark.parametrize("hkv,kv_dtype", [(8, jnp.bfloat16),
+                                          (2, jnp.bfloat16),
+                                          (8, jnp.int8)])
+def test_topo_flash_decode_paged(hkv, kv_dtype):
+    """The serving cells' decode attention (8 slots, page 16, max_seq
+    4096, D 128; 8 KV heads on one chip, 2 a chip at tp=4): the manual
+    page gather — dynamic trip counts, per-page DMAs — through Mosaic."""
+    from triton_distributed_tpu.kernels.flash_decode import (
+        flash_decode_paged)
+
+    b, d, ps, t, pages = 8, 128, 16, 256, 1385
+    quantized = kv_dtype == jnp.int8
+
+    def fn(q, kp, vp, tab, ln, *sc):
+        kw = dict(k_scale=sc[0], v_scale=sc[1]) if sc else {}
+        return flash_decode_paged(q, kp, vp, tab, ln, **kw)[0]
+
+    pool, scales = (pages, hkv, ps, d), (pages, hkv, ps)
+    shapes = [(b, 4 * hkv, d), pool, pool, (b, t), (b,)]
+    dtypes = [jnp.bfloat16, kv_dtype, kv_dtype, jnp.int32, jnp.int32]
+    if quantized:
+        shapes += [scales, scales]
+        dtypes += [jnp.float32, jnp.float32]
+    _compile(fn, _mesh((8,), ("tp",)), (P(),) * len(shapes), P(),
+             shapes, dtypes)

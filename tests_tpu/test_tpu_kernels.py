@@ -441,16 +441,20 @@ def _to_pool(x, table, ps):
     return pool.at[np.asarray(table).reshape(-1)].set(pages)
 
 
-@pytest.mark.parametrize("quantized,ps", [(False, 16), (True, 32)])
-def test_flash_decode_paged_scattered_pool(quantized, ps):
+@pytest.mark.parametrize("quantized,ps,hkv",
+                         [(False, 16, 8), (False, 16, 2), (True, 32, 8),
+                          (True, 16, 8)])
+def test_flash_decode_paged_scattered_pool(quantized, ps, hkv):
     """`flash_decode_paged` against the dense kernel on the same logical
-    KV, physically scattered over a pool — bf16 pages of 16 rows, int8
-    pages of 32 (the int8 sublane tile), page table sized for
-    max_seq 4096 (T = 4096 / page)."""
+    KV, physically scattered over a pool — bf16 pages of 16 rows at 8
+    KV heads (the one-chip cells) and 2 (a chip of the tp=4 cell), int8
+    pages of 32 (the int8 sublane tile) and of 16 (the scheduler's
+    page size), page table sized for max_seq 4096 (T = 4096 / page)."""
     from triton_distributed_tpu.kernels.flash_decode import (
         flash_decode, flash_decode_paged, quantize_kv)
 
-    b, h, hkv, d, max_seq = 8, 32, 8, 128, 4096
+    b, d, max_seq = 8, 128, 4096
+    h = 4 * hkv
     t = max_seq // ps
     lens = np.array([1, 17, 100, 511, 512, 1500, 4095, 4096], np.int32)
     q = (jax.random.normal(jax.random.key(0), (b, h, d)) / 4

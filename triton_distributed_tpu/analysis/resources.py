@@ -15,9 +15,12 @@ tables — and proves three resource properties with no TPU:
   the int8 scale-row rule from `quantized.py`).  → `tiling_illegal`.
 - **Block-index bounds** — every BlockSpec index map is evaluated at
   every grid point with the *concrete* scalar-prefetch operands the
-  call received, so indirection through index/page tables
-  (`flash_attention`'s packed schedule, `flash_decode_paged`'s
-  ``(ptab[b, j], h, 0, 0)``) is checked against the real table values.
+  call received, so indirection through index tables
+  (`flash_attention`'s packed schedule) is checked against the real
+  table values; blocks a kernel copies by hand out of an HBM operand
+  are stated as `ManualBlocks` and bounded the same way
+  (`flash_decode_paged`'s page gather, ``(ptab[b, j], 0, 0, 0)`` for
+  every page below the row's length).
   The reserved NULL/trash page (`models.kv_cache.NULL_PAGE` = 0) is in
   bounds by construction — physical page 0 exists precisely so NULL
   entries land somewhere harmless — so a clean paged table analyzes
@@ -65,6 +68,7 @@ __all__ = [
     "CapturedCall",
     "LANE",
     "MOSAIC_DEFAULT_VMEM_LIMIT",
+    "ManualBlocks",
     "PREFETCH_SMEM_LIMIT",
     "all_resource_kernels",
     "block_bytes",
@@ -170,6 +174,24 @@ class _SpecView:
     array_shape: Tuple[int, ...]
     dtype: np.dtype
     name: str
+    #: ``index_map`` is a `ManualBlocks.indices`: a LIST of block
+    #: indices a grid point (the operand itself is not in VMEM).
+    manual: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class ManualBlocks:
+    """The blocks a kernel copies BY HAND (`make_async_copy`) out of
+    an operand it leaves whole in HBM (`memory_space=pl.ANY`), stated
+    for the bounds check the way a BlockSpec states them:
+    ``indices(*grid_point, *prefetch)`` lists the block indices the
+    kernel reads at that grid point, in units of ``block_shape``.
+    `pallas_call` has no slot for it, so the host sets
+    ``kernel.manual_blocks = {input position: ManualBlocks}`` on the
+    kernel callable it passes (`flash_decode_paged`'s page gather)."""
+
+    block_shape: Tuple[int, ...]
+    indices: Callable
 
 
 @dataclasses.dataclass
@@ -196,18 +218,27 @@ def _dtype_of(x) -> np.dtype:
         return np.dtype(getattr(x, "dtype", np.float32))
 
 
-def _spec_views(specs, operands, kind: str) -> List[_SpecView]:
+def _spec_views(specs, operands, kind: str,
+                manual=None) -> List[_SpecView]:
     views = []
     for i, (spec, op) in enumerate(zip(specs, operands)):
+        by_hand = (manual or {}).get(i)
+        if by_hand is not None:
+            block_shape, index_map = (tuple(by_hand.block_shape),
+                                      by_hand.indices)
+        else:
+            block_shape = (tuple(spec.block_shape)
+                           if getattr(spec, "block_shape", None)
+                           is not None else None)
+            index_map = getattr(spec, "index_map", None)
         views.append(_SpecView(
-            block_shape=(tuple(spec.block_shape)
-                         if getattr(spec, "block_shape", None) is not None
-                         else None),
-            index_map=getattr(spec, "index_map", None),
+            block_shape=block_shape,
+            index_map=index_map,
             memory_space=_space_of(spec),
             array_shape=tuple(np.shape(op)),
             dtype=_dtype_of(getattr(op, "dtype", np.float32)),
-            name=f"{kind}{i}"))
+            name=f"{kind}{i}",
+            manual=by_hand is not None))
     return views
 
 
@@ -258,7 +289,8 @@ def capture_pallas_calls():
         def runner(*operands):
             outs = [o for o in jax.tree_util.tree_leaves(out_shape)]
             out_ops = [np.zeros(tuple(o.shape), o.dtype) for o in outs]
-            views = (_spec_views(gs_in, operands[n_pre:], "in")
+            views = (_spec_views(gs_in, operands[n_pre:], "in",
+                                 getattr(kernel, "manual_blocks", None))
                      + _spec_views(gs_out, out_ops, "out"))
             scratch = []
             for s in gs_scratch:
@@ -393,32 +425,34 @@ def check_captured_call(call: CapturedCall,
                         f"grid point {gp}: {type(e).__name__}: {e}",
                         ref=view.name, kernel=kernel))
                 continue
-            idx = tuple(int(i) for i in (
-                idx if isinstance(idx, (tuple, list)) else (idx,)))
-            if not varies[si]:
-                first = getattr(view, "_first_idx", None)
-                if first is None:
-                    view._first_idx = idx
-                elif idx != first:
-                    varies[si] = True
-            for d, (i, bs) in enumerate(zip(idx, view.block_shape)):
-                hi = _cdiv(int(view.array_shape[d]), int(bs)) - 1
-                if 0 <= i <= hi:
-                    continue
-                key = (si, d)
-                if key in oob_seen:
-                    continue
-                oob_seen.add(key)
-                via = (" (index fed by a scalar-prefetch table — a "
-                       "stale/corrupt page-table entry reads foreign "
-                       "memory)" if call.prefetch else "")
-                findings.append(Finding(
-                    FindingKind.OOB_BLOCK_INDEX,
-                    f"{call.name}.{view.name}: block index {i} along "
-                    f"dim {d} at grid point {gp} is outside "
-                    f"[0, {hi}] for operand shape {view.array_shape} "
-                    f"with block {view.block_shape}{via}",
-                    ref=view.name, kernel=kernel))
+            for idx in (idx if view.manual else [idx]):
+                idx = tuple(int(i) for i in (
+                    idx if isinstance(idx, (tuple, list)) else (idx,)))
+                if not varies[si]:
+                    first = getattr(view, "_first_idx", None)
+                    if first is None:
+                        view._first_idx = idx
+                    elif idx != first:
+                        varies[si] = True
+                for d, (i, bs) in enumerate(zip(idx, view.block_shape)):
+                    hi = _cdiv(int(view.array_shape[d]), int(bs)) - 1
+                    if 0 <= i <= hi:
+                        continue
+                    key = (si, d)
+                    if key in oob_seen:
+                        continue
+                    oob_seen.add(key)
+                    via = (" (index fed by a scalar-prefetch table — a "
+                           "stale/corrupt page-table entry reads foreign "
+                           "memory)" if call.prefetch else "")
+                    findings.append(Finding(
+                        FindingKind.OOB_BLOCK_INDEX,
+                        f"{call.name}.{view.name}: block index {i} "
+                        f"along dim {d} at grid point {gp} is outside "
+                        f"[0, {hi}] for operand shape "
+                        f"{view.array_shape} with block "
+                        f"{view.block_shape}{via}",
+                        ref=view.name, kernel=kernel))
 
     # -- VMEM footprint -------------------------------------------------
     total = 0

@@ -11,6 +11,10 @@ TPU re-design:
 - single chip: one Pallas kernel, grid over KV splits, online-softmax
   partials (acc, m, l) carried in VMEM, masked by the true cache
   length (static shapes; `kv_len` rides in SMEM).
+- paged (`flash_decode_paged`): the same mathematics over a page
+  pool; grid over batch rows, an in-kernel loop over blocks of pages
+  gathered by async copies through the page table, bounded by each
+  row's live length.
 - distributed (SP): every rank runs the local kernel over its KV shard
   emitting (out, lse); the tiny partials are exchanged with the
   one-shot push allgather (the reference's LL-allgather of (out, lse))
@@ -29,6 +33,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from triton_distributed_tpu import collective_ids as cids
 
+from triton_distributed_tpu.analysis.resources import (
+    MOSAIC_DEFAULT_VMEM_LIMIT,
+    ManualBlocks,
+    block_bytes,
+)
 from triton_distributed_tpu.kernels.flash_attention import zero_oob_rows
 from triton_distributed_tpu.utils.platform import default_interpret
 
@@ -233,15 +242,146 @@ def flash_decode(q, k_cache, v_cache, kv_len, *,
 
 
 
-def _paged_decode_kernel(nk, s_cache, scale, bk, quantized,
-                         compute_dtype, kvlen_ref, ptab_ref, *rest):
-    """Paged wrapper: the page table rides as a SECOND scalar-prefetch
-    operand consumed only by the BlockSpec index maps (the KV block
-    index becomes an indirection through it); the compute body is the
-    dense split-KV kernel unchanged — every page is a full block, so
-    the ragged-tail guards are statically off (s_cache % bk == 0)."""
-    _decode_kernel(nk, s_cache, scale, bk, quantized, compute_dtype,
-                   kvlen_ref, *rest)
+#: Rows of KV one online-softmax update of the paged kernel covers at
+#: most (pages per block x page size).
+_PAGED_BLOCK_ROWS = 512
+
+#: VMEM the paged kernel's gather buffers (K and V, two slots each)
+#: may take together: a quarter of Mosaic's default scoped limit.
+_PAGED_KV_VMEM_BYTES = MOSAIC_DEFAULT_VMEM_LIMIT // 4
+
+
+def _pages_per_block(t: int, hkv: int, ps: int, d: int, dtype) -> int:
+    """Pages one block of the paged kernel gathers: as many as give
+    `_PAGED_BLOCK_ROWS` rows, fewer where the four (Hkv, rows, D)
+    buffers would pass `_PAGED_KV_VMEM_BYTES`, at least one, at most
+    the table's width."""
+    rows = _PAGED_KV_VMEM_BYTES // (4 * block_bytes((hkv, d), dtype))
+    return max(1, min(t, min(rows, _PAGED_BLOCK_ROWS) // ps))
+
+
+def _paged_decode_kernel(n, ps, scale, quantized, compute_dtype,
+                         kvlen_ref, ptab_ref, q_ref, k_hbm, v_hbm,
+                         *rest):
+    """Grid: (B,).  One grid step is one row: all its KV heads, and
+    only the pages below its length.
+
+    The pools stay in HBM.  A block is ``n`` consecutive logical pages:
+    page ``ptab[b, j]`` — all KV heads of it, contiguous in the pool —
+    is copied into slot ``blk % 2`` of the (2, n, Hkv, page, D) VMEM
+    buffers while the other slot is computed on.  The loop runs
+    ``cdiv(kv_len[b], n * page)`` times; within a block only pages
+    below ``cdiv(kv_len[b], page)`` are copied, and only the last
+    block is masked (its tail holds whatever the slot held before).
+    The online-softmax update is `_decode_kernel`'s over ``n * page``
+    rows: f32 running max / sum / accumulator per KV head, p in the
+    value dtype for the PV product.
+
+    With ``quantized`` the per-token scales arrive as dense
+    (1, Hkv, 1, T * page) rows (gathered by the wrapper) and are
+    folded onto the (G, rows) tiles as in the dense kernel."""
+    if quantized:
+        ks_ref, vs_ref, o_ref, lse_ref = rest[:4]
+    else:
+        o_ref, lse_ref = rest[:2]
+    kbuf, vbuf, sem, m_scr, l_scr, acc_scr = rest[-6:]
+    bb = pl.program_id(0)
+    hkv, d = kbuf.shape[2], kbuf.shape[4]
+    rows = n * ps
+    kv_len = kvlen_ref[bb]
+    npages = pl.cdiv(kv_len, ps)
+    nblk = pl.cdiv(npages, n)
+
+    def gather(blk, slot, wait):
+        """Start (or wait for) the copies of block ``blk``'s live
+        pages into ``slot``."""
+        def page(i, _):
+            # A wait needs the copy's shape and semaphore only.
+            src = 0 if wait else ptab_ref[bb, blk * n + i]
+            for a, (hbm, buf) in enumerate(((k_hbm, kbuf),
+                                            (v_hbm, vbuf))):
+                copy = pltpu.make_async_copy(
+                    hbm.at[src], buf.at[slot, i], sem.at[a, slot])
+                if wait:
+                    copy.wait()
+                else:
+                    copy.start()
+        jax.lax.fori_loop(0, jnp.minimum(n, npages - blk * n), page,
+                          None)
+
+    def update(blk, slot, masked):
+        if quantized:
+            cols = pl.ds(pl.multiple_of(blk * rows, rows), rows)
+        if masked:
+            col_live = blk * rows + jax.lax.broadcasted_iota(
+                jnp.int32, (1, rows), 1) < kv_len
+        # Unrolled over the KV heads: their chains are independent, and
+        # a loop would leave each matmul's latency exposed.
+        for h in range(hkv):
+            q = q_ref[0, h]                             # (G, D)
+            k = kbuf[slot, :, h].reshape(rows, d)
+            v = vbuf[slot, :, h].reshape(rows, d)
+            if quantized:
+                k = k.astype(compute_dtype)
+                v = v.astype(compute_dtype)
+            s = jax.lax.dot_general(
+                q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            if quantized:
+                s = s * ks_ref[0, h, :, cols]           # (1, rows)
+                vs = vs_ref[0, h, :, cols]
+            if masked:
+                s = jnp.where(col_live, s, NEG_INF)
+                # 0 x NaN: rows no copy wrote must not reach the sums.
+                v = zero_oob_rows(v, blk, rows, kv_len)
+                if quantized:
+                    vs = jnp.where(col_live, vs, 0)
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=1,
+                                                  keepdims=True)
+            if quantized:
+                p = p * vs
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = m_new
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(nblk > 0)
+    def _():
+        gather(0, 0, wait=False)
+
+    def block(blk, _):
+        slot = blk % 2
+
+        @pl.when(blk + 1 < nblk)
+        def _():
+            gather(blk + 1, 1 - slot, wait=False)
+
+        gather(blk, slot, wait=True)
+
+        @pl.when(blk + 1 < nblk)
+        def _():
+            update(blk, slot, masked=False)
+
+        @pl.when(blk + 1 == nblk)
+        def _():
+            update(blk, slot, masked=True)
+
+    jax.lax.fori_loop(0, nblk, block, None)
+
+    l = jnp.maximum(l_scr[...], 1e-30)
+    o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+    # log-sum-exp for cross-rank combine, (Hkv, G, 1)
+    lse_ref[0] = m_scr[...] + jnp.log(l)
 
 
 def flash_decode_paged(q, k_pool, v_pool, page_table, kv_len, *,
@@ -257,22 +397,25 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, kv_len, *,
     (B,) int32 true filled lengths.  Returns (out (B, H, D),
     lse (B, H)).
 
-    This is the dense split-KV kernel (`flash_decode`) with ONE
-    change: the KV BlockSpec's block index is an indirection through
-    the scalar-prefetched page table — ``(page_table[b, j], h, 0, 0)``
-    instead of ``(b, h, j, 0)`` — the same index-table idiom as
-    `flash_attention`'s packed causal schedule.  The split size IS the
-    page size, so the online-softmax body is reused unchanged.
-    Logical pages at or beyond a row's length should map to
-    `NULL_PAGE` (0): their scores are masked by ``kv_len`` (exact
-    zeros), and the repeated null-page fetch is cheap.
+    The work follows each row's LIVE length, not the table's width:
+    the grid is (B,), the pools stay in HBM, and each grid step loops
+    over blocks of `_pages_per_block` pages — gathered through the
+    scalar-prefetched table with one async copy a page (all KV heads
+    of a page are contiguous), double-buffered, one online-softmax
+    update of `flash_decode`'s mathematics a block — for
+    ``cdiv(kv_len[b], pages * page)`` blocks.  No page at or beyond a
+    row's length is read, so the table may map those anywhere
+    (`NULL_PAGE`, a stale page); a row with ``kv_len`` 0 returns zeros
+    and lse ~ -1e30.  The program is the same for every batch: the
+    lengths are read in the kernel, not traced.
 
     With ``k_scale``/``v_scale`` ((P, Hkv, page) f32 pools) the KV
     pools are int8 — half the streaming bytes, dequantized in-kernel
-    exactly as the dense path.
+    exactly as the dense path.  The scales (4 bytes a token) are
+    gathered for the whole table by XLA ahead of the kernel.
     """
     b, h, d = q.shape
-    p, hkv, ps, _ = k_pool.shape
+    _, hkv, ps, _ = k_pool.shape
     t = page_table.shape[1]
     assert h % hkv == 0
     g = h // hkv
@@ -281,36 +424,47 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, kv_len, *,
     if quantized:
         assert k_pool.dtype == jnp.int8 and v_pool.dtype == jnp.int8
     scale = scale if scale is not None else d ** -0.5
-    nk = t
+    n = _pages_per_block(t, hkv, ps, d, k_pool.dtype)
+    page_table = page_table.astype(jnp.int32)
 
-    def kv_spec():
-        return pl.BlockSpec(
-            (1, 1, ps, d),
-            lambda bb, hh, ki, kvlen, ptab: (ptab[bb, ki], hh, 0, 0),
-            memory_space=pltpu.VMEM)
+    def row_spec(*tail):
+        return pl.BlockSpec((1, hkv) + tail,
+                            lambda bb, *pre: (bb, 0, 0, 0),
+                            memory_space=pltpu.VMEM)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, g, d),
-                     lambda bb, hh, ki, *pre: (bb, hh, 0, 0),
-                     memory_space=pltpu.VMEM),
-        kv_spec(),
-        kv_spec(),
-    ]
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [row_spec(g, d), pool_spec, pool_spec]
     operands = [q.reshape(b, hkv, g, d), k_pool, v_pool]
     if quantized:
-        # (P, Hkv, 1, page) layout: same Mosaic-legal trailing
-        # (1, page) block as the dense path, indexed through the table.
-        sspec = pl.BlockSpec(
-            (1, 1, 1, ps),
-            lambda bb, hh, ki, kvlen, ptab: (ptab[bb, ki], hh, 0, 0),
-            memory_space=pltpu.VMEM)
-        in_specs += [sspec, sspec]
-        operands += [k_scale.astype(jnp.float32).reshape(p, hkv, 1, ps),
-                     v_scale.astype(jnp.float32).reshape(p, hkv, 1, ps)]
+        # (B, Hkv, 1, T*page): the trailing (1, rows) slices are the
+        # broadcast shape the kernel multiplies onto the (G, rows)
+        # tiles.  Columns past a row's length are masked in-kernel.
+        # Padded to whole blocks, so the last block's slice is in bounds.
+        t_pad = pl.cdiv(t, n) * n
+
+        def dense(sc):
+            sc = sc.astype(jnp.float32)[page_table]     # (B,T,Hkv,ps)
+            sc = jnp.pad(sc, ((0, 0), (0, t_pad - t), (0, 0), (0, 0)))
+            return sc.transpose(0, 2, 1, 3).reshape(b, hkv, 1,
+                                                    t_pad * ps)
+
+        in_specs += [row_spec(1, t_pad * ps)] * 2
+        operands += [dense(k_scale), dense(v_scale)]
+
+    kernel = functools.partial(_paged_decode_kernel, n, ps, scale,
+                               quantized, q.dtype)
+    # What the resource sanitizer bounds in place of a BlockSpec index
+    # map (`analysis.resources.ManualBlocks`): the pages `gather`
+    # copies for row `bb`.
+    pages = ManualBlocks(
+        (1, hkv, ps, d),
+        lambda bb, kvlen, ptab: [
+            (ptab[bb, j], 0, 0, 0)
+            for j in range(-(-int(kvlen[bb]) // ps))])
+    kernel.manual_blocks = {1: pages, 2: pages}
 
     out, lse = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, nk, t * ps, scale, ps,
-                          quantized, q.dtype),
+        kernel,
         name="flash_decode_paged",
         out_shape=(
             jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
@@ -318,36 +472,32 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, kv_len, *,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, hkv, nk),
+            grid=(b,),
             in_specs=in_specs,
-            out_specs=(
-                pl.BlockSpec((1, 1, g, d),
-                             lambda bb, hh, ki, *pre: (bb, hh, 0, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 1, g, 1),
-                             lambda bb, hh, ki, *pre: (bb, hh, 0, 0),
-                             memory_space=pltpu.VMEM),
-            ),
+            out_specs=(row_spec(g, d), row_spec(g, 1)),
             scratch_shapes=[
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, 1), jnp.float32),
-                pltpu.VMEM((g, d), jnp.float32),
+                pltpu.VMEM((2, n, hkv, ps, d), k_pool.dtype),
+                pltpu.VMEM((2, n, hkv, ps, d), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((hkv, g, 1), jnp.float32),
+                pltpu.VMEM((hkv, g, 1), jnp.float32),
+                pltpu.VMEM((hkv, g, d), jnp.float32),
             ],
         ),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel",),
         ),
         cost_estimate=pl.CostEstimate(
-            # Streams at most the mapped pages; worst case = T full
-            # pages per row (same bound as the dense kernel at S=T*ps).
+            # The worst case — T full pages per row (the dense
+            # kernel's bound at S=T*ps): the live lengths are not
+            # known when tracing.
             flops=4 * b * h * t * ps * d,
             bytes_accessed=(2 * b * hkv * t * ps * d
                             * k_pool.dtype.itemsize),
             transcendentals=b * h * t * ps,
         ),
         interpret=default_interpret(interpret),
-    )(kv_len.astype(jnp.int32), page_table.astype(jnp.int32),
-      *operands)
+    )(kv_len.astype(jnp.int32), page_table, *operands)
     return out.reshape(b, h, d), lse.reshape(b, h)
 
 
@@ -516,8 +666,8 @@ def _analysis_flash_decode_paged_ag(axis_sizes):
 # kernels' pallas_call geometry captured from the real host wrappers.
 # The paged builders use a PERMUTED physical page table with NULL
 # (trash-page) tail entries — the layout a live PagedKV produces — so
-# the bounds proof covers the indirection `(ptab[b, j], h, 0, 0)`
-# including the reserved page-0 mapping.
+# the bounds proof covers the gather's `(ptab[b, j], 0, 0, 0)` for
+# every page below a row's length (`ManualBlocks` on the kernel).
 # ---------------------------------------------------------------------------
 
 from triton_distributed_tpu.analysis.resources import (  # noqa: E402
