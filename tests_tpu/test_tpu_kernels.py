@@ -284,43 +284,33 @@ def test_grouped_matmul_count_skipping():
 
 
 def test_moe_fused_world1():
-    """MoE epilogue kernel class (grouped GEMM + combine matmul +
-    reduce) compiles and runs on hardware at world=1."""
-    from jax.sharding import Mesh
+    """The one-chip expert layer (`SparseMoE`, dropless: rows packed by
+    expert, `moe_*_gate_up` / `moe_*_down` grouped GEMMs) compiles and
+    runs on hardware at decode rows and at prefill rows, under an even
+    load and with every token on the same four experts, against the
+    masked dense golden.  (Until PR 28 this test drove
+    `moe_reduce_rs_fused` at world=1, a shape no layer ever calls it
+    at and Mosaic rejects — PR 21; `MoEMLP` falls to XLA there.)"""
+    import dataclasses
 
-    from triton_distributed_tpu.kernels import moe_utils
-    from triton_distributed_tpu.kernels.moe_reduce_rs import (
-        MoEReduceRSContext, moe_reduce_rs_fused)
-    from triton_distributed_tpu.ops import shard_map_op
+    from triton_distributed_tpu.layers.moe_mlp import SparseMoE
 
-    mesh = Mesh(np.array(jax.devices()[:1]), ("tp",))
-    world, e, cap, mc, k, n = 1, 4, 32, 64, 256, 256
-    key = jax.random.key(2)
-    buckets = (jax.random.normal(key, (world, e, cap, k)) / 16
-               ).astype(jnp.bfloat16)
-    wdown = (jax.random.normal(jax.random.fold_in(key, 1), (e, k, n))
-             / 16).astype(jnp.bfloat16)
-    ids = jax.random.randint(jax.random.fold_in(key, 2),
-                             (world * mc, 2), 0, e)
-    w = jax.nn.softmax(jax.random.normal(jax.random.fold_in(key, 3),
-                                         (world * mc, 2)))
-    plan = moe_utils.plan_chunks(ids, w, world, e, cap)
-
-    ctx = MoEReduceRSContext(axis="tp", world_size=world, num_experts=e,
-                             topk=2, gemm=MatmulConfig(32, 256, 256))
-    fn = jax.jit(shard_map_op(
-        lambda bb, ww: moe_reduce_rs_fused(bb, ww, plan, ctx),
-        mesh,
-        in_specs=(P(None, None, None, None), P(None, None, None)),
-        out_specs=P(None, None)))
-    out = fn(buckets, wdown)
-
-    partial = jnp.einsum("weck,ekn->wecn", buckets.astype(jnp.float32),
-                         wdown.astype(jnp.float32))
-    ref = jax.vmap(moe_utils.combine_tokens)(
-        partial, ids.reshape(world, mc, 2), plan.slot_of_pair,
-        w.reshape(world, mc, 2)).reshape(world * mc, n)
-    assert _rel_err(out, ref) < 2e-2
+    moe = SparseMoE(hidden=1024, ffn=512, num_experts=16, topk=4,
+                    routed_scaling=1.8)
+    params = moe.init_params(jax.random.key(2))
+    gold = dataclasses.replace(moe, mode="xla")
+    for rows, skew in ((32, False), (32, True), (1024, False),
+                       (1024, True)):
+        p = dict(params)
+        if skew:
+            p["router_bias"] = jnp.asarray([9.0] * 4 + [0.0] * 12)
+        x = jax.random.normal(jax.random.key(rows), (rows, 1024)
+                              ).astype(jnp.bfloat16)
+        out, stats = jax.jit(lambda x, p: moe(x, p, phase="decode"))(x, p)
+        ref, _ = jax.jit(lambda x, p: gold(x, p))(x, p)
+        assert _rel_err(out, ref) < 2e-2, (rows, skew)
+        assert float(stats[0]) == rows * 4
+        assert float(stats[1]) == (4 if skew else 16)
 
 
 def test_w8a8_matmul_hardware():

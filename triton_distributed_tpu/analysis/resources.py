@@ -556,7 +556,7 @@ def _load_resource_modules():
 
     _load_kernel_modules()
     for mod in ("flash_attention", "matmul", "grouped_gemm",
-                "quantized"):
+                "quantized", "mla_decode"):
         importlib.import_module(
             f"triton_distributed_tpu.kernels.{mod}")
 

@@ -660,6 +660,143 @@ def emit_packed_combine_matmul(cmatb_ref, stage_ref, o_ref, *,
 
 
 # ---------------------------------------------------------------------------
+# Dropless packed expert GEMMs (`moe_utils.pack_by_expert` layout)
+# ---------------------------------------------------------------------------
+
+
+def _expert_tile(n: int, most: int = 512) -> int:
+    """Widest lane-aligned column tile of ``n`` at most ``most``."""
+    for tn in range(min(most, n) // 128 * 128, 0, -128):
+        if n % tn == 0:
+            return tn
+    return n
+
+
+def _packed_call(kernel, name, operands, weight_specs, plan_tables, *,
+                 rows, block, k, n, tn, out_dtype, row_operands=(),
+                 interpret=None):
+    """The grid both packed expert GEMMs share: (column tiles, row
+    blocks), row blocks innermost, so one (expert, column tile) of the
+    weights is fetched once for the consecutive blocks of that expert
+    and the blocks past ``n_blocks`` — mapped to the last block in use
+    — fetch and store nothing."""
+    block_expert, n_blocks = plan_tables
+    t_max = rows // block
+
+    def row_map(j, t, bexp, nblk):
+        return (jnp.minimum(t, nblk[0] - 1), 0)
+
+    def out_map(j, t, bexp, nblk):
+        return (jnp.minimum(t, nblk[0] - 1), j)
+
+    in_specs = [pl.BlockSpec((block, k), row_map,
+                             memory_space=pltpu.VMEM)]
+    in_specs += weight_specs
+    in_specs += [pl.BlockSpec((block, 1), row_map,
+                              memory_space=pltpu.VMEM)
+                 for _ in row_operands]
+    w_itemsize = operands[1].dtype.itemsize
+    return pl.pallas_call(
+        kernel,
+        name=name,
+        out_shape=jax.ShapeDtypeStruct((rows, n), out_dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n // tn, t_max),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((block, tn), out_map,
+                                   memory_space=pltpu.VMEM),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=SCOPED_VMEM_LIMIT,
+        ),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * rows * k * n * (len(operands) - 1),
+            # every expert once (the worst case), the rows once a tile
+            bytes_accessed=(operands[1].shape[0] * k * n * w_itemsize
+                            * (len(operands) - 1)
+                            + rows * k * (n // tn) * 2 + rows * n * 2),
+            transcendentals=0,
+        ),
+        interpret=default_interpret(interpret),
+    )(block_expert.astype(jnp.int32),
+      jnp.reshape(n_blocks, (1,)).astype(jnp.int32),
+      *operands, *row_operands)
+
+
+def _gate_up_kernel(bexp_ref, nblk_ref, x_ref, g_ref, u_ref, o_ref):
+    @pl.when(pl.program_id(1) < nblk_ref[0])
+    def _():
+        x = x_ref[...]
+        dims = (((1,), (0,)), ((), ()))
+        g = jax.lax.dot_general(x, g_ref[0], dims,
+                                preferred_element_type=jnp.float32)
+        u = jax.lax.dot_general(x, u_ref[0], dims,
+                                preferred_element_type=jnp.float32)
+        o_ref[...] = (g * jax.nn.sigmoid(g) * u).astype(o_ref.dtype)
+
+
+def packed_expert_gate_up(x_rows, w_gate, w_up, block_expert, n_blocks,
+                          *, block: int, name: str = "moe_gate_up",
+                          interpret: Optional[bool] = None):
+    """``silu(x W_gate[e]) * (x W_up[e])`` for rows packed by expert.
+
+    x_rows: (T * block, h) — `PackedPlan` row order, padding rows
+    zero; w_gate / w_up: (E, h, f); block_expert: (T,); n_blocks: ().
+    Returns (T * block, f) in x's dtype; rows of blocks past
+    ``n_blocks`` are not written.  Only the experts named by the
+    blocks in use are read.  ``name`` is the kernel's name in a device
+    trace (the expert layer names its decode and prefill calls
+    apart)."""
+    rows, h = x_rows.shape
+    e, h2, f = w_gate.shape
+    assert h == h2 and w_up.shape == w_gate.shape and rows % block == 0
+    tn = _expert_tile(f)
+    wspec = pl.BlockSpec((1, h, tn),
+                         lambda j, t, bexp, nblk: (bexp[t], 0, j),
+                         memory_space=pltpu.VMEM)
+    return _packed_call(
+        _gate_up_kernel, name, [x_rows, w_gate, w_up],
+        [wspec, wspec], (block_expert, n_blocks), rows=rows,
+        block=block, k=h, n=f, tn=tn, out_dtype=x_rows.dtype,
+        interpret=interpret)
+
+
+def _down_kernel(bexp_ref, nblk_ref, a_ref, w_ref, s_ref, o_ref):
+    @pl.when(pl.program_id(1) < nblk_ref[0])
+    def _():
+        y = jax.lax.dot_general(a_ref[...], w_ref[0],
+                                (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        o_ref[...] = (y * s_ref[...]).astype(o_ref.dtype)
+
+
+def packed_expert_down(act_rows, w_down, row_weight, block_expert,
+                       n_blocks, *, block: int, name: str = "moe_down",
+                       interpret: Optional[bool] = None):
+    """``(act W_down[e]) * row_weight`` for rows packed by expert: the
+    combine weight is applied to the float32 accumulator, so the
+    caller's combine is a plain sum of each token's rows.
+
+    act_rows: (T * block, f); w_down: (E, f, h); row_weight:
+    (T * block,) f32.  Returns (T * block, h) in act's dtype."""
+    rows, f = act_rows.shape
+    e, f2, h = w_down.shape
+    assert f == f2 and rows % block == 0
+    tn = _expert_tile(h)
+    wspec = pl.BlockSpec((1, f, tn),
+                         lambda j, t, bexp, nblk: (bexp[t], 0, j),
+                         memory_space=pltpu.VMEM)
+    return _packed_call(
+        _down_kernel, name, [act_rows, w_down], [wspec],
+        (block_expert, n_blocks), rows=rows, block=block, k=f, n=h,
+        tn=tn, out_dtype=act_rows.dtype,
+        row_operands=[row_weight.astype(jnp.float32)[:, None]],
+        interpret=interpret)
+
+
+# ---------------------------------------------------------------------------
 # Resource-sanitizer registration (analysis.resources).
 # ---------------------------------------------------------------------------
 
@@ -681,4 +818,21 @@ def _resource_grouped_w8a8():
     sb = jnp.ones((4, 256), jnp.float32)
     with resources.capture_pallas_calls() as records:
         grouped_matmul_w8a8(a, b, sa, sb, interpret=False)
+    return records
+
+
+@resources.register_resource_kernel("grouped_gemm.packed_experts")
+def _resource_packed_experts():
+    e, h, f, block, t = 8, 256, 384, 16, 12
+    bexp = jnp.zeros((t,), jnp.int32)
+    x = jnp.zeros((t * block, h), jnp.bfloat16)
+    with resources.capture_pallas_calls() as records:
+        act = packed_expert_gate_up(
+            x, jnp.zeros((e, h, f), jnp.bfloat16),
+            jnp.zeros((e, h, f), jnp.bfloat16), bexp, jnp.int32(t),
+            block=block, interpret=False)
+        packed_expert_down(
+            act, jnp.zeros((e, f, h), jnp.bfloat16),
+            jnp.zeros((t * block,), jnp.float32), bexp, jnp.int32(t),
+            block=block, interpret=False)
     return records

@@ -7,8 +7,11 @@ alignment ops `csrc/lib/moe_utils.cu` (`moe_ag_scatter_align_block_size`)
 which compute block-aligned expert offsets so grouped-GEMM tiles are
 uniform.
 
-TPU re-design: dynamic token counts per expert are handled by
-**capacity padding** (fixed expert capacity, drop-or-pad), which keeps
+TPU re-design, two plans.  `pack_by_expert` is DROPLESS: pairs sorted
+by expert into block-aligned groups under a static bound, for the
+one-chip expert layer (`layers.moe_mlp.SparseMoE`).  For the
+communication-overlapped tensor-parallel layer, dynamic token counts
+per expert are handled by **capacity padding** (fixed expert capacity, drop-or-pad), which keeps
 every shape static so XLA can tile the grouped GEMM onto the MXU — the
 TPU equivalent of block-aligning expert segments.  All routines are
 jit-friendly (no data-dependent shapes).  For exact no-drop parity with
@@ -285,6 +288,77 @@ def dense_combine_mats(plan: ChunkPlan, capacity: int):
 
     return jax.vmap(per_chunk)(plan.block_expert, plan.block_slot,
                                plan.n_blocks, plan.combine_blocks)
+
+
+class PackedPlan(NamedTuple):
+    """DROPLESS routing: every (token, k) pair gets a row.
+
+    Pairs are sorted by expert and laid front to back into row-blocks
+    of ``block`` rows, each expert's group padded up to whole blocks —
+    the block-aligned ragged segments of the reference
+    (`moe_align_block_size`), with a static bound in place of a
+    capacity: ``T = packed_blocks_bound(...)`` blocks hold ANY
+    assignment, however uneven, so nothing is ever dropped.
+
+    row_token:    (T * block,) int32 — source token of each packed row;
+      ``n_tokens`` marks a padding row (reads a zero row).
+    row_weight:   (T * block,) f32 — combine weight of the pair in
+      that row, 0 for padding.
+    pair_row:     (n_tokens, topk) int32 — the row each pair landed in.
+    block_expert: (T,) int32 — expert of each block; blocks past
+      ``n_blocks`` repeat the last used expert, so a kernel that maps
+      a weight block by this table fetches nothing new for them.
+    n_blocks:     () int32 — blocks in use.
+    counts:       (E,) int32 — pairs per expert.
+    """
+
+    row_token: jnp.ndarray
+    row_weight: jnp.ndarray
+    pair_row: jnp.ndarray
+    block_expert: jnp.ndarray
+    n_blocks: jnp.ndarray
+    counts: jnp.ndarray
+
+
+def packed_blocks_bound(n_pairs: int, num_experts: int,
+                        block: int) -> int:
+    """Blocks that hold any assignment of ``n_pairs`` pairs: every
+    expert in use wastes less than one block of alignment."""
+    return min(num_experts, n_pairs) + n_pairs // block
+
+
+def pack_by_expert(expert_ids, weights, num_experts: int,
+                   block: int) -> PackedPlan:
+    """Build the dropless packed plan.  expert_ids / weights:
+    (n_tokens, topk).  Deterministic (stable sort: earlier pairs come
+    first within an expert)."""
+    n_tokens, topk = expert_ids.shape
+    npairs = n_tokens * topk
+    t_max = packed_blocks_bound(npairs, num_experts, block)
+    flat_e = expert_ids.reshape(-1).astype(jnp.int32)
+    order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
+    sorted_e = flat_e[order]
+    counts = histogram(flat_e, num_experts)
+    blocks_e = (counts + block - 1) // block
+    cum = jnp.cumsum(blocks_e)
+    first_pair = jnp.cumsum(counts) - counts            # (E,)
+    pos = jnp.arange(npairs, dtype=jnp.int32) - first_pair[sorted_e]
+    dest = ((cum - blocks_e) * block)[sorted_e] + pos   # packed row
+    rows = t_max * block
+    t_ids = jnp.arange(t_max, dtype=jnp.int32)
+    return PackedPlan(
+        row_token=jnp.full((rows,), n_tokens, jnp.int32)
+        .at[dest].set(order // topk),
+        row_weight=jnp.zeros((rows,), jnp.float32)
+        .at[dest].set(weights.reshape(-1).astype(jnp.float32)[order]),
+        pair_row=jnp.zeros((npairs,), jnp.int32).at[order].set(dest)
+        .reshape(n_tokens, topk),
+        block_expert=jnp.where(
+            t_ids < cum[-1],
+            jnp.searchsorted(cum, t_ids, side="right").astype(jnp.int32),
+            sorted_e[-1]),
+        n_blocks=cum[-1].astype(jnp.int32),
+        counts=counts)
 
 
 def tokens_per_rank(expert_ids, num_experts: int, ep_size: int):

@@ -258,3 +258,150 @@ class MoEMLP:
         if mode == "w8a8":
             return self._fwd_w8a8(x, params)
         raise ValueError(f"unknown mode {self.mode}")
+
+
+# ---------------------------------------------------------------------------
+# One-chip dropless expert layer
+# ---------------------------------------------------------------------------
+
+#: What `SparseMoE` counts in a call, in this order.
+MOE_STATS = ("pairs", "experts_hit", "expert_load_max")
+
+
+def _pack_block(n_pairs: int, num_experts: int) -> int:
+    """Rows of a packed block: about an even expert's share of the
+    pairs, a power of two between the 2-byte sublane tile (16: decode
+    rows) and 256 (prefill rows)."""
+    even = max(n_pairs // num_experts, 1)
+    return min(max(1 << (even - 1).bit_length(), 16), 256)
+
+
+@dataclasses.dataclass
+class SparseMoE:
+    """Sparse feed-forward on ONE chip, dropless: sigmoid router with a
+    selection bias (`noaux_tc` without groups), ``topk`` routed experts
+    a token weighted by their normalised scores times
+    ``routed_scaling``, plus ``n_shared`` always-on experts.
+
+    ``s = sigmoid(x W_r)`` in float32; the choice is the top-k of
+    ``s + bias`` and the bias goes no further: the weights are
+    ``s[chosen] / (sum + 1e-20) * routed_scaling``.  Every pair is
+    computed, whatever the imbalance (`moe_utils.pack_by_expert`).
+
+    Mode "fused": rows packed by expert, two Pallas grouped GEMMs
+    (`kernels.grouped_gemm.packed_expert_*`) that read only the experts
+    a row was sent to — a decode step of a few rows reads a few
+    experts, a prefill reads each once.  Mode "xla": every expert over
+    every token, masked (the golden; test sizes only).
+
+    Not tensor- or expert-parallel: `MoEMLP` above is the tp layer."""
+
+    hidden: int
+    ffn: int                       # per-expert intermediate size
+    num_experts: int
+    topk: int
+    n_shared: int = 1
+    routed_scaling: float = 1.0
+    norm_topk_prob: bool = True
+    mode: str = "fused"            # xla | fused
+    interpret: Optional[bool] = None
+
+    def init_params(self, key, dtype=jnp.bfloat16):
+        ks = jax.random.split(key, 7)
+        e, h, f = self.num_experts, self.hidden, self.ffn
+        fs = f * self.n_shared
+
+        def normal(k, shape, fan_in):
+            return (jax.random.normal(k, shape) * fan_in ** -0.5
+                    ).astype(dtype)
+
+        return {
+            "router": normal(ks[0], (h, e), h).astype(jnp.float32),
+            "router_bias": 0.01 * jax.random.normal(ks[1], (e,)),
+            "gate": normal(ks[2], (e, h, f), h),
+            "up": normal(ks[3], (e, h, f), h),
+            "down": normal(ks[4], (e, f, h), f),
+            "shared": {"gate_up": normal(ks[5], (h, 2 * fs), h),
+                       "down": normal(ks[6], (fs, h), fs)},
+        }
+
+    def param_specs(self):
+        from jax.sharding import PartitionSpec as P
+        return {"router": P(None, None), "router_bias": P(None),
+                "gate": P(None, None, None), "up": P(None, None, None),
+                "down": P(None, None, None),
+                "shared": {"gate_up": P(None, None),
+                           "down": P(None, None)}}
+
+    # ------------------------------------------------------------------
+
+    def route(self, x, params):
+        """(ids (N, topk) int32, weights (N, topk) f32)."""
+        s = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), params["router"].astype(jnp.float32),
+            precision="highest"))
+        _, ids = jax.lax.top_k(
+            s + params["router_bias"].astype(jnp.float32), self.topk)
+        w = jnp.take_along_axis(s, ids, axis=1)
+        if self.norm_topk_prob:
+            w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+        return ids.astype(jnp.int32), w * self.routed_scaling
+
+    def _shared(self, x, params):
+        h = jnp.dot(x, params["gate_up"],
+                    preferred_element_type=jnp.float32).astype(x.dtype)
+        return jnp.dot(gated_silu(h), params["down"],
+                       preferred_element_type=jnp.float32)
+
+    def _routed_xla(self, x, params, ids, w):
+        dense_w = jnp.zeros((x.shape[0], self.num_experts), jnp.float32
+                            ).at[jnp.arange(x.shape[0])[:, None],
+                                 ids].add(w)
+        g = jnp.einsum("nh,ehf->enf", x, params["gate"],
+                       preferred_element_type=jnp.float32)
+        u = jnp.einsum("nh,ehf->enf", x, params["up"],
+                       preferred_element_type=jnp.float32)
+        act = (jax.nn.silu(g) * u).astype(x.dtype)
+        y = jnp.einsum("enf,efh->enh", act, params["down"],
+                       preferred_element_type=jnp.float32)
+        return jnp.einsum("enh,ne->nh", y, dense_w)
+
+    def _routed_fused(self, x, params, plan, block, phase):
+        from triton_distributed_tpu.kernels.grouped_gemm import (
+            packed_expert_down, packed_expert_gate_up)
+
+        rows = moe_utils.gather_tokens(x, plan.row_token)
+        act = packed_expert_gate_up(
+            rows, params["gate"], params["up"], plan.block_expert,
+            plan.n_blocks, block=block, name=f"moe_{phase}_gate_up",
+            interpret=self.interpret)
+        out = packed_expert_down(
+            act, params["down"], plan.row_weight, plan.block_expert,
+            plan.n_blocks, block=block, name=f"moe_{phase}_down",
+            interpret=self.interpret)
+        # each token's topk rows, already weighted: a float32 sum
+        return out[plan.pair_row].astype(jnp.float32).sum(axis=1)
+
+    def __call__(self, x, params, phase: str = "prefill"):
+        """x: (N, hidden).  Returns (y (N, hidden), stats (3,) f32 in
+        `MOE_STATS` order: pairs computed, experts with at least one
+        row, the busiest expert's share of the pairs).  ``phase``
+        ("decode" | "prefill") names the two grouped GEMMs in a device
+        trace: `moe_<phase>_gate_up`, `moe_<phase>_down`."""
+        n = x.shape[0]
+        ids, w = self.route(x, params)
+        block = _pack_block(n * self.topk, self.num_experts)
+        plan = moe_utils.pack_by_expert(ids, w, self.num_experts, block)
+        if self.mode == "xla":
+            y = self._routed_xla(x, params, ids, w)
+        elif self.mode == "fused":
+            y = self._routed_fused(x, params, plan, block, phase)
+        else:
+            raise ValueError(f"unknown mode {self.mode}")
+        if self.n_shared:
+            y = y + self._shared(x, params["shared"])
+        pairs = jnp.float32(n * self.topk)
+        stats = jnp.stack([
+            pairs, jnp.sum(plan.counts > 0).astype(jnp.float32),
+            jnp.max(plan.counts).astype(jnp.float32) / pairs])
+        return y.astype(x.dtype), stats

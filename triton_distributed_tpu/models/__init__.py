@@ -4,6 +4,8 @@
 from triton_distributed_tpu.models.config import ModelConfig  # noqa: F401
 from triton_distributed_tpu.models.kv_cache import KVCache  # noqa: F401
 from triton_distributed_tpu.models.qwen import Qwen3  # noqa: F401
+from triton_distributed_tpu.models.glm4_moe_lite import (  # noqa: F401
+    Glm4MoeLite)
 from triton_distributed_tpu.models.engine import Engine  # noqa: F401
 
 
@@ -13,4 +15,6 @@ def AutoLLM(config, mesh, **kw):
     arch = (config.architecture or "qwen3").lower()
     if "qwen" in arch or "llama" in arch:
         return Qwen3(config, mesh, **kw)
+    if "glm4_moe_lite" in arch or "glm4moelite" in arch:
+        return Glm4MoeLite(config, mesh, **kw)
     raise ValueError(f"unknown architecture: {config.architecture}")

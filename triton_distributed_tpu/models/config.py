@@ -32,10 +32,33 @@ class ModelConfig:
     #: Int8-quantize the KV cache (per-token scales): halves the cache
     #: footprint and decode's KV bandwidth (kernels/flash_decode.py).
     quantize_kv_cache: bool = False
+    # Latent attention (MLA; `models/glm4_moe_lite.py`): queries go
+    # through a rank-``q_lora_rank`` bottleneck, keys and values are
+    # expanded from ONE ``kv_lora_rank``-wide latent a token, and a
+    # ``qk_rope_head_dim``-wide rotated key is shared by all heads.
+    # ``kv_lora_rank`` 0 = ordinary attention (``head_dim`` above).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Sparse feed-forward of the same family: layers below
+    # ``first_k_dense_replace`` are dense (``intermediate_size``), the
+    # rest route each token to ``num_experts_per_tok`` of
+    # ``num_experts`` by sigmoid scores (dropless) beside
+    # ``n_shared_experts`` always-on experts.
+    first_k_dense_replace: int = 0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
 
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @classmethod
     def qwen3_0_6b(cls):
@@ -94,6 +117,25 @@ class ModelConfig:
                  moe_intermediate_size=128)
         d.update(kw)
         return cls.tiny(**d)
+
+    @classmethod
+    def tiny_glm4_moe_lite(cls, **kw):
+        """Test-size latent-attention + sparse-expert config: the
+        published ratios (rope 64 of a 256 head; one leading dense
+        layer; top-4 with one shared expert) at widths the CPU walks."""
+        d = dict(architecture="glm4_moe_lite", vocab_size=256,
+                 hidden_size=128, intermediate_size=256, num_layers=3,
+                 num_heads=4, num_kv_heads=4, head_dim=0,
+                 rms_norm_eps=1e-5, rope_theta=1e6, qk_norm=False,
+                 tie_word_embeddings=False, max_seq_len=128,
+                 q_lora_rank=64, kv_lora_rank=128, qk_nope_head_dim=32,
+                 qk_rope_head_dim=32, v_head_dim=64,
+                 num_experts=8, num_experts_per_tok=4,
+                 moe_intermediate_size=128, first_k_dense_replace=1,
+                 n_shared_experts=1, routed_scaling_factor=1.8,
+                 norm_topk_prob=True)
+        d.update(kw)
+        return cls(**d)
 
     @classmethod
     def from_hf(cls, model_name_or_path: str):

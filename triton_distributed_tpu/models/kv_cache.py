@@ -7,11 +7,20 @@ TPU: a pytree of per-layer (k, v) arrays with a shared offset vector;
 updates are functional (`jax.lax.dynamic_update_slice`) and the whole
 cache is donated through the jitted decode step, so XLA updates it in
 place — the role CUDA graphs + in-place writes play in the reference.
+
+Both layouts hold either kind of layer state.  An ordinary layer keeps
+K and V per KV head (``ks`` and ``vs``).  A LATENT layer (MLA,
+`layers.mla_attn`) keeps ONE row a token — the normalised latent and
+the rotated shared key, zero-padded to a lane multiple — that serves
+as K and as V for every head: ``ks[l]`` is ``(B | P, 1, S | page, R)``
+and ``vs`` is None.  Everything that walks a cache (insert, spill,
+byte accounting) walks ``ks`` and, where there is one, ``vs``.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import List, Optional
 
 import jax
@@ -22,7 +31,7 @@ import jax.numpy as jnp
 @dataclasses.dataclass
 class KVCache:
     ks: List[jnp.ndarray]          # per layer: (B, Hkv_loc, S_max, D)
-    vs: List[jnp.ndarray]
+    vs: Optional[List[jnp.ndarray]]    # None: latent rows (K is V)
     offset: jnp.ndarray            # (B,) int32 — filled length
     #: Per-token dequant scales (B, Hkv_loc, S_max) f32 per layer when
     #: the cache is int8-quantized (see `kernels.flash_decode`:
@@ -39,13 +48,17 @@ class KVCache:
     @classmethod
     def create(cls, num_layers: int, batch: int, num_kv_heads: int,
                max_seq: int, head_dim: int, dtype=jnp.bfloat16,
-               quantized: bool = False):
+               quantized: bool = False, latent: bool = False):
+        """``latent``: one ``head_dim``-wide row a token and no V
+        (``num_kv_heads`` must be 1)."""
+        assert not latent or (num_kv_heads == 1 and not quantized)
         shape = (batch, num_kv_heads, max_seq, head_dim)
         if quantized:
             dtype = jnp.int8
         return cls(
             ks=[jnp.zeros(shape, dtype) for _ in range(num_layers)],
-            vs=[jnp.zeros(shape, dtype) for _ in range(num_layers)],
+            vs=(None if latent else
+                [jnp.zeros(shape, dtype) for _ in range(num_layers)]),
             offset=jnp.zeros((batch,), jnp.int32),
             kss=([jnp.zeros(shape[:3], jnp.float32)
                   for _ in range(num_layers)] if quantized else None),
@@ -53,10 +66,16 @@ class KVCache:
                   for _ in range(num_layers)] if quantized else None),
         )
 
-    def write_prefill(self, layer: int, k, v):
+    def write_prefill(self, layer: int, k, v=None):
         """k/v: (B, Hkv, S, D) float — fill from position 0
-        (quantizing on write when the cache is int8)."""
+        (quantizing on write when the cache is int8); a latent cache
+        takes its rows as ``k`` alone."""
         ks = list(self.ks)
+        if self.vs is None:
+            ks[layer] = jax.lax.dynamic_update_slice(
+                self.ks[layer], k.astype(self.ks[layer].dtype),
+                (0, 0, 0, 0))
+            return dataclasses.replace(self, ks=ks)
         vs = list(self.vs)
         if self.quantized:
             from triton_distributed_tpu.kernels.flash_decode import (
@@ -81,12 +100,14 @@ class KVCache:
             self.vs[layer], v.astype(self.vs[layer].dtype), (0, 0, 0, 0))
         return dataclasses.replace(self, ks=ks, vs=vs)
 
-    def set_layer(self, layer: int, k, v, kscale=None, vscale=None):
+    def set_layer(self, layer: int, k, v=None, kscale=None, vscale=None):
         ks = list(self.ks)
-        vs = list(self.vs)
         ks[layer] = k
-        vs[layer] = v
-        rep = dict(ks=ks, vs=vs)
+        rep = dict(ks=ks)
+        if self.vs is not None:
+            vs = list(self.vs)
+            vs[layer] = v
+            rep["vs"] = vs
         if kscale is not None:
             kss = list(self.kss)
             vss = list(self.vss)
@@ -112,10 +133,8 @@ class KVCache:
         the serving scheduler's KV admission budget is counted in.
         Covers K+V (and the per-token dequant scales when the cache is
         int8-quantized)."""
-        total = 0
-        for k, v in zip(self.ks, self.vs):
-            per_row = k.shape[1] * k.shape[2] * k.shape[3]
-            total += per_row * (k.dtype.itemsize + v.dtype.itemsize)
+        total = sum(math.prod(x.shape[1:]) * x.dtype.itemsize
+                    for x in self.ks + (self.vs or []))
         if self.quantized:
             for ks_, vs_ in zip(self.kss, self.vss):
                 per_row = ks_.shape[1] * ks_.shape[2]
@@ -169,13 +188,19 @@ class PagedKVCache:
     """
 
     ks: List[jnp.ndarray]          # per layer: (P, Hkv_loc, page, D)
-    vs: List[jnp.ndarray]
+    vs: Optional[List[jnp.ndarray]]    # None: latent rows (K is V)
     page_table: jnp.ndarray        # (B, T) int32 — physical page ids
     offset: jnp.ndarray            # (B,) int32 — filled length
     #: Per-token dequant scales (P, Hkv_loc, page) f32 per layer when
     #: int8-quantized (same scheme as `KVCache.kss/vss`); None = float.
     kss: Optional[List[jnp.ndarray]] = None
     vss: Optional[List[jnp.ndarray]] = None
+    #: What the model counted in its LAST decode step, left here so it
+    #: reaches the host with the step's outputs and no dispatch of its
+    #: own (a sparse model's routing counters,
+    #: `models.glm4_moe_lite.MOE_STATS`); None: the model counts
+    #: nothing.
+    stats: Optional[jnp.ndarray] = None
     #: Tokens per page — static: it shapes the compiled programs.
     page_size: int = dataclasses.field(
         default=16, metadata=dict(static=True))
@@ -205,16 +230,23 @@ class PagedKVCache:
     def create(cls, num_layers: int, num_pages: int, batch: int,
                num_kv_heads: int, page_size: int, head_dim: int,
                max_pages_per_seq: int, dtype=jnp.bfloat16,
-               quantized: bool = False):
+               quantized: bool = False, latent: bool = False,
+               num_stats: int = 0):
         """``num_pages`` INCLUDES the reserved null page 0 (usable
-        pages = num_pages - 1)."""
+        pages = num_pages - 1).  ``latent``: one pool a layer of
+        ``head_dim``-wide rows and no V pool (``num_kv_heads`` 1).
+        ``num_stats``: width of the model's `stats` vector."""
         assert num_pages >= 2, "need >= 1 usable page beside NULL_PAGE"
+        assert not latent or (num_kv_heads == 1 and not quantized)
         shape = (num_pages, num_kv_heads, page_size, head_dim)
         if quantized:
             dtype = jnp.int8
         return cls(
             ks=[jnp.zeros(shape, dtype) for _ in range(num_layers)],
-            vs=[jnp.zeros(shape, dtype) for _ in range(num_layers)],
+            vs=(None if latent else
+                [jnp.zeros(shape, dtype) for _ in range(num_layers)]),
+            stats=(jnp.zeros((num_stats,), jnp.float32) if num_stats
+                   else None),
             page_table=jnp.zeros((batch, max_pages_per_seq), jnp.int32),
             offset=jnp.zeros((batch,), jnp.int32),
             kss=([jnp.zeros(shape[:3], jnp.float32)
@@ -230,10 +262,8 @@ class PagedKVCache:
         in.  Unlike `KVCache.bytes_per_slot` (which prices a request
         at max-context worst case), a request costs
         ``pages_for(len) * bytes_per_page`` — its TRUE footprint."""
-        total = 0
-        for k, v in zip(self.ks, self.vs):
-            per_page = k.shape[1] * k.shape[2] * k.shape[3]
-            total += per_page * (k.dtype.itemsize + v.dtype.itemsize)
+        total = sum(math.prod(x.shape[1:]) * x.dtype.itemsize
+                    for x in self.ks + (self.vs or []))
         if self.quantized:
             for ks_, vs_ in zip(self.kss, self.vss):
                 per_page = ks_.shape[1] * ks_.shape[2]
@@ -241,12 +271,14 @@ class PagedKVCache:
                                      + vs_.dtype.itemsize)
         return total
 
-    def set_layer(self, layer: int, k, v, kscale=None, vscale=None):
+    def set_layer(self, layer: int, k, v=None, kscale=None, vscale=None):
         ks = list(self.ks)
-        vs = list(self.vs)
         ks[layer] = k
-        vs[layer] = v
-        rep = dict(ks=ks, vs=vs)
+        rep = dict(ks=ks)
+        if self.vs is not None:
+            vs = list(self.vs)
+            vs[layer] = v
+            rep["vs"] = vs
         if kscale is not None:
             kss = list(self.kss)
             vss = list(self.vss)
@@ -282,8 +314,12 @@ class PagedKVCache:
         D) view of ``layer`` through the page table.  NOT for the hot
         path — decode reads through the table in-kernel."""
         b = self.batch
-        k = self.ks[layer][self.page_table]    # (B, T, Hkv, page, D)
-        v = self.vs[layer][self.page_table]
-        k = jnp.moveaxis(k, 2, 1).reshape(b, k.shape[2], -1, k.shape[-1])
-        v = jnp.moveaxis(v, 2, 1).reshape(b, v.shape[2], -1, v.shape[-1])
-        return k, v
+
+        def logical(pool):
+            x = pool[self.page_table]          # (B, T, Hkv, page, D)
+            return jnp.moveaxis(x, 2, 1).reshape(
+                b, x.shape[2], -1, x.shape[-1])
+
+        k = logical(self.ks[layer])
+        return k, (logical(self.vs[layer]) if self.vs is not None
+                   else None)

@@ -305,16 +305,18 @@ def make_insert_fn(donate: bool = True):
 
     def insert(big: KVCache, keys, row: KVCache, key, slot, offset):
         slot = jnp.asarray(slot, jnp.int32)
-        ks = [jax.lax.dynamic_update_slice(
-                  bk, rk.astype(bk.dtype), (slot, 0, 0, 0))
-              for bk, rk in zip(big.ks, row.ks)]
-        vs = [jax.lax.dynamic_update_slice(
-                  bv, rv.astype(bv.dtype), (slot, 0, 0, 0))
-              for bv, rv in zip(big.vs, row.vs)]
+
+        def rows(big_list, row_list):
+            return [jax.lax.dynamic_update_slice(
+                        bk, rk.astype(bk.dtype), (slot, 0, 0, 0))
+                    for bk, rk in zip(big_list, row_list)]
+
         off = jax.lax.dynamic_update_slice(
             big.offset, jnp.reshape(jnp.asarray(offset, jnp.int32), (1,)),
             (slot,))
-        rep = dict(ks=ks, vs=vs, offset=off)
+        rep = dict(ks=rows(big.ks, row.ks), offset=off)
+        if big.vs is not None:          # None: latent rows (K is V)
+            rep["vs"] = rows(big.vs, row.vs)
         if big.quantized:
             rep["kss"] = [jax.lax.dynamic_update_slice(
                               bs, rs, (slot, 0, 0))
@@ -338,6 +340,9 @@ def make_paged_insert_fn(donate: bool = True):
     pages of the paged pool, set the slot's offset and PRNG key — one
     dispatch per admission, one compiled program per (bucket,
     pool-geometry).
+
+    A latent pool (`models.kv_cache`: ``vs`` None) has one pool a
+    layer to scatter into; the walk is the same.
 
     ``page_ids`` is a (ceil(bucket / page_size),) int32 vector naming
     the physical destination of each LOCAL page of the row cache;
@@ -374,11 +379,12 @@ def make_paged_insert_fn(donate: bool = True):
             return out
 
         rep = dict(ks=scatter(pool.ks, row.ks, False),
-                   vs=scatter(pool.vs, row.vs, False),
                    offset=jax.lax.dynamic_update_slice(
                        pool.offset,
                        jnp.reshape(jnp.asarray(offset, jnp.int32), (1,)),
                        (jnp.asarray(slot, jnp.int32),)))
+        if pool.vs is not None:         # None: latent rows (K is V)
+            rep["vs"] = scatter(pool.vs, row.vs, False)
         if pool.quantized:
             rep["kss"] = scatter(pool.kss, row.kss, True)
             rep["vss"] = scatter(pool.vss, row.vss, True)

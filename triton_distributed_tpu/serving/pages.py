@@ -726,7 +726,14 @@ class PagedKV:
             return []
         ids = self.pool.alloc(n)
         if ids is None and self.radix is not None:
-            self.radix.evict(n - self.pool.free_pages)
+            # `evict` walks the whole tree of retained prefixes for its
+            # LRU frontier, whatever it is asked for: ask for a 64th of
+            # the pool at a time, so that a full pool pays one walk for
+            # many allocations and not one for each (14 ms a decode
+            # step at 10k pages — PERF.md, PR 28).  The order of
+            # eviction is the same; small pools evict what they need.
+            self.radix.evict(max(n - self.pool.free_pages,
+                                 self.usable_pages // 64))
             ids = self.pool.alloc(n)
         return ids
 
@@ -986,7 +993,8 @@ class PagedKV:
         out: Dict[str, np.ndarray] = {}
         for layer in range(len(c.ks)):
             out[f"k{layer}"] = np.asarray(c.ks[layer][page])
-            out[f"v{layer}"] = np.asarray(c.vs[layer][page])
+            if c.vs is not None:        # None: latent rows (K is V)
+                out[f"v{layer}"] = np.asarray(c.vs[layer][page])
             if c.quantized:
                 out[f"ks{layer}"] = np.asarray(c.kss[layer][page])
                 out[f"vs{layer}"] = np.asarray(c.vss[layer][page])
@@ -998,9 +1006,10 @@ class PagedKV:
         c = self.cache
         ks = [k.at[page].set(jnp.asarray(payload[f"k{i}"]))
               for i, k in enumerate(c.ks)]
-        vs = [v.at[page].set(jnp.asarray(payload[f"v{i}"]))
-              for i, v in enumerate(c.vs)]
-        rep = dict(ks=ks, vs=vs)
+        rep = dict(ks=ks)
+        if c.vs is not None:
+            rep["vs"] = [v.at[page].set(jnp.asarray(payload[f"v{i}"]))
+                         for i, v in enumerate(c.vs)]
         if c.quantized:
             rep["kss"] = [x.at[page].set(
                 jnp.asarray(payload[f"ks{i}"]))

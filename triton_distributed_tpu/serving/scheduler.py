@@ -38,8 +38,9 @@ slot-occupancy / KV-budget gauges (all in the Prometheus export), one
 detached `serving.request` span per request feeding the cross-rank
 timeline, and a span around each phase of a step (`serving.step` >
 `serving.admit` > `serving.admit.request` > `serving.prefill.block`;
-`serving.pages`, `serving.dispatch`, `serving.sync`, `serving.commit`,
-`serving.gauges`) that says where the host's time in a step went.
+`serving.pages`, `serving.dispatch`, `serving.sync`, `serving.moe` (a
+sparse model's expert counters), `serving.commit`, `serving.gauges`)
+that says where the host's time in a step went.
 Metric and span names: docs/serving.md, docs/observability.md.
 """
 
@@ -1149,7 +1150,7 @@ class ContinuousBatchingScheduler:
                 self._pages_phase(writes, sp)
             if not self._by_slot:      # defensive: all preempted
                 return 0
-        accept_host = n_draft = None
+        accept_host = n_draft = counted = None
         if spec is not None:
             drafts, n_draft = spec
             with span("serving.dispatch", k=self.config.spec_k,
@@ -1181,6 +1182,7 @@ class ContinuousBatchingScheduler:
                     self.slots.active_mask())
                 self.slots.cache = cache
                 self.slots.keys = keys
+            counted = self._moe_counters()
             with span("serving.sync"):
                 toks_host = np.asarray(toks)      # THE host sync
             if k == 1:
@@ -1188,6 +1190,8 @@ class ContinuousBatchingScheduler:
             steps = k
         now = self.clock()
         reg = self._registry()
+        if counted is not None:
+            self._moe_phase(counted, reg)
         if reg:
             elapsed_ms = (self.step_timer() - t0) * 1e3
             step_ms = elapsed_ms / steps
@@ -1244,6 +1248,30 @@ class ContinuousBatchingScheduler:
         if reg:
             reg.counter("serving_tokens_generated_total").inc(generated)
         return retired
+
+    def _moe_counters(self):
+        """What a sparse model's expert layers counted in the dispatch
+        just enqueued (`layers.moe_mlp.MOE_STATS`, left in the cache's
+        `stats` by the decode program itself), its copy to the host
+        started so that it lands with the step's tokens: no sync of
+        its own.  None: the model counts nothing, or nothing records."""
+        counted = getattr(self.slots.cache, "stats", None)
+        if counted is None or self._registry() is None:
+            return None
+        counted.copy_to_host_async()
+        return counted
+
+    def _moe_phase(self, counted, reg) -> None:
+        """After the step's host sync: the counters as a `serving.moe`
+        span's attributes and as metrics."""
+        with span("serving.moe") as sp:
+            pairs, hit, load_max = (float(v) for v in
+                                    np.asarray(counted))
+            sp.attrs.update(pairs=pairs, experts_hit=hit,
+                            expert_load_max=load_max)
+        reg.counter("serving_moe_pairs_total").inc(pairs)
+        reg.counter("serving_moe_experts_hit_total").inc(hit)
+        reg.gauge("serving_moe_expert_load_max").set(load_max)
 
     def _spec_outcome(self, rows, accept_host, n_draft, now,
                       reg) -> None:
@@ -1406,6 +1434,13 @@ class ContinuousBatchingScheduler:
             reg.gauge("serving_kv_pages_live").set(self.slots.live_pages)
             reg.gauge("serving_kv_page_occupancy").set(
                 self.slots.page_occupancy)
+            latent = getattr(self.model, "latent_bytes_per_token", 0)
+            if latent:
+                # a latent-attention model: the bytes of the live
+                # context that carry information (rows less their pad)
+                reg.gauge("serving_kv_latent_bytes_live").set(
+                    latent * sum(r.prompt_len + len(r.generated)
+                                 for r in self._by_slot.values()))
             reg.gauge("serving_prefix_cache_pages").set(
                 self.slots.cached_prefix_pages)
             # Per-tier admission accounting mirrored as gauges so the
