@@ -1,0 +1,195 @@
+"""No pool-sized copy in a compiled decode step or admission.
+
+A decode step changes ONE row a slot of each layer's KV pools.  Until
+PR 29 the compiled step (`jit_body`) copied every pool twice: XLA's
+TPU scatter wants its window dimensions minor, and the write
+`pool.at[phys, :, within]` put a window (the heads) between the two
+scattered dimensions, so the pool was copied into another layout,
+scattered into, and copied back — 6.8 of 15 ms a step at 12 layers
+(PERF.md section 6, PR 29).  Donation was never the fault.  This file
+compiles the programs the scheduler runs — the masked decode step and
+the paged insert, pools donated — for a DESCRIBED v5e:2x2 (nothing
+executes) at published widths, two layers, and asserts that no `copy`
+in the compiled module has a pool's shape.
+
+Pinned for both families: `Qwen3` (one device, and the tp=4 mesh whose
+shard holds 2 of the 8 KV heads) and `Glm4MoeLite`'s latent pool, whose
+write was always in the leading-dimensions form.
+"""
+
+import functools
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from triton_distributed_tpu.models.config import ModelConfig
+from triton_distributed_tpu.models.glm4_moe_lite import (
+    MOE_STATS, Glm4MoeLite)
+from triton_distributed_tpu.models.kv_cache import KVCache, PagedKVCache
+from triton_distributed_tpu.models.qwen import Qwen3
+from triton_distributed_tpu.serving.engine_batched import (
+    make_masked_step_fn, make_paged_insert_fn)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+LAYERS, PAGE, SLOTS = 2, 16, 8
+#: The Qwen cells' pool: 8 slots x 2768 tokens / 16 + the trash page.
+PAGES = 1385
+BUCKET = 2048
+
+
+@pytest.fixture(scope="module")
+def topo_devices():
+    from jax.experimental import topologies
+    try:
+        return tuple(topologies.get_topology_desc("v5e:2x2", "tpu").devices)
+    except Exception as e:     # noqa: BLE001 — whatever PJRT raises
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+def _config(name):
+    with open(os.path.join(ROOT, "cellbench", "configs", name)) as f:
+        return json.load(f)
+
+
+def _qwen(devices):
+    c = _config("qwen3-8b-1c.json")
+    cfg = ModelConfig(
+        vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
+        intermediate_size=c["intermediate_size"], num_layers=LAYERS,
+        num_heads=c["num_attention_heads"],
+        num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
+        rms_norm_eps=c["rms_norm_eps"], rope_theta=c["rope_theta"],
+        qk_norm=True, tie_word_embeddings=c["tie_word_embeddings"],
+        max_seq_len=4096, dtype=c["torch_dtype"])
+    model = Qwen3(cfg, Mesh(np.array(devices), ("tp",)), mode="fused",
+                  interpret=False)
+    return model, cfg.num_kv_heads, cfg.head_dim, False
+
+
+def _glm(devices):
+    c = _config("glm-4.7-flash-1c.json")
+    cfg = ModelConfig(
+        architecture=c["model_type"], vocab_size=c["vocab_size"],
+        hidden_size=c["hidden_size"],
+        intermediate_size=c["intermediate_size"], num_layers=LAYERS,
+        num_heads=c["num_attention_heads"],
+        num_kv_heads=c["num_key_value_heads"], head_dim=0,
+        rms_norm_eps=c["rms_norm_eps"], rope_theta=c["rope_theta"],
+        qk_norm=False, tie_word_embeddings=c["tie_word_embeddings"],
+        max_seq_len=4096, dtype=c["torch_dtype"],
+        q_lora_rank=c["q_lora_rank"], kv_lora_rank=c["kv_lora_rank"],
+        qk_nope_head_dim=c["qk_nope_head_dim"],
+        qk_rope_head_dim=c["qk_rope_head_dim"],
+        v_head_dim=c["v_head_dim"], num_experts=c["n_routed_experts"],
+        num_experts_per_tok=c["num_experts_per_tok"],
+        moe_intermediate_size=c["moe_intermediate_size"],
+        first_k_dense_replace=c["first_k_dense_replace"],
+        n_shared_experts=c["n_shared_experts"],
+        routed_scaling_factor=c["routed_scaling_factor"],
+        norm_topk_prob=c["norm_topk_prob"])
+    model = Glm4MoeLite(cfg, Mesh(np.array(devices), ("tp",)),
+                        mode="fused", interpret=False)
+    return model, 1, model.attn.row_width, True
+
+
+def _shaped(model, make, specs):
+    """The shapes ``make`` would build, each with its sharding on the
+    model's (described) mesh: nothing can be placed on such devices."""
+    return jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        jax.eval_shape(make), model._named(specs))
+
+
+def _programs(model, heads, width, latent):
+    """Compiled text of the step and the insert as the scheduler runs
+    them, and the per-device shape of a pool of ``heads`` x ``width``
+    rows."""
+    world = model.mesh.shape["tp"]
+    rep = NamedSharding(model.mesh, P())
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
+        shape, dt, sharding=rep)
+    pool = _shaped(model, functools.partial(
+        PagedKVCache.create, LAYERS, PAGES, SLOTS, heads, PAGE, width,
+        4096 // PAGE, model.dtype, latent=latent,
+        num_stats=len(MOE_STATS) if latent else 0),
+        model._paged_cache_specs(PAGE))
+    row = _shaped(model, functools.partial(
+        KVCache.create, LAYERS, 1, heads, BUCKET, width, model.dtype,
+        latent=latent),
+        model._cache_specs() if latent else model._cache_specs(None))
+    params = _shaped(
+        model, lambda: model.init_params(jax.random.key(0)),
+        model.param_specs())
+    keys = arg((SLOTS, 2), jnp.uint32)
+    step = make_masked_step_fn(model.make_paged_decode_fn(PAGE)).lower(
+        params, arg((SLOTS,), jnp.int32), pool, keys,
+        arg((SLOTS,), jnp.bool_)).compile().as_text()
+    insert = make_paged_insert_fn().lower(
+        pool, keys, row, arg((2,), jnp.uint32), arg((), jnp.int32),
+        arg((BUCKET // PAGE,), jnp.int32),
+        arg((), jnp.int32)).compile().as_text()
+    return step, insert, (PAGES, heads // world, PAGE, width)
+
+
+def pool_copies(hlo_text: str, shape) -> dict:
+    """Instructions of a compiled module that copy an array of
+    ``shape``, by name.  ``layout``: a `copy` (alone or as the root a
+    fusion is named for) — the transposing copies around the old
+    scatter, a `copy` row of the device trace.  ``staged``:
+    `copy-done` — XLA's memory-space assignment moving a whole array
+    into or out of VMEM, asynchronous and at the speed of a read."""
+    dims = ",".join(str(d) for d in shape)
+    pat = re.compile(
+        r"^\s*(?:ROOT\s+)?%?(\S+)\s*=\s*\w+\[" + re.escape(dims)
+        + r"\]\S*\s+([\w-]+)\(", re.M)
+    found = {"layout": [], "staged": []}
+    for name, opcode in pat.findall(hlo_text):
+        if opcode == "copy" or (opcode == "fusion" and "copy" in name):
+            found["layout"].append(name)
+        elif opcode == "copy-done":
+            found["staged"].append(name)
+    return found
+
+
+def test_the_reader_sees_a_pool_copy():
+    text = """
+ENTRY %main {
+  %copy.7 = bf16[1385,8,16,128]{3,1,2,0:T(8,128)(2,1)} copy(%p), metadata={}
+  %fusion.1 = bf16[1385,8,16,128]{3,2,1,0} fusion(%copy.7), kind=kLoop
+  ROOT %copy_fusion.2 = bf16[1385,8,16,128]{3,2,1,0} fusion(%fusion.1)
+  %copy-done.1 = bf16[1385,8,16,128]{3,2,1,0:S(1)} copy-done(%copy-start.1)
+  %copy.9 = bf16[8,128]{1,0} copy(%q)
+}"""
+    assert pool_copies(text, (1385, 8, 16, 128)) == {
+        "layout": ["copy.7", "copy_fusion.2"], "staged": ["copy-done.1"]}
+
+
+#: One pool may be staged through VMEM and back (two `copy-done`): at
+#: tp=4 the compiler does that to ONE of the 72 11 MB pools, 28 us of
+#: HBM traffic a step.  More than that is worth reading.
+STAGED_MAX = 2
+
+
+@pytest.mark.parametrize("family,world", [
+    ("qwen3", 1), ("qwen3", 4), ("glm4_moe_lite", 1)])
+def test_no_pool_sized_copy_in_step_or_insert(topo_devices, family,
+                                              world):
+    build = _qwen if family == "qwen3" else _glm
+    step, insert, shard = _programs(*build(topo_devices[:world]))
+    assert "tpu_custom_call" in step, "the attention kernel is missing"
+    dims = ",".join(str(d) for d in shard)
+    assert f"[{dims}]" in step and f"[{dims}]" in insert, (
+        f"no array of the pool's per-device shape {shard} in the "
+        f"compiled programs: the reader would pass on anything")
+    found = {"step": pool_copies(step, shard),
+             "insert": pool_copies(insert, shard)}
+    print(f"{family} tp={world} pool shard {shard}: {found}")
+    for program, copies in found.items():
+        assert not copies["layout"], (program, copies)
+        assert len(copies["staged"]) <= STAGED_MAX, (program, copies)

@@ -272,7 +272,16 @@ class TPAttention:
         (masked rows' NULL-mapped writes land in the reserved trash
         page) and attention runs the page-table-indexed split-KV
         kernel (`flash_decode_paged`).  Same projections, rope and
-        int8 quantize-on-write as the dense path."""
+        int8 quantize-on-write as the dense path.
+
+        The write goes through `kv_cache.write_token_rows`, which
+        indexes page, head and row explicitly: with the heads left as
+        a slice the compiled step copied every pool twice (a layout
+        change around the scatter; PERF.md section 6, PR 29)."""
+        # `models` imports this package: bind at call time
+        from triton_distributed_tpu.models.kv_cache import (
+            write_token_rows)
+
         k_pool, v_pool = kv_pools
         b = offset.shape[0]
         ps = k_pool.shape[2]
@@ -308,12 +317,12 @@ class TPAttention:
 
             k_sc, v_sc = kv_scales
             k, v, kscale_new, vscale_new = quantize_kv(k, v)
-            k_sc = k_sc.at[phys, :, within].set(kscale_new[:, :, 0])
-            v_sc = v_sc.at[phys, :, within].set(vscale_new[:, :, 0])
-        k_pool = k_pool.at[phys, :, within, :].set(
-            k[:, :, 0].astype(k_pool.dtype))
-        v_pool = v_pool.at[phys, :, within, :].set(
-            v[:, :, 0].astype(v_pool.dtype))
+            k_sc = write_token_rows(k_sc, phys, within,
+                                    kscale_new[:, :, 0])
+            v_sc = write_token_rows(v_sc, phys, within,
+                                    vscale_new[:, :, 0])
+        k_pool = write_token_rows(k_pool, phys, within, k[:, :, 0])
+        v_pool = write_token_rows(v_pool, phys, within, v[:, :, 0])
 
         out, _ = flash_decode_paged(
             q.reshape(b, self.h_loc, self.head_dim), k_pool, v_pool,

@@ -165,6 +165,29 @@ def pages_for(tokens: int, page_size: int) -> int:
     return -(-int(tokens) // int(page_size)) if tokens > 0 else 0
 
 
+def write_token_rows(pool, phys, within, rows):
+    """One decode step's rows into a page pool, where the pool lies.
+
+    ``pool``: (P, H, page, D) values or (P, H, page) scales; ``phys``,
+    ``within``: (B,) int32 — the physical page and the row inside it
+    of each batch row's new token; ``rows``: (B, H, D) / (B, H).
+
+    The head index is WRITTEN OUT so that the three scattered
+    dimensions (page, head, row) are the pool's leading ones and the
+    window is ``D`` alone.  ``pool.at[phys, :, within]`` names the
+    same elements, but its window dimension (the heads) lies between
+    the two scattered ones; XLA's TPU scatter wants windows minor, so
+    it copied the whole pool into another layout and back around the
+    scatter — two pool-sized copies a pool a layer a step, although
+    the donated buffer was aliased (PERF.md, PR 29).  Duplicate
+    targets are allowed (masked rows all land in the trash page), so
+    the scatter does not promise unique indices.
+    """
+    heads = jnp.arange(pool.shape[1])
+    return pool.at[phys[:, None], heads[None, :], within[:, None]].set(
+        rows.astype(pool.dtype))
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class PagedKVCache:

@@ -29,7 +29,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from triton_distributed_tpu.models.kv_cache import KVCache, PagedKVCache
+from triton_distributed_tpu.models.kv_cache import (
+    KVCache, PagedKVCache, write_token_rows)
 
 
 @dataclasses.dataclass
@@ -194,10 +195,14 @@ class ToyModel:
             within = offset % ps
             if cache.quantized:
                 kq, vq, ksn, vsn = _quantize_token(k, v)
-                ks = cache.ks[0].at[phys, :, within, :].set(kq[:, :, 0])
-                vs = cache.vs[0].at[phys, :, within, :].set(vq[:, :, 0])
-                kss = cache.kss[0].at[phys, :, within].set(ksn[:, :, 0])
-                vss = cache.vss[0].at[phys, :, within].set(vsn[:, :, 0])
+                ks = write_token_rows(cache.ks[0], phys, within,
+                                      kq[:, :, 0])
+                vs = write_token_rows(cache.vs[0], phys, within,
+                                      vq[:, :, 0])
+                kss = write_token_rows(cache.kss[0], phys, within,
+                                       ksn[:, :, 0])
+                vss = write_token_rows(cache.vss[0], phys, within,
+                                       vsn[:, :, 0])
                 cache = dataclasses.replace(
                     cache, ks=[ks], vs=[vs], kss=[kss], vss=[vss])
                 kseq = ks[cache.page_table]   # (B, T, Hkv, page, H)
@@ -209,10 +214,10 @@ class ToyModel:
                 vf = (vseq.astype(jnp.float32)
                       * vsseq[..., None])
             else:
-                ks = cache.ks[0].at[phys, :, within, :].set(
-                    k[:, None, :])
-                vs = cache.vs[0].at[phys, :, within, :].set(
-                    v[:, None, :])
+                ks = write_token_rows(cache.ks[0], phys, within,
+                                      k[:, None, :])
+                vs = write_token_rows(cache.vs[0], phys, within,
+                                      v[:, None, :])
                 cache = dataclasses.replace(cache, ks=[ks], vs=[vs])
                 kf = ks[cache.page_table]
                 vf = vs[cache.page_table]
