@@ -22,8 +22,9 @@ Usage::
     python scripts/gen_metrics_reference.py --check    # drift gate
 
 ``--check`` exits 1 when the committed table differs from the
-scraped one — wired into `scripts/verify_tier1.sh` so the reference
-cannot drift from the code.
+scraped one — tier-1 runs it as a test
+(`tests/test_tooling.py::test_static_gate_passes[metrics_reference]`)
+so the reference cannot drift from the code.
 """
 
 from __future__ import annotations

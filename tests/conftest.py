@@ -36,8 +36,8 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "slow: multi-process/subprocess tests excluded from the "
-        "tier-1 `-m 'not slow'` sweep (covered by the NET_SMOKE "
-        "gate instead)")
+        "tier-1 `-m 'not slow'` sweep (run them by hand: "
+        "`pytest -m slow`)")
 
 
 @pytest.fixture(scope="session")

@@ -1,0 +1,55 @@
+"""Tier-1 guards the benchmark the driver reads.
+
+`cellbench/tests` lies outside `pytest tests/`, so the yardstick's own
+unit tests broke unseen (one has been red since PR 28).  This file
+brings the cases of its seven subprocess-free files into tier-1, each
+under its own id (`test_<file>__<case>`): the test functions and the
+fixtures they ask for are imported, nothing is copied and nothing
+under `cellbench/` is edited.
+
+Left out:
+
+- `test_rehearse*.py`, `test_reference.py`: subprocess runs of
+  `cellbench/run.py`, minutes each; still by hand
+  (`python -m pytest cellbench/tests`).
+- `test_span_readers.py::test_the_benchmark_names_the_six_readers_and_the_new_cell`:
+  red on main since PR 28 appended per-layer metrics — it pins
+  `names[-6:]` of `per_layer` (PERF.md §7 (d)).  The next `benchmark`
+  PR makes it compare by membership and takes the name out of
+  `KNOWN_RED`; a PR of another kind may not edit `cellbench/`.
+"""
+
+import importlib
+import inspect
+import os
+import sys
+
+import pytest
+from _pytest.fixtures import getfixturemarker
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+FILES = ("test_model_math", "test_model_math_glm4_moe_lite", "test_stats",
+         "test_traffic_gen", "test_trace_reduce", "test_span_readers",
+         "test_trace_bound")
+#: ids of this file's, see the docstring
+KNOWN_RED = {
+    "test_span_readers__the_benchmark_names_the_six_readers_and_the_new_cell",
+}
+
+for _file in FILES:
+    _name = f"cellbench.tests.{_file}"
+    pytest.register_assert_rewrite(_name)
+    for _attr, _obj in vars(importlib.import_module(_name)).items():
+        if getattr(_obj, "__module__", None) != _name:
+            continue
+        _id = f"{_file}__{_attr.removeprefix('test_')}"
+        if _attr.startswith("test_") and inspect.isfunction(_obj):
+            if _id not in KNOWN_RED:
+                globals()[_id] = _obj
+        elif getfixturemarker(_obj) is not None:
+            # a fixture the file's tests ask for by name
+            assert _attr not in globals(), f"two fixtures named {_attr}"
+            globals()[_attr] = _obj

@@ -756,8 +756,8 @@ class TestDoctorDecisions:
 
     def test_golden_corpus_unchanged(self):
         # The committed incident corpus has no decisions artifact:
-        # its reports must not grow the key (the byte-identical gate
-        # verify_tier1.sh also runs).
+        # its reports must not grow the key (the goldens themselves
+        # are compared in test_doctor.py::test_matches_golden).
         from triton_distributed_tpu.observability.doctor import (
             diagnose)
         base = os.path.join(os.path.dirname(__file__), "data",

@@ -23,7 +23,6 @@ which a bfloat16 router is checked to break.
 """
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -41,8 +40,7 @@ from triton_distributed_tpu.layers.moe_mlp import MOE_STATS, SparseMoE
 from triton_distributed_tpu.models import AutoLLM, ModelConfig, Qwen3
 from triton_distributed_tpu.models.glm4_moe_lite import Glm4MoeLite
 from triton_distributed_tpu.models.kv_cache import KVCache, PagedKVCache
-from triton_distributed_tpu.serving import (
-    ContinuousBatchingScheduler, Request, SchedulerConfig)
+from triton_distributed_tpu.serving import Request
 from triton_distributed_tpu.serving.engine_batched import (
     pad_prompt, pick_bucket)
 from triton_distributed_tpu.serving.pages import PagedKV

@@ -495,21 +495,6 @@ def test_serving_metrics_and_spans(toy, tmp_path, monkeypatch):
                for e in merged["traceEvents"]) == 4
 
 
-def test_bench_serving_schedule_is_deterministic():
-    import importlib
-    bench = importlib.import_module("benchmark.bench_serving")
-    a = bench.make_schedule(7, 16, 100.0, (8, 16), 31)
-    b = bench.make_schedule(7, 16, 100.0, (8, 16), 31)
-    assert a == b                      # seeded: no wall-clock randomness
-    assert len(a) == 16
-    assert all(len(p) in (8, 16) for _, p, _ in a)
-    arrivals = [t for t, _, _ in a]
-    assert arrivals == sorted(arrivals)
-    assert bench.useful_len([5, 6, 3, 9], eos=3) == 3
-    assert bench.useful_len([5, 6], eos=3) == 2
-    assert bench.useful_len([3], eos=3) == 1
-
-
 def test_observability_disabled_still_serves(toy, monkeypatch):
     monkeypatch.setenv("TDT_OBSERVABILITY", "0")
     model, params = toy

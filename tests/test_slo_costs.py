@@ -1,5 +1,5 @@
 """SLO error budgets + per-tenant cost accounting + time-series
-retention + the capacity planner (ISSUE 16).
+retention (ISSUE 16).
 
 The load-bearing assertions:
 
@@ -13,8 +13,6 @@ The load-bearing assertions:
   rows in the lineage artifact.
 - **Burn alerts are schema-v1 DecisionEvents.**  Edge-triggered, one
   per class per excursion, valid under ``validate_decision``.
-- **Determinism.**  The planner's full sweep is byte-identical
-  across runs (virtual clock + seeded trace).
 """
 
 import dataclasses
@@ -511,45 +509,6 @@ class TestClusterSLO:
         assert not any(n.startswith("timeseries-") for n in names)
         report = diagnose([str(tmp_path)])
         assert "slo" not in report and "timeseries" not in report
-
-
-# ---------------------------------------------------------------------------
-# Capacity planner
-# ---------------------------------------------------------------------------
-
-class TestPlanner:
-    def test_build_trace_is_seed_deterministic(self):
-        from triton_distributed_tpu.observability.planner import (
-            build_trace)
-        a = build_trace(8, seed=7, rate_multiplier=2.0)
-        b = build_trace(8, seed=7, rate_multiplier=2.0)
-        assert a == b
-        assert build_trace(8, seed=8) != a
-        assert {t["tenant"] for t in a} == {"web", "batch"}
-        # Doubling the rate halves every interarrival gap exactly.
-        slow = build_trace(8, seed=7, rate_multiplier=1.0)
-        assert all(f["arrival_time"] <= s["arrival_time"]
-                   for f, s in zip(a, slow))
-
-    def test_plan_is_byte_deterministic_and_never_arms_costs(
-            self, toy):
-        from triton_distributed_tpu.observability.planner import (
-            default_policy, plan)
-        model, params = toy
-        kw = dict(policy=default_policy(), replicas_max=2,
-                  rates=(1.0,), n_requests=12, seed=7)
-        first = plan(model, params, **kw)
-        again = plan(model, params, **kw)
-        assert (json.dumps(first, sort_keys=True)
-                == json.dumps(again, sort_keys=True))
-        rate = first["rates"][0]
-        assert rate["feasible"] is True
-        assert rate["deterministic"] is True
-        assert rate["cells"][-1]["finished"] == 12
-        # The planner is a pure what-if: replays score via
-        # evaluate_outcomes, never the global cost/SLO state.
-        assert not cost_accounting_enabled()
-        assert len(get_cost_recorder()) == 0
 
 
 # ---------------------------------------------------------------------------

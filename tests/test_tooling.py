@@ -2,9 +2,13 @@
 (reference: autotuner docs/tests, `test_fast_allgather.py`)."""
 
 import functools
+import pathlib
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from triton_distributed_tpu.autotuner import (
@@ -247,3 +251,21 @@ def test_collective_disk_hit_adopts_with_nan_sentinel(monkeypatch):
     assert entry.config == "cfgB"
     assert math.isnan(entry.time_s)
     assert entry.ranking == []
+
+
+REPO = pathlib.Path(__file__).resolve().parent.parent
+
+
+@pytest.mark.parametrize("script", [
+    pytest.param(["scripts/lint.py"], id="lint"),
+    pytest.param(["scripts/gen_metrics_reference.py", "--check"],
+                 id="metrics_reference"),
+])
+def test_static_gate_passes(script):
+    """The two static gates no other test holds: the tree lints clean,
+    and the metrics table of docs/observability.md matches the
+    registry call sites.  Both are stdlib-only scripts (no JAX), run
+    from the repo root as a user would."""
+    done = subprocess.run([sys.executable, *script], cwd=REPO,
+                          capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stdout[-2000:] + done.stderr[-2000:]

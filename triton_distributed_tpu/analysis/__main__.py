@@ -22,8 +22,8 @@ duplicate / corrupt / crash / staleness over a small scope), or
 ``all``.
 
 Exit status: 0 = no findings, 1 = findings, 2 = usage error.
-`scripts/verify_tier1.sh` runs the comm + resources sweeps and the
-serving model check as tier-1 gates.
+Tier-1 runs the comm + resources sweeps and the serving model check
+as tests (`tests/test_analysis.py`, `test_resources`, `test_serving_model`).
 """
 
 from __future__ import annotations

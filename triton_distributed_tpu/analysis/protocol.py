@@ -2,7 +2,7 @@
 
 `analysis.protocol_model` is the engine (one exhaustive exploration
 of one `ProtocolScope`); this module fixes the MATRIX the CLI, the
-tier-1 gate (`PROTOCOL_CHECK` in ``scripts/verify_tier1.sh``) and
+tier-1 tests (``tests/test_protocol_analysis.py``) and
 the doctor's protocol consult all share: both transport contracts
 (in-process `VirtualTransport` and the `SocketTransport`+`WireHost`
 networked claim/partition discipline), flat and hierarchical

@@ -7,7 +7,7 @@ kernel body and its ref/semaphore layout — the same information the
 module's `pl.pallas_call` site encodes in `out_shape`/`scratch_shapes`.
 The CLI (`python -m triton_distributed_tpu.analysis`) sweeps every
 registered kernel across its representative mesh shapes and fails on
-any finding; `scripts/verify_tier1.sh` runs that sweep as a gate.
+any finding; tier-1 runs that sweep (`tests/test_analysis.py`).
 
 Keeping the hook next to the `pallas_call` site is deliberate: when a
 kernel's scratch layout changes, the spec that the sanitizer replays

@@ -66,12 +66,12 @@ class ContextualAutotuner:
         #: (unjitted) fn retraces on EVERY chained call — pure Python
         #: tracing that drowns a ~40 µs kernel by orders of
         #: magnitude.  Off by default only because
-        #: some callers (bench.py) pass pre-jitted thunks.
+        #: some callers pass pre-jitted thunks.
         self.jit_configs = jit_configs
         self._config_jits = {}
         #: With ``jit_configs`` + a ``chain``, each timing sample runs
         #: ``scan_inner`` chained iterations inside ONE jitted
-        #: `lax.scan` (the `measure_ops_scanned` methodology): ops
+        #: `lax.scan` (one dispatch for the whole chain): ops
         #: under ~150 µs CANNOT be ranked by per-dispatch chains — the
         #: host's dispatch floor dominates and the tuner picks noise.
         self.scan_inner = 16

@@ -415,15 +415,15 @@ def _packed_schedule(nq: int, nk: int, bq: int, bk: int, off: int,
 def flash_attention_config_space(sq: int, sk: int):
     """(block_q, block_k[, diag_sub]) candidates for the contextual
     autotuner (reference: the `triton.Config` spaces its
-    `contextual_autotune` sweeps, `autotuner.py:95-101`).  The
-    measured hand sweep (docs/performance.md) found 1024×1024 optimal
+    `contextual_autotune` sweeps, `autotuner.py:95-101`).  A hand
+    sweep on the chip before the ledger found 1024×1024 optimal
     at S ≥ 4096 — the tuner re-derives that per shape and persists it.
     3-component entries pin the block-triangular diagonal sub-tile:
     2-tuples keep the 256 heuristic, `sub == bq` is the dense-masked
     single-matmul form — the tuner weighs masked-FLOP savings against
     MXU tile efficiency per shape (at S=1024 the 256 heuristic's ten
-    small matmuls measured NO faster than the dense tile; see
-    docs/performance.md)."""
+    small matmuls measured NO faster than the dense tile, in the
+    same pre-ledger sweep)."""
     cands = [(1024, 1024), (2048, 1024), (1024, 512), (512, 1024),
              (512, 512), (2048, 2048), (256, 256),
              (1024, 1024, 512), (1024, 1024, 1024),

@@ -137,9 +137,9 @@ def quantize_kv(k, v):
 
 def flash_decode_config_space(s: int):
     """block_k candidates for the contextual autotuner — the KV block
-    length trades DMA granularity against grid bookkeeping (the hand
-    sweep in docs/performance.md picked 4096; the tuner re-derives it
-    per shape and persists it)."""
+    length trades DMA granularity against grid bookkeeping (a hand
+    sweep on the chip before the ledger picked 4096; the tuner
+    re-derives it per shape and persists it)."""
     out = [bk for bk in (1024, 2048, 4096, 8192) if bk <= s]
     return out or [s]
 

@@ -236,7 +236,7 @@ def grouped_matmul_w8a8(a_q, b_q, scale_a, scale_b, config=None,
     per-output-channel.  The int8 path doubles both the MXU ceiling
     AND the weight-streaming roofline — the binding resource at MoE
     decode shapes (E=64/cap=128 measured 65 TFLOP/s weight-bound in
-    bf16, docs/performance.md; VERDICT r4 weak #5): expert weights are
+    bf16, a kernel sweep from before the ledger): expert weights are
     half the bytes.  The reference stops at fp8 *payloads*
     (`kernels/nvidia/low_latency_all_to_all.py`); its grouped GEMM
     (`moe_reduce_rs.py:1003`) is half-precision only.

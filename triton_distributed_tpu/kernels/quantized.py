@@ -4,8 +4,8 @@ The reference is bf16/fp16-only for GEMMs (fp8 appears only as an
 AllToAll payload format, `kernels/nvidia/low_latency_all_to_all.py`).
 On TPU v5e the MXU's int8 path doubles peak throughput (394 TOPS vs
 197 TFLOP/s bf16), so a quantized-inference path is a genuine win:
-the kernel below measures 326 TOPS at 4096³ (83% of int8 peak,
-1.66× the bf16 peak; see docs/performance.md) with the
+the kernel below measured 326 TOPS at 4096³ (83% of int8 peak,
+1.66× the bf16 peak; a kernel sweep from before the ledger) with the
 (512, 1024, 4096) default blocks — int8 tiles are half the bytes, so
 the winning configs run K-deep.
 

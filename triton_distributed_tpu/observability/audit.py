@@ -89,8 +89,8 @@ def format_report(rows: List[AuditRow],
 
 
 # ---------------------------------------------------------------------------
-# Bench integration: one helper gives BENCH JSON lines and
-# benchmark/results/*.json the same registry-backed schema.
+# Bench integration: one helper gives a bench driver's JSON lines
+# the registry-backed schema (no driver is left in the tree: PERF.md).
 # ---------------------------------------------------------------------------
 
 #: bench name -> (op, fields needed to re-derive a model estimate).
@@ -149,15 +149,15 @@ def bench_record(rec: dict, *, print_line: bool = True) -> dict:
     ``rec`` is the driver's JSON-line dict (must carry "bench" and a
     measured "us"); the estimate/deviation are attached when the
     bench maps onto a perf model, the event lands in the recorder and
-    metrics, and the (augmented) line is printed — so stdout, the
-    committed benchmark/results/*.json and the registry export all
+    metrics, and the (augmented) line is printed — so stdout
+    and the registry export
     carry the same record.
 
     ``samples_us`` (optional, consumed): per-iteration latencies.
     Each lands in the ``bench_iteration_us{bench=...}`` registry
     histogram, and the line gains ``p50_us``/``p99_us`` — tails, not
-    just the mean, so `scripts/check_bench_regression.py` can gate on
-    p99 (a kernel that got jittery without moving its median).
+    just the mean, so a reader can tell
+    a kernel that got jittery without moving its median.
     """
     import json
 

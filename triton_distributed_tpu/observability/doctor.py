@@ -32,7 +32,7 @@ Usage::
 ``scripts/launch.py`` invokes it automatically when the watchdog fires
 (exit 124) or a rank exits nonzero.  Reports are deterministic given
 the artifacts ("now" is the newest artifact timestamp, not the wall
-clock), so golden reports can gate CI (`scripts/verify_tier1.sh`).
+clock), so golden reports can gate CI (`tests/test_doctor.py`).
 
 Exit status: 0 report written, 2 usage/no artifacts, 3 golden drift.
 """

@@ -478,7 +478,7 @@ def ttft_breakdown(events, arrival: Optional[float] = None,
     Fraction→float conversion both round the same exact value), and —
     when the caller supplies them — that ``t0`` equals the request's
     ``arrival`` and the total equals its ``measured_ttft``.  This is
-    the invariant the bench gate and the LINEAGE_SMOKE enforce on
+    the invariant `tests/test_lineage.py` enforces on
     every request."""
     evs = sorted(events, key=_ts_of)
     if not evs:

@@ -129,7 +129,7 @@ def evaluate_outcomes(policy: SLOPolicy,
                       ) -> Dict[str, dict]:
     """Batch compliance for a finished trace: ``outcomes`` are
     ``(tenant, ttft_ms, tbt_ms)`` tuples.  Returns per-class
-    compliance + nearest-rank p99s — the planner's scoring function,
+    compliance + nearest-rank p99s — a pure scoring function,
     deterministic given its inputs."""
     per: Dict[str, dict] = {}
     for c in policy.classes:

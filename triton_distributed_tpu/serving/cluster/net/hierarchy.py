@@ -24,8 +24,8 @@ so a big cell is not penalized for having more members.
 
 Affinity composes across the levels: the front door keys a
 prefix -> home-CELL map (bounded LRU), the cell router keys its own
-prefix -> home-REPLICA map, both written at route COMMIT only.  The
-bench (`benchmark/bench_router.py`, ``hierarchical`` row) pins the
+prefix -> home-REPLICA map, both written at route COMMIT only.
+`tests/test_net.py::TestHierarchy` pins the
 O(cell) claims: per-request score evaluations and per-cell directory
 size must stay flat as the pod grows.
 """

@@ -31,7 +31,7 @@ Backpressure is at `submit`: a bounded queue and static feasibility
 checks reject with a typed reason instead of queueing unservable work.
 
 Time comes from an injectable ``clock`` (+ optional ``clock_advance``
-for virtual time), so tests and `benchmark/bench_serving.py` replay
+for virtual time), so tests and the cluster's virtual clock replay
 deterministic arrival schedules.  Request-level observability rides
 the PR-1/2 stack: TTFT / TBT / queue-wait histograms, queue-depth /
 slot-occupancy / KV-budget gauges (all in the Prometheus export), one
