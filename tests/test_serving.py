@@ -233,21 +233,21 @@ def test_reject_prompt_too_long_and_kv_capacity(toy):
 def test_capacity_boundary_request_gets_full_length(toy):
     """A request sized exactly to the KV horizon (prompt + max_new ==
     max_seq + 1: the final token needs no KV write) must deliver every
-    promised token and finish LENGTH, not KV_CAPACITY — in both
-    single-step and block mode."""
+    promised token and finish LENGTH, not KV_CAPACITY: its last step
+    is known to be its last before it is read, and no step is
+    dispatched past the horizon."""
     model, params = toy
-    for k in (1, 4):
-        sched, _ = make_sched(model, params, max_seq=16,
-                              prefill_buckets=(8,), steps_per_sync=k)
-        req = Request(prompt=[1, 2, 3, 4], max_new_tokens=13)
-        assert sched.submit(req), req.reject_reason
-        sched.drain()
-        assert req.finish_reason == FinishReason.LENGTH, (
-            k, req.finish_reason, len(req.generated))
-        assert len(req.generated) == 13
-        over = Request(prompt=[1, 2, 3, 4], max_new_tokens=14)
-        assert not sched.submit(over)
-        assert over.reject_reason == RejectReason.EXCEEDS_KV_CAPACITY
+    sched, _ = make_sched(model, params, max_seq=16,
+                          prefill_buckets=(8,))
+    req = Request(prompt=[1, 2, 3, 4], max_new_tokens=13)
+    assert sched.submit(req), req.reject_reason
+    sched.drain()
+    assert req.finish_reason == FinishReason.LENGTH, (
+        req.finish_reason, len(req.generated))
+    assert len(req.generated) == 13
+    over = Request(prompt=[1, 2, 3, 4], max_new_tokens=14)
+    assert not sched.submit(over)
+    assert over.reject_reason == RejectReason.EXCEEDS_KV_CAPACITY
 
 
 def test_eos_retirement(toy):
@@ -367,36 +367,6 @@ def test_continuous_matches_serial_greedy(toy):
     assert len(done) == 7
     for r, w in zip(sorted(done, key=lambda r: r.request_id), want):
         assert r.generated == w, (r.request_id, r.generated, w)
-
-
-def test_block_mode_matches_single_step(toy):
-    """steps_per_sync > 1 (multi-step scheduling) must emit the same
-    pre-EOS streams; post-EOS block tokens are discarded."""
-    model, params = toy
-    prompts = rand_prompts(5, seed=2)
-    outs = {}
-    for k in (1, 4):
-        sched, _ = make_sched(model, params, num_slots=2,
-                              steps_per_sync=k)
-        reqs = [Request(prompt=p, max_new_tokens=6,
-                        arrival_time=i * 0.01)
-                for i, p in enumerate(prompts)]
-        done = sched.run(reqs)
-        outs[k] = [r.generated for r in
-                   sorted(done, key=lambda r: r.request_id)]
-    assert outs[1] == outs[4]
-
-
-def test_block_mode_eos_discards_overshoot(toy):
-    model, params = toy
-    prompt = [11, 12, 13]
-    first = serial_reference(model, params, prompt, 1)[0]
-    sched, _ = make_sched(model, params, steps_per_sync=4)
-    req = Request(prompt=prompt, max_new_tokens=10,
-                  eos_token_ids=(first,))
-    sched.run([req])
-    assert req.finish_reason == FinishReason.EOS
-    assert req.generated == [first]   # block overshoot trimmed
 
 
 def test_sampling_independent_of_batch_composition(toy):

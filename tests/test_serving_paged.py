@@ -331,47 +331,10 @@ def test_mid_stream_page_allocation_boundary(toy):
         assert got == want, ps
         # pages grew past the prefill allocation: 14+10-1 positions
         assert sched.slots.pool.refs.sum() >= 0  # bookkeeping intact
-    # block mode crosses the boundary inside one dispatch
-    _, got = run_layout(model, params, "paged", reqs, page_size=8,
-                        steps_per_sync=4)
+    # the smallest page: a boundary every other dispatch, each mapped
+    # while the step before it is still unread
+    _, got = run_layout(model, params, "paged", reqs, page_size=2)
     assert got == want
-
-
-def test_block_overgeneration_stays_within_budgeted_pages(toy):
-    """Regression: a block dispatch over-generates up to k-1 positions
-    past a request's own horizon (prompt + max_new - 1); those writes
-    must fall into the NULL page, not demand pages feasible() never
-    budgeted.  Pool of exactly the horizon's 2 pages, steps_per_sync=8
-    crossing the horizon mid-block: pre-fix this crashed the
-    sole-request allocator-invariant assert."""
-    model, params = toy
-    prompt = [1 + i % 50 for i in range(24)]
-
-    def reqs():
-        return [Request(prompt=prompt, max_new_tokens=9)]
-
-    _, want = run_layout(model, params, "slots", reqs)
-    sched, got = run_layout(model, params, "paged", reqs,
-                            num_pages=2, steps_per_sync=8)
-    assert got == want
-    assert sched.finished[0].finish_reason == FinishReason.LENGTH
-    assert len(sched.finished[0].generated) == 9
-
-
-def test_paged_block_mode_matches_single_step(toy):
-    model, params = toy
-    prompts = rand_prompts(5, seed=2)
-
-    def reqs():
-        return [Request(prompt=p, max_new_tokens=6,
-                        arrival_time=i * 0.01)
-                for i, p in enumerate(prompts)]
-
-    outs = {}
-    for k in (1, 4):
-        _, outs[k] = run_layout(model, params, "paged", reqs,
-                                num_slots=2, steps_per_sync=k)
-    assert outs[1] == outs[4]
 
 
 # ---------------------------------------------------------------------------
@@ -505,15 +468,14 @@ def test_paged_capacity_boundary_full_length(toy):
     max_seq + 1 delivers every token (the final token needs no KV
     write)."""
     model, params = toy
-    for k in (1, 4):
+    for ps in (4, 16):
         sched, _ = make_sched(model, params, "paged", max_seq=16,
-                              prefill_buckets=(8, 16),
-                              steps_per_sync=k)
+                              prefill_buckets=(8, 16), page_size=ps)
         req = Request(prompt=[1, 2, 3, 4], max_new_tokens=13)
         assert sched.submit(req), req.reject_reason
         sched.drain()
         assert req.finish_reason == FinishReason.LENGTH, (
-            k, req.finish_reason)
+            ps, req.finish_reason)
         assert len(req.generated) == 13
         over = Request(prompt=[1, 2, 3, 4], max_new_tokens=14)
         assert not sched.submit(over)
@@ -593,6 +555,8 @@ def _by_name(spans):
 
 @pytest.mark.parametrize("layout", ["paged", "slots"])
 def test_one_step_leaves_the_phase_spans(toy, tracer, layout):
+    """The first iteration admits and dispatches; the second
+    dispatches the next step and THEN reads and commits the first."""
     model, params = toy
     sched, _ = make_sched(model, params, layout)
     prompts = rand_prompts(2, seed=3)
@@ -606,9 +570,13 @@ def test_one_step_leaves_the_phase_spans(toy, tracer, layout):
     assert step.parent is None
     assert step.attrs == {"step": 1, "admitted": 2, "active": 2,
                           "retired": 0}
-    expected = [p for p in STEP_PHASES
-                if layout == "paged" or p != "serving.pages"]
-    for name in expected:
+    # nothing was in flight: nothing to read or commit yet
+    first = [p for p in STEP_PHASES
+             if p not in ("serving.sync", "serving.commit")
+             and (layout == "paged" or p != "serving.pages")]
+    assert {s.name for s in tracer.finished()
+            if s.parent == step.id} == set(first)
+    for name in first:
         (sp,) = spans[name]
         assert sp.parent == step.id, name
     (admit,) = spans["serving.admit"]
@@ -632,18 +600,30 @@ def test_one_step_leaves_the_phase_spans(toy, tracer, layout):
     assert [s.attrs["request_id"] for s in lives] == [
         r.request_id for r in reqs]
     (dispatch,) = spans["serving.dispatch"]
-    assert dispatch.attrs == {"k": 1, "spec": False}
-    (commit,) = spans["serving.commit"]
-    assert commit.attrs == {"tokens": 2, "retired": 0}
+    assert dispatch.attrs == {"k": 1, "spec": False, "inflight": 0}
     if layout == "paged":
         (pages,) = spans["serving.pages"]
         assert pages.attrs["preempted"] == 0
         assert pages.attrs["flushed_rows"] == sched.config.num_slots
         assert pages.attrs["live_pages"] == sched.slots.live_pages > 0
-    # a later step with nothing to admit has no admit span
+    assert all(r.generated == [] for r in reqs) and sched.has_work()
+    # the second iteration: no admit span; dispatch, then the read
     tracer.clear()
-    sched.step()
-    assert "serving.admit" not in _by_name(tracer.finished())
+    out = sched.step()
+    assert out == {"admitted": 0, "active": 2, "retired": 0}
+    spans = _by_name(tracer.finished())
+    (step,) = spans["serving.step"]
+    assert "serving.admit" not in spans
+    order = [s.name for s in sorted(tracer.finished(),
+                                    key=lambda s: s.t0)
+             if s.parent == step.id]
+    assert order == [p for p in STEP_PHASES[1:-1]
+                     if layout == "paged" or p != "serving.pages"]
+    (dispatch,) = spans["serving.dispatch"]
+    assert dispatch.attrs == {"k": 1, "spec": False, "inflight": 1}
+    (commit,) = spans["serving.commit"]
+    assert commit.attrs == {"tokens": 2, "retired": 0, "discarded": 0}
+    assert all(len(r.generated) == 1 for r in reqs)
     sched.drain()
 
 
@@ -700,7 +680,9 @@ def test_request_span_survives_out_of_order_retirement(toy, tracer,
                         for s in tracer.open_spans()
                         if s.name == "serving.request"]
     assert open_ids() == [r.request_id for r in reqs]
-    sched.step()
+    sched.step()        # dispatches step 2, reads step 1
+    assert open_ids() == [r.request_id for r in reqs]
+    sched.step()        # reads step 2: the shortest has its two tokens
     assert open_ids() == [reqs[0].request_id, reqs[1].request_id]
     sched.drain()
     assert tracer.open_spans() == []

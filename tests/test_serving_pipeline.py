@@ -1,0 +1,647 @@
+"""The pipelined decode step: `step()` N dispatches step t+1 before it
+reads step t's tokens.  CPU-only, deterministic, tier-1.
+
+Two halves.
+
+**No program and no kind of argument is first met after set-up.**  The
+benchmark's OWN `cellbench.run.warm_up` runs against a `System`-shaped
+object (the benchmark's adapters at test size, for both model classes,
+and a toy model), and then — under a `jax.monitoring` listener like
+`cellbench.run.CompileCounters`, and comparing `_cache_size()` of every
+jitted program of the step path before and after — the scheduler is
+driven through the transitions `qwen3-8b-1c.longprompt-steady` has and
+the benchmark's CPU rehearsal lacks: it runs dry and restarts, requests
+are admitted into an empty batch with and without a step in flight, one
+row retires beside one that goes on, two retire in one step, a lone row
+crosses page boundaries, every bucket of the mix comes after an idle
+period.  Nothing may compile and no program may gain a cache entry.
+
+**What the mechanism means**: tokens are delivered one call after
+their dispatch and `has_work()` says so; the streams are the serial
+goldens' whatever happens to a step in flight (EOS seen one step late,
+a preemption, an admission beside a retirement); the counters, the
+`inflight=` attribute and the span tree say what happened.
+"""
+
+import json
+import os
+import sys
+import time
+
+import jax
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from cellbench import run as cellrun                      # noqa: E402
+from triton_distributed_tpu.serving import (             # noqa: E402
+    ContinuousBatchingScheduler,
+    FinishReason,
+    Request,
+    SchedulerConfig,
+    ToyConfig,
+    ToyModel,
+)
+from triton_distributed_tpu.serving.speculative import Drafter  # noqa: E402
+
+COMPILED = "/jax/core/compile/backend_compile_duration"
+
+
+# ---------------------------------------------------------------------------
+# helpers
+# ---------------------------------------------------------------------------
+
+class Clock:
+    def __init__(self):
+        self.t = 0.0
+
+    def now(self):
+        return self.t
+
+    def advance(self, dt):
+        self.t += dt
+
+
+@pytest.fixture(scope="module")
+def toy():
+    model = ToyModel(ToyConfig(vocab_size=61, hidden=16, max_seq_len=64))
+    return model, model.init_params(jax.random.key(0))
+
+
+def make_sched(model, params, layout="paged", **kw):
+    kw.setdefault("num_slots", 3)
+    kw.setdefault("prefill_buckets", (8, 16, 32, 64))
+    kw.setdefault("page_size", 8)
+    ck = Clock()
+    return ContinuousBatchingScheduler(
+        model, params, SchedulerConfig(kv_layout=layout, **kw),
+        clock=ck.now, clock_advance=ck.advance)
+
+
+def rand_prompts(n, vocab=61, seed=0, lo=3, hi=20):
+    rng = np.random.default_rng(seed)
+    return [list(map(int, rng.integers(1, vocab, rng.integers(lo, hi))))
+            for _ in range(n)]
+
+
+def golden(model, params, prompt, n, seed=0, **kw):
+    """The stream of one request served alone by a speculating
+    scheduler whose drafter never proposes: the SERIAL step (dispatch,
+    read, commit in one call) of the same programs."""
+    sched = make_sched(model, params, num_slots=1, spec_k=2,
+                       spec_drafter=lambda s: _NoDrafts(), **kw)
+    req = Request(prompt=prompt, max_new_tokens=n, seed=seed)
+    sched.run([req])
+    assert sched._flight is None
+    return req.generated
+
+
+class _NoDrafts(Drafter):
+    """A drafter that never proposes: every dispatch of its scheduler
+    is the plain masked step, read before the next."""
+    name = "none"
+
+    def _propose(self, req, k):
+        return []
+
+
+@pytest.fixture
+def metrics():
+    from triton_distributed_tpu.observability import get_registry
+    reg = get_registry()
+    reg.clear()
+    yield reg
+    reg.clear()
+
+
+@pytest.fixture
+def tracer():
+    from triton_distributed_tpu.observability import get_tracer
+    tr = get_tracer()
+    tr.clear()
+    yield tr
+    tr.clear()
+
+
+def counter(reg, name):
+    return sum(v for k, v in reg.snapshot()["counters"].items()
+               if k.split("{")[0] == name)
+
+
+# ---------------------------------------------------------------------------
+# 1. nothing is first met after set-up
+# ---------------------------------------------------------------------------
+
+class ToySystem:
+    """What `cellbench.run.warm_up` and `settle` ask of a system, over
+    the toy model: the adapters' surface, none of their weights."""
+
+    def __init__(self):
+        self.model = ToyModel(ToyConfig(vocab_size=61, hidden=16,
+                                        max_seq_len=128))
+        self.params = self.model.init_params(jax.random.key(0))
+        self.config = {"vocab_size": 61}
+        self.num_slots, self.max_seq = 8, 128
+        self.sched = ContinuousBatchingScheduler(
+            self.model, self.params,
+            SchedulerConfig(num_slots=8, max_seq=128, kv_layout="paged",
+                            page_size=8, num_pages=96, max_queue=64),
+            clock=time.monotonic)
+        self.buckets = self.sched.buckets
+        self.page_size = self.sched.slots.page_size
+
+    def submit(self, prompt, max_new, due, on_token):
+        req = Request(prompt, max_new, eos_token_ids=(), seed=0,
+                      arrival_time=due, on_token=on_token)
+        if self.sched.submit(req):
+            return req, None
+        return None, req.reject_reason.value
+
+    def step(self):
+        return self.sched.step()
+
+    def has_work(self):
+        return self.sched.has_work()
+
+
+def _qwen_system(devices, chips=1):
+    from cellbench.adapters import qwen3
+    # the cell's configuration at the rehearsal's sizes, as
+    # `cellbench/run.py --rehearse` overlays them
+    cfg = dict(
+        cellrun.load_json(ROOT, "cellbench", "configs", "qwen3-8b-1c.json"),
+        **cellrun.load_json(ROOT, "cellbench", "rehearsal", "config.json"))
+    return qwen3.System(cfg, 11, devices[:chips])
+
+
+def _glm_system(devices):
+    from cellbench.adapters import glm4_moe_lite
+    from tests.test_glm4_moe_lite import TINY
+    serving = dict(TINY["serving"], num_slots=8,
+                   kv_budget_bytes_per_chip=8 * 128 * 3 * 256 * 2)
+    return glm4_moe_lite.System(dict(TINY, serving=serving), 11,
+                                devices[:1])
+
+
+#: Slots for `warm_up` to admit both requests of every bucket in one
+#: call (1 + 2 x 3 buckets here), as every cell but the two of seven
+#: buckets on eight slots has: there an insert of the fourth bucket
+#: first meets a pool another insert returned in the window, on the
+#: parent as on this scheduler (PERF.md section 7).
+SYSTEMS = {"toy": lambda devices: ToySystem(),
+           "qwen3": _qwen_system, "glm4_moe_lite": _glm_system,
+           "qwen3-tp4": lambda devices: _qwen_system(devices, 4)}
+#: six minutes of interpreted ring kernels: by hand (`-m slow`)
+FAMILIES = [pytest.param(f, marks=pytest.mark.slow) if f == "qwen3-tp4"
+            else f for f in sorted(SYSTEMS)]
+
+
+class Plan:
+    """What `warm_up` reads of a traffic plan."""
+
+    def __init__(self, prompt_range, output_max):
+        self.prompt_range, self.output_max = prompt_range, output_max
+
+
+class Compiled:
+    """`cellbench.run.CompileCounters`' listener: every backend
+    compilation while `watch` is set, with what JAX says of it."""
+
+    def __init__(self):
+        self.watch, self.seen = False, []
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, event, secs, **kw):
+        if self.watch and event == COMPILED:
+            self.seen.append({k: str(v) for k, v in kw.items()})
+
+
+@pytest.fixture(scope="module")
+def compiled():
+    return Compiled()
+
+
+def programs(sched):
+    """Every jitted program of the step path, by name."""
+    out = {"merge": sched._merge, "step": sched._step,
+           "keep": sched._keep, "prefill": sched._prefill,
+           "insert": sched.slots._insert}
+    if sched._prefill_suffix is not None:
+        out["prefill_suffix"] = sched._prefill_suffix
+    return out
+
+
+def cache_sizes(sched):
+    return {name: fn._cache_size() for name, fn in programs(sched).items()}
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_no_program_and_no_kind_of_argument_is_first_met_after_warm_up(
+        family, devices, compiled):
+    system = SYSTEMS[family](devices)
+    sched = system.sched
+    ps = system.page_size
+    buckets = [b for b in system.buckets if b <= 64]
+    lo, hi = 3, buckets[-1]
+    warmed = cellrun.warm_up(system, Plan((lo, hi), 3 * ps), seed=5)
+    assert warmed["buckets"] == buckets and not system.has_work()
+    before = cache_sizes(sched)
+    assert before["merge"] >= 1 and before["step"] >= 1
+    rng = np.random.default_rng(17)
+    vocab = system.config["vocab_size"]
+    served = []
+
+    def send(plen, new):
+        handle, why = system.submit(
+            rng.integers(0, vocab, plen).tolist(), new, 0.0, None)
+        assert handle is not None, why
+        served.append((handle, new))
+        return handle
+
+    def run_dry():
+        while system.has_work():
+            system.step()
+        assert sched._flight is None
+
+    compiled.watch, compiled.seen = True, []
+    try:
+        # run dry and restart, several times: into an empty batch with
+        # no step in flight, a lone row crossing page boundaries
+        for n in (2 * ps + 3, 1, 2, ps + 1):
+            send(lo + 2, n)
+            run_dry()
+        # every bucket of the mix after an idle period, alone and two
+        # at once (the second insert takes the pool from an insert)
+        for b in buckets:
+            send(min(b, hi), 3)
+            run_dry()
+            send(min(b, hi), 2)
+            send(max(b // 2 + 1, lo), 4)
+            run_dry()
+        # admitted with a step in flight; one row retires while the
+        # other goes on; then the survivor alone; then a newcomer into
+        # the freed slot beside it
+        a = send(9, 2 * ps + 4)
+        system.step()
+        system.step()
+        assert sched._flight is not None
+        b = send(17, 3)
+        system.step()
+        assert sched._flight is not None and len(sched._by_slot) == 2
+        while b.finish_reason is None:
+            system.step()
+        assert a.finish_reason is None and sched._flight is not None
+        system.step()
+        c = send(5, 2)
+        while c.finish_reason is None:
+            system.step()
+        assert a.finish_reason is None
+        run_dry()
+        # two retire in one step beside one that goes on, then three
+        # admitted in one call with a step in flight
+        long = send(12, 3 * ps)
+        system.step()
+        x, y = send(7, 4), send(20, 4)
+        while x.finish_reason is None:
+            system.step()
+        assert y.finish_reason is not None and long.finish_reason is None
+        send(6, 2), send(6, 5), send(30, 1)
+        run_dry()
+        # the pipeline empties with rows still queued behind an arrival
+        # in the future: idle, then admitted into an empty batch
+        for i in range(3):
+            send(10 + i, 2)
+            system.step()
+        run_dry()
+    finally:
+        compiled.watch = False
+    assert compiled.seen == [], json.dumps(compiled.seen, indent=1)
+    assert cache_sizes(sched) == before
+    for handle, new in served:
+        assert handle.finish_reason == FinishReason.LENGTH
+        assert len(handle.generated) == new
+
+
+def test_the_first_dispatch_of_a_process_is_the_only_one_with_zeros(toy):
+    """From the second dispatch on the previous tokens are always what
+    the last dispatch returned — also across an idle period."""
+    model, params = toy
+    sched = make_sched(model, params)
+    seen, returned = [], [sched._prev]
+    merge, step = sched._merge, sched._step
+
+    def merging(prev, host, fresh):
+        seen.append((prev, host, fresh))
+        return merge(prev, host, fresh)
+
+    def stepping(*args):
+        out = step(*args)
+        returned.append(out[0])
+        return out
+    sched._merge, sched._step = merging, stepping
+    sched.run([Request(prompt=[1, 2, 3], max_new_tokens=3)])
+    assert not sched.has_work()
+    sched.run([Request(prompt=[4, 5, 6], max_new_tokens=2)])
+    assert len(seen) == 5
+    assert all(prev is was for (prev, _, _), was in zip(seen, returned))
+    # host tokens and masks: fresh numpy arrays of one shape and dtype
+    for _, host, fresh in seen:
+        assert type(host) is np.ndarray and host.dtype == np.int32
+        assert type(fresh) is np.ndarray and fresh.dtype == np.bool_
+        assert host.shape == fresh.shape == (3,)
+    assert len({id(h) for _, h, _ in seen}) == len(seen)
+    # the host's word counts where a row was admitted, nowhere else
+    assert [int(f.sum()) for _, _, f in seen] == [1, 0, 0, 1, 0]
+
+
+# ---------------------------------------------------------------------------
+# 2. what the mechanism means
+# ---------------------------------------------------------------------------
+
+def test_has_work_until_the_last_token_is_delivered(toy):
+    model, params = toy
+    sched = make_sched(model, params)
+    got = []
+    req = Request(prompt=[5, 6, 7], max_new_tokens=2,
+                  on_token=lambda r, t: got.append(t))
+    sched.submit(req)
+    assert sched.step() == {"admitted": 1, "active": 1, "retired": 0}
+    assert got == [] and sched.has_work()           # step 1 in flight
+    assert sched.step()["retired"] == 0
+    assert len(got) == 1 and sched.has_work()       # step 2 in flight
+    # the row ends by length: known before its last step is read, so
+    # nothing is dispatched for it — and there is still work
+    assert sched._flight is not None and sched._by_slot
+    out = sched.step()
+    assert out == {"admitted": 0, "active": 1, "retired": 1}
+    assert got == req.generated and len(got) == 2
+    assert not sched.has_work() and sched._flight is None
+    assert req.finish_reason == FinishReason.LENGTH
+    assert got == golden(model, params, [5, 6, 7], 2)
+
+
+def test_a_row_that_ends_by_length_runs_no_wasted_step(toy, metrics):
+    model, params = toy
+    sched = make_sched(model, params)
+    reqs = [Request(prompt=p, max_new_tokens=n)
+            for p, n in zip(rand_prompts(3, seed=4), (1, 4, 7))]
+    sched.run(reqs)
+    assert counter(metrics, "serving_tokens_generated_total") == 12
+    assert counter(metrics, "serving_decode_discarded_tokens_total") == 0
+    # 7 steps for the longest; all but the first overlapped
+    assert counter(metrics, "serving_decode_dispatch_total") == 7
+    assert counter(metrics, "serving_decode_overlapped_total") == 6
+
+
+@pytest.mark.parametrize("layout", ["paged", "slots"])
+def test_eos_is_seen_one_step_late_and_its_overshoot_discarded(
+        toy, metrics, layout):
+    """The step after the EOS ran for the row: its token is discarded
+    and counted, the stream is the golden's up to the EOS, and the
+    neighbour's stream is untouched."""
+    model, params = toy
+    prompt, other = [11, 12, 13], [3, 1, 4, 1, 5]
+    want = golden(model, params, prompt, 8)
+    want_mate = golden(model, params, other, 8)
+    eos = want[2]
+    cut = want[:want.index(eos) + 1]
+    metrics.clear()
+    sched = make_sched(model, params, layout)
+    req = Request(prompt=prompt, max_new_tokens=8, eos_token_ids=(eos,))
+    mate = Request(prompt=other, max_new_tokens=8)
+    sched.run([req, mate])
+    assert req.finish_reason == FinishReason.EOS
+    assert req.generated == cut
+    assert mate.generated == want_mate
+    assert counter(metrics, "serving_decode_discarded_tokens_total") == 1
+    assert counter(metrics, "serving_tokens_generated_total") == (
+        len(cut) + 8)
+
+
+def test_eos_overshoot_writes_nothing_outside_the_rows_own_pages(toy):
+    """The pool holds exactly the horizon's pages of the one request:
+    the step that ran past its EOS asked for no page more (its write
+    fell below the horizon), and a request admitted into the freed slot
+    and pages right after reads what the golden reads."""
+    model, params = toy
+    prompt = [1 + i % 50 for i in range(13)]
+    want = golden(model, params, prompt, 12)
+    eos = want[3]                                   # position 16: a new page
+    cut = want[:want.index(eos) + 1]
+    sched = make_sched(model, params, num_slots=1, num_pages=3,
+                       prefix_cache=False)
+    req = Request(prompt=prompt, max_new_tokens=12, eos_token_ids=(eos,))
+    nxt = Request(prompt=prompt[:9], max_new_tokens=10)
+    sched.run([req, nxt])
+    assert req.generated == cut
+    assert nxt.generated == golden(model, params, prompt[:9], 10)
+    assert sched.slots.used_pages == 0
+
+
+def test_a_slot_reused_while_its_old_rows_step_is_unread(toy, metrics):
+    """EOS frees the slot while the step that still ran for the old row
+    is in flight; the next call admits a newcomer into that slot and
+    dispatches it before that step is read: the old row's token is
+    discarded by identity, not by slot."""
+    model, params = toy
+    prompt = [9, 8, 7]
+    want = golden(model, params, prompt, 6)
+    eos = want[1]
+    sched = make_sched(model, params, num_slots=1)
+    old = Request(prompt=prompt, max_new_tokens=6, eos_token_ids=(eos,))
+    new = Request(prompt=[2, 4, 6, 8], max_new_tokens=4)
+    sched.submit(old)
+    sched.submit(new)
+    while old.finish_reason is None:
+        sched.step()
+    assert sched._flight is not None and 0 in sched._flight.rows
+    out = sched.step()
+    assert out["admitted"] == 1 and new.slot == 0
+    assert new.generated == []              # the old row's token: dropped
+    sched.drain()
+    assert old.generated == want[:want.index(eos) + 1]
+    assert new.generated == golden(model, params, [2, 4, 6, 8], 4)
+    assert counter(metrics, "serving_decode_discarded_tokens_total") == 1
+
+
+@pytest.mark.parametrize("temperature", [0.0, 1.0])
+def test_preemption_with_a_step_in_flight_resumes_exactly(
+        toy, metrics, temperature):
+    """The pool runs dry while a step is in flight: that step is read
+    first (the victim's committed tokens and its slot's key are then
+    exact), the newest request is preempted and later resumes its
+    stream and its sample chain bit for bit."""
+    model, params = toy
+    kw = dict(temperature=temperature)
+    prompts = rand_prompts(3, seed=9, lo=6, hi=8)
+    want = [golden(model, params, p, 20, seed=40 + i, **kw)
+            for i, p in enumerate(prompts)]
+    sched = make_sched(model, params, num_pages=7, prefix_cache=False,
+                       **kw)
+    reqs = [Request(prompt=p, max_new_tokens=20, seed=40 + i)
+            for i, p in enumerate(prompts)]
+    inflight_at_preempt = []
+    preempt = sched._preempt
+
+    def spy(slot):
+        inflight_at_preempt.append(sched._flight)
+        preempt(slot)
+    sched._preempt = spy
+    sched.run(reqs)
+    assert [r.generated for r in reqs] == want
+    assert sum(r.preemptions for r in reqs) >= 1
+    assert inflight_at_preempt and all(
+        f is None for f in inflight_at_preempt)
+    assert counter(metrics, "serving_preemptions_total") == len(
+        inflight_at_preempt)
+    assert counter(metrics, "serving_decode_discarded_tokens_total") == 0
+
+
+def test_admission_and_retirement_in_one_call(toy):
+    """One call admits a request (dispatching its first step) and reads
+    the step that retires another; each stream is its golden."""
+    model, params = toy
+    sched = make_sched(model, params, num_slots=2)
+    a = Request(prompt=[1, 2, 3, 4], max_new_tokens=2)
+    b = Request(prompt=[5, 6, 7], max_new_tokens=5)
+    sched.submit(a)
+    sched.step()
+    sched.step()
+    sched.submit(b)
+    out = sched.step()          # admits b, dispatches b alone, reads a's last
+    assert out == {"admitted": 1, "active": 2, "retired": 1}
+    assert a.finish_reason == FinishReason.LENGTH and b.generated == []
+    sched.drain()
+    assert a.generated == golden(model, params, [1, 2, 3, 4], 2)
+    assert b.generated == golden(model, params, [5, 6, 7], 5)
+
+
+def test_stop_drops_the_step_in_flight_unread(toy, metrics):
+    """An abort delivers no token; what was streamed is a prefix of
+    the golden, and the scheduler restarts clean."""
+    model, params = toy
+    sched = make_sched(model, params)
+    got = []
+    req = Request(prompt=[3, 3, 3], max_new_tokens=9,
+                  on_token=lambda r, t: got.append(t))
+    sched.submit(req)
+    for _ in range(4):
+        sched.step()
+    assert sched._flight is not None and len(got) == 3
+    sched.stop()
+    assert sched._flight is None and not sched.has_work()
+    assert req.finish_reason == FinishReason.STOPPED
+    want = golden(model, params, [3, 3, 3], 9)
+    assert got == req.generated == want[:3]
+    assert counter(metrics, "serving_decode_discarded_tokens_total") == 1
+    sched.restart()
+    again = Request(prompt=[3, 3, 3], max_new_tokens=9)
+    sched.run([again])
+    assert again.generated == want
+
+
+def test_pipelined_streams_equal_the_serial_schedulers(toy):
+    """Many requests, staggered arrivals, sampled: request by request
+    the pipelined scheduler serves what the serial one serves."""
+    model, params = toy
+    prompts = rand_prompts(9, seed=21)
+
+    def reqs():
+        return [Request(prompt=p, max_new_tokens=2 + i % 6, seed=70 + i,
+                        arrival_time=0.01 * (i // 2))
+                for i, p in enumerate(prompts)]
+
+    outs = {}
+    for serial in (False, True):
+        kw = (dict(spec_k=2, spec_drafter=lambda s: _NoDrafts())
+              if serial else {})
+        sched = make_sched(model, params, temperature=0.8, **kw)
+        done = sched.run(reqs())
+        assert sched._flight is None
+        outs[serial] = [r.generated for r in
+                        sorted(done, key=lambda r: r.request_id)]
+    assert outs[False] == outs[True]
+
+
+def test_the_counters_and_inflight_say_what_was_overlapped(
+        toy, metrics, tracer):
+    model, params = toy
+    sched = make_sched(model, params)
+    sched.run([Request(prompt=[1, 2, 3], max_new_tokens=5)])
+    sched.run([Request(prompt=[4, 5, 6], max_new_tokens=3)])
+    assert counter(metrics, "serving_decode_dispatch_total") == 8
+    # one dispatch per restart is made with nothing in flight
+    assert counter(metrics, "serving_decode_overlapped_total") == 6
+    flags = [s.attrs["inflight"] for s in tracer.finished()
+             if s.name == "serving.dispatch"]
+    assert flags == [0, 1, 1, 1, 1, 0, 1, 1]
+    assert all(s.attrs == {"k": 1, "spec": False,
+                           "inflight": s.attrs["inflight"]}
+               for s in tracer.finished() if s.name == "serving.dispatch")
+
+
+def test_sync_is_a_direct_child_of_every_step_that_read_a_step(
+        toy, tracer):
+    """Also of a step whose page phase found the pool dry with a step
+    in flight and had it read before preempting."""
+    model, params = toy
+    sched = make_sched(model, params, num_pages=7, prefix_cache=False)
+    reqs = [Request(prompt=p, max_new_tokens=20)
+            for p in rand_prompts(3, seed=9, lo=6, hi=8)]
+    sched.run(reqs)
+    assert sum(r.preemptions for r in reqs) >= 1
+    spans = tracer.finished()
+    steps = {s.id: s for s in spans if s.name == "serving.step"}
+    syncs = [s for s in spans if s.name == "serving.sync"]
+    commits = [s for s in spans if s.name == "serving.commit"]
+    assert syncs and all(s.parent in steps for s in syncs)
+    assert [c.parent for c in commits] == [s.parent for s in syncs]
+    # every token was read under some step's sync
+    assert sum(c.attrs["tokens"] for c in commits) == sum(
+        len(r.generated) for r in reqs) == 60
+    # a step that preempted read its flight BETWEEN two page phases
+    preempting = [s for s in spans if s.name == "serving.pages"
+                  and s.attrs.get("preempted")]
+    assert preempting
+    for p in preempting:
+        kids = sorted((s for s in spans if s.parent == p.parent),
+                      key=lambda s: s.t0)
+        names = [k.name for k in kids]
+        i = names.index("serving.pages")
+        assert names[i:i + 4] == ["serving.pages", "serving.sync",
+                                  "serving.commit", "serving.pages"]
+
+
+def test_decode_step_ms_is_one_steps_time(toy, metrics):
+    """Each step is timed once, from its dispatch — or from the
+    landing of the step before it, if later — to its own read: the
+    times add up to the clock's span, never to twice it."""
+    model, params = toy
+    sched = make_sched(model, params)
+    ticks = iter(range(10_000))
+    sched.step_timer = lambda: float(next(ticks))
+    sched.run([Request(prompt=[1, 2, 3], max_new_tokens=6)])
+    h = metrics.snapshot()["histograms"]["serving_decode_step_ms"]
+    assert h["count"] == 6
+    # two readings a call (its start, a landing), and a step lands one
+    # call after its dispatch.  From its own dispatch to its own read a
+    # step would count 3 ticks — the landing of the step before it lies
+    # between; counted from that landing it is 2, and the six add up
+    # to the clock's twelve
+    assert h["sum"] == pytest.approx(12e3)
+    assert h["max"] == pytest.approx(2e3)
+
+
+def test_a_speculating_scheduler_keeps_nothing_in_flight(toy, metrics):
+    model, params = toy
+    sched = make_sched(model, params, spec_k=2)
+    req = Request(prompt=[1, 2, 3, 1, 2, 3, 1, 2], max_new_tokens=6)
+    sched.submit(req)
+    while sched.has_work():
+        sched.step()
+        assert sched._flight is None
+    assert req.generated == golden(model, params, req.prompt, 6)
+    assert counter(metrics, "serving_decode_overlapped_total") == 0

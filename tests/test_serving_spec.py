@@ -167,12 +167,6 @@ def test_batched_drafter_self_draft_full_accept(toy):
     assert all(r.spec_accepted == r.spec_proposed > 0 for r in done)
 
 
-def test_spec_requires_single_step_sync(toy):
-    model, params = toy
-    with pytest.raises(ValueError, match="mutually exclusive"):
-        _sched(model, params, spec_k=2, steps_per_sync=4)
-
-
 # ---------------------------------------------------------------------------
 # Exactness: greedy and sampled, both layouts, both drafters
 # ---------------------------------------------------------------------------

@@ -800,7 +800,9 @@ class PagedKV:
         """Re-ship the host page table to the device cache if any
         allocation/release changed it since the last dispatch."""
         if self._dirty:
-            self.cache = self.cache.with_page_table(self._table)
+            # a copy: the step that gets this table may still be
+            # running when the host next edits its mirror
+            self.cache = self.cache.with_page_table(self._table.copy())
             self._dirty = False
             self.flushed_rows += self.num_slots
             _count_metric("serving_kv_table_rows_flushed_total",

@@ -10,9 +10,16 @@ Every `step()` is one scheduler iteration:
    join mid-flight; nobody waits for the batch to drain);
 2. **decode** — ONE jitted masked step for all slots
    (`engine_batched.make_masked_step_fn`); free/finished slots emit
-   the pad id and don't advance offsets or RNG keys.  With
-   ``spec_k`` set, the dispatch is a speculative draft–verify round
-   instead (`make_spec_verify_fn` + `serving.speculative` drafters):
+   the pad id and don't advance offsets or RNG keys.  The step is
+   PIPELINED: iteration N dispatches step t+1 — its input tokens are
+   step t's output, still on the device, with the first tokens of
+   rows admitted in this iteration merged in by one small jitted
+   program — BEFORE it reads step t's tokens, so the device runs
+   while the host commits, returns to its caller, admits and
+   enqueues.  With ``spec_k`` set the scheduler stays serial (a
+   drafter needs the committed tokens on the host) and the dispatch
+   is a speculative draft–verify round instead
+   (`make_spec_verify_fn` + `serving.speculative` drafters):
    K proposed tokens scored in one scanned program, the accepted
    prefix + bonus token committed per row, the rejected tail's KV
    cursor / pages / key chain rolled back — token-for-token
@@ -21,11 +28,15 @@ Every `step()` is one scheduler iteration:
    (`PagedKV.ensure`), preempting the newest request — resumed later,
    bit-exactly — if the pool is dry even after LRU-evicting
    unreferenced prefix pages;
-3. **retire** — the step's tokens are synced to host (the one
-   unavoidable sync: EOS is data-dependent), appended, streamed via
-   ``on_token``, and rows that hit EOS / ``max_new_tokens`` / the KV
-   horizon release their slot (and, paged, their private pages —
-   prompt pages stay cached for future prefix hits).
+3. **retire** — the tokens of the step dispatched one iteration
+   EARLIER are synced to host (the one unavoidable sync), appended,
+   streamed via ``on_token``, and rows that hit EOS /
+   ``max_new_tokens`` / the KV horizon release their slot (and,
+   paged, their private pages — prompt pages stay cached for future
+   prefix hits).  A row that ends by length or horizon is known to
+   end before its last step is read and is simply not in the next
+   dispatch; a row that ends by EOS is seen one step late — the
+   token of the step that ran for it is discarded and counted.
 
 Backpressure is at `submit`: a bounded queue and static feasibility
 checks reject with a typed reason instead of queueing unservable work.
@@ -62,7 +73,6 @@ from triton_distributed_tpu.observability.tracing import (
 )
 from triton_distributed_tpu.serving.engine_batched import (
     DEFAULT_PREFILL_BUCKETS,
-    make_masked_block_fn,
     make_masked_step_fn,
     make_spec_verify_fn,
     pad_prompt,
@@ -132,14 +142,6 @@ class SchedulerConfig:
     temperature: float = 0.0
     top_k: int = 0
     top_p: float = 1.0
-    #: Decode steps per host sync (multi-step scheduling).  1 = check
-    #: EOS after every token (lowest latency).  K>1 scans K masked
-    #: steps in one dispatch and retires at block granularity —
-    #: over-generating <= K-1 discarded tokens past EOS — which
-    #: amortizes host/dispatch overhead when the model step is cheap
-    #: relative to it (small models, CPU).  Pre-EOS tokens are
-    #: identical either way.
-    steps_per_sync: int = 1
     #: Speculative decoding: draft–verify ``spec_k`` proposed tokens
     #: per decode dispatch (`engine_batched.make_spec_verify_fn`).
     #: 0 = off.  With it on, each dispatch scores K proposals + the
@@ -148,9 +150,10 @@ class SchedulerConfig:
     #: target-model dispatch, with the rejected tail's KV cursor and
     #: key chain rolled back so output is TOKEN-FOR-TOKEN identical to
     #: the non-speculative engine at any temperature (the accept rule
-    #: is exact-match verification — see docs/serving.md).  Mutually
-    #: exclusive with ``steps_per_sync > 1`` (speculation IS the
-    #: multi-token dispatch; EOS is checked every round).  Rows
+    #: is exact-match verification — see docs/serving.md).  A
+    #: speculating scheduler reads every dispatch before the next
+    #: (drafting needs the committed tokens on the host): it is the
+    #: one configuration that does not pipeline its steps.  Rows
     #: without a proposal this round (or near their KV horizon) fall
     #: back to the plain masked step, bit-identically.
     spec_k: int = 0
@@ -204,6 +207,23 @@ def _observe_prefill(bucket: int, ms: float) -> None:
         get_baseline_store)
     get_baseline_store().observe(prefill_baseline_key(bucket),
                                  ms * 1e3)
+
+
+@dataclasses.dataclass
+class _Flight:
+    """One decode dispatch whose tokens the host has not read yet."""
+    #: (B,) tokens the step returned — (B, K+1) targets of a verify
+    #: round — on the device.
+    toks: object
+    #: slot -> request of the rows that ran in it.
+    rows: Dict[int, Request]
+    #: `step_timer` reading when its preparation began.
+    t0: float
+    #: A sparse model's expert counters (`_moe_counters`), or None.
+    counted: object = None
+    #: Verify round only: accept lengths (device), proposals (host).
+    accept: object = None
+    n_draft: object = None
 
 
 class ContinuousBatchingScheduler:
@@ -278,11 +298,16 @@ class ContinuousBatchingScheduler:
         self._step = make_masked_step_fn(
             decode_fn, cfg.temperature, cfg.top_k, cfg.top_p,
             cfg.pad_id)
-        assert cfg.steps_per_sync >= 1, cfg.steps_per_sync
-        self._block_fn = (make_masked_block_fn(
-            decode_fn, cfg.temperature, cfg.top_k, cfg.top_p,
-            cfg.pad_id, block=cfg.steps_per_sync)
-            if cfg.steps_per_sync > 1 else None)
+        #: Host-known first tokens merged into the token vector the
+        #: last dispatch left on the device: ONE jitted program, run
+        #: in front of every dispatch of `_step`, full batch or not,
+        #: pipeline empty or not — no `jnp` operation runs on the
+        #: host path between the programs of a step.
+        self._merge = jax.jit(
+            lambda prev, host, fresh: jnp.where(fresh, host, prev))
+        #: A sparse model's expert counters, copied out of the cache a
+        #: dispatch returned (`_moe_counters`): a jitted program too.
+        self._keep = jax.jit(jnp.copy)
         #: Speculative verify program + drafter (``spec_k > 0``).
         self._spec_fn = None
         self.drafter = None
@@ -290,11 +315,6 @@ class ContinuousBatchingScheduler:
             if cfg.spec_k < 1:
                 raise ValueError(f"spec_k must be >= 1, got "
                                  f"{cfg.spec_k}")
-            if cfg.steps_per_sync > 1:
-                raise ValueError(
-                    "spec_k and steps_per_sync > 1 are mutually "
-                    "exclusive: speculation IS the multi-token "
-                    "dispatch (EOS is checked every verify round)")
             from triton_distributed_tpu.serving.speculative import (
                 make_drafter)
             self.drafter = make_drafter(cfg.spec_drafter, self)
@@ -317,7 +337,28 @@ class ContinuousBatchingScheduler:
         #: Actor label on this engine's lineage hops (the cluster's
         #: `Replica` renames it to "replica-<i>" so a hop says WHERE).
         self.name = "engine"
+        #: Input token of each slot as far as the HOST knows it (the
+        #: last prompt token at admission, the last committed token
+        #: after) and, in `_fresh`, the rows where the device does not
+        #: know it yet: set at admission, cleared by the dispatch that
+        #: ships them.
         self._tokens = np.full(cfg.num_slots, cfg.pad_id, np.int32)
+        self._fresh = np.zeros(cfg.num_slots, bool)
+        #: What the last dispatch returned, still on the device: the
+        #: next dispatch's input tokens.  Kept across idle periods, so
+        #: that from a process's second dispatch on this argument is
+        #: always "a token vector a step returned"; the first gets
+        #: zeros, placed where the cache's own per-row vector lies —
+        #: with the model's parameters — so that the merged tokens
+        #: reach `_step` placed alike from the first dispatch on and
+        #: the decode program is not compiled once more for it.
+        self._prev = jax.device_put(
+            np.zeros(cfg.num_slots, np.int32),
+            self.slots.cache.offset.sharding)
+        #: The decode step dispatched and not yet read.
+        self._flight: Optional[_Flight] = None
+        #: `step_timer` reading when the last step's tokens landed.
+        self._read_at = float("-inf")
         #: Per-bucket reusable prefill input caches (see _admit).
         self._row_caches: Dict[int, object] = {}
         self._queue: Deque[Request] = collections.deque()
@@ -454,7 +495,11 @@ class ContinuousBatchingScheduler:
     # -- the iteration loop ---------------------------------------------
 
     def has_work(self) -> bool:
-        return bool(self._queue) or bool(self._by_slot)
+        """True while anything is queued, running, or dispatched and
+        unread: the last tokens of the last request are delivered by
+        the `step()` after the one that dispatched them."""
+        return (bool(self._queue) or bool(self._by_slot)
+                or self._flight is not None)
 
     def step(self) -> dict:
         """One scheduler iteration.  Returns counts for introspection:
@@ -471,7 +516,7 @@ class ContinuousBatchingScheduler:
         admitted = self._admit(now)
         retired = 0
         active_n = len(self._by_slot)
-        if self._by_slot:
+        if self._by_slot or self._flight is not None:
             retired = self._decode_step()
         elif self._queue:
             # Nothing running, head not arrived yet: move time.
@@ -503,8 +548,14 @@ class ContinuousBatchingScheduler:
 
     def stop(self) -> None:
         """Abort: live requests finish with reason STOPPED, queued ones
-        are rejected, later submits are rejected."""
+        are rejected, later submits are rejected.  A step in flight is
+        dropped unread — an abort delivers no token: what each request
+        streamed so far is what a scheduler stopped one step earlier
+        would have streamed (the cluster's failover calls this AFTER
+        it took the streams over; a token delivered here could end
+        one it is about to resume)."""
         self._stopped = True
+        self._drop_flight()
         for slot in list(self._by_slot):
             self._retire(slot, self.clock(), FinishReason.STOPPED)
         reg = self._registry()
@@ -790,6 +841,7 @@ class ContinuousBatchingScheduler:
             slot = self.slots.insert_prefill(
                 row_cache, s, self._request_key(req))
         self._tokens[slot] = tokens[-1]
+        self._fresh[slot] = True
         req.state = RequestState.RUNNING
         req.slot = slot
         req.bucket = bucket
@@ -940,47 +992,57 @@ class ContinuousBatchingScheduler:
                                          row_start=row_start)
         return slot, bucket, tokens, mode, c
 
-    def _block_size(self) -> int:
-        """Steps for this dispatch: the configured block, unless some
-        active row is within a block of its KV horizon (its offset may
-        not cross max_seq) — then single steps until it retires."""
-        k = self.config.steps_per_sync
-        if self._block_fn is None:
-            return 1
-        for req in self._by_slot.values():
-            # current offset = prompt_len - 1 + generated; K steps
-            # write offsets up to offset + K - 1 <= max_seq - 1.
-            if (self.max_seq - req.prompt_len - len(req.generated)
-                    + 1) < k:
-                return 1
-        return k
+    def _ahead(self, req: Request) -> int:
+        """Tokens dispatched for ``req`` and not read yet (0 or 1)."""
+        f = self._flight
+        return int(f is not None and f.rows.get(req.slot) is req)
 
-    def _prepare_pages(self, k: int) -> None:
+    def _ends_unread(self, req: Request) -> bool:
+        """True when the step in flight holds ``req``'s LAST token: it
+        ends by length or at the KV horizon, which no token decides —
+        so it is known before that step is read, and the row is not in
+        the next dispatch."""
+        ahead = self._ahead(req)
+        g = len(req.generated) + ahead
+        return bool(ahead) and (g >= req.max_new_tokens
+                                or req.prompt_len + g > self.max_seq)
+
+    def _prepare_pages(self, k: int) -> bool:
         """Paged mode, before a dispatch: every active slot must have
-        pages mapped for the ``k`` positions this dispatch writes.
+        pages mapped for the ``k`` positions this dispatch writes.  A
+        row's length after the step in flight does not depend on that
+        step's token, so nothing here waits for the device.
         The pool evicts unreferenced prefix pages on demand; if it is
         STILL dry, preempt the most recently admitted request (its
         pages fund the older ones; it resumes later, exactly — see
         `Request.resume_tokens`).  Admission feasibility guarantees a
-        sole remaining request can always grow to its horizon."""
+        sole remaining request can always grow to its horizon.
+
+        False: the pool is dry and a step is in flight.  Preemption
+        reads the victim's committed tokens and its slot's key, so the
+        caller first reads that step (which may retire rows and free
+        pages), then calls again."""
         while True:
             ok = True
             for slot, req in list(self._by_slot.items()):
                 # Cap at the request's OWN horizon (what feasible()
-                # budgeted), not just max_seq: a block may over-
-                # generate up to k-1 positions past max_new, and
-                # those writes — whose tokens retire() discards —
-                # fall through the NULL page-table entries into the
-                # trash page.  Kept tokens only ever attend KV below
-                # the horizon, so this is exact.
-                need = min(req.prompt_len + len(req.generated) + k - 1,
+                # budgeted), not just max_seq.  A row that ends by
+                # EOS is seen one step late: the step that ran for it
+                # wrote below this horizon (into its own page, or
+                # through a NULL page-table entry into the trash
+                # page) and its token is discarded.  Kept tokens only
+                # ever attend KV below the horizon, so this is exact.
+                need = min(req.prompt_len + len(req.generated)
+                           + self._ahead(req) + k - 1,
                            req.prompt_len + req.max_new_tokens - 1,
                            self.max_seq)
                 if not self.slots.ensure(slot, need):
                     ok = False
                     break
             if ok:
-                return
+                return True
+            if self._flight is not None:
+                return False
             assert len(self._by_slot) > 1, (
                 "page pool cannot hold a sole feasible request — "
                 "allocator invariant broken")
@@ -989,12 +1051,13 @@ class ContinuousBatchingScheduler:
                                          self._by_slot[sl].request_id))
             self._preempt(victim)
 
-    def _pages_phase(self, writes: int, sp) -> None:
+    def _pages_phase(self, writes: int, sp) -> bool:
         """Paged mode, the KV phase of a dispatch: map the pages it
         writes (evicting, preempting), then re-ship the page table if
         that changed it.  ``sp`` is the `serving.pages` span (or the
         no-op one): it records what the phase did, and the pages live
-        requests hold once it is done."""
+        requests hold once it is done.  False: `_prepare_pages` needs
+        the step in flight read first."""
         slots = self.slots
 
         def work():
@@ -1003,8 +1066,8 @@ class ContinuousBatchingScheduler:
                     -len(self._by_slot))
 
         before = work() if sp is not NULL_SPAN else None
-        self._prepare_pages(writes)
-        if self._by_slot:
+        mapped_all = self._prepare_pages(writes)
+        if mapped_all and self._by_slot:
             slots.flush()
         if before is not None:
             mapped, flushed, evicted, preempted = (
@@ -1012,8 +1075,10 @@ class ContinuousBatchingScheduler:
             sp.attrs.update(mapped=mapped, flushed_rows=flushed,
                             evicted=evicted, preempted=preempted,
                             live_pages=slots.live_pages)
+        return mapped_all
 
     def _preempt(self, slot: int) -> None:
+        assert self._flight is None, "preempting with a step in flight"
         req = self._by_slot.pop(slot)
         if self.drafter is not None:
             # Draft state is rebuilt from the committed context at
@@ -1031,6 +1096,7 @@ class ContinuousBatchingScheduler:
         req.slot = None
         self.slots.release(slot)
         self._tokens[slot] = self.config.pad_id
+        self._fresh[slot] = False
         sp = self._spans.pop(slot, None)
         if sp is not None:
             sp.__exit__(None, None, None)
@@ -1054,8 +1120,8 @@ class ContinuousBatchingScheduler:
             return None
         K = self.config.spec_k
         for req in self._by_slot.values():
-            # The verify pass writes K+1 positions; the same
-            # near-horizon fallback `_block_size` applies to blocks.
+            # The verify pass writes K+1 positions, which a row
+            # near its KV horizon has no room for.
             if (self.max_seq - req.prompt_len - len(req.generated)
                     + 1) < K + 1:
                 return None
@@ -1139,61 +1205,140 @@ class ContinuousBatchingScheduler:
         return True
 
     def _decode_step(self) -> int:
+        """Dispatch the next decode step, THEN read the one in flight
+        (dispatched by the previous iteration) and commit its tokens:
+        while the host commits, returns to its caller, admits and
+        enqueues, the device runs.  Returns the rows retired."""
         t0 = self.step_timer()
+        retired = 0
         spec = self._spec_drafts()
-        k = 1 if spec is not None else self._block_size()
         # Paged mode maps pages for every position this dispatch
         # writes: K proposals + the bonus position under speculation.
-        writes = self.config.spec_k + 1 if spec is not None else k
-        if self.paged:
+        writes = self.config.spec_k + 1 if spec is not None else 1
+        if self.paged and not all(map(self._ends_unread,
+                                      self._by_slot.values())):
+            # (only where a dispatch follows: a page table shipped and
+            # then met first by an insert would be a kind of argument
+            # no set-up has shown that program)
             with span("serving.pages") as sp:
-                self._pages_phase(writes, sp)
-            if not self._by_slot:      # defensive: all preempted
-                return 0
-        accept_host = n_draft = counted = None
+                mapped = self._pages_phase(writes, sp)
+            if not mapped:
+                # Pool dry with a step in flight: read it (it may
+                # retire rows), then map again, preempting if need be.
+                retired += self._read(self._take_flight())
+                with span("serving.pages") as sp:
+                    self._pages_phase(writes, sp)
+        rows = {slot: req for slot, req in self._by_slot.items()
+                if not self._ends_unread(req)}
+        prior = self._take_flight()
+        if rows:
+            self._flight = self._dispatch(rows, spec, t0,
+                                          prior is not None)
+        if prior is not None:
+            retired += self._read(prior)
+        if self.config.spec_k and self._flight is not None:
+            # Drafts are built from tokens the host has read: a
+            # speculating scheduler keeps nothing in flight.
+            retired += self._read(self._take_flight())
+        return retired
+
+    def _take_flight(self) -> Optional[_Flight]:
+        flight, self._flight = self._flight, None
+        return flight
+
+    def _drop_flight(self) -> None:
+        """Forget the step in flight, unread (`stop`): its tokens are
+        counted as discarded."""
+        flight = self._take_flight()
+        reg = self._registry()
+        if flight is not None and reg:
+            reg.counter("serving_decode_discarded_tokens_total").inc(
+                len(flight.rows))
+
+    def _dispatch(self, rows: Dict[int, Request], spec, t0: float,
+                  inflight: bool) -> _Flight:
+        """Enqueue one decode dispatch for ``rows``.  Every argument
+        of every program has a fixed provenance: the previous tokens
+        are what the last dispatch returned, the host's tokens and the
+        masks are fresh `numpy` arrays of fixed shape and dtype (new
+        ones each time: the step that reads them may still be running
+        when the host next writes its own), the page table is shipped
+        by `PagedKV.flush`."""
+        active = np.zeros(self.config.num_slots, bool)
+        active[list(rows)] = True
+        reg = self._registry()
+        if reg:
+            reg.counter("serving_decode_dispatch_total").inc()
+            if inflight:
+                reg.counter("serving_decode_overlapped_total").inc()
         if spec is not None:
             drafts, n_draft = spec
             with span("serving.dispatch", k=self.config.spec_k,
-                      spec=True):
+                      spec=True, inflight=int(inflight)):
                 targets, accept, cache, keys = self._spec_fn(
                     self.params, jnp.asarray(self._tokens),
                     jnp.asarray(drafts), self.slots.cache,
-                    self.slots.keys, self.slots.active_mask(),
-                    jnp.asarray(n_draft))
+                    self.slots.keys, active, jnp.asarray(n_draft))
                 self.slots.cache = cache
                 self.slots.keys = keys
-            with span("serving.sync"):
-                toks_host = np.asarray(targets)   # THE host sync
-                accept_host = np.asarray(accept)
+            return _Flight(targets, rows, t0, accept=accept,
+                           n_draft=n_draft)
+        # A speculating scheduler reads every step before the next, and
+        # a verify round leaves no token vector of this form behind:
+        # there the host's word counts for every row.
+        fresh = active if self.config.spec_k else self._fresh.copy()
+        with span("serving.dispatch", k=1, spec=False,
+                  inflight=int(inflight)):
+            tokens = self._merge(self._prev, self._tokens.copy(), fresh)
+            toks, cache, keys = self._step(
+                self.params, tokens, self.slots.cache, self.slots.keys,
+                active)
+            self.slots.cache = cache
+            self.slots.keys = keys
+        self._prev = toks
+        self._fresh[:] = False
+        return _Flight(toks, rows, t0, counted=self._moe_counters())
+
+    def _read(self, flight: _Flight) -> int:
+        """Read one dispatch's tokens — THE host sync — and commit
+        them.  Returns the rows retired."""
+        spec = flight.accept is not None
+        accept_host = None
+        with span("serving.sync"):
+            toks_host = np.asarray(flight.toks)   # THE host sync
+            if spec:
+                accept_host = np.asarray(flight.accept)
+        landed = self.step_timer()
+        now = self.clock()
+        reg = self._registry()
+        if not spec:
+            toks_host = toks_host[:, None]
+        if flight.counted is not None:
+            self._moe_phase(flight.counted, reg)
+        # A row that ended by EOS one step ago ran in this step too
+        # (its slot may already hold another request): its token is
+        # discarded.
+        rows = [(slot, req) for slot, req in flight.rows.items()
+                if self._by_slot.get(slot) is req]
+        discarded = len(flight.rows) - len(rows)
+        # One step's time: from its dispatch — or from when the step
+        # before it landed, if that was later: the device runs one
+        # step at a time — to its own tokens on the host.
+        elapsed_ms = (landed - max(flight.t0, self._read_at)) * 1e3
+        self._read_at = landed
+        if reg:
+            if discarded:
+                reg.counter(
+                    "serving_decode_discarded_tokens_total").inc(
+                        discarded)
             # Normalize the step metric by tokens COMMITTED, not
             # positions scanned: serving_decode_step_ms/us feed the
             # SLO admission baseline and the router's placement
             # scoring as "cost per token here, now" — a collapsed
             # drafter must read as slow (K+1 forwards, ~1 token),
             # not as K+1 healthy steps.
-            steps = float(np.mean(
-                accept_host[list(self._by_slot)])) + 1.0
-        else:
-            fn = self._block_fn if k > 1 else self._step
-            with span("serving.dispatch", k=k, spec=False):
-                toks, cache, keys = fn(
-                    self.params, jnp.asarray(self._tokens),
-                    self.slots.cache, self.slots.keys,
-                    self.slots.active_mask())
-                self.slots.cache = cache
-                self.slots.keys = keys
-            counted = self._moe_counters()
-            with span("serving.sync"):
-                toks_host = np.asarray(toks)      # THE host sync
-            if k == 1:
-                toks_host = toks_host[:, None]
-            steps = k
-        now = self.clock()
-        reg = self._registry()
-        if counted is not None:
-            self._moe_phase(counted, reg)
-        if reg:
-            elapsed_ms = (self.step_timer() - t0) * 1e3
+            steps = (float(np.mean(accept_host[[s for s, _ in rows]]))
+                     + 1.0 if spec and rows else 1.0)
             step_ms = elapsed_ms / steps
             reg.histogram("serving_decode_step_ms").observe(step_ms)
             # Last measured step as a gauge: rides the heartbeat
@@ -1225,7 +1370,6 @@ class ContinuousBatchingScheduler:
                 emit_kernel_event(
                     "serving.decode_step", kind="engine",
                     measured_us=step_ms * 1e3, anomaly_z=round(z, 2))
-        rows = list(self._by_slot.items())
         if reg and rows:
             # Cost attribution: the dispatch's measured window is
             # split exactly across the rows that ran in it (a spec
@@ -1234,17 +1378,18 @@ class ContinuousBatchingScheduler:
             # and each row's pinned KV pages integrate page-seconds
             # since their previous charge.
             self._charge_device(
-                "spec_verify" if spec is not None else "decode",
+                "spec_verify" if spec else "decode",
                 elapsed_ms * 1e3, [r for _, r in rows])
             self._charge_kv_residency([r for _, r in rows], now)
         with span("serving.commit") as sp:
-            if spec is not None:
-                self._spec_outcome(rows, accept_host, n_draft, now,
-                                   reg)
+            if spec:
+                self._spec_outcome(rows, accept_host, flight.n_draft,
+                                   now, reg)
             retired, generated = self._commit_tokens(
                 rows, toks_host, accept_host, now, reg)
             if sp is not NULL_SPAN:
-                sp.attrs.update(tokens=generated, retired=retired)
+                sp.attrs.update(tokens=generated, retired=retired,
+                                discarded=discarded)
         if reg:
             reg.counter("serving_tokens_generated_total").inc(generated)
         return retired
@@ -1254,10 +1399,15 @@ class ContinuousBatchingScheduler:
         just enqueued (`layers.moe_mlp.MOE_STATS`, left in the cache's
         `stats` by the decode program itself), its copy to the host
         started so that it lands with the step's tokens: no sync of
-        its own.  None: the model counts nothing, or nothing records."""
+        its own.  It travels with its step (`_Flight.counted`) and is
+        read when that step is.  None: the model counts nothing, or
+        nothing records."""
         counted = getattr(self.slots.cache, "stats", None)
         if counted is None or self._registry() is None:
             return None
+        # the next dispatch (or insert) donates the cache, and these
+        # with it, before this step is read: keep a copy of our own
+        counted = self._keep(counted)
         counted.copy_to_host_async()
         return counted
 
@@ -1316,17 +1466,15 @@ class ContinuousBatchingScheduler:
         ``on_token``, check EOS / budget / KV horizon, retire, and
         (speculative mode) reconcile the drafter with what was
         actually committed.  A row emits ``accept + 1`` tokens under
-        speculation, else the block width; tokens decoded past a
-        retirement reason are discarded — bounded over-generation,
-        exactly as in block mode."""
+        speculation, else one; tokens decoded past a retirement
+        reason are discarded — bounded over-generation."""
         retired = 0
         generated = 0
-        k = toks_host.shape[1]
         batched = getattr(self.drafter, "batched", False)
         outcomes = []
         for slot, req in rows:
             count = (int(accept_host[slot]) + 1
-                     if accept_host is not None else k)
+                     if accept_host is not None else 1)
             committed = []
             done = False
             for j in range(count):
@@ -1402,6 +1550,7 @@ class ContinuousBatchingScheduler:
         req.t_finish = now
         self.slots.release(slot)
         self._tokens[slot] = self.config.pad_id
+        self._fresh[slot] = False
         sp = self._spans.pop(slot, None)
         if sp is not None:
             sp.__exit__(None, None, None)
