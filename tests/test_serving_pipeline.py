@@ -186,6 +186,15 @@ def _glm_system(devices):
                                 devices[:1])
 
 
+def _solar_system(devices):
+    from cellbench.adapters import solar_open2
+    from tests.test_solar_open2 import PAGE, STATE, TINY
+    serving = dict(TINY["serving"], num_slots=8,
+                   kv_budget_bytes_per_chip=8 * (STATE + 8 * PAGE))
+    return solar_open2.System(dict(TINY, serving=serving), 11,
+                              devices[:1])
+
+
 #: Slots for `warm_up` to admit both requests of every bucket in one
 #: call (1 + 2 x 3 buckets here), as every cell but the two of seven
 #: buckets on eight slots has: there an insert of the fourth bucket
@@ -193,6 +202,7 @@ def _glm_system(devices):
 #: parent as on this scheduler (PERF.md section 7).
 SYSTEMS = {"toy": lambda devices: ToySystem(),
            "qwen3": _qwen_system, "glm4_moe_lite": _glm_system,
+           "solar_open2": _solar_system,
            "qwen3-tp4": lambda devices: _qwen_system(devices, 4)}
 #: six minutes of interpreted ring kernels: by hand (`-m slow`)
 FAMILIES = [pytest.param(f, marks=pytest.mark.slow) if f == "qwen3-tp4"
@@ -225,10 +235,14 @@ def compiled():
 
 
 def programs(sched):
-    """Every jitted program of the step path, by name."""
+    """Every jitted program of the step path, by name ("reset": the
+    zeroing of a released slot's recurrent state, run only for a model
+    that has one)."""
     out = {"merge": sched._merge, "step": sched._step,
            "keep": sched._keep, "prefill": sched._prefill,
            "insert": sched.slots._insert}
+    if getattr(sched.slots, "_reset", None) is not None:
+        out["reset"] = sched.slots._reset
     if sched._prefill_suffix is not None:
         out["prefill_suffix"] = sched._prefill_suffix
     return out

@@ -193,3 +193,80 @@ def test_no_pool_sized_copy_in_step_or_insert(topo_devices, family,
     for program, copies in found.items():
         assert not copies["layout"], (program, copies)
         assert len(copies["staged"]) <= STAGED_MAX, (program, copies)
+
+
+def test_no_state_sized_copy_in_step_insert_or_reset(topo_devices):
+    """The hybrid family (`models/solar_open2.py`): a decode step
+    rewrites every live slot's recurrent state — 4 MB a slot a layer —
+    through a kernel that aliases the pool, the insert and the reset
+    write one slot's rows on the leading dimension.  None of the three
+    programs may copy the state pool or the softmax layer's page pools.
+    (The convolution's kept inputs, 0.15 MB a slot a layer, are
+    rewritten whole by every step anyway — the shift — and are not
+    held to it.)"""
+    from triton_distributed_tpu.models.kv_cache import zero_state_rows
+    from triton_distributed_tpu.models.solar_open2 import SolarOpen2
+
+    c = _config("solar-open2-250b-1c.json")
+    lin = c["linear_attn_config"]
+    cfg = ModelConfig(
+        architecture=c["model_type"], vocab_size=c["vocab_size"],
+        hidden_size=c["hidden_size"],
+        intermediate_size=c["intermediate_size"], num_layers=LAYERS,
+        num_heads=c["num_attention_heads"],
+        num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
+        rms_norm_eps=c["rms_norm_eps"], qk_norm=False,
+        tie_word_embeddings=False, max_seq_len=4096,
+        dtype=c["torch_dtype"],
+        num_experts=c["share"]["experts_of_layer"],
+        experts_held=tuple(c["share"]["experts_held"]),
+        num_experts_per_tok=c["num_experts_per_tok"],
+        moe_intermediate_size=c["moe_intermediate_size"],
+        n_shared_experts=c["n_shared_experts"],
+        gqa_layers=(0,), use_rope=c["use_rope"],
+        use_gqa_gate=c["use_gqa_gate"], kda_num_heads=lin["num_heads"],
+        kda_head_dim=lin["head_dim"],
+        kda_conv_size=lin["short_conv_kernel_size"],
+        kda_rank=lin["head_dim"])
+    model = SolarOpen2(cfg, Mesh(np.array(topo_devices[:1]), ("tp",)),
+                       mode="fused", interpret=False)
+    slots = 16
+    rep = NamedSharding(model.mesh, P())
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
+        shape, dt, sharding=rep)
+    pool = _shaped(model, functools.partial(
+        PagedKVCache.create, 1, PAGES, slots, 8, PAGE, 128,
+        4096 // PAGE, model.dtype, num_stats=len(model.STATS),
+        state_shapes=model._state_shapes),
+        model._paged_cache_specs(PAGE))
+    row = _shaped(model, functools.partial(
+        KVCache.create, 1, 1, 8, BUCKET, 128, model.dtype,
+        state_shapes=model._state_shapes), model._cache_specs())
+    params = _shaped(
+        model, lambda: model.init_params(jax.random.key(0)),
+        model.param_specs())
+    keys = arg((slots, 2), jnp.uint32)
+    programs = {
+        "step": make_masked_step_fn(model.make_paged_decode_fn(PAGE))
+        .lower(params, arg((slots,), jnp.int32), pool, keys,
+               arg((slots,), jnp.bool_)),
+        "insert": make_paged_insert_fn().lower(
+            pool, keys, row, arg((2,), jnp.uint32), arg((), jnp.int32),
+            arg((BUCKET // PAGE,), jnp.int32), arg((), jnp.int32)),
+        "reset": jax.jit(zero_state_rows, donate_argnums=(0, 1)).lower(
+            pool.states, pool.convs, arg((), jnp.int32))}
+    shapes = {"state": (slots, 64, 128, 128),
+              "pages": (PAGES, 8, PAGE, 128)}
+    for name, lowered in programs.items():
+        text = lowered.compile().as_text()
+        if name == "step":
+            assert "kda_decode_step" in text
+        for what, shape in shapes.items():
+            if name == "reset" and what == "pages":
+                continue
+            dims = ",".join(str(d) for d in shape)
+            assert f"[{dims}]" in text, (name, what)
+            found = pool_copies(text, shape)
+            print(f"solar_open2 {name} {what} {shape}: {found}")
+            assert not found["layout"], (name, what, found)
+            assert len(found["staged"]) <= STAGED_MAX, (name, what, found)

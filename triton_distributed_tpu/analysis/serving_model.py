@@ -85,6 +85,9 @@ class _StubPagedCache:
     def bytes_per_page(self) -> int:
         return 4096  # any constant: admission arithmetic is in pages
 
+    def state_bytes_per_slot(self) -> int:
+        return 0     # no recurrent layer: every byte is pages
+
     def _use(self) -> None:
         if self.donated:
             raise DonationError(

@@ -328,17 +328,32 @@ def packed_blocks_bound(n_pairs: int, num_experts: int,
 
 
 def pack_by_expert(expert_ids, weights, num_experts: int,
-                   block: int) -> PackedPlan:
+                   block: int, held=None) -> PackedPlan:
     """Build the dropless packed plan.  expert_ids / weights:
     (n_tokens, topk).  Deterministic (stable sort: earlier pairs come
-    first within an expert)."""
+    first within an expert).
+
+    ``held = (lo, hi)``: only experts ``lo <= e < hi`` live here.  The
+    plan is then over the ``hi - lo`` held experts, numbered from 0;
+    pairs routed elsewhere are sorted behind them under one more
+    number, ``hi - lo``, whose blocks lie past ``n_blocks`` (never
+    computed): ``counts[hi - lo]`` says how many there were, their
+    rows read the zero row with weight 0, and their ``pair_row`` names
+    rows no kernel wrote."""
     n_tokens, topk = expert_ids.shape
     npairs = n_tokens * topk
-    t_max = packed_blocks_bound(npairs, num_experts, block)
     flat_e = expert_ids.reshape(-1).astype(jnp.int32)
+    flat_w = weights.reshape(-1).astype(jnp.float32)
+    n_plan = num_experts
+    if held is not None:
+        lo, hi = held
+        n_plan = hi - lo + 1            # the last: "not here"
+        flat_e = jnp.where((flat_e >= lo) & (flat_e < hi), flat_e - lo,
+                           hi - lo)
+    t_max = packed_blocks_bound(npairs, n_plan, block)
     order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
     sorted_e = flat_e[order]
-    counts = histogram(flat_e, num_experts)
+    counts = histogram(flat_e, n_plan)
     blocks_e = (counts + block - 1) // block
     cum = jnp.cumsum(blocks_e)
     first_pair = jnp.cumsum(counts) - counts            # (E,)
@@ -346,18 +361,38 @@ def pack_by_expert(expert_ids, weights, num_experts: int,
     dest = ((cum - blocks_e) * block)[sorted_e] + pos   # packed row
     rows = t_max * block
     t_ids = jnp.arange(t_max, dtype=jnp.int32)
-    return PackedPlan(
-        row_token=jnp.full((rows,), n_tokens, jnp.int32)
-        .at[dest].set(order // topk),
-        row_weight=jnp.zeros((rows,), jnp.float32)
-        .at[dest].set(weights.reshape(-1).astype(jnp.float32)[order]),
-        pair_row=jnp.zeros((npairs,), jnp.int32).at[order].set(dest)
-        .reshape(n_tokens, topk),
-        block_expert=jnp.where(
+    token, weight = order // topk, flat_w[order]
+    if held is None:
+        n_blocks = cum[-1].astype(jnp.int32)
+        block_expert = jnp.where(
             t_ids < cum[-1],
             jnp.searchsorted(cum, t_ids, side="right").astype(jnp.int32),
-            sorted_e[-1]),
-        n_blocks=cum[-1].astype(jnp.int32),
+            sorted_e[-1])
+    else:
+        # At least one block "in use", whatever the routing: the
+        # grouped GEMMs map every block past the last to block
+        # n_blocks - 1, and a step whose pairs ALL went elsewhere (a
+        # few rows, an eighth of the experts here) would name block -1
+        # — a read outside the array, which hangs the chip.  That one
+        # block then holds zero rows of weight 0.  Blocks past the held
+        # ones repeat the last held expert in use (the last held one
+        # where none is): nothing new is fetched for them.
+        n_held = n_plan - 1
+        n_blocks = jnp.maximum(cum[n_held - 1], 1).astype(jnp.int32)
+        block_expert = jnp.minimum(jnp.searchsorted(
+            cum[:n_held], jnp.minimum(t_ids, n_blocks - 1),
+            side="right").astype(jnp.int32), n_held - 1)
+        here = sorted_e < n_held
+        token = jnp.where(here, token, n_tokens)
+        weight = jnp.where(here, weight, 0.0)
+    return PackedPlan(
+        row_token=jnp.full((rows,), n_tokens, jnp.int32)
+        .at[dest].set(token),
+        row_weight=jnp.zeros((rows,), jnp.float32).at[dest].set(weight),
+        pair_row=jnp.zeros((npairs,), jnp.int32).at[order].set(dest)
+        .reshape(n_tokens, topk),
+        block_expert=block_expert,
+        n_blocks=n_blocks,
         counts=counts)
 
 

@@ -264,8 +264,11 @@ class MoEMLP:
 # One-chip dropless expert layer
 # ---------------------------------------------------------------------------
 
-#: What `SparseMoE` counts in a call, in this order.
+#: What `SparseMoE` counts in a call, in this order; a layer that
+#: holds a share of the experts (`held`) counts its own and adds the
+#: pairs routed to experts that live elsewhere.
 MOE_STATS = ("pairs", "experts_hit", "expert_load_max")
+HELD_STATS = MOE_STATS + ("pairs_elsewhere",)
 
 
 def _pack_block(n_pairs: int, num_experts: int) -> int:
@@ -294,7 +297,15 @@ class SparseMoE:
     experts, a prefill reads each once.  Mode "xla": every expert over
     every token, masked (the golden; test sizes only).
 
-    Not tensor- or expert-parallel: `MoEMLP` above is the tp layer."""
+    ``held = (lo, hi)``: this chip holds experts ``lo .. hi - 1`` of
+    ``num_experts`` — its share of a layer that several chips divide.
+    The router keeps its width and its ``topk``; the expert weights are
+    the ``hi - lo`` held ones; only pairs whose expert is held are
+    packed and computed, the shared expert is added, and what the
+    experts elsewhere would have added is left out (on one chip the
+    layer runs without its exchange).  None: every expert is here.
+
+    Not tensor-parallel: `MoEMLP` above is the tp layer."""
 
     hidden: int
     ffn: int                       # per-expert intermediate size
@@ -305,11 +316,18 @@ class SparseMoE:
     norm_topk_prob: bool = True
     mode: str = "fused"            # xla | fused
     interpret: Optional[bool] = None
+    held: Optional[tuple] = None   # (lo, hi) of num_experts
+
+    @property
+    def num_held(self) -> int:
+        lo, hi = self.held or (0, self.num_experts)
+        return hi - lo
 
     def init_params(self, key, dtype=jnp.bfloat16):
         ks = jax.random.split(key, 7)
         e, h, f = self.num_experts, self.hidden, self.ffn
         fs = f * self.n_shared
+        n = self.num_held
 
         def normal(k, shape, fan_in):
             return (jax.random.normal(k, shape) * fan_in ** -0.5
@@ -318,9 +336,9 @@ class SparseMoE:
         return {
             "router": normal(ks[0], (h, e), h).astype(jnp.float32),
             "router_bias": 0.01 * jax.random.normal(ks[1], (e,)),
-            "gate": normal(ks[2], (e, h, f), h),
-            "up": normal(ks[3], (e, h, f), h),
-            "down": normal(ks[4], (e, f, h), f),
+            "gate": normal(ks[2], (n, h, f), h),
+            "up": normal(ks[3], (n, h, f), h),
+            "down": normal(ks[4], (n, f, h), f),
             "shared": {"gate_up": normal(ks[5], (h, 2 * fs), h),
                        "down": normal(ks[6], (fs, h), fs)},
         }
@@ -357,6 +375,8 @@ class SparseMoE:
         dense_w = jnp.zeros((x.shape[0], self.num_experts), jnp.float32
                             ).at[jnp.arange(x.shape[0])[:, None],
                                  ids].add(w)
+        if self.held is not None:
+            dense_w = dense_w[:, self.held[0]:self.held[1]]
         g = jnp.einsum("nh,ehf->enf", x, params["gate"],
                        preferred_element_type=jnp.float32)
         u = jnp.einsum("nh,ehf->enf", x, params["up"],
@@ -366,7 +386,7 @@ class SparseMoE:
                        preferred_element_type=jnp.float32)
         return jnp.einsum("enh,ne->nh", y, dense_w)
 
-    def _routed_fused(self, x, params, plan, block, phase):
+    def _routed_fused(self, x, params, ids, plan, block, phase):
         from triton_distributed_tpu.kernels.grouped_gemm import (
             packed_expert_down, packed_expert_gate_up)
 
@@ -380,26 +400,43 @@ class SparseMoE:
             plan.n_blocks, block=block, name=f"moe_{phase}_down",
             interpret=self.interpret)
         # each token's topk rows, already weighted: a float32 sum
-        return out[plan.pair_row].astype(jnp.float32).sum(axis=1)
+        picked = out[plan.pair_row].astype(jnp.float32)
+        if self.held is not None:
+            # a pair routed elsewhere has no row here (its `pair_row`
+            # names one that no kernel wrote) and adds nothing
+            lo, hi = self.held
+            picked = jnp.where(((ids >= lo) & (ids < hi))[..., None],
+                               picked, 0.0)
+        return picked.sum(axis=1)
 
     def __call__(self, x, params, phase: str = "prefill"):
         """x: (N, hidden).  Returns (y (N, hidden), stats (3,) f32 in
         `MOE_STATS` order: pairs computed, experts with at least one
-        row, the busiest expert's share of the pairs).  ``phase``
+        row, the busiest expert's share of the pairs — of the held
+        experts, and in `HELD_STATS` order, where `held`).  ``phase``
         ("decode" | "prefill") names the two grouped GEMMs in a device
         trace: `moe_<phase>_gate_up`, `moe_<phase>_down`."""
         n = x.shape[0]
         ids, w = self.route(x, params)
         block = _pack_block(n * self.topk, self.num_experts)
-        plan = moe_utils.pack_by_expert(ids, w, self.num_experts, block)
+        plan = moe_utils.pack_by_expert(ids, w, self.num_experts, block,
+                                        held=self.held)
         if self.mode == "xla":
             y = self._routed_xla(x, params, ids, w)
         elif self.mode == "fused":
-            y = self._routed_fused(x, params, plan, block, phase)
+            y = self._routed_fused(x, params, ids, plan, block, phase)
         else:
             raise ValueError(f"unknown mode {self.mode}")
         if self.n_shared:
             y = y + self._shared(x, params["shared"])
+        if self.held is not None:
+            counts = plan.counts[:-1].astype(jnp.float32)
+            pairs = counts.sum()
+            stats = jnp.stack([
+                pairs, jnp.sum(counts > 0).astype(jnp.float32),
+                jnp.max(counts) / jnp.maximum(pairs, 1.0),
+                plan.counts[-1].astype(jnp.float32)])
+            return y.astype(x.dtype), stats
         pairs = jnp.float32(n * self.topk)
         stats = jnp.stack([
             pairs, jnp.sum(plan.counts > 0).astype(jnp.float32),

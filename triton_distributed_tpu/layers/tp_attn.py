@@ -80,6 +80,12 @@ class TPAttention:
     head_dim: int
     rope_theta: float = 1e6
     qk_norm: bool = True          # Qwen3-style per-head q/k RMSNorm
+    #: False: no positional encoding at all (NoPE).  Set by the model.
+    rope: bool = True
+    #: An output gate (arXiv 2505.06708): ``sigmoid(x W_g)``, a number
+    #: a head and channel, times the heads' output before ``W_o``;
+    #: ``W_g`` rides as further columns of ``wqkv``.  Set by the model.
+    gate: bool = False
     mode: str = "fused"           # xla | fused
     gemm: MatmulConfig = dataclasses.field(default_factory=MatmulConfig)
     collective_ids: tuple = (cids.TP_ATTN_QKV, cids.TP_ATTN_OUT)
@@ -103,7 +109,8 @@ class TPAttention:
 
     @property
     def qkv_cols(self):
-        return (self.h_loc + 2 * self.hkv_loc) * self.head_dim
+        return ((1 + self.gate) * self.h_loc
+                + 2 * self.hkv_loc) * self.head_dim
 
     def init_params(self, key, dtype=jnp.bfloat16):
         k1, k2 = jax.random.split(key)
@@ -144,6 +151,14 @@ class TPAttention:
                           ).astype(x.dtype)
         return qkv  # (M, qkv_cols)
 
+    def _split_gate(self, qkv):
+        """(q | k | v columns, the gate (M, h_loc * d) or None)."""
+        if not self.gate:
+            return qkv, None
+        cut = qkv.shape[-1] - self.h_loc * self.head_dim
+        return qkv[:, :cut], jax.nn.sigmoid(
+            qkv[:, cut:].astype(jnp.float32))
+
     def _split_heads(self, qkv, batch, seq):
         d = self.head_dim
         q, k, v = jnp.split(
@@ -172,17 +187,19 @@ class TPAttention:
     def prefill(self, x, params, batch: int):
         """x: (M/world, hidden) M-sharded; returns same sharding, plus
         this rank's KV (B, Hkv_loc, S, D) for the cache."""
-        qkv = self._project_qkv(x, params)          # (M, qkv_cols)
+        qkv, gate = self._split_gate(
+            self._project_qkv(x, params))           # (M, qkv_cols)
         m = qkv.shape[0]
         seq = m // batch
         q, k, v = self._split_heads(qkv, batch, seq)
         if self.qk_norm:
             q = rms_norm(q, params["q_norm"])
             k = rms_norm(k, params["k_norm"])
-        cos, sin = rope_cos_sin(jnp.arange(seq), self.head_dim,
-                                self.rope_theta)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        if self.rope:
+            cos, sin = rope_cos_sin(jnp.arange(seq), self.head_dim,
+                                    self.rope_theta)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
         if self.mode == "xla":
             # dense golden (differentiable; materializes S² — use the
             # fused mode for long sequences)
@@ -193,6 +210,8 @@ class TPAttention:
             attn = flash_attention_diff(q, k, v, causal=True,
                                         interpret=self.interpret)
         attn = attn.transpose(0, 2, 1, 3).reshape(m, -1)
+        if gate is not None:
+            attn = (attn * gate).astype(x.dtype)
         out = self._out_proj(attn, x.dtype, params)
         return out, (k, v)
 
@@ -208,6 +227,9 @@ class TPAttention:
         ((k_scale, v_scale), each (B, Hkv_loc, S_max) f32) the cache is
         int8 and the new token is quantized on write.
         Returns (out like x, updated cache, updated scales or None)."""
+        assert not self.gate and self.rope, (
+            "the gate and NoPE are built for the prefill and the paged "
+            "decode")
         k_cache, v_cache = kv_cache
         b = k_cache.shape[0]
         qkv = self._project_qkv(x, params)          # (B, qkv_cols)
@@ -285,24 +307,27 @@ class TPAttention:
         k_pool, v_pool = kv_pools
         b = offset.shape[0]
         ps = k_pool.shape[2]
-        qkv = self._project_qkv(x, params)          # (B, qkv_cols)
+        qkv, gate = self._split_gate(
+            self._project_qkv(x, params))           # (B, qkv_cols)
         q, k, v = self._split_heads(qkv, b, 1)
         if self.qk_norm:
             q = rms_norm(q, params["q_norm"])
             k = rms_norm(k, params["k_norm"])
-        cos, sin = rope_cos_sin(offset, self.head_dim, self.rope_theta)
+        if self.rope:
+            cos, sin = rope_cos_sin(offset, self.head_dim,
+                                    self.rope_theta)
 
-        def rope1(x_):  # x_: (B, H, 1, D); cos/sin: (B, D/2)
-            d2 = x_.shape[-1] // 2
-            c = cos[:, None, None, :].astype(jnp.float32)
-            s = sin[:, None, None, :].astype(jnp.float32)
-            x1, x2 = x_[..., :d2], x_[..., d2:]
-            return jnp.concatenate(
-                [x1 * c - x2 * s, x2 * c + x1 * s],
-                axis=-1).astype(x_.dtype)
+            def rope1(x_):  # x_: (B, H, 1, D); cos/sin: (B, D/2)
+                d2 = x_.shape[-1] // 2
+                c = cos[:, None, None, :].astype(jnp.float32)
+                s = sin[:, None, None, :].astype(jnp.float32)
+                x1, x2 = x_[..., :d2], x_[..., d2:]
+                return jnp.concatenate(
+                    [x1 * c - x2 * s, x2 * c + x1 * s],
+                    axis=-1).astype(x_.dtype)
 
-        q = rope1(q)
-        k = rope1(k)
+            q = rope1(q)
+            k = rope1(k)
 
         assert (kv_scales is not None) == (k_pool.dtype == jnp.int8), (
             "int8 pools require kv_scales (and float pools must not "
@@ -329,6 +354,8 @@ class TPAttention:
             page_table, offset + 1, k_scale=k_sc, v_scale=v_sc,
             interpret=self.interpret)
         attn = out.reshape(b, self.h_loc * self.head_dim)
+        if gate is not None:
+            attn = (attn * gate).astype(x.dtype)
         out_x = self._out_proj(attn, x.dtype, params)
         scales = (k_sc, v_sc) if kv_scales is not None else None
         return out_x, (k_pool, v_pool), scales

@@ -17,4 +17,8 @@ def AutoLLM(config, mesh, **kw):
         return Qwen3(config, mesh, **kw)
     if "glm4_moe_lite" in arch or "glm4moelite" in arch:
         return Glm4MoeLite(config, mesh, **kw)
+    if "solar_open2" in arch or "solaropen2" in arch:
+        # bound here: the other families' set-up imports none of it
+        from triton_distributed_tpu.models.solar_open2 import SolarOpen2
+        return SolarOpen2(config, mesh, **kw)
     raise ValueError(f"unknown architecture: {config.architecture}")

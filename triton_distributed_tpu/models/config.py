@@ -51,6 +51,25 @@ class ModelConfig:
     n_shared_experts: int = 0
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
+    #: This chip's share of a layer's experts, ``(lo, hi)`` of
+    #: ``num_experts`` (`layers.moe_mlp.SparseMoE.held`); None: all.
+    experts_held: Optional[tuple] = None
+    # Hybrid of softmax and linear attention
+    # (`models/solar_open2.py`): the layers named in ``gqa_layers``
+    # are grouped-query attention (``num_heads`` / ``num_kv_heads`` /
+    # ``head_dim`` above; ``use_rope`` False: no positional encoding;
+    # ``use_gqa_gate``: an output gate), every other layer is Kimi
+    # Delta Attention with ``kda_num_heads`` heads of ``kda_head_dim``,
+    # a short convolution of ``kda_conv_size`` taps and decay and gate
+    # maps of rank ``kda_rank``.  ``kda_num_heads`` 0: no such layer.
+    gqa_layers: tuple = ()
+    use_rope: bool = True
+    use_gqa_gate: bool = False
+    kda_num_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv_size: int = 4
+    kda_rank: int = 128
+    kda_allow_neg_eigval: bool = True
 
     @property
     def is_moe(self) -> bool:
@@ -134,6 +153,28 @@ class ModelConfig:
                  moe_intermediate_size=128, first_k_dense_replace=1,
                  n_shared_experts=1, routed_scaling_factor=1.8,
                  norm_topk_prob=True)
+        d.update(kw)
+        return cls(**d)
+
+    @classmethod
+    def tiny_solar_open2(cls, **kw):
+        """Test-size hybrid: one grouped-query layer (no positions,
+        gated) before two delta-rule layers, every feed-forward a
+        share (8 of 16) of a top-4 expert layer with a shared expert.
+        The delta-rule heads keep their published 128: the kernels'
+        state tile."""
+        d = dict(architecture="solar_open2", vocab_size=256,
+                 hidden_size=128, intermediate_size=256, num_layers=3,
+                 num_heads=8, num_kv_heads=2, head_dim=16,
+                 rms_norm_eps=1e-5, qk_norm=False,
+                 tie_word_embeddings=False, max_seq_len=128,
+                 num_experts=16, num_experts_per_tok=4,
+                 moe_intermediate_size=128, n_shared_experts=1,
+                 routed_scaling_factor=1.0, norm_topk_prob=True,
+                 experts_held=(0, 8), gqa_layers=(0,), use_rope=False,
+                 use_gqa_gate=True, kda_num_heads=8, kda_head_dim=128,
+                 kda_conv_size=4, kda_rank=128,
+                 kda_allow_neg_eigval=True)
         d.update(kw)
         return cls(**d)
 

@@ -44,6 +44,9 @@ __all__ = ["Glm4MoeLite", "MOE_STATS"]
 
 
 class Glm4MoeLite:
+    #: What a decode step leaves in the cache's `stats`, in order.
+    STATS = MOE_STATS
+
     def __init__(self, config: ModelConfig, mesh: Mesh, axis: str = "tp",
                  mode: str = "fused", interpret: Optional[bool] = None,
                  gemm: Optional[MatmulConfig] = None):

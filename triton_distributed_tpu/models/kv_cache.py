@@ -15,6 +15,15 @@ the rotated shared key, zero-padded to a lane multiple — that serves
 as K and as V for every head: ``ks[l]`` is ``(B | P, 1, S | page, R)``
 and ``vs`` is None.  Everything that walks a cache (insert, spill,
 byte accounting) walks ``ks`` and, where there is one, ``vs``.
+
+A third kind of layer keeps no row a token at all: a RECURRENT layer
+(`layers.kda_attn`) holds a fixed-size state a sequence — ``states[i]``
+``(B, H, dk, dv)`` float32 and the short convolution's last inputs
+``convs[i]`` ``(B, (taps - 1) * channels)`` — indexed by batch row (slot)
+on the leading dimension in both layouts.  A hybrid model's cache holds
+both: ``ks`` / ``vs`` list its attention layers, ``states`` / ``convs``
+its recurrent ones, each in layer order; pages, the page table and the
+radix cache concern the former alone.
 """
 
 from __future__ import annotations
@@ -40,6 +49,14 @@ class KVCache:
     #: streaming bytes (measured 1.6–1.66× faster decode).
     kss: Optional[List[jnp.ndarray]] = None
     vss: Optional[List[jnp.ndarray]] = None
+    #: Recurrent layers' state (module docstring); None: there are none.
+    states: Optional[List[jnp.ndarray]] = None
+    convs: Optional[List[jnp.ndarray]] = None
+    #: (B,) int32, read by a prefill whose model has recurrent layers:
+    #: the tokens of each row its state is to absorb (a bucket's padded
+    #: tail must not reach it; attention simply masks the tail later).
+    #: `create` says the whole row.
+    length: Optional[jnp.ndarray] = None
 
     @property
     def quantized(self) -> bool:
@@ -48,14 +65,21 @@ class KVCache:
     @classmethod
     def create(cls, num_layers: int, batch: int, num_kv_heads: int,
                max_seq: int, head_dim: int, dtype=jnp.bfloat16,
-               quantized: bool = False, latent: bool = False):
+               quantized: bool = False, latent: bool = False,
+               state_shapes=None):
         """``latent``: one ``head_dim``-wide row a token and no V
-        (``num_kv_heads`` must be 1)."""
+        (``num_kv_heads`` must be 1).  ``state_shapes``: a recurrent
+        layer's (state, conv) shapes a row, one pair a layer."""
         assert not latent or (num_kv_heads == 1 and not quantized)
         shape = (batch, num_kv_heads, max_seq, head_dim)
         if quantized:
             dtype = jnp.int8
+        states, convs = (_state_pools(batch, state_shapes, dtype)
+                         if state_shapes else (None, None))
         return cls(
+            states=states, convs=convs,
+            length=(jnp.full((batch,), max_seq, jnp.int32)
+                    if state_shapes else None),
             ks=[jnp.zeros(shape, dtype) for _ in range(num_layers)],
             vs=(None if latent else
                 [jnp.zeros(shape, dtype) for _ in range(num_layers)]),
@@ -116,6 +140,10 @@ class KVCache:
             rep.update(kss=kss, vss=vss)
         return dataclasses.replace(self, **rep)
 
+    def set_state(self, layer: int, state, conv):
+        """The ``layer``-th recurrent layer's state and inputs."""
+        return _with_state(self, layer, state, conv)
+
     def inc_offset(self, n: int = 1):
         return dataclasses.replace(self, offset=self.offset + n)
 
@@ -163,6 +191,32 @@ NULL_PAGE = 0
 def pages_for(tokens: int, page_size: int) -> int:
     """Pages needed to hold ``tokens`` KV positions."""
     return -(-int(tokens) // int(page_size)) if tokens > 0 else 0
+
+
+def _state_pools(batch: int, state_shapes, dtype):
+    """(states, convs) of zeros for ``batch`` rows."""
+    return ([jnp.zeros((batch, *st), jnp.float32)
+             for st, _ in state_shapes],
+            [jnp.zeros((batch, *cv), dtype) for _, cv in state_shapes])
+
+
+def _with_state(cache, layer: int, state, conv):
+    states = list(cache.states)
+    convs = list(cache.convs)
+    states[layer] = state
+    convs[layer] = conv.astype(convs[layer].dtype)
+    return dataclasses.replace(cache, states=states, convs=convs)
+
+
+def zero_state_rows(states, convs, b):
+    """(states, convs) of a cache with row (slot) ``b`` zeroed where it
+    lies — run it jitted with both lists donated
+    (`serving.pages.PagedKV.release`): eagerly it would copy every pool
+    whole."""
+    def zero(pool):
+        return jax.lax.dynamic_update_slice_in_dim(
+            pool, jnp.zeros((1, *pool.shape[1:]), pool.dtype), b, axis=0)
+    return [zero(x) for x in states], [zero(x) for x in convs]
 
 
 def write_token_rows(pool, phys, within, rows):
@@ -224,6 +278,11 @@ class PagedKVCache:
     #: `models.glm4_moe_lite.MOE_STATS`); None: the model counts
     #: nothing.
     stats: Optional[jnp.ndarray] = None
+    #: Recurrent layers' state a slot (module docstring): (B, H, dk,
+    #: dv) float32 and (B, (taps - 1) * channels) a layer, rewritten where
+    #: they lie by every decode step.  None: there are none.
+    states: Optional[List[jnp.ndarray]] = None
+    convs: Optional[List[jnp.ndarray]] = None
     #: Tokens per page — static: it shapes the compiled programs.
     page_size: int = dataclasses.field(
         default=16, metadata=dict(static=True))
@@ -254,17 +313,22 @@ class PagedKVCache:
                num_kv_heads: int, page_size: int, head_dim: int,
                max_pages_per_seq: int, dtype=jnp.bfloat16,
                quantized: bool = False, latent: bool = False,
-               num_stats: int = 0):
+               num_stats: int = 0, state_shapes=None):
         """``num_pages`` INCLUDES the reserved null page 0 (usable
         pages = num_pages - 1).  ``latent``: one pool a layer of
         ``head_dim``-wide rows and no V pool (``num_kv_heads`` 1).
-        ``num_stats``: width of the model's `stats` vector."""
+        ``num_stats``: width of the model's `stats` vector.
+        ``state_shapes``: a recurrent layer's (state, conv) shapes a
+        slot, one pair a layer (``num_layers`` counts the others)."""
         assert num_pages >= 2, "need >= 1 usable page beside NULL_PAGE"
         assert not latent or (num_kv_heads == 1 and not quantized)
         shape = (num_pages, num_kv_heads, page_size, head_dim)
         if quantized:
             dtype = jnp.int8
+        states, convs = (_state_pools(batch, state_shapes, dtype)
+                         if state_shapes else (None, None))
         return cls(
+            states=states, convs=convs,
             ks=[jnp.zeros(shape, dtype) for _ in range(num_layers)],
             vs=(None if latent else
                 [jnp.zeros(shape, dtype) for _ in range(num_layers)]),
@@ -294,6 +358,22 @@ class PagedKVCache:
                                      + vs_.dtype.itemsize)
         return total
 
+    def state_bytes_per_slot(self) -> int:
+        """HBM bytes one slot's recurrent state pins across all layers
+        whatever the sequence's length (0: no recurrent layer)."""
+        return sum(math.prod(x.shape[1:]) * x.dtype.itemsize
+                   for x in (self.states or []) + (self.convs or []))
+
+    @property
+    def live_rows(self):
+        """(B,) bool: the rows that hold a sequence — a slot's first
+        logical page is mapped from its insert to its release."""
+        return self.page_table[:, 0] != NULL_PAGE
+
+    def set_state(self, layer: int, state, conv):
+        """The ``layer``-th recurrent layer's state and inputs."""
+        return _with_state(self, layer, state, conv)
+
     def set_layer(self, layer: int, k, v=None, kscale=None, vscale=None):
         ks = list(self.ks)
         ks[layer] = k
@@ -317,7 +397,8 @@ class PagedKVCache:
         """Zero slot ``b``'s offset.  The page-table row is host-
         managed (`serving.pages.PagedKV.release` resets it to
         NULL_PAGE before the next dispatch) — an offset of 0 already
-        masks every position."""
+        masks every position.  (A recurrent layer's state has no such
+        mask: `zero_state_rows` clears it.)"""
         return dataclasses.replace(
             self, offset=self.offset.at[b].set(0))
 

@@ -342,7 +342,9 @@ def make_paged_insert_fn(donate: bool = True):
     pool-geometry).
 
     A latent pool (`models.kv_cache`: ``vs`` None) has one pool a
-    layer to scatter into; the walk is the same.
+    layer to scatter into; the walk is the same.  A recurrent layer's
+    state (``states`` / ``convs``) goes whole into the slot's row of
+    its pool.
 
     ``page_ids`` is a (ceil(bucket / page_size),) int32 vector naming
     the physical destination of each LOCAL page of the row cache;
@@ -388,6 +390,15 @@ def make_paged_insert_fn(donate: bool = True):
         if pool.quantized:
             rep["kss"] = scatter(pool.kss, row.kss, True)
             rep["vss"] = scatter(pool.vss, row.vss, True)
+        if pool.states is not None:
+            # recurrent layers: the row's state, whole, into the slot
+            def put(dst_list, src_list):
+                return [jax.lax.dynamic_update_slice_in_dim(
+                    dst, src.astype(dst.dtype),
+                    jnp.asarray(slot, jnp.int32), axis=0)
+                    for dst, src in zip(dst_list, src_list)]
+            rep["states"] = put(pool.states, row.states)
+            rep["convs"] = put(pool.convs, row.convs)
         keys = jax.lax.dynamic_update_slice(
             keys, key.astype(keys.dtype)[None, :],
             (jnp.asarray(slot, jnp.int32), 0))

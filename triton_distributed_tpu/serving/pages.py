@@ -27,7 +27,11 @@ host-side structures cooperate over one donated `PagedKVCache`:
   grow (`ensure`), page-based admission/feasibility arithmetic, and
   the jitted paged insert.  API mirrors `SlotKV` where the scheduler
   needs it (`can_admit` / `insert_prefill` / `release` /
-  `active_mask` / occupancy properties).
+  `active_mask` / occupancy properties).  A model with recurrent
+  layers also holds a fixed-size state a SLOT (`models.kv_cache`):
+  that pool is sized by slots, paid for out of the byte budget before
+  any page, written whole by the insert and zeroed by `release`; pages,
+  prefix sharing and spill concern its attention layers alone.
 
 - `SpillPool` — graceful degradation under KV pressure: when the
   radix cache must evict a refcount-0 prefix page, its CONTENT is
@@ -61,6 +65,7 @@ import heapq
 import itertools
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -68,6 +73,7 @@ from triton_distributed_tpu.models.kv_cache import (
     NULL_PAGE,
     PagedKVCache,
     pages_for,
+    zero_state_rows,
 )
 from triton_distributed_tpu.serving.engine_batched import (
     make_paged_insert_fn,
@@ -505,10 +511,16 @@ class PagedKV:
         # parity (every slot can reach max_seq simultaneously).
         probe = model.create_paged_cache(1, 2, ps, 1)
         self.bytes_per_page = probe.bytes_per_page()
+        #: What a slot's recurrent layers hold whatever its length
+        #: (0: the model has none).  That pool is sized by slots, not
+        #: pages, and a byte budget pays for it first.
+        self.state_bytes_per_slot = probe.state_bytes_per_slot()
         del probe
+        state_pool = self.num_slots * self.state_bytes_per_slot
         if num_pages is None:
             if kv_budget_bytes:
-                num_pages = int(kv_budget_bytes // self.bytes_per_page)
+                num_pages = int((kv_budget_bytes - state_pool)
+                                // self.bytes_per_page)
             else:
                 num_pages = self.num_slots * t
         self.usable_pages = int(num_pages)
@@ -516,7 +528,8 @@ class PagedKV:
             raise ValueError(
                 f"kv budget holds {self.usable_pages} pages — nothing "
                 f"is ever admittable")
-        self.kv_budget_bytes = self.usable_pages * self.bytes_per_page
+        self.kv_budget_bytes = (self.usable_pages * self.bytes_per_page
+                                + state_pool)
         self.cache: PagedKVCache = model.create_paged_cache(
             self.num_slots, 1 + self.usable_pages, ps, t)
         self.keys = jnp.zeros((self.num_slots, 2), jnp.uint32)
@@ -575,6 +588,11 @@ class PagedKV:
         # host-side page accounting runs against a recording insert
         # and a stub cache, no jit, no device arrays.
         self._insert = insert_fn or make_paged_insert_fn()
+        #: Slots whose state was zeroed since the start
+        #: (``serving_state_resets_total``), and the program that does
+        #: it (`_reset_state`), built at the first release.
+        self.state_resets = 0
+        self._reset = None
 
     # -- occupancy / accounting -----------------------------------------
 
@@ -617,9 +635,11 @@ class PagedKV:
 
     @property
     def bytes_in_use(self) -> int:
-        """TRUE bytes pinned (pages actually allocated) — not the
-        max-context estimate `SlotKV` reports."""
-        return self.used_pages * self.bytes_per_page
+        """TRUE bytes pinned (pages actually allocated, and the
+        recurrent state of the slots in use) — not the max-context
+        estimate `SlotKV` reports."""
+        return (self.used_pages * self.bytes_per_page
+                + self.active_slots * self.state_bytes_per_slot)
 
     def _reclaimable(self) -> int:
         return self.pool.free_pages + (
@@ -981,8 +1001,30 @@ class PagedKV:
         self._mapped[slot] = 0
         self._dirty = True
         self.cache = self.cache.reset_slot(slot)
+        if self.state_bytes_per_slot:
+            self._reset_state(slot)
         self._active[slot] = False
         self._free.append(slot)
+
+    def _reset_state(self, slot: int) -> None:
+        """Zero slot ``slot``'s recurrent state where it lies.  The
+        pools are donated (an eager `.at[].set` would copy them whole)
+        and they alone go through the program: the page table, the
+        pages and the counters stay the arrays they were, as for any
+        other model.  The program hands back arrays PLACED AS IT GOT
+        THEM (a plain `jit` would spell a replicated result its own
+        way, and the insert and the step after a release would meet
+        their argument as a new kind: a compilation in the window)."""
+        c = self.cache
+        if self._reset is None:
+            placed = lambda pools: [x.sharding for x in pools]  # noqa: E731
+            self._reset = jax.jit(
+                zero_state_rows, donate_argnums=(0, 1),
+                out_shardings=(placed(c.states), placed(c.convs)))
+        states, convs = self._reset(c.states, c.convs, np.int32(slot))
+        self.cache = dataclasses.replace(c, states=states, convs=convs)
+        self.state_resets += 1
+        _count_metric("serving_state_resets_total")
 
     # -- spill content I/O (admission path, not the decode hot path) ----
 

@@ -294,6 +294,19 @@ class ContinuousBatchingScheduler:
             self._prefill_suffix = None
         else:
             raise ValueError(f"unknown kv_layout {cfg.kv_layout!r}")
+        #: The model has recurrent layers (`models.kv_cache`): a state
+        #: a slot beside the pages.  Its prefill is told each row's
+        #: true length, and a resumed or prefix-sharing request
+        #: recomputes its state through that prefill (no snapshot).
+        self._stateful = bool(getattr(self.slots, "state_bytes_per_slot",
+                                      0))
+        #: What the decode program leaves in the cache's `stats`, by
+        #: name (`_moe_phase`).
+        self._stats_names = getattr(model, "STATS", ())
+        #: Host-side halves of the `serving.state` span, as of the
+        #: last one: slots reset, tokens whose state was recomputed.
+        self._state_seen = [0, 0]
+        self._state_recomputed = 0
         self._prefill = jax.jit(model.make_prefill_fn())
         self._step = make_masked_step_fn(
             decode_fn, cfg.temperature, cfg.top_k, cfg.top_p,
@@ -972,9 +985,23 @@ class ContinuousBatchingScheduler:
                 self.finished.append(req)
                 return None
             ids, _ = pad_prompt(tokens, bucket, self.config.pad_id)
+            row_in = self._row_cache(bucket)
+            if self._stateful:
+                # the state absorbs what lies below position s-1 (the
+                # first decode step takes that token, as it rewrites
+                # that position's K/V), never the bucket's padded tail
+                row_in = dataclasses.replace(
+                    row_in, length=np.full((1,), s - 1, np.int32))
+                redone = s if req.resume_tokens is not None else c
+                if redone:
+                    # a snapshot of the state would have saved these
+                    self._state_recomputed += redone
+                    if reg:
+                        reg.counter(
+                            "serving_state_recomputed_tokens_total"
+                        ).inc(redone)
             t0 = time.perf_counter()
-            _, row = self._prefill(self.params, ids,
-                                   self._row_cache(bucket))
+            _, row = self._prefill(self.params, ids, row_in)
             row_start = 0
         if reg:
             with span("serving.prefill.block",
@@ -1413,15 +1440,30 @@ class ContinuousBatchingScheduler:
 
     def _moe_phase(self, counted, reg) -> None:
         """After the step's host sync: the counters as a `serving.moe`
-        span's attributes and as metrics."""
+        span's attributes and as metrics — and, where the model keeps
+        a recurrent state, a `serving.state` span beside it."""
+        read = dict(zip(self._stats_names,
+                        (float(v) for v in np.asarray(counted))))
+        live = read.pop("live_slots", None)
         with span("serving.moe") as sp:
-            pairs, hit, load_max = (float(v) for v in
-                                    np.asarray(counted))
-            sp.attrs.update(pairs=pairs, experts_hit=hit,
-                            expert_load_max=load_max)
-        reg.counter("serving_moe_pairs_total").inc(pairs)
-        reg.counter("serving_moe_experts_hit_total").inc(hit)
-        reg.gauge("serving_moe_expert_load_max").set(load_max)
+            sp.attrs.update(read)
+        reg.counter("serving_moe_pairs_total").inc(read["pairs"])
+        reg.counter("serving_moe_experts_hit_total").inc(
+            read["experts_hit"])
+        reg.gauge("serving_moe_expert_load_max").set(
+            read["expert_load_max"])
+        if live is None:
+            return
+        # a model with recurrent layers: what its state pool held in
+        # that step, and what the host did to it since the last one
+        seen = [self.slots.state_resets, self._state_recomputed]
+        with span("serving.state") as sp:
+            sp.attrs.update(
+                live_slots=live,
+                state_bytes_live=live * self.slots.state_bytes_per_slot,
+                resets=seen[0] - self._state_seen[0],
+                recomputed_tokens=seen[1] - self._state_seen[1])
+        self._state_seen = seen
 
     def _spec_outcome(self, rows, accept_host, n_draft, now,
                       reg) -> None:
@@ -1590,6 +1632,12 @@ class ContinuousBatchingScheduler:
                 reg.gauge("serving_kv_latent_bytes_live").set(
                     latent * sum(r.prompt_len + len(r.generated)
                                  for r in self._by_slot.values()))
+            if self._stateful:
+                reg.gauge("serving_state_slots_live").set(
+                    self.slots.active_slots)
+                reg.gauge("serving_state_bytes_live").set(
+                    self.slots.active_slots
+                    * self.slots.state_bytes_per_slot)
             reg.gauge("serving_prefix_cache_pages").set(
                 self.slots.cached_prefix_pages)
             # Per-tier admission accounting mirrored as gauges so the
