@@ -113,3 +113,171 @@ def test_gemm_rs_diff_grads(tp4_mesh):
     for got, want, name in zip(g_fused, g_ref, ("da", "db")):
         assert_allclose(got, want, atol=2e-3, rtol=2e-3,
                         name=f"gemm_rs_diff {name}")
+
+
+# ---------------------------------------------------------------------------
+# The `ll` schedule (PR 34): barrier signalled at entry and awaited
+# before the first put, N block j scattered while block j+1 streams.
+# Held against the SERIAL composition it replaced — barrier, whole
+# matmul, one-shot scatter, reduce — bit for bit.
+# ---------------------------------------------------------------------------
+
+from tests.test_ag_gemm import (  # noqa: E402
+    LL_DECODE_SHAPES,
+    LL_FAULT_IDS,
+    LL_FAULTS,
+    LL_ROWS,
+    serial_chunked_matmul,
+)
+
+
+def _serial_gemm_rs_ll(a, b, config, world=4):
+    """The parent's `gemm_rs_ll`: matmul first, then the scatter."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from triton_distributed_tpu import collective_ids as cids
+    from triton_distributed_tpu.kernels.matmul import round_up_rows
+    from triton_distributed_tpu.kernels.reduce_scatter import (
+        emit_scatter_reduce)
+    from triton_distributed_tpu.utils.platform import (
+        comm_compiler_params, default_interpret)
+
+    mt, k = a.shape
+    n = b.shape[1]
+    mc = mt // world
+    mcp = round_up_rows(mc, a.dtype)
+    a3 = jnp.pad(a.reshape(world, mc, k), ((0, 0), (0, mcp - mc), (0, 0)))
+
+    def body(a_ref, b_ref, o_ref, rbuf_ref, c_ref, ls, ss, rs):
+        serial_chunked_matmul(a_ref, b_ref, c_ref, chunks=world, mc=mcp,
+                              n=n, k=k, config=config)
+        emit_scatter_reduce("tp", world, c_ref, o_ref, rbuf_ref, ls, ss, rs,
+                            m=mcp, n=n)
+
+    any_ = pl.BlockSpec(memory_space=pl.ANY)
+    out, _, _ = pl.pallas_call(
+        body,
+        out_shape=(jax.ShapeDtypeStruct((mcp, n), a.dtype),
+                   jax.ShapeDtypeStruct((world, mcp, n), a.dtype),
+                   jax.ShapeDtypeStruct((world, mcp, n), a.dtype)),
+        in_specs=[any_, any_], out_specs=(any_,) * 3,
+        scratch_shapes=[pltpu.SemaphoreType.DMA(()),
+                        pltpu.SemaphoreType.DMA(()),
+                        pltpu.SemaphoreType.DMA((world,))],
+        compiler_params=comm_compiler_params(cids.GEMM_RS, world),
+        interpret=default_interpret(None),
+    )(a3, b)
+    return out[:mc]
+
+
+@pytest.fixture(scope="module")
+def ll_serial_outputs(tp4_mesh):
+    cache = {}
+
+    def get(name, a, b):
+        if name not in cache:
+            fn = shard_map_op(
+                functools.partial(_serial_gemm_rs_ll,
+                                  config=MatmulConfig(64, 128, 128)),
+                tp4_mesh, in_specs=(P(None, "tp"), P("tp", None)),
+                out_specs=P("tp", None))
+            cache[name] = jax.jit(fn)(a, b)
+        return cache[name]
+
+    return get
+
+
+@pytest.mark.parametrize("fault", LL_FAULTS, ids=LL_FAULT_IDS)
+@pytest.mark.parametrize("name,k_loc,n", LL_DECODE_SHAPES,
+                         ids=[s[0] for s in LL_DECODE_SHAPES])
+def test_gemm_rs_ll_schedule(tp4_mesh, ll_serial_outputs, name, k_loc, n,
+                             fault):
+    """The overlapped `ll` schedule equals the XLA golden, as before,
+    AND the serial composition bit for bit (same blocks, same slot a
+    sender, same order of the sum) — also staggered and with each rank
+    in turn entering late, which is when a put could meet a peer that
+    has not signalled yet."""
+    world = 4
+    a = (jax.random.normal(jax.random.key(31),
+                           (world * LL_ROWS, world * k_loc))
+         / 16).astype(jnp.bfloat16)
+    b = (jax.random.normal(jax.random.key(32), (world * k_loc, n))
+         / 16).astype(jnp.bfloat16)
+    ctx = GEMMReduceScatterContext(axis="tp", world_size=world, method="ll",
+                                   gemm=MatmulConfig(64, 128, 128), **fault)
+    fn = shard_map_op(functools.partial(gemm_rs, ctx=ctx), tp4_mesh,
+                      in_specs=(P(None, "tp"), P("tp", None)),
+                      out_specs=P("tp", None))
+    out = jax.jit(fn)(a, b)
+    assert out.shape == (world * LL_ROWS, n)
+    assert_allclose(out.astype(jnp.float32), _golden(a, b), atol=5e-2,
+                    rtol=5e-2, name=f"gemm_rs_ll_{name}")
+    serial = ll_serial_outputs(name, a, b)
+    assert jnp.array_equal(out, serial), (
+        f"{name}: {int((out != serial).sum())} elements differ from the "
+        f"serial composition")
+
+
+@pytest.mark.parametrize("method,schedule", [
+    ("ll", "scatter_behind_stream"), ("fused", "ring"),
+    ("xla", "collective_then_matmul")])
+def test_gemm_rs_launch_event_names_the_schedule(tp4_mesh, method, schedule):
+    from triton_distributed_tpu.observability import capture_events
+
+    world = 4
+    ctx = GEMMReduceScatterContext(axis="tp", world_size=world,
+                                   method=method)
+    fn = shard_map_op(functools.partial(gemm_rs, ctx=ctx), tp4_mesh,
+                      in_specs=(P(None, "tp"), P("tp", None)),
+                      out_specs=P("tp", None))
+    with capture_events() as events:
+        jax.eval_shape(fn, jnp.zeros((world * 2, world * 128), jnp.bfloat16),
+                       jnp.zeros((world * 128, 256), jnp.bfloat16))
+    (ev,) = [e for e in events if e.op == "gemm_rs"]
+    assert ev.method == method and ev.extra["schedule"] == schedule
+
+
+@pytest.mark.parametrize("name,k_loc,n", LL_DECODE_SHAPES[2:],
+                         ids=[s[0] for s in LL_DECODE_SHAPES[2:]])
+def test_gemm_rs_ll_stream_slices_n_only(tp4_mesh, monkeypatch, name, k_loc,
+                                         n):
+    """As `test_ag_gemm_ll_stream_slices_n_only`: a sliced N block is
+    also a finer scatter — more, smaller puts into the same slot."""
+    from triton_distributed_tpu.kernels import matmul as mm
+
+    monkeypatch.setattr(mm, "_STREAM_BLOCK_BYTES", 32 * 1024)
+    monkeypatch.setattr(mm, "_STREAM_MAX_AHEAD", 3)
+    cfg = MatmulConfig(64, 256, 128)
+    world = 4
+    a = (jax.random.normal(jax.random.key(33),
+                           (world * LL_ROWS, world * k_loc))
+         / 16).astype(jnp.bfloat16)
+    b = (jax.random.normal(jax.random.key(34), (world * k_loc, n))
+         / 16).astype(jnp.bfloat16)
+    specs = dict(in_specs=(P(None, "tp"), P("tp", None)),
+                 out_specs=P("tp", None))
+    ctx = GEMMReduceScatterContext(axis="tp", world_size=world, method="ll",
+                                   gemm=cfg)
+    out = jax.jit(shard_map_op(functools.partial(gemm_rs, ctx=ctx),
+                               tp4_mesh, **specs))(a, b)
+    serial = jax.jit(shard_map_op(
+        functools.partial(_serial_gemm_rs_ll, config=cfg),
+        tp4_mesh, **specs))(a, b)
+    assert jnp.array_equal(out, serial)
+
+
+def test_gemm_rs_ll_refuses_a_receive_buffer_that_cannot_fit_vmem(tp4_mesh):
+    """`gemm_rs_ll` receives and sums the partials in VMEM beside the
+    stream: forced far outside its regime, the kernel's own buffers
+    (counted through `resident=`) must fail the pre-flight readably."""
+    world = 4
+    ctx = GEMMReduceScatterContext(axis="tp", world_size=world, method="ll")
+    fn = shard_map_op(functools.partial(gemm_rs, ctx=ctx), tp4_mesh,
+                      in_specs=(P(None, "tp"), P("tp", None)),
+                      out_specs=P("tp", None))
+    rows, n = 2048, 8192  # rbuf: 4 x 2048 x 8192 bf16 = 128 MB
+    with pytest.raises(ValueError, match="emit_chunked_matmul.*VMEM"):
+        jax.eval_shape(fn, jnp.zeros((world * rows, world * 128),
+                                     jnp.bfloat16),
+                       jnp.zeros((world * 128, n), jnp.bfloat16))

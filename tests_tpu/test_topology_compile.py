@@ -155,6 +155,63 @@ def test_topo_gemm_rs(method, k_loc):
              jnp.bfloat16)
 
 
+#: The tp=4 cell's four fused calls a layer (Qwen3-8B: hidden 4096,
+#: 32 q / 8 kv heads x 128, ffn 12288; this chip's shard): name, K
+#: and N as the kernel sees them.  8 slots = 2 rows a chip.
+LL_DECODE_SHAPES = [
+    ("wqkv", "ag", 4096, 1536),
+    ("gate_up", "ag", 4096, 6144),
+    ("wo", "rs", 1024, 4096),
+    ("down", "rs", 3072, 4096),
+]
+
+
+@pytest.mark.parametrize("rows", [2, "largest"])
+@pytest.mark.parametrize("name,op,k,n", LL_DECODE_SHAPES,
+                         ids=[s[0] for s in LL_DECODE_SHAPES])
+def test_topo_ll_decode_shapes(name, op, k, n, rows):
+    """The two `ll` kernels at the published decode shapes of the
+    four-chip cell on a described v5e:2x2: the hand-written weight
+    stream (all of A, a dozen 1 MB weight slices and, in `gemm_rs_ll`,
+    the receive buffer in VMEM; puts straight out of VMEM, dynamic
+    block offsets) through Mosaic, within the scoped-VMEM limit the
+    kernels ask for — at the cell's 2 rows a chip and at the LARGEST
+    row count for which `resolve_method` still picks `ll` at the
+    shape (32-80 rows a chip), where the resident A, the output
+    blocks and the receive buffer are at their biggest."""
+    from jax.experimental import topologies
+    from triton_distributed_tpu.kernels.allgather_gemm import (
+        AllGatherGEMMContext, ag_gemm)
+    from triton_distributed_tpu.kernels.gemm_reduce_scatter import (
+        GEMMReduceScatterContext, gemm_rs)
+
+    world = 4
+    devs = topologies.get_topology_desc("v5e:2x2", "tpu").devices
+    mesh = Mesh(np.array(devs).reshape(world), ("tp",))
+    ctx = (AllGatherGEMMContext if op == "ag" else GEMMReduceScatterContext)(
+        axis="tp", world_size=world, interpret=False)
+    if rows == "largest":
+        rows = max(r for r in range(16, 1025, 16)
+                   if ctx.resolve_method(r, jnp.bfloat16, k=k, n=n) == "ll")
+        assert ctx.resolve_method(rows + 16, jnp.bfloat16, k=k, n=n) != "ll"
+    assert ctx.resolve_method(rows, jnp.bfloat16, k=k, n=n) == "ll"
+    if op == "ag":
+        compiled = _compile(
+            lambda a, b: ag_gemm(a, b, ctx), mesh,
+            (P("tp", None), P(None, "tp")), P(None, "tp"),
+            [(world * rows, k), (k, world * n)], jnp.bfloat16)
+    else:
+        compiled = _compile(
+            lambda a, b: gemm_rs(a, b, ctx), mesh,
+            (P(None, "tp"), P("tp", None)), P("tp", None),
+            [(world * rows, world * k), (world * k, n)], jnp.bfloat16)
+    # interpret=False: without it a CPU-held run of this file compiles
+    # the interpreter's lowering, not Mosaic's.
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"{'ag_gemm' if op == 'ag' else 'gemm_rs'}_ll" in text
+
+
 # ---------------------------------------------------------------------------
 # Torus schedules: 2-axis (2, 4) and 3-axis (2, 2, 2)
 # ---------------------------------------------------------------------------

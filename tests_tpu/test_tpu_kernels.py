@@ -523,3 +523,89 @@ def test_qwen3_8b_width_layer_fused_vs_xla(seq):
         offset=cache_f.offset).with_page_table(table)
     pf, _ = jax.jit(fused.make_paged_decode_fn(ps))(params, tok, pool)
     close(pf, dx, "paged decode")
+
+
+#: The four-chip cell's fused calls a layer (Qwen3-8B at tp=4, this
+#: chip's shard): name, which op, K and N as the kernel sees them.
+LL_DECODE_SHAPES = [("wqkv", "ag", 4096, 1536), ("gate_up", "ag", 4096, 6144),
+                    ("wo", "rs", 1024, 4096), ("down", "rs", 3072, 4096)]
+
+
+@pytest.mark.parametrize("name,op,k,n", LL_DECODE_SHAPES,
+                         ids=[s[0] for s in LL_DECODE_SHAPES])
+def test_ll_decode_shape_timing(name, op, k, n, tmp_path):
+    """Four chips: one `ll` call at a published decode shape (8 slots =
+    2 rows a chip), timed from a device trace over 16 layers' worth of
+    DIFFERENT weights (one matrix asked again is served from on-chip
+    memory up to ~25 MB and reads 2x too fast), and printed beside its
+    weight bytes at the HBM's peak — the per-kernel share without a
+    cell run (the busiest chip's rows, as the cell's readers sum
+    them).  Asserts only that the kernel ran and agrees with XLA."""
+    from triton_distributed_tpu.kernels.allgather_gemm import (
+        AllGatherGEMMContext, ag_gemm, ag_gemm_nonoverlap)
+    from triton_distributed_tpu.kernels.gemm_reduce_scatter import (
+        GEMMReduceScatterContext, gemm_rs, gemm_rs_nonoverlap)
+
+    world, rows, layers, reps = 4, 2, 16, 8
+    if len(jax.devices()) < world:
+        pytest.skip("needs four chips")
+    mesh = Mesh(np.array(jax.devices()[:world]), ("tp",))
+    if op == "ag":
+        ctx = AllGatherGEMMContext(axis="tp", world_size=world)
+        fused = functools.partial(ag_gemm, ctx=ctx)
+        golden = functools.partial(ag_gemm_nonoverlap, axis="tp")
+        xs, ws, os_ = P("tp", None), P(None, "tp"), P(None, "tp")
+        kf, nf = k, world * n
+    else:
+        ctx = GEMMReduceScatterContext(axis="tp", world_size=world)
+        fused = functools.partial(gemm_rs, ctx=ctx)
+        golden = functools.partial(gemm_rs_nonoverlap, axis="tp")
+        xs, ws, os_ = P(None, "tp"), P("tp", None), P("tp", None)
+        kf, nf = world * k, n
+    assert ctx.resolve_method(rows, jnp.bfloat16, k=k, n=n) == "ll"
+
+    sh = lambda spec: jax.sharding.NamedSharding(mesh, spec)
+    x = jax.jit(lambda: jax.random.normal(
+        jax.random.key(1), (world * rows, kf)).astype(jnp.bfloat16),
+        out_shardings=sh(xs))()
+    gen = jax.jit(lambda key: (jax.random.normal(key, (kf, nf), jnp.bfloat16)
+                               * kf ** -0.5).astype(jnp.bfloat16),
+                  out_shardings=sh(ws))
+    weights = [gen(key) for key in jax.random.split(jax.random.key(2),
+                                                    layers)]
+
+    def chain(fn):
+        def per_chip(x, *ws_):
+            s = jnp.float32(0)
+            for w in ws_:
+                s = fn(x + s.astype(x.dtype), w).reshape(-1)[0].astype(
+                    jnp.float32) * 1e-6
+            return s[None]
+        return jax.jit(jax.shard_map(
+            per_chip, mesh=mesh, in_specs=(xs,) + (ws,) * layers,
+            out_specs=P("tp"), check_vma=False))
+
+    one = lambda fn: jax.jit(jax.shard_map(
+        fn, mesh=mesh, in_specs=(xs, ws), out_specs=os_, check_vma=False))
+    got = one(fused)(x, weights[0]).astype(jnp.float32)
+    ref = one(golden)(x, weights[0]).astype(jnp.float32)
+    assert _rel_err(got, ref) < 2e-2
+
+    f = chain(fused)
+    f(x, *weights).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    for _ in range(reps):
+        r = f(x, *weights)
+    r.block_until_ready()
+    jax.profiler.stop_trace()
+    from cellbench import trace_reduce
+
+    kernel = "ag_gemm_ll" if op == "ag" else "gemm_rs_ll"
+    per_op = trace_reduce.reduce_planes(trace_reduce.read(
+        trace_reduce.find_xplane(str(tmp_path)))).per_op
+    us = sum(s for name_, s in per_op.items()
+             if name_.startswith(kernel)) / (reps * layers) * 1e6
+    floor = k * n * 2 / 819e9 * 1e6
+    print(f"\n{kernel} {name}: {us:.1f} us a call; {k * n * 2} weight "
+          f"bytes = {floor:.1f} us at 819 GB/s ({100 * floor / us:.0f}%)")
+    assert us > 0

@@ -34,7 +34,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from triton_distributed_tpu import collective_ids as cids
 
-from triton_distributed_tpu.kernels.allgather import emit_push_allgather
 from triton_distributed_tpu.kernels.matmul import (
     MatmulConfig,
     emit_chunked_matmul,
@@ -176,16 +175,49 @@ def _ag_gemm_ll_kernel(ctx: AllGatherGEMMContext, mp, n, k,
                        x_ref, b_ref, gathered_ref, out_ref,
                        local_sem, send_sem, recv_sems):
     """Low-latency variant: one-shot push AG (1 hop, all peers
-    concurrent — reference `low_latency_allgather.py:48-217`) then a
-    single chunked matmul that streams B exactly once.  No per-chunk
-    overlap: in this regime comm is microseconds while the GEMM is
-    B-bandwidth-bound, so reading B once IS the optimisation."""
+    concurrent — reference `low_latency_allgather.py:48-217`) and a
+    single chunked matmul that streams B exactly once.
+
+    Schedule (``weights_ahead_of_gather`` in the launch event): every B
+    block needs every peer's rows, so the matmul cannot start before
+    the gather — but its weight stream can.  The entry barrier's
+    SIGNAL (`dl.barrier_all_signal`) goes out first, the first
+    B blocks are put in flight, and only then does the kernel talk to
+    its peers (local copy, barrier WAIT, push, arrivals): the gather's
+    few microseconds pass while HBM is already streaming, and the
+    sends are drained after the matmul, not before it.  At decode
+    shapes a call is 10-60 us of weight streaming, so a serial gather
+    in front of an idle stream cost a fifth of it (PERF.md section 5,
+    PR 34)."""
+    world = ctx.world_size
+    my = jax.lax.axis_index(ctx.axis)
     dl.maybe_straggle(ctx.axis, ctx.straggler)
-    dl.correctness_delay(ctx.axis, ctx.for_correctness)
-    emit_push_allgather(ctx.axis, ctx.world_size, x_ref, gathered_ref,
-                        local_sem, send_sem, recv_sems)
-    emit_chunked_matmul(gathered_ref, b_ref, out_ref, chunks=ctx.world_size,
-                        mc=mp, n=n, k=k, config=ctx.gemm)
+    dl.barrier_all_signal(ctx.axis)
+
+    def gather():
+        dl.correctness_delay(ctx.axis, ctx.for_correctness)
+        own = pltpu.make_async_copy(x_ref, gathered_ref.at[my], local_sem)
+        own.start()
+        dl.barrier_all_wait(ctx.axis)  # peers' gathered_ref
+        for i in range(1, world):
+            pltpu.make_async_remote_copy(
+                src_ref=x_ref,
+                dst_ref=gathered_ref.at[my],
+                send_sem=send_sem,
+                recv_sem=recv_sems.at[my],
+                device_id=dl.peer_id(ctx.axis, jax.lax.rem(my + i, world)),
+                device_id_type=pltpu.DeviceIdType.MESH,
+            ).start()
+        own.wait()
+        for i in range(1, world):
+            peer = jax.lax.rem(my + i, world)
+            dl.wait_recv(gathered_ref.at[peer], recv_sems.at[peer])
+
+    emit_chunked_matmul(gathered_ref, b_ref, out_ref, chunks=world,
+                        mc=mp, n=n, k=k, config=ctx.gemm,
+                        while_prefetching=gather)
+    for _ in range(1, world):
+        dl.wait_send(x_ref, send_sem)
 
 
 def _ag_gemm_2d(a_shard, b, hctx, return_gathered: bool):

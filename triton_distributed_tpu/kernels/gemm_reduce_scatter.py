@@ -36,10 +36,7 @@ from triton_distributed_tpu.kernels.matmul import (
     pad_contraction_lanes,
     round_up_rows,
 )
-from triton_distributed_tpu.kernels.reduce_scatter import (
-    _emit_reduce_sum,
-    emit_scatter_reduce,
-)
+from triton_distributed_tpu.kernels.reduce_scatter import _emit_reduce_sum
 from triton_distributed_tpu.language import core as dl
 from triton_distributed_tpu.utils.platform import (
     comm_compiler_params,
@@ -145,22 +142,67 @@ def _gemm_rs_fused_kernel(ctx: GEMMReduceScatterContext, mc, n, k,
 
 
 def _gemm_rs_ll_kernel(ctx: GEMMReduceScatterContext, mcp, n, k,
-                       a_ref, b_ref, out_ref, rbuf_ref, cstage_ref,
-                       local_sem, send_sem, recv_sems):
-    """Low-latency variant: one chunked matmul (streams B once), then
-    a one-shot scatter of every remote chunk to its owner (1 hop, all
+                       a_ref, b_ref, out_ref, rbuf, osum, recv_sems):
+    """Low-latency variant: one chunked matmul (streams B once) whose
+    N blocks are scattered to their owners as they finish (1 hop, all
     peers concurrent), then the local reduction.  The decode-regime
     `gemm_rs` — reference analogue: the low-latency RS composition
-    rather than the persistent tile-scatter producer."""
+    rather than the persistent tile-scatter producer.
+
+    Schedule (``scatter_behind_stream`` in the launch event): the entry
+    barrier protects the peers' receive buffer, and no peer is
+    written before this chip's first N
+    block is done — so the barrier's SIGNAL goes out at entry and its
+    WAIT stands immediately before the first put, behind the weight
+    stream.  Block j of every chunk travels straight out of the
+    stream's VMEM block into the owner's VMEM (``rbuf``; the own chunk
+    by a local copy) while block j+1's weights stream; only the last
+    block's put, the sum over the ``world`` partials (in VMEM, no
+    trip through HBM) and one write of the result stay exposed.  Same
+    bytes on the wire, same slot per sender, same order of the sum.
+    At decode shapes a call is 10-35 us of weight streaming, and the
+    serial form (barrier, whole matmul, scatter, a pipelined reduce
+    out of HBM) cost 9-17 us on top (PERF.md section 5, PR 34)."""
     world = ctx.world_size
+    my = jax.lax.axis_index(ctx.axis)
     dl.maybe_straggle(ctx.axis, ctx.straggler)
-    dl.entry_barrier(ctx.axis, world)  # every peer puts into rbuf_ref
+    dl.barrier_all_signal(ctx.axis)
     dl.correctness_delay(ctx.axis, ctx.for_correctness)
-    emit_chunked_matmul(a_ref, b_ref, cstage_ref, chunks=world,
-                        mc=mcp, n=n, k=k, config=ctx.gemm)
-    emit_scatter_reduce(ctx.axis, world, cstage_ref, out_ref, rbuf_ref,
-                        local_sem, send_sem, recv_sems, m=mcp, n=n,
-                        barrier=False)
+
+    def scatter_block(j, blk, cols, sem):
+        @pl.when(j == 0)
+        def _():
+            dl.barrier_all_wait(ctx.axis)  # peers' rbuf
+
+        # Partial chunk c goes to owner c; slot = my rank on the
+        # receiver.  Our own partial for our own chunk stays here.
+        pltpu.make_async_copy(blk.at[my], rbuf.at[my, :, cols], sem).start()
+        for i in range(1, world):
+            peer = jax.lax.rem(my + i, world)
+            pltpu.make_async_remote_copy(
+                src_ref=blk.at[peer],
+                dst_ref=rbuf.at[my, :, cols],
+                send_sem=sem,
+                recv_sem=recv_sems.at[my],
+                device_id=dl.peer_id(ctx.axis, peer),
+                device_id_type=pltpu.DeviceIdType.MESH,
+            ).start()
+
+    emit_chunked_matmul(a_ref, b_ref, chunks=world, mc=mcp, n=n, k=k,
+                        config=ctx.gemm, write_block=scatter_block,
+                        resident=[(rbuf.shape, rbuf.dtype),
+                                  (osum.shape, osum.dtype)])
+
+    # Wait for the other world-1 partials of *our* chunk to land.
+    for i in range(1, world):
+        peer = jax.lax.rem(my + i, world)
+        dl.wait_recv(rbuf.at[peer], recv_sems.at[peer])
+    acc = rbuf[0].astype(jnp.float32)
+    for w in range(1, world):
+        acc = acc + rbuf[w].astype(jnp.float32)
+    osum[...] = acc.astype(osum.dtype)
+    # Our own slot of recv_sems is otherwise unused: nobody puts to it.
+    dl.local_copy(osum, out_ref, recv_sems.at[my])
 
 
 def _gemm_rs_2d(a, b, hctx):
@@ -269,39 +311,35 @@ def gemm_rs(a, b, ctx):
     # compile catch at k_local=64 — interpret mode accepts anything).
     a3, b, k = pad_contraction_lanes(a3, b)
 
+    out_shape = [jax.ShapeDtypeStruct((mcp, n), a.dtype)]
     if method == "ll":
         kernel = _gemm_rs_ll_kernel
-        # Full-width compute staging (chunked matmul output).
-        stage_shape = (world, mcp, n)
-        scratch = [
-            pltpu.SemaphoreType.DMA(()),
-            pltpu.SemaphoreType.DMA(()),
-            pltpu.SemaphoreType.DMA((world,)),
-        ]
+        # The partials leave straight from the stream's VMEM blocks and
+        # are received, and summed, in VMEM.
+        scratch = [pltpu.VMEM((world, mcp, n), a.dtype),
+                   pltpu.VMEM((mcp, n), a.dtype),
+                   pltpu.SemaphoreType.DMA((world,))]
     else:
         kernel = _gemm_rs_fused_kernel
-        # Double-buffered send staging (per-chunk matmul + put).
-        stage_shape = (2, mcp, n)
+        # HBM receive buffer and double-buffered send staging
+        # (per-chunk matmul + put) are extra outputs (discarded) —
+        # Mosaic only allows vmem/smem/semaphore scratch.
+        out_shape += [jax.ShapeDtypeStruct((world, mcp, n), a.dtype),
+                      jax.ShapeDtypeStruct((2, mcp, n), a.dtype)]
         scratch = [
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((world,)),
         ]
 
-    # HBM receive/staging buffers are extra outputs (discarded) —
-    # Mosaic only allows vmem/smem/semaphore scratch.
-    out, _, _ = pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(kernel, ctx, mcp, n, k),
         name="gemm_rs_ll" if method == "ll" else "gemm_rs_fused",
-        out_shape=(
-            jax.ShapeDtypeStruct((mcp, n), a.dtype),
-            jax.ShapeDtypeStruct((world, mcp, n), a.dtype),
-            jax.ShapeDtypeStruct(stage_shape, a.dtype),
-        ),
+        out_shape=tuple(out_shape),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=(pl.BlockSpec(memory_space=pl.ANY),) * 3,
+        out_specs=(pl.BlockSpec(memory_space=pl.ANY),) * len(out_shape),
         scratch_shapes=scratch,
         compiler_params=comm_compiler_params(ctx.collective_id, world),
         cost_estimate=pl.CostEstimate(
@@ -311,7 +349,7 @@ def gemm_rs(a, b, ctx):
             transcendentals=0,
         ),
         interpret=default_interpret(ctx.interpret),
-    )(a3, b)
+    )(a3, b)[0]
     return out[:mc] if mcp != mc else out
 
 
@@ -435,6 +473,6 @@ def _analysis_gemm_rs_ll(axis_sizes):
               RefSpec("b", (k, n), jnp.bfloat16),
               RefSpec("out", (mc, n), jnp.bfloat16),
               RefSpec("rbuf", (world, mc, n), jnp.bfloat16),
-              RefSpec("cstage", (world, mc, n), jnp.bfloat16)],
-        sems=[SemSpec("local"), SemSpec("send"), SemSpec("recv", (world,))],
+              RefSpec("osum", (mc, n), jnp.bfloat16)],
+        sems=[SemSpec("recv", (world,))],
     )

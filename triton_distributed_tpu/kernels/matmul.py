@@ -219,59 +219,172 @@ def emit_matmul(a_ref, b_ref, o_ref, *, m, n, k,
     )
 
 
-def emit_chunked_matmul(a_ref, b_ref, o_ref, *, chunks, mc, n, k,
-                        config: Optional[MatmulConfig] = None):
-    """O[w] = A[w] @ B for all ``chunks`` row-chunks in ONE pipeline.
+#: The few-rows stream's DMA granule: an N block of `MatmulConfig` is
+#: fetched and multiplied in column slices of at most this many bytes.
+#: Measured on one v5e chip at the four-chip cell's decode shapes
+#: (PERF.md section 5, PR 34): 4 MB blocks stream at 77-89% of the HBM's
+#: peak, 1 MB slices at 85-93% — the first slice arrives sooner and
+#: the last one's multiply, which nothing hides, is a quarter as long.
+_STREAM_BLOCK_BYTES = 1 << 20
+#: How far the stream's DMAs run ahead of the MXU: enough bytes in
+#: flight (~15 us of HBM time) that a fused kernel's talk with its
+#: peers passes behind them, in at most this many blocks.
+_STREAM_AHEAD_BYTES = 12 << 20
+_STREAM_MAX_AHEAD = 12
 
-    ``a_ref``: (chunks, mc, k), ``o_ref``: (chunks, mc, n) HBM refs.
+
+def _stream_plan(cfg: "MatmulConfig", n: int, k: int, itemsize: int):
+    """(bn, ahead) of the few-rows stream for resolved blocks ``cfg``:
+    the column slice of an N block that one DMA fetches, and how many
+    blocks are in flight ahead of the one being multiplied.  Only N is
+    sliced — block_k, and with it the order of every sum over K, is
+    `MatmulConfig.resolve`'s — so the results do not depend on it."""
+    bn = min(cfg.block_n, n)
+    while cfg.block_k * bn * itemsize > _STREAM_BLOCK_BYTES and bn % 256 == 0:
+        bn //= 2
+    total = pl.cdiv(n, bn) * pl.cdiv(k, cfg.block_k)
+    ahead = max(2, _STREAM_AHEAD_BYTES // (cfg.block_k * bn * itemsize))
+    return bn, min(ahead, _STREAM_MAX_AHEAD, total)
+
+
+def emit_chunked_matmul(a_ref, b_ref, o_ref=None, *, chunks, mc, n, k,
+                        config: Optional[MatmulConfig] = None,
+                        while_prefetching=None, write_block=None,
+                        resident=()):
+    """O[w] = A[w] @ B for all ``chunks`` row-chunks, B streamed ONCE.
+
+    ``a_ref``: (chunks, mc, k), ``b_ref``: (k, n), ``o_ref``:
+    (chunks, mc, n) HBM refs.
 
     For the latency regime (decode: mc is a handful of rows) the cost
     of a GEMM is streaming B from HBM, not FLOPs — so unlike a loop of
     per-chunk `emit_matmul` (which would re-read B per chunk, a
-    ``chunks``× bandwidth blowup) every B block is fetched exactly
+    ``chunks``x bandwidth blowup) every B block is fetched exactly
     once and multiplied against *all* chunks while resident in VMEM.
     The accumulator holds all chunks of one N block: chunks*mc rows,
     small by the regime's definition.  Reference analogue: the
     low-latency AG + single GEMM composition
     (`kernels/nvidia/low_latency_allgather.py:48-217`).
+
+    The stream is written by hand (`_stream_plan`: B fetched in ~1 MB
+    column slices, a dozen of them in flight ahead of the MXU) rather
+    than with `emit_pipeline`, because the fused ``ll`` kernels need
+    two things a pipeline has no hook for — at a 10-60 us call every
+    microsecond the B stream stands still while the kernel talks to a
+    peer is a tenth of the call:
+
+    - ``while_prefetching()`` runs AFTER the first B blocks are in
+      flight and BEFORE A is read: communication that produces
+      ``a_ref`` (the all-gather) goes here and hides behind them.
+    - ``write_block(j, blk, cols, sem)`` replaces the write of N block
+      ``j`` to ``o_ref``: ``blk`` is the finished (chunks, mc, bn)
+      block in VMEM, ``cols`` its column slice of the output.  It
+      must START DMAs out of ``blk`` that move the block's bytes in
+      total, all signalling ``sem`` (local copies as their semaphore,
+      remote puts as their send semaphore), and wait for none: the
+      stream waits before it reuses the buffer, and drains the rest
+      before it returns — so block j travels while block j+1 streams.
+
+    block_k and the order over K are `MatmulConfig.resolve`'s, the
+    accumulation is float32 with one cast at the end of a block: the
+    results are those of the pipelined form this replaces, bit for bit
+    (tier-1 holds it; on the chip at the cell's four shapes, PR 34).
+
+    The stream keeps all of A, ``ahead + 1`` B blocks, two output
+    blocks and the accumulator in VMEM — more than the pipelined form
+    did, and growing with the rows — so its working set, plus the
+    ``resident`` (shape, dtype) buffers the calling kernel holds in
+    VMEM beside it, is checked against ``MATMUL_VMEM_LIMIT`` here,
+    with a readable message instead of a Mosaic abort.
     """
     cfg = (config or MatmulConfig()).resolve(chunks * mc, n, k)
-    nk = pl.cdiv(k, cfg.block_k)
-    bn = min(cfg.block_n, n)
+    bk = cfg.block_k
+    bn, ahead = _stream_plan(cfg, n, k, jnp.dtype(b_ref.dtype).itemsize)
+    nk, nj = pl.cdiv(k, bk), pl.cdiv(n, bn)
+    total = nj * nk
+    depth = ahead + 1
+    dtype = a_ref.dtype
 
-    def inner(a_blk, b_blk, o_blk, acc_ref):
-        kk = pl.program_id(1)
+    if write_block is None:
+        def write_block(j, blk, cols, sem):
+            del j
+            pltpu.make_async_copy(blk, o_ref.at[:, :, cols], sem).start()
 
-        @pl.when(kk == 0)
-        def _():
-            acc_ref[:] = jnp.zeros_like(acc_ref)
+    def run(a_buf, b_buf, o_buf, acc_ref, a_sem, b_sems, o_sems):
+        def b_copy(j, kk, slot):
+            return pltpu.make_async_copy(
+                b_ref.at[pl.ds(kk * bk, bk),
+                         pl.ds(pl.multiple_of(j * bn, bn), bn)],
+                b_buf.at[slot], b_sems.at[slot])
 
-        a2 = a_blk[:].reshape(chunks * mc, a_blk.shape[-1])
-        acc_ref[:] += jnp.dot(a2, b_blk[:],
-                              preferred_element_type=jnp.float32)
+        def o_wait(slot):
+            # Drains one block's bytes, whatever DMAs carried them.
+            pltpu.make_async_copy(o_buf.at[slot], o_buf.at[slot],
+                                  o_sems.at[slot]).wait()
 
-        @pl.when(kk == nk - 1)
-        def _():
-            o_blk[:] = acc_ref[:].reshape(o_blk.shape).astype(o_blk.dtype)
+        for t in range(ahead):
+            b_copy(t // nk, t % nk, t).start()
+        if while_prefetching is not None:
+            while_prefetching()
+        a_copies = [
+            pltpu.make_async_copy(a_ref.at[:, :, pl.ds(kk * bk, bk)],
+                                  a_buf.at[kk], a_sem)
+            for kk in range(nk)]
+        for cp in a_copies:
+            cp.start()
+        for cp in a_copies:
+            cp.wait()
 
-    def run(acc_ref):
-        pipeline = pltpu.emit_pipeline(
-            functools.partial(inner, acc_ref=acc_ref),
-            grid=(pl.cdiv(n, bn), nk),
-            in_specs=[
-                pl.BlockSpec((chunks, mc, cfg.block_k),
-                             lambda j, kk: (0, 0, kk)),
-                pl.BlockSpec((cfg.block_k, bn), lambda j, kk: (kk, j)),
-            ],
-            out_specs=[
-                pl.BlockSpec((chunks, mc, bn), lambda j, kk: (0, 0, j)),
-            ],
-        )
-        pipeline(a_ref, b_ref, o_ref)
+        def n_block(j, carry):
+            for kk in range(nk):
+                t = j * nk + kk
+                slot = jax.lax.rem(t, depth)
+                b_copy(j, kk, slot).wait()
 
+                @pl.when(t + ahead < total)
+                def _():
+                    b_copy(j + (kk + ahead) // nk, (kk + ahead) % nk,
+                           jax.lax.rem(t + ahead, depth)).start()
+
+                if kk == 0:
+                    acc_ref[...] = jnp.zeros_like(acc_ref)
+                a2 = a_buf[kk].reshape(chunks * mc, bk)
+                acc_ref[...] += jnp.dot(
+                    a2, b_buf[slot], preferred_element_type=jnp.float32)
+
+            oslot = jax.lax.rem(j, 2)
+
+            @pl.when(j >= 2)
+            def _():
+                o_wait(oslot)
+
+            o_buf[oslot] = acc_ref[...].reshape(chunks, mc, bn).astype(dtype)
+            write_block(j, o_buf.at[oslot],
+                        pl.ds(pl.multiple_of(j * bn, bn), bn),
+                        o_sems.at[oslot])
+            return carry
+
+        jax.lax.fori_loop(0, nj, n_block, 0)
+        for j in range(max(nj - 2, 0), nj):
+            o_wait(j % 2)
+
+    buffers = dict(
+        a_buf=((nk, chunks, mc, bk), dtype),
+        b_buf=((depth, bk, bn), b_ref.dtype),
+        o_buf=((2, chunks, mc, bn), dtype),
+        acc_ref=((chunks * mc, bn), jnp.float32),
+    )
+    resources.check_vmem_fit(
+        "emit_chunked_matmul", [],
+        list(buffers.values()) + list(resident),
+        limit=MATMUL_VMEM_LIMIT, double_buffer=False)
     pl.run_scoped(
         run,
-        acc_ref=pltpu.VMEM((chunks * mc, bn), jnp.float32),
+        **{name: pltpu.VMEM(shape, dt)
+           for name, (shape, dt) in buffers.items()},
+        a_sem=pltpu.SemaphoreType.DMA(()),
+        b_sems=pltpu.SemaphoreType.DMA((depth,)),
+        o_sems=pltpu.SemaphoreType.DMA((2,)),
     )
 
 

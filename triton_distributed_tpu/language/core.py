@@ -201,6 +201,26 @@ def barrier_all(axis: str, sem=None):
 
     Kernels using this must set a ``collective_id`` in CompilerParams.
     """
+    barrier_all_signal(axis, sem)
+    barrier_all_wait(axis, sem)
+
+
+def barrier_all_signal(axis: str, sem=None):
+    """First half of :func:`barrier_all`: tell every peer this device
+    has arrived.  Does not block.
+
+    Issued at kernel entry with :func:`barrier_all_wait` immediately
+    before this device's first remote put, the pair is the SECOND
+    allowed form of the entry barrier (the first: :func:`entry_barrier`).
+    It is as safe: the barrier exists so that no device puts into a
+    peer that is still in the previous program; a put is only issued
+    after the wait, and the wait only returns once EVERY peer has
+    signalled from inside this program.  What moves is that purely
+    local work (a weight stream, a local copy) runs between the
+    halves, so the barrier's latency and the skew between chips hide
+    behind it.  A second launch cannot confuse the counts: a device
+    leaves the program only after every peer's data has arrived, and
+    a peer sends only after its own wait returned."""
     n = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     bsem = pltpu.get_barrier_semaphore() if sem is None else sem
@@ -212,7 +232,13 @@ def barrier_all(axis: str, sem=None):
         return 0
 
     jax.lax.fori_loop(1, n, body, 0)
-    pltpu.semaphore_wait(bsem, n - 1)
+
+
+def barrier_all_wait(axis: str, sem=None):
+    """Second half of :func:`barrier_all`: block until every peer has
+    signalled its arrival (consumes the n-1 signals)."""
+    bsem = pltpu.get_barrier_semaphore() if sem is None else sem
+    pltpu.semaphore_wait(bsem, jax.lax.axis_size(axis) - 1)
 
 
 # NVSHMEM `sync_all` parity: barrier without a DMA-drain (quiet); see

@@ -166,6 +166,19 @@ def estimate_overlap_gemm_us(op: str, m: int, n: int, k: int,
     return t_overlap
 
 
+#: (op, method) -> the name of the schedule a fused-GEMM program was
+#: BUILT with, carried as ``extra["schedule"]`` beside ``method`` in
+#: its launch event (table in `docs/observability.md`).
+OVERLAP_GEMM_SCHEDULES = {
+    ("ag_gemm", "ll"): "weights_ahead_of_gather",
+    ("gemm_rs", "ll"): "scatter_behind_stream",
+    ("ag_gemm", "fused"): "ring",
+    ("gemm_rs", "fused"): "ring",
+    ("ag_gemm", "xla"): "collective_then_matmul",
+    ("gemm_rs", "xla"): "collective_then_matmul",
+}
+
+
 def record_overlap_gemm(op: str, *, axis, world: int, method, m: int,
                         n: int, k: int, dtype, config=None, hops=None,
                         **extra):
@@ -174,6 +187,8 @@ def record_overlap_gemm(op: str, *, axis, world: int, method, m: int,
     if not observability_enabled():
         return None
     method_s = method.value if hasattr(method, "value") else method
+    if (op, method_s) in OVERLAP_GEMM_SCHEDULES:
+        extra["schedule"] = OVERLAP_GEMM_SCHEDULES[op, method_s]
     chunk_bytes = (m * (k if op.startswith("ag_gemm") else n)
                    * _itemsize(dtype))
     if world > 1:
