@@ -2,7 +2,7 @@
 
 `cellbench/tests` lies outside `pytest tests/`, so the yardstick's own
 unit tests broke unseen (one has been red since PR 28).  This file
-brings the cases of its eight subprocess-free files into tier-1, each
+brings the cases of its nine subprocess-free files into tier-1, each
 under its own id (`test_<file>__<case>`): the test functions and the
 fixtures they ask for are imported, nothing is copied and nothing
 under `cellbench/` is edited.
@@ -32,7 +32,8 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 FILES = ("test_model_math", "test_model_math_glm4_moe_lite",
-         "test_model_math_solar_open2", "test_stats",
+         "test_model_math_solar_open2", "test_model_math_sdar_moe",
+         "test_stats",
          "test_traffic_gen", "test_trace_reduce", "test_span_readers",
          "test_trace_bound")
 #: ids of this file's, see the docstring

@@ -195,6 +195,14 @@ def _solar_system(devices):
                               devices[:1])
 
 
+def _sdar_system(devices):
+    from cellbench.adapters import sdar_moe
+    from tests.test_sdar_moe import TINY
+    serving = dict(TINY["serving"], num_slots=8,
+                   kv_budget_bytes_per_chip=8 * 128 * 2 * 2 * 16 * 2)
+    return sdar_moe.System(dict(TINY, serving=serving), 11, devices[:1])
+
+
 #: Slots for `warm_up` to admit both requests of every bucket in one
 #: call (1 + 2 x 3 buckets here), as every cell but the two of seven
 #: buckets on eight slots has: there an insert of the fourth bucket
@@ -202,7 +210,7 @@ def _solar_system(devices):
 #: parent as on this scheduler (PERF.md section 7).
 SYSTEMS = {"toy": lambda devices: ToySystem(),
            "qwen3": _qwen_system, "glm4_moe_lite": _glm_system,
-           "solar_open2": _solar_system,
+           "solar_open2": _solar_system, "sdar_moe": _sdar_system,
            "qwen3-tp4": lambda devices: _qwen_system(devices, 4)}
 #: six minutes of interpreted ring kernels: by hand (`-m slow`)
 FAMILIES = [pytest.param(f, marks=pytest.mark.slow) if f == "qwen3-tp4"
@@ -241,6 +249,10 @@ def programs(sched):
     out = {"merge": sched._merge, "step": sched._step,
            "keep": sched._keep, "prefill": sched._prefill,
            "insert": sched.slots._insert}
+    if sched._block > 1:
+        # a block pass takes the rows the host knows itself: the block
+        # state it returned stays on the device, nothing is merged
+        del out["merge"]
     if getattr(sched.slots, "_reset", None) is not None:
         out["reset"] = sched.slots._reset
     if sched._prefill_suffix is not None:
@@ -263,7 +275,7 @@ def test_no_program_and_no_kind_of_argument_is_first_met_after_warm_up(
     warmed = cellrun.warm_up(system, Plan((lo, hi), 3 * ps), seed=5)
     assert warmed["buckets"] == buckets and not system.has_work()
     before = cache_sizes(sched)
-    assert before["merge"] >= 1 and before["step"] >= 1
+    assert before.get("merge", 1) >= 1 and before["step"] >= 1
     rng = np.random.default_rng(17)
     vocab = system.config["vocab_size"]
     served = []

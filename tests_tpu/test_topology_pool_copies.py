@@ -270,3 +270,62 @@ def test_no_state_sized_copy_in_step_insert_or_reset(topo_devices):
             print(f"solar_open2 {name} {what} {shape}: {found}")
             assert not found["layout"], (name, what, found)
             assert len(found["staged"]) <= STAGED_MAX, (name, what, found)
+
+
+def test_no_pool_sized_copy_in_a_block_pass(topo_devices):
+    """The block-diffusion family (`models/sdar_moe.py`): a pass
+    writes every row's block — four rows a slot a layer — into its
+    mapped pages through the same scatter as a decode step's one row,
+    and `flash_decode_paged` takes 32 query rows a KV head.  Compiled
+    at the published widths for the described v5e: Mosaic takes the
+    kernel at those rows and no pool is copied."""
+    from triton_distributed_tpu.models.sdar_moe import SdarMoe
+    from triton_distributed_tpu.serving.engine_batched import (
+        make_block_pass_fn)
+
+    c = _config("sdar-30b-a3b-1c.json")
+    gen = c["generation"]
+    cfg = ModelConfig(
+        architecture=c["model_type"], vocab_size=c["vocab_size"],
+        hidden_size=c["hidden_size"],
+        intermediate_size=c["intermediate_size"], num_layers=LAYERS,
+        num_heads=c["num_attention_heads"],
+        num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
+        rms_norm_eps=c["rms_norm_eps"], rope_theta=c["rope_theta"],
+        qk_norm=True, tie_word_embeddings=False, max_seq_len=3584,
+        dtype=c["torch_dtype"], num_experts=c["num_experts"],
+        num_experts_per_tok=c["num_experts_per_tok"],
+        moe_intermediate_size=c["moe_intermediate_size"],
+        n_shared_experts=0, norm_topk_prob=c["norm_topk_prob"],
+        moe_scoring="softmax", block_length=gen["block_length"],
+        denoising_steps=gen["denoising_steps"],
+        remasking=gen["remasking"], mask_token_id=c["mask_token_id"])
+    model = SdarMoe(cfg, Mesh(np.array(topo_devices[:1]), ("tp",)),
+                    mode="fused", interpret=False)
+    slots, n = 64, gen["block_length"]
+    pages = slots * 3584 // PAGE + 1
+    rep = NamedSharding(model.mesh, P())
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
+        shape, dt, sharding=rep)
+    pool = _shaped(model, functools.partial(
+        PagedKVCache.create, LAYERS, pages, slots, 4, PAGE, 128,
+        3584 // PAGE, model.dtype, num_stats=len(model.STATS)),
+        model._paged_cache_specs(PAGE))
+    params = _shaped(
+        model, lambda: model.init_params(jax.random.key(0)),
+        model.param_specs())
+    blk, flag = arg((slots, 2, n), jnp.int32), arg((slots,), jnp.bool_)
+    step = make_block_pass_fn(
+        model.make_paged_decode_fn(PAGE), n, cfg.mask_token_id,
+        cfg.remasking).lower(
+            params, blk, pool, blk, flag, flag, flag,
+            arg((slots,), jnp.int32)).compile().as_text()
+    shard = (pages, 4, PAGE, 128)
+    for kernel in ("flash_decode_paged", "moe_decode_gate_up",
+                   "moe_decode_down"):
+        assert f"%{kernel}" in step, kernel
+    assert f"[{','.join(map(str, shard))}]" in step
+    found = pool_copies(step, shard)
+    print(f"sdar_moe block pass, pool {shard}: {found}")
+    assert not found["layout"] and len(found["staged"]) <= STAGED_MAX, (
+        found)

@@ -52,9 +52,19 @@ def zero_oob_rows(v, block_idx, block_rows: int, bound: int):
     return jnp.where(row < bound, v, 0)
 
 
+def _row_limit(q_pos, cblock: int):
+    """The last KV column query position ``q_pos`` may see.  Plain
+    causal (``cblock`` <= 1): itself.  BLOCK-causal: the end of its
+    block of ``cblock`` positions — causal across blocks, bidirectional
+    inside one (the diagonal widened to the block)."""
+    if cblock <= 1:
+        return q_pos
+    return (q_pos // cblock) * cblock + (cblock - 1)
+
+
 def _emit_attend(q, k_ref, v_ref, m_scr, l_scr, acc_scr, *,
                  masked, causal, ragged, qi, ki, off, sk,
-                 block_q, block_k):
+                 block_q, block_k, cblock=0):
     """One online-softmax block update (shared by the rectangular and
     packed kernels).  ``q`` is the loaded, pre-scaled (bq, D) row
     block (the kernels scale into a scratch once per row — a host-side
@@ -89,7 +99,8 @@ def _emit_attend(q, k_ref, v_ref, m_scr, l_scr, acc_scr, *,
                      + jax.lax.broadcasted_iota(
                          jnp.int32, (block_q, block_k), 0)
                      + off)
-            s = jnp.where(k_pos <= q_pos, s, NEG_INF)
+            s = jnp.where(k_pos <= _row_limit(q_pos, cblock), s,
+                          NEG_INF)
 
     m_prev = m_scr[:]                 # (bq, 1), log2 domain
     m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -106,7 +117,7 @@ def _emit_attend(q, k_ref, v_ref, m_scr, l_scr, acc_scr, *,
 
 
 def _emit_attend_diag(q, k_ref, v_ref, m_scr, l_scr, acc_scr, *,
-                      block_q, block_k, sub):
+                      block_q, block_k, sub, cblock=0):
     """Static block-lower-triangular attend for EXACT-diagonal causal
     blocks (mask offset 0 — guaranteed by the caller when the packed
     schedule runs with ``off % block_k == 0`` and ``block_q ==
@@ -125,7 +136,8 @@ def _emit_attend_diag(q, k_ref, v_ref, m_scr, l_scr, acc_scr, *,
     nt = block_q // sub
     row = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
-    tri = col <= row              # one (sub, sub) mask, reused nt×
+    # one (sub, sub) mask, reused nt× (`cblock` divides `sub`)
+    tri = col <= _row_limit(row, cblock)
     for i in range(nt):
         rows = slice(i * sub, (i + 1) * sub)
         qi_rows = q[rows]                          # (sub, D)
@@ -164,6 +176,7 @@ def _emit_epilogue(o_ref, lse_ref, m_scr, l_scr, acc_scr):
 
 def _flash_kernel(nk: int, sk: int, causal: bool, scale: float,
                   block_q: int, block_k: int, with_lse: bool,
+                  cblock: int,
                   off_ref, q_ref, k_ref, v_ref, *rest):
     """Grid: (B, H, nq, nk); blocks: q (1,1,bq,D), k/v (1,1,bk,D).
 
@@ -206,7 +219,7 @@ def _flash_kernel(nk: int, sk: int, causal: bool, scale: float,
         _emit_attend(qs_scr[:], k_ref, v_ref, m_scr, l_scr, acc_scr,
                      masked=masked, causal=causal, ragged=ragged,
                      qi=qi, ki=ki, off=off_ref[0], sk=sk,
-                     block_q=block_q, block_k=block_k)
+                     block_q=block_q, block_k=block_k, cblock=cblock)
 
     if causal:
         # Skip blocks entirely above the causal diagonal (their every
@@ -217,12 +230,12 @@ def _flash_kernel(nk: int, sk: int, causal: bool, scale: float,
         # inside a visible block produces the classic p = exp(0)
         # uniform average instead.  Callers that can present
         # fully-masked rows must consume lse.
-        visible = ki * block_k <= (qi * block_q + block_q - 1
-                                   + off_ref[0])
+        visible = ki * block_k <= _row_limit(
+            qi * block_q + block_q - 1 + off_ref[0], cblock)
         # Fully-visible blocks (last k column <= the block's FIRST
         # query's limit) need no causal mask.
         fully = (ki * block_k + block_k - 1
-                 <= qi * block_q + off_ref[0])
+                 <= _row_limit(qi * block_q + off_ref[0], cblock))
         if ragged:
             fully = jnp.logical_and(fully, ki != nk - 1)
         pl.when(jnp.logical_and(visible, fully))(
@@ -242,7 +255,7 @@ def _flash_kernel(nk: int, sk: int, causal: bool, scale: float,
 
 def _flash_kernel_packed(sk: int, scale: float,
                          block_q: int, block_k: int, with_lse: bool,
-                         diag_sub: int,
+                         diag_sub: int, cblock: int,
                          off_ref, qmap_ref, kmap_ref, flags_ref,
                          q_ref, k_ref, v_ref, *rest):
     """PACKED causal grid (B, H, n_vis): the third dim walks only the
@@ -285,7 +298,7 @@ def _flash_kernel_packed(sk: int, scale: float,
         _emit_attend(qs_scr[:], k_ref, v_ref, m_scr, l_scr, acc_scr,
                      masked=masked, causal=True, ragged=ragged,
                      qi=qi, ki=ki, off=off_ref[0], sk=sk,
-                     block_q=block_q, block_k=block_k)
+                     block_q=block_q, block_k=block_k, cblock=cblock)
 
     attend = jax.lax.rem(flags // 4, 2) == 1
     masked = jax.lax.rem(flags // 8, 2) == 1
@@ -299,7 +312,8 @@ def _flash_kernel_packed(sk: int, scale: float,
         pl.when(jnp.logical_and(attend, diag))(
             lambda: _emit_attend_diag(
                 qs_scr[:], k_ref, v_ref, m_scr, l_scr, acc_scr,
-                block_q=block_q, block_k=block_k, sub=diag_sub))
+                block_q=block_q, block_k=block_k, sub=diag_sub,
+                cblock=cblock))
     else:
         pl.when(jnp.logical_and(attend, masked))(
             lambda: attend_block(True))
@@ -310,7 +324,7 @@ def _flash_kernel_packed(sk: int, scale: float,
 
 
 def _flash_kernel_single_diag(scale: float, block_q: int, block_k: int,
-                              with_lse: bool, diag_sub: int,
+                              with_lse: bool, diag_sub: int, cblock: int,
                               q_ref, k_ref, v_ref, *rest):
     """ONE exact-diagonal block covers the whole problem (sq <= bq, sk
     <= bk, static aligned offset): grid is just (B, H) and the body is
@@ -345,7 +359,8 @@ def _flash_kernel_single_diag(scale: float, block_q: int, block_k: int,
           ).astype(q_ref.dtype)
     row = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
-    tri = col <= row              # one (sub, sub) mask, reused nt×
+    # one (sub, sub) mask, reused nt× (`cblock` divides `sub`)
+    tri = col <= _row_limit(row, cblock)
     for i in range(nt):
         rows = slice(i * sub, (i + 1) * sub)
         parts = []
@@ -373,7 +388,8 @@ def _flash_kernel_single_diag(scale: float, block_q: int, block_k: int,
 
 
 def _packed_schedule(nq: int, nk: int, bq: int, bk: int, off: int,
-                     sk: int, diag_static: bool = False):
+                     sk: int, diag_static: bool = False,
+                     cblock: int = 0):
     """Host-side visible-block tables for the packed causal grid.
     Every q row contributes at least one step (a fully-masked row
     still needs its init + epilogue to write out/lse).
@@ -385,19 +401,27 @@ def _packed_schedule(nq: int, nk: int, bq: int, bk: int, off: int,
     iff ki*bk + bk - 1 <= u iff ki <= u/bk - 1, and visible at all iff
     ki*bk <= u + bq - 1 iff ki <= u/bk; so the only masked visible
     block is ki == u/bk, offset u - ki*bk = 0.  Those blocks get flag
-    bit 4 and the kernel's static block-triangular path."""
+    bit 4 and the kernel's static block-triangular path.
+
+    ``cblock`` > 1 (block-causal, `_row_limit`; it divides ``bq``,
+    ``bk`` and ``off``): a row's limit is the end of its ``cblock``
+    positions, so a q block's last limit is what it was and the
+    visible blocks are the same; the diagonal block is the only masked
+    one still, and not even that where ``cblock == bk``."""
     import numpy as np
 
     ragged = sk % bk != 0
     qmap, kmap, flags = [], [], []
     for qi in range(nq):
-        hi = min((qi * bq + bq - 1 + off) // bk, nk - 1)
+        hi = min(_row_limit(qi * bq + bq - 1 + off, cblock) // bk,
+                 nk - 1)
         row = list(range(0, hi + 1)) if hi >= 0 else [0]
         for j, ki in enumerate(row):
             f = (1 if j == 0 else 0) | (2 if j == len(row) - 1 else 0)
             if hi >= 0:
                 f |= 4
-                fully = (ki * bk + bk - 1 <= qi * bq + off
+                fully = (ki * bk + bk - 1
+                         <= _row_limit(qi * bq + off, cblock)
                          and not (ragged and ki == nk - 1))
                 if not fully:
                     f |= 8
@@ -460,10 +484,21 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     return_lse: bool = False,
                     block_q: int = 1024, block_k: int = 1024,
                     diag_sub: Optional[int] = None,
+                    causal_block: int = 0,
                     interpret: Optional[bool] = None,
                     _max_packed_steps: Optional[int] = None):
     """q: (B, H, Sq, D); k, v: (B, Hkv, Sk, D) → (B, H, Sq, D)
     [, lse (B, H, Sq)].
+
+    `causal_block` > 1 with ``causal``: the BLOCK-causal mask of a
+    model that generates by blocks — query row i attends kv cols
+    ``<= ((i + kv_offset) // causal_block + 1) * causal_block - 1``:
+    causal across blocks of that many positions, bidirectional inside
+    one.  The clamped block sizes and a static `kv_offset` must be
+    multiples of it (a traced offset is the caller's to keep so): the
+    schedules then visit the blocks plain causal visits and only the
+    element mask differs.  Forward only (`flash_attention_diff` does
+    not take it).
 
     `kv_offset` (python int or traced scalar) shifts the causal
     diagonal: query row i attends kv cols <= i + kv_offset (used by SP
@@ -495,12 +530,17 @@ def flash_attention(q, k, v, *, causal: bool = True,
     nq = pl.cdiv(sq, bq)
     nk = pl.cdiv(sk, bk)
     off = jnp.asarray(kv_offset, jnp.int32).reshape(1)
+    cblock = int(causal_block) if causal else 0
+    import numpy as np
+    if cblock > 1:
+        assert bq % cblock == 0 and bk % cblock == 0, (bq, bk, cblock)
+        assert (not isinstance(kv_offset, (int, np.integer))
+                or int(kv_offset) % cblock == 0), (kv_offset, cblock)
 
     # PACKED causal schedule (static kv_offset): iterate only the
     # visible (qi, ki) blocks via prefetch tables — see
     # `_flash_kernel_packed`.  Traced offsets (ring/SP callers) and
     # non-causal calls keep the rectangular grid below.
-    import numpy as np
     # SMEM cap for the three prefetch tables (ADVICE r4): ~nq*nk/2
     # int32 entries each; above this, fall back to the rectangular
     # grid (whose skip bookkeeping is cheap relative to such long
@@ -529,16 +569,20 @@ def flash_attention(q, k, v, *, causal: bool = True,
         if (sub_req and sub_req % LANE != 0
                 and default_interpret(interpret) is False):
             sub_req = None
+        if sub_req and cblock > 1 and sub_req % cblock:
+            sub_req = None       # a sub-tile holds whole mask blocks
         diag_sub = 0
         if bq == bk and int(kv_offset) % bk == 0:
             if sub_req and bq % sub_req == 0:
                 diag_sub = sub_req
             else:
-                diag_sub = next((s for s in (256, 128) if bq % s == 0),
+                diag_sub = next((s for s in (256, 128)
+                                 if bq % s == 0 and s % max(cblock, 1) == 0),
                                 0)
         qmap, kmap, flags = _packed_schedule(nq, nk, bq, bk,
                                              int(kv_offset), sk,
-                                             diag_static=diag_sub > 0)
+                                             diag_static=diag_sub > 0,
+                                             cblock=cblock)
         n_vis = len(qmap)
         use_packed = n_vis <= max_packed_steps
 
@@ -563,7 +607,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
                                           memory_space=pltpu.VMEM))
         res = pl.pallas_call(
             functools.partial(_flash_kernel_single_diag, scale, bq, bk,
-                              return_lse, diag_sub),
+                              return_lse, diag_sub, cblock),
             name="flash_attention_fwd_single_diag",
             out_shape=tuple(out_shape),
             grid_spec=pl.GridSpec(
@@ -614,7 +658,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
                                           memory_space=pltpu.VMEM))
         res = pl.pallas_call(
             functools.partial(_flash_kernel_packed, sk, scale, bq, bk,
-                              return_lse, diag_sub),
+                              return_lse, diag_sub, cblock),
             name="flash_attention_fwd_packed",
             out_shape=tuple(out_shape),
             grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -665,7 +709,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
         # blocks' HBM traffic nor stalls on a cold fetch when the next
         # row starts (the jax flash kernel's `next_kv_index` trick).
         if causal:
-            visible = ki * bk <= qi * bq + bq - 1 + off[0]
+            visible = ki * bk <= _row_limit(qi * bq + bq - 1 + off[0],
+                                            cblock)
             ki = jax.lax.select(visible, ki, 0)
         return (bb, hh // g, ki, 0)
 
@@ -681,7 +726,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
                          memory_space=pltpu.VMEM))
     res = pl.pallas_call(
         functools.partial(_flash_kernel, nk, sk, causal, scale, bq, bk,
-                          return_lse),
+                          return_lse, cblock),
         name="flash_attention_fwd",
         out_shape=tuple(out_shape),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1068,8 +1113,10 @@ def flash_attention_diff(q, k, v, kv_offset=0, *,
 
 
 def attention_reference(q, k, v, *, causal: bool = True,
-                        scale: Optional[float] = None, kv_offset: int = 0):
-    """Golden dense attention (fp32)."""
+                        scale: Optional[float] = None, kv_offset: int = 0,
+                        causal_block: int = 0):
+    """Golden dense attention (fp32); ``causal_block`` as
+    `flash_attention`'s."""
     b, h, sq, d = q.shape
     _, hkv, sk, _ = k.shape
     group = h // hkv
@@ -1080,7 +1127,7 @@ def attention_reference(q, k, v, *, causal: bool = True,
     if causal:
         qpos = jnp.arange(sq)[:, None] + kv_offset
         kpos = jnp.arange(sk)[None, :]
-        s = jnp.where(kpos <= qpos, s, NEG_INF)
+        s = jnp.where(kpos <= _row_limit(qpos, causal_block), s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, vf).astype(q.dtype)
 

@@ -397,6 +397,14 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, kv_len, *,
     (B,) int32 true filled lengths.  Returns (out (B, H, D),
     lse (B, H)).
 
+    The H query rows of a batch row are ANY queries that see the same
+    ``kv_len[b]`` keys, ``H / Hkv`` consecutive ones to a KV head: one
+    position's heads (plain decode: ``G`` a KV head), or — a model that
+    generates by blocks, `layers.tp_attn.TPAttention.block_paged` — the
+    heads of all ``n`` positions of the block in flight, laid out
+    ``(Hkv, G * n)``, whose K/V the caller has written into the pages
+    first.  Nothing is masked inside a row's length either way.
+
     The work follows each row's LIVE length, not the table's width:
     the grid is (B,), the pools stay in HBM, and each grid step loops
     over blocks of `_pages_per_block` pages — gathered through the

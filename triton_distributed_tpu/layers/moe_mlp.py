@@ -281,15 +281,22 @@ def _pack_block(n_pairs: int, num_experts: int) -> int:
 
 @dataclasses.dataclass
 class SparseMoE:
-    """Sparse feed-forward on ONE chip, dropless: sigmoid router with a
-    selection bias (`noaux_tc` without groups), ``topk`` routed experts
-    a token weighted by their normalised scores times
-    ``routed_scaling``, plus ``n_shared`` always-on experts.
+    """Sparse feed-forward on ONE chip, dropless: ``topk`` routed
+    experts a token weighted by their normalised scores times
+    ``routed_scaling``, plus ``n_shared`` always-on experts (0: none,
+    and no ``shared`` weights).  Two routers, by ``scoring``:
 
-    ``s = sigmoid(x W_r)`` in float32; the choice is the top-k of
-    ``s + bias`` and the bias goes no further: the weights are
-    ``s[chosen] / (sum + 1e-20) * routed_scaling``.  Every pair is
-    computed, whatever the imbalance (`moe_utils.pack_by_expert`).
+    - ``"sigmoid"`` (`noaux_tc` without groups): ``s = sigmoid(x W_r)``
+      in float32; the choice is the top-k of ``s + bias`` and the
+      selection bias goes no further;
+    - ``"softmax"``: ``s = softmax(x W_r)`` over all experts in float32;
+      the choice is the top-k of ``s``; there is no bias (and no
+      ``router_bias`` weight).
+
+    Either way the weights are ``s[chosen] / (sum + 1e-20) *
+    routed_scaling`` (``norm_topk_prob``; else ``s[chosen]`` as it is).
+    Every pair is computed, whatever the imbalance
+    (`moe_utils.pack_by_expert`).
 
     Mode "fused": rows packed by expert, two Pallas grouped GEMMs
     (`kernels.grouped_gemm.packed_expert_*`) that read only the experts
@@ -317,6 +324,11 @@ class SparseMoE:
     mode: str = "fused"            # xla | fused
     interpret: Optional[bool] = None
     held: Optional[tuple] = None   # (lo, hi) of num_experts
+    scoring: str = "sigmoid"       # sigmoid (+ selection bias) | softmax
+
+    def __post_init__(self):
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"unknown scoring {self.scoring!r}")
 
     @property
     def num_held(self) -> int:
@@ -333,33 +345,49 @@ class SparseMoE:
             return (jax.random.normal(k, shape) * fan_in ** -0.5
                     ).astype(dtype)
 
-        return {
-            "router": normal(ks[0], (h, e), h).astype(jnp.float32),
-            "router_bias": 0.01 * jax.random.normal(ks[1], (e,)),
-            "gate": normal(ks[2], (n, h, f), h),
-            "up": normal(ks[3], (n, h, f), h),
-            "down": normal(ks[4], (n, f, h), f),
-            "shared": {"gate_up": normal(ks[5], (h, 2 * fs), h),
-                       "down": normal(ks[6], (fs, h), fs)},
+        make = {
+            "router": lambda: normal(ks[0], (h, e), h).astype(jnp.float32),
+            "router_bias": lambda: 0.01 * jax.random.normal(ks[1], (e,)),
+            "gate": lambda: normal(ks[2], (n, h, f), h),
+            "up": lambda: normal(ks[3], (n, h, f), h),
+            "down": lambda: normal(ks[4], (n, f, h), f),
+            "shared": lambda: {"gate_up": normal(ks[5], (h, 2 * fs), h),
+                               "down": normal(ks[6], (fs, h), fs)},
         }
+        return {k: m() for k, m in make.items()
+                if k not in self._absent()}
+
+    def _absent(self):
+        """Weights this layer does not have."""
+        return (("router_bias",) * (self.scoring == "softmax")
+                + ("shared",) * (not self.n_shared))
 
     def param_specs(self):
         from jax.sharding import PartitionSpec as P
-        return {"router": P(None, None), "router_bias": P(None),
-                "gate": P(None, None, None), "up": P(None, None, None),
-                "down": P(None, None, None),
-                "shared": {"gate_up": P(None, None),
-                           "down": P(None, None)}}
+        p = {"router": P(None, None), "router_bias": P(None),
+             "gate": P(None, None, None), "up": P(None, None, None),
+             "down": P(None, None, None),
+             "shared": {"gate_up": P(None, None),
+                        "down": P(None, None)}}
+        return {k: v for k, v in p.items() if k not in self._absent()}
 
     # ------------------------------------------------------------------
 
     def route(self, x, params):
-        """(ids (N, topk) int32, weights (N, topk) f32)."""
-        s = jax.nn.sigmoid(jnp.dot(
+        """(ids (N, topk) int32, weights (N, topk) f32) by ``scoring``:
+        float32 scores of the layer's input as it is served; sigmoid
+        scores choose with the selection bias added, softmax scores
+        (over every expert) as they are."""
+        logits = jnp.dot(
             x.astype(jnp.float32), params["router"].astype(jnp.float32),
-            precision="highest"))
-        _, ids = jax.lax.top_k(
-            s + params["router_bias"].astype(jnp.float32), self.topk)
+            precision="highest")
+        if self.scoring == "softmax":
+            s = jax.nn.softmax(logits, axis=-1)
+            _, ids = jax.lax.top_k(s, self.topk)
+        else:
+            s = jax.nn.sigmoid(logits)
+            _, ids = jax.lax.top_k(
+                s + params["router_bias"].astype(jnp.float32), self.topk)
         w = jnp.take_along_axis(s, ids, axis=1)
         if self.norm_topk_prob:
             w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)

@@ -27,6 +27,7 @@ from triton_distributed_tpu.kernels.allgather_gemm import (
 )
 from triton_distributed_tpu.kernels.flash_attention import (
     attention_reference,
+    flash_attention,
     flash_attention_diff,
 )
 from triton_distributed_tpu.kernels.flash_decode import (
@@ -86,6 +87,11 @@ class TPAttention:
     #: a head and channel, times the heads' output before ``W_o``;
     #: ``W_g`` rides as further columns of ``wqkv``.  Set by the model.
     gate: bool = False
+    #: > 1: the model generates by blocks of this many positions — the
+    #: prefill's mask is BLOCK-causal (causal across blocks,
+    #: bidirectional inside one; forward only) and the paged step is
+    #: `block_paged`.  Set by the model.
+    block: int = 0
     mode: str = "fused"           # xla | fused
     gemm: MatmulConfig = dataclasses.field(default_factory=MatmulConfig)
     collective_ids: tuple = (cids.TP_ATTN_QKV, cids.TP_ATTN_OUT)
@@ -203,7 +209,12 @@ class TPAttention:
         if self.mode == "xla":
             # dense golden (differentiable; materializes S² — use the
             # fused mode for long sequences)
-            attn = attention_reference(q, k, v, causal=True)
+            attn = attention_reference(q, k, v, causal=True,
+                                       causal_block=self.block)
+        elif self.block > 1:
+            attn = flash_attention(q, k, v, causal=True,
+                                   causal_block=self.block,
+                                   interpret=self.interpret)
         else:
             # Pallas flash with a Pallas backward (custom VJP): the
             # fused mode trains too.
@@ -359,3 +370,63 @@ class TPAttention:
         out_x = self._out_proj(attn, x.dtype, params)
         scales = (k_sc, v_sc) if kv_scales is not None else None
         return out_x, (k_pool, v_pool), scales
+
+    def block_paged(self, x, params, kv_pools, page_table, cursor,
+                    active):
+        """One pass over the block in flight of every row
+        (``self.block`` positions a row): x ``(B * block, hidden)``, a
+        row's positions together; ``cursor`` (B,) int32 the block's
+        first position (a multiple of ``block``, so a block never
+        straddles a page); ``active`` (B,) bool.
+
+        The block's K/V goes into the pages mapped at
+        ``cursor .. cursor + block - 1`` BEFORE attention — provisional
+        rows above the cursor, overwritten by every later pass of the
+        block and final only once the commit pass has written them (an
+        inactive row's go to the trash page) — and every query of the
+        block then sees the same ``cursor + block`` keys: the committed
+        prefix and the whole of its own block.  So the kernel needs no
+        mask: it is `flash_decode_paged` at ``G * block`` query rows a
+        KV head.  Returns (out like x, updated pools)."""
+        from triton_distributed_tpu.models.kv_cache import (
+            NULL_PAGE, write_token_rows)
+
+        assert self.block > 1 and not self.gate, (self.block, self.gate)
+        k_pool, v_pool = kv_pools
+        b, n = cursor.shape[0], self.block
+        ps = k_pool.shape[2]
+        d, hkv, g = self.head_dim, self.hkv_loc, self.h_loc // self.hkv_loc
+        q, k, v = self._split_heads(self._project_qkv(x, params), b, n)
+        if self.qk_norm:
+            q = rms_norm(q, params["q_norm"])
+            k = rms_norm(k, params["k_norm"])
+        pos = cursor[:, None] + jnp.arange(n, dtype=jnp.int32)  # (B, n)
+        if self.rope:
+            cos, sin = rope_cos_sin(pos.reshape(-1), d, self.rope_theta)
+            cos = cos.reshape(b, 1, n, d // 2)
+            sin = sin.reshape(b, 1, n, d // 2)
+
+            def rope_rows(x_):      # x_: (B, H, n, D)
+                x1, x2 = x_[..., :d // 2], x_[..., d // 2:]
+                return jnp.concatenate(
+                    [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                    axis=-1).astype(x_.dtype)
+
+            q = rope_rows(q)
+            k = rope_rows(k)
+        phys = jnp.take_along_axis(page_table, pos // ps, axis=1)
+        phys = jnp.where(active[:, None], phys, NULL_PAGE).reshape(-1)
+        within = (pos % ps).reshape(-1)
+
+        def rows(t):                # (B, Hkv, n, D) -> (B * n, Hkv, D)
+            return t.transpose(0, 2, 1, 3).reshape(b * n, hkv, d)
+
+        k_pool = write_token_rows(k_pool, phys, within, rows(k))
+        v_pool = write_token_rows(v_pool, phys, within, rows(v))
+        # head h reads KV head h // G: a KV head's G * n queries together
+        out, _ = flash_decode_paged(
+            q.reshape(b, hkv * g * n, d), k_pool, v_pool, page_table,
+            cursor + n, interpret=self.interpret)
+        attn = out.reshape(b, hkv * g, n, d).transpose(0, 2, 1, 3)
+        out_x = self._out_proj(attn.reshape(b * n, -1), x.dtype, params)
+        return out_x, (k_pool, v_pool)

@@ -21,4 +21,7 @@ def AutoLLM(config, mesh, **kw):
         # bound here: the other families' set-up imports none of it
         from triton_distributed_tpu.models.solar_open2 import SolarOpen2
         return SolarOpen2(config, mesh, **kw)
+    if "sdar_moe" in arch or "sdarmoe" in arch:
+        from triton_distributed_tpu.models.sdar_moe import SdarMoe
+        return SdarMoe(config, mesh, **kw)
     raise ValueError(f"unknown architecture: {config.architecture}")

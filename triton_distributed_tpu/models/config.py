@@ -70,6 +70,19 @@ class ModelConfig:
     kda_conv_size: int = 4
     kda_rank: int = 128
     kda_allow_neg_eigval: bool = True
+    # Generation by diffusion over blocks (`models/sdar_moe.py`):
+    # ``block_length`` > 1 positions are denoised together — attention
+    # is causal across blocks and bidirectional inside one — in
+    # ``denoising_steps`` passes a block (it divides the block), the
+    # positions to reveal chosen by ``remasking`` (``"sequential"`` |
+    # ``"low_confidence_static"``); ``mask_token_id`` stands where a
+    # position is not revealed yet.  0: one token a step.
+    block_length: int = 0
+    mask_token_id: int = 0
+    denoising_steps: int = 1
+    remasking: str = "sequential"
+    #: The sparse layer's router (`layers.moe_mlp.SparseMoE.scoring`).
+    moe_scoring: str = "sigmoid"
 
     @property
     def is_moe(self) -> bool:
@@ -175,6 +188,26 @@ class ModelConfig:
                  use_gqa_gate=True, kda_num_heads=8, kda_head_dim=128,
                  kda_conv_size=4, kda_rank=128,
                  kda_allow_neg_eigval=True)
+        d.update(kw)
+        return cls(**d)
+
+    @classmethod
+    def tiny_sdar_moe(cls, **kw):
+        """Test-size block-diffusion decoder: grouped-query attention
+        with q/k norms (8 query heads a KV head, as published), every
+        feed-forward a dropless top-4 of 16 experts by softmax scores
+        with no shared expert; blocks of 4 positions denoised in 2
+        passes; the mask token is the vocabulary's last id."""
+        d = dict(architecture="sdar_moe", vocab_size=256,
+                 hidden_size=128, intermediate_size=256, num_layers=2,
+                 num_heads=8, num_kv_heads=1, head_dim=16,
+                 rms_norm_eps=1e-6, rope_theta=1e6, qk_norm=True,
+                 tie_word_embeddings=False, max_seq_len=128,
+                 num_experts=16, num_experts_per_tok=4,
+                 moe_intermediate_size=64, n_shared_experts=0,
+                 norm_topk_prob=True, moe_scoring="softmax",
+                 block_length=4, mask_token_id=255, denoising_steps=2,
+                 remasking="sequential")
         d.update(kw)
         return cls(**d)
 

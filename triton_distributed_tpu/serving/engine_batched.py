@@ -35,6 +35,11 @@ slot are scored in one scanned dispatch, emitting a per-row
 accept-length plus the bonus token, with the rejected tail's KV
 cursor and PRNG key chain rolled back in-program (drafters live in
 `serving.speculative`; the scheduler's ``spec_k`` mode drives it).
+
+A fifth, `make_block_pass_fn`, is the step of a model that generates
+by diffusion over blocks (`models.sdar_moe`): every slot's block in
+flight through the model in one of two phases, denoise or commit —
+the block's tokens, its revealed flags and the phase all data.
 """
 
 from __future__ import annotations
@@ -272,6 +277,87 @@ def make_spec_verify_fn(decode_fn, temperature: float = 0.0,
     if donate:
         return jax.jit(verify, donate_argnums=(3, 4))
     return jax.jit(verify)
+
+
+def make_block_pass_fn(decode_fn, block: int, mask_id: int,
+                       remasking: str = "sequential",
+                       donate: bool = True):
+    """One pass of generation by diffusion over blocks, for every slot
+    and every mixture of phases: ONE jitted program, in which the
+    phase, the revealed flags, the reveal counts and the commit flag
+    are data.
+
+    ``(params, blk (B, 2, n) int32, cache, host_blk (B, 2, n), fresh
+    (B,) bool, active (B,) bool, commit (B,) bool, n_reveal (B,) int32)
+    -> (blk (B, 2, n), cache)``
+
+    ``blk[b, 0]`` are the tokens of row b's block in flight and
+    ``blk[b, 1]`` whether each is REVEALED (a flag, never ``token ==
+    mask_id``: a prompt may hold the mask id and an arg-max may return
+    it); the block's first position is ``cache.offset[b]``.  ``blk``
+    stays on the device between passes — it is what the last pass
+    returned — and rows ``fresh`` take the host's (a newly admitted
+    row: the prompt's tail revealed, the rest masked).
+
+    ``decode_fn(params, tokens (B, n), cache, active) -> (logits (B, n,
+    V), cache)`` is fed the block with ``mask_id`` where a position is
+    not revealed; it writes the block's K/V into the pages mapped past
+    the cursor and attends over ``offset + n`` keys.  Then
+
+    - a DENOISE row (``active & ~commit``) reveals ``n_reveal[b]`` of
+      its masked positions with their arg-max tokens: the leftmost
+      (``"sequential"``) or those whose arg-max is most probable
+      (``"low_confidence_static"``; ties to the left).  A revealed
+      token is never masked again;
+    - a COMMIT row (``active & commit``; its block is fully revealed)
+      reveals nothing: the pass has just written the finished block's
+      K/V, its cursor moves on by ``n`` and the block it returns is
+      all masked — the next block;
+    - an inactive row keeps its block and its cursor.
+
+    Greedy only.  The cache is donated (rebind to the returned one);
+    the block state is a few integers a row and is not.
+    """
+    if remasking not in ("sequential", "low_confidence_static"):
+        raise ValueError(f"unknown remasking {remasking!r}")
+    n = int(block)
+
+    def block_pass(params, blk, cache, host_blk, fresh, active, commit,
+                   n_reveal):
+        blk = jnp.where(fresh[:, None, None], host_blk, blk)
+        toks, shown = blk[:, 0], blk[:, 1] != 0
+        cursor = cache.offset
+        logits, cache = decode_fn(
+            params, jnp.where(shown, toks, jnp.int32(mask_id)), cache,
+            active)
+        best = jnp.argmax(logits, axis=-1).astype(jnp.int32)   # (B, n)
+        place = jnp.arange(n, dtype=jnp.float32)
+        if remasking == "sequential":
+            score = jnp.broadcast_to(-place, best.shape)
+        else:
+            # the arg-max's softmax probability, float32
+            score = jnp.exp(jnp.max(logits, axis=-1)
+                            - jax.nn.logsumexp(logits, axis=-1))
+        score = jnp.where(shown, -jnp.inf, score)
+        # rank among the row's positions: how many score higher (the
+        # left one of a tie first)
+        ahead = ((score[:, None, :] > score[:, :, None])
+                 | ((score[:, None, :] == score[:, :, None])
+                    & (place[None, None, :] < place[None, :, None])))
+        rank = ahead.sum(axis=-1)
+        denoise = active & ~commit
+        reveal = (~shown & (rank < n_reveal[:, None])
+                  & denoise[:, None])
+        toks = jnp.where(reveal, best, toks)
+        done = active & commit
+        shown = (shown | reveal) & ~done[:, None]
+        cache = dataclasses.replace(
+            cache, offset=jnp.where(done, cursor + n, cursor))
+        return jnp.stack([toks, shown.astype(jnp.int32)], axis=1), cache
+
+    if donate:
+        return jax.jit(block_pass, donate_argnums=(2,))
+    return jax.jit(block_pass)
 
 
 def _split_rows(keys):

@@ -504,6 +504,17 @@ class PagedKV:
                  spill_disk_pages: int = 0,
                  insert_fn=None):
         self.page_size = ps = int(page_size)
+        #: > 1: the model generates by blocks of this many positions
+        #: (`models.sdar_moe`).  A request then has a BLOCK IN FLIGHT:
+        #: the pages mapped at and past its cursor hold provisional
+        #: rows that every pass of the block overwrites, final only
+        #: once the commit pass has written them — they are private
+        #: (`ensure` allocates them to the slot alone; no radix node
+        #: ever covers a position at or past the cursor:
+        #: `insert_prefill`) and go back to the pool at `release`.
+        self.block = int(getattr(model, "block_length", 0) or 0)
+        assert self.block <= 1 or ps % self.block == 0, (
+            "a block must not straddle a page", ps, self.block)
         self.max_seq = int(max_seq)
         self.pages_per_seq = t = pages_for(self.max_seq, ps)
         self.num_slots = int(num_slots)
@@ -648,8 +659,12 @@ class PagedKV:
     def feasible(self, prompt_len: int, max_new: int) -> bool:
         """Could this request EVER run alone on an empty pool?  The
         last generated token needs no KV write, so the horizon is
-        ``prompt_len + max_new - 1`` positions."""
+        ``prompt_len + max_new - 1`` positions — for a model that
+        generates by blocks, the end of the block that holds the last
+        token (the block runs whole)."""
         horizon = prompt_len + max_new - 1
+        if self.block > 1:
+            horizon = -(-(prompt_len + max_new) // self.block) * self.block
         return (horizon <= self.max_seq
                 and pages_for(horizon, self.page_size)
                 <= self.usable_pages)
@@ -833,11 +848,13 @@ class PagedKV:
     def insert_prefill(self, row_cache, tokens: Sequence[int],
                        prompt_len: int, key,
                        shared_path: List[_RadixNode],
-                       row_start: int = 0) -> int:
+                       row_start: int = 0,
+                       offset: Optional[int] = None) -> int:
         """Claim a slot, map shared prefix pages + freshly allocated
         private pages, scatter the prefilled row cache into the
-        private pages, set offset to ``prompt_len - 1`` and the slot
-        PRNG key.  ``row_cache`` covers prompt positions
+        private pages, set offset to ``prompt_len - 1`` (``offset``
+        where given: a block-generating model's cursor, the end of the
+        prompt's whole blocks) and the slot PRNG key.  ``row_cache`` covers prompt positions
         ``[row_start, prompt_len)`` (``row_start = 0`` for a full
         prefill, or the page-aligned shared-prefix length for the
         suffix path).  Full prompt pages are registered into the
@@ -904,7 +921,8 @@ class PagedKV:
                 page_ids[j] = row[g]
         self.cache, self.keys = self._insert(
             self.cache, self.keys, row_cache, key,
-            jnp.int32(slot), jnp.asarray(page_ids), jnp.int32(s - 1))
+            jnp.int32(slot), jnp.asarray(page_ids),
+            jnp.int32(s - 1 if offset is None else offset))
         self._active[slot] = True
         self._slot_pages[slot] = list(priv)
         self._slot_path[slot] = list(shared_path)
@@ -912,6 +930,8 @@ class PagedKV:
         # position s-1) so the next same-prefix arrival shares them.
         if self.radix is not None:
             sharable = (s - 1) // ps          # pages 0..sharable-1
+            # no shared page holds a position at or past the cursor
+            assert offset is None or sharable * ps <= offset, (s, offset)
             n_new = sharable - c_pages
             if n_new > 0:
                 new_pages = [row[c_pages + i] for i in range(n_new)]

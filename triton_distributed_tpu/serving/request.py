@@ -108,6 +108,18 @@ class Request:
     #: artifact is identical to a local prefill's, so tokens are
     #: unchanged.  Cleared at admission.
     shipped_kv: Optional[object] = None
+    #: A model that generates by blocks (`models.sdar_moe`): beside
+    #: prompt + tokens delivered the request has a BLOCK IN FLIGHT —
+    #: ``block_start`` its first position (the slot's write cursor),
+    #: ``block_masked`` how many of its positions are still masked —
+    #: both AS THE LAST DISPATCH LEAVES THEM: the schedule is static,
+    #: so the host knows a row's next phase without reading a token
+    #: (`ContinuousBatchingScheduler._dispatch_block`).  The block's
+    #: K/V lies in pages past the cursor that nobody else may read
+    #: until the commit; a preemption drops the block and the resume
+    #: redoes it from the tokens delivered.  None: one token a step.
+    block_start: Optional[int] = None
+    block_masked: int = 0
 
     # -- SLO timestamps (scheduler clock, seconds) ---------------------
     t_arrival: Optional[float] = None

@@ -16,9 +16,20 @@ Every `step()` is one scheduler iteration:
    rows admitted in this iteration merged in by one small jitted
    program — BEFORE it reads step t's tokens, so the device runs
    while the host commits, returns to its caller, admits and
-   enqueues.  With ``spec_k`` set the scheduler stays serial (a
-   drafter needs the committed tokens on the host) and the dispatch
-   is a speculative draft–verify round instead
+   enqueues.  Two paths yield something other than one token a row a
+   dispatch.  BLOCK GENERATION (a model with ``block_length``,
+   `models.sdar_moe`) is pipelined like the plain step: a dispatch
+   feeds every row's block in flight in one of two phases — denoise
+   (some masked positions revealed) or commit (the finished block's
+   K/V made final, the write cursor moved on by the block) — the
+   block's tokens, revealed flags and phase live on the device
+   between passes (`make_block_pass_fn`), and the reveal schedule is
+   static, so the host knows each row's next phase, its pages and its
+   last pass without reading a token; 0..block tokens a row are
+   delivered a pass, in position order.  With ``spec_k`` set the
+   scheduler stays SERIAL (a drafter needs the committed tokens on
+   the host, and a verify round's yield is not known before it is
+   read) and the dispatch is a speculative draft–verify round instead
    (`make_spec_verify_fn` + `serving.speculative` drafters):
    K proposed tokens scored in one scanned program, the accepted
    prefix + bonus token committed per row, the rejected tail's KV
@@ -50,7 +61,8 @@ detached `serving.request` span per request feeding the cross-rank
 timeline, and a span around each phase of a step (`serving.step` >
 `serving.admit` > `serving.admit.request` > `serving.prefill.block`;
 `serving.pages`, `serving.dispatch`, `serving.sync`, `serving.moe` (a
-sparse model's expert counters), `serving.commit`, `serving.gauges`)
+sparse model's expert counters), `serving.diffusion` (a block pass's
+rows by phase and tokens), `serving.commit`, `serving.gauges`)
 that says where the host's time in a step went.
 Metric and span names: docs/serving.md, docs/observability.md.
 """
@@ -73,6 +85,7 @@ from triton_distributed_tpu.observability.tracing import (
 )
 from triton_distributed_tpu.serving.engine_batched import (
     DEFAULT_PREFILL_BUCKETS,
+    make_block_pass_fn,
     make_masked_step_fn,
     make_spec_verify_fn,
     pad_prompt,
@@ -224,6 +237,10 @@ class _Flight:
     #: Verify round only: accept lengths (device), proposals (host).
     accept: object = None
     n_draft: object = None
+    #: Block pass only (``toks`` is then the (B, 2, n) block state it
+    #: returned): slot -> (first position of the row's block, positions
+    #: it reveals) of the DENOISE rows; the rest of ``rows`` committed.
+    denoised: Optional[Dict[int, tuple]] = None
 
 
 class ContinuousBatchingScheduler:
@@ -264,6 +281,13 @@ class ContinuousBatchingScheduler:
             raise ValueError(
                 f"no prefill bucket fits max_seq={self.max_seq}")
         self.paged = cfg.kv_layout == "paged"
+        #: > 1: the model generates by blocks of this many positions;
+        #: the step is a block pass (module docstring).
+        self._block = int(getattr(model, "block_length", 0) or 0)
+        if self._block > 1 and (not self.paged or cfg.spec_k):
+            raise ValueError(
+                "block generation runs over the paged layout, without "
+                "speculation")
         if self.paged:
             if not (hasattr(model, "create_paged_cache")
                     and hasattr(model, "make_paged_decode_fn")):
@@ -308,9 +332,28 @@ class ContinuousBatchingScheduler:
         self._state_seen = [0, 0]
         self._state_recomputed = 0
         self._prefill = jax.jit(model.make_prefill_fn())
-        self._step = make_masked_step_fn(
-            decode_fn, cfg.temperature, cfg.top_k, cfg.top_p,
-            cfg.pad_id)
+        if self._block > 1:
+            if cfg.temperature:
+                raise ValueError(
+                    "block generation is greedy: sampling with "
+                    "temperature inside a block is not built")
+            gen = model.config
+            self._step = make_block_pass_fn(
+                decode_fn, self._block, gen.mask_token_id, gen.remasking)
+            #: Positions a denoise pass reveals, and whether they are
+            #: always the leftmost masked ones.
+            self._reveal = self._block // gen.denoising_steps
+            self._sequential = gen.remasking == "sequential"
+            #: The host's word for the block of a newly admitted row
+            #: (`_fresh`): the prompt's tail revealed, the rest masked.
+            self._blk_host = np.zeros(
+                (cfg.num_slots, 2, self._block), np.int32)
+            #: Revealed and not yet deliverable, as of the last read.
+            self._held_back = 0
+        else:
+            self._step = make_masked_step_fn(
+                decode_fn, cfg.temperature, cfg.top_k, cfg.top_p,
+                cfg.pad_id)
         #: Host-known first tokens merged into the token vector the
         #: last dispatch left on the device: ONE jitted program, run
         #: in front of every dispatch of `_step`, full batch or not,
@@ -365,8 +408,10 @@ class ContinuousBatchingScheduler:
         #: with the model's parameters — so that the merged tokens
         #: reach `_step` placed alike from the first dispatch on and
         #: the decode program is not compiled once more for it.
+        # (A block pass returns, and takes, the (B, 2, n) block state.)
         self._prev = jax.device_put(
-            np.zeros(cfg.num_slots, np.int32),
+            self._blk_host.copy() if self._block > 1
+            else np.zeros(cfg.num_slots, np.int32),
             self.slots.cache.offset.sharding)
         #: The decode step dispatched and not yet read.
         self._flight: Optional[_Flight] = None
@@ -436,10 +481,12 @@ class ContinuousBatchingScheduler:
             if (c == 0 or pick_bucket(req.prompt_len - c,
                                       self.buckets) is None):
                 return RejectReason.PROMPT_TOO_LONG
-        if req.prompt_len + req.max_new_tokens > self.max_seq + 1:
+        if (req.prompt_len + req.max_new_tokens > self.max_seq + 1
+                and self._block <= 1):
             # offset after the last generated token may reach max_seq:
             # position max_seq-1 is the last writable KV row, and the
-            # final token needs no KV write of its own.
+            # final token needs no KV write of its own.  (A block's
+            # horizon is the end of its last block: `feasible` below.)
             return RejectReason.EXCEEDS_KV_CAPACITY
         if self.paged and not self.slots.feasible(
                 req.prompt_len, req.max_new_tokens):
@@ -853,7 +900,10 @@ class ContinuousBatchingScheduler:
                                         (req,))
             slot = self.slots.insert_prefill(
                 row_cache, s, self._request_key(req))
-        self._tokens[slot] = tokens[-1]
+        if self._block > 1:
+            self._start_block(slot, req, tokens)
+        else:
+            self._tokens[slot] = tokens[-1]
         self._fresh[slot] = True
         req.state = RequestState.RUNNING
         req.slot = slot
@@ -1015,9 +1065,137 @@ class ContinuousBatchingScheduler:
             reg.counter("serving_prefix_cache_hit_tokens_total").inc(c)
             reg.counter("serving_prefix_cache_miss_tokens_total").inc(
                 s - c)
-        slot = self.slots.insert_prefill(row, tokens, s, key, shared,
-                                         row_start=row_start)
+        slot = self.slots.insert_prefill(
+            row, tokens, s, key, shared, row_start=row_start,
+            offset=(s // self._block * self._block
+                    if self._block > 1 else None))
         return slot, bucket, tokens, mode, c
+
+    # -- generation by blocks (module docstring) -------------------------
+
+    def _start_block(self, slot: int, req: Request, tokens) -> None:
+        """Admission (or resume) of a block-generating request: the
+        prefill left the whole blocks of ``tokens`` below the cursor;
+        what is left of them enters the first block in flight already
+        revealed, the rest of it masked.  A resume drops the block
+        that was in flight and redoes it from the tokens delivered."""
+        n = self._block
+        start = len(tokens) // n * n
+        tail = list(tokens[start:])
+        blk = self._blk_host[slot]
+        blk[:] = 0
+        blk[0, :len(tail)] = tail
+        blk[1, :len(tail)] = 1
+        req.block_start = start
+        req.block_masked = n - len(tail)
+
+    def _block_ends(self, req: Request) -> bool:
+        """True once the passes DISPATCHED for ``req`` reveal its last
+        token and every position before it: no further pass is
+        dispatched, and the last block gets no commit pass (nothing
+        will read it).  Sequential reveals leave a revealed prefix, so
+        that is known position by position; otherwise only a block
+        with nothing masked is known to hold its every token — the
+        last block then runs whole."""
+        end = req.prompt_len + req.max_new_tokens
+        edge = req.block_start + self._block
+        if self._sequential:
+            return edge - req.block_masked >= end
+        return req.block_masked == 0 and edge >= end
+
+    def _dispatch_block(self, rows: Dict[int, Request], t0: float,
+                        inflight: bool) -> _Flight:
+        """Enqueue one block pass for ``rows``: each row in the phase
+        its own schedule says — commit once nothing of its block is
+        masked, else a denoise pass revealing its next share — and
+        move the host's picture of each row on.  Nothing here reads
+        the device."""
+        n = self._block
+        slots = self.config.num_slots
+        active = np.zeros(slots, bool)
+        commit = np.zeros(slots, bool)
+        n_reveal = np.zeros(slots, np.int32)
+        denoised = {}
+        self._count_dispatch(inflight)
+        for slot, req in rows.items():
+            active[slot] = True
+            if req.block_masked == 0:
+                commit[slot] = True
+                req.block_start += n
+                req.block_masked = n
+            else:
+                k = min(self._reveal, req.block_masked)
+                n_reveal[slot] = k
+                req.block_masked -= k
+                denoised[slot] = (req.block_start, k)
+        with span("serving.dispatch", k=n, spec=False,
+                  inflight=int(inflight)):
+            blk, cache = self._step(
+                self.params, self._prev, self.slots.cache,
+                self._blk_host.copy(), self._fresh.copy(), active,
+                commit, n_reveal)
+            self.slots.cache = cache
+        self._prev = blk
+        self._fresh[:] = False
+        return _Flight(blk, rows, t0, counted=self._moe_counters(),
+                       denoised=denoised)
+
+    def _commit_block(self, rows, flight: _Flight, blk_host, now,
+                      reg):
+        """Deliver what one block pass revealed: a token reaches its
+        request as soon as it and every earlier position are revealed
+        — in position order, each once; positions past the request's
+        length are never delivered.  A commit row delivers nothing.
+        Returns (rows retired, tokens delivered)."""
+        n = self._block
+        retired = generated = held = 0
+        for slot, req in rows:
+            at = flight.denoised.get(slot)
+            if at is None:
+                continue
+            start = at[0]
+            toks, shown = blk_host[slot]
+            j = req.prompt_len + len(req.generated) - start
+            done = False
+            while j < n and shown[j] and not done:
+                done = self._emit_token(slot, req, int(toks[j]), now,
+                                        reg)
+                generated += 1
+                j += 1
+            if done:
+                retired += 1
+            else:
+                last = req.prompt_len + req.max_new_tokens - start
+                held += int(shown[j:last].sum())
+        self._held_back = held
+        return retired, generated
+
+    def _diffusion_phase(self, flight: _Flight, delivered: int,
+                         reg) -> None:
+        """After a block pass's host sync and commit: what the pass
+        was, as a `serving.diffusion` span's attributes and as metrics
+        (all the host's own counts: no sync of its own)."""
+        n = self._block
+        denoise = len(flight.denoised)
+        commits = len(flight.rows) - denoise
+        revealed = sum(k for _, k in flight.denoised.values())
+        with span("serving.diffusion") as sp:
+            sp.attrs.update(
+                rows_denoise=denoise, rows_commit=commits,
+                positions_fed=len(flight.rows) * n,
+                tokens_revealed=revealed, tokens_delivered=delivered,
+                blocks_committed=commits)
+        if reg:
+            reg.counter("serving_diffusion_passes_total",
+                        phase="denoise").inc(denoise)
+            reg.counter("serving_diffusion_passes_total",
+                        phase="commit").inc(commits)
+            reg.counter(
+                "serving_diffusion_tokens_revealed_total").inc(revealed)
+            reg.counter(
+                "serving_diffusion_blocks_committed_total").inc(commits)
+            reg.gauge("serving_diffusion_tokens_held_back").set(
+                self._held_back)
 
     def _ahead(self, req: Request) -> int:
         """Tokens dispatched for ``req`` and not read yet (0 or 1)."""
@@ -1030,6 +1208,8 @@ class ContinuousBatchingScheduler:
         so it is known before that step is read, and the row is not in
         the next dispatch."""
         ahead = self._ahead(req)
+        if self._block > 1:
+            return bool(ahead) and self._block_ends(req)
         g = len(req.generated) + ahead
         return bool(ahead) and (g >= req.max_new_tokens
                                 or req.prompt_len + g > self.max_seq)
@@ -1059,10 +1239,17 @@ class ContinuousBatchingScheduler:
                 # through a NULL page-table entry into the trash
                 # page) and its token is discarded.  Kept tokens only
                 # ever attend KV below the horizon, so this is exact.
-                need = min(req.prompt_len + len(req.generated)
-                           + self._ahead(req) + k - 1,
-                           req.prompt_len + req.max_new_tokens - 1,
-                           self.max_seq)
+                if self._block > 1:
+                    # the block this dispatch runs (a commit pass
+                    # moves the cursor only afterwards): mapped whole,
+                    # a block ahead of the cursor
+                    need = min(req.block_start + self._block,
+                               self.max_seq)
+                else:
+                    need = min(req.prompt_len + len(req.generated)
+                               + self._ahead(req) + k - 1,
+                               req.prompt_len + req.max_new_tokens - 1,
+                               self.max_seq)
                 if not self.slots.ensure(slot, need):
                     ok = False
                     break
@@ -1258,7 +1445,10 @@ class ContinuousBatchingScheduler:
         rows = {slot: req for slot, req in self._by_slot.items()
                 if not self._ends_unread(req)}
         prior = self._take_flight()
-        if rows:
+        if rows and self._block > 1:
+            self._flight = self._dispatch_block(rows, t0,
+                                                prior is not None)
+        elif rows:
             self._flight = self._dispatch(rows, spec, t0,
                                           prior is not None)
         if prior is not None:
@@ -1282,6 +1472,13 @@ class ContinuousBatchingScheduler:
             reg.counter("serving_decode_discarded_tokens_total").inc(
                 len(flight.rows))
 
+    def _count_dispatch(self, inflight: bool) -> None:
+        reg = self._registry()
+        if reg:
+            reg.counter("serving_decode_dispatch_total").inc()
+            if inflight:
+                reg.counter("serving_decode_overlapped_total").inc()
+
     def _dispatch(self, rows: Dict[int, Request], spec, t0: float,
                   inflight: bool) -> _Flight:
         """Enqueue one decode dispatch for ``rows``.  Every argument
@@ -1293,11 +1490,7 @@ class ContinuousBatchingScheduler:
         by `PagedKV.flush`."""
         active = np.zeros(self.config.num_slots, bool)
         active[list(rows)] = True
-        reg = self._registry()
-        if reg:
-            reg.counter("serving_decode_dispatch_total").inc()
-            if inflight:
-                reg.counter("serving_decode_overlapped_total").inc()
+        self._count_dispatch(inflight)
         if spec is not None:
             drafts, n_draft = spec
             with span("serving.dispatch", k=self.config.spec_k,
@@ -1330,6 +1523,7 @@ class ContinuousBatchingScheduler:
         """Read one dispatch's tokens — THE host sync — and commit
         them.  Returns the rows retired."""
         spec = flight.accept is not None
+        block = flight.denoised is not None
         accept_host = None
         with span("serving.sync"):
             toks_host = np.asarray(flight.toks)   # THE host sync
@@ -1338,7 +1532,7 @@ class ContinuousBatchingScheduler:
         landed = self.step_timer()
         now = self.clock()
         reg = self._registry()
-        if not spec:
+        if not spec and not block:
             toks_host = toks_host[:, None]
         if flight.counted is not None:
             self._moe_phase(flight.counted, reg)
@@ -1412,11 +1606,17 @@ class ContinuousBatchingScheduler:
             if spec:
                 self._spec_outcome(rows, accept_host, flight.n_draft,
                                    now, reg)
-            retired, generated = self._commit_tokens(
-                rows, toks_host, accept_host, now, reg)
+            if block:
+                retired, generated = self._commit_block(
+                    rows, flight, toks_host, now, reg)
+            else:
+                retired, generated = self._commit_tokens(
+                    rows, toks_host, accept_host, now, reg)
             if sp is not NULL_SPAN:
                 sp.attrs.update(tokens=generated, retired=retired,
                                 discarded=discarded)
+        if block:
+            self._diffusion_phase(flight, generated, reg)
         if reg:
             reg.counter("serving_tokens_generated_total").inc(generated)
         return retired
@@ -1521,45 +1721,11 @@ class ContinuousBatchingScheduler:
             done = False
             for j in range(count):
                 token = int(toks_host[slot, j])
-                req.generated.append(token)
                 committed.append(token)
                 generated += 1
-                if req.t_first_token is None:
-                    req.t_first_token = now
-                    if reg:
-                        reg.histogram("serving_ttft_ms").observe(
-                            max(req.ttft, 0.0) * 1e3)
-                        # The TTFT endpoint: `now` is the same clock
-                        # value the cluster's token mirror stamps, so
-                        # the lineage sum telescopes to the measured
-                        # TTFT exactly (ttft_breakdown's invariant).
-                        self._hop(req, "first_token", now, slot=slot)
-                elif reg:
-                    # With a multi-token dispatch the whole batch
-                    # lands at one sync: TBT is reported at sync
-                    # granularity (the first token carries the gap,
-                    # the rest ~0).
-                    reg.histogram("serving_tbt_ms").observe(
-                        max(now - req.t_last_token, 0.0) * 1e3)
-                req.t_last_token = now
-                if req.on_token is not None:
-                    req.on_token(req, token)
-                reason = None
-                if token in req.eos_token_ids:
-                    reason = FinishReason.EOS
-                elif len(req.generated) >= req.max_new_tokens:
-                    reason = FinishReason.LENGTH
-                elif (req.prompt_len + len(req.generated)
-                      > self.max_seq):
-                    # The NEXT step would write KV at offset
-                    # prompt+generated-1 > max_seq-1; the admission
-                    # rule mirrors this (the final token needs no KV
-                    # write of its own).
-                    reason = FinishReason.KV_CAPACITY
-                if reason is not None:
+                if self._emit_token(slot, req, token, now, reg):
                     # Tokens decoded past this point are discarded —
                     # bounded over-generation.
-                    self._retire(slot, now, reason)
                     retired += 1
                     done = True
                     break
@@ -1581,6 +1747,48 @@ class ContinuousBatchingScheduler:
         if outcomes:
             self.drafter.commit_batched(outcomes)
         return retired, generated
+
+    def _emit_token(self, slot: int, req: Request, token: int,
+                    now: float, reg) -> bool:
+        """One token to its request: appended, timed, streamed via
+        ``on_token``, then checked against EOS / budget / KV horizon.
+        True: it was the request's last and the row is retired."""
+        req.generated.append(token)
+        if req.t_first_token is None:
+            req.t_first_token = now
+            if reg:
+                reg.histogram("serving_ttft_ms").observe(
+                    max(req.ttft, 0.0) * 1e3)
+                # The TTFT endpoint: `now` is the same clock
+                # value the cluster's token mirror stamps, so
+                # the lineage sum telescopes to the measured
+                # TTFT exactly (ttft_breakdown's invariant).
+                self._hop(req, "first_token", now, slot=slot)
+        elif reg:
+            # With a multi-token dispatch the whole batch
+            # lands at one sync: TBT is reported at sync
+            # granularity (the first token carries the gap,
+            # the rest ~0).
+            reg.histogram("serving_tbt_ms").observe(
+                max(now - req.t_last_token, 0.0) * 1e3)
+        req.t_last_token = now
+        if req.on_token is not None:
+            req.on_token(req, token)
+        reason = None
+        if token in req.eos_token_ids:
+            reason = FinishReason.EOS
+        elif len(req.generated) >= req.max_new_tokens:
+            reason = FinishReason.LENGTH
+        elif req.prompt_len + len(req.generated) > self.max_seq:
+            # The NEXT step would write KV at offset
+            # prompt+generated-1 > max_seq-1; the admission
+            # rule mirrors this (the final token needs no KV
+            # write of its own).
+            reason = FinishReason.KV_CAPACITY
+        if reason is None:
+            return False
+        self._retire(slot, now, reason)
+        return True
 
     def _retire(self, slot: int, now: float,
                 reason: FinishReason) -> None:
