@@ -720,7 +720,9 @@ class TestObserveRuntime:
                 SchedulerConfig(num_slots=2, prefill_buckets=(8, 16)),
                 clock=lambda: c.t,
                 clock_advance=lambda dt: setattr(c, "t", c.t + dt))
-            sched.run([Request(prompt=[1, 2, 3], max_new_tokens=6,
+            # (the first read carries the prefill: it is the prefill's
+            # observation and feeds no step baseline)
+            sched.run([Request(prompt=[1, 2, 3], max_new_tokens=7,
                                arrival_time=0.0)])
             cfg = tuner.cache[tuner.key_fn(x)].config
             store = an.get_baseline_store()

@@ -581,13 +581,21 @@ def test_one_step_leaves_the_phase_spans(toy, tracer, layout):
         assert sp.parent == step.id, name
     (admit,) = spans["serving.admit"]
     assert admit.attrs == {"queued": 2}
+    # an admission's two halves — the prefill's enqueue, then the
+    # insert's dispatch — side by side under the admission loop; the
+    # host waits for neither prefill (no `serving.prefill.block`), and
+    # with nothing in flight there was no step to read between them
     ones = spans["serving.admit.request"]
-    blocks = spans["serving.prefill.block"]
+    fronts = spans["serving.admit.prefill"]
+    assert "serving.prefill.block" not in spans
     assert [s.parent for s in ones] == [admit.id] * 2
-    assert [s.parent for s in blocks] == [s.id for s in ones]
-    for one, block, req in zip(ones, blocks, reqs):
+    assert [s.parent for s in fronts] == [admit.id] * 2
+    for one, front, req in zip(ones, fronts, reqs):
         assert one.attrs["request_id"] == req.request_id
-        assert block.attrs == {"request_id": req.request_id}
+        assert front.attrs == {"request_id": req.request_id,
+                               "behind_flight": 0}
+        assert front.t0 + front.dur <= one.t0
+        assert one.attrs["read_flight"] == 0
         assert one.attrs["prompt_len"] == req.prompt_len
         assert one.attrs["bucket"] == req.bucket
         assert one.attrs["mode"] == "local"

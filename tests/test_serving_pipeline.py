@@ -644,20 +644,24 @@ def test_sync_is_a_direct_child_of_every_step_that_read_a_step(
 def test_decode_step_ms_is_one_steps_time(toy, metrics):
     """Each step is timed once, from its dispatch — or from the
     landing of the step before it, if later — to its own read: the
-    times add up to the clock's span, never to twice it."""
+    times add up to the clock's span, never to twice it.  The first
+    read carried the prefill: it is the prefill's reading (here with
+    no step measured before it: unobserved), no step's."""
     model, params = toy
     sched = make_sched(model, params)
     ticks = iter(range(10_000))
     sched.step_timer = lambda: float(next(ticks))
     sched.run([Request(prompt=[1, 2, 3], max_new_tokens=6)])
     h = metrics.snapshot()["histograms"]["serving_decode_step_ms"]
-    assert h["count"] == 6
-    # two readings a call (its start, a landing), and a step lands one
+    assert h["count"] == 5
+    assert counter(metrics, "serving_prefill_unobserved_total") == 1
+    # the admission reads the timer twice (its first half), then two
+    # readings a call (its start, a landing), and a step lands one
     # call after its dispatch.  From its own dispatch to its own read a
     # step would count 3 ticks — the landing of the step before it lies
-    # between; counted from that landing it is 2, and the six add up
-    # to the clock's twelve
-    assert h["sum"] == pytest.approx(12e3)
+    # between; counted from that landing it is 2, and the five add up
+    # to the ten ticks after the first landing
+    assert h["sum"] == pytest.approx(10e3)
     assert h["max"] == pytest.approx(2e3)
 
 
@@ -671,3 +675,417 @@ def test_a_speculating_scheduler_keeps_nothing_in_flight(toy, metrics):
         assert sched._flight is None
     assert req.generated == golden(model, params, req.prompt, 6)
     assert counter(metrics, "serving_decode_overlapped_total") == 0
+
+
+# ---------------------------------------------------------------------------
+# 3. an admission joins the pipeline: the host waits for the step in
+#    flight, never for the prefill
+# ---------------------------------------------------------------------------
+
+#: Where the read of the step in flight goes in an admitting call:
+#: between the admission's halves, ahead of them, or as measured.
+ORDERS = {"front_first": True, "read_first": False, "measured": None}
+
+
+def pin_order(sched, order):
+    if ORDERS[order] is not None:
+        sched._front_fits = lambda: ORDERS[order]
+
+
+def enqueues(sched, log):
+    """Note every enqueue of a prefill and of an insert in ``log``, on
+    the spans' clock."""
+    prefill, insert = sched._prefill, sched.slots._insert
+
+    def prefilling(*args):
+        log.append((time.perf_counter(), "prefill"))
+        return prefill(*args)
+
+    def inserting(*args):
+        log.append((time.perf_counter(), "insert"))
+        return insert(*args)
+    sched._prefill, sched.slots._insert = prefilling, inserting
+
+
+def in_order(tracer, log, step):
+    """What one `step()` did, in time order: its phase spans and the
+    enqueues noted in ``log``."""
+    (root,) = [s for s in tracer.finished()
+               if s.name == "serving.step" and s.attrs["step"] == step]
+    seen = [(s.t0, s.name) for s in tracer.finished()
+            if s.parent == root.id and s.name != "serving.gauges"]
+    seen += [e for e in log if root.t0 <= e[0] <= root.t0 + root.dur]
+    return [name for _, name in sorted(seen)]
+
+
+@pytest.mark.parametrize("order", ["front_first", "read_first"])
+def test_an_admitting_call_reads_the_step_in_flight_early(
+        toy, tracer, metrics, order):
+    """prefill enqueue -> sync -> commit -> insert -> pages -> dispatch
+    (or, where the first half would not fit: sync -> commit first), the
+    step dispatched with nothing in flight; the call after it is a
+    plain one: dispatch -> sync."""
+    model, params = toy
+    sched = make_sched(model, params)
+    pin_order(sched, order)
+    log = []
+    enqueues(sched, log)
+    got = []
+    a = Request(prompt=[1, 2, 3, 4], max_new_tokens=9,
+                on_token=lambda r, t: got.append(len(log)))
+    sched.submit(a)
+    sched.step()
+    sched.step()
+    assert sched._flight is not None and len(a.generated) == 1
+    b = Request(prompt=[5, 6, 7], max_new_tokens=4)
+    sched.submit(b)
+    tracer.clear()
+    del log[:]
+    out = sched.step()                  # step 3: admits b
+    assert out == {"admitted": 1, "active": 2, "retired": 0}
+    halves = ["serving.admit", "prefill", "serving.sync",
+              "serving.commit", "serving.admit", "insert"]
+    if order == "read_first":
+        halves = ["serving.sync", "serving.commit", "serving.admit",
+                  "prefill", "insert"]
+    assert in_order(tracer, log, 3) == halves + [
+        "serving.pages", "serving.dispatch"]
+    # a's token was delivered before the insert was enqueued
+    assert len(a.generated) == 2 and len(log) == 2
+    assert got[-1] == int(order == "front_first")
+    assert b.generated == [] and sched._flight is not None
+    (dispatch,) = [s for s in tracer.finished()
+                   if s.name == "serving.dispatch"]
+    assert dispatch.attrs["inflight"] == 0
+    (front,) = [s for s in tracer.finished()
+                if s.name == "serving.admit.prefill"]
+    (one,) = [s for s in tracer.finished()
+              if s.name == "serving.admit.request"]
+    assert front.attrs == {"request_id": b.request_id,
+                           "behind_flight": int(order == "front_first")}
+    assert one.attrs["read_flight"] == 1
+    # sync and commit stay the step's own children, between the halves
+    syncs = [s for s in tracer.finished() if s.name == "serving.sync"]
+    steps = [s.id for s in tracer.finished() if s.name == "serving.step"]
+    assert [s.parent for s in syncs] == steps
+    sched.step()                        # step 4: a plain call
+    assert in_order(tracer, log, 4) == [
+        "serving.pages", "serving.dispatch", "serving.sync",
+        "serving.commit"]
+    assert len(a.generated) == 3 and len(b.generated) == 1
+    sched.drain()
+    assert counter(metrics, "serving_admit_overlapped_total") == 2
+    assert counter(metrics, "serving_prefills_total") == 2
+    flights = {k: v for k, v in metrics.snapshot()["counters"].items()
+               if k.startswith("serving_admit_overlapped_total")}
+    assert flights == {
+        'serving_admit_overlapped_total{flight="none"}': 1,
+        'serving_admit_overlapped_total{flight="%s"}'
+        % ("behind" if order == "front_first" else "read"): 1}
+    assert a.generated == golden(model, params, [1, 2, 3, 4], 9)
+    assert b.generated == golden(model, params, [5, 6, 7], 4)
+
+
+# -- the device as one queue, the host as one clock ---------------------------
+
+class Modelled:
+    """A scheduler run against a MODEL of its machine: the device one
+    queue that runs what is enqueued in order, for scripted times; the
+    host one clock that every dispatch, read and commit moves on by a
+    scripted cost.  The toy's arrays land at once, so the times are
+    the model's and the ORDER of enqueues and reads is the
+    scheduler's own — which it chooses by this clock (`step_timer`).
+    `ops` is that order, `replay` runs any such order again."""
+
+    def __init__(self, host, device):
+        self.host, self.device = host, device
+        self.now = self.free = 0.0
+        self.ends, self.ops, self.commits = {}, [], []
+
+    def call(self):
+        self.ops.append(("call",))
+        self.now += self.host["call"]
+
+    def enqueue(self, kind, key=None):
+        self.ops.append(("enqueue", kind, key))
+        self.now += self.host[kind]
+        self.free = max(self.free, self.now) + self.device[kind]
+        self.ends[key] = self.free
+
+    def read(self, key):
+        self.ops.append(("read", key))
+        self.now = max(self.now, self.ends[key])
+        self.commits.append(self.now)
+        self.now += self.host["commit"]
+
+    @classmethod
+    def replay(cls, ops, host, device):
+        out = cls(host, device)
+        for op in ops:
+            getattr(out, op[0])(*op[1:])
+        return out
+
+    def drive(self, sched):
+        """Put ``sched`` on this machine."""
+        prefill, insert, step, read = (
+            sched._prefill, sched.slots._insert, sched._step, sched._read)
+
+        def prefilling(*args):
+            self.enqueue("prefill")
+            return prefill(*args)
+
+        def inserting(*args):
+            self.enqueue("insert")
+            return insert(*args)
+
+        def stepping(*args):
+            out = step(*args)
+            self.enqueue("step", id(out[0]))
+            return out
+
+        def reading(flight):
+            self.read(id(flight.toks))
+            return read(flight)
+        sched._prefill, sched.slots._insert = prefilling, inserting
+        sched._step, sched._read = stepping, reading
+        sched.step_timer = lambda: self.now
+
+    def long_intervals(self):
+        """Commit-to-commit intervals over 1.25 x their median."""
+        gaps = np.diff(self.commits)
+        return int((gaps > 1.25 * np.median(gaps)).sum())
+
+
+def admissions_under_load(sched, machine, n=5):
+    """Two rows that run throughout and ``n`` short requests admitted
+    one by one, each with a step in flight.  Returns them all."""
+    reqs = [Request(prompt=[3, 1, 4, 1, 5], max_new_tokens=60),
+            Request(prompt=[2, 7, 1, 8], max_new_tokens=60)]
+    for r in reqs:
+        sched.submit(r)
+    for i in range(n * 9):
+        if i % 9 == 4:
+            reqs.append(Request(prompt=[9, 2, 6, 5, 3, 5 + i],
+                                max_new_tokens=3))
+            sched.submit(reqs[-1])
+        machine.call()
+        sched.step()
+    assert len(sched._by_slot) == 2 and len(reqs) == n + 2
+    return reqs
+
+
+def misplaced(ops):
+    """``ops`` with the read of every admitting call moved behind that
+    call's dispatches: the admission's work and the next step's
+    dispatch both in front of the read of the step in flight."""
+    calls = []
+    for op in ops:
+        if op[0] == "call":
+            calls.append([])
+        calls[-1].append(op)
+    for call in calls:
+        if ("enqueue", "prefill", None) in call:
+            call.sort(key=lambda op: op[0] == "read")
+    return [op for call in calls for op in call]
+
+
+#: Four chips' shape (PERF.md section 5, milliseconds): a 7.3 ms step
+#: whose dispatch costs the host more than half of it; an admission
+#: costs it 6 (3 to the prefill's enqueue, 3 for the insert).
+TP4 = ({"call": 0.2, "step": 4.3, "prefill": 3.0, "insert": 3.0,
+        "commit": 0.3},
+       {"step": 7.3, "prefill": 30.0, "insert": 0.4})
+
+
+@pytest.mark.parametrize("front_ms,flight", [(3.0, "behind"),
+                                             (10.0, "read")])
+def test_an_admission_lengthens_one_token_gap_not_two(
+        toy, metrics, front_ms, flight):
+    """Where the host's dispatch costs more than half a step, the
+    admission's host work has to lie where the prefill hides it: ONE
+    commit-to-commit interval per admission is long.  The scheduler
+    places the read by what it measured — a first half that fits into
+    what is left of the step in flight runs in front of the read, one
+    that does not runs behind it — and either way the same order of
+    enqueues and reads with the admission's work AND the next step's
+    dispatch in front of the read (PR 37's tp4 regression: `itl_p95_ms`
+    7.73 -> 10.58) shows two."""
+    model, params = toy
+    host = dict(TP4[0], prefill=front_ms)
+    machine = Modelled(host, TP4[1])
+    sched = make_sched(model, params, num_slots=4)
+    machine.drive(sched)
+    reqs = admissions_under_load(sched, machine)
+    sched.drain()
+    # the first admissions found nothing measured yet and read first
+    placed = {k: v for k, v in metrics.snapshot()["counters"].items()
+              if k.startswith("serving_admit_overlapped_total")}
+    for r in reqs:
+        assert r.generated == golden(model, params, r.prompt,
+                                     r.max_new_tokens)
+    assert placed['serving_admit_overlapped_total{flight="%s"}'
+                  % flight] >= 4
+    again = Modelled.replay(machine.ops, host, TP4[1])
+    assert again.commits == machine.commits
+    assert np.median(np.diff(machine.commits)) == pytest.approx(7.3)
+    assert machine.long_intervals() == 5
+    wrong = Modelled.replay(misplaced(machine.ops), host, TP4[1])
+    assert len(wrong.commits) == len(machine.commits)
+    assert wrong.long_intervals() == 10
+
+
+def test_a_first_half_that_does_not_fit_in_front_of_the_read_costs_two(
+        toy):
+    """Why the read's place is measured and not fixed: with a first
+    half of 10 ms against a 7.3 ms step, enqueuing the prefill in front
+    of the read delivers the tokens of the step in flight late."""
+    model, params = toy
+    host = dict(TP4[0], prefill=10.0)
+    machine = Modelled(host, TP4[1])
+    sched = make_sched(model, params, num_slots=4)
+    machine.drive(sched)
+    pin_order(sched, "front_first")
+    admissions_under_load(sched, machine)
+    assert machine.long_intervals() == 10
+
+
+# -- streams ------------------------------------------------------------------
+
+@pytest.mark.parametrize("layout", ["paged", "slots"])
+@pytest.mark.parametrize("order", sorted(ORDERS))
+def test_streams_with_admissions_on_every_phase_equal_the_serial(
+        toy, order, layout):
+    """An admission into an idle server; one with a step in flight;
+    a row retired by the early read and its slot refilled in the same
+    call, beside a second admission of that call: token for token the
+    serial scheduler's."""
+    model, params = toy
+    sched = make_sched(model, params, layout)
+    pin_order(sched, order)
+    prompts = rand_prompts(5, seed=33)
+    new = (3, 12, 5, 4, 6)
+    a, b, c, d, e = reqs = [
+        Request(prompt=p, max_new_tokens=n, seed=90 + i)
+        for i, (p, n) in enumerate(zip(prompts, new))]
+    sched.submit(a)                     # into an idle server
+    assert sched.step()["admitted"] == 1
+    sched.submit(b)                     # with a's first step in flight
+    assert sched.step() == {"admitted": 1, "active": 2, "retired": 0}
+    sched.step()
+    # a's last step is in flight; one slot is free, two are waiting:
+    # c takes it, the early read retires a, and d takes a's slot
+    assert len(a.generated) == 2 and sched._flight is not None
+    sched.submit(c)
+    sched.submit(d)
+    out = sched.step()
+    assert out == {"admitted": 2, "active": 4, "retired": 1}
+    assert a.finish_reason == FinishReason.LENGTH and d.slot == 0
+    assert sched._flight is not None and len(sched._flight.prefills) == 2
+    sched.submit(e)                     # queued until a slot frees
+    sched.drain()
+    for r, p, n in zip(reqs, prompts, new):
+        assert r.generated == golden(model, params, p, n, seed=r.seed)
+
+
+def test_sampled_streams_with_admissions_mid_stream_equal_the_serial(
+        toy):
+    """The staggered, sampled mix of
+    `test_pipelined_streams_equal_the_serial_schedulers`, with the
+    read ahead of the halves and between them."""
+    model, params = toy
+    prompts = rand_prompts(9, seed=21)
+
+    def serve(order=None, **kw):
+        sched = make_sched(model, params, temperature=0.8, **kw)
+        if order:
+            pin_order(sched, order)
+        done = sched.run([
+            Request(prompt=p, max_new_tokens=2 + i % 6, seed=70 + i,
+                    arrival_time=0.01 * (i // 2))
+            for i, p in enumerate(prompts)])
+        return [r.generated for r in
+                sorted(done, key=lambda r: r.request_id)]
+
+    serial = serve(spec_k=2, spec_drafter=lambda s: _NoDrafts())
+    assert serve("front_first") == serial
+    assert serve("read_first") == serial
+
+
+# -- what the block fed stays fed ----------------------------------------------
+
+def test_a_read_that_carried_a_prefill_is_the_prefills_observation(
+        toy, metrics, monkeypatch):
+    """`serving_decode_step_ms` gets nothing from it; where exactly one
+    prefill stood in front, `serving_prefill_ms` and the bucket's
+    baseline get the reading less the rolling step time; the rest are
+    counted as unobserved.  No wait of its own anywhere."""
+    from triton_distributed_tpu.serving import scheduler as module
+    model, params = toy
+    fed = []
+    monkeypatch.setattr(module, "_observe_prefill",
+                        lambda bucket, ms: fed.append((bucket, ms)))
+    sched = make_sched(model, params, num_slots=4)
+    ticks = iter(range(10_000))
+    sched.step_timer = lambda: float(next(ticks))
+    waited = []
+    ready = jax.block_until_ready
+    jax.block_until_ready = lambda x: waited.append(x) or ready(x)
+    try:
+        a = Request(prompt=[1, 2, 3], max_new_tokens=30)
+        sched.submit(a)
+        for _ in range(3):
+            sched.step()    # its first read: no step measured yet
+        assert counter(metrics, "serving_prefill_unobserved_total") == 1
+        hists = metrics.snapshot()["histograms"]
+        assert "serving_prefill_ms" not in hists
+        assert hists["serving_decode_step_ms"]["count"] == 1
+        b = Request(prompt=[4, 5, 6, 7], max_new_tokens=4)
+        sched.submit(b)
+        sched.step()        # admits b: ONE prefill before the dispatch
+        assert sched._flight.prefills == [(8, b)]
+        reads = metrics.snapshot()["histograms"][
+            "serving_decode_step_ms"]["count"]
+        sched.step()        # its read: the prefill's observation
+        hists = metrics.snapshot()["histograms"]
+        assert hists["serving_prefill_ms"]["count"] == 1
+        assert hists["serving_prefill_ms"]["sum"] >= 0.0
+        assert hists["serving_decode_step_ms"]["count"] == reads
+        assert fed == [(8, hists["serving_prefill_ms"]["sum"])]
+        sched.step()
+        assert metrics.snapshot()["histograms"][
+            "serving_decode_step_ms"]["count"] == reads + 1
+        for p in ([8, 9, 10], [11, 12, 13, 14]):
+            sched.submit(Request(prompt=p, max_new_tokens=2))
+        sched.step()        # two prefills in front of one dispatch
+        assert len(sched._flight.prefills) == 2
+        sched.step()
+        assert counter(metrics, "serving_prefill_unobserved_total") == 3
+        assert metrics.snapshot()["histograms"][
+            "serving_prefill_ms"]["count"] == 1 == len(fed)
+        sched.stop()        # b and the two: nothing left to time them
+        assert counter(metrics, "serving_prefills_total") == 4
+    finally:
+        jax.block_until_ready = ready
+    assert waited == []
+
+
+def test_stop_with_an_admission_enqueued_counts_its_prefill_unobserved(
+        toy, metrics):
+    model, params = toy
+    sched = make_sched(model, params)
+    a = Request(prompt=[1, 2, 3], max_new_tokens=9)
+    sched.submit(a)
+    for _ in range(3):
+        sched.step()
+    b = Request(prompt=[4, 5, 6, 7], max_new_tokens=4)
+    sched.submit(b)
+    sched.step()
+    assert sched._flight.prefills and b.slot is not None
+    sched.stop()
+    assert sched._flight is None and sched._prefills == []
+    assert counter(metrics, "serving_prefill_unobserved_total") == 2
+    assert a.finish_reason == b.finish_reason == FinishReason.STOPPED
+    sched.restart()
+    again = Request(prompt=[4, 5, 6, 7], max_new_tokens=4)
+    sched.run([again])
+    assert again.generated == golden(model, params, [4, 5, 6, 7], 4)

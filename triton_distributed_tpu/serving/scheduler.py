@@ -59,11 +59,35 @@ the PR-1/2 stack: TTFT / TBT / queue-wait histograms, queue-depth /
 slot-occupancy / KV-budget gauges (all in the Prometheus export), one
 detached `serving.request` span per request feeding the cross-rank
 timeline, and a span around each phase of a step (`serving.step` >
-`serving.admit` > `serving.admit.request` > `serving.prefill.block`;
-`serving.pages`, `serving.dispatch`, `serving.sync`, `serving.moe` (a
-sparse model's expert counters), `serving.diffusion` (a block pass's
-rows by phase and tokens), `serving.commit`, `serving.gauges`)
-that says where the host's time in a step went.
+`serving.admit` > `serving.admit.prefill`, `serving.admit.request`: an
+admission's two halves; `serving.pages`, `serving.dispatch`,
+`serving.sync`, `serving.moe` (a sparse model's expert counters),
+`serving.diffusion` (a block pass's rows by phase and tokens),
+`serving.commit`, `serving.gauges`) that says where the host's time in
+a step went.
+
+An admission joins the pipeline: the host never waits for a prefill.
+It is made in two halves — the first (`_admit_front`) matches the
+prefix and ENQUEUES the prefill, the second (`_admit_insert`)
+dispatches the insert and keeps the books — and in a call that admits
+while a step is in flight the host's one wait is for THAT step, whose
+tokens are due: it is read once, early, and `_decode_step` then finds
+nothing in flight, dispatches step t+1 behind prefill and insert and
+reads nothing.  Where the read goes is decided by what the host has
+measured (`_front_fits`): BETWEEN the halves when the first half's
+host time — the slowest of the last few — fits into what is left of
+the step in flight — the prefill then waits on the device behind it
+and the chip never idles — else BEFORE the first half, so that the tokens of the step in
+flight are not held up by it (where the host's dispatch costs more
+than half a step, as over four chips, a first half in front of the
+read would lengthen a second token gap of every running row).  The
+device's queue is the same either way — step t, prefill, insert, step
+t+1 — with every argument of the provenance the serial order gave it;
+only the moment at which the host blocks differs.  What a prefill
+took is read from the one sync there is: the first read after it
+measures prefill + step, and that reading less the rolling step time
+is the prefill's (`_prefill_reading`); it is kept out of the step
+metrics.
 Metric and span names: docs/serving.md, docs/observability.md.
 """
 
@@ -71,6 +95,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import statistics
 import time
 from typing import Callable, Deque, Dict, List, Optional, Sequence
 
@@ -241,6 +266,10 @@ class _Flight:
     #: returned): slot -> (first position of the row's block, positions
     #: it reveals) of the DENOISE rows; the rest of ``rows`` committed.
     denoised: Optional[Dict[int, tuple]] = None
+    #: (bucket, request) of the prefills enqueued since the dispatch
+    #: before this one: the device runs them first, so the read of
+    #: this dispatch times THEM (`_prefill_reading`), not a step.
+    prefills: Sequence[tuple] = ()
 
 
 class ContinuousBatchingScheduler:
@@ -417,6 +446,20 @@ class ContinuousBatchingScheduler:
         self._flight: Optional[_Flight] = None
         #: `step_timer` reading when the last step's tokens landed.
         self._read_at = float("-inf")
+        #: The last plain dispatches' times, each from the landing of
+        #: the one before it to its own (seconds; reads that carried a
+        #: prefill are kept out) — their median is the rolling step
+        #: time — and the host time of the last admissions' first
+        #: halves: what `_front_fits` and `_prefill_reading` reckon
+        #: with.  (Medians and a maximum of few: one pause of the
+        #: collector inside a step must not sit in a mean for seconds.)
+        self._step_times: Deque[float] = collections.deque(maxlen=32)
+        self._front_times: Deque[float] = collections.deque(maxlen=16)
+        #: (bucket, request) of the prefills enqueued since the last
+        #: dispatch, and `step_timer` at the first one's enqueue: the
+        #: next dispatch takes them along (`_Flight.prefills`).
+        self._prefills: List[tuple] = []
+        self._prefill_t0 = 0.0
         #: Per-bucket reusable prefill input caches (see _admit).
         self._row_caches: Dict[int, object] = {}
         self._queue: Deque[Request] = collections.deque()
@@ -573,11 +616,11 @@ class ContinuousBatchingScheduler:
 
     def _step_phases(self) -> dict:
         now = self.clock()
-        admitted = self._admit(now)
-        retired = 0
-        active_n = len(self._by_slot)
+        admitted, retired = self._admit(now)
+        # (rows that an admission's read retired were in the batch)
+        active_n = len(self._by_slot) + retired
         if self._by_slot or self._flight is not None:
-            retired = self._decode_step()
+            retired += self._decode_step()
         elif self._queue:
             # Nothing running, head not arrived yet: move time.
             dt = self._queue[0].t_arrival - now
@@ -839,102 +882,176 @@ class ContinuousBatchingScheduler:
                 inputs=inputs))
         return True
 
-    def _admit(self, now: float) -> int:
+    def _head_ready(self, now: float) -> bool:
+        """The queue's head has arrived, a slot and the pool take it
+        and the SLO gate lets it in: it is admitted in this call."""
+        return bool(self._queue and self._queue[0].t_arrival <= now
+                    and self._can_admit_head() and self._slo_gate(now))
+
+    def _admit(self, now: float) -> tuple:
+        """Admit what the queue's head, the slots and the pool allow.
+        Returns (requests admitted, rows retired): a call that admits
+        while a step is in flight READS that step here, once — between
+        the first admission's halves or ahead of them (`_front_fits`)
+        — so its tokens never wait for an insert's dispatch, and the
+        host waits for no prefill at all.  A slot that read frees may
+        be filled in the same call; every admission after it has no
+        flight to read and enqueues straight through."""
         # Nothing to try: no arrived head, or no slot to put it in
         # (both layouts refuse without a free slot) — and no span.
         if (not self._queue or self._stopped
                 or self._queue[0].t_arrival > now
                 or not self.slots.free_slots):
-            return 0
-        n = 0
-        with span("serving.admit", queued=len(self._queue)):
-            while (self._queue and self._queue[0].t_arrival <= now
-                   and self._can_admit_head()
-                   and self._slo_gate(now)):
-                req = self._queue.popleft()
-                with span("serving.admit.request",
-                          request_id=req.request_id,
-                          prompt_len=req.prompt_len) as sp:
-                    n += self._admit_one(req, now, sp)
-        return n
+            return 0, 0
+        n = retired = 0
+        read = False      # this call read the step in flight
+        began = None      # the admission whose insert waits for that read
+        ready = self._head_ready(now)
+        while ready or began is not None:
+            if self._flight is not None and (began is not None
+                                             or not self._front_fits()):
+                retired += self._read(self._take_flight())
+                read = True
+            with span("serving.admit", queued=len(self._queue)):
+                if began is not None:
+                    self._admit_insert(began, now, read)
+                    n, began, read = n + 1, None, False
+                    ready = self._head_ready(now)
+                while ready:
+                    front = self._admit_front(self._queue.popleft(), now)
+                    if front is not None and self._flight is not None:
+                        began = front       # its insert: after the read
+                        break
+                    if front is not None:   # (None: retired at admission)
+                        self._admit_insert(front, now, read)
+                        n, read = n + 1, False
+                    ready = self._head_ready(now)
+        return n, retired
 
-    def _admit_one(self, req: Request, now: float, sp) -> int:
-        """Admit ``req`` (already off the queue) into a slot: prefix
-        match, prefill dispatch, insert dispatch.  Returns 1, or 0
-        when the request was retired at admission.  ``sp`` is the
-        `serving.admit.request` span (or the no-op one)."""
+    def _front_fits(self) -> bool:
+        """With a step in flight and an admission due: True when the
+        admission's first half may run in front of the read of that
+        step — its tokens are still not due when the prefill is
+        enqueued, and the device goes from the step into the prefill
+        without waiting for the host.  False: read first; the chip then
+        idles for the first half, and no token waits for it.  Judged by
+        what was measured: the step lands a rolling step time after
+        the one before it did; the first half takes what the slowest
+        of the last few took (they scatter with the prompt's length
+        and bucket).  A step behind a prefill has all of that
+        prefill's time left.  Nothing measured yet: read first."""
+        flight = self._flight
+        if flight.prefills:
+            return True
+        if not (self._step_times and self._front_times):
+            return False
+        lands = max(flight.t0, self._read_at) + self._step_s()
+        return self.step_timer() + max(self._front_times) <= lands
+
+    def _step_s(self) -> float:
+        """The rolling time of a plain dispatch, in seconds."""
+        return statistics.median(self._step_times)
+
+    def _admit_front(self, req: Request, now: float):
+        """First half of the admission of ``req`` (already off the
+        queue): prefix match and the prefill's ENQUEUE — or a
+        shipment's row.  Nothing here waits for the device.  Returns
+        what `_admit_insert` takes, or None when the request was
+        retired at admission."""
         reg = self._registry()
         had_ship = req.shipped_kv is not None
-        cached = 0
-        if self.paged:
-            admitted = self._admit_paged(req, now, reg)
-            if admitted is None:
-                return 0              # retired at admission
-            slot, bucket, tokens, mode, cached = admitted
-        else:
-            tokens = req.prompt
-            mode = "local"
-            if req.shipped_kv is not None:
-                row_cache, s, bucket = self._shipped_row(req, reg)
-                mode = "shipped"
+        behind = self._flight is not None
+        t0 = self.step_timer()
+        with span("serving.admit.prefill", request_id=req.request_id,
+                  behind_flight=int(behind)):
+            if self.paged:
+                row = self._prefill_paged(req, now, reg)
+                if row is None:
+                    return None           # retired at admission
+            elif had_ship:
+                row = self._shipped_row(req, reg) + ("shipped",)
             else:
                 bucket = pick_bucket(req.prompt_len, self.buckets)
                 assert bucket is not None  # submit() validated
                 ids, s = pad_prompt(req.prompt, bucket,
                                     self.config.pad_id)
-                row_in = self._row_cache(bucket)
-                t0 = time.perf_counter()
                 _, row_cache = self._prefill(self.params, ids,
-                                             row_in)
-                if reg:
-                    # dispatch is async: block so the histogram
-                    # records prefill compute, not dispatch (as
-                    # Engine.serve does)
-                    with span("serving.prefill.block",
-                              request_id=req.request_id):
-                        jax.block_until_ready(row_cache.ks[0])
-                    ms = (time.perf_counter() - t0) * 1e3
-                    reg.histogram("serving_prefill_ms").observe(ms)
-                    _observe_prefill(bucket, ms)
-                    self._charge_device("prefill", ms * 1e3,
-                                        (req,))
-            slot = self.slots.insert_prefill(
-                row_cache, s, self._request_key(req))
-        if self._block > 1:
-            self._start_block(slot, req, tokens)
-        else:
-            self._tokens[slot] = tokens[-1]
-        self._fresh[slot] = True
-        req.state = RequestState.RUNNING
-        req.slot = slot
-        req.bucket = bucket
-        req.t_admitted = now
-        self._by_slot[slot] = req
-        if self.drafter is not None and not self._spec_throttled:
-            # Admission (or resume) seeds the draft state from the
-            # full committed context — same tokens that seeded the
-            # slot's input above.  A throttled engine skips the
-            # upkeep entirely (draft prefills, reconcile
-            # dispatches): the throttle is for the scheduler's
-            # lifetime, so the draft cache will never be read.
-            self.drafter.start(req, tokens)
-        if sp is not NULL_SPAN:
-            sp.attrs.update(bucket=bucket, cached_tokens=cached,
-                            mode=mode, slot=slot)
-        life = get_tracer().detached(
-            "serving.request", request_id=req.request_id,
-            prompt_len=req.prompt_len, slot=slot, bucket=bucket)
-        life.__enter__()
-        self._spans[slot] = life
+                                             self._row_cache(bucket))
+                row = (row_cache, s, bucket, "local")
+        # A consumed shipment (`_shipped_row` clears the hook) ran NO
+        # local prefill — it has its own serving_shipped_inserts_total,
+        # and counting it would desync serving_prefills_total from the
+        # serving_prefill_ms histogram it pairs with.
+        local = not (had_ship and req.shipped_kv is None)
+        if local:
+            if not self._prefills:
+                self._prefill_t0 = t0
+            # (either layout's row ends: bucket, mode)
+            self._prefills.append((row[-2], req))
+            self._front_times.append(self.step_timer() - t0)
+        return req, row, local, behind
+
+    def _admit_insert(self, front, now: float, read: bool) -> None:
+        """Second half of an admission: the insert's dispatch into a
+        slot, and the books.  ``read``: the step in flight was read
+        for this admission (`serving.admit.request`'s ``read_flight``).
+        """
+        req, row, local, behind = front
+        reg = self._registry()
+        with span("serving.admit.request", request_id=req.request_id,
+                  prompt_len=req.prompt_len) as sp:
+            cached = 0
+            if self.paged:
+                (row_cache, tokens, s, key, shared, row_start, bucket,
+                 mode) = row
+                cached = len(shared) * self.config.page_size
+                slot = self.slots.insert_prefill(
+                    row_cache, tokens, s, key, shared,
+                    row_start=row_start,
+                    offset=(s // self._block * self._block
+                            if self._block > 1 else None))
+            else:
+                row_cache, s, bucket, mode = row
+                tokens = req.prompt
+                slot = self.slots.insert_prefill(
+                    row_cache, s, self._request_key(req))
+            if self._block > 1:
+                self._start_block(slot, req, tokens)
+            else:
+                self._tokens[slot] = tokens[-1]
+            self._fresh[slot] = True
+            req.state = RequestState.RUNNING
+            req.slot = slot
+            req.bucket = bucket
+            req.t_admitted = now
+            self._by_slot[slot] = req
+            if self.drafter is not None and not self._spec_throttled:
+                # Admission (or resume) seeds the draft state from the
+                # full committed context — same tokens that seeded the
+                # slot's input above.  A throttled engine skips the
+                # upkeep entirely (draft prefills, reconcile
+                # dispatches): the throttle is for the scheduler's
+                # lifetime, so the draft cache will never be read.
+                self.drafter.start(req, tokens)
+            if sp is not NULL_SPAN:
+                sp.attrs.update(bucket=bucket, cached_tokens=cached,
+                                mode=mode, slot=slot,
+                                read_flight=int(read))
+            life = get_tracer().detached(
+                "serving.request", request_id=req.request_id,
+                prompt_len=req.prompt_len, slot=slot, bucket=bucket)
+            life.__enter__()
+            self._spans[slot] = life
         if reg:
-            # A consumed shipment (`_shipped_row` clears the
-            # hook) ran NO local prefill — it has its own
-            # serving_shipped_inserts_total, and counting it here
-            # would desync this counter from the
-            # serving_prefill_ms histogram it pairs with.
-            if not (had_ship and req.shipped_kv is None):
+            if local:
                 reg.counter("serving_prefills_total",
                             bucket=str(bucket)).inc()
+                # enqueued and never waited for: behind the step in
+                # flight, after its read, or with nothing in flight
+                reg.counter(
+                    "serving_admit_overlapped_total",
+                    flight=("behind" if behind
+                            else "read" if read else "none")).inc()
             reg.histogram("serving_queue_wait_ms").observe(
                 max(now - req.t_arrival, 0.0) * 1e3)
             if (req.resume_tokens is not None or req.preemptions
@@ -949,23 +1066,22 @@ class ContinuousBatchingScheduler:
             else:
                 self._hop(req, "admit", now, slot=slot,
                           bucket=bucket, mode=mode)
-        return 1
 
-    def _admit_paged(self, req: Request, now: float, reg):
-        """Paged admission: radix prefix match, suffix-only prefill on
-        a hit (near-zero-cost shared system prompts), paged insert.
-        Returns (slot, bucket, tokens, mode, cached tokens) — mode is
-        the lineage admission class (local / shipped / suffix) — or
-        None when the
-        request had to be retired at admission (a resumed stream that
-        no longer fits any prefill bucket)."""
+    def _prefill_paged(self, req: Request, now: float, reg):
+        """Paged admission, first half: radix prefix match, then the
+        prefill's enqueue — suffix-only on a hit (near-zero-cost
+        shared system prompts).  Returns what the paged insert takes —
+        (row, tokens, prompt length, key, shared path, row start,
+        bucket, mode), mode the lineage admission class (local /
+        shipped / suffix) — or None when the request had to be retired
+        at admission (a resumed stream that no longer fits any prefill
+        bucket)."""
         tokens = req.resume_tokens or req.prompt
         s = len(tokens)
         shared = self.slots.match_prefix(tokens)
         c = len(shared) * self.config.page_size
         key = self._request_key(req)
         bucket = row = row_start = None
-        t0 = None
         mode = "local"
         if req.shipped_kv is not None and req.resume_tokens is None:
             # Prefill-worker shipment: the full-prompt row arrives
@@ -985,7 +1101,6 @@ class ContinuousBatchingScheduler:
             if bucket is not None:
                 ids, _ = pad_prompt(tokens[c:], bucket,
                                     self.config.pad_id)
-                t0 = time.perf_counter()
                 row = self._prefill_suffix(self.params, ids,
                                            jnp.int32(c),
                                            self._row_cache(bucket))
@@ -1050,26 +1165,13 @@ class ContinuousBatchingScheduler:
                         reg.counter(
                             "serving_state_recomputed_tokens_total"
                         ).inc(redone)
-            t0 = time.perf_counter()
             _, row = self._prefill(self.params, ids, row_in)
             row_start = 0
         if reg:
-            with span("serving.prefill.block",
-                      request_id=req.request_id):
-                jax.block_until_ready(row.ks[0])
-            if t0 is not None:
-                ms = (time.perf_counter() - t0) * 1e3
-                reg.histogram("serving_prefill_ms").observe(ms)
-                _observe_prefill(bucket, ms)
-                self._charge_device("prefill", ms * 1e3, (req,))
             reg.counter("serving_prefix_cache_hit_tokens_total").inc(c)
             reg.counter("serving_prefix_cache_miss_tokens_total").inc(
                 s - c)
-        slot = self.slots.insert_prefill(
-            row, tokens, s, key, shared, row_start=row_start,
-            offset=(s // self._block * self._block
-                    if self._block > 1 else None))
-        return slot, bucket, tokens, mode, c
+        return row, tokens, s, key, shared, row_start, bucket, mode
 
     # -- generation by blocks (module docstring) -------------------------
 
@@ -1451,6 +1553,11 @@ class ContinuousBatchingScheduler:
         elif rows:
             self._flight = self._dispatch(rows, spec, t0,
                                           prior is not None)
+        if rows and self._prefills:
+            # program order puts them in front of this dispatch: its
+            # read is theirs, and counts from the first one's enqueue
+            self._flight.prefills, self._prefills = self._prefills, []
+            self._flight.t0 = self._prefill_t0
         if prior is not None:
             retired += self._read(prior)
         if self.config.spec_k and self._flight is not None:
@@ -1465,12 +1572,18 @@ class ContinuousBatchingScheduler:
 
     def _drop_flight(self) -> None:
         """Forget the step in flight, unread (`stop`): its tokens are
-        counted as discarded."""
+        counted as discarded, and the prefills nothing will time any
+        more as unobserved."""
         flight = self._take_flight()
+        lost = len(self._prefills)
+        self._prefills = []
         reg = self._registry()
         if flight is not None and reg:
             reg.counter("serving_decode_discarded_tokens_total").inc(
                 len(flight.rows))
+            lost += len(flight.prefills)
+        if lost and reg:
+            reg.counter("serving_prefill_unobserved_total").inc(lost)
 
     def _count_dispatch(self, inflight: bool) -> None:
         reg = self._registry()
@@ -1547,11 +1660,19 @@ class ContinuousBatchingScheduler:
         # step at a time — to its own tokens on the host.
         elapsed_ms = (landed - max(flight.t0, self._read_at)) * 1e3
         self._read_at = landed
-        if reg:
-            if discarded:
-                reg.counter(
-                    "serving_decode_discarded_tokens_total").inc(
-                        discarded)
+        # A read that carried a prefill measures prefill + step: the
+        # SLO gate and the router price "a token here, now" from the
+        # step metrics, and a prefill inside would read as a straggler.
+        prefill_ms = 0.0
+        if flight.prefills:
+            prefill_ms = self._prefill_reading(flight.prefills,
+                                               elapsed_ms, reg)
+        else:
+            self._step_times.append(elapsed_ms / 1e3)
+        if reg and discarded:
+            reg.counter("serving_decode_discarded_tokens_total").inc(
+                discarded)
+        if reg and not flight.prefills:
             # Normalize the step metric by tokens COMMITTED, not
             # positions scanned: serving_decode_step_ms/us feed the
             # SLO admission baseline and the router's placement
@@ -1600,7 +1721,7 @@ class ContinuousBatchingScheduler:
             # since their previous charge.
             self._charge_device(
                 "spec_verify" if spec else "decode",
-                elapsed_ms * 1e3, [r for _, r in rows])
+                (elapsed_ms - prefill_ms) * 1e3, [r for _, r in rows])
             self._charge_kv_residency([r for _, r in rows], now)
         with span("serving.commit") as sp:
             if spec:
@@ -1620,6 +1741,33 @@ class ContinuousBatchingScheduler:
         if reg:
             reg.counter("serving_tokens_generated_total").inc(generated)
         return retired
+
+    def _prefill_reading(self, prefills, elapsed_ms: float,
+                         reg) -> float:
+        """The read of a dispatch that had ``prefills`` in front of it
+        took ``elapsed_ms``: less the rolling step time, that is what
+        the prefills took — no sync of its own.  Their requests are
+        charged it; where it was ONE prefill, `serving_prefill_ms` and
+        its bucket's baseline (the router's ship-or-recompute price)
+        get the reading; several cannot be told apart and are counted
+        as unobserved, as is one read before any plain step was.
+        Returns the milliseconds that were the prefills'."""
+        if not self._step_times:
+            if reg:
+                reg.counter("serving_prefill_unobserved_total").inc(
+                    len(prefills))
+            return 0.0
+        ms = max(elapsed_ms - self._step_s() * 1e3, 0.0)
+        if reg:
+            self._charge_device("prefill", ms * 1e3,
+                                [req for _, req in prefills])
+            if len(prefills) == 1:
+                reg.histogram("serving_prefill_ms").observe(ms)
+                _observe_prefill(prefills[0][0], ms)
+            else:
+                reg.counter("serving_prefill_unobserved_total").inc(
+                    len(prefills))
+        return ms
 
     def _moe_counters(self):
         """What a sparse model's expert layers counted in the dispatch
