@@ -244,9 +244,13 @@ class TestFaultGrid:
         # (warmed by whatever ran earlier in the suite) z-scores each
         # REAL step duration, so a jittery step can flag in one run
         # and not the other — orthogonal to the fault protocol this
-        # test pins.
+        # test pins.  So are the two counters that hold the DEVICE's
+        # word (a non-blocking `is_ready()`: had the tokens landed, had
+        # the last program finished) — true or not by real time.
         nondet = ("serving_decode_anomalies_total",
-                  'events_total{kind="engine"')
+                  'events_total{kind="engine"',
+                  "serving_read_late_total",
+                  "serving_enqueue_starved_total")
 
         def run(injector):
             get_registry().clear()

@@ -592,8 +592,15 @@ def test_one_step_leaves_the_phase_spans(toy, tracer, layout):
     assert [s.parent for s in fronts] == [admit.id] * 2
     for one, front, req in zip(ones, fronts, reqs):
         assert one.attrs["request_id"] == req.request_id
-        assert front.attrs == {"request_id": req.request_id,
-                               "behind_flight": 0}
+        # the first enqueue of an idle server says so; `starved` is the
+        # device's word (asked, not waited for)
+        idle = {"idle": 1} if req is reqs[0] else {}
+        assert front.attrs == dict(
+            idle, request_id=req.request_id, flight="none",
+            bucket=req.bucket, queue_wait_ms=0.0,
+            starved=front.attrs["starved"])
+        assert front.attrs["starved"] in (0, 1) and (
+            front.attrs["starved"] or not idle)
         assert front.t0 + front.dur <= one.t0
         assert one.attrs["read_flight"] == 0
         assert one.attrs["prompt_len"] == req.prompt_len
@@ -608,7 +615,8 @@ def test_one_step_leaves_the_phase_spans(toy, tracer, layout):
     assert [s.attrs["request_id"] for s in lives] == [
         r.request_id for r in reqs]
     (dispatch,) = spans["serving.dispatch"]
-    assert dispatch.attrs == {"k": 1, "spec": False, "inflight": 0}
+    assert dispatch.attrs == {"k": 1, "spec": False, "inflight": 0,
+                              "starved": dispatch.attrs["starved"]}
     if layout == "paged":
         (pages,) = spans["serving.pages"]
         assert pages.attrs["preempted"] == 0
@@ -628,7 +636,16 @@ def test_one_step_leaves_the_phase_spans(toy, tracer, layout):
     assert order == [p for p in STEP_PHASES[1:-1]
                      if layout == "paged" or p != "serving.pages"]
     (dispatch,) = spans["serving.dispatch"]
-    assert dispatch.attrs == {"k": 1, "spec": False, "inflight": 1}
+    assert dispatch.attrs == {"k": 1, "spec": False, "inflight": 1,
+                              "starved": dispatch.attrs["starved"]}
+    # the read's record: a process's first read closes no interval,
+    # stood behind both prefills and delivered two first tokens
+    (sync,) = spans["serving.sync"]
+    assert sync.attrs == {
+        "rows": 2, "first_tokens": 2, "prefills": 2,
+        "prefill_tokens": sum(r.bucket for r in reqs), "early": 0,
+        "landed": sync.attrs["landed"],
+        "prefill_request_ids": [r.request_id for r in reqs]}
     (commit,) = spans["serving.commit"]
     assert commit.attrs == {"tokens": 2, "retired": 0, "discarded": 0}
     assert all(len(r.generated) == 1 for r in reqs)

@@ -605,7 +605,8 @@ def test_the_counters_and_inflight_say_what_was_overlapped(
              if s.name == "serving.dispatch"]
     assert flags == [0, 1, 1, 1, 1, 0, 1, 1]
     assert all(s.attrs == {"k": 1, "spec": False,
-                           "inflight": s.attrs["inflight"]}
+                           "inflight": s.attrs["inflight"],
+                           "starved": s.attrs["starved"]}
                for s in tracer.finished() if s.name == "serving.dispatch")
 
 
@@ -761,8 +762,10 @@ def test_an_admitting_call_reads_the_step_in_flight_early(
                 if s.name == "serving.admit.prefill"]
     (one,) = [s for s in tracer.finished()
               if s.name == "serving.admit.request"]
-    assert front.attrs == {"request_id": b.request_id,
-                           "behind_flight": int(order == "front_first")}
+    assert front.attrs == {
+        "request_id": b.request_id, "bucket": 8, "queue_wait_ms": 0.0,
+        "flight": "behind" if order == "front_first" else "read",
+        "starved": front.attrs["starved"]}
     assert one.attrs["read_flight"] == 1
     # sync and commit stay the step's own children, between the halves
     syncs = [s for s in tracer.finished() if s.name == "serving.sync"]
@@ -801,6 +804,7 @@ class Modelled:
         self.host, self.device = host, device
         self.now = self.free = 0.0
         self.ends, self.ops, self.commits = {}, [], []
+        self.kept = []      # the steps' tokens: their ids stay theirs
 
     def call(self):
         self.ops.append(("call",))
@@ -840,15 +844,22 @@ class Modelled:
 
         def stepping(*args):
             out = step(*args)
+            self.kept.append(out[0])
             self.enqueue("step", id(out[0]))
             return out
 
-        def reading(flight):
+        def reading(flight, **kw):
             self.read(id(flight.toks))
-            return read(flight)
+            return read(flight, **kw)
         sched._prefill, sched.slots._insert = prefilling, inserting
         sched._step, sched._read = stepping, reading
         sched.step_timer = lambda: self.now
+
+    def ready(self, arr) -> bool:
+        """`scheduler._is_ready` on this machine: a step's tokens when
+        that step has ended, anything else (the cache: the output of
+        what was enqueued last) when the queue has run empty."""
+        return self.now >= self.ends.get(id(arr), self.free)
 
     def long_intervals(self):
         """Commit-to-commit intervals over 1.25 x their median."""
@@ -1089,3 +1100,209 @@ def test_stop_with_an_admission_enqueued_counts_its_prefill_unobserved(
     again = Request(prompt=[4, 5, 6, 7], max_new_tokens=4)
     sched.run([again])
     assert again.generated == golden(model, params, [4, 5, 6, 7], 4)
+
+
+# ---------------------------------------------------------------------------
+# 4. every token gap put down to what made it: the read's record, the
+#    starved enqueues, the counters
+# ---------------------------------------------------------------------------
+
+def syncs(tracer):
+    return [s for s in tracer.finished() if s.name == "serving.sync"]
+
+
+def ticking(sched):
+    """A timer that moves one tick a reading (as in
+    `test_decode_step_ms_is_one_steps_time`)."""
+    ticks = iter(range(10_000))
+    sched.step_timer = lambda: float(next(ticks))
+
+
+@pytest.mark.parametrize("scenario", [
+    "plain", "behind_one", "behind_two", "early_between", "early_ahead"])
+def test_a_read_leaves_its_record_on_its_sync_span(
+        toy, tracer, metrics, monkeypatch, scenario):
+    """`interval_ms` to the timer's tick, the rows committed, the
+    prefills in front and their buckets, whether the read was the early
+    one of an admitting call and whether the tokens had landed."""
+    from triton_distributed_tpu.serving import scheduler as module
+    model, params = toy
+    sched = make_sched(model, params, num_slots=4)
+    landed = []
+    monkeypatch.setattr(module, "_is_ready",
+                        lambda arr: bool(landed and landed[-1]))
+    a = Request(prompt=[1, 2, 3], max_new_tokens=30)
+    sched.submit(a)
+    for _ in range(3):
+        sched.step()
+    ticking(sched)
+    sched.step()                 # a plain call: its read ends at tick 1
+    tracer.clear()
+    new = [Request(prompt=p, max_new_tokens=5)
+           for p in ([4, 5, 6, 7], [8, 9, 10, 11, 12, 13, 14, 15, 16])]
+    if scenario == "plain":
+        landed.append(True)
+        sched.step()             # ticks 2 (start), 3 (landed)
+        (rec,) = syncs(tracer)
+        assert rec.attrs == {
+            "rows": 1, "first_tokens": 0, "prefills": 0,
+            "prefill_tokens": 0, "early": 0, "landed": 1,
+            "interval_ms": 2e3}
+        return
+    if scenario in ("behind_one", "behind_two"):
+        n = 1 if scenario == "behind_one" else 2
+        pin_order(sched, "front_first")
+        for r in new[:n]:
+            sched.submit(r)
+        sched.step()             # admits: its early read is plain
+        (early,) = syncs(tracer)
+        assert early.attrs["early"] == 1 and early.attrs["prefills"] == 0
+        tracer.clear()
+        landed.append(False)
+        sched.step()             # the read behind the prefill(s)
+        (rec,) = syncs(tracer)
+        assert rec.attrs["prefill_request_ids"] == [
+            r.request_id for r in new[:n]]
+        assert {k: rec.attrs[k] for k in (
+            "rows", "first_tokens", "prefills", "prefill_tokens",
+            "early", "landed")} == {
+            "rows": 1 + n, "first_tokens": n, "prefills": n,
+            "prefill_tokens": 8 * n + 8 * (n - 1), "early": 0,
+            "landed": 0}
+        # since the early read's landing: the admitting call's own
+        # start, this call's start and landing — and the two readings
+        # of a second admission's first half, made after that read
+        assert rec.attrs["interval_ms"] == (3 + 2 * (n - 1)) * 1e3
+        return
+    order = ("front_first" if scenario == "early_between"
+             else "read_first")
+    pin_order(sched, order)
+    sched.submit(new[0])
+    landed.append(scenario == "early_between")
+    sched.step()
+    (rec,) = syncs(tracer)
+    (front,) = [s for s in tracer.finished()
+                if s.name == "serving.admit.prefill"]
+    assert (rec.t0 > front.t0) == (scenario == "early_between")
+    # between the halves the first half's two readings of the timer
+    # lie in front of the landing; ahead of them nothing does
+    assert rec.attrs == {
+        "rows": 1, "first_tokens": 0, "prefills": 0,
+        "prefill_tokens": 0, "early": 1,
+        "landed": int(scenario == "early_between"),
+        "interval_ms": 3e3 if scenario == "early_between" else 1e3}
+    assert front.attrs["flight"] == (
+        "behind" if scenario == "early_between" else "read")
+
+
+@pytest.mark.parametrize("front_ms,flight", [(3.0, "behind"),
+                                             (10.0, "read")])
+def test_starved_is_1_exactly_where_the_device_had_finished(
+        toy, tracer, metrics, monkeypatch, front_ms, flight):
+    """On the modelled machine (four chips' shape): every enqueue of a
+    prefill or a step says `starved` exactly where the device's queue
+    had run empty at that moment — with the first half read first that
+    is every admission's prefill, behind the flight none — the first
+    enqueue of the idle server says `idle`, and the counters agree
+    with the spans."""
+    from triton_distributed_tpu.serving import scheduler as module
+    model, params = toy
+    host = dict(TP4[0], prefill=front_ms)
+    machine = Modelled(host, TP4[1])
+    sched = make_sched(model, params, num_slots=4)
+    machine.drive(sched)
+    monkeypatch.setattr(module, "_is_ready", machine.ready)
+    truth = []
+    enqueue = machine.enqueue
+
+    def enqueuing(kind, key=None):
+        if kind != "insert":
+            truth.append((kind, int(machine.now >= machine.free)))
+        enqueue(kind, key)
+    machine.enqueue = enqueuing
+    admissions_under_load(sched, machine)
+    sched.drain()
+    said = [("prefill" if s.name == "serving.admit.prefill" else "step",
+             s.attrs["starved"], s.attrs.get("idle", 0))
+            for s in tracer.finished()
+            if s.name in ("serving.admit.prefill", "serving.dispatch")]
+    assert [(p, st) for p, st, _ in said] == truth
+    # the server was idle once: before its first request
+    assert [i for _, _, i in said] == [1] + [0] * (len(said) - 1)
+    hungry = [p for p, st, idle in said if st and not idle]
+    # where the first half is read first the chip idles for every such
+    # prefill; behind the flight it never does
+    late = sum(p == "prefill" for p in hungry)
+    assert late >= 4 if flight == "read" else late == 0
+    counters = metrics.snapshot()["counters"]
+
+    def total(name):
+        return {k.split('"')[1]: v for k, v in counters.items()
+                if k.startswith(name + "{")}
+    assert total("serving_enqueues_total") == {
+        p: sum(q == p for q, _, _ in said) for p in ("prefill", "step")}
+    assert sum(total("serving_enqueue_starved_total").values()) == len(
+        hungry)
+    # the tokens of an early read that stood behind a first half too
+    # long for the step had landed; those read first had not
+    early = [s.attrs["landed"] for s in syncs(tracer)
+             if s.attrs["early"]]
+    assert len(early) == 5
+    assert counter(metrics, "serving_read_late_total") == sum(
+        s.attrs["landed"] for s in syncs(tracer))
+
+
+@pytest.mark.parametrize("layout", ["paged", "slots"])
+def test_the_read_counters_add_up(toy, tracer, metrics, layout):
+    """`serving_reads_total` over its labels is the `serving.sync`
+    spans; `serving_read_rows_total` is the tokens generated (one token
+    a row a read)."""
+    model, params = toy
+    sched = make_sched(model, params, layout)
+    reqs = [Request(prompt=p, max_new_tokens=3 + i % 5, seed=i,
+                    arrival_time=0.01 * (i // 2))
+            for i, p in enumerate(rand_prompts(9, seed=5))]
+    sched.run(reqs)
+    recs = syncs(tracer)
+    assert counter(metrics, "serving_reads_total") == len(recs)
+    assert counter(metrics, "serving_read_rows_total") == counter(
+        metrics, "serving_tokens_generated_total") == sum(
+        len(r.generated) for r in reqs)
+    by = {k: v for k, v in metrics.snapshot()["counters"].items()
+          if k.startswith("serving_reads_total")}
+    for label, want in (("0", lambda n: n == 0), ("1", lambda n: n == 1),
+                        ("2+", lambda n: n >= 2)):
+        assert by.get('serving_reads_total{prefills="%s"}' % label,
+                      0) == sum(want(s.attrs["prefills"]) for s in recs)
+    assert sum(s.attrs["prefills"] for s in recs) == len(reqs)
+    assert sum(s.attrs["first_tokens"] for s in recs) == len(reqs)
+    # every read but the process's first closes an interval
+    assert ["interval_ms" in s.attrs for s in recs] == [False] + [
+        True] * (len(recs) - 1)
+
+
+def test_with_observability_off_nothing_is_asked_and_streams_are_the_same(
+        toy, tracer, metrics, monkeypatch):
+    from triton_distributed_tpu.serving import scheduler as module
+    model, params = toy
+    asked = []
+    monkeypatch.setattr(module, "_is_ready",
+                        lambda arr: asked.append(arr) or True)
+
+    def serve():
+        sched = make_sched(model, params, temperature=0.8)
+        done = sched.run([
+            Request(prompt=p, max_new_tokens=2 + i % 6, seed=70 + i,
+                    arrival_time=0.01 * (i // 2))
+            for i, p in enumerate(rand_prompts(7, seed=21))])
+        return [r.generated for r in
+                sorted(done, key=lambda r: r.request_id)]
+
+    on = serve()
+    assert asked and syncs(tracer)
+    del asked[:]
+    tracer.clear()
+    monkeypatch.setenv("TDT_OBSERVABILITY", "0")
+    off = serve()
+    assert asked == [] and tracer.finished() == []
+    assert off == on

@@ -729,6 +729,62 @@ def test_monotonic_offset_places_spans_on_the_harness_clock():
     assert start + sp.dur <= after + 1e-3
 
 
+def test_a_recorded_span_is_over_and_under_what_was_open():
+    """`SpanTracer.record`: a span the caller timed itself — in the
+    ring with its own start and duration, caused by the span open on
+    the thread, never opened, never shown to the profiler."""
+    tr = SpanTracer(capacity=8)
+    t0 = time.perf_counter()
+    tr.record("loose", t0, 0.25, n=1)
+    with tr.span("pages") as pages:
+        tr.record("runtime.gc", t0 + 1.0, 0.5, generation=2)
+        assert [s.name for s in tr.open_spans()] == ["pages"]
+    loose, gc_, _ = tr.finished()
+    assert (loose.parent, loose.dur, loose.attrs) == (None, 0.25, {"n": 1})
+    assert gc_.parent == pages.id and gc_.depth == 1
+    assert (gc_.t0, gc_.dur) == (t0 + 1.0, 0.5)
+    assert gc_.chrome_event(rank=0)["dur"] == 0.5e6
+    assert tr.open_spans() == []
+
+
+@pytest.mark.parametrize("long", [True, False])
+def test_a_long_collection_is_a_runtime_gc_span(monkeypatch, long):
+    """One hook a process: a collection over the threshold leaves a
+    `runtime.gc` span under whatever was open and an observation of
+    `runtime_gc_pause_ms`; a short one leaves nothing."""
+    import gc
+
+    from triton_distributed_tpu.observability import (
+        get_registry, get_tracer, tracing)
+    tracing.install_gc_hook()
+    hooks = len(gc.callbacks)
+    tracing.install_gc_hook()
+    assert len(gc.callbacks) == hooks
+    monkeypatch.setattr(tracing, "GC_PAUSE_MIN_S",
+                        0.0 if long else 3600.0)
+    tr = get_tracer()
+    tr.clear()
+    get_registry().clear()
+    with tr.span("serving.pages") as pages:
+        gc.collect()
+    found = [s for s in tr.finished() if s.name == "runtime.gc"]
+    hist = get_registry().snapshot()["histograms"].get(
+        "runtime_gc_pause_ms")
+    if not long:
+        assert found == [] and hist is None
+        return
+    (pause,) = found
+    assert pause.parent == pages.id
+    assert pause.attrs["generation"] == 2 and "collected" in pause.attrs
+    assert pages.t0 <= pause.t0 and pause.dur <= pages.dur
+    assert hist["count"] == 1
+    assert hist["sum"] == pytest.approx(pause.dur * 1e3)
+    monkeypatch.setenv("TDT_OBSERVABILITY", "0")
+    tr.clear()
+    gc.collect()
+    assert tr.finished() == []
+
+
 def test_ring_counts_what_it_drops():
     from triton_distributed_tpu.observability import get_registry
     c = get_registry().counter("trace_dropped_spans_total")

@@ -53,6 +53,7 @@ from __future__ import annotations
 
 import atexit
 import functools
+import gc
 import itertools
 import json
 import os
@@ -208,6 +209,21 @@ class SpanTracer:
             return NULL_SPAN
         return Span(self, name, attrs, detached=True)
 
+    def record(self, name: str, t0: float, dur: float, **attrs) -> None:
+        """A span that is over: the caller measured its start (on
+        ``time.perf_counter``) and its duration itself, because most
+        of its kind are not worth a span (`install_gc_hook`).  Its
+        parent is the span open on this thread now; the profiler never
+        saw it."""
+        s = Span(self, name, attrs)
+        s.tid = threading.get_ident()
+        s.t0, s.ts, s.dur = t0, _CLOCK_BASE + t0, dur
+        with self._lock:
+            stack = self._open.get(s.tid)
+            if stack:
+                s.parent, s.depth = stack[-1].id, len(stack)
+            self._finish(s)
+
     @property
     def monotonic_offset(self) -> float:
         """:data:`MONOTONIC_OFFSET`, for a reader that holds the
@@ -242,13 +258,17 @@ class SpanTracer:
                     stack.remove(s)
                 if not stack:
                     self._open.pop(s.tid, None)
-            if len(self._ring) == self._ring.maxlen:
-                self.dropped += 1
-                # Overflow must not be silent: a timeline merged from
-                # this ring is missing the evicted span, and a doctor
-                # report built on it should say so.
-                get_registry().counter("trace_dropped_spans_total").inc()
-            self._ring.append(s)
+            self._finish(s)
+
+    def _finish(self, s: Span) -> None:
+        """Into the ring (under the lock)."""
+        if len(self._ring) == self._ring.maxlen:
+            self.dropped += 1
+            # Overflow must not be silent: a timeline merged from
+            # this ring is missing the evicted span, and a doctor
+            # report built on it should say so.
+            get_registry().counter("trace_dropped_spans_total").inc()
+        self._ring.append(s)
 
     # -- inspection ------------------------------------------------------
 
@@ -376,6 +396,43 @@ def traced(fn=None, *, name: Optional[str] = None):
             return fn(*args, **kwargs)
 
     return wrapper
+
+
+# -- pauses of Python's cyclic collector -----------------------------------
+
+#: A collection shorter than this leaves nothing behind (there are
+#: thousands; a pause that shows in a step is a hundred times longer).
+GC_PAUSE_MIN_S = 1e-3
+
+_GC_HOOKED = False
+
+
+def install_gc_hook() -> None:
+    """One `gc.callbacks` hook for the process (idempotent): a
+    collection that took over `GC_PAUSE_MIN_S` is a `runtime.gc` span
+    (``generation``, ``collected``) in the global tracer, under
+    whatever span was open when it struck, and an observation of
+    `runtime_gc_pause_ms` — so a long step's "self" names its cause.
+    A short one costs two clock reads."""
+    global _GC_HOOKED
+    if _GC_HOOKED:
+        return
+    _GC_HOOKED = True
+    began = [0.0]
+
+    def on_gc(phase, info):
+        if phase == "start":
+            began[0] = time.perf_counter()
+            return
+        dur = time.perf_counter() - began[0]
+        if dur < GC_PAUSE_MIN_S or not observability_enabled():
+            return
+        get_tracer().record("runtime.gc", began[0], dur,
+                            generation=info.get("generation"),
+                            collected=info.get("collected"))
+        get_registry().histogram("runtime_gc_pause_ms").observe(dur * 1e3)
+
+    gc.callbacks.append(on_gc)
 
 
 # -- step tracking (heartbeat / timeline context) -------------------------

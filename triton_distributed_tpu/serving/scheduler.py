@@ -88,6 +88,16 @@ took is read from the one sync there is: the first read after it
 measures prefill + step, and that reading less the rolling step time
 is the prefill's (`_prefill_reading`); it is kept out of the step
 metrics.
+
+Every token gap is put down to what made it.  Each read leaves its
+record on its `serving.sync` span — the interval since the read before
+it (the gap every committed row sees), the rows committed, the
+prefills that stood in front, whether it was the early read of an
+admitting call and whether the tokens had already landed when the host
+came for them — and each enqueue of a prefill or a step asks, without
+blocking, whether the program enqueued last has finished: if so the
+chip is standing idle for this enqueue (`starved`).  Both are asked
+only where spans are recorded.
 Metric and span names: docs/serving.md, docs/observability.md.
 """
 
@@ -106,6 +116,7 @@ import numpy as np
 from triton_distributed_tpu.observability.tracing import (
     NULL_SPAN,
     get_tracer,
+    install_gc_hook,
     span,
 )
 from triton_distributed_tpu.serving.engine_batched import (
@@ -238,6 +249,21 @@ def prefill_baseline_key(bucket: int) -> str:
     shipping the cached pages cost over the measured wire"."""
     from triton_distributed_tpu.observability.anomaly import event_key
     return event_key("serving.prefill", None, (int(bucket),), 1)
+
+
+def _is_ready(arr) -> bool:
+    """Has the device finished ``arr``?  Never blocks."""
+    return arr.is_ready()
+
+
+def _flight_label(behind: bool, read: bool) -> str:
+    """Where an admission's prefill stands to the step that was in
+    flight: enqueued behind it, after its read, or there was none."""
+    return "behind" if behind else "read" if read else "none"
+
+
+def _prefills_label(n: int) -> str:
+    return str(n) if n < 2 else "2+"
 
 
 def _observe_prefill(bucket: int, ms: float) -> None:
@@ -460,6 +486,10 @@ class ContinuousBatchingScheduler:
         #: next dispatch takes them along (`_Flight.prefills`).
         self._prefills: List[tuple] = []
         self._prefill_t0 = 0.0
+        #: The call began with nothing running and nothing in flight:
+        #: its first enqueue is `starved` for want of arrivals, not of
+        #: the host (`_starved` says ``idle=1`` and clears this).
+        self._idle = True
         #: Per-bucket reusable prefill input caches (see _admit).
         self._row_caches: Dict[int, object] = {}
         self._queue: Deque[Request] = collections.deque()
@@ -470,6 +500,7 @@ class ContinuousBatchingScheduler:
         self._steps = 0
         self._stopped = False
         self.finished: List[Request] = []
+        install_gc_hook()
         self._update_gauges()
 
     @property
@@ -616,6 +647,7 @@ class ContinuousBatchingScheduler:
 
     def _step_phases(self) -> dict:
         now = self.clock()
+        self._idle = not self._by_slot and self._flight is None
         admitted, retired = self._admit(now)
         # (rows that an admission's read retired were in the batch)
         active_n = len(self._by_slot) + retired
@@ -910,7 +942,7 @@ class ContinuousBatchingScheduler:
         while ready or began is not None:
             if self._flight is not None and (began is not None
                                              or not self._front_fits()):
-                retired += self._read(self._take_flight())
+                retired += self._read(self._take_flight(), early=True)
                 read = True
             with span("serving.admit", queued=len(self._queue)):
                 if began is not None:
@@ -918,7 +950,8 @@ class ContinuousBatchingScheduler:
                     n, began, read = n + 1, None, False
                     ready = self._head_ready(now)
                 while ready:
-                    front = self._admit_front(self._queue.popleft(), now)
+                    front = self._admit_front(self._queue.popleft(), now,
+                                              read)
                     if front is not None and self._flight is not None:
                         began = front       # its insert: after the read
                         break
@@ -952,20 +985,23 @@ class ContinuousBatchingScheduler:
         """The rolling time of a plain dispatch, in seconds."""
         return statistics.median(self._step_times)
 
-    def _admit_front(self, req: Request, now: float):
+    def _admit_front(self, req: Request, now: float, read: bool):
         """First half of the admission of ``req`` (already off the
         queue): prefix match and the prefill's ENQUEUE — or a
-        shipment's row.  Nothing here waits for the device.  Returns
-        what `_admit_insert` takes, or None when the request was
-        retired at admission."""
+        shipment's row.  Nothing here waits for the device.  ``read``:
+        this call has read the step in flight already.  Returns what
+        `_admit_insert` takes, or None when the request was retired at
+        admission."""
         reg = self._registry()
         had_ship = req.shipped_kv is not None
         behind = self._flight is not None
         t0 = self.step_timer()
         with span("serving.admit.prefill", request_id=req.request_id,
-                  behind_flight=int(behind)):
+                  flight=_flight_label(behind, read),
+                  queue_wait_ms=max(now - req.t_arrival, 0.0) * 1e3
+                  ) as sp:
             if self.paged:
-                row = self._prefill_paged(req, now, reg)
+                row = self._prefill_paged(req, now, reg, sp)
                 if row is None:
                     return None           # retired at admission
             elif had_ship:
@@ -975,9 +1011,12 @@ class ContinuousBatchingScheduler:
                 assert bucket is not None  # submit() validated
                 ids, s = pad_prompt(req.prompt, bucket,
                                     self.config.pad_id)
+                self._starved("prefill", sp)
                 _, row_cache = self._prefill(self.params, ids,
                                              self._row_cache(bucket))
                 row = (row_cache, s, bucket, "local")
+            if sp is not NULL_SPAN:
+                sp.attrs["bucket"] = row[-2]
         # A consumed shipment (`_shipped_row` clears the hook) ran NO
         # local prefill — it has its own serving_shipped_inserts_total,
         # and counting it would desync serving_prefills_total from the
@@ -1048,10 +1087,8 @@ class ContinuousBatchingScheduler:
                             bucket=str(bucket)).inc()
                 # enqueued and never waited for: behind the step in
                 # flight, after its read, or with nothing in flight
-                reg.counter(
-                    "serving_admit_overlapped_total",
-                    flight=("behind" if behind
-                            else "read" if read else "none")).inc()
+                reg.counter("serving_admit_overlapped_total",
+                            flight=_flight_label(behind, read)).inc()
             reg.histogram("serving_queue_wait_ms").observe(
                 max(now - req.t_arrival, 0.0) * 1e3)
             if (req.resume_tokens is not None or req.preemptions
@@ -1067,10 +1104,12 @@ class ContinuousBatchingScheduler:
                 self._hop(req, "admit", now, slot=slot,
                           bucket=bucket, mode=mode)
 
-    def _prefill_paged(self, req: Request, now: float, reg):
+    def _prefill_paged(self, req: Request, now: float, reg, sp):
         """Paged admission, first half: radix prefix match, then the
         prefill's enqueue — suffix-only on a hit (near-zero-cost
-        shared system prompts).  Returns what the paged insert takes —
+        shared system prompts); ``sp`` is the `serving.admit.prefill`
+        span, told whether the enqueue found the chip idle.  Returns
+        what the paged insert takes —
         (row, tokens, prompt length, key, shared path, row start,
         bucket, mode), mode the lineage admission class (local /
         shipped / suffix) — or None when the request had to be retired
@@ -1101,6 +1140,7 @@ class ContinuousBatchingScheduler:
             if bucket is not None:
                 ids, _ = pad_prompt(tokens[c:], bucket,
                                     self.config.pad_id)
+                self._starved("prefill", sp)
                 row = self._prefill_suffix(self.params, ids,
                                            jnp.int32(c),
                                            self._row_cache(bucket))
@@ -1165,6 +1205,7 @@ class ContinuousBatchingScheduler:
                         reg.counter(
                             "serving_state_recomputed_tokens_total"
                         ).inc(redone)
+            self._starved("prefill", sp)
             _, row = self._prefill(self.params, ids, row_in)
             row_start = 0
         if reg:
@@ -1231,7 +1272,8 @@ class ContinuousBatchingScheduler:
                 req.block_masked -= k
                 denoised[slot] = (req.block_start, k)
         with span("serving.dispatch", k=n, spec=False,
-                  inflight=int(inflight)):
+                  inflight=int(inflight)) as sp:
+            self._starved("step", sp)
             blk, cache = self._step(
                 self.params, self._prev, self.slots.cache,
                 self._blk_host.copy(), self._fresh.copy(), active,
@@ -1585,6 +1627,30 @@ class ContinuousBatchingScheduler:
         if lost and reg:
             reg.counter("serving_prefill_unobserved_total").inc(lost)
 
+    def _starved(self, program: str, sp) -> None:
+        """Just before the enqueue of a prefill or a step, under its
+        span ``sp``: has the program enqueued last — a step, an insert,
+        a slot's reset: the cache as it stands is its output, and is
+        asked before the next program donates it — finished?  Then the
+        chip is standing idle for this enqueue: ``starved=1`` on the
+        span, and counted.  The first enqueue of a call that began
+        with nothing to do is starved for want of arrivals
+        (``idle=1``), which a reader leaves out and the counter does
+        not count.  One non-blocking question; none where nothing
+        records."""
+        if sp is NULL_SPAN:
+            return
+        idle, self._idle = self._idle, False
+        starved = idle or _is_ready(self.slots.cache.offset)
+        sp.attrs["starved"] = int(starved)
+        reg = self._registry()
+        reg.counter("serving_enqueues_total", program=program).inc()
+        if idle:
+            sp.attrs["idle"] = 1
+        elif starved:
+            reg.counter("serving_enqueue_starved_total",
+                        program=program).inc()
+
     def _count_dispatch(self, inflight: bool) -> None:
         reg = self._registry()
         if reg:
@@ -1607,7 +1673,8 @@ class ContinuousBatchingScheduler:
         if spec is not None:
             drafts, n_draft = spec
             with span("serving.dispatch", k=self.config.spec_k,
-                      spec=True, inflight=int(inflight)):
+                      spec=True, inflight=int(inflight)) as sp:
+                self._starved("step", sp)
                 targets, accept, cache, keys = self._spec_fn(
                     self.params, jnp.asarray(self._tokens),
                     jnp.asarray(drafts), self.slots.cache,
@@ -1621,7 +1688,8 @@ class ContinuousBatchingScheduler:
         # there the host's word counts for every row.
         fresh = active if self.config.spec_k else self._fresh.copy()
         with span("serving.dispatch", k=1, spec=False,
-                  inflight=int(inflight)):
+                  inflight=int(inflight)) as sp:
+            self._starved("step", sp)
             tokens = self._merge(self._prev, self._tokens.copy(), fresh)
             toks, cache, keys = self._step(
                 self.params, tokens, self.slots.cache, self.slots.keys,
@@ -1632,13 +1700,17 @@ class ContinuousBatchingScheduler:
         self._fresh[:] = False
         return _Flight(toks, rows, t0, counted=self._moe_counters())
 
-    def _read(self, flight: _Flight) -> int:
+    def _read(self, flight: _Flight, early: bool = False) -> int:
         """Read one dispatch's tokens — THE host sync — and commit
-        them.  Returns the rows retired."""
+        them; ``early``: the read an admitting call makes between or
+        ahead of its admission's halves.  Returns the rows retired."""
         spec = flight.accept is not None
         block = flight.denoised is not None
         accept_host = None
-        with span("serving.sync"):
+        with span("serving.sync") as sync:
+            # landed already: the sync waits for nothing, and the
+            # tokens waited for the host
+            late = sync is not NULL_SPAN and _is_ready(flight.toks)
             toks_host = np.asarray(flight.toks)   # THE host sync
             if spec:
                 accept_host = np.asarray(flight.accept)
@@ -1655,6 +1727,8 @@ class ContinuousBatchingScheduler:
         rows = [(slot, req) for slot, req in flight.rows.items()
                 if self._by_slot.get(slot) is req]
         discarded = len(flight.rows) - len(rows)
+        if sync is not NULL_SPAN:
+            self._read_record(sync, flight, rows, landed, early, late)
         # One step's time: from its dispatch — or from when the step
         # before it landed, if that was later: the device runs one
         # step at a time — to its own tokens on the host.
@@ -1741,6 +1815,36 @@ class ContinuousBatchingScheduler:
         if reg:
             reg.counter("serving_tokens_generated_total").inc(generated)
         return retired
+
+    def _read_record(self, sync, flight: _Flight, rows, landed: float,
+                     early: bool, late: bool) -> None:
+        """What a read knows of the token gap it closes, on its
+        `serving.sync` span and as counters: commit to commit
+        (``interval_ms``; none before a process's first read), the
+        rows committed and how many of them got their request's first
+        token (no gap), the prefills the device ran in front — however
+        many: together they are what the interval holds over a plain
+        one — and whether the tokens had landed before the host asked
+        (``landed``)."""
+        n = len(flight.prefills)
+        sync.attrs.update(
+            rows=len(rows),
+            first_tokens=sum(not req.generated for _, req in rows),
+            prefills=n,
+            prefill_tokens=sum(bucket for bucket, _ in flight.prefills),
+            early=int(early), landed=int(late))
+        if self._read_at > float("-inf"):
+            sync.attrs["interval_ms"] = (landed - self._read_at) * 1e3
+        if 1 <= n <= 4:
+            sync.attrs["prefill_request_ids"] = [
+                req.request_id for _, req in flight.prefills]
+        reg = self._registry()
+        label = _prefills_label(n)
+        reg.counter("serving_reads_total", prefills=label).inc()
+        reg.counter("serving_read_rows_total",
+                    prefills=label).inc(len(rows))
+        if late:
+            reg.counter("serving_read_late_total").inc()
 
     def _prefill_reading(self, prefills, elapsed_ms: float,
                          reg) -> float:
