@@ -94,7 +94,8 @@ def counters() -> dict:
     from triton_distributed_tpu.observability import get_registry
     snap = get_registry().snapshot()["counters"]
     return {k: v for k, v in sorted(snap.items()) if k.split("{")[0] in (
-        "serving_prefills_total", "serving_admit_overlapped_total",
+        "serving_prefills_total", "serving_prefill_chunks_total",
+        "serving_admit_overlapped_total",
         "serving_prefill_unobserved_total", "serving_reads_total",
         "serving_read_rows_total", "serving_read_late_total",
         "serving_enqueues_total", "serving_enqueue_starved_total",

@@ -509,3 +509,122 @@ def test_glm_decode_program_names_its_kernels(system):
                  "moe_decode_down"):
         assert name in text
     assert "moe_prefill" not in text
+
+
+# ---------------------------------------------------------------------------
+# a prompt prefilled in chunks that attend the pages already in the pool
+# ---------------------------------------------------------------------------
+
+def _serve_one_by_one(system, chunk, prompts, monkeypatch):
+    """Each prompt through a scheduler of its own pool, one after the
+    other (a later one may hit an earlier one's pages): the tokens
+    served, the chunk starts enqueued, and every full prompt page the
+    radix tree holds afterwards as (page id, its rows in every layer).
+    """
+    from triton_distributed_tpu.serving import (
+        ContinuousBatchingScheduler, SchedulerConfig)
+    monkeypatch.setattr(system.model, "prefill_chunk", chunk)
+    sched = ContinuousBatchingScheduler(
+        system.model, system.params,
+        SchedulerConfig(num_slots=2, max_seq=128, kv_layout="paged",
+                        num_pages=40, prefill_buckets=(16, 32, 64, 128)))
+    starts = []
+    suffix = sched._prefill_suffix
+    sched._prefill_suffix = lambda p, ids, start, *a: (
+        starts.append((int(start), ids.shape[1]))
+        or suffix(p, ids, start, *a))
+    served, pages = [], []
+    for p in prompts:
+        req = Request(p, 6, eos_token_ids=(), seed=0)
+        sched.run([req])
+        served.append(req.generated)
+        held = sched.slots.match_prefix(p)
+        assert len(held) == (len(p) - 1) // 16
+        pages.append([(n.page, [np.asarray(
+            k[n.page].astype(jnp.float32)) for k in sched.slots.cache.ks])
+            for n in held])
+    return served, starts, pages
+
+
+def test_chunked_prefill_leaves_the_whole_prefills_rows_and_tokens(
+        system, monkeypatch):
+    """2, 3 and 4 chunks of 32 (the last right-padded), and one whose
+    first chunk starts at 16 — a radix hit on the page an earlier
+    prompt left, not a multiple of the chunk: the same latent rows in
+    the same pages and the same greedy tokens as the whole prefill
+    through its bucket."""
+    rng = np.random.default_rng(21)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (100, 70, 40)]
+    prompts.append(prompts[1][:16] + rng.integers(0, 256, 60).tolist())
+    whole, none, want = _serve_one_by_one(system, 0, prompts, monkeypatch)
+    # (the unchunked scheduler prefills a hit's suffix through the
+    # same program, in one piece)
+    assert none == [(16, 64)]
+    got, starts, pages = _serve_one_by_one(system, 32, prompts,
+                                           monkeypatch)
+    assert starts == [(at, 32) for at in
+                      (0, 32, 64, 96, 0, 32, 64, 0, 32, 16, 48)]
+    assert got == whole
+    for held, held_whole in zip(pages, want):
+        assert [p for p, _ in held] == [p for p, _ in held_whole]
+        for (_, rows), (_, rows_whole) in zip(held, held_whole):
+            # (the first layer's rows bit for bit; behind an attention
+            # the sum over keys runs in another order — a buffer past
+            # the bucket's length — and a few numbers round to the
+            # neighbouring bfloat16)
+            np.testing.assert_array_equal(rows[0], rows_whole[0])
+            for a, b in zip(rows[1:], rows_whole[1:]):
+                np.testing.assert_allclose(a, b, rtol=2 ** -6, atol=2 ** -7)
+                assert (a == b).mean() > 0.9
+
+
+def test_a_chunk_reads_its_prefix_through_the_page_ids(system):
+    """The chunk program against the whole prefill, by hand: rows at
+    scattered pages, named in logical order; another page in the ids
+    is seen (the rows of layers past the first differ)."""
+    model, params = system.model, system.params
+    rng = np.random.default_rng(22)
+    prompt = rng.integers(0, 256, 64).tolist()
+    ids, _ = pad_prompt(prompt, 64)
+    _, whole = jax.jit(model.make_prefill_fn())(
+        params, ids, model.create_cache(1, 64))
+    cache = model.create_paged_cache(2, 12, 16, 8)
+    order = np.asarray([7, 2, 9, 4, 0, 0, 0, 0], np.int32)
+    pool = [k.at[order[:2], 0].set(w[0, 0, :32].reshape(2, 16, -1))
+            for k, w in zip(cache.ks, whole.ks)]
+    suffix = jax.jit(model.make_prefill_suffix_fn())
+    tail, _ = pad_prompt(prompt[32:], 32)
+
+    def rows(page_ids):
+        out = suffix(params, tail, jnp.int32(32), model.create_cache(1, 32),
+                     (pool, None), jnp.asarray(page_ids))
+        return [np.asarray(k[0, 0].astype(jnp.float32)) for k in out.ks]
+    for got, w in zip(rows(order), whole.ks):
+        np.testing.assert_array_equal(
+            got, np.asarray(w[0, 0, 32:].astype(jnp.float32)))
+    other = order.copy()
+    other[0] = 5                    # a page nobody wrote
+    wrong = rows(other)
+    np.testing.assert_array_equal(                # layer 0: no prefix in it
+        wrong[0], np.asarray(whole.ks[0][0, 0, 32:].astype(jnp.float32)))
+    assert np.abs(wrong[2] - np.asarray(
+        whole.ks[2][0, 0, 32:].astype(jnp.float32))).max() > 1e-2
+
+
+def test_glm_chunk_program_keeps_the_prefills_names(system):
+    """The device trace reads a chunk as a prefill: the program's name
+    starts like the whole prefill's, its expert kernels are the
+    prefill phase's, its attention the flash kernel."""
+    model = system.model
+    fn = jax.jit(model.make_prefill_suffix_fn())
+    cache = system.sched.slots.cache
+    args = (system.params, jnp.zeros((1, 32), jnp.int32), jnp.int32(16),
+            model.create_cache(1, 32), (cache.ks, cache.vs),
+            jnp.zeros((cache.page_table.shape[1],), jnp.int32))
+    assert fn.lower(*args).as_text().splitlines()[0].startswith(
+        "module @jit_prefill_shard")
+    text = str(jax.make_jaxpr(fn)(*args))
+    for name in ("moe_prefill_gate_up", "moe_prefill_down",
+                 "flash_attention_fwd"):
+        assert name in text
+    assert "moe_decode" not in text and "mla_decode" not in text

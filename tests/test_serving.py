@@ -471,3 +471,28 @@ def test_observability_disabled_still_serves(toy, monkeypatch):
     sched, _ = make_sched(model, params)
     done = sched.run([Request(prompt=[1, 2, 3], max_new_tokens=2)])
     assert len(done) == 1 and len(done[0].generated) == 2
+
+
+def test_the_slot_layout_never_chunks_and_serves_the_same_streams(toy):
+    """A model that names a chunk length prefills in chunks over the
+    PAGED layout alone (the chunks attend the page pool): the slot
+    layout admits its whole prompt through its bucket, and both serve
+    the streams of the model that never chunks."""
+    _, params = toy
+    kw = dict(vocab_size=61, hidden=16, max_seq_len=64)
+    chunking = ToyModel(ToyConfig(prefill_chunk=8, **kw))
+    plain = ToyModel(ToyConfig(**kw))
+    rng = np.random.default_rng(12)
+    prompts = [list(map(int, rng.integers(1, 61, n)))
+               for n in (30, 7, 19, 8, 25)]
+
+    def serve(model, **cfg):
+        sched, _ = make_sched(model, params, **cfg)
+        reqs = [Request(prompt=p, max_new_tokens=5) for p in prompts]
+        sched.run(reqs)
+        return sched, [r.generated for r in reqs]
+    want = serve(plain)[1]
+    slotted, got = serve(chunking)
+    assert slotted._chunk == 0 and got == want
+    paged, got = serve(chunking, kv_layout="paged", page_size=8)
+    assert paged._chunk == 8 and got == want

@@ -785,3 +785,322 @@ def test_kv_counters_count_an_eviction_and_a_flush(toy, tracer):
     assert max(s.attrs["live_pages"] for s in pages) == 4
     assert sum(s.attrs["flushed_rows"] for s in pages) > 0
     assert reg.snapshot()["gauges"]["serving_kv_pages_live"] == 0
+
+
+# ---------------------------------------------------------------------------
+# a long prompt is prefilled in chunks, at most one a decode step
+# ---------------------------------------------------------------------------
+
+CHUNK = 16
+
+
+@pytest.fixture(scope="module")
+def toys():
+    """(chunking model, the same model never chunking, params)."""
+    kw = dict(vocab_size=61, hidden=16, max_seq_len=128)
+    model = ToyModel(ToyConfig(prefill_chunk=CHUNK, **kw))
+    return (model, ToyModel(ToyConfig(**kw)),
+            model.init_params(jax.random.key(0)))
+
+
+def chunk_sched(model, params, **kw):
+    kw.setdefault("num_slots", 4)
+    kw.setdefault("page_size", 8)
+    kw.setdefault("prefill_buckets", (8, 16, 32, 64))
+    return make_sched(model, params, "paged", **kw)[0]
+
+
+def long_prompts(lengths, seed=0, vocab=61):
+    rng = np.random.default_rng(seed)
+    return [list(map(int, rng.integers(1, vocab, n))) for n in lengths]
+
+
+@pytest.fixture
+def metrics():
+    from triton_distributed_tpu.observability import get_registry
+    reg = get_registry()
+    reg.clear()
+    yield reg
+    reg.clear()
+
+
+class Watch:
+    """Every enqueue of the scheduler's programs, in order: `log` holds
+    ("chunk", request's first token, start) for the chunk program,
+    ("prefill",) for a whole prefill and ("step", rows) for a decode
+    dispatch.  At every chunk the rows below its start are READ BACK
+    through the page ids it was given and held against the toy's own
+    K for those tokens: chunk k finds what its predecessors (and a
+    prefix hit) left in the pool."""
+
+    def __init__(self, sched, params):
+        self.log, self.sched = [], sched
+        suffix, prefill, step = (sched._prefill_suffix, sched._prefill,
+                                 sched._step)
+        wk, pe, embed = (np.asarray(params[k])
+                         for k in ("wk", "pe", "embed"))
+
+        def chunk(p, ids, start, row, pools, pages):
+            adm = sched._underway or self.planned
+            start = int(start)
+            assert ids.shape == (1, adm.pieces[adm.done][1])
+            if start:
+                ps = sched.slots.page_size
+                pool = np.asarray(pools[0][0])
+                toks = np.asarray(adm.tokens[:start])
+                want = (embed[toks] + pe[:start]) @ wk
+                got = np.stack([pool[pages[i // ps], 0, i % ps]
+                                for i in range(start)])
+                np.testing.assert_allclose(got, want, rtol=1e-5,
+                                           atol=1e-6)
+            self.log.append(("chunk", adm.tokens[0], start))
+            return suffix(p, ids, start, row, pools, pages)
+
+        def whole(*a):
+            self.log.append(("prefill",))
+            return prefill(*a)
+
+        def stepping(p, tokens, cache, keys, active):
+            self.log.append(("step", int(np.sum(active))))
+            return step(p, tokens, cache, keys, active)
+
+        plan = sched._plan
+
+        def planning(*a):
+            self.planned = plan(*a)
+            return self.planned
+        sched._plan = planning
+        sched._prefill_suffix, sched._prefill = chunk, whole
+        sched._step = stepping
+
+    def between_steps(self):
+        """Prefill enqueues between consecutive decode dispatches that
+        had a row running."""
+        out, n = [], 0
+        for ev in self.log:
+            if ev[0] == "step":
+                out.append(n)
+                n = 0
+            else:
+                n += 1
+        return out
+
+
+def test_chunked_prefill_serves_the_unchunked_streams(toys):
+    """2, 3 and 4 chunks (a last one padded), one exactly a chunk long
+    (a whole prefill, as ever) and short ones, admitted beside one
+    another: token for token what the never-chunking scheduler
+    serves, and every chunk found its predecessors' rows."""
+    model, plain, params = toys
+    prompts = long_prompts([50, 40, 17, 16, 5, 64, 33], seed=1)
+    mk = lambda: [Request(prompt=p, max_new_tokens=6 + i, seed=i)  # noqa: E731
+                  for i, p in enumerate(prompts)]
+    want = chunk_sched(plain, params).run(mk())
+    sched = chunk_sched(model, params)
+    watch = Watch(sched, params)
+    got = sched.run(mk())
+    by_id = lambda rs: [r.generated for r in  # noqa: E731
+                        sorted(rs, key=lambda r: r.prompt)]
+    assert by_id(got) == by_id(want)
+    chunks = [ev for ev in watch.log if ev[0] == "chunk"]
+    starts = {}
+    for _, first, start in chunks:
+        starts.setdefault(first, []).append(start)
+    assert sorted(starts.values()) == sorted(
+        list(range(0, n, CHUNK)) for n in (50, 40, 17, 64, 33))
+    # 16 and 5 tokens: today's whole prefill
+    assert sum(ev == ("prefill",) for ev in watch.log) == 2
+    assert sched.slots.used_pages == sched.slots.cached_prefix_pages
+    assert sched.slots.free_slots == 4 and not sched.has_work()
+
+
+def test_never_two_chunks_between_two_decode_dispatches(toys, metrics):
+    """Three long admissions queued behind a running row: one enqueue
+    — a chunk, or a short prompt's whole prefill — a decode dispatch,
+    first come first, and the running row gets its token every call
+    while its neighbours are in mid-prefill."""
+    model, _, params = toys
+    sched = chunk_sched(model, params)
+    runner = Request(prompt=[3, 1, 4], max_new_tokens=40)
+    sched.submit(runner)
+    sched.step()
+    sched.step()
+    watch = Watch(sched, params)
+    longs = [Request(prompt=p, max_new_tokens=3)
+             for p in long_prompts([60, 9, 41, 50], seed=2)]
+    for r in longs:
+        sched.submit(r)
+    mid = 0
+    while any(r.finish_reason is None for r in longs):
+        before = len(runner.generated)
+        sched.step()
+        assert len(runner.generated) == before + 1
+        mid += sched._underway is not None
+    assert mid >= 6             # 4 + 3 + 4 chunks: calls in mid-prefill
+    assert max(watch.between_steps()) == 1
+    order = [ev for ev in watch.log if ev[0] != "step"]
+    firsts = [r.prompt[0] for r in longs]
+    assert order == (
+        [("chunk", firsts[0], at) for at in (0, 16, 32, 48)]
+        + [("prefill",)]
+        + [("chunk", firsts[2], at) for at in (0, 16, 32)]
+        + [("chunk", firsts[3], at) for at in (0, 16, 32, 48)])
+    # a slot in mid-prefill is masked: the dispatches between a
+    # request's first and last chunk ran the rows before it alone
+    steps = [ev[1] for ev in watch.log if ev[0] == "step"]
+    assert steps[:4] == [1, 1, 1, 2]
+    # every enqueue a prefill, the multi-piece ones chunks; each timed
+    # by its own read or counted unobserved
+    snap = metrics.snapshot()
+    count = lambda name: sum(  # noqa: E731
+        v for k, v in snap["counters"].items()
+        if k.split("{")[0] == name)
+    assert count("serving_prefill_chunks_total") == 11
+    assert count("serving_prefills_total") == 13     # + runner, + short
+    assert (snap["histograms"]["serving_prefill_ms"]["count"]
+            + count("serving_prefill_unobserved_total")) == 13
+    assert snap["counters"]['serving_prefills_total{bucket="16"}'] == 12
+
+
+def test_with_nothing_running_the_chunks_go_in_one_call(toys):
+    """No row waits for a token: the whole prompt is enqueued at once,
+    and the request runs from that call's dispatch on."""
+    model, _, params = toys
+    sched = chunk_sched(model, params)
+    watch = Watch(sched, params)
+    req = Request(prompt=long_prompts([50], seed=3)[0], max_new_tokens=2)
+    sched.submit(req)
+    assert sched.step()["admitted"] == 1
+    assert [ev[0] for ev in watch.log] == ["chunk"] * 4 + ["step"]
+    assert len(sched._flight.prefills) == 4 and req.slot is not None
+
+
+def test_the_radix_tree_learns_a_prompt_after_its_last_chunk(toys):
+    model, _, params = toys
+    sched = chunk_sched(model, params)
+    runner = Request(prompt=[2, 7, 1], max_new_tokens=30)
+    sched.submit(runner)
+    sched.step()
+    prompt = long_prompts([45], seed=4)[0]
+    req = Request(prompt=prompt, max_new_tokens=2)
+    sched.submit(req)
+    seen = []
+    while req.t_admitted is None:
+        sched.step()
+        seen.append((sched._underway is not None,
+                     len(sched.slots.match_prefix(prompt))))
+    # in mid-prefill the tree holds nothing of it; its slot is
+    # claimed, masked, and its row of the page table still NULL
+    assert seen == [(True, 0), (True, 0), (False, 5)]
+    assert (sched.slots._table[req.slot][:6] != NULL_PAGE).all()
+
+
+def test_the_page_table_row_stays_null_until_the_last_chunk(toys):
+    model, _, params = toys
+    sched = chunk_sched(model, params)
+    runner = Request(prompt=[2, 7, 1], max_new_tokens=30)
+    sched.submit(runner)
+    sched.step()
+    req = Request(prompt=long_prompts([45], seed=4)[0], max_new_tokens=2)
+    sched.submit(req)
+    sched.step()
+    slot = sched._underway.slot
+    assert slot is not None and slot != runner.slot
+    assert (sched.slots._table[slot] == NULL_PAGE).all()
+    assert (np.asarray(sched.slots.cache.page_table)[slot]
+            == NULL_PAGE).all()
+    assert int(np.asarray(sched.slots.cache.offset)[slot]) == 0
+    assert sched.slots.free_slots == 2      # claimed all the same
+    sched.drain()
+    assert req.finish_reason == FinishReason.LENGTH
+
+
+def test_a_prefix_hit_starts_the_first_chunk_at_the_matched_length(toys):
+    model, plain, params = toys
+    shared = long_prompts([24], seed=5)[0]
+    tails = long_prompts([40, 30], seed=6)
+    prompts = [shared + t for t in tails]
+
+    def serve(m, watch=False):
+        sched = chunk_sched(m, params)
+        w = Watch(sched, params) if watch else None
+        out = []
+        for p in prompts:           # one after the other: a hit
+            r = Request(prompt=p, max_new_tokens=5)
+            sched.run([r])
+            out.append(r.generated)
+        return out, w
+    want, _ = serve(plain)
+    got, watch = serve(model, watch=True)
+    assert got == want
+    starts = [ev[2] for ev in watch.log if ev[0] == "chunk"]
+    # 64 tokens cold: 0..48; then 54 tokens with 24 matched (3 pages)
+    assert starts == [0, 16, 32, 48, 24, 40]
+
+
+@pytest.mark.parametrize("how", ["stop", "pool_dry"])
+def test_giving_up_in_mid_prefill_returns_every_page_and_the_slot(
+        toys, how):
+    model, plain, params = toys
+    kw = dict(prefix_cache=False, num_pages=7 if how == "pool_dry" else 40)
+    sched = chunk_sched(model, params, **kw)
+    runner = Request(prompt=long_prompts([15], seed=8)[0],
+                     max_new_tokens=30)
+    long = Request(prompt=long_prompts([40], seed=7)[0], max_new_tokens=4)
+    sched.submit(runner)
+    sched.step()
+    sched.submit(long)
+    sched.step()
+    assert sched._underway is not None and sched._underway.req is long
+    assert sched.slots.used_pages == 2 + 5 and sched.slots.free_slots == 2
+    if how == "stop":
+        sched.stop()
+        assert sched._underway is None
+        assert sched.slots.used_pages == 0 and sched.slots.free_slots == 4
+        assert long.reject_reason == RejectReason.STOPPED
+        assert runner.finish_reason == FinishReason.STOPPED
+        return
+    # 7 pages, all claimed: the runner's next page meets the long
+    # prompt's claim with two of its three chunks in.  The admission
+    # under way is given up — the newest claim — and starts over once
+    # the pool takes it
+    gave_up = []
+    give_up = sched._give_up_underway
+    sched._give_up_underway = lambda: gave_up.append(
+        sched._underway.done) or give_up()
+    sched.drain()
+    assert long.finish_reason == runner.finish_reason == FinishReason.LENGTH
+    assert sched.slots.used_pages == 0 and sched.slots.free_slots == 4
+    assert gave_up == [2] and long.preemptions == 0
+    want = [Request(prompt=r.prompt, max_new_tokens=r.max_new_tokens)
+            for r in (runner, long)]
+    chunk_sched(plain, params, prefix_cache=False).run(want)
+    assert [r.generated for r in want] == [runner.generated,
+                                           long.generated]
+
+
+def test_a_chunks_scatter_hands_back_the_offset_it_was_given(toys):
+    """`_starved` asks whether the program enqueued last has finished
+    by asking the cache's offset: the scatter of a chunk's rows, which
+    changes no offset, still has to return it — a new handle, the old
+    one donated — or every dispatch behind a chunk would read the chip
+    as idle."""
+    model, _, params = toys
+    slots = PagedKV(model, 2, max_seq=64, page_size=8)
+    prompt = long_prompts([40], seed=9)[0]
+    slot = slots.begin_prefill(len(prompt), [])
+    ids, _ = pad_prompt(prompt[:CHUNK], CHUNK)
+    row = jax.jit(model.make_prefill_suffix_fn())(
+        params, ids, jnp.int32(0), model.create_cache(1, CHUNK),
+        (slots.cache.ks, slots.cache.vs), slots.prefill_pages(slot))
+    before, table = slots.cache.offset, slots.cache.page_table
+    slots.insert_rows(slot, row, 0)
+    assert slots.cache.offset is not before and before.is_deleted()
+    assert slots.cache.page_table is table
+    assert (np.asarray(slots.cache.offset) == 0).all()
+    pages = slots.prefill_pages(slot)[:2]
+    np.testing.assert_array_equal(
+        np.asarray(slots.cache.ks[0][pages, 0]).reshape(CHUNK, -1),
+        np.asarray(row.ks[0][0, 0]))
+    slots.release(slot)
+    assert slots.used_pages == 0 and slots.free_slots == 2

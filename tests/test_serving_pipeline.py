@@ -139,9 +139,10 @@ class ToySystem:
     """What `cellbench.run.warm_up` and `settle` ask of a system, over
     the toy model: the adapters' surface, none of their weights."""
 
-    def __init__(self):
+    def __init__(self, prefill_chunk=0):
         self.model = ToyModel(ToyConfig(vocab_size=61, hidden=16,
-                                        max_seq_len=128))
+                                        max_seq_len=128,
+                                        prefill_chunk=prefill_chunk))
         self.params = self.model.init_params(jax.random.key(0))
         self.config = {"vocab_size": 61}
         self.num_slots, self.max_seq = 8, 128
@@ -257,6 +258,8 @@ def programs(sched):
         out["reset"] = sched.slots._reset
     if sched._prefill_suffix is not None:
         out["prefill_suffix"] = sched._prefill_suffix
+    if sched._chunk:
+        out["rows"] = sched.slots._put_rows
     return out
 
 
@@ -333,6 +336,11 @@ def test_no_program_and_no_kind_of_argument_is_first_met_after_warm_up(
         x, y = send(7, 4), send(20, 4)
         while x.finish_reason is None:
             system.step()
+        if sched._chunk:
+            # a model that prefills in chunks: one enqueue a decode
+            # dispatch, so y was admitted a call after x
+            assert y.finish_reason is None
+            system.step()
         assert y.finish_reason is not None and long.finish_reason is None
         send(6, 2), send(6, 5), send(30, 1)
         run_dry()
@@ -341,6 +349,123 @@ def test_no_program_and_no_kind_of_argument_is_first_met_after_warm_up(
         for i in range(3):
             send(10 + i, 2)
             system.step()
+        run_dry()
+    finally:
+        compiled.watch = False
+    assert compiled.seen == [], json.dumps(compiled.seen, indent=1)
+    assert cache_sizes(sched) == before
+    for handle, new in served:
+        assert handle.finish_reason == FinishReason.LENGTH
+        assert len(handle.generated) == new
+
+
+#: The chunk of the two models that prefill a long prompt in chunks, at
+#: test size: the warm-up's buckets 16 / 32 / 64 are then a whole
+#: prefill, two chunks, and four with the last one padded — as 1024 /
+#: 2048 / 4000 tokens are on `glm-4.7-flash-1c.agent-closed`.
+CHUNK = 16
+
+
+def _chunked_system(family, devices, monkeypatch):
+    if family == "toy":
+        return ToySystem(prefill_chunk=CHUNK)
+    from triton_distributed_tpu.models import glm4_moe_lite
+    monkeypatch.setattr(glm4_moe_lite, "PREFILL_CHUNK", CHUNK)
+    return _glm_system(devices)
+
+
+@pytest.mark.parametrize("family", ["toy", "glm4_moe_lite"])
+def test_no_kind_of_chunk_argument_is_first_met_after_warm_up(
+        family, devices, compiled, monkeypatch):
+    """The benchmark's own `warm_up`, as it stands, on a model that
+    prefills in chunks; then, under the compile listener, a window's
+    chunked admissions: a chunk behind a step in flight, behind an
+    insert (nothing running: the chunks of one prompt in one call),
+    behind a retirement's reset and a page-table flush; two and three
+    long prompts due in one call, beside short ones; every number of
+    chunks, the last one full and padded.  Nothing compiles and no
+    program gains a cache entry."""
+    system = _chunked_system(family, devices, monkeypatch)
+    sched = system.sched
+    assert sched._chunk == CHUNK
+    ps = system.page_size
+    buckets = [b for b in system.buckets if b <= 64]
+    lo, hi = 3, 62
+    chunks = []
+    suffix = sched._prefill_suffix
+
+    class Counting:
+        """The jitted chunk program, its enqueues counted."""
+        _cache_size = suffix._cache_size
+
+        def __call__(self, p, ids, start, *a):
+            chunks.append((int(start), ids.shape[1]))
+            return suffix(p, ids, start, *a)
+    sched._prefill_suffix = Counting()
+    warmed = cellrun.warm_up(system, Plan((lo, hi), 3 * ps), seed=5)
+    assert warmed["buckets"] == buckets and not system.has_work()
+    # the warm-up met: a first chunk, middle ones, a padded last one
+    assert {at for at, _ in chunks} == {0, 16, 32, 48}
+    assert {n for _, n in chunks} == {CHUNK} and len(chunks) == 2 * (2 + 4)
+    before = cache_sizes(sched)
+    assert before["prefill_suffix"] >= 1
+    rng = np.random.default_rng(23)
+    vocab = system.config["vocab_size"]
+    served = []
+
+    def send(plen, new):
+        handle, why = system.submit(
+            rng.integers(0, vocab, plen).tolist(), new, 0.0, None)
+        assert handle is not None, why
+        served.append((handle, new))
+        return handle
+
+    def run_dry():
+        while system.has_work():
+            system.step()
+        assert sched._flight is None and sched._underway is None
+
+    compiled.watch, compiled.seen = True, []
+    del chunks[:]
+    try:
+        # into an idle server: every chunk of the prompt in one call,
+        # each behind the insert of the one before
+        for plen in (62, 33, 48, 17):
+            send(plen, ps + 2)
+            assert system.step()["admitted"] == 1
+            run_dry()
+        assert len(chunks) == 4 + 3 + 3 + 2
+        # behind a step in flight, a chunk a call, a row running all
+        # the while; a short prompt's whole prefill takes its turn
+        runner = send(9, 6 * ps)
+        system.step()
+        system.step()
+        a, b, c = send(60, 3), send(12, 2), send(40, 3)
+        mid = 0
+        while c.finish_reason is None:
+            assert sched._flight is not None
+            system.step()
+            mid += sched._underway is not None
+        assert mid >= 5 and runner.finish_reason is None
+        assert a.finish_reason == b.finish_reason == FinishReason.LENGTH
+        # a chunk in the call whose early read retired a row (a slot's
+        # reset in front of it), then the next prompt into that slot
+        d = send(35, 2)
+        while d.t_admitted is None:
+            system.step()
+        e = send(50, 2)
+        while e.finish_reason is None:
+            system.step()
+        run_dry()
+        # two long prompts and a short one due at once, nothing
+        # running: the first goes in whole, the others a chunk a step
+        send(47, 4), send(64 - 2 * ps, 2 * ps), send(5, 3)
+        run_dry()
+        # the pipeline empties in mid-prefill: the rows before it
+        # retire, its last chunks follow in one call
+        send(6, 2)
+        system.step()
+        send(62, 2)
         run_dry()
     finally:
         compiled.watch = False

@@ -34,7 +34,7 @@ from triton_distributed_tpu.models.glm4_moe_lite import (
 from triton_distributed_tpu.models.kv_cache import KVCache, PagedKVCache
 from triton_distributed_tpu.models.qwen import Qwen3
 from triton_distributed_tpu.serving.engine_batched import (
-    make_masked_step_fn, make_paged_insert_fn)
+    make_masked_step_fn, make_paged_insert_fn, make_paged_rows_fn)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LAYERS, PAGE, SLOTS = 2, 16, 8
@@ -193,6 +193,52 @@ def test_no_pool_sized_copy_in_step_or_insert(topo_devices, family,
     for program, copies in found.items():
         assert not copies["layout"], (program, copies)
         assert len(copies["staged"]) <= STAGED_MAX, (program, copies)
+
+
+def test_no_pool_sized_copy_in_a_chunk_or_in_its_rows(topo_devices):
+    """A long prompt's prefill in chunks (`Glm4MoeLite.
+    make_prefill_suffix_fn`): the chunk program READS the latent pools
+    through the request's page ids — gathered rows, never the pool —
+    and the scatter of its rows (`make_paged_rows_fn`) writes the
+    donated pools where they lie.  At the published widths and the
+    model's own chunk length, for the described v5e: Mosaic takes the
+    attention at a traced offset, and neither program copies a pool."""
+    model, heads, width, _ = _glm(topo_devices[:1])
+    chunk = model.prefill_chunk
+    rep = NamedSharding(model.mesh, P())
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
+        shape, dt, sharding=rep)
+    pool = _shaped(model, functools.partial(
+        PagedKVCache.create, LAYERS, PAGES, SLOTS, heads, PAGE, width,
+        4096 // PAGE, model.dtype, latent=True,
+        num_stats=len(MOE_STATS)), model._paged_cache_specs(PAGE))
+    row = _shaped(model, functools.partial(
+        KVCache.create, LAYERS, 1, heads, chunk, width, model.dtype,
+        latent=True), model._cache_specs())
+    params = _shaped(
+        model, lambda: model.init_params(jax.random.key(0)),
+        model.param_specs())
+    programs = {
+        "chunk": jax.jit(model.make_prefill_suffix_fn()).lower(
+            params, arg((1, chunk), jnp.int32), arg((), jnp.int32), row,
+            (pool.ks, None), arg((4096 // PAGE,), jnp.int32)),
+        "rows": make_paged_rows_fn().lower(
+            (pool.ks, None, None, None), pool.offset, row,
+            arg((chunk // PAGE,), jnp.int32))}
+    shard = (PAGES, heads, PAGE, width)
+    dims = ",".join(str(d) for d in shard)
+    for name, lowered in programs.items():
+        text = lowered.compile().as_text()
+        assert f"[{dims}]" in text, name
+        found = pool_copies(text, shard)
+        print(f"glm4_moe_lite {name} of {chunk}, pool {shard}: {found}")
+        assert not found["layout"], (name, found)
+        assert len(found["staged"]) <= STAGED_MAX, (name, found)
+        if name == "chunk":
+            # (the LAST layer's attention and feed-forward are not in
+            # the program: it returns rows, no logits, so nothing
+            # reads them — at this depth that is the expert layer)
+            assert "flash_attention_fwd" in text
 
 
 def test_no_state_sized_copy_in_step_insert_or_reset(topo_devices):

@@ -15,6 +15,12 @@ What is cached a token is the row ``[c_kv | k_rope | 0 pad]``
 - `prefill` is the non-absorbed form: keys and values are expanded and
   go through `flash_attention` at head size ``nope + rope`` (which the
   published sizes make equal to the value head size).
+- `prefill_suffix` is `prefill` for a CHUNK of one sequence whose
+  earlier rows already lie in the page pool: those rows are gathered
+  through the sequence's page ids and expanded (non-absorbed, as
+  above) in front of the chunk's own keys and values, and the chunk's
+  queries attend both under a causal diagonal shifted by the chunk's
+  first position.
 - `decode_paged` is the absorbed form: ``W_kvb``'s key half is folded
   into the query (``q~ = q_nope W^K``), the scores and the weighted sum
   are taken over the cached rows themselves (`mla_decode_paged`: one
@@ -29,6 +35,7 @@ parallel (`TPAttention` is the tp layer).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -49,6 +56,9 @@ from triton_distributed_tpu.layers.tp_attn import (
 )
 
 LANES = 128
+#: Rows of the pool that `prefill_suffix` expands at a time: only the
+#: blocks below the chunk's first position are.
+EXPAND_ROWS = 1024
 
 
 def _mm(x, w):
@@ -174,6 +184,84 @@ class MLAttention:
         rows = self._rows(c, k_rope.reshape(m, self.rope))
         return _mm(attn, params["wo"]), rows.reshape(
             batch, 1, s, self.row_width)
+
+    def _keys_values(self, rows, params):
+        """Cached rows (M, R) expanded for every head: keys
+        (H, M, nope + rope) — the shared rotated key behind each head's
+        own — and values (H, M, v)."""
+        c = rows[:, :self.lat]
+        k_rope = rows[:, self.lat:self.row_used]
+        k_nope = jnp.einsum("ml,lhn->hmn", c, params["wk_b"],
+                            preferred_element_type=jnp.float32
+                            ).astype(rows.dtype)
+        v = jnp.einsum("ml,lhv->hmv", c, params["wv_b"],
+                       preferred_element_type=jnp.float32
+                       ).astype(rows.dtype)
+        return jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope[None], (
+                self.num_heads, *k_rope.shape))], axis=-1), v
+
+    def suffix_buffers(self, span: int, chunk: int, dtype):
+        """The key and value buffers `prefill_suffix` fills: ``span``
+        positions a sequence's page ids reach, in whole blocks, and
+        room behind them for a chunk that starts at the last.  Made
+        once a program and handed from layer to layer: what a layer
+        leaves behind lies past the next one's diagonal."""
+        eb = min(EXPAND_ROWS, span)
+        n = -(-span // eb) * eb + chunk
+        return (jnp.zeros((self.num_heads, n, self.nope + self.rope),
+                          dtype),
+                jnp.zeros((self.num_heads, n, self.v_dim), dtype))
+
+    def prefill_suffix(self, x, params, start, pool, page_ids, bufs):
+        """x: (C, hidden), positions ``start + arange(C)`` of ONE
+        sequence whose rows below ``start`` lie in ``pool``
+        (P, 1, page, R) at the pages ``page_ids`` (T,) names in
+        logical order.  Those rows are expanded block by block up to
+        ``start`` into ``bufs`` (`suffix_buffers`), the chunk's own keys
+        and values are put at ``start``, and `flash_attention` runs
+        with its diagonal shifted by ``start``: row i sees nothing
+        past ``start + i``, so what the buffers hold beyond the chunk
+        needs no mask of its own.  Returns (out (C, hidden), rows
+        (1, 1, C, R) for the cache, bufs)."""
+        m = x.shape[0]
+        hd = self.num_heads
+        q, c, k_r = self._project(x, params)
+        cos, sin = rope_cos_sin(start + jnp.arange(m), self.rope,
+                                self.rope_theta)
+        q = q.reshape(1, m, hd, -1).transpose(0, 2, 1, 3)
+        qf = jnp.concatenate(
+            [q[..., :self.nope], apply_rope(q[..., self.nope:], cos, sin)],
+            axis=-1)
+        rows = self._rows(c, apply_rope(k_r, cos, sin))
+
+        span = page_ids.shape[0] * pool.shape[2]
+        room = bufs[0].shape[1] - m         # span, in whole blocks
+        eb = min(EXPAND_ROWS, room)
+        prefix = jnp.pad(pool[page_ids, 0].reshape(span, self.row_width),
+                         ((0, room - span), (0, 0)))
+
+        def expand(i, kv):
+            blk = jax.lax.dynamic_slice_in_dim(prefix, i * eb, eb)
+            return tuple(
+                jax.lax.dynamic_update_slice_in_dim(buf, new, i * eb,
+                                                    axis=1)
+                for buf, new in zip(kv, self._keys_values(blk, params)))
+
+        bufs = jax.lax.fori_loop(0, (start + eb - 1) // eb, expand, bufs)
+        kf, v = (jax.lax.dynamic_update_slice_in_dim(buf, own, start,
+                                                     axis=1)
+                 for buf, own in zip(bufs,
+                                     self._keys_values(rows, params)))
+        attend = (attention_reference if self.mode == "xla" else
+                  functools.partial(flash_attention,
+                                    interpret=self.interpret))
+        attn = attend(qf, kf[None], v[None], causal=True,
+                      scale=self.scale, kv_offset=start)
+        attn = attn.astype(x.dtype).transpose(0, 2, 1, 3).reshape(
+            m, hd * self.v_dim)
+        return (_mm(attn, params["wo"]),
+                rows.reshape(1, 1, m, self.row_width), (kf, v))
 
     def decode_paged(self, x, params, pool, page_table, offset):
         """One position a row.  x: (B, hidden); pool: (P, 1, page, R);

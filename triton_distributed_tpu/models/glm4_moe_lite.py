@@ -9,7 +9,11 @@ beside a shared expert) in the rest.
 It stands behind the same entry points the scheduler calls on `Qwen3`
 (`make_prefill_fn`, `make_paged_decode_fn`, `create_paged_cache`,
 `create_cache`, ``config.max_seq_len``), so the scheduler, the page
-pool, the radix cache and the step loop are shared unchanged.  What
+pool, the radix cache and the step loop are shared unchanged.  It also
+offers `make_prefill_suffix_fn`: a prefill of ONE CHUNK of a prompt
+whose earlier rows already lie in the page pool, which is how the
+scheduler carries a long prompt out a chunk a step (``prefill_chunk``
+tokens: `PREFILL_CHUNK`) and prefills only what a prefix hit left.  What
 differs is below them: the mixer (`layers.mla_attn.MLAttention`), the
 cache's layer state (one latent row a token, `models.kv_cache`) and the
 sparse feed-forward (`layers.moe_mlp.SparseMoE`, dropless).  The dense
@@ -40,7 +44,14 @@ from triton_distributed_tpu.layers.tp_mlp import TPMLP
 from triton_distributed_tpu.models.config import ModelConfig
 from triton_distributed_tpu.models.kv_cache import KVCache, PagedKVCache
 
-__all__ = ["Glm4MoeLite", "MOE_STATS"]
+__all__ = ["Glm4MoeLite", "MOE_STATS", "PREFILL_CHUNK"]
+
+#: Tokens of a prompt the scheduler prefills between two decode steps
+#: (`make_prefill_suffix_fn`).  A chunk streams the experts once, so a
+#: shorter one costs tokens a second and a longer one lengthens the
+#: token gap of every running row: settled on the chip, PERF.md
+#: section 6 (PR 40) has the sweep.
+PREFILL_CHUNK = 2048
 
 
 class Glm4MoeLite:
@@ -62,6 +73,7 @@ class Glm4MoeLite:
         self.mode = mode
         self.interpret = interpret
         self.dtype = jnp.dtype(config.dtype)
+        self.prefill_chunk = PREFILL_CHUNK
         self.attn = MLAttention(
             hidden=config.hidden_size, num_heads=config.num_heads,
             q_rank=config.q_lora_rank, lat=config.kv_lora_rank,
@@ -177,6 +189,17 @@ class Glm4MoeLite:
                          "prefill")
         return x + h, rows
 
+    def _layer_fwd_suffix(self, x, lp, pool, page_ids, start, bufs, *,
+                          sparse):
+        eps = self.config.rms_norm_eps
+        h, rows, bufs = self.attn.prefill_suffix(
+            rms_norm(x, lp["ln1"], eps), lp["attn"], start, pool,
+            page_ids, bufs)
+        x = x + h
+        h, _ = self._ffn(rms_norm(x, lp["ln2"], eps), lp["mlp"], sparse,
+                         "prefill")
+        return x + h, rows, bufs
+
     def _layer_fwd_decode(self, x, lp, pool, page_table, offset, *,
                           sparse):
         eps = self.config.rms_norm_eps
@@ -211,6 +234,34 @@ class Glm4MoeLite:
         if cache is not None:
             cache = cache.set_offset(s)
         return logits, cache
+
+    def prefill_shard_suffix(self, params, input_ids, start,
+                             cache: KVCache, pools, page_ids):
+        """One chunk of one prompt.  input_ids: (1, C), the tokens at
+        positions ``start + arange(C)`` (a last chunk right-padded);
+        ``pools``: the paged cache's (ks, vs), read and not written —
+        the rows below ``start`` lie there, at the pages ``page_ids``
+        (T,) names in logical order.  Returns ``cache`` (the single-row
+        cache of `create_cache`, C long) holding the chunk's rows at
+        LOCAL positions [0, C): the paged insert puts them into their
+        pages.  No logits: the first decode step recomputes the
+        prompt's last position — so nothing reads the last layer's
+        attention output or feed-forward, and the compiler leaves both
+        out (a chunk at ``start`` 0 is cheaper than the same bucket's
+        whole prefill)."""
+        b, s = input_ids.shape
+        assert b == 1, "a chunk is one sequence's"
+        ks, _ = pools
+        x = params["embed"][input_ids].reshape(s, -1)
+        start = jnp.asarray(start, jnp.int32).reshape(())
+        layer = self._per_layer(self._layer_fwd_suffix)
+        bufs = self.attn.suffix_buffers(
+            page_ids.shape[0] * ks[0].shape[2], s, self.dtype)
+        for li, lp in enumerate(params["layers"]):
+            x, rows, bufs = layer[self.is_sparse(li)](
+                x, lp, ks[li], page_ids, start, bufs)
+            cache = cache.write_prefill(li, rows)
+        return cache.set_offset(s)
 
     def decode_shard(self, params, tokens, cache: PagedKVCache):
         """One decode step.  tokens: (B,).  Returns (logits (B, V),
@@ -259,6 +310,19 @@ class Glm4MoeLite:
                       self._cache_specs()),
             out_specs=(P(None, self.axis), self._cache_specs()),
             check_vma=False)
+
+    def make_prefill_suffix_fn(self):
+        """``(params, ids (1, C), start, row_cache, (ks, vs), page_ids
+        (T,)) -> row_cache``: `prefill_shard_suffix`.  The program's
+        name starts like the whole prefill's, and its expert layers run
+        in the prefill phase, so a device trace reads both alike."""
+        n = self.config.num_layers
+        return jax.shard_map(
+            self.prefill_shard_suffix, mesh=self.mesh,
+            in_specs=(self.param_specs(), P(None, None), P(),
+                      self._cache_specs(),
+                      ([P(None, None, None, None)] * n, None), P(None)),
+            out_specs=self._cache_specs(), check_vma=False)
 
     def make_paged_decode_fn(self, page_size: int = 16):
         cspecs = self._paged_cache_specs(page_size)

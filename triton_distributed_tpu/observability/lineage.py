@@ -10,8 +10,8 @@ shipment-retry backoff vs decode admission.  This module closes that:
 - :class:`LineageEvent` (schema v1): one record per **hop** a request
   crosses — cluster submit, route stage/commit, prefill-worker
   start/end, transport ship/retry/NACK/deliver, decode admission
-  (local / shipped / suffix-only), preempt, failover, first token,
-  retire/reject (:data:`HOPS`).  Events carry the request id
+  (local / shipped / suffix-only / in chunks), preempt, failover,
+  first token, retire/reject (:data:`HOPS`).  Events carry the request id
   (`ClusterRequest.record_id` in a cluster, so they JOIN the router's
   DecisionEvents — ``op == "request:<id>"`` — and the chaos harness's
   FaultEvents — shipment ids ride in ``detail``), the emitting actor,

@@ -45,6 +45,7 @@ the block's tokens, its revealed flags and the phase all data.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -419,6 +420,50 @@ def make_insert_fn(donate: bool = True):
     return jax.jit(insert)
 
 
+def _scatter_pages(page_ids, ps: int, dst_list, src_list,
+                   scales: bool):
+    """Each LOCAL page j of a row's layers ``src_list`` into physical
+    page ``page_ids[j]`` of the pools ``dst_list``, where they lie."""
+    bucket = int(src_list[0].shape[2])
+    out = []
+    for dst, src in zip(dst_list, src_list):
+        for j in range(-(-bucket // ps)):
+            lo, hi = j * ps, min((j + 1) * ps, bucket)
+            blk = (src[:, :, lo:hi] if scales
+                   else src[:, :, lo:hi, :])
+            blk = blk.astype(dst.dtype)
+            idx = ((page_ids[j], 0, 0) if scales
+                   else (page_ids[j], 0, 0, 0))
+            dst = jax.lax.dynamic_update_slice(dst, blk, idx)
+        out.append(dst)
+    return out
+
+
+def make_paged_rows_fn():
+    """``(pools, offset, row_cache, page_ids) -> (pools, offset)`` —
+    the scatter of `make_paged_insert_fn` and nothing else: a CHUNK of
+    a prompt whose prefill is still under way goes into its pages, and
+    the slot's offset, key and page table are not touched (the slot is
+    masked until its last chunk, which takes the insert proper).
+    ``pools`` is ``(ks, vs, kss, vss)`` of the paged cache, None where
+    it has none; donated, and every pool comes back placed as it went
+    in.  The cache's ``offset`` passes through unchanged: whoever asks
+    whether the program enqueued last has finished asks that array
+    (`scheduler._starved`), so it has to be this program's output too.
+    """
+
+    def rows(pools, offset, row: KVCache, page_ids):
+        ps = int(pools[0][0].shape[2])
+        return tuple(
+            dst if dst is None else _scatter_pages(
+                page_ids, ps, dst, src, scales)
+            for dst, src, scales in zip(
+                pools, (row.ks, row.vs, row.kss, row.vss),
+                (False, False, True, True))), offset
+
+    return jax.jit(rows, donate_argnums=(0, 1))
+
+
 def make_paged_insert_fn(donate: bool = True):
     """``(pool_cache, keys, row_cache, key, slot, page_ids, offset) ->
     (pool_cache, keys)`` — scatter a freshly prefilled single-row
@@ -448,24 +493,8 @@ def make_paged_insert_fn(donate: bool = True):
     """
 
     def insert(pool, keys, row: KVCache, key, slot, page_ids, offset):
-        ps = pool.page_size
-        bucket = int(row.ks[0].shape[2])
-        n_pages = -(-bucket // ps)
-
-        def scatter(dst_list, src_list, scales: bool):
-            out = []
-            for dst, src in zip(dst_list, src_list):
-                for j in range(n_pages):
-                    lo, hi = j * ps, min((j + 1) * ps, bucket)
-                    blk = (src[:, :, lo:hi] if scales
-                           else src[:, :, lo:hi, :])
-                    blk = blk.astype(dst.dtype)
-                    idx = ((page_ids[j], 0, 0) if scales
-                           else (page_ids[j], 0, 0, 0))
-                    dst = jax.lax.dynamic_update_slice(dst, blk, idx)
-                out.append(dst)
-            return out
-
+        scatter = functools.partial(_scatter_pages, page_ids,
+                                    pool.page_size)
         rep = dict(ks=scatter(pool.ks, row.ks, False),
                    offset=jax.lax.dynamic_update_slice(
                        pool.offset,

@@ -77,6 +77,7 @@ from triton_distributed_tpu.models.kv_cache import (
 )
 from triton_distributed_tpu.serving.engine_batched import (
     make_paged_insert_fn,
+    make_paged_rows_fn,
 )
 
 
@@ -588,6 +589,9 @@ class PagedKV:
                                                    range(self.num_slots)]
         #: Logical pages currently mapped per slot.
         self._mapped = np.zeros(self.num_slots, np.int64)
+        #: slot -> (page-table row to be, prompt length) of the
+        #: prefills under way (`begin_prefill` .. `finish_prefill`).
+        self._prefilling: Dict[int, tuple] = {}
         #: Work at the KV boundary since the start: pages `ensure`
         #: mapped, page-table rows `flush` uploaded — mirrored as
         #: ``serving_kv_pages_mapped_total`` /
@@ -599,6 +603,9 @@ class PagedKV:
         # host-side page accounting runs against a recording insert
         # and a stub cache, no jit, no device arrays.
         self._insert = insert_fn or make_paged_insert_fn()
+        #: The scatter alone, for the chunks of a prefill under way
+        #: (`insert_rows`).
+        self._put_rows = make_paged_rows_fn()
         #: Slots whose state was zeroed since the start
         #: (``serving_state_resets_total``), and the program that does
         #: it (`_reset_state`), built at the first release.
@@ -859,13 +866,29 @@ class PagedKV:
         prefill, or the page-aligned shared-prefix length for the
         suffix path).  Full prompt pages are registered into the
         radix cache so later arrivals share them.  Returns the slot.
-        """
+
+        A prefill in ONE piece: `begin_prefill`, one `insert_rows`,
+        `finish_prefill`.  One carried out in chunks calls the three
+        itself, an insert a chunk."""
+        assert row_start <= len(shared_path) * self.page_size
+        slot = self.begin_prefill(prompt_len, shared_path)
+        self.insert_rows(slot, row_cache, row_start, key, offset)
+        self.finish_prefill(slot, tokens, offset)
+        return slot
+
+    def begin_prefill(self, prompt_len: int,
+                      shared_path: List[_RadixNode]) -> int:
+        """Claim a slot and every page of a prompt of ``prompt_len``
+        tokens: the shared chain acquired (spilled nodes restored),
+        the rest allocated.  The slot's row of the page TABLE stays
+        NULL until `finish_prefill` — a decode step that runs while
+        the prompt is still being prefilled masks the slot, and a
+        masked row's write must land in the trash page — so the pages
+        are kept beside it (`prefill_pages`).  Returns the slot."""
         s = int(prompt_len)
         ps = self.page_size
         assert self._free, "insert_prefill without can_admit()"
-        assert row_start % ps == 0, row_start
         c_pages = len(shared_path)
-        assert row_start <= c_pages * ps
         total_pages = pages_for(s, ps)
         # Acquire the shared chain BEFORE allocating: _alloc may evict
         # refcount-0 radix pages, and the matched chain must not be
@@ -901,15 +924,38 @@ class PagedKV:
         priv = self._alloc(total_pages - c_pages)
         assert priv is not None, "insert_prefill without can_admit()"
         slot = self._free.pop(0)
-        # host table row: shared chain, then private pages, then NULL
+        # the slot's row-to-be: shared chain, then private pages, then
+        # NULL
         row = np.full(self.pages_per_seq, NULL_PAGE, np.int32)
         for j, node in enumerate(shared_path):
             row[j] = node.page
         for i, p in enumerate(priv):
             row[c_pages + i] = p
-        self._table[slot] = row
-        self._mapped[slot] = total_pages
-        self._dirty = True
+        self._prefilling[slot] = (row, s)
+        self._slot_pages[slot] = list(priv)
+        self._slot_path[slot] = list(shared_path)
+        return slot
+
+    def prefill_pages(self, slot: int) -> np.ndarray:
+        """The pages of a slot whose prefill is under way, in logical
+        order (NULL past the prompt): what a chunk's program reads the
+        rows of its predecessors through."""
+        return self._prefilling[slot][0]
+
+    def insert_rows(self, slot: int, row_cache, row_start: int,
+                    key=None, offset: Optional[int] = None) -> None:
+        """Scatter ``row_cache`` — positions ``[row_start, row_start +
+        its length)`` of the prompt begun in ``slot`` — into the
+        slot's private pages.  With ``key`` (the prompt's LAST rows)
+        the same dispatch sets the slot's PRNG key and its offset —
+        ``offset``, or the prompt's last position: the insert proper.
+        Without it the rows are written and nothing else — the slot of
+        a prefill under way stays as its release left it, offset 0."""
+        row, s = self._prefilling[slot]
+        ps = self.page_size
+        assert row_start % ps == 0, row_start
+        c_pages = len(self._slot_path[slot])
+        total_pages = pages_for(s, ps)
         # physical destination of each LOCAL row page (NULL = discard:
         # shared pages the row may not overwrite, pad-tail overflow)
         bucket = int(row_cache.ks[0].shape[2])
@@ -919,13 +965,34 @@ class PagedKV:
             g = row_start // ps + j
             if c_pages <= g < total_pages:
                 page_ids[j] = row[g]
+        if key is None:
+            c = self.cache
+            (ks, vs, kss, vss), offset = self._put_rows(
+                (c.ks, c.vs, c.kss, c.vss), c.offset, row_cache,
+                jnp.asarray(page_ids))
+            self.cache = dataclasses.replace(
+                c, ks=ks, vs=vs, kss=kss, vss=vss, offset=offset)
+            return
         self.cache, self.keys = self._insert(
             self.cache, self.keys, row_cache, key,
             jnp.int32(slot), jnp.asarray(page_ids),
             jnp.int32(s - 1 if offset is None else offset))
+
+    def finish_prefill(self, slot: int, tokens: Sequence[int],
+                       offset: Optional[int] = None) -> None:
+        """The last rows of the prompt begun in ``slot`` are in: map
+        its pages, and register its full pages into the radix cache so
+        that later arrivals share them (``offset``: a block-generating
+        model's cursor, below which alone pages are shared)."""
+        row, s = self._prefilling.pop(slot)
+        ps = self.page_size
+        shared_path = self._slot_path[slot]
+        priv = self._slot_pages[slot]
+        c_pages = len(shared_path)
+        self._table[slot] = row
+        self._mapped[slot] = pages_for(s, ps)
+        self._dirty = True
         self._active[slot] = True
-        self._slot_pages[slot] = list(priv)
-        self._slot_path[slot] = list(shared_path)
         # Register newly written FULL prompt pages (strictly below
         # position s-1) so the next same-prefix arrival shares them.
         if self.radix is not None:
@@ -947,7 +1014,6 @@ class PagedKV:
             # (freshly prefilled; the never-sharable tail page is
             # not a cache miss).
             self._tier_account(None, max(sharable - c_pages, 0))
-        return slot
 
     def adopt_prefix(self, tokens: Sequence[int],
                      payloads: Sequence[dict]) -> int:
@@ -1012,6 +1078,9 @@ class PagedKV:
         issuing (frozen-offset) writes, which must land in the trash
         page, never in a page someone else may get."""
         assert 0 <= slot < self.num_slots and slot not in self._free
+        # (a prefill under way is given up with its slot: the pages it
+        # holds are the slot's, mapped or not)
+        self._prefilling.pop(slot, None)
         if self._slot_path[slot] and self.radix is not None:
             self.radix.release(self._slot_path[slot])
         self.pool.decref(self._slot_pages[slot])

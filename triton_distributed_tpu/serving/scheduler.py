@@ -89,6 +89,27 @@ measures prefill + step, and that reading less the rolling step time
 is the prefill's (`_prefill_reading`); it is kept out of the step
 metrics.
 
+An admission is a SEQUENCE of such enqueues, each followed by its
+insert — a whole prefill being a sequence of one.  Where the model
+offers a prefill of one chunk over the pages already in the pool
+(``make_prefill_suffix_fn``) and names a chunk length
+(``prefill_chunk``), a prompt with more than a chunk still to prefill
+— after the prefix match — is carried out chunk by chunk: chunk k
+attends the rows its predecessors put into the request's pages, and AT
+MOST ONE ENQUEUE — a chunk, or a short prompt's whole prefill — stands
+between two decode dispatches, so a running row's token gap holds a
+step and a chunk where it held a step and the longest prefill.  The
+request's slot and pages are claimed at its first insert (at its
+first chunk that reads the pool, if that comes first) and the slot
+stays masked — its row of the page table NULL — until the last chunk
+is in; only then does the radix tree learn the prompt.  One admission
+is under way at a time, first come first; with nothing running there
+is no gap to protect and its chunks are enqueued in one call.  Each
+chunk follows the rules of the paragraph above (`_front_fits`, the
+early read), is a prefill in the read's record, and is timed by the
+step's own sync.  A model without the program admits as ever, several
+prefills a call included.
+
 Every token gap is put down to what made it.  Each read leaves its
 record on its `serving.sync` span — the interval since the read before
 it (the gap every committed row sees), the rows committed, the
@@ -298,6 +319,28 @@ class _Flight:
     prefills: Sequence[tuple] = ()
 
 
+@dataclasses.dataclass
+class _Admission:
+    """One request on its way into a slot: the prefill enqueues it
+    takes (``pieces``), each followed by its insert."""
+    req: Request
+    #: What is prefilled: the prompt, or a resumed stream's context.
+    tokens: Sequence[int]
+    key: object
+    #: The matched radix path (paged layout).
+    shared: list
+    #: The lineage admission class: local / shipped / suffix / chunk.
+    mode: str
+    #: (first position, bucket) of each piece, in order.
+    pieces: List[tuple]
+    #: A shipment's row: nothing is enqueued for it.
+    row: object = None
+    #: Pieces whose insert is dispatched.
+    done: int = 0
+    #: Paged layout: the slot, once its pages are claimed.
+    slot: Optional[int] = None
+
+
 class ContinuousBatchingScheduler:
     """model: anything with the engine contract (`create_cache`,
     `make_prefill_fn`, `make_decode_fn`) — `models.qwen.Qwen3` or
@@ -361,10 +404,12 @@ class ContinuousBatchingScheduler:
                 spill_disk_pages=cfg.spill_disk_pages)
             decode_fn = model.make_paged_decode_fn(
                 page_size=cfg.page_size)
+            # ``(params, ids, start, row_cache, (ks, vs), page_ids)
+            # -> row_cache``: a prefill of positions ``start ...`` over
+            # the rows the pool already holds at ``page_ids``.
             sfn = getattr(model, "make_prefill_suffix_fn", None)
             self._prefill_suffix = (jax.jit(sfn())
-                                    if sfn is not None
-                                    and cfg.prefix_cache else None)
+                                    if sfn is not None else None)
         elif cfg.kv_layout == "slots":
             self.slots = SlotKV(model.create_cache(cfg.num_slots,
                                                    max_seq=self.max_seq),
@@ -373,6 +418,18 @@ class ContinuousBatchingScheduler:
             self._prefill_suffix = None
         else:
             raise ValueError(f"unknown kv_layout {cfg.kv_layout!r}")
+        #: Tokens a chunk of a long prompt's prefill (module docstring);
+        #: 0: the model does not offer it, nothing is ever chunked.
+        self._chunk = (int(getattr(model, "prefill_chunk", 0) or 0)
+                       if self._prefill_suffix is not None else 0)
+        #: The admission whose next chunk is due, and whether a prefill
+        #: was enqueued since the last decode dispatch (a model that
+        #: chunks gets one between two).
+        self._underway: Optional[_Admission] = None
+        self._spent = False
+        #: The page ids of a first chunk that reads no row of the pool.
+        self._no_pages = (np.zeros(self.slots.pages_per_seq, np.int32)
+                          if self.paged else None)
         #: The model has recurrent layers (`models.kv_cache`): a state
         #: a slot beside the pages.  Its prefill is told each row's
         #: true length, and a resumed or prefix-sharing request
@@ -633,7 +690,8 @@ class ContinuousBatchingScheduler:
         unread: the last tokens of the last request are delivered by
         the `step()` after the one that dispatched them."""
         return (bool(self._queue) or bool(self._by_slot)
-                or self._flight is not None)
+                or self._flight is not None
+                or self._underway is not None)
 
     def step(self) -> dict:
         """One scheduler iteration.  Returns counts for introspection:
@@ -647,7 +705,8 @@ class ContinuousBatchingScheduler:
 
     def _step_phases(self) -> dict:
         now = self.clock()
-        self._idle = not self._by_slot and self._flight is None
+        self._idle = (not self._by_slot and self._flight is None
+                      and self._underway is None)
         admitted, retired = self._admit(now)
         # (rows that an admission's read retired were in the batch)
         active_n = len(self._by_slot) + retired
@@ -691,6 +750,7 @@ class ContinuousBatchingScheduler:
         one it is about to resume)."""
         self._stopped = True
         self._drop_flight()
+        self._give_up_underway()
         for slot in list(self._by_slot):
             self._retire(slot, self.clock(), FinishReason.STOPPED)
         reg = self._registry()
@@ -920,45 +980,57 @@ class ContinuousBatchingScheduler:
         return bool(self._queue and self._queue[0].t_arrival <= now
                     and self._can_admit_head() and self._slo_gate(now))
 
+    def _piece_due(self, now: float) -> bool:
+        """A prefill may be enqueued now: an admission is under way, or
+        the queue's head can begin one — and, on a model that chunks,
+        none was enqueued since the last decode dispatch while anything
+        runs that would wait for a second one."""
+        if (self._chunk and self._spent
+                and (self._by_slot or self._flight is not None)):
+            return False
+        return self._underway is not None or self._head_ready(now)
+
     def _admit(self, now: float) -> tuple:
-        """Admit what the queue's head, the slots and the pool allow.
-        Returns (requests admitted, rows retired): a call that admits
-        while a step is in flight READS that step here, once — between
-        the first admission's halves or ahead of them (`_front_fits`)
-        — so its tokens never wait for an insert's dispatch, and the
-        host waits for no prefill at all.  A slot that read frees may
-        be filled in the same call; every admission after it has no
-        flight to read and enqueues straight through."""
-        # Nothing to try: no arrived head, or no slot to put it in
-        # (both layouts refuse without a free slot) — and no span.
-        if (not self._queue or self._stopped
-                or self._queue[0].t_arrival > now
-                or not self.slots.free_slots):
+        """Admit what the queue's head, the slots and the pool allow,
+        and carry the admission under way a chunk further.
+        Returns (requests admitted, rows retired): a call that enqueues
+        a prefill while a step is in flight READS that step here, once
+        — between the first admission's halves or ahead of them
+        (`_front_fits`) — so its tokens never wait for an insert's
+        dispatch, and the host waits for no prefill at all.  A slot
+        that read frees may be filled in the same call; every admission
+        after it has no flight to read and enqueues straight through.
+        """
+        # Nothing to try: no chunk due and no arrived head, or no slot
+        # to put it in (both layouts refuse without a free slot) — and
+        # no span.
+        if self._stopped or (self._underway is None and (
+                not self._queue or self._queue[0].t_arrival > now
+                or not self.slots.free_slots)):
             return 0, 0
         n = retired = 0
         read = False      # this call read the step in flight
-        began = None      # the admission whose insert waits for that read
-        ready = self._head_ready(now)
-        while ready or began is not None:
+        began = None      # the piece whose insert waits for that read
+        due = self._piece_due(now)
+        while due or began is not None:
             if self._flight is not None and (began is not None
                                              or not self._front_fits()):
                 retired += self._read(self._take_flight(), early=True)
                 read = True
             with span("serving.admit", queued=len(self._queue)):
                 if began is not None:
-                    self._admit_insert(began, now, read)
-                    n, began, read = n + 1, None, False
-                    ready = self._head_ready(now)
-                while ready:
-                    front = self._admit_front(self._queue.popleft(), now,
-                                              read)
+                    n += self._admit_insert(began, now, read)
+                    began, read = None, False
+                    due = self._piece_due(now)
+                while due:
+                    front = self._admit_front(now, read)
                     if front is not None and self._flight is not None:
                         began = front       # its insert: after the read
                         break
                     if front is not None:   # (None: retired at admission)
-                        self._admit_insert(front, now, read)
-                        n, read = n + 1, False
-                    ready = self._head_ready(now)
+                        n += self._admit_insert(front, now, read)
+                        read = False
+                    due = self._piece_due(now)
         return n, retired
 
     def _front_fits(self) -> bool:
@@ -985,143 +1057,154 @@ class ContinuousBatchingScheduler:
         """The rolling time of a plain dispatch, in seconds."""
         return statistics.median(self._step_times)
 
-    def _admit_front(self, req: Request, now: float, read: bool):
-        """First half of the admission of ``req`` (already off the
-        queue): prefix match and the prefill's ENQUEUE — or a
-        shipment's row.  Nothing here waits for the device.  ``read``:
-        this call has read the step in flight already.  Returns what
-        `_admit_insert` takes, or None when the request was retired at
-        admission."""
+    def _admit_front(self, now: float, read: bool):
+        """First half of a piece of an admission — the next chunk of
+        the one under way, else the first (or only) piece of the
+        queue's head: prefix match and plan, then the prefill's
+        ENQUEUE — or a shipment's row.  Nothing here waits for the
+        device.  ``read``: this call has read the step in flight
+        already.  Returns what `_admit_insert` takes, or None when the
+        request was retired at admission."""
         reg = self._registry()
-        had_ship = req.shipped_kv is not None
+        adm = self._underway
+        req = adm.req if adm is not None else self._queue.popleft()
         behind = self._flight is not None
         t0 = self.step_timer()
         with span("serving.admit.prefill", request_id=req.request_id,
                   flight=_flight_label(behind, read),
                   queue_wait_ms=max(now - req.t_arrival, 0.0) * 1e3
                   ) as sp:
-            if self.paged:
-                row = self._prefill_paged(req, now, reg, sp)
-                if row is None:
+            if adm is None:
+                adm = self._plan(req, now, reg)
+                if adm is None:
                     return None           # retired at admission
-            elif had_ship:
-                row = self._shipped_row(req, reg) + ("shipped",)
-            else:
-                bucket = pick_bucket(req.prompt_len, self.buckets)
-                assert bucket is not None  # submit() validated
-                ids, s = pad_prompt(req.prompt, bucket,
-                                    self.config.pad_id)
-                self._starved("prefill", sp)
-                _, row_cache = self._prefill(self.params, ids,
-                                             self._row_cache(bucket))
-                row = (row_cache, s, bucket, "local")
+            row = self._enqueue_piece(adm, sp)
+            bucket = adm.pieces[adm.done][1]
             if sp is not NULL_SPAN:
-                sp.attrs["bucket"] = row[-2]
+                sp.attrs["bucket"] = bucket
+                if len(adm.pieces) > 1:
+                    sp.attrs.update(chunk=adm.done,
+                                    chunks=len(adm.pieces))
         # A consumed shipment (`_shipped_row` clears the hook) ran NO
         # local prefill — it has its own serving_shipped_inserts_total,
         # and counting it would desync serving_prefills_total from the
         # serving_prefill_ms histogram it pairs with.
-        local = not (had_ship and req.shipped_kv is None)
+        local = adm.row is None
         if local:
             if not self._prefills:
                 self._prefill_t0 = t0
-            # (either layout's row ends: bucket, mode)
-            self._prefills.append((row[-2], req))
+            self._prefills.append((bucket, req))
             self._front_times.append(self.step_timer() - t0)
-        return req, row, local, behind
+            self._spent = True
+        return adm, row, local, behind
 
-    def _admit_insert(self, front, now: float, read: bool) -> None:
-        """Second half of an admission: the insert's dispatch into a
-        slot, and the books.  ``read``: the step in flight was read
-        for this admission (`serving.admit.request`'s ``read_flight``).
-        """
-        req, row, local, behind = front
+    def _admit_insert(self, front, now: float, read: bool) -> int:
+        """Second half of a piece: the insert's dispatch into the
+        request's slot and, behind the last piece, the books.
+        ``read``: the step in flight was read for this piece
+        (`serving.admit.request`'s ``read_flight``).  Returns 1 when
+        the request is now running, 0 while chunks are left."""
+        adm, row, local, behind = front
+        req, tokens = adm.req, adm.tokens
+        start, bucket = adm.pieces[adm.done]
+        adm.done += 1
+        last = adm.done == len(adm.pieces)
+        self._underway = None if last else adm
         reg = self._registry()
         with span("serving.admit.request", request_id=req.request_id,
                   prompt_len=req.prompt_len) as sp:
             cached = 0
             if self.paged:
-                (row_cache, tokens, s, key, shared, row_start, bucket,
-                 mode) = row
-                cached = len(shared) * self.config.page_size
-                slot = self.slots.insert_prefill(
-                    row_cache, tokens, s, key, shared,
-                    row_start=row_start,
-                    offset=(s // self._block * self._block
-                            if self._block > 1 else None))
+                s = len(tokens)
+                cached = len(adm.shared) * self.config.page_size
+                if adm.slot is None:
+                    adm.slot = self.slots.begin_prefill(s, adm.shared)
+                slot = adm.slot
+                if last:
+                    cursor = (s // self._block * self._block
+                              if self._block > 1 else None)
+                    self.slots.insert_rows(slot, row, start, adm.key,
+                                           cursor)
+                    self.slots.finish_prefill(slot, tokens, cursor)
+                else:
+                    # rows alone: the slot stays masked, untouched
+                    self.slots.insert_rows(slot, row, start)
             else:
-                row_cache, s, bucket, mode = row
-                tokens = req.prompt
-                slot = self.slots.insert_prefill(
-                    row_cache, s, self._request_key(req))
-            if self._block > 1:
-                self._start_block(slot, req, tokens)
-            else:
-                self._tokens[slot] = tokens[-1]
-            self._fresh[slot] = True
-            req.state = RequestState.RUNNING
-            req.slot = slot
-            req.bucket = bucket
-            req.t_admitted = now
-            self._by_slot[slot] = req
-            if self.drafter is not None and not self._spec_throttled:
-                # Admission (or resume) seeds the draft state from the
-                # full committed context — same tokens that seeded the
-                # slot's input above.  A throttled engine skips the
-                # upkeep entirely (draft prefills, reconcile
-                # dispatches): the throttle is for the scheduler's
-                # lifetime, so the draft cache will never be read.
-                self.drafter.start(req, tokens)
+                slot = self.slots.insert_prefill(row, len(tokens),
+                                                 adm.key)
+            if last:
+                if self._block > 1:
+                    self._start_block(slot, req, tokens)
+                else:
+                    self._tokens[slot] = tokens[-1]
+                self._fresh[slot] = True
+                req.state = RequestState.RUNNING
+                req.slot = slot
+                req.bucket = bucket
+                req.t_admitted = now
+                self._by_slot[slot] = req
+                if self.drafter is not None and not self._spec_throttled:
+                    # Admission (or resume) seeds the draft state from
+                    # the full committed context — same tokens that
+                    # seeded the slot's input above.  A throttled
+                    # engine skips the upkeep entirely (draft prefills,
+                    # reconcile dispatches): the throttle is for the
+                    # scheduler's lifetime, so the draft cache will
+                    # never be read.
+                    self.drafter.start(req, tokens)
+                life = get_tracer().detached(
+                    "serving.request", request_id=req.request_id,
+                    prompt_len=req.prompt_len, slot=slot, bucket=bucket)
+                life.__enter__()
+                self._spans[slot] = life
             if sp is not NULL_SPAN:
                 sp.attrs.update(bucket=bucket, cached_tokens=cached,
-                                mode=mode, slot=slot,
+                                mode=adm.mode, slot=slot,
                                 read_flight=int(read))
-            life = get_tracer().detached(
-                "serving.request", request_id=req.request_id,
-                prompt_len=req.prompt_len, slot=slot, bucket=bucket)
-            life.__enter__()
-            self._spans[slot] = life
-        if reg:
-            if local:
-                reg.counter("serving_prefills_total",
-                            bucket=str(bucket)).inc()
-                # enqueued and never waited for: behind the step in
-                # flight, after its read, or with nothing in flight
-                reg.counter("serving_admit_overlapped_total",
-                            flight=_flight_label(behind, read)).inc()
-            reg.histogram("serving_queue_wait_ms").observe(
-                max(now - req.t_arrival, 0.0) * 1e3)
-            if (req.resume_tokens is not None or req.preemptions
-                    or req.resume_key is not None):
-                # A preempt-and-requeue (or failover re-prefill)
-                # resume: the "resume" half of the seam.  The
-                # tokens recomputed by this admission are the
-                # preemption's waste bill.
-                self._charge_tokens("reprefill", req, len(tokens))
-                self._hop(req, "admit", now, slot=slot,
-                          bucket=bucket, mode=mode, resumed=True)
-            else:
-                self._hop(req, "admit", now, slot=slot,
-                          bucket=bucket, mode=mode)
+        if not reg:
+            return int(last)
+        if local:
+            reg.counter("serving_prefills_total",
+                        bucket=str(bucket)).inc()
+            if len(adm.pieces) > 1:
+                reg.counter("serving_prefill_chunks_total").inc()
+            # enqueued and never waited for: behind the step in
+            # flight, after its read, or with nothing in flight
+            reg.counter("serving_admit_overlapped_total",
+                        flight=_flight_label(behind, read)).inc()
+        if not last:
+            return 0
+        reg.histogram("serving_queue_wait_ms").observe(
+            max(now - req.t_arrival, 0.0) * 1e3)
+        if (req.resume_tokens is not None or req.preemptions
+                or req.resume_key is not None):
+            # A preempt-and-requeue (or failover re-prefill)
+            # resume: the "resume" half of the seam.  The
+            # tokens recomputed by this admission are the
+            # preemption's waste bill.
+            self._charge_tokens("reprefill", req, len(tokens))
+            self._hop(req, "admit", now, slot=slot,
+                      bucket=bucket, mode=adm.mode, resumed=True)
+        else:
+            self._hop(req, "admit", now, slot=slot,
+                      bucket=bucket, mode=adm.mode)
+        return 1
 
-    def _prefill_paged(self, req: Request, now: float, reg, sp):
-        """Paged admission, first half: radix prefix match, then the
-        prefill's enqueue — suffix-only on a hit (near-zero-cost
-        shared system prompts); ``sp`` is the `serving.admit.prefill`
-        span, told whether the enqueue found the chip idle.  Returns
-        what the paged insert takes —
-        (row, tokens, prompt length, key, shared path, row start,
-        bucket, mode), mode the lineage admission class (local /
-        shipped / suffix) — or None when the request had to be retired
-        at admission (a resumed stream that no longer fits any prefill
-        bucket)."""
+    def _plan(self, req: Request, now: float,
+              reg) -> Optional[_Admission]:
+        """How ``req`` (just off the queue) gets into a slot: the radix
+        prefix match, then the prefill enqueues it takes — a shipment's
+        row and none; on a hit with a prefix-aware model ONLY the
+        private suffix (near-zero-cost shared system prompts); in
+        chunks where more than the model's chunk length is left; else
+        the whole prompt through its bucket.  None: the request had to
+        be retired at admission (a resumed stream that no longer fits
+        any prefill bucket)."""
         tokens = req.resume_tokens or req.prompt
         s = len(tokens)
-        shared = self.slots.match_prefix(tokens)
+        shared = self.slots.match_prefix(tokens) if self.paged else []
         c = len(shared) * self.config.page_size
         key = self._request_key(req)
-        bucket = row = row_start = None
-        mode = "local"
         if req.shipped_kv is not None and req.resume_tokens is None:
             # Prefill-worker shipment: the full-prompt row arrives
             # precomputed; shared prefix pages (if any matched) are
@@ -1129,90 +1212,125 @@ class ContinuousBatchingScheduler:
             # storage sharing composes with shipping unchanged.
             row, s2, bucket = self._shipped_row(req, reg)
             assert s2 == s, (s2, s)
-            row_start = 0
-            mode = "shipped"
-        elif c > 0 and self._prefill_suffix is not None:
+            adm = _Admission(req, tokens, key, shared, "shipped",
+                             [(0, bucket)], row=row)
+        elif self._chunk and s - c > self._chunk:
+            # More than a chunk to prefill: a chunk a step, each
+            # attending what its predecessors (and a prefix hit) left
+            # in the pool.
+            adm = _Admission(req, tokens, key, shared, "chunk",
+                             [(at, self._chunk)
+                              for at in range(c, s, self._chunk)])
+        elif (c > 0 and self._prefill_suffix is not None
+              and (bucket := pick_bucket(s - c, self.buckets))):
             # Prefix hit with a prefix-aware model: prefill ONLY the
             # private suffix — the shared pages are already in the
             # pool.  This is the compute half of prefix sharing (the
             # storage half — page reuse — works for any model).
-            bucket = pick_bucket(s - c, self.buckets)
-            if bucket is not None:
-                ids, _ = pad_prompt(tokens[c:], bucket,
-                                    self.config.pad_id)
-                self._starved("prefill", sp)
-                row = self._prefill_suffix(self.params, ids,
-                                           jnp.int32(c),
-                                           self._row_cache(bucket))
-                row_start = c
-                mode = "suffix"
-        if row is None:
-            mode = "local"
+            adm = _Admission(req, tokens, key, shared, "suffix",
+                             [(c, bucket)])
+        else:
             bucket = pick_bucket(s, self.buckets)
             if bucket is None:
-                # No full-prompt bucket.  (The matched chain was
-                # never acquired — nothing to undo.)  Two ways here:
-                if (req.resume_tokens is None
-                        and req.resume_key is None
-                        and not req.generated):
-                    # A fresh request admitted on the strength of a
-                    # cached prefix (prefix-dependent admission,
-                    # `structural_reject`) whose prefix was EVICTED
-                    # under pressure before it reached a slot: shed
-                    # it with the truthful reason.  With spill
-                    # enabled the prefix would have been restored —
-                    # this branch is the no-spill degradation.
-                    req.state = RequestState.REJECTED
-                    req.reject_reason = RejectReason.KV_PRESSURE
-                    req.t_finish = now
-                    if reg:
-                        reg.counter(
-                            "serving_requests_rejected_total",
-                            reason=RejectReason.KV_PRESSURE.value
-                        ).inc()
-                        self._hop(req, "reject", now,
-                                  reason=RejectReason.KV_PRESSURE
-                                  .value)
-                    self.finished.append(req)
-                    return None
-                # Resume: prompt + generated outgrew every bucket —
-                # deliver what it has.
-                req.state = RequestState.FINISHED
-                req.finish_reason = FinishReason.KV_CAPACITY
-                req.t_finish = now
-                if reg:
-                    reg.counter("serving_requests_completed_total",
-                                reason=FinishReason.KV_CAPACITY.value
-                                ).inc()
-                    self._hop(req, "retire", now,
-                              reason=FinishReason.KV_CAPACITY.value,
-                              generated=len(req.generated))
-                self.finished.append(req)
+                self._retire_unbucketed(req, now, reg)
                 return None
-            ids, _ = pad_prompt(tokens, bucket, self.config.pad_id)
-            row_in = self._row_cache(bucket)
+            adm = _Admission(req, tokens, key, shared, "local",
+                             [(0, bucket)])
+            redone = s if req.resume_tokens is not None else c
+            if self._stateful and redone:
+                # a snapshot of the state would have saved these
+                self._state_recomputed += redone
+                if reg:
+                    reg.counter(
+                        "serving_state_recomputed_tokens_total"
+                    ).inc(redone)
+        if reg and self.paged:
+            reg.counter("serving_prefix_cache_hit_tokens_total").inc(c)
+            reg.counter("serving_prefix_cache_miss_tokens_total").inc(
+                s - c)
+        return adm
+
+    def _retire_unbucketed(self, req: Request, now: float, reg) -> None:
+        """No full-prompt bucket for a request already off the queue.
+        (The matched chain was never acquired — nothing to undo.)  Two
+        ways here."""
+        assert self.paged                 # submit() validated
+        if (req.resume_tokens is None and req.resume_key is None
+                and not req.generated):
+            # A fresh request admitted on the strength of a
+            # cached prefix (prefix-dependent admission,
+            # `structural_reject`) whose prefix was EVICTED
+            # under pressure before it reached a slot: shed
+            # it with the truthful reason.  With spill
+            # enabled the prefix would have been restored —
+            # this branch is the no-spill degradation.
+            req.state = RequestState.REJECTED
+            req.reject_reason = RejectReason.KV_PRESSURE
+            req.t_finish = now
+            if reg:
+                reg.counter("serving_requests_rejected_total",
+                            reason=RejectReason.KV_PRESSURE.value).inc()
+                self._hop(req, "reject", now,
+                          reason=RejectReason.KV_PRESSURE.value)
+            self.finished.append(req)
+            return
+        # Resume: prompt + generated outgrew every bucket —
+        # deliver what it has.
+        req.state = RequestState.FINISHED
+        req.finish_reason = FinishReason.KV_CAPACITY
+        req.t_finish = now
+        if reg:
+            reg.counter("serving_requests_completed_total",
+                        reason=FinishReason.KV_CAPACITY.value).inc()
+            self._hop(req, "retire", now,
+                      reason=FinishReason.KV_CAPACITY.value,
+                      generated=len(req.generated))
+        self.finished.append(req)
+
+    def _enqueue_piece(self, adm: _Admission, sp):
+        """ENQUEUE the prefill of the admission's next piece; ``sp`` is
+        the `serving.admit.prefill` span, told whether the enqueue
+        found the chip idle.  Returns the row its insert takes."""
+        if adm.row is not None:
+            return adm.row
+        start, bucket = adm.pieces[adm.done]
+        s = len(adm.tokens)
+        ids, _ = pad_prompt(adm.tokens[start:start + bucket], bucket,
+                            self.config.pad_id)
+        row_in = self._row_cache(bucket)
+        if adm.mode == "local":
             if self._stateful:
                 # the state absorbs what lies below position s-1 (the
                 # first decode step takes that token, as it rewrites
                 # that position's K/V), never the bucket's padded tail
                 row_in = dataclasses.replace(
                     row_in, length=np.full((1,), s - 1, np.int32))
-                redone = s if req.resume_tokens is not None else c
-                if redone:
-                    # a snapshot of the state would have saved these
-                    self._state_recomputed += redone
-                    if reg:
-                        reg.counter(
-                            "serving_state_recomputed_tokens_total"
-                        ).inc(redone)
             self._starved("prefill", sp)
             _, row = self._prefill(self.params, ids, row_in)
-            row_start = 0
-        if reg:
-            reg.counter("serving_prefix_cache_hit_tokens_total").inc(c)
-            reg.counter("serving_prefix_cache_miss_tokens_total").inc(
-                s - c)
-        return row, tokens, s, key, shared, row_start, bucket, mode
+            return row
+        # positions ``start ...`` over the rows below them: the pages
+        # are claimed here where the piece reads them, else — as for a
+        # whole prefill — at its insert, behind the enqueue
+        if start and adm.slot is None:
+            adm.slot = self.slots.begin_prefill(s, adm.shared)
+        pages = (self.slots.prefill_pages(adm.slot)
+                 if adm.slot is not None else self._no_pages)
+        cache = self.slots.cache
+        self._starved("prefill", sp)
+        return self._prefill_suffix(self.params, ids, jnp.int32(start),
+                                    row_in, (cache.ks, cache.vs), pages)
+
+    def _give_up_underway(self) -> None:
+        """Drop the admission under way: its slot and pages go back,
+        its request to the head of the queue (what was enqueued for it
+        writes pages nobody reads before their next owner's own
+        rows; its prefills stay counted where a read still times them)."""
+        adm, self._underway = self._underway, None
+        if adm is None:
+            return
+        if adm.slot is not None:
+            self.slots.release(adm.slot)
+        self._queue.appendleft(adm.req)
 
     # -- generation by blocks (module docstring) -------------------------
 
@@ -1401,6 +1519,12 @@ class ContinuousBatchingScheduler:
                 return True
             if self._flight is not None:
                 return False
+            if (self._underway is not None
+                    and self._underway.slot is not None):
+                # the newest claim on the pool: its pages fund the
+                # rows that are running, it starts over later
+                self._give_up_underway()
+                continue
             assert len(self._by_slot) > 1, (
                 "page pool cannot hold a sole feasible request — "
                 "allocator invariant broken")
@@ -1652,6 +1776,7 @@ class ContinuousBatchingScheduler:
                         program=program).inc()
 
     def _count_dispatch(self, inflight: bool) -> None:
+        self._spent = False     # the next chunk may follow this step
         reg = self._registry()
         if reg:
             reg.counter("serving_decode_dispatch_total").inc()

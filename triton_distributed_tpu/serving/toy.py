@@ -39,6 +39,9 @@ class ToyConfig:
     hidden: int = 32
     max_seq_len: int = 128
     quantize_kv_cache: bool = False
+    #: Tokens a chunk of a long prompt's prefill (`ToyModel.
+    #: prefill_chunk`, read by the scheduler); 0: never chunked.
+    prefill_chunk: int = 0
 
 
 def _quantize_token(k, v):
@@ -53,6 +56,7 @@ def _quantize_token(k, v):
 class ToyModel:
     def __init__(self, config: Optional[ToyConfig] = None):
         self.config = config or ToyConfig()
+        self.prefill_chunk = self.config.prefill_chunk
 
     def init_params(self, key):
         cfg = self.config
@@ -109,15 +113,20 @@ class ToyModel:
         """Prefix-cache-aware prefill: compute KV for suffix positions
         ``[start, start + S)`` of a prompt whose first ``start`` tokens
         are already cached (their pages are shared via the radix
-        cache).  The toy's K/V at position i depend only on token i and
-        position i, so no attention over the prefix is needed; a
-        multi-layer model would attend its suffix queries over the
-        cached prefix KV here.  Returns the row cache with the suffix
+        cache) — or, a chunk at a time, of a prompt the scheduler
+        prefills in chunks of ``prefill_chunk`` tokens.  ``pools`` is
+        the paged cache's ``(ks, vs)`` and ``page_ids`` (T,) the
+        request's pages in logical order: where a multi-layer model
+        attends its suffix queries over the rows below ``start``
+        (`models.glm4_moe_lite`).  The toy's K/V at position i depend
+        only on token i and position i, so it reads neither.  Returns
+        the row cache with the suffix
         KV at LOCAL positions [0, S) — the paged insert scatters local
         pages to physical pages.  No logits: the serving insert path
         recomputes position s-1 and never consumes prefill logits."""
 
-        def prefill_suffix(params, ids, start, cache: KVCache):
+        def prefill_suffix(params, ids, start, cache: KVCache, pools,
+                           page_ids):
             b, s = ids.shape
             pos = jnp.asarray(start, jnp.int32) + jnp.arange(s)
             x = params["embed"][ids] + params["pe"][pos][None]
