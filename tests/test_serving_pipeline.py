@@ -204,6 +204,15 @@ def _sdar_system(devices):
     return sdar_moe.System(dict(TINY, serving=serving), 11, devices[:1])
 
 
+def _nemotron_system(devices):
+    from cellbench.adapters import nemotron_h
+    from tests.test_nemotron_h import PAGE, STATE, TINY
+    serving = dict(TINY["serving"], num_slots=8,
+                   kv_budget_bytes_per_chip=8 * (STATE + 8 * PAGE))
+    return nemotron_h.System(dict(TINY, serving=serving), 11,
+                             devices[:1])
+
+
 #: Slots for `warm_up` to admit both requests of every bucket in one
 #: call (1 + 2 x 3 buckets here), as every cell but the two of seven
 #: buckets on eight slots has: there an insert of the fourth bucket
@@ -212,6 +221,7 @@ def _sdar_system(devices):
 SYSTEMS = {"toy": lambda devices: ToySystem(),
            "qwen3": _qwen_system, "glm4_moe_lite": _glm_system,
            "solar_open2": _solar_system, "sdar_moe": _sdar_system,
+           "nemotron_h": _nemotron_system,
            "qwen3-tp4": lambda devices: _qwen_system(devices, 4)}
 #: six minutes of interpreted ring kernels: by hand (`-m slow`)
 FAMILIES = [pytest.param(f, marks=pytest.mark.slow) if f == "qwen3-tp4"
@@ -336,9 +346,10 @@ def test_no_program_and_no_kind_of_argument_is_first_met_after_warm_up(
         x, y = send(7, 4), send(20, 4)
         while x.finish_reason is None:
             system.step()
-        if sched._chunk:
-            # a model that prefills in chunks: one enqueue a decode
-            # dispatch, so y was admitted a call after x
+        if sched._paced:
+            # a model that prefills in chunks, or one with a recurrent
+            # state: one enqueue a decode dispatch, so y was admitted
+            # a call after x
             assert y.finish_reason is None
             system.step()
         assert y.finish_reason is not None and long.finish_reason is None

@@ -318,6 +318,93 @@ def test_no_state_sized_copy_in_step_insert_or_reset(topo_devices):
             assert len(found["staged"]) <= STAGED_MAX, (name, what, found)
 
 
+def test_no_state_sized_copy_in_the_state_space_step(topo_devices):
+    """The state-space hybrid (`models/nemotron_h.py`) at the cell's own
+    widths, five layers with every kind among them (`MEM*E`): a decode
+    step rewrites every live slot's Mamba-2 state —
+    4 MB a slot a layer, two heads side by side — through a kernel that
+    aliases the pool; the insert and the reset write one slot's rows.
+    None of the three programs may copy the state pool or the attention
+    layer's page pools, and the step names its kernels."""
+    from triton_distributed_tpu.models.kv_cache import zero_state_rows
+    from triton_distributed_tpu.models.nemotron_h import NemotronH
+
+    c = _config("nemotron-3-super-120b-1c.json")
+    pattern = "MEM*E"
+    cfg = ModelConfig(
+        architecture=c["model_type"], vocab_size=c["vocab_size"],
+        hidden_size=c["hidden_size"],
+        intermediate_size=c["intermediate_size"],
+        num_layers=len(pattern), num_heads=c["num_attention_heads"],
+        num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
+        rms_norm_eps=c["layer_norm_epsilon"], qk_norm=False,
+        use_rope=False, tie_word_embeddings=False, max_seq_len=4096,
+        dtype=c["torch_dtype"],
+        num_experts=c["share"]["experts_of_layer"],
+        experts_held=tuple(c["share"]["experts_held"]),
+        num_experts_per_tok=c["num_experts_per_tok"],
+        moe_intermediate_size=c["moe_intermediate_size"],
+        n_shared_experts=c["n_shared_experts"],
+        routed_scaling_factor=c["routed_scaling_factor"],
+        layer_pattern=pattern, mamba_num_heads=c["mamba_num_heads"],
+        mamba_head_dim=c["mamba_head_dim"], mamba_n_groups=c["n_groups"],
+        ssm_state_size=c["ssm_state_size"],
+        mamba_conv_size=c["conv_kernel"], moe_act=c["mlp_hidden_act"],
+        moe_latent_size=c["moe_latent_size"],
+        moe_shared_intermediate_size=c[
+            "moe_shared_expert_intermediate_size"])
+    model = NemotronH(cfg, Mesh(np.array(topo_devices[:1]), ("tp",)),
+                      mode="fused", interpret=False)
+    slots = 16
+    rep = NamedSharding(model.mesh, P())
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
+        shape, dt, sharding=rep)
+    pool = _shaped(model, functools.partial(
+        PagedKVCache.create, 1, PAGES, slots, 2, PAGE, 128,
+        4096 // PAGE, model.dtype, num_stats=len(model.STATS),
+        state_shapes=model._state_shapes),
+        model._paged_cache_specs(PAGE))
+    row = _shaped(model, functools.partial(
+        KVCache.create, 1, 1, 2, BUCKET, 128, model.dtype,
+        state_shapes=model._state_shapes), model._cache_specs())
+    params = _shaped(
+        model, lambda: model.init_params(jax.random.key(0)),
+        model.param_specs())
+    keys = arg((slots, 2), jnp.uint32)
+    programs = {
+        "step": make_masked_step_fn(model.make_paged_decode_fn(PAGE))
+        .lower(params, arg((slots,), jnp.int32), pool, keys,
+               arg((slots,), jnp.bool_)),
+        "prefill": jax.jit(model.make_prefill_fn()).lower(
+            params, arg((1, BUCKET), jnp.int32), row),
+        "insert": make_paged_insert_fn().lower(
+            pool, keys, row, arg((2,), jnp.uint32), arg((), jnp.int32),
+            arg((BUCKET // PAGE,), jnp.int32), arg((), jnp.int32)),
+        "reset": jax.jit(zero_state_rows, donate_argnums=(0, 1)).lower(
+            pool.states, pool.convs, arg((), jnp.int32))}
+    named = {"step": ("mamba2_decode_step", "moe_decode_relu2_up",
+                      "moe_decode_relu2_down", "flash_decode_paged"),
+             "prefill": ("mamba2_prefill_chunk", "moe_prefill_relu2_up",
+                         "moe_prefill_relu2_down")}
+    shapes = {"state": (slots, 64, 128, 128),
+              "pages": (PAGES, 2, PAGE, 128)}
+    for name, lowered in programs.items():
+        text = lowered.compile().as_text()
+        for kernel in named.get(name, ()):
+            assert kernel in text, (name, kernel)
+        if name == "prefill":
+            continue
+        for what, shape in shapes.items():
+            if name == "reset" and what == "pages":
+                continue
+            dims = ",".join(str(d) for d in shape)
+            assert f"[{dims}]" in text, (name, what)
+            found = pool_copies(text, shape)
+            print(f"nemotron_h {name} {what} {shape}: {found}")
+            assert not found["layout"], (name, what, found)
+            assert len(found["staged"]) <= STAGED_MAX, (name, what, found)
+
+
 def test_no_pool_sized_copy_in_a_block_pass(topo_devices):
     """The block-diffusion family (`models/sdar_moe.py`): a pass
     writes every row's block — four rows a slot a layer — into its

@@ -763,6 +763,36 @@ def packed_expert_gate_up(x_rows, w_gate, w_up, block_expert, n_blocks,
         interpret=interpret)
 
 
+def _relu2_up_kernel(bexp_ref, nblk_ref, x_ref, w_ref, o_ref):
+    @pl.when(pl.program_id(1) < nblk_ref[0])
+    def _():
+        u = jnp.maximum(jax.lax.dot_general(
+            x_ref[...], w_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32), 0.0)
+        o_ref[...] = (u * u).astype(o_ref.dtype)
+
+
+def packed_expert_relu2_up(x_rows, w_up, block_expert, n_blocks, *,
+                           block: int, name: str = "moe_relu2_up",
+                           interpret: Optional[bool] = None):
+    """``relu(x W_up[e]) ** 2`` for rows packed by expert: the
+    up-projection of an expert of TWO matrices (no gate matrix).
+
+    x_rows: (T * block, k); w_up: (E, k, f); the rest as
+    `packed_expert_gate_up`.  Returns (T * block, f) in x's dtype."""
+    rows, k = x_rows.shape
+    e, k2, f = w_up.shape
+    assert k == k2 and rows % block == 0
+    tn = _expert_tile(f)
+    wspec = pl.BlockSpec((1, k, tn),
+                         lambda j, t, bexp, nblk: (bexp[t], 0, j),
+                         memory_space=pltpu.VMEM)
+    return _packed_call(
+        _relu2_up_kernel, name, [x_rows, w_up], [wspec],
+        (block_expert, n_blocks), rows=rows, block=block, k=k, n=f,
+        tn=tn, out_dtype=x_rows.dtype, interpret=interpret)
+
+
 def _down_kernel(bexp_ref, nblk_ref, a_ref, w_ref, s_ref, o_ref):
     @pl.when(pl.program_id(1) < nblk_ref[0])
     def _():
@@ -834,5 +864,8 @@ def _resource_packed_experts():
         packed_expert_down(
             act, jnp.zeros((e, f, h), jnp.bfloat16),
             jnp.zeros((t * block,), jnp.float32), bexp, jnp.int32(t),
+            block=block, interpret=False)
+        packed_expert_relu2_up(
+            x, jnp.zeros((e, h, f), jnp.bfloat16), bexp, jnp.int32(t),
             block=block, interpret=False)
     return records

@@ -312,6 +312,17 @@ class SparseMoE:
     experts elsewhere would have added is left out (on one chip the
     layer runs without its exchange).  None: every expert is here.
 
+    ``act``: the expert's form.  ``"silu"``: ``silu(x W_g) * (x W_u)``
+    through ``W_d``, three matrices; ``"relu2"``: ``relu(x W_u) ** 2``
+    through ``W_d``, two, and no ``gate`` weight.  The shared expert
+    (``shared_ffn`` wide; None: ``ffn * n_shared``) follows the form.
+
+    ``latent``: the routed experts work in a space of that width
+    behind ONE shared down-projection ``latent_down`` (hidden x
+    latent) and one shared up-projection ``latent_up`` around the
+    packed kernels; the router and the shared expert read the hidden
+    stream.  None: the experts work on the hidden stream.
+
     Not tensor-parallel: `MoEMLP` above is the tp layer."""
 
     hidden: int
@@ -325,10 +336,15 @@ class SparseMoE:
     interpret: Optional[bool] = None
     held: Optional[tuple] = None   # (lo, hi) of num_experts
     scoring: str = "sigmoid"       # sigmoid (+ selection bias) | softmax
+    act: str = "silu"              # silu (gated) | relu2 (no gate)
+    latent: Optional[int] = None   # the routed experts' width
+    shared_ffn: Optional[int] = None
 
     def __post_init__(self):
         if self.scoring not in ("sigmoid", "softmax"):
             raise ValueError(f"unknown scoring {self.scoring!r}")
+        if self.act not in ("silu", "relu2"):
+            raise ValueError(f"unknown expert form {self.act!r}")
 
     @property
     def num_held(self) -> int:
@@ -336,10 +352,14 @@ class SparseMoE:
         return hi - lo
 
     def init_params(self, key, dtype=jnp.bfloat16):
-        ks = jax.random.split(key, 7)
+        # the first seven as ever: a layer of the old form keeps the
+        # weights a key gave it
+        ks = (*jax.random.split(key, 7),
+              *jax.random.split(jax.random.fold_in(key, 7), 2))
         e, h, f = self.num_experts, self.hidden, self.ffn
-        fs = f * self.n_shared
-        n = self.num_held
+        fs = self.shared_ffn or f * self.n_shared
+        n, v = self.num_held, self.latent or self.hidden
+        gated = self.act == "silu"
 
         def normal(k, shape, fan_in):
             return (jax.random.normal(k, shape) * fan_in ** -0.5
@@ -348,11 +368,15 @@ class SparseMoE:
         make = {
             "router": lambda: normal(ks[0], (h, e), h).astype(jnp.float32),
             "router_bias": lambda: 0.01 * jax.random.normal(ks[1], (e,)),
-            "gate": lambda: normal(ks[2], (n, h, f), h),
-            "up": lambda: normal(ks[3], (n, h, f), h),
-            "down": lambda: normal(ks[4], (n, f, h), f),
-            "shared": lambda: {"gate_up": normal(ks[5], (h, 2 * fs), h),
-                               "down": normal(ks[6], (fs, h), fs)},
+            "gate": lambda: normal(ks[2], (n, v, f), v),
+            "up": lambda: normal(ks[3], (n, v, f), v),
+            "down": lambda: normal(ks[4], (n, f, v), f),
+            "shared": lambda: {
+                "gate_up" if gated else "up":
+                normal(ks[5], (h, (1 + gated) * fs), h),
+                "down": normal(ks[6], (fs, h), fs)},
+            "latent_down": lambda: normal(ks[7], (h, v), h),
+            "latent_up": lambda: normal(ks[8], (v, h), v),
         }
         return {k: m() for k, m in make.items()
                 if k not in self._absent()}
@@ -360,15 +384,18 @@ class SparseMoE:
     def _absent(self):
         """Weights this layer does not have."""
         return (("router_bias",) * (self.scoring == "softmax")
-                + ("shared",) * (not self.n_shared))
+                + ("shared",) * (not self.n_shared)
+                + ("gate",) * (self.act == "relu2")
+                + ("latent_down", "latent_up") * (not self.latent))
 
     def param_specs(self):
         from jax.sharding import PartitionSpec as P
         p = {"router": P(None, None), "router_bias": P(None),
              "gate": P(None, None, None), "up": P(None, None, None),
              "down": P(None, None, None),
-             "shared": {"gate_up": P(None, None),
-                        "down": P(None, None)}}
+             "shared": {"gate_up" if self.act == "silu" else "up":
+                        P(None, None), "down": P(None, None)},
+             "latent_down": P(None, None), "latent_up": P(None, None)}
         return {k: v for k, v in p.items() if k not in self._absent()}
 
     # ------------------------------------------------------------------
@@ -394,9 +421,15 @@ class SparseMoE:
         return ids.astype(jnp.int32), w * self.routed_scaling
 
     def _shared(self, x, params):
-        h = jnp.dot(x, params["gate_up"],
-                    preferred_element_type=jnp.float32).astype(x.dtype)
-        return jnp.dot(gated_silu(h), params["down"],
+        if self.act == "relu2":
+            h = jnp.square(jax.nn.relu(jnp.dot(
+                x, params["up"], preferred_element_type=jnp.float32))
+            ).astype(x.dtype)
+        else:
+            h = gated_silu(jnp.dot(
+                x, params["gate_up"],
+                preferred_element_type=jnp.float32).astype(x.dtype))
+        return jnp.dot(h, params["down"],
                        preferred_element_type=jnp.float32)
 
     def _routed_xla(self, x, params, ids, w):
@@ -405,28 +438,40 @@ class SparseMoE:
                                  ids].add(w)
         if self.held is not None:
             dense_w = dense_w[:, self.held[0]:self.held[1]]
-        g = jnp.einsum("nh,ehf->enf", x, params["gate"],
-                       preferred_element_type=jnp.float32)
         u = jnp.einsum("nh,ehf->enf", x, params["up"],
                        preferred_element_type=jnp.float32)
-        act = (jax.nn.silu(g) * u).astype(x.dtype)
+        if self.act == "relu2":
+            act = jnp.square(jax.nn.relu(u)).astype(x.dtype)
+        else:
+            g = jnp.einsum("nh,ehf->enf", x, params["gate"],
+                           preferred_element_type=jnp.float32)
+            act = (jax.nn.silu(g) * u).astype(x.dtype)
         y = jnp.einsum("enf,efh->enh", act, params["down"],
                        preferred_element_type=jnp.float32)
         return jnp.einsum("enh,ne->nh", y, dense_w)
 
     def _routed_fused(self, x, params, ids, plan, block, phase):
         from triton_distributed_tpu.kernels.grouped_gemm import (
-            packed_expert_down, packed_expert_gate_up)
+            packed_expert_down, packed_expert_gate_up,
+            packed_expert_relu2_up)
 
         rows = moe_utils.gather_tokens(x, plan.row_token)
-        act = packed_expert_gate_up(
-            rows, params["gate"], params["up"], plan.block_expert,
-            plan.n_blocks, block=block, name=f"moe_{phase}_gate_up",
-            interpret=self.interpret)
+        tables = (plan.block_expert, plan.n_blocks)
+        if self.act == "relu2":
+            # named apart in a device trace: another kernel, and an
+            # expert of another size
+            down = f"moe_{phase}_relu2_down"
+            act = packed_expert_relu2_up(
+                rows, params["up"], *tables, block=block,
+                name=f"moe_{phase}_relu2_up", interpret=self.interpret)
+        else:
+            down = f"moe_{phase}_down"
+            act = packed_expert_gate_up(
+                rows, params["gate"], params["up"], *tables, block=block,
+                name=f"moe_{phase}_gate_up", interpret=self.interpret)
         out = packed_expert_down(
-            act, params["down"], plan.row_weight, plan.block_expert,
-            plan.n_blocks, block=block, name=f"moe_{phase}_down",
-            interpret=self.interpret)
+            act, params["down"], plan.row_weight, *tables, block=block,
+            name=down, interpret=self.interpret)
         # each token's topk rows, already weighted: a float32 sum
         picked = out[plan.pair_row].astype(jnp.float32)
         if self.held is not None:
@@ -443,18 +488,28 @@ class SparseMoE:
         row, the busiest expert's share of the pairs — of the held
         experts, and in `HELD_STATS` order, where `held`).  ``phase``
         ("decode" | "prefill") names the two grouped GEMMs in a device
-        trace: `moe_<phase>_gate_up`, `moe_<phase>_down`."""
+        trace: `moe_<phase>_gate_up`, `moe_<phase>_down`
+        (`moe_<phase>_relu2_up`, `moe_<phase>_relu2_down` where the
+        expert is of that form)."""
         n = x.shape[0]
         ids, w = self.route(x, params)
         block = _pack_block(n * self.topk, self.num_experts)
         plan = moe_utils.pack_by_expert(ids, w, self.num_experts, block,
                                         held=self.held)
+        xin = x
+        if self.latent:
+            xin = jnp.dot(x, params["latent_down"],
+                          preferred_element_type=jnp.float32
+                          ).astype(x.dtype)
         if self.mode == "xla":
-            y = self._routed_xla(x, params, ids, w)
+            y = self._routed_xla(xin, params, ids, w)
         elif self.mode == "fused":
-            y = self._routed_fused(x, params, ids, plan, block, phase)
+            y = self._routed_fused(xin, params, ids, plan, block, phase)
         else:
             raise ValueError(f"unknown mode {self.mode}")
+        if self.latent:
+            y = jnp.dot(y.astype(x.dtype), params["latent_up"],
+                        preferred_element_type=jnp.float32)
         if self.n_shared:
             y = y + self._shared(x, params["shared"])
         if self.held is not None:

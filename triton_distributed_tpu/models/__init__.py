@@ -24,4 +24,7 @@ def AutoLLM(config, mesh, **kw):
     if "sdar_moe" in arch or "sdarmoe" in arch:
         from triton_distributed_tpu.models.sdar_moe import SdarMoe
         return SdarMoe(config, mesh, **kw)
+    if "nemotron_h" in arch or "nemotronh" in arch:
+        from triton_distributed_tpu.models.nemotron_h import NemotronH
+        return NemotronH(config, mesh, **kw)
     raise ValueError(f"unknown architecture: {config.architecture}")

@@ -83,6 +83,26 @@ class ModelConfig:
     remasking: str = "sequential"
     #: The sparse layer's router (`layers.moe_mlp.SparseMoE.scoring`).
     moe_scoring: str = "sigmoid"
+    # State-space hybrid (`models/nemotron_h.py`): a layer is ONE
+    # mixer, named by its character of ``layer_pattern`` — ``M`` a
+    # Mamba-2 layer (``mamba_num_heads`` heads of ``mamba_head_dim``
+    # channels over a state of ``ssm_state_size``, ``mamba_n_groups``
+    # groups of B and C, a convolution of ``mamba_conv_size`` taps),
+    # ``*`` grouped-query attention (the keys above), ``E`` a sparse
+    # feed-forward whose experts are of the form ``moe_act``
+    # (`layers.moe_mlp.SparseMoE.act`) in a latent of
+    # ``moe_latent_size`` (None: the hidden stream) beside a shared
+    # expert ``moe_shared_intermediate_size`` wide.  "": not this
+    # family.
+    layer_pattern: str = ""
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 64
+    mamba_n_groups: int = 8
+    ssm_state_size: int = 128
+    mamba_conv_size: int = 4
+    moe_act: str = "silu"
+    moe_latent_size: Optional[int] = None
+    moe_shared_intermediate_size: Optional[int] = None
 
     @property
     def is_moe(self) -> bool:
@@ -208,6 +228,30 @@ class ModelConfig:
                  norm_topk_prob=True, moe_scoring="softmax",
                  block_length=4, mask_token_id=255, denoising_steps=2,
                  remasking="sequential")
+        d.update(kw)
+        return cls(**d)
+
+    @classmethod
+    def tiny_nemotron_h(cls, **kw):
+        """Test-size state-space hybrid: two Mamba-2 layers, one
+        grouped-query layer without positions and two latent expert
+        layers (a share, 8 of 32, of a top-6 layer of two-matrix
+        squared-ReLU experts in a latent half the hidden size, beside
+        an ungated shared expert), one mixer a layer.  The Mamba-2
+        head keeps its published 64 x 128 state: the kernels' tile."""
+        d = dict(architecture="nemotron_h", vocab_size=256,
+                 hidden_size=128, intermediate_size=96, num_layers=5,
+                 num_heads=8, num_kv_heads=2, head_dim=16,
+                 rms_norm_eps=1e-5, qk_norm=False, use_rope=False,
+                 tie_word_embeddings=False, max_seq_len=128,
+                 layer_pattern="MEM*E", mamba_num_heads=8,
+                 mamba_head_dim=64, mamba_n_groups=2,
+                 ssm_state_size=128, mamba_conv_size=4,
+                 num_experts=32, num_experts_per_tok=6,
+                 moe_intermediate_size=96, n_shared_experts=1,
+                 moe_shared_intermediate_size=192, moe_latent_size=64,
+                 moe_act="relu2", routed_scaling_factor=5.0,
+                 norm_topk_prob=True, experts_held=(0, 8))
         d.update(kw)
         return cls(**d)
 

@@ -20,10 +20,16 @@ A third kind of layer keeps no row a token at all: a RECURRENT layer
 (`layers.kda_attn`) holds a fixed-size state a sequence — ``states[i]``
 ``(B, H, dk, dv)`` float32 and the short convolution's last inputs
 ``convs[i]`` ``(B, (taps - 1) * channels)`` — indexed by batch row (slot)
-on the leading dimension in both layouts.  A hybrid model's cache holds
-both: ``ks`` / ``vs`` list its attention layers, ``states`` / ``convs``
-its recurrent ones, each in layer order; pages, the page table and the
-radix cache concern the former alone.
+on the leading dimension in both layouts.  A STATE-SPACE layer
+(`layers.mamba2_mixer`) is recurrent in the same way and rides the same
+two lists with shapes of its own (``state_shapes``): ``(B, H / 2, N,
+2 P)`` float32 — two heads' ``(P, N)`` states side by side
+(`kernels.mamba2.pair_state`) — and ``(B, (taps - 1) * channels)`` over
+the channels of x, B and C.  A hybrid model's cache holds both kinds:
+``ks`` / ``vs`` list its attention layers, ``states`` / ``convs`` its
+recurrent ones, each in layer order; a layer that is a feed-forward
+alone owns nothing here; pages, the page table and the radix cache
+concern the attention layers alone.
 """
 
 from __future__ import annotations
@@ -279,8 +285,9 @@ class PagedKVCache:
     #: nothing.
     stats: Optional[jnp.ndarray] = None
     #: Recurrent layers' state a slot (module docstring): (B, H, dk,
-    #: dv) float32 and (B, (taps - 1) * channels) a layer, rewritten where
-    #: they lie by every decode step.  None: there are none.
+    #: dv) — or a state-space layer's (B, H / 2, N, 2 P) — float32 and
+    #: (B, (taps - 1) * channels) a layer, rewritten where they lie by
+    #: every decode step.  None: there are none.
     states: Optional[List[jnp.ndarray]] = None
     convs: Optional[List[jnp.ndarray]] = None
     #: Tokens per page — static: it shapes the compiled programs.

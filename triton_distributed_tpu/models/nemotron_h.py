@@ -1,0 +1,329 @@
+"""Nemotron-H family (`model_type` ``nemotron_h``): a state-space
+hybrid in which a layer is ONE mixer.
+
+    x <- x + Mixer_l(RMSNorm(x));  final RMSNorm, untied head
+
+``Mixer_l`` is named by character ``l`` of ``config.layer_pattern``
+(`hybrid_override_pattern`): ``M`` a Mamba-2 layer
+(`layers.mamba2_mixer.Mamba2Mixer`): a fixed-size recurrent state a
+sequence and the tail of a short convolution; ``*`` grouped-query
+softmax attention with no positional encoding
+(`layers.tp_attn.TPAttention`, ``rope=False``) over pages; ``E`` a
+sparse feed-forward (`layers.moe_mlp.SparseMoE`) whose experts are two
+matrices around a squared ReLU in a latent narrower than the hidden
+stream, told which experts of the layer this chip holds
+(``config.experts_held``).  An ``E`` layer owns no cache at all.  The
+embedding and the head are over the vocabulary this chip holds.
+
+It stands behind the entry points the scheduler calls on the other
+families (`make_prefill_fn`, `make_paged_decode_fn`,
+`create_paged_cache`, `create_cache`), so the scheduler, the page
+pool, the state pool and the pipelined step are shared.  Its cache
+(`models.kv_cache`) holds both kinds of layer state: pages for the
+``*`` layers, a state a slot for the ``M`` layers.  The prefill reads
+``cache.length``: the tokens of each row the state is to absorb (never
+a bucket's padded tail).  A decode step updates the state of the LIVE
+rows only (`PagedKVCache.live_rows`).
+
+ONE device (``tp`` of size 1); tensor parallelism for this family, the
+exchange that would make the held expert layer expert-parallel, a
+snapshot of the state and a prefill that starts from a carried state
+are not built (ROADMAP Reach).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from triton_distributed_tpu.kernels.matmul import MatmulConfig
+from triton_distributed_tpu.layers.mamba2_mixer import Mamba2Mixer
+from triton_distributed_tpu.layers.moe_mlp import HELD_STATS, SparseMoE
+from triton_distributed_tpu.layers.tp_attn import TPAttention, rms_norm
+from triton_distributed_tpu.models.config import ModelConfig
+from triton_distributed_tpu.models.kv_cache import KVCache, PagedKVCache
+
+__all__ = ["NemotronH"]
+
+#: A layer's kind, as the published pattern writes it.
+SSM, ATTN, MOE = "M", "*", "E"
+
+
+class NemotronH:
+    #: What a decode step leaves in the cache's `stats`, in order: the
+    #: held experts' counters summed over the ``E`` layers (the busiest
+    #: expert's share in the worst), and the rows whose state it
+    #: updated.
+    STATS = HELD_STATS + ("live_slots",)
+
+    def __init__(self, config: ModelConfig, mesh: Mesh, axis: str = "tp",
+                 mode: str = "fused", interpret: Optional[bool] = None,
+                 gemm: Optional[MatmulConfig] = None):
+        pattern = config.layer_pattern
+        assert len(pattern) == config.num_layers, (pattern, config)
+        assert {ATTN, MOE} <= set(pattern) <= {SSM, ATTN, MOE}, pattern
+        assert config.experts_held is not None, "which experts are here?"
+        assert mesh.shape[axis] == 1, (
+            f"{type(self).__name__} runs on one device; "
+            f"{axis}={mesh.shape[axis]} is not built")
+        assert not config.quantize_kv_cache, "no int8 cache beside a state"
+        self.config = config
+        self.mesh = mesh
+        self.axis = axis
+        self.world = 1
+        self.mode = mode
+        self.interpret = interpret
+        self.dtype = jnp.dtype(config.dtype)
+        self.pattern = pattern
+        self.attn = TPAttention(
+            axis=axis, world_size=1, hidden=config.hidden_size,
+            num_heads=config.num_heads,
+            num_kv_heads=config.num_kv_heads, head_dim=config.head_dim,
+            rope_theta=config.rope_theta, qk_norm=False,
+            rope=config.use_rope, mode=mode,
+            gemm=gemm or MatmulConfig(), interpret=interpret)
+        self.ssm = Mamba2Mixer(
+            hidden=config.hidden_size, num_heads=config.mamba_num_heads,
+            head_dim=config.mamba_head_dim, groups=config.mamba_n_groups,
+            state=config.ssm_state_size, conv=config.mamba_conv_size,
+            eps=config.rms_norm_eps, mode=mode, interpret=interpret)
+        self.moe = SparseMoE(
+            hidden=config.hidden_size, ffn=config.moe_intermediate_size,
+            num_experts=config.num_experts,
+            topk=config.num_experts_per_tok,
+            n_shared=config.n_shared_experts,
+            routed_scaling=config.routed_scaling_factor,
+            norm_topk_prob=config.norm_topk_prob, mode=mode,
+            interpret=interpret, held=tuple(config.experts_held),
+            act=config.moe_act, latent=config.moe_latent_size,
+            shared_ffn=config.moe_shared_intermediate_size)
+        self._mixers = {SSM: self.ssm, ATTN: self.attn, MOE: self.moe}
+        #: Each layer's place among the layers of its kind: the index
+        #: of its pools (``ks`` / ``vs``) or of its state (``states``).
+        self._index = [pattern[:i].count(k) for i, k in enumerate(pattern)]
+        self.num_attn, self.num_ssm = pattern.count(ATTN), pattern.count(SSM)
+
+    @property
+    def _state_shapes(self):
+        return [self.ssm.state_shapes] * self.num_ssm
+
+    # ------------------------------------------------------------------
+    # parameters
+    # ------------------------------------------------------------------
+
+    def _named(self, specs):
+        return jax.tree.map(
+            lambda sp: NamedSharding(self.mesh, sp), specs,
+            is_leaf=lambda x: isinstance(x, P))
+
+    def _layer_specs(self, kind: str):
+        mixer = self._mixers[kind]
+        return {"ln": P(None),
+                "mixer": (mixer.global_param_specs() if kind == ATTN
+                          else mixer.param_specs())}
+
+    def param_specs(self):
+        return {"embed": P(None, None),
+                "layers": [self._layer_specs(k) for k in self.pattern],
+                "ln_f": P(None),
+                "lm_head": P(None, self.axis)}
+
+    def init_params(self, key):
+        """Seeded parameters, made on the device a layer at a time."""
+        cfg = self.config
+        h = cfg.hidden_size
+        specs = self.param_specs()
+
+        def one_layer(k, kind):
+            return {"ln": jnp.ones((h,), self.dtype),
+                    "mixer": self._mixers[kind].init_params(k, self.dtype)}
+
+        def ends(k_embed, k_head):
+            normal = jax.random.normal
+            return {"embed": (normal(k_embed, (cfg.vocab_size, h))
+                              * h ** -0.5).astype(self.dtype),
+                    "ln_f": jnp.ones((h,), self.dtype),
+                    "lm_head": (normal(k_head, (h, cfg.vocab_size))
+                                * h ** -0.5).astype(self.dtype)}
+
+        keys = jax.random.split(key, cfg.num_layers + 2)
+        params = jax.jit(ends, out_shardings=self._named(
+            {k: specs[k] for k in ("embed", "ln_f", "lm_head")}))(
+                keys[-1], keys[-2])
+        make = {kind: jax.jit(
+            functools.partial(one_layer, kind=kind),
+            out_shardings=self._named(self._layer_specs(kind)))
+            for kind in set(self.pattern)}
+        params["layers"] = [make[kind](keys[i])
+                            for i, kind in enumerate(self.pattern)]
+        return params
+
+    # ------------------------------------------------------------------
+    # per-device forward bodies (called inside shard_map)
+    # ------------------------------------------------------------------
+
+    def _layer_fwd_prefill(self, x, lp, length, *, batch, kind):
+        """(x, what the layer leaves in the cache): (k, v), (state,
+        conv inputs) or nothing."""
+        h = rms_norm(x, lp["ln"], self.config.rms_norm_eps)
+        if kind == ATTN:
+            h, kept = self.attn.prefill(h, lp["mixer"], batch)
+        elif kind == SSM:
+            h, *kept = self.ssm.prefill(h, lp["mixer"], batch, length)
+        else:
+            h, _ = self.moe(h, lp["mixer"], phase="prefill")
+            kept = ()
+        return x + h, tuple(kept)
+
+    def _layer_fwd_decode(self, x, lp, kept, page_table, offset, live, *,
+                          kind):
+        """``kept``: the layer's (k pool, v pool), (state, conv) or
+        nothing.  Returns (x, kept, the expert layer's counters or
+        None)."""
+        h = rms_norm(x, lp["ln"], self.config.rms_norm_eps)
+        stats = None
+        if kind == ATTN:
+            h, kept, _ = self.attn.decode_paged(
+                h, lp["mixer"], kept, page_table, offset)
+        elif kind == SSM:
+            h, *kept = self.ssm.decode(h, lp["mixer"], *kept, live)
+        else:
+            h, stats = self.moe(h, lp["mixer"], phase="decode")
+        return x + h, tuple(kept), stats
+
+    def _per_layer(self, fn, **static):
+        """One jitted body for each KIND of layer (`Qwen3._per_layer`):
+        the loop over layers traces each kind once."""
+        return {kind: jax.jit(functools.partial(fn, kind=kind, **static))
+                for kind in set(self.pattern)}
+
+    def prefill_shard(self, params, input_ids, cache: Optional[KVCache]):
+        """input_ids: (B, S).  Returns (logits (B, V) float32 of each
+        sequence's last position, cache).  The state-space layers'
+        state absorbs ``cache.length`` tokens of each row (all S
+        without a cache)."""
+        cfg = self.config
+        b, s = input_ids.shape
+        length = (cache.length if cache is not None
+                  and cache.length is not None
+                  else jnp.full((b,), s, jnp.int32))
+        x = params["embed"][input_ids].reshape(b * s, -1)
+        layer = self._per_layer(self._layer_fwd_prefill, batch=b)
+        for li, (kind, lp) in enumerate(zip(self.pattern,
+                                            params["layers"])):
+            x, kept = layer[kind](x, lp, length)
+            if cache is None or kind == MOE:
+                continue
+            if kind == ATTN:
+                cache = cache.write_prefill(self._index[li], *kept)
+            else:
+                cache = cache.set_state(self._index[li], *kept)
+        x = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
+        logits = jnp.dot(x.reshape(b, s, -1)[:, -1], params["lm_head"],
+                         preferred_element_type=jnp.float32)
+        if cache is not None:
+            cache = cache.set_offset(s)
+        return logits, cache
+
+    def decode_shard(self, params, tokens, cache: PagedKVCache):
+        """One decode step.  tokens: (B,).  Returns (logits (B, V),
+        cache) — the cache's `stats` hold what the step counted
+        (`STATS`)."""
+        cfg = self.config
+        live = cache.live_rows
+        x = params["embed"][tokens]
+        layer = self._per_layer(self._layer_fwd_decode)
+        counted = []
+        for li, (kind, lp) in enumerate(zip(self.pattern,
+                                            params["layers"])):
+            i = self._index[li]
+            kept = ()
+            if kind == ATTN:
+                kept = (cache.ks[i], cache.vs[i])
+            elif kind == SSM:
+                kept = (cache.states[i], cache.convs[i])
+            x, kept, stats = layer[kind](
+                x, lp, kept, cache.page_table, cache.offset, live)
+            if kind == ATTN:
+                cache = cache.set_layer(i, *kept)
+            elif kind == SSM:
+                cache = cache.set_state(i, *kept)
+            else:
+                counted.append(stats)
+        x = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
+        logits = jnp.dot(x, params["lm_head"],
+                         preferred_element_type=jnp.float32)
+        if cache.stats is not None:
+            c = jnp.stack(counted)                      # (E layers, 4)
+            cache = dataclasses.replace(cache, stats=jnp.concatenate(
+                [c[:, :2].sum(axis=0), c[:, 2:3].max(axis=0),
+                 c[:, 3:].sum(axis=0),
+                 jnp.sum(live).astype(jnp.float32)[None]]))
+        return logits, cache.inc_offset(1)
+
+    # ------------------------------------------------------------------
+    # mesh-level entry points
+    # ------------------------------------------------------------------
+
+    def _state_specs(self):
+        if not self.num_ssm:     # a cut that kept no state-space layer
+            return {}
+        return dict(states=[P(None, None, None, None)] * self.num_ssm,
+                    convs=[P(None, None)] * self.num_ssm)
+
+    def _cache_specs(self):
+        pools = [P(None, None, None, None)] * self.num_attn
+        state = self._state_specs()
+        return KVCache(ks=pools, vs=pools, offset=P(None),
+                       length=P(None) if state else None, **state)
+
+    def _paged_cache_specs(self, page_size: int):
+        pools = [P(None, None, None, None)] * self.num_attn
+        return PagedKVCache(
+            ks=pools, vs=pools, page_table=P(None, None),
+            offset=P(None), stats=P(None), page_size=page_size,
+            **self._state_specs())
+
+    def make_prefill_fn(self):
+        return jax.shard_map(
+            self.prefill_shard, mesh=self.mesh,
+            in_specs=(self.param_specs(), P(None, None),
+                      self._cache_specs()),
+            out_specs=(P(None, self.axis), self._cache_specs()),
+            check_vma=False)
+
+    def make_paged_decode_fn(self, page_size: int = 16):
+        cspecs = self._paged_cache_specs(page_size)
+        return jax.shard_map(
+            self.decode_shard, mesh=self.mesh,
+            in_specs=(self.param_specs(), P(None), cspecs),
+            out_specs=(P(None, self.axis), cspecs),
+            check_vma=False)
+
+    def create_paged_cache(self, batch: int, num_pages: int,
+                           page_size: int, max_pages_per_seq: int):
+        cfg = self.config
+        make = functools.partial(
+            PagedKVCache.create, self.num_attn, num_pages, batch,
+            cfg.num_kv_heads, page_size, cfg.head_dim,
+            max_pages_per_seq, self.dtype, num_stats=len(self.STATS),
+            state_shapes=self._state_shapes)
+        return jax.jit(make, out_shardings=self._named(
+            self._paged_cache_specs(page_size)))()
+
+    def create_cache(self, batch: int, max_seq: Optional[int] = None):
+        """The single-row cache a bucketed prefill fills: the attention
+        layers' rows and the state-space layers' state; the dense-slot
+        decode layout is not built for this family."""
+        cfg = self.config
+        make = functools.partial(
+            KVCache.create, self.num_attn, batch, cfg.num_kv_heads,
+            max_seq or cfg.max_seq_len, cfg.head_dim, self.dtype,
+            state_shapes=self._state_shapes)
+        return jax.jit(make, out_shardings=self._named(
+            self._cache_specs()))()

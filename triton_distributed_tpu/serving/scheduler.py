@@ -98,7 +98,9 @@ offers a prefill of one chunk over the pages already in the pool
 attends the rows its predecessors put into the request's pages, and AT
 MOST ONE ENQUEUE — a chunk, or a short prompt's whole prefill — stands
 between two decode dispatches, so a running row's token gap holds a
-step and a chunk where it held a step and the longest prefill.  The
+step and a chunk where it held a step and the longest prefill.  (A
+model with recurrent layers is paced the same way — `_paced` — though
+it offers no chunk program yet: its whole prefill is the piece.)  The
 request's slot and pages are claimed at its first insert (at its
 first chunk that reads the pool, if that comes first) and the slot
 stays masked — its row of the page table NULL — until the last chunk
@@ -107,8 +109,8 @@ is under way at a time, first come first; with nothing running there
 is no gap to protect and its chunks are enqueued in one call.  Each
 chunk follows the rules of the paragraph above (`_front_fits`, the
 early read), is a prefill in the read's record, and is timed by the
-step's own sync.  A model without the program admits as ever, several
-prefills a call included.
+step's own sync.  A model without the program and without a recurrent
+state admits as ever, several prefills a call included.
 
 Every token gap is put down to what made it.  Each read leaves its
 record on its `serving.sync` span — the interval since the read before
@@ -423,8 +425,8 @@ class ContinuousBatchingScheduler:
         self._chunk = (int(getattr(model, "prefill_chunk", 0) or 0)
                        if self._prefill_suffix is not None else 0)
         #: The admission whose next chunk is due, and whether a prefill
-        #: was enqueued since the last decode dispatch (a model that
-        #: chunks gets one between two).
+        #: was enqueued since the last decode dispatch (paced
+        #: admissions — `_paced` — get one between two).
         self._underway: Optional[_Admission] = None
         self._spent = False
         #: The page ids of a first chunk that reads no row of the pool.
@@ -436,6 +438,13 @@ class ContinuousBatchingScheduler:
         #: recomputes its state through that prefill (no snapshot).
         self._stateful = bool(getattr(self.slots, "state_bytes_per_slot",
                                       0))
+        #: At most ONE prefill enqueue between two decode dispatches
+        #: while rows run: a model that chunks (a chunk is the piece),
+        #: and a model with recurrent layers, whose whole prefill is the
+        #: piece until it can start one from a carried state — its
+        #: short answers hand slots on every few steps, and a token gap
+        #: that held a step and two or three prefills holds one.
+        self._paced = bool(self._chunk or self._stateful)
         #: What the decode program leaves in the cache's `stats`, by
         #: name (`_moe_phase`).
         self._stats_names = getattr(model, "STATS", ())
@@ -982,10 +991,11 @@ class ContinuousBatchingScheduler:
 
     def _piece_due(self, now: float) -> bool:
         """A prefill may be enqueued now: an admission is under way, or
-        the queue's head can begin one — and, on a model that chunks,
-        none was enqueued since the last decode dispatch while anything
-        runs that would wait for a second one."""
-        if (self._chunk and self._spent
+        the queue's head can begin one — and, where admissions are
+        paced (`_paced`), none was enqueued since the last decode
+        dispatch while anything runs that would wait for a second
+        one."""
+        if (self._paced and self._spent
                 and (self._by_slot or self._flight is not None)):
             return False
         return self._underway is not None or self._head_ready(now)
