@@ -211,6 +211,9 @@ def test_scheduler_serves_it_and_counts(system):
     rng = np.random.default_rng(3)
     reqs = [Request(rng.integers(0, 256, n).tolist(), 20,
                     eos_token_ids=(), seed=0) for n in (9, 40, 17)]
+    # (the ring is the process's: another file's schedulers, run before
+    # this one by the same worker, leave `serving.moe` spans of theirs)
+    seen = {id(s) for s in get_tracer().finished()}
     for r in reqs:
         assert system.sched.submit(r)
     while system.sched.has_work():
@@ -226,7 +229,7 @@ def test_scheduler_serves_it_and_counts(system):
         assert gap.mean() < LOGIT_TOL / 2, gap
         assert (gap > LOGIT_TOL).sum() <= FLIPS, gap
     spans = [s for s in get_tracer().finished()
-             if s.name == "serving.moe"]
+             if s.name == "serving.moe" and id(s) not in seen]
     assert spans and all(
         s.attrs["pairs"] == 2 * 4 * 2 and s.attrs["experts_hit"] >= 8
         for s in spans)

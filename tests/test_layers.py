@@ -249,6 +249,66 @@ def test_tp_attn_decode(tp4_mesh, mode):
     assert float(jnp.abs(nk[0, :, 5] - k_cache[0, :, 5]).max()) > 0
 
 
+@pytest.mark.parametrize("mode", ["xla", "fused"])
+@pytest.mark.parametrize("form", ["rope", "nope_gated", "rope_qk_norm"])
+def test_tp_attn_prefill_suffix_over_the_pools_pages(devices, mode, form):
+    """A chunk of one sequence over the rows its predecessors left in
+    the page pool (`prefill_suffix`, one device) against the whole
+    prefill of the sequence: the chunk's output rows and its K/V.  The
+    pages lie out of order in the pool, the page ids past the chunk
+    name a page full of NaN (another owner's rows, the trash page), and
+    the chunk starts at each page-aligned position a scheduler gives it
+    — 0 (nothing below it), a middle one, and the last, whose chunk is
+    right-padded."""
+    hidden, heads, kv_heads, d, ps, chunk = 128, 8, 2, 16, 16, 32
+    attn = TPAttention(axis="tp", world_size=1, hidden=hidden,
+                       num_heads=heads, num_kv_heads=kv_heads, head_dim=d,
+                       rope=form != "nope_gated", gate=form == "nope_gated",
+                       qk_norm=form == "rope_qk_norm", mode=mode,
+                       gemm=MatmulConfig(32, 64, 128))
+    params = attn.init_params(jax.random.key(14), jnp.float32)
+    mesh = jax.sharding.Mesh(devices[:1], ("tp",))
+    s = 3 * chunk                   # 96 positions; the pool reaches 128
+    x = jax.random.normal(jax.random.key(15), (s, hidden)) / 8
+    rep = jax.tree_util.tree_map(lambda _: P(), params)
+    whole = jax.jit(shard_map_op(
+        lambda xx, pp: attn.prefill(xx, pp, batch=1), mesh,
+        in_specs=(P(), rep), out_specs=(P(), (P(), P()))))
+    out, (k, v) = whole(x, params)
+    pages = jnp.asarray([5, 2, 7, 0, 3, 6, 9, 9], jnp.int32)  # 9: trash
+    suffix = jax.jit(shard_map_op(
+        lambda xx, pp, at, kp, vp: attn.prefill_suffix(
+            xx, pp, at, (kp, vp), pages), mesh,
+        in_specs=(P(), rep, P(), P(), P()),
+        out_specs=(P(), (P(), P()))))
+
+    def pool_of(rows, upto):
+        """(10 pages, Hkv, 16, D): the sequence's rows below ``upto``
+        at their pages, NaN everywhere else."""
+        pool = jnp.full((10, kv_heads, ps, d), jnp.nan, jnp.float32)
+        for j in range(upto // ps):
+            pool = pool.at[pages[j]].set(rows[0, :, j * ps:(j + 1) * ps])
+        return pool
+
+    for at in (0, chunk, 2 * chunk):
+        got, (ck, cv) = suffix(x[at:at + chunk], params, jnp.int32(at),
+                               pool_of(k, at), pool_of(v, at))
+        assert bool(jnp.isfinite(got).all())
+        assert_allclose(got, out[at:at + chunk], atol=2e-3, rtol=2e-3,
+                        name=f"suffix-{mode}-{form}-at-{at}")
+        assert_allclose(ck, k[:, :, at:at + chunk], atol=1e-5, rtol=1e-5,
+                        name="chunk-k")
+        assert_allclose(cv, v[:, :, at:at + chunk], atol=1e-5, rtol=1e-5,
+                        name="chunk-v")
+    # a right-padded last chunk: the rows that count are the prompt's
+    tail = jnp.concatenate([x[2 * chunk:2 * chunk + 5],
+                            jnp.zeros((chunk - 5, hidden))])
+    got, _ = suffix(tail, params, jnp.int32(2 * chunk),
+                    pool_of(k, 2 * chunk), pool_of(v, 2 * chunk))
+    assert_allclose(got[:5], out[2 * chunk:2 * chunk + 5], atol=2e-3,
+                    rtol=2e-3, name="padded-last-chunk")
+
+
 def test_ep_a2a_layer(ep4_mesh):
     ep, E, topk, n_loc, hidden, cap = 4, 8, 2, 8, 64, 32
     layer = EPAll2AllLayer(axis="ep", ep_size=ep, num_experts=E,

@@ -37,7 +37,7 @@ from cellbench.adapters import nemotron_h as adapter
 from cellbench.references import nemotron_h as reference
 from triton_distributed_tpu.kernels import mamba2, moe_utils
 from triton_distributed_tpu.layers.moe_mlp import HELD_STATS, SparseMoE
-from triton_distributed_tpu.models import AutoLLM, ModelConfig
+from triton_distributed_tpu.models import AutoLLM, ModelConfig, nemotron_h
 from triton_distributed_tpu.models.nemotron_h import NemotronH
 from triton_distributed_tpu.serving.engine_batched import (
     pad_prompt, pick_bucket)
@@ -178,6 +178,103 @@ def test_float8_control_fails_the_tolerance(decoded):
     assert (err > LOGIT_TOL).all() and np.median(err) > 2 * LOGIT_TOL
 
 
+#: Tokens a chunk of the tests below: the 21-token prompt goes in two
+#: pieces, the 50-token one in four — the last two tokens long, of
+#: which the state absorbs ONE.
+CHUNK = 16
+
+
+@pytest.fixture(scope="module")
+def decoded_in_chunks(system, decoded):
+    """`decoded`'s two prompts prefilled by suffix calls — each piece
+    over the pages its predecessors filled, from the state and tail
+    they returned — then the same teacher-forced decode steps."""
+    model, params = system.model, system.params
+    prompts, teacher, steps, *_ = decoded
+    slots = PagedKV(model, len(prompts), max_seq=128, page_size=16,
+                    prefix_cache=False)
+    suffix = jax.jit(model.make_prefill_suffix_fn())
+    decode = jax.jit(model.make_paged_decode_fn(page_size=16))
+    rows = []
+    for p in prompts:
+        s = len(p)
+        slot = slots.begin_prefill(s, [])
+        row = model.create_cache(1, CHUNK)
+        for at in range(0, s, CHUNK):
+            ids, _ = pad_prompt(p[at:at + CHUNK], CHUNK)
+            row = suffix(
+                params, ids, jnp.int32(at), dataclasses.replace(
+                    row, length=np.full(
+                        (1,), min(max(s - 1 - at, 0), CHUNK), np.int32)),
+                (slots.cache.ks, slots.cache.vs),
+                slots.prefill_pages(slot))
+            last = at + CHUNK >= s
+            slots.insert_rows(slot, row, at, *(
+                [jnp.zeros((2,), jnp.uint32)] if last else []))
+        slots.finish_prefill(slot, p)
+        rows.append(row)
+    got = []
+    tokens = np.asarray([p[-1] for p in prompts], np.int32)
+    for i in range(steps):
+        for b, p in enumerate(prompts):
+            assert slots.ensure(b, len(p) + i)
+        slots.flush()
+        logits, slots.cache = decode(params, jnp.asarray(tokens),
+                                     slots.cache)
+        got.append(np.asarray(logits))
+        tokens = np.asarray([t[i] for t in teacher], np.int32)
+    return np.stack(got), rows
+
+
+@pytest.mark.parametrize("row", [0, 1])
+def test_a_prompt_prefilled_in_chunks_decodes_like_the_whole_prefill(
+        system, decoded, decoded_in_chunks, row):
+    """State, tail and K/V rows after the last chunk are the whole
+    prefill's (the chunks' kernels cut the sequence elsewhere: float32
+    rounding), and the decode steps behind them give the whole
+    prefill's logits and the float32 reference's within the file's
+    tolerance."""
+    prompts, teacher, steps, whole, _ = decoded
+    got, rows = decoded_in_chunks
+    p = prompts[row]
+    ids, s = pad_prompt(p, 64)
+    _, want = jax.jit(system.model.make_prefill_fn())(
+        system.params, ids, _row_for(system.model, 64, s - 1))
+    for a, b in zip(rows[row].states, want.states):
+        assert _close(a, b, 2e-2), float(jnp.abs(a - b).max())
+    for a, b in zip(rows[row].convs, want.convs):
+        assert float(jnp.abs(a.astype(jnp.float32)
+                             - b.astype(jnp.float32)).max()) < 0.1
+    err = np.abs(got[:, row] - whole[:, row]).max(axis=1)
+    assert np.median(err) < LOGIT_TOL / 2, err
+    assert (err > LOGIT_TOL).sum() <= FLIPS, err
+    seq = p + teacher[row][:steps - 1]
+    ref = _ref_logits(seq, len(p) - 1, steps)
+    err = np.abs(got[:, row] - ref).max(axis=1)
+    assert np.median(err) < LOGIT_TOL / 2, err
+    assert (err > LOGIT_TOL).sum() <= FLIPS, err
+
+
+def test_the_chunk_program_names_the_prefills_kernels(system):
+    """A device trace reads a chunk as a prefill: the program's name
+    starts like the whole prefill's and its kernels are the prefill's;
+    no logits, so the head is not in it."""
+    model = system.model
+    fn = jax.jit(model.make_prefill_suffix_fn())
+    cache = system.sched.slots.cache
+    args = (system.params, jnp.zeros((1, 32), jnp.int32), jnp.int32(32),
+            model.create_cache(1, 32), (cache.ks, cache.vs),
+            jnp.zeros((8,), jnp.int32))
+    assert fn.lower(*args).as_text().splitlines()[0].startswith(
+        "module @jit_prefill_shard")
+    text = str(jax.make_jaxpr(fn)(*args))
+    for name in ("mamba2_prefill_chunk", "moe_prefill_relu2_up",
+                 "moe_prefill_relu2_down", "flash_attention_fwd"):
+        assert name in text
+    assert "mamba2_decode_step" not in text
+    assert " cos " not in text and " sin " not in text
+
+
 def test_decode_leaves_its_counts_in_the_cache(decoded):
     """`PagedKVCache.stats` after a step, in `NemotronH.STATS` order:
     the HELD experts' pairs and those routed elsewhere add up to rows x
@@ -297,6 +394,80 @@ def test_a_padded_row_moves_neither_state_nor_tail(system):
     assert (c == c_ref).all()
     _, s77, c77 = golden.prefill(x[:77], p, 1, n)
     assert _close(s, s77) and (c == c77).all()
+
+
+def test_the_chunked_kernel_starts_from_a_carried_state():
+    """From a state that is not zero: outputs and final state are the
+    recurrence's from that state; and cut in two calls, the second
+    starting from what the first returned, the kernel gives bit for bit
+    what it gives in one."""
+    c = mamba2.CHUNK
+    x, dt, a, bm, cm = _ssm_inputs(2 * c, seed=4)
+    s0 = jax.random.normal(jax.random.key(11), (2, H, P, N))
+    y_ref, s_ref = _recurrence(x, dt, a, bm, cm, s0)
+    y, s = mamba2.mamba2_prefill_chunk(x, dt, a, bm, cm,
+                                       mamba2.pair_state(s0))
+    assert _close(y, y_ref) and _close(mamba2.unpair_state(s), s_ref)
+    y1, s1 = mamba2.mamba2_prefill_chunk(
+        x[:, :c], dt[:, :c], a, bm[:, :c], cm[:, :c],
+        mamba2.pair_state(s0))
+    y2, s2 = mamba2.mamba2_prefill_chunk(
+        x[:, c:], dt[:, c:], a, bm[:, c:], cm[:, c:], s1)
+    assert (jnp.concatenate([y1, y2], axis=1) == y).all()
+    assert (s2 == s).all()
+    # dt = 0 everywhere: the state comes back as it went in
+    _, same = mamba2.mamba2_prefill_chunk(x[:, :c], 0 * dt[:, :c], a,
+                                          bm[:, :c], cm[:, :c], s1)
+    assert (same == s1).all()
+
+
+#: (tokens a piece, the prompt's tokens): the state absorbs all but the
+#: prompt's last token, so the last piece absorbs 35 of 64 (the rest a
+#: padded tail); 33 of 48 in three pieces; 63 (a last piece that is
+#: full); then fewer than the convolution's 3 kept inputs — 2, 1 and 0.
+PIECES = {"two": (64, 100), "three": (48, 130), "full": (64, 128),
+          "absorbs_2": (64, 67), "absorbs_1": (64, 66),
+          "absorbs_0": (64, 65)}
+
+
+@pytest.mark.parametrize("mode", ["fused", "xla"])
+@pytest.mark.parametrize("case", sorted(PIECES))
+def test_a_prefill_in_pieces_equals_the_prefill_in_one(system, case, mode):
+    """`Mamba2Mixer.prefill` piece by piece — each from the state and
+    the convolution's tail the one before it returned — against one
+    prefill over the same rows: output, state and tail.  A last piece
+    that absorbs fewer tokens than the convolution keeps hands on
+    inputs of the piece BEFORE it."""
+    size, t = PIECES[case]
+    layer = dataclasses.replace(system.model.ssm, mode=mode)
+    p = system.params["layers"][0]["mixer"]
+    rows = -(-t // size) * size
+    x = jax.random.normal(jax.random.key(t), (rows, 128)).astype(
+        jnp.bfloat16)
+    n = t - 1
+    y_ref, s_ref, c_ref = layer.prefill(x, p, 1,
+                                        jnp.asarray([n], jnp.int32))
+    ys, kept = [], ()
+    for at in range(0, rows, size):
+        took = jnp.asarray([min(max(n - at, 0), size)], jnp.int32)
+        y, *kept = layer.prefill(x[at:at + size], p, 1, took, *kept)
+        ys.append(y)
+        if at >= n:                   # absorbed nothing: handed on
+            assert (kept[0] == before[0]).all()
+            assert (kept[1] == before[1]).all()
+        before = kept
+    s, c = kept
+    assert _close(s, s_ref)
+    assert (c == c_ref).all()
+    err = jnp.abs(jnp.concatenate(ys).astype(jnp.float32)
+                  - y_ref.astype(jnp.float32))
+    assert float(err.max()) < 2e-2, float(err.max())
+    # and the tail is the inputs at positions n-3 .. n-1, which for the
+    # short last pieces lie in the piece before
+    want = layer._split(jnp.dot(
+        x, p["w_in"], preferred_element_type=jnp.float32).astype(
+            x.dtype))[1][n - 3:n]
+    assert (c.reshape(3, -1) == want).all()
 
 
 def test_a_prefill_that_continues_into_decode():
@@ -486,17 +657,18 @@ def test_churn_hands_a_zeroed_state_row_to_the_next_owner(system):
 
 
 def test_admissions_are_paced_while_rows_run(system):
-    """A model with recurrent layers offers no chunk program, so its
-    whole prefill is the piece: with rows running at most ONE prefill
-    is enqueued between two decode dispatches: three requests due at
-    once go in a call apart, and so does whatever follows the first
-    admission into an idle server."""
+    """A model with recurrent layers is paced: with rows running at
+    most ONE prefill — here a short prompt's whole prefill, the chunk
+    being 512 tokens — is enqueued between two decode dispatches:
+    three requests due at once go in a call apart, and so does whatever
+    follows the first admission into an idle server."""
     from triton_distributed_tpu.serving import (
         ContinuousBatchingScheduler, Request, SchedulerConfig)
     sched = ContinuousBatchingScheduler(
         system.model, system.params, SchedulerConfig(
             num_slots=4, max_seq=128, kv_layout="paged"))
-    assert sched._paced and not sched._chunk
+    assert sched._paced and sched._stateful
+    assert sched._chunk == nemotron_h.PREFILL_CHUNK == 512
     rng = np.random.default_rng(31)
 
     def send(n, new):
@@ -518,6 +690,242 @@ def test_admissions_are_paced_while_rows_run(system):
     assert [sched.step()["admitted"] for _ in range(3)] == [1, 1, 1]
     while sched.has_work():
         sched.step()
+
+
+# ---------------------------------------------------------------------------
+# a long prompt goes in by chunks that start from the carried state
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def chunking(devices):
+    """The adapter's system with the model's chunk at test size: the
+    model reads `PREFILL_CHUNK` when it is built."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(nemotron_h, "PREFILL_CHUNK", CHUNK)
+        yield adapter.System(TINY, SEED, devices[:1])
+
+
+def _chunk_sched(chunking, **kw):
+    from triton_distributed_tpu.serving import (
+        ContinuousBatchingScheduler, SchedulerConfig)
+    kw.setdefault("num_slots", 4)
+    sched = ContinuousBatchingScheduler(
+        chunking.model, chunking.params, SchedulerConfig(
+            max_seq=128, kv_layout="paged", **kw))
+    assert sched._chunk == CHUNK and sched._stateful and sched._paced
+    return sched
+
+
+class Enqueues:
+    """Every enqueue of the scheduler's programs, in order: ("chunk",
+    start, tokens the state absorbs, the row cache that went in, the
+    row that came out), ("prefill",), ("step", rows running)."""
+
+    def __init__(self, sched):
+        self.log = []
+        suffix, prefill, step = (sched._prefill_suffix, sched._prefill,
+                                 sched._step)
+
+        def chunk(p, ids, start, row, pools, pages):
+            assert ids.shape == (1, CHUNK)
+            out = suffix(p, ids, start, row, pools, pages)
+            self.log.append(("chunk", int(start), int(row.length[0]),
+                             row, out))
+            return out
+
+        def whole(*a):
+            self.log.append(("prefill",))
+            return prefill(*a)
+
+        def stepping(p, tokens, cache, keys, active):
+            self.log.append(("step", int(np.sum(active))))
+            return step(p, tokens, cache, keys, active)
+        sched._prefill_suffix, sched._prefill = chunk, whole
+        sched._step = stepping
+
+    def chunks(self):
+        return [ev for ev in self.log if ev[0] == "chunk"]
+
+
+def _request(prompt, new):
+    from triton_distributed_tpu.serving import Request
+    return Request(list(prompt), new, eos_token_ids=(), seed=0)
+
+
+def _scored(rows, limits=None):
+    """What was served against the reference, as the churn test."""
+    sample = [{"index": i, "prompt": r.prompt, "prompt_len": len(r.prompt),
+               "tokens": list(r.generated), "ok": True}
+              for i, r in enumerate(rows)]
+    res = correctness.score(reference, DIMS, SEED, sample, 128, 16,
+                            control=True)
+    limits = limits or {"served_gap_max": 0.2, "served_gap_mean": 0.01}
+    assert correctness.judge(res["program"], limits)[0], res
+    assert not correctness.judge(res["control"], limits)[0], res
+
+
+def test_chunks_start_at_zero_despite_a_prefix_hit_and_carry_the_state(
+        chunking):
+    """Two prompts that share their first 32 tokens, one after the
+    other.  The second finds two pages in the radix tree and shares
+    them — for storage: its chunks still cover the prompt from position
+    0 (a state has no snapshot; the tokens a snapshot would have saved
+    are counted), each starts from the state and tail the one before it
+    returned, the reusable zero row is never written, and what is
+    served is the reference's."""
+    from triton_distributed_tpu.observability import get_registry
+    reg = get_registry()
+    reg.clear()
+    rng = np.random.default_rng(41)
+    shared = rng.integers(0, 256, 32).tolist()
+    prompts = [shared + rng.integers(0, 256, n).tolist() for n in (18, 9)]
+    sched = _chunk_sched(chunking)
+    seen = Enqueues(sched)
+    served = []
+    for p in prompts:
+        req = _request(p, 6)
+        sched.run([req])
+        served.append(req)
+    a, b = seen.chunks()[:4], seen.chunks()[4:]
+    assert [ev[1] for ev in a] == [0, 16, 32, 48]          # 50 tokens
+    assert [ev[1] for ev in b] == [0, 16, 32]              # 41, hit 32
+    # all but the prompt's last token, never a padded tail
+    assert [ev[2] for ev in a] == [16, 16, 16, 1]
+    assert [ev[2] for ev in b] == [16, 16, 8]
+    zero = sched._row_cache(CHUNK)
+    for pieces in (a, b):
+        assert pieces[0][3].states[0] is zero.states[0]
+        for before, after in zip(pieces, pieces[1:]):
+            for kind in ("states", "convs"):
+                for x, y in zip(getattr(before[4], kind),
+                                getattr(after[3], kind)):
+                    assert x is y
+    assert all(not np.asarray(x).any() for x in zero.states + zero.convs)
+    assert sum(ev == ("prefill",) for ev in seen.log) == 0
+    # the hit shared its pages and saved no compute
+    snap = reg.snapshot()["counters"]
+    assert snap["serving_state_recomputed_tokens_total"] == 32
+    assert snap["serving_prefix_cache_hit_tokens_total"] == 32
+    assert snap["serving_prefill_chunks_total"] == 7
+    assert sched.slots.cached_prefix_pages == 3 + 0   # 49 // 16, 40 // 16
+    reg.clear()
+    _scored(served)
+
+
+def test_a_prefix_hit_on_a_short_prompt_keeps_the_whole_prefill(chunking):
+    """At most a chunk long: today's whole prefill, from position 0 —
+    the suffix branch stays closed to a model with a state."""
+    rng = np.random.default_rng(43)
+    prompt = rng.integers(0, 256, 16).tolist()
+    sched = _chunk_sched(chunking)
+    seen = Enqueues(sched)
+    plans = []
+    plan = sched._plan
+    sched._plan = lambda *a: plans.append(plan(*a)) or plans[-1]
+    served = [_request(prompt[:n], 4) for n in (16, 14)]
+    for req in served:
+        sched.run([req])
+    assert [(adm.mode, adm.pieces, len(adm.shared)) for adm in plans] == [
+        ("local", [(0, 16)], 0), ("local", [(0, 16)], 0)]
+    # (a page is shared once a token lies beyond it)
+    late = _request(prompt + [5], 3)
+    sched.run([late])
+    assert (plans[-1].mode, plans[-1].pieces, len(plans[-1].shared)) == (
+        "chunk", [(0, 16), (16, 16)], 0)
+    assert not seen.chunks()[-1][2]      # 17 tokens: the last absorbs 0
+    _scored(served + [late])
+
+
+def test_one_enqueue_between_two_decode_dispatches_while_rows_run(
+        chunking):
+    """Long and short prompts queued behind a running row: a chunk or
+    a short prompt's whole prefill a decode dispatch, first come first;
+    the running row gets its token every call, and a slot in
+    mid-prefill is masked — the steps in between leave its state row
+    as its release left it."""
+    rng = np.random.default_rng(47)
+    sched = _chunk_sched(chunking)
+    runner = _request(rng.integers(0, 256, 9).tolist(), 30)
+    sched.submit(runner)
+    sched.step()
+    sched.step()
+    seen = Enqueues(sched)
+    late = [_request(rng.integers(0, 256, n).tolist(), 3)
+            for n in (50, 7, 33)]
+    for r in late:
+        sched.submit(r)
+    mid = 0
+    while any(r.finish_reason is None for r in late):
+        before = len(runner.generated)
+        sched.step()
+        assert len(runner.generated) == before + 1
+        adm = sched._underway
+        if adm is not None and adm.slot is not None:
+            mid += 1
+            cache = sched.slots.cache
+            assert all(not np.asarray(x[adm.slot]).any()
+                       for x in cache.states + cache.convs)
+            assert adm.carry is not None
+    assert mid >= 4
+    between, n = [], 0
+    for ev in seen.log:
+        if ev[0] == "step":
+            between.append(n)
+            n = 0
+        else:
+            n += 1
+    assert max(between) == 1
+    assert [ev[:2] for ev in seen.log if ev[0] != "step"] == (
+        [("chunk", at) for at in (0, 16, 32, 48)] + [("prefill",)]
+        + [("chunk", at) for at in (0, 16, 32)])
+    sched.drain()
+    assert len(runner.generated) == 30
+    _scored(late)
+
+
+def test_giving_up_in_mid_prefill_returns_slot_pages_and_carry(chunking):
+    rng = np.random.default_rng(53)
+    sched = _chunk_sched(chunking, prefix_cache=False)
+    runner = _request(rng.integers(0, 256, 15).tolist(), 12)
+    long = _request(rng.integers(0, 256, 50).tolist(), 4)
+    sched.submit(runner)
+    sched.step()
+    sched.submit(long)
+    sched.step()
+    sched.step()
+    adm = sched._underway
+    assert adm is not None and adm.req is long and adm.done == 2
+    assert adm.carry is not None and adm.slot is not None
+    held = sched.slots.used_pages       # the runner's and 4 of 50 tokens
+    assert sched.slots.free_slots == 2
+    resets = sched.slots.state_resets
+    sched._give_up_underway()
+    assert sched._underway is None and adm.carry is None
+    assert sched.slots.used_pages == held - 4
+    assert sched.slots.free_slots == 3
+    assert sched.slots.state_resets == resets + 1
+    assert sched._queue[0] is long
+    # and it starts over, from position 0 and a zero state
+    seen = Enqueues(sched)
+    sched.drain()
+    assert [ev[1] for ev in seen.chunks()] == [0, 16, 32, 48]
+    assert seen.chunks()[0][3].states[0] is sched._row_cache(
+        CHUNK).states[0]
+    assert long.finish_reason == runner.finish_reason
+    assert len(long.generated) == 4 and long.preemptions == 0
+    _scored([runner, long])
+
+
+def test_no_kind_of_chunk_argument_is_first_met_after_warm_up(
+        devices, monkeypatch):
+    """`tests/test_serving_pipeline.py`'s case for a model whose chunks
+    carry a recurrent state: the benchmark's own `warm_up` meets every
+    kind of argument of the chunk program — the zero row and a carried
+    one, no pages and the slot's — and of the scatter and the insert
+    behind it; a window's chunked admissions then compile nothing."""
+    from tests import test_serving_pipeline as pipeline
+    pipeline.chunk_arguments_are_met_in_warm_up(
+        "nemotron_h", devices, pipeline.Compiled(), monkeypatch)
 
 
 def test_a_reset_slot_starts_from_zero(system):

@@ -1038,6 +1038,55 @@ def test_a_prefix_hit_starts_the_first_chunk_at_the_matched_length(toys):
     assert starts == [0, 16, 32, 48, 24, 40]
 
 
+#: (tokens behind a 24-token prefix another request left in the tree,
+#: the plan of the second request): more than a chunk is left behind
+#: the three matched pages / at most a chunk is / the prefix is no
+#: other request's (a miss).
+PLANS = {"chunks_behind_the_match": (30, "chunk", [(24, 16), (40, 16)]),
+         "suffix_behind_the_match": (9, "suffix", [(24, 16)]),
+         "a_miss_in_chunks": (None, "chunk", [(0, 16), (16, 16),
+                                              (32, 16)])}
+
+
+@pytest.mark.parametrize("case", sorted(PLANS))
+def test_a_model_without_a_state_plans_from_the_match_and_carries_nothing(
+        toys, case):
+    """What PR 43 put behind `_stateful` (a model with a recurrent
+    state plans its chunks from position 0 and hands the state from
+    chunk to chunk) leaves a model without one where it was: its
+    pieces begin at the matched length, a hit that leaves at most a
+    chunk is a suffix prefill, no admission carries anything, nothing
+    counts as recomputed, and the streams are the unchunked model's."""
+    model, plain, params = toys
+    tail, mode, pieces = PLANS[case]
+    shared = long_prompts([24], seed=11)[0]
+    first = shared + long_prompts([20], seed=12)[0]
+    second = (shared + long_prompts([tail], seed=13)[0] if tail
+              else long_prompts([40], seed=14)[0])
+
+    def serve(m, plans=None):
+        sched = chunk_sched(m, params)
+        assert not sched._stateful
+        if plans is not None:
+            plan = sched._plan
+            sched._plan = lambda *a: plans.append(plan(*a)) or plans[-1]
+        out = []
+        for p in (first, second):       # one after the other: a hit
+            r = Request(prompt=p, max_new_tokens=5)
+            sched.submit(r)
+            while sched.has_work():
+                sched.step()
+                adm = sched._underway
+                assert adm is None or adm.carry is None
+            out.append(r.generated)
+        assert sched._state_recomputed == 0
+        return out
+    plans = []
+    assert serve(model, plans) == serve(plain)
+    assert (plans[1].mode, plans[1].pieces) == (mode, pieces)
+    assert all(adm.carry is None for adm in plans)
+
+
 @pytest.mark.parametrize("how", ["stop", "pool_dry"])
 def test_giving_up_in_mid_prefill_returns_every_page_and_the_slot(
         toys, how):

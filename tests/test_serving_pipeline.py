@@ -23,6 +23,7 @@ a preemption, an admission beside a retirement); the counters, the
 `inflight=` attribute and the span tree say what happened.
 """
 
+import importlib
 import json
 import os
 import sys
@@ -370,25 +371,36 @@ def test_no_program_and_no_kind_of_argument_is_first_met_after_warm_up(
         assert len(handle.generated) == new
 
 
-#: The chunk of the two models that prefill a long prompt in chunks, at
+#: The chunk of the models that prefill a long prompt in chunks, at
 #: test size: the warm-up's buckets 16 / 32 / 64 are then a whole
 #: prefill, two chunks, and four with the last one padded — as 1024 /
-#: 2048 / 4000 tokens are on `glm-4.7-flash-1c.agent-closed`.
+#: 2048 / 4000 tokens are on `glm-4.7-flash-1c.agent-closed` (and 512 /
+#: 1024 / 2000 on `nemotron-3-super-120b-1c.chat-closed`, whose chunks
+#: carry a recurrent state from one to the next).
 CHUNK = 16
 
 
 def _chunked_system(family, devices, monkeypatch):
     if family == "toy":
         return ToySystem(prefill_chunk=CHUNK)
-    from triton_distributed_tpu.models import glm4_moe_lite
-    monkeypatch.setattr(glm4_moe_lite, "PREFILL_CHUNK", CHUNK)
-    return _glm_system(devices)
+    monkeypatch.setattr(importlib.import_module(
+        f"triton_distributed_tpu.models.{family}"), "PREFILL_CHUNK", CHUNK)
+    return SYSTEMS[family](devices)
 
 
 @pytest.mark.parametrize("family", ["toy", "glm4_moe_lite"])
 def test_no_kind_of_chunk_argument_is_first_met_after_warm_up(
         family, devices, compiled, monkeypatch):
-    """The benchmark's own `warm_up`, as it stands, on a model that
+    chunk_arguments_are_met_in_warm_up(family, devices, compiled,
+                                       monkeypatch)
+
+
+def chunk_arguments_are_met_in_warm_up(family, devices, compiled,
+                                       monkeypatch):
+    """(`nemotron_h`'s case stands in `tests/test_nemotron_h.py`: this
+    file is the last a worker takes up, and what it holds is what the
+    whole run waits for.)
+    The benchmark's own `warm_up`, as it stands, on a model that
     prefills in chunks; then, under the compile listener, a window's
     chunked admissions: a chunk behind a step in flight, behind an
     insert (nothing running: the chunks of one prompt in one call),

@@ -118,6 +118,112 @@ def test_prefill_then_decode_through_state_tail_and_pages(system):
     assert all(all(b[1:]) for b in bad), bad
 
 
+def test_a_long_prompt_in_chunks_against_the_same_prompt_whole(system):
+    """A 2000-token prompt prefilled in the model's chunks (three of
+    512 and a right-padded fourth: each over the pages the ones before
+    it filled, from the state and the convolution's tail the last of
+    them returned) beside the same
+    prompt through the 2048 bucket, then 24 decode steps of both slots
+    in one batch.  The FIRST layer's state and tail are the whole
+    prefill's bit for bit (the kernel cuts the sequence at the same 128
+    tokens and carries float32; my chip run, PR 43: 0.0 and 0.0); from
+    the first expert layer on the two programs round differently (512
+    rows a program against 2048: the second state-space layer's state
+    read 2.1e-4 of its largest apart, its tail 0) and a token's routing
+    may flip, as between program and reference: printed, and held only
+    to the same order of magnitude.  The
+    chunked slot's logits are held to the reference by the file's
+    tolerance, as the whole prefill's are, the float8 control stays
+    outside it (its median and its count of positions past
+    `LOGIT_TOL`: one of this prompt's 24 reads 0.676), and chunked
+    against whole — an order of magnitude closer than either to the
+    reference: a median 0.034 of the spread — is held to a quarter of
+    the tolerance."""
+    cfg, sysm = system
+    model, params = sysm.model, sysm.params
+    dims = reference.dims_of(cfg)
+    rng = np.random.default_rng(43)
+    p = rng.integers(0, cfg["vocab_size"], 2000).tolist()
+    steps = 24
+    teacher = rng.integers(0, cfg["vocab_size"], steps).tolist()
+    chunk = model.prefill_chunk
+    assert chunk and len(p) > chunk
+    slots = PagedKV(model, 2, max_seq=sysm.max_seq, page_size=16,
+                    prefix_cache=False)
+    key = jnp.zeros((2,), jnp.uint32)
+    bucket = pick_bucket(len(p), sysm.buckets)
+    ids, s = pad_prompt(p, bucket)
+    _, whole = jax.jit(model.make_prefill_fn())(
+        params, ids, dataclasses.replace(
+            model.create_cache(1, bucket),
+            length=np.full((1,), s - 1, np.int32)))
+    assert slots.insert_prefill(whole, p, s, key, []) == 0
+    suffix = jax.jit(model.make_prefill_suffix_fn())
+    slot = slots.begin_prefill(s, [])
+    row = model.create_cache(1, chunk)
+    for at in range(0, s, chunk):
+        ids, _ = pad_prompt(p[at:at + chunk], chunk)
+        row = suffix(
+            params, ids, jnp.int32(at), dataclasses.replace(
+                row, length=np.full(
+                    (1,), min(max(s - 1 - at, 0), chunk), np.int32)),
+            (slots.cache.ks, slots.cache.vs), slots.prefill_pages(slot))
+        slots.insert_rows(slot, row, at,
+                          *([key] if at + chunk >= s else []))
+    slots.finish_prefill(slot, p)
+    for li, kind in enumerate(model.pattern):
+        if kind != "M":
+            continue
+        i = model._index[li]
+        a, b = row.states[i], whole.states[i]
+        es = float(jnp.abs(a - b).max() / jnp.abs(b).max())
+        ca, cb = (x.convs[i].astype(jnp.float32) for x in (row, whole))
+        ec = float(jnp.abs(ca - cb).max() / jnp.abs(cb).max())
+        print(f"layer {li}: state off by {es:.2e} of its largest, tail "
+              f"by {ec:.2e}")
+        assert max(es, ec) < (1e-6 if li == 0 else 0.25), (li, es, ec)
+    decode = jax.jit(model.make_paged_decode_fn(page_size=16))
+    got = []
+    tokens = np.asarray([p[-1]] * 2, np.int32)
+    for i in range(steps):
+        for b in range(2):
+            assert slots.ensure(b, len(p) + i)
+        slots.flush()
+        logits, slots.cache = decode(params, jnp.asarray(tokens),
+                                     slots.cache)
+        got.append(np.asarray(logits))
+        tokens = np.asarray([teacher[i]] * 2, np.int32)
+    got = np.stack(got)
+    seq = np.zeros(2048, np.int64)
+    full = p + teacher[:steps - 1]
+    seq[:len(full)] = full
+    ref = np.asarray(reference.logits_at(dims, SEED, seq, len(p) - 1,
+                                         steps))
+    low = np.asarray(reference.logits_at(dims, SEED, seq, len(p) - 1,
+                                         steps, precision="fp8"))
+    spread = ref.std(axis=1, keepdims=True)
+    ctl = (np.abs(low - ref) / spread).max(axis=1)
+    apart = (np.abs(got[:, 1] - got[:, 0]) / spread).max(axis=1)
+    print(f"chunked against whole: median {np.median(apart):.4f} max "
+          f"{apart.max():.4f} of the spread, first step {apart[0]:.4f}; "
+          f"float8 control median {np.median(ctl):.4f} min "
+          f"{ctl.min():.4f}")
+    # the control fails BOTH halves of the tolerance the program is
+    # held to (this prompt's reads a median 1.15 with 23 of 24 past
+    # 0.7 and one position at 0.676: my chip run, PR 43)
+    assert np.median(ctl) > MEDIAN_TOL, ctl
+    assert (ctl > LOGIT_TOL).sum() > 2 * FLIPS, ctl
+    for b, name in enumerate(("whole", "chunked")):
+        err = (np.abs(got[:, b] - ref) / spread).max(axis=1)
+        print(f"{name}: worst logit off by median {np.median(err):.4f} "
+              f"max {err.max():.4f} of the spread, first step "
+              f"{err[0]:.4f}, {int((err > LOGIT_TOL).sum())} of {steps} "
+              f"past {LOGIT_TOL}")
+        assert np.median(err) < MEDIAN_TOL, (name, err)
+        assert (err > LOGIT_TOL).sum() <= FLIPS, (name, err)
+    assert np.median(apart) < MEDIAN_TOL / 4 and apart[0] < LOGIT_TOL, apart
+
+
 def _inputs(b, t, seed=0):
     ks = jax.random.split(jax.random.key(seed), 5)
     x = jax.random.normal(ks[0], (b, t, H * P))

@@ -318,19 +318,12 @@ def test_no_state_sized_copy_in_step_insert_or_reset(topo_devices):
             assert len(found["staged"]) <= STAGED_MAX, (name, what, found)
 
 
-def test_no_state_sized_copy_in_the_state_space_step(topo_devices):
-    """The state-space hybrid (`models/nemotron_h.py`) at the cell's own
-    widths, five layers with every kind among them (`MEM*E`): a decode
-    step rewrites every live slot's Mamba-2 state —
-    4 MB a slot a layer, two heads side by side — through a kernel that
-    aliases the pool; the insert and the reset write one slot's rows.
-    None of the three programs may copy the state pool or the attention
-    layer's page pools, and the step names its kernels."""
-    from triton_distributed_tpu.models.kv_cache import zero_state_rows
+def _nemotron(devices, pattern="MEM*E"):
+    """The state-space hybrid at the cell's own widths, five layers
+    with every kind among them."""
     from triton_distributed_tpu.models.nemotron_h import NemotronH
 
     c = _config("nemotron-3-super-120b-1c.json")
-    pattern = "MEM*E"
     cfg = ModelConfig(
         architecture=c["model_type"], vocab_size=c["vocab_size"],
         hidden_size=c["hidden_size"],
@@ -353,8 +346,21 @@ def test_no_state_sized_copy_in_the_state_space_step(topo_devices):
         moe_latent_size=c["moe_latent_size"],
         moe_shared_intermediate_size=c[
             "moe_shared_expert_intermediate_size"])
-    model = NemotronH(cfg, Mesh(np.array(topo_devices[:1]), ("tp",)),
-                      mode="fused", interpret=False)
+    return NemotronH(cfg, Mesh(np.array(devices), ("tp",)),
+                     mode="fused", interpret=False)
+
+
+def test_no_state_sized_copy_in_the_state_space_step(topo_devices):
+    """The state-space hybrid (`models/nemotron_h.py`) at the cell's own
+    widths, five layers with every kind among them (`MEM*E`): a decode
+    step rewrites every live slot's Mamba-2 state —
+    4 MB a slot a layer, two heads side by side — through a kernel that
+    aliases the pool; the insert and the reset write one slot's rows.
+    None of the three programs may copy the state pool or the attention
+    layer's page pools, and the step names its kernels."""
+    from triton_distributed_tpu.models.kv_cache import zero_state_rows
+
+    model = _nemotron(topo_devices[:1])
     slots = 16
     rep = NamedSharding(model.mesh, P())
     arg = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
@@ -403,6 +409,69 @@ def test_no_state_sized_copy_in_the_state_space_step(topo_devices):
             print(f"nemotron_h {name} {what} {shape}: {found}")
             assert not found["layout"], (name, what, found)
             assert len(found["staged"]) <= STAGED_MAX, (name, what, found)
+
+
+def test_the_state_space_chunk_reads_the_pools_and_copies_none(
+        topo_devices):
+    """A long prompt of the state-space hybrid in chunks
+    (`NemotronH.make_prefill_suffix_fn`) at the cell's widths and the
+    model's own chunk length, for the described v5e: Mosaic takes the
+    scan kernel with a carried state as an operand and the attention at
+    a traced offset; the chunk program reads the attention layer's page
+    pools through the request's page ids — gathered rows, never a pool
+    — and the scatter of a middle chunk's rows writes the donated pools
+    where they lie.  The state pool is no argument of either: a chunk's
+    state rides in the row cache, and the last chunk's insert (the
+    whole prefill's, pinned above) writes it.  (A state-space layer
+    stands BEHIND the attention layer here, as in the cell's pattern:
+    a chunk returns rows and states and no logits, so whatever only the
+    head would read — an attention layer's output too — is left out.)"""
+    model = _nemotron(topo_devices[:1], "M*EM")
+    chunk = model.prefill_chunk
+    #: the cell's own pool: what its budget leaves of pages beside 128
+    #: slots' states, and the trash page
+    pages = (3059220480 - 128 * 21278720) // (1024 * PAGE) + 1
+    assert chunk % PAGE == 0 and chunk < BUCKET
+    rep = NamedSharding(model.mesh, P())
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
+        shape, dt, sharding=rep)
+    pool = _shaped(model, functools.partial(
+        PagedKVCache.create, 1, pages, 16, 2, PAGE, 128,
+        2560 // PAGE, model.dtype, num_stats=len(model.STATS),
+        state_shapes=model._state_shapes),
+        model._paged_cache_specs(PAGE))
+    row = _shaped(model, functools.partial(
+        KVCache.create, 1, 1, 2, chunk, 128, model.dtype,
+        state_shapes=model._state_shapes), model._cache_specs())
+    params = _shaped(
+        model, lambda: model.init_params(jax.random.key(0)),
+        model.param_specs())
+    programs = {
+        "chunk": jax.jit(model.make_prefill_suffix_fn()).lower(
+            params, arg((1, chunk), jnp.int32), arg((), jnp.int32), row,
+            (pool.ks, pool.vs), arg((2560 // PAGE,), jnp.int32)),
+        "rows": make_paged_rows_fn().lower(
+            (pool.ks, pool.vs, None, None), pool.offset, row,
+            arg((chunk // PAGE,), jnp.int32))}
+    shard = (pages, 2, PAGE, 128)
+    dims = ",".join(str(d) for d in shard)
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        assert f"[{dims}]" in text, name
+        found = pool_copies(text, shard)
+        print(f"nemotron_h {name} of {chunk}, pool {shard}: {found}; "
+              f"temporaries "
+              f"{compiled.memory_analysis().temp_size_in_bytes >> 20} MB")
+        assert not found["layout"], (name, found)
+        assert len(found["staged"]) <= STAGED_MAX, (name, found)
+        if name == "chunk":
+            assert text.startswith("HloModule jit_prefill_shard"), text[:80]
+            for kernel in ("mamba2_prefill_chunk", "flash_attention_fwd",
+                           "moe_prefill_relu2_up",
+                           "moe_prefill_relu2_down"):
+                assert kernel in text, kernel
+            assert "mamba2_decode_step" not in text
 
 
 def test_no_pool_sized_copy_in_a_block_pass(topo_devices):

@@ -19,8 +19,10 @@ Two heads of a pair share their group's ``B`` and ``C``.
 `mamba2_decode_step` is the recurrence for one token a batch row: it
 reads and writes each live row's state once, in place, and touches no
 row that is not live.  `mamba2_prefill_chunk` is the chunked form
-(chunks of ``CHUNK`` tokens, the model's ``chunk_size``): with ``G_t``
-the log-decay summed from the chunk's start through ``t``,
+(chunks of ``CHUNK`` tokens, the model's ``chunk_size``), from a zero
+state or from the state a predecessor left (a long prompt prefilled in
+pieces): with ``G_t`` the log-decay summed from the chunk's start
+through ``t``,
 
     Y = (tril(C B^T * exp(G_t - G_s)) (dt X)) + exp(G) * (C S_0)
     S_C = exp(G_C) S_0 + B^T (dt X * exp(G_C - G))
@@ -223,19 +225,21 @@ _NT = ((1,), (1,))
 _TN = ((0,), (0,))
 
 
-def _prefill_kernel(x_ref, b_ref, c_ref, dt_ref, gc_ref, gr_ref, y_ref,
-                    so_ref, s_scr):
+def _prefill_kernel(x_ref, b_ref, c_ref, dt_ref, gc_ref, gr_ref, *rest):
     """Grid (B, G, T / CHUNK), the chunks in order.  One group a step:
     x, y (1, CHUNK, pairs * 128); b, c (1, CHUNK, N); dt and gc — G,
     the log-decay summed from the chunk's start — (1, CHUNK, 128), a
     lane a head of the group; gr the same G, a ROW a head (1, >= heads,
-    CHUNK).  ``s_scr``: the group's pairs' states."""
+    CHUNK).  ``rest``: [s0_ref (1, pairs, N, 128), where the caller
+    carries a state in,] y_ref, so_ref, s_scr — the group's pairs'
+    states, which start from ``s0_ref``, else from zeros."""
+    *s0_ref, y_ref, so_ref, s_scr = rest
     ci = pl.program_id(2)
     f32 = jnp.float32
 
     @pl.when(ci == 0)
     def _():
-        s_scr[...] = jnp.zeros_like(s_scr)
+        s_scr[...] = s0_ref[0][0] if s0_ref else jnp.zeros_like(s_scr)
 
     bm, cm = b_ref[0].astype(f32), c_ref[0].astype(f32)
     dt, gc, gr = dt_ref[0], gc_ref[0], gr_ref[0]
@@ -271,9 +275,12 @@ def _prefill_kernel(x_ref, b_ref, c_ref, dt_ref, gc_ref, gr_ref, y_ref,
             so_ref[0, k] = s_new
 
 
-def mamba2_prefill_chunk(x, dt, a, b, c, *,
+def mamba2_prefill_chunk(x, dt, a, b, c, state=None, *,
                          interpret: Optional[bool] = None):
-    """The recurrence over whole sequences from a zero state.
+    """The recurrence over T tokens a sequence, from ``state`` — (B,
+    H / 2, N, 2 P) float32 in the pool's layout: what the sequence's
+    earlier tokens left — or, ``None``, from a zero state (a whole
+    prompt; the program then has no such operand).
 
     x: (B, T, H * P); dt: (B, T, H) float32 — 0 where a token is to
     leave the state as it was (how a caller masks a padded tail); a:
@@ -305,6 +312,11 @@ def mamba2_prefill_chunk(x, dt, a, b, c, *,
     def seq(width):
         return pl.BlockSpec((1, CHUNK, width), lambda i, j, ci: (i, ci, j))
 
+    st = pl.BlockSpec((1, pairs, n, lanes), lambda i, j, ci: (i, j, 0, 0))
+    carried = [] if state is None else [state]
+    for s0 in carried:
+        assert s0.shape == (bsz, h // 2, n, lanes), (s0.shape, h, p)
+        assert s0.dtype == f32, s0.dtype
     macs = CHUNK * CHUNK * n + pairs * 4 * CHUNK * n * lanes
     return pl.pallas_call(
         _prefill_kernel,
@@ -315,10 +327,9 @@ def mamba2_prefill_chunk(x, dt, a, b, c, *,
         in_specs=[seq(pairs * lanes), seq(n), seq(n), seq(lanes),
                   seq(lanes),
                   pl.BlockSpec((1, rows, CHUNK),
-                               lambda i, j, ci: (i, j, ci))],
-        out_specs=(seq(pairs * lanes),
-                   pl.BlockSpec((1, pairs, n, lanes),
-                                lambda i, j, ci: (i, j, 0, 0))),
+                               lambda i, j, ci: (i, j, ci))]
+        + [st] * len(carried),
+        out_specs=(seq(pairs * lanes), st),
         scratch_shapes=[pltpu.VMEM((pairs, n, lanes), f32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -330,4 +341,4 @@ def mamba2_prefill_chunk(x, dt, a, b, c, *,
             transcendentals=bsz * t * h * (CHUNK + 2 * p)),
         interpret=default_interpret(interpret),
     )(x, b, c, lane_rows(dt.reshape(bsz, t, g, per)), lane_rows(gsum),
-      g_rows)
+      g_rows, *carried)

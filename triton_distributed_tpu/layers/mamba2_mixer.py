@@ -126,18 +126,33 @@ class Mamba2Mixer:
         y = y.reshape(*lead, -1) * params["norm"].astype(f32)
         return _dot(y.astype(dtype), params["w_out"]).astype(dtype)
 
-    def prefill(self, x, params, batch: int, length):
+    def prefill(self, x, params, batch: int, length, state=None,
+                conv_in=None):
         """x: (B * T, hidden); ``length``: (B,) int32 — the tokens of
         each row the state absorbs (positions from there on, a padded
-        tail, leave it as it was).  Returns (y like x, state (B, H / 2,
-        N, 2 P) float32, conv inputs (B, (conv - 1) * conv_width): the
-        projections at positions ``length - conv + 1 .. length - 1``
-        side by side, oldest first, zeros before the start)."""
+        tail, leave it as it was).  ``state`` (B, H / 2, N, 2 P)
+        float32 and ``conv_in`` (B, (conv - 1) * conv_width), both or
+        neither: what a prefill of the rows' EARLIER tokens returned —
+        x then continues those sequences (a long prompt prefilled in
+        pieces); without them the rows start a sequence, from a zero
+        state behind a window of zeros.  Returns (y like x, state (B,
+        H / 2, N, 2 P) float32, conv inputs (B, (conv - 1) *
+        conv_width): the projections at positions ``length - conv + 1
+        .. length - 1`` side by side, oldest first — cut from the
+        window the convolution ran over, so a piece that absorbs fewer
+        than ``conv - 1`` tokens hands on inputs of the piece before
+        it, and one that absorbs none hands on what it was given)."""
+        assert (state is None) == (conv_in is None), "both or neither"
         t = x.shape[0] // batch
         taps = self.conv
         z, xbc, dt = self._split(
             _dot(x, params["w_in"]).astype(x.dtype).reshape(batch, t, -1))
-        padded = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0)))
+        if conv_in is None:
+            padded = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0)))
+        else:
+            padded = jnp.concatenate(
+                [conv_in.reshape(batch, taps - 1, -1).astype(xbc.dtype),
+                 xbc], axis=1)
         xs, b, c = jnp.split(
             self._conved([padded[:, i:i + t] for i in range(taps)],
                          params, x.dtype),
@@ -149,14 +164,15 @@ class Mamba2Mixer:
             heads = lambda v, n: v.reshape(batch, t, n, -1)  # noqa: E731
             y, state = mamba2.mamba2_recurrent_reference(
                 heads(xs, self.num_heads), dt, a, heads(b, self.groups),
-                heads(c, self.groups))
+                heads(c, self.groups),
+                None if state is None else mamba2.unpair_state(state))
             y, state = y.reshape(batch, t, -1), mamba2.pair_state(state)
         else:
             pad = -t % mamba2.CHUNK
             grow = lambda v: jnp.pad(       # noqa: E731
                 v, ((0, 0), (0, pad), (0, 0)))    # dt = 0: no change
             y, state = mamba2.mamba2_prefill_chunk(
-                grow(xs), grow(dt), a, grow(b), grow(c),
+                grow(xs), grow(dt), a, grow(b), grow(c), state,
                 interpret=self.interpret)
             y = y[:, :t]
         out = self._output(y, xs, z, params, x.dtype)

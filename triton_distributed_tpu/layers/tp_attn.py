@@ -14,6 +14,7 @@ reference's `dist_triton_fwd`.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -190,22 +191,39 @@ class TPAttention:
             partial.reshape(world, m // world, -1), self.axis,
             scatter_dimension=0, tiled=False).astype(x_dtype)
 
-    def prefill(self, x, params, batch: int):
-        """x: (M/world, hidden) M-sharded; returns same sharding, plus
-        this rank's KV (B, Hkv_loc, S, D) for the cache."""
+    def _prefill_heads(self, x, params, batch: int, positions=None):
+        """The projections of ``batch`` sequences whose rows stand at
+        ``positions`` (S,), ``arange(S)`` by default: (q (B, H_loc, S,
+        D), k, v (B, Hkv_loc, S, D) — normed and rotated as the layer
+        is set — and the output gate or None)."""
         qkv, gate = self._split_gate(
             self._project_qkv(x, params))           # (M, qkv_cols)
-        m = qkv.shape[0]
-        seq = m // batch
+        seq = qkv.shape[0] // batch
         q, k, v = self._split_heads(qkv, batch, seq)
         if self.qk_norm:
             q = rms_norm(q, params["q_norm"])
             k = rms_norm(k, params["k_norm"])
         if self.rope:
-            cos, sin = rope_cos_sin(jnp.arange(seq), self.head_dim,
-                                    self.rope_theta)
+            cos, sin = rope_cos_sin(
+                jnp.arange(seq) if positions is None else positions,
+                self.head_dim, self.rope_theta)
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
+        return q, k, v, gate
+
+    def _prefill_out(self, attn, gate, x_dtype, params):
+        """The heads' output (B, H_loc, S, D) gated and projected."""
+        b, _, seq, _ = attn.shape
+        attn = attn.astype(x_dtype).transpose(0, 2, 1, 3).reshape(
+            b * seq, -1)
+        if gate is not None:
+            attn = (attn * gate).astype(x_dtype)
+        return self._out_proj(attn, x_dtype, params)
+
+    def prefill(self, x, params, batch: int):
+        """x: (M/world, hidden) M-sharded; returns same sharding, plus
+        this rank's KV (B, Hkv_loc, S, D) for the cache."""
+        q, k, v, gate = self._prefill_heads(x, params, batch)
         if self.mode == "xla":
             # dense golden (differentiable; materializes S² — use the
             # fused mode for long sequences)
@@ -220,11 +238,49 @@ class TPAttention:
             # fused mode trains too.
             attn = flash_attention_diff(q, k, v, causal=True,
                                         interpret=self.interpret)
-        attn = attn.transpose(0, 2, 1, 3).reshape(m, -1)
-        if gate is not None:
-            attn = (attn * gate).astype(x.dtype)
-        out = self._out_proj(attn, x.dtype, params)
-        return out, (k, v)
+        return self._prefill_out(attn, gate, x.dtype, params), (k, v)
+
+    def prefill_suffix(self, x, params, start, kv_pools, page_ids):
+        """`prefill` for a CHUNK of ONE sequence whose earlier rows lie
+        in the page pool.  x: (C / world, hidden), positions ``start +
+        arange(C)``; ``kv_pools``: this layer's (k pool, v pool), each
+        (P, Hkv_loc, page, D), read and not written; ``page_ids`` (T,):
+        the sequence's pages in logical order.  The pages' rows below
+        ``start`` are gathered into one buffer a pool (whatever lies at
+        or past ``start`` there is zeroed: another owner's rows, the
+        trash page), the chunk's own K and V are put at ``start``, and
+        `flash_attention` runs with its diagonal shifted by ``start``:
+        row i sees nothing past ``start + i``, so the buffer's tail
+        needs no mask of its own.  Nothing is expanded, so the buffers
+        are a gather a layer (`MLAttention.prefill_suffix` hands its
+        own from layer to layer).  Returns (out like x, the chunk's
+        (k, v), each (1, Hkv_loc, C, D), for the cache)."""
+        assert self.block <= 1, "a chunk under a block-causal mask"
+        k_pool, v_pool = kv_pools
+        assert k_pool.dtype != jnp.int8, "chunks over an int8 pool"
+        m = x.shape[0] * self.world_size
+        q, k, v, gate = self._prefill_heads(x, params, 1,
+                                            start + jnp.arange(m))
+        span = page_ids.shape[0] * k_pool.shape[2]
+        # whole query blocks, and room for a chunk that starts at the
+        # last row the pages reach
+        room = -(-span // m) * m + m - span
+        below = (jnp.arange(span) < start)[None, :, None]
+
+        def with_prefix(pool, own):
+            rows = jnp.moveaxis(pool[page_ids], 0, 1).reshape(
+                self.hkv_loc, span, self.head_dim)
+            buf = jnp.pad(jnp.where(below, rows, 0),
+                          ((0, 0), (0, room), (0, 0)))
+            return jax.lax.dynamic_update_slice_in_dim(
+                buf, own[0].astype(buf.dtype), start, axis=1)[None]
+
+        attend = (attention_reference if self.mode == "xla" else
+                  functools.partial(flash_attention,
+                                    interpret=self.interpret))
+        attn = attend(q, with_prefix(k_pool, k), with_prefix(v_pool, v),
+                      causal=True, kv_offset=start)
+        return self._prefill_out(attn, gate, x.dtype, params), (k, v)
 
     def decode(self, x, params, kv_cache, offset, kv_scales=None):
         """x: (B/world... ) decode step with B*1 tokens: x is

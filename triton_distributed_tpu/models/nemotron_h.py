@@ -25,10 +25,17 @@ pool, the state pool and the pipelined step are shared.  Its cache
 a bucket's padded tail).  A decode step updates the state of the LIVE
 rows only (`PagedKVCache.live_rows`).
 
+It also offers `make_prefill_suffix_fn`: a prefill of ONE CHUNK of a
+prompt that begins where its predecessor ended — from the state and the
+convolution's tail that one returned (they ride in and out in the row
+cache's ``states`` / ``convs``), attending the K/V rows already in the
+page pool — which is how the scheduler carries a long prompt out a
+chunk a step (``prefill_chunk`` tokens: `PREFILL_CHUNK`).  A state has
+no snapshot, so the chunks of a prompt always start at position 0.
+
 ONE device (``tp`` of size 1); tensor parallelism for this family, the
-exchange that would make the held expert layer expert-parallel, a
-snapshot of the state and a prefill that starts from a carried state
-are not built (ROADMAP Reach).
+exchange that would make the held expert layer expert-parallel and a
+snapshot of the state are not built (ROADMAP Reach).
 """
 
 from __future__ import annotations
@@ -48,10 +55,18 @@ from triton_distributed_tpu.layers.tp_attn import TPAttention, rms_norm
 from triton_distributed_tpu.models.config import ModelConfig
 from triton_distributed_tpu.models.kv_cache import KVCache, PagedKVCache
 
-__all__ = ["NemotronH"]
+__all__ = ["NemotronH", "PREFILL_CHUNK"]
 
 #: A layer's kind, as the published pattern writes it.
 SSM, ATTN, MOE = "M", "*", "E"
+
+#: Tokens of a prompt the scheduler prefills between two decode steps
+#: (`make_prefill_suffix_fn`).  A chunk streams the held experts once,
+#: so a shorter one costs tokens a second and a longer one lengthens the
+#: token gap of every running row: settled on the chip by PR 40's rule
+#: (the shortest bucket that keeps the tokens a second within 5%;
+#: PERF.md section 5 has the sweep of PR 43).
+PREFILL_CHUNK = 512
 
 
 class NemotronH:
@@ -79,6 +94,7 @@ class NemotronH:
         self.mode = mode
         self.interpret = interpret
         self.dtype = jnp.dtype(config.dtype)
+        self.prefill_chunk = PREFILL_CHUNK
         self.pattern = pattern
         self.attn = TPAttention(
             axis=axis, world_size=1, hidden=config.hidden_size,
@@ -180,6 +196,23 @@ class NemotronH:
             kept = ()
         return x + h, tuple(kept)
 
+    def _layer_fwd_suffix(self, x, lp, length, kept, page_ids, start, *,
+                          kind):
+        """A chunk of one sequence.  ``kept``: what the layer reads of
+        the sequence's earlier tokens — (k pool, v pool), (state, conv
+        inputs) or nothing; returned: what it leaves in the row cache —
+        the chunk's (k, v), the (state, conv inputs) after it, or
+        nothing."""
+        h = rms_norm(x, lp["ln"], self.config.rms_norm_eps)
+        if kind == ATTN:
+            h, kept = self.attn.prefill_suffix(h, lp["mixer"], start, kept,
+                                               page_ids)
+        elif kind == SSM:
+            h, *kept = self.ssm.prefill(h, lp["mixer"], 1, length, *kept)
+        else:
+            h, _ = self.moe(h, lp["mixer"], phase="prefill")
+        return x + h, tuple(kept)
+
     def _layer_fwd_decode(self, x, lp, kept, page_table, offset, live, *,
                           kind):
         """``kept``: the layer's (k pool, v pool), (state, conv) or
@@ -229,6 +262,47 @@ class NemotronH:
         if cache is not None:
             cache = cache.set_offset(s)
         return logits, cache
+
+    def prefill_shard_suffix(self, params, input_ids, start,
+                             cache: KVCache, pools, page_ids):
+        """One chunk of one prompt.  input_ids: (1, C), the tokens at
+        positions ``start + arange(C)`` (a last chunk right-padded);
+        ``cache``: the single-row cache of `create_cache`, C long,
+        whose ``states`` / ``convs`` hold what the chunk before this
+        one returned (zeros in front of the first) and whose ``length``
+        says how many of THIS chunk's tokens the state absorbs;
+        ``pools``: the paged cache's (ks, vs), read and not written —
+        the attention layers' rows below ``start`` lie there, at the
+        pages ``page_ids`` (T,) names in logical order.  Returns
+        ``cache`` holding the chunk's K/V rows at LOCAL positions
+        [0, C) — the paged insert puts them into their pages — and the
+        state and convolution tail after the chunk: the next chunk's
+        to start from, or the last one's insert's to write into the
+        slot.  No logits: the first decode step recomputes the prompt's
+        last position — so a layer behind the last that leaves
+        something in the cache (an expert layer closes the published
+        pattern) is read by nothing and the compiler leaves it out."""
+        b, s = input_ids.shape
+        assert b == 1, "a chunk is one sequence's"
+        ks, vs = pools
+        x = params["embed"][input_ids].reshape(s, -1)
+        start = jnp.asarray(start, jnp.int32).reshape(())
+        layer = self._per_layer(self._layer_fwd_suffix)
+        for li, (kind, lp) in enumerate(zip(self.pattern,
+                                            params["layers"])):
+            i = self._index[li]
+            kept = ()
+            if kind == ATTN:
+                kept = (ks[i], vs[i])
+            elif kind == SSM:
+                kept = (cache.states[i], cache.convs[i])
+            x, kept = layer[kind](x, lp, cache.length, kept, page_ids,
+                                  start)
+            if kind == ATTN:
+                cache = cache.write_prefill(i, *kept)
+            elif kind == SSM:
+                cache = cache.set_state(i, *kept)
+        return cache.set_offset(s)
 
     def decode_shard(self, params, tokens, cache: PagedKVCache):
         """One decode step.  tokens: (B,).  Returns (logits (B, V),
@@ -296,6 +370,18 @@ class NemotronH:
                       self._cache_specs()),
             out_specs=(P(None, self.axis), self._cache_specs()),
             check_vma=False)
+
+    def make_prefill_suffix_fn(self):
+        """``(params, ids (1, C), start, row_cache, (ks, vs), page_ids
+        (T,)) -> row_cache``: `prefill_shard_suffix`.  The program's
+        name starts like the whole prefill's, and its kernels are the
+        prefill's, so a device trace reads both alike."""
+        pools = [P(None, None, None, None)] * self.num_attn
+        return jax.shard_map(
+            self.prefill_shard_suffix, mesh=self.mesh,
+            in_specs=(self.param_specs(), P(None, None), P(),
+                      self._cache_specs(), (pools, pools), P(None)),
+            out_specs=self._cache_specs(), check_vma=False)
 
     def make_paged_decode_fn(self, page_size: int = 16):
         cspecs = self._paged_cache_specs(page_size)

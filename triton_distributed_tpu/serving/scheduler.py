@@ -98,9 +98,17 @@ offers a prefill of one chunk over the pages already in the pool
 attends the rows its predecessors put into the request's pages, and AT
 MOST ONE ENQUEUE — a chunk, or a short prompt's whole prefill — stands
 between two decode dispatches, so a running row's token gap holds a
-step and a chunk where it held a step and the longest prefill.  (A
-model with recurrent layers is paced the same way — `_paced` — though
-it offers no chunk program yet: its whole prefill is the piece.)  The
+step and a chunk where it held a step and the longest prefill.  A
+model with recurrent layers is paced the same way (`_paced`), and where
+it offers the chunk program its chunks cover the prompt FROM POSITION 0
+whatever the prefix match found: a state has no snapshot, so the
+matched pages are shared for storage alone, and chunk k+1 starts from
+the state and the convolution's tail chunk k returned — carried on the
+admission, in and out through the row cache's ``states`` / ``convs``,
+with ``length`` the tokens of the chunk the state absorbs; the last
+chunk's insert writes the state into the slot's row, as a whole
+prefill's does.  (One that offers no chunk program has its whole
+prefill as the piece.)  The
 request's slot and pages are claimed at its first insert (at its
 first chunk that reads the pool, if that comes first) and the slot
 stays masked — its row of the page table NULL — until the last chunk
@@ -341,6 +349,10 @@ class _Admission:
     done: int = 0
     #: Paged layout: the slot, once its pages are claimed.
     slot: Optional[int] = None
+    #: A model with recurrent layers, in chunks: the row the last chunk
+    #: enqueued returned — its ``states`` / ``convs`` are what the next
+    #: chunk starts from.
+    carry: object = None
 
 
 class ContinuousBatchingScheduler:
@@ -440,10 +452,10 @@ class ContinuousBatchingScheduler:
                                       0))
         #: At most ONE prefill enqueue between two decode dispatches
         #: while rows run: a model that chunks (a chunk is the piece),
-        #: and a model with recurrent layers, whose whole prefill is the
-        #: piece until it can start one from a carried state — its
-        #: short answers hand slots on every few steps, and a token gap
-        #: that held a step and two or three prefills holds one.
+        #: and a model with recurrent layers (a chunk where it offers
+        #: the program, else its whole prefill) — its short answers
+        #: hand slots on every few steps, and a token gap that held a
+        #: step and two or three prefills holds one.
         self._paced = bool(self._chunk or self._stateful)
         #: What the decode program leaves in the cache's `stats`, by
         #: name (`_moe_phase`).
@@ -1207,14 +1219,18 @@ class ContinuousBatchingScheduler:
         row and none; on a hit with a prefix-aware model ONLY the
         private suffix (near-zero-cost shared system prompts); in
         chunks where more than the model's chunk length is left; else
-        the whole prompt through its bucket.  None: the request had to
-        be retired at admission (a resumed stream that no longer fits
-        any prefill bucket)."""
+        the whole prompt through its bucket.  A model with recurrent
+        layers prefills from position 0 whatever matched (its state has
+        no snapshot: the matched pages are shared for storage alone).
+        None: the request had to be retired at admission (a resumed
+        stream that no longer fits any prefill bucket)."""
         tokens = req.resume_tokens or req.prompt
         s = len(tokens)
         shared = self.slots.match_prefix(tokens) if self.paged else []
         c = len(shared) * self.config.page_size
         key = self._request_key(req)
+        #: where the prefill begins
+        lo = 0 if self._stateful else c
         if req.shipped_kv is not None and req.resume_tokens is None:
             # Prefill-worker shipment: the full-prompt row arrives
             # precomputed; shared prefix pages (if any matched) are
@@ -1224,14 +1240,14 @@ class ContinuousBatchingScheduler:
             assert s2 == s, (s2, s)
             adm = _Admission(req, tokens, key, shared, "shipped",
                              [(0, bucket)], row=row)
-        elif self._chunk and s - c > self._chunk:
+        elif self._chunk and s - lo > self._chunk:
             # More than a chunk to prefill: a chunk a step, each
             # attending what its predecessors (and a prefix hit) left
             # in the pool.
             adm = _Admission(req, tokens, key, shared, "chunk",
                              [(at, self._chunk)
-                              for at in range(c, s, self._chunk)])
-        elif (c > 0 and self._prefill_suffix is not None
+                              for at in range(lo, s, self._chunk)])
+        elif (lo > 0 and self._prefill_suffix is not None
               and (bucket := pick_bucket(s - c, self.buckets))):
             # Prefix hit with a prefix-aware model: prefill ONLY the
             # private suffix — the shared pages are already in the
@@ -1246,14 +1262,13 @@ class ContinuousBatchingScheduler:
                 return None
             adm = _Admission(req, tokens, key, shared, "local",
                              [(0, bucket)])
-            redone = s if req.resume_tokens is not None else c
-            if self._stateful and redone:
-                # a snapshot of the state would have saved these
-                self._state_recomputed += redone
-                if reg:
-                    reg.counter(
-                        "serving_state_recomputed_tokens_total"
-                    ).inc(redone)
+        redone = s if req.resume_tokens is not None else c
+        if self._stateful and redone and adm.row is None:
+            # a snapshot of the state would have saved these
+            self._state_recomputed += redone
+            if reg:
+                reg.counter(
+                    "serving_state_recomputed_tokens_total").inc(redone)
         if reg and self.paged:
             reg.counter("serving_prefix_cache_hit_tokens_total").inc(c)
             reg.counter("serving_prefix_cache_miss_tokens_total").inc(
@@ -1308,13 +1323,18 @@ class ContinuousBatchingScheduler:
         ids, _ = pad_prompt(adm.tokens[start:start + bucket], bucket,
                             self.config.pad_id)
         row_in = self._row_cache(bucket)
+        if self._stateful:
+            # the state absorbs what lies below position s-1 (the
+            # first decode step takes that token, as it rewrites that
+            # position's K/V), never the bucket's padded tail; a chunk
+            # starts from what the one before it returned (the reusable
+            # row's own zeros in front of the first: never written)
+            was = row_in if adm.carry is None else adm.carry
+            row_in = dataclasses.replace(
+                row_in, states=was.states, convs=was.convs,
+                length=np.full(
+                    (1,), min(max(s - 1 - start, 0), bucket), np.int32))
         if adm.mode == "local":
-            if self._stateful:
-                # the state absorbs what lies below position s-1 (the
-                # first decode step takes that token, as it rewrites
-                # that position's K/V), never the bucket's padded tail
-                row_in = dataclasses.replace(
-                    row_in, length=np.full((1,), s - 1, np.int32))
             self._starved("prefill", sp)
             _, row = self._prefill(self.params, ids, row_in)
             return row
@@ -1327,17 +1347,23 @@ class ContinuousBatchingScheduler:
                  if adm.slot is not None else self._no_pages)
         cache = self.slots.cache
         self._starved("prefill", sp)
-        return self._prefill_suffix(self.params, ids, jnp.int32(start),
-                                    row_in, (cache.ks, cache.vs), pages)
+        row = self._prefill_suffix(self.params, ids, jnp.int32(start),
+                                   row_in, (cache.ks, cache.vs), pages)
+        if self._stateful:
+            adm.carry = row
+        return row
 
     def _give_up_underway(self) -> None:
         """Drop the admission under way: its slot and pages go back,
         its request to the head of the queue (what was enqueued for it
         writes pages nobody reads before their next owner's own
-        rows; its prefills stay counted where a read still times them)."""
+        rows; its prefills stay counted where a read still times them).
+        A carried state goes with it: the request starts again from
+        position 0."""
         adm, self._underway = self._underway, None
         if adm is None:
             return
+        adm.carry = None
         if adm.slot is not None:
             self.slots.release(adm.slot)
         self._queue.appendleft(adm.req)
