@@ -598,6 +598,8 @@ def test_one_step_leaves_the_phase_spans(toy, tracer, layout):
         assert front.attrs == dict(
             idle, request_id=req.request_id, flight="none",
             bucket=req.bucket, queue_wait_ms=0.0,
+            # where the piece starts, and the prompt's tokens in it
+            start=0, tokens=req.prompt_len,
             starved=front.attrs["starved"])
         assert front.attrs["starved"] in (0, 1) and (
             front.attrs["starved"] or not idle)

@@ -912,6 +912,8 @@ def test_an_admitting_call_reads_the_step_in_flight_early(
               if s.name == "serving.admit.request"]
     assert front.attrs == {
         "request_id": b.request_id, "bucket": 8, "queue_wait_ms": 0.0,
+        # where the piece starts, and the prompt's tokens in it
+        "start": 0, "tokens": b.prompt_len,
         "flight": "behind" if order == "front_first" else "read",
         "starved": front.attrs["starved"]}
     assert one.attrs["read_flight"] == 1

@@ -531,3 +531,95 @@ def test_no_pool_sized_copy_in_a_block_pass(topo_devices):
     print(f"sdar_moe block pass, pool {shard}: {found}")
     assert not found["layout"] and len(found["staged"]) <= STAGED_MAX, (
         found)
+
+
+def _cohere(devices, kinds=None):
+    """The window / full hybrid at the cell's own widths, one period."""
+    from triton_distributed_tpu.models.cohere2_moe import Cohere2Moe
+
+    c = _config("command-a-plus-218b-1c.json")
+    kinds = tuple(kinds or c["layer_types"])
+    cfg = ModelConfig(
+        architecture=c["model_type"], vocab_size=c["vocab_size"],
+        hidden_size=c["hidden_size"],
+        intermediate_size=c["intermediate_size"], num_layers=len(kinds),
+        num_heads=c["num_attention_heads"],
+        num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
+        rms_norm_eps=c["layer_norm_eps"], rope_theta=c["rope_theta"],
+        qk_norm=False, rope_pairs=True, max_seq_len=25600,
+        dtype=c["torch_dtype"],
+        num_experts=c["share"]["experts_of_layer"],
+        experts_held=tuple(c["share"]["experts_held"]),
+        num_experts_per_tok=c["num_experts_per_tok"],
+        moe_intermediate_size=c["intermediate_size"],
+        n_shared_experts=c["num_shared_experts"],
+        moe_shared_combine="average", moe_selection_bias=False,
+        layer_types=kinds, sliding_window=c["sliding_window"])
+    return Cohere2Moe(cfg, Mesh(np.array(devices), ("tp",)),
+                      mode="fused", interpret=False), c
+
+
+def test_window_and_full_pools_step_chunk_and_inserts(topo_devices):
+    """The window / full hybrid's four programs at the cell's widths,
+    slots and pools, for the described v5e: the decode step over TWO
+    page tables (Mosaic takes the paged kernel with a window's lower
+    bound), a chunk of a long prompt (the windowed rectangular grid at
+    a traced offset over a window's gathered pages, the causal one over
+    the full layer's), its rows' scatter into both kinds of pool, and
+    the insert.  No program copies a pool of either kind."""
+    model, c = _cohere(topo_devices[:1])
+    serving = c["serving"]
+    slots, chunk = serving["num_slots"], model.prefill_chunk
+    t = serving["max_seq"] // PAGE
+    wpages = slots * (c["sliding_window"] // PAGE + 1) + 1
+    pages = slots * t + 1
+    rep = NamedSharding(model.mesh, P())
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
+        shape, dt, sharding=rep)
+    pool = _shaped(model, functools.partial(
+        PagedKVCache.create, 1, pages, slots, 8, PAGE, 128, t,
+        model.dtype, num_stats=len(model.STATS), window_layers=3,
+        window_pages=wpages), model._paged_cache_specs(PAGE))
+    row = _shaped(model, functools.partial(
+        KVCache.create, 1, 1, 8, chunk, 128, model.dtype,
+        window_layers=3), model._cache_specs())
+    params = _shaped(
+        model, lambda: model.init_params(jax.random.key(0)),
+        model.param_specs())
+    keys = arg((slots, 2), jnp.uint32)
+    ids = arg((chunk // PAGE,), jnp.int32)
+    programs = {
+        "step": make_masked_step_fn(model.make_paged_decode_fn(PAGE))
+        .lower(params, arg((slots,), jnp.int32), pool, keys,
+               arg((slots,), jnp.bool_)),
+        "prefill": jax.jit(model.make_prefill_fn()).lower(
+            params, arg((1, chunk), jnp.int32), row),
+        "chunk": jax.jit(model.make_prefill_suffix_fn()).lower(
+            params, arg((1, chunk), jnp.int32), arg((), jnp.int32), row,
+            (pool.ks, pool.vs, pool.wks, pool.wvs),
+            arg((2, t), jnp.int32)),
+        "rows": make_paged_rows_fn().lower(
+            (pool.ks, pool.vs, None, None), pool.offset, row, ids,
+            (pool.wks, pool.wvs), ids),
+        "insert": make_paged_insert_fn().lower(
+            pool, keys, row, arg((2,), jnp.uint32), arg((), jnp.int32),
+            ids, arg((), jnp.int32), ids)}
+    shards = ((pages, 8, PAGE, 128), (wpages, 8, PAGE, 128))
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        found = [pool_copies(text, shard) for shard in shards]
+        print(f"cohere2_moe {name}: {found}; temporaries "
+              f"{compiled.memory_analysis().temp_size_in_bytes >> 20} MB")
+        for f in found:
+            assert not f["layout"], (name, f)
+            assert len(f["staged"]) <= STAGED_MAX, (name, f)
+        if name == "step":
+            for kernel in ("swa_decode_paged", "flash_decode_paged",
+                           "moe_decode_gate_up", "moe_decode_down"):
+                assert kernel in text, kernel
+        if name == "chunk":
+            assert text.startswith("HloModule jit_prefill_shard"), text[:80]
+        if name in ("chunk", "prefill"):
+            assert "swa_prefill_attention" in text
+            assert "moe_prefill_gate_up" in text

@@ -64,7 +64,7 @@ def _row_limit(q_pos, cblock: int):
 
 def _emit_attend(q, k_ref, v_ref, m_scr, l_scr, acc_scr, *,
                  masked, causal, ragged, qi, ki, off, sk,
-                 block_q, block_k, cblock=0):
+                 block_q, block_k, cblock=0, window=0):
     """One online-softmax block update (shared by the rectangular and
     packed kernels).  ``q`` is the loaded, pre-scaled (bq, D) row
     block (the kernels scale into a scratch once per row — a host-side
@@ -101,6 +101,9 @@ def _emit_attend(q, k_ref, v_ref, m_scr, l_scr, acc_scr, *,
                      + off)
             s = jnp.where(k_pos <= _row_limit(q_pos, cblock), s,
                           NEG_INF)
+            if window:
+                # a sliding window: nothing at or below q_pos - window
+                s = jnp.where(k_pos > q_pos - window, s, NEG_INF)
 
     m_prev = m_scr[:]                 # (bq, 1), log2 domain
     m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -176,9 +179,14 @@ def _emit_epilogue(o_ref, lse_ref, m_scr, l_scr, acc_scr):
 
 def _flash_kernel(nk: int, sk: int, causal: bool, scale: float,
                   block_q: int, block_k: int, with_lse: bool,
-                  cblock: int,
+                  cblock: int, window: int,
                   off_ref, q_ref, k_ref, v_ref, *rest):
     """Grid: (B, H, nq, nk); blocks: q (1,1,bq,D), k/v (1,1,bk,D).
+
+    ``window`` > 0 (causal only): query row i also sees nothing at or
+    below column ``i + off - window``; blocks wholly below a q block's
+    window are skipped like those above its diagonal, and the blocks
+    its edge crosses take the masked path.
 
     `q` is scaled by `scale * log2(e)` ONCE PER ROW into `qs_scr`
     (the same trick as `sp_ag_attention._emit_flash_chunk`; a
@@ -219,7 +227,8 @@ def _flash_kernel(nk: int, sk: int, causal: bool, scale: float,
         _emit_attend(qs_scr[:], k_ref, v_ref, m_scr, l_scr, acc_scr,
                      masked=masked, causal=causal, ragged=ragged,
                      qi=qi, ki=ki, off=off_ref[0], sk=sk,
-                     block_q=block_q, block_k=block_k, cblock=cblock)
+                     block_q=block_q, block_k=block_k, cblock=cblock,
+                     window=window)
 
     if causal:
         # Skip blocks entirely above the causal diagonal (their every
@@ -238,6 +247,14 @@ def _flash_kernel(nk: int, sk: int, causal: bool, scale: float,
                  <= _row_limit(qi * block_q + off_ref[0], cblock))
         if ragged:
             fully = jnp.logical_and(fully, ki != nk - 1)
+        if window:
+            # the block's last column is inside the first row's window,
+            # and its first inside the last row's
+            first_row = qi * block_q + off_ref[0]
+            visible = jnp.logical_and(
+                visible, ki * block_k + block_k - 1 > first_row - window)
+            fully = jnp.logical_and(
+                fully, ki * block_k > first_row + block_q - 1 - window)
         pl.when(jnp.logical_and(visible, fully))(
             lambda: attend_block(False))
         pl.when(jnp.logical_and(visible, jnp.logical_not(fully)))(
@@ -485,6 +502,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     block_q: int = 1024, block_k: int = 1024,
                     diag_sub: Optional[int] = None,
                     causal_block: int = 0,
+                    window: Optional[int] = None,
+                    name: Optional[str] = None,
                     interpret: Optional[bool] = None,
                     _max_packed_steps: Optional[int] = None):
     """q: (B, H, Sq, D); k, v: (B, Hkv, Sk, D) → (B, H, Sq, D)
@@ -499,6 +518,16 @@ def flash_attention(q, k, v, *, causal: bool = True,
     schedules then visit the blocks plain causal visits and only the
     element mask differs.  Forward only (`flash_attention_diff` does
     not take it).
+
+    `window` (tokens, static; with ``causal``): a SLIDING window —
+    query row i sees kv col j iff ``i + kv_offset - window < j <= i +
+    kv_offset``.  The rectangular grid runs it (blocks wholly below a q
+    block's window are skipped, and their K/V never fetched, like those
+    above its diagonal); where a static offset shows that no row's
+    window can reach below column 0 the call is the plain causal one.
+    Forward only.  `name`: the kernel's name in a device trace, in place
+    of the schedule's own (a window layer passes one, whichever
+    schedule runs).
 
     `kv_offset` (python int or traced scalar) shifts the causal
     diagonal: query row i attends kv cols <= i + kv_offset (used by SP
@@ -532,6 +561,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
     off = jnp.asarray(kv_offset, jnp.int32).reshape(1)
     cblock = int(causal_block) if causal else 0
     import numpy as np
+    static_off = isinstance(kv_offset, (int, np.integer))
+    window = int(window or 0)
+    assert not window or (causal and cblock <= 1), (window, causal, cblock)
+    if window and static_off and sq + int(kv_offset) <= window:
+        window = 0          # no row's window reaches below column 0
     if cblock > 1:
         assert bq % cblock == 0 and bk % cblock == 0, (bq, bk, cblock)
         assert (not isinstance(kv_offset, (int, np.integer))
@@ -553,7 +587,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
     max_packed_steps = (max_prefetch_steps(3)
                         if _max_packed_steps is None
                         else _max_packed_steps)
-    use_packed = (causal and isinstance(kv_offset, (int, np.integer))
+    use_packed = (causal and static_off and not window
                   and nq * ((nk + 1) // 2 + 1) <= max_packed_steps)
     if use_packed:
         # Static-diagonal fast path: bq == bk and an aligned offset
@@ -608,7 +642,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
         res = pl.pallas_call(
             functools.partial(_flash_kernel_single_diag, scale, bq, bk,
                               return_lse, diag_sub, cblock),
-            name="flash_attention_fwd_single_diag",
+            name=name or "flash_attention_fwd_single_diag",
             out_shape=tuple(out_shape),
             grid_spec=pl.GridSpec(
                 grid=(b, h),
@@ -659,7 +693,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
         res = pl.pallas_call(
             functools.partial(_flash_kernel_packed, sk, scale, bq, bk,
                               return_lse, diag_sub, cblock),
-            name="flash_attention_fwd_packed",
+            name=name or "flash_attention_fwd_packed",
             out_shape=tuple(out_shape),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=4,
@@ -712,6 +746,15 @@ def flash_attention(q, k, v, *, causal: bool = True,
             visible = ki * bk <= _row_limit(qi * bq + bq - 1 + off[0],
                                             cblock)
             ki = jax.lax.select(visible, ki, 0)
+        if window:
+            # the first block a q row's window reaches: what a skipped
+            # step below it holds, and — of the NEXT q row — a skipped
+            # step past the diagonal
+            def first(row):
+                return jnp.clip((row * bq + off[0] - window + 1) // bk,
+                                0, nk - 1)
+            ki = jnp.where(visible, jnp.maximum(ki, first(qi)),
+                           first(qi + 1))
         return (bb, hh // g, ki, 0)
 
     out_shape = [jax.ShapeDtypeStruct((b, h, sq, d), q.dtype)]
@@ -726,8 +769,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
                          memory_space=pltpu.VMEM))
     res = pl.pallas_call(
         functools.partial(_flash_kernel, nk, sk, causal, scale, bq, bk,
-                          return_lse, cblock),
-        name="flash_attention_fwd",
+                          return_lse, cblock, window),
+        name=name or "flash_attention_fwd",
         out_shape=tuple(out_shape),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -1114,9 +1157,10 @@ def flash_attention_diff(q, k, v, kv_offset=0, *,
 
 def attention_reference(q, k, v, *, causal: bool = True,
                         scale: Optional[float] = None, kv_offset: int = 0,
-                        causal_block: int = 0):
-    """Golden dense attention (fp32); ``causal_block`` as
-    `flash_attention`'s."""
+                        causal_block: int = 0,
+                        window: Optional[int] = None):
+    """Golden dense attention (fp32); ``causal_block`` and ``window``
+    as `flash_attention`'s."""
     b, h, sq, d = q.shape
     _, hkv, sk, _ = k.shape
     group = h // hkv
@@ -1128,6 +1172,8 @@ def attention_reference(q, k, v, *, causal: bool = True,
         qpos = jnp.arange(sq)[:, None] + kv_offset
         kpos = jnp.arange(sk)[None, :]
         s = jnp.where(kpos <= _row_limit(qpos, causal_block), s, NEG_INF)
+        if window:
+            s = jnp.where(kpos > qpos - window, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, vf).astype(q.dtype)
 

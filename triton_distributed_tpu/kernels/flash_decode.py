@@ -260,11 +260,12 @@ def _pages_per_block(t: int, hkv: int, ps: int, d: int, dtype) -> int:
     return max(1, min(t, min(rows, _PAGED_BLOCK_ROWS) // ps))
 
 
-def _paged_decode_kernel(n, ps, scale, quantized, compute_dtype,
+def _paged_decode_kernel(n, ps, scale, quantized, compute_dtype, window,
                          kvlen_ref, ptab_ref, q_ref, k_hbm, v_hbm,
                          *rest):
     """Grid: (B,).  One grid step is one row: all its KV heads, and
-    only the pages below its length.
+    only the pages below its length — with ``window``, only the pages
+    that hold one of its last ``window`` positions.
 
     The pools stay in HBM.  A block is ``n`` consecutive logical pages:
     page ``ptab[b, j]`` — all KV heads of it, contiguous in the pool —
@@ -276,6 +277,13 @@ def _paged_decode_kernel(n, ps, scale, quantized, compute_dtype,
     The online-softmax update is `_decode_kernel`'s over ``n * page``
     rows: f32 running max / sum / accumulator per KV head, p in the
     value dtype for the PV product.
+
+    ``window`` (a static int; None: the program it always was): the
+    row's query stands at position ``kv_len - 1`` and sees the keys at
+    and above ``kv_len - window``.  The loop starts at the block that
+    holds that position, copies no page below it (the table may map
+    those anywhere: their owner gave them back), and masks the first
+    block as it masks the last.
 
     With ``quantized`` the per-token scales arrive as dense
     (1, Hkv, 1, T * page) rows (gathered by the wrapper) and are
@@ -291,6 +299,10 @@ def _paged_decode_kernel(n, ps, scale, quantized, compute_dtype,
     kv_len = kvlen_ref[bb]
     npages = pl.cdiv(kv_len, ps)
     nblk = pl.cdiv(npages, n)
+    # the first position, page and block the row's query sees
+    first = jnp.maximum(kv_len - window, 0) if window else 0
+    page0 = first // ps
+    blk0 = page0 // n
 
     def gather(blk, slot, wait):
         """Start (or wait for) the copies of block ``blk``'s live
@@ -306,15 +318,18 @@ def _paged_decode_kernel(n, ps, scale, quantized, compute_dtype,
                     copy.wait()
                 else:
                     copy.start()
-        jax.lax.fori_loop(0, jnp.minimum(n, npages - blk * n), page,
-                          None)
+        jax.lax.fori_loop(jnp.maximum(page0 - blk * n, 0) if window else 0,
+                          jnp.minimum(n, npages - blk * n), page, None)
 
     def update(blk, slot, masked):
         if quantized:
             cols = pl.ds(pl.multiple_of(blk * rows, rows), rows)
         if masked:
-            col_live = blk * rows + jax.lax.broadcasted_iota(
-                jnp.int32, (1, rows), 1) < kv_len
+            col = blk * rows + jax.lax.broadcasted_iota(
+                jnp.int32, (1, rows), 1)
+            col_live = col < kv_len
+            if window:
+                col_live = jnp.logical_and(col_live, col >= first)
         # Unrolled over the KV heads: their chains are independent, and
         # a loop would leave each matmul's latency exposed.
         for h in range(hkv):
@@ -334,6 +349,10 @@ def _paged_decode_kernel(n, ps, scale, quantized, compute_dtype,
                 s = jnp.where(col_live, s, NEG_INF)
                 # 0 x NaN: rows no copy wrote must not reach the sums.
                 v = zero_oob_rows(v, blk, rows, kv_len)
+                if window:
+                    v = jnp.where(
+                        blk * rows + jax.lax.broadcasted_iota(
+                            jnp.int32, v.shape, 0) >= first, v, 0)
                 if quantized:
                     vs = jnp.where(col_live, vs, 0)
             m_prev = m_scr[h]
@@ -357,7 +376,7 @@ def _paged_decode_kernel(n, ps, scale, quantized, compute_dtype,
 
     @pl.when(nblk > 0)
     def _():
-        gather(0, 0, wait=False)
+        gather(blk0, blk0 % 2, wait=False)
 
     def block(blk, _):
         slot = blk % 2
@@ -367,16 +386,19 @@ def _paged_decode_kernel(n, ps, scale, quantized, compute_dtype,
             gather(blk + 1, 1 - slot, wait=False)
 
         gather(blk, slot, wait=True)
+        edge = blk + 1 == nblk
+        if window:
+            edge = jnp.logical_or(edge, blk == blk0)
 
-        @pl.when(blk + 1 < nblk)
+        @pl.when(jnp.logical_not(edge))
         def _():
             update(blk, slot, masked=False)
 
-        @pl.when(blk + 1 == nblk)
+        @pl.when(edge)
         def _():
             update(blk, slot, masked=True)
 
-    jax.lax.fori_loop(0, nblk, block, None)
+    jax.lax.fori_loop(blk0, nblk, block, None)
 
     l = jnp.maximum(l_scr[...], 1e-30)
     o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
@@ -387,6 +409,8 @@ def _paged_decode_kernel(n, ps, scale, quantized, compute_dtype,
 def flash_decode_paged(q, k_pool, v_pool, page_table, kv_len, *,
                        k_scale=None, v_scale=None,
                        scale: Optional[float] = None,
+                       window: Optional[int] = None,
+                       name: str = "flash_decode_paged",
                        interpret: Optional[bool] = None):
     """Single-position GQA decode over a PAGED KV pool
     (`models.kv_cache.PagedKVCache` layout).
@@ -403,7 +427,17 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, kv_len, *,
     generates by blocks, `layers.tp_attn.TPAttention.block_paged` — the
     heads of all ``n`` positions of the block in flight, laid out
     ``(Hkv, G * n)``, whose K/V the caller has written into the pages
-    first.  Nothing is masked inside a row's length either way.
+    first.  Without a ``window`` nothing is masked inside a row's
+    length either way.
+
+    ``window`` (tokens, static; a sliding-window layer): row b's query
+    stands at position ``kv_len[b] - 1`` and sees key j iff
+    ``kv_len[b] - window <= j < kv_len[b]``.  The block loop starts at
+    the block that holds the first such key, no page below it is read
+    (a window layer's pool gives those back: the table may say
+    `NULL_PAGE` there) and the first block is masked like the last.
+    None compiles to the program without the argument.  ``name``: the
+    kernel's name in a device trace (a window layer passes its own).
 
     The work follows each row's LIVE length, not the table's width:
     the grid is (B,), the pools stay in HBM, and each grid step loops
@@ -459,8 +493,9 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, kv_len, *,
         in_specs += [row_spec(1, t_pad * ps)] * 2
         operands += [dense(k_scale), dense(v_scale)]
 
+    window = int(window) if window else None
     kernel = functools.partial(_paged_decode_kernel, n, ps, scale,
-                               quantized, q.dtype)
+                               quantized, q.dtype, window)
     # What the resource sanitizer bounds in place of a BlockSpec index
     # map (`analysis.resources.ManualBlocks`): the pages `gather`
     # copies for row `bb`.
@@ -468,12 +503,13 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, kv_len, *,
         (1, hkv, ps, d),
         lambda bb, kvlen, ptab: [
             (ptab[bb, j], 0, 0, 0)
-            for j in range(-(-int(kvlen[bb]) // ps))])
+            for j in range(max(int(kvlen[bb]) - (window or 1 << 40), 0)
+                           // ps, -(-int(kvlen[bb]) // ps))])
     kernel.manual_blocks = {1: pages, 2: pages}
 
     out, lse = pl.pallas_call(
         kernel,
-        name="flash_decode_paged",
+        name=name,
         out_shape=(
             jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
             jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),

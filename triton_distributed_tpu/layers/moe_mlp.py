@@ -284,11 +284,16 @@ class SparseMoE:
     """Sparse feed-forward on ONE chip, dropless: ``topk`` routed
     experts a token weighted by their normalised scores times
     ``routed_scaling``, plus ``n_shared`` always-on experts (0: none,
-    and no ``shared`` weights).  Two routers, by ``scoring``:
+    and no ``shared`` weights).  The shared experts are ONE expert
+    ``n_shared * ffn`` wide — their gate / up columns side by side,
+    their down rows stacked — which is their SUM
+    (``shared_combine="sum"``); ``"average"`` is that sum over
+    ``n_shared``, their mean.  Two routers, by ``scoring``:
 
     - ``"sigmoid"`` (`noaux_tc` without groups): ``s = sigmoid(x W_r)``
       in float32; the choice is the top-k of ``s + bias`` and the
-      selection bias goes no further;
+      selection bias goes no further (``selection_bias=False``: no
+      bias, no ``router_bias`` weight, the top-k of ``s``);
     - ``"softmax"``: ``s = softmax(x W_r)`` over all experts in float32;
       the choice is the top-k of ``s``; there is no bias (and no
       ``router_bias`` weight).
@@ -339,8 +344,13 @@ class SparseMoE:
     act: str = "silu"              # silu (gated) | relu2 (no gate)
     latent: Optional[int] = None   # the routed experts' width
     shared_ffn: Optional[int] = None
+    shared_combine: str = "sum"    # sum | average (of n_shared experts)
+    selection_bias: bool = True    # sigmoid scoring's, for the choice
 
     def __post_init__(self):
+        if self.shared_combine not in ("sum", "average"):
+            raise ValueError(
+                f"unknown shared_combine {self.shared_combine!r}")
         if self.scoring not in ("sigmoid", "softmax"):
             raise ValueError(f"unknown scoring {self.scoring!r}")
         if self.act not in ("silu", "relu2"):
@@ -383,7 +393,8 @@ class SparseMoE:
 
     def _absent(self):
         """Weights this layer does not have."""
-        return (("router_bias",) * (self.scoring == "softmax")
+        return (("router_bias",) * (self.scoring == "softmax"
+                                    or not self.selection_bias)
                 + ("shared",) * (not self.n_shared)
                 + ("gate",) * (self.act == "relu2")
                 + ("latent_down", "latent_up") * (not self.latent))
@@ -413,8 +424,9 @@ class SparseMoE:
             _, ids = jax.lax.top_k(s, self.topk)
         else:
             s = jax.nn.sigmoid(logits)
-            _, ids = jax.lax.top_k(
-                s + params["router_bias"].astype(jnp.float32), self.topk)
+            biased = (s + params["router_bias"].astype(jnp.float32)
+                      if self.selection_bias else s)
+            _, ids = jax.lax.top_k(biased, self.topk)
         w = jnp.take_along_axis(s, ids, axis=1)
         if self.norm_topk_prob:
             w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
@@ -511,7 +523,10 @@ class SparseMoE:
             y = jnp.dot(y.astype(x.dtype), params["latent_up"],
                         preferred_element_type=jnp.float32)
         if self.n_shared:
-            y = y + self._shared(x, params["shared"])
+            shared = self._shared(x, params["shared"])
+            if self.shared_combine == "average":
+                shared = shared / self.n_shared
+            y = y + shared
         if self.held is not None:
             counts = plan.counts[:-1].astype(jnp.float32)
             pairs = counts.sum()

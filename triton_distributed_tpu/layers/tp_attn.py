@@ -53,14 +53,32 @@ def rope_cos_sin(positions, dim: int, theta: float = 1e6,
 
 
 def apply_rope(x, cos, sin):
-    """x: (..., S, D) with rotate-half convention."""
+    """x: (..., S, D) with rotate-half convention; cos/sin (S, D/2),
+    or anything that broadcasts against half a head."""
     d2 = x.shape[-1] // 2
     x1, x2 = x[..., :d2], x[..., d2:]
-    shape = (1,) * (x.ndim - 2) + cos.shape
-    c = cos.reshape(shape)
-    s = sin.reshape(shape)
+    c, s = cos, sin
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
                            axis=-1).astype(x.dtype)
+
+
+def apply_rope_pairs(x, cos, sin):
+    """`apply_rope` over ADJACENT pairs ``(x[2i], x[2i + 1])`` (the
+    GPT-J convention), where `apply_rope` pairs ``x[i]`` with ``x[i +
+    D/2]``: the same rotation on another layout of the head.  cos/sin
+    broadcast against ``x[..., ::2]``."""
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                     axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+def layer_norm(x, weight, eps: float = 1e-5):
+    """Mean-subtracting LayerNorm with a weight and no bias."""
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * weight
 
 
 def rms_norm(x, weight, eps: float = 1e-6):
@@ -68,6 +86,11 @@ def rms_norm(x, weight, eps: float = 1e-6):
                    keepdims=True)
     return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
             ).astype(x.dtype) * weight
+
+
+#: A sliding-window layer's kernels in a device trace.
+WINDOW_KERNELS = {"decode": "swa_decode_paged",
+                  "prefill": "swa_prefill_attention"}
 
 
 @dataclasses.dataclass
@@ -84,6 +107,14 @@ class TPAttention:
     qk_norm: bool = True          # Qwen3-style per-head q/k RMSNorm
     #: False: no positional encoding at all (NoPE).  Set by the model.
     rope: bool = True
+    #: True: the rotation pairs ADJACENT dimensions (`apply_rope_pairs`;
+    #: `rope_gptj`), not a dimension with the one half a head away.
+    rope_pairs: bool = False
+    #: > 0: a SLIDING-WINDOW layer — key j is visible to query i iff
+    #: ``i - window < j <= i``.  Its kernels run under names of their
+    #: own (`WINDOW_KERNELS`), and its pages behind the window go back
+    #: to their pool (`serving.pages`).  Set by the model.
+    window: int = 0
     #: An output gate (arXiv 2505.06708): ``sigmoid(x W_g)``, a number
     #: a head and channel, times the heads' output before ``W_o``;
     #: ``W_g`` rides as further columns of ``wqkv``.  Set by the model.
@@ -191,6 +222,11 @@ class TPAttention:
             partial.reshape(world, m // world, -1), self.axis,
             scatter_dimension=0, tiled=False).astype(x_dtype)
 
+    def _rotate(self, x, cos, sin):
+        """x (..., D) by cos/sin that broadcast against half a head."""
+        return (apply_rope_pairs if self.rope_pairs else apply_rope)(
+            x, cos, sin)
+
     def _prefill_heads(self, x, params, batch: int, positions=None):
         """The projections of ``batch`` sequences whose rows stand at
         ``positions`` (S,), ``arange(S)`` by default: (q (B, H_loc, S,
@@ -207,8 +243,8 @@ class TPAttention:
             cos, sin = rope_cos_sin(
                 jnp.arange(seq) if positions is None else positions,
                 self.head_dim, self.rope_theta)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+            q = self._rotate(q, cos, sin)
+            k = self._rotate(k, cos, sin)
         return q, k, v, gate
 
     def _prefill_out(self, attn, gate, x_dtype, params):
@@ -228,7 +264,13 @@ class TPAttention:
             # dense golden (differentiable; materializes S² — use the
             # fused mode for long sequences)
             attn = attention_reference(q, k, v, causal=True,
-                                       causal_block=self.block)
+                                       causal_block=self.block,
+                                       window=self.window)
+        elif self.window:
+            attn = flash_attention(q, k, v, causal=True,
+                                   window=self.window,
+                                   name=WINDOW_KERNELS["prefill"],
+                                   interpret=self.interpret)
         elif self.block > 1:
             attn = flash_attention(q, k, v, causal=True,
                                    causal_block=self.block,
@@ -253,15 +295,32 @@ class TPAttention:
         row i sees nothing past ``start + i``, so the buffer's tail
         needs no mask of its own.  Nothing is expanded, so the buffers
         are a gather a layer (`MLAttention.prefill_suffix` hands its
-        own from layer to layer).  Returns (out like x, the chunk's
-        (k, v), each (1, Hkv_loc, C, D), for the cache)."""
+        own from layer to layer).  A WINDOW layer gathers only the
+        ``window`` tokens' pages below ``start`` (``start`` a multiple
+        of the page; ``page_ids`` its own table's row, NULL where a
+        page was given back: nothing there is visible) and runs the
+        windowed kernel over that buffer and the chunk.  Returns (out
+        like x, the chunk's (k, v), each (1, Hkv_loc, C, D), for the
+        cache)."""
         assert self.block <= 1, "a chunk under a block-causal mask"
         k_pool, v_pool = kv_pools
         assert k_pool.dtype != jnp.int8, "chunks over an int8 pool"
         m = x.shape[0] * self.world_size
         q, k, v, gate = self._prefill_heads(x, params, 1,
                                             start + jnp.arange(m))
-        span = page_ids.shape[0] * k_pool.shape[2]
+        ps = k_pool.shape[2]
+        kw = {}
+        if self.window:
+            # the pages that hold the window's tokens below ``start``:
+            # the buffer begins at position ``base``
+            n = min(-(-self.window // ps), page_ids.shape[0])
+            first = jnp.clip(start // ps - n, 0, page_ids.shape[0] - n)
+            page_ids = jax.lax.dynamic_slice_in_dim(page_ids, first, n)
+            start = start - first * ps
+            kw = dict(window=self.window)
+            if self.mode != "xla":
+                kw["name"] = WINDOW_KERNELS["prefill"]
+        span = page_ids.shape[0] * ps
         # whole query blocks, and room for a chunk that starts at the
         # last row the pages reach
         room = -(-span // m) * m + m - span
@@ -279,7 +338,7 @@ class TPAttention:
                   functools.partial(flash_attention,
                                     interpret=self.interpret))
         attn = attend(q, with_prefix(k_pool, k), with_prefix(v_pool, v),
-                      causal=True, kv_offset=start)
+                      causal=True, kv_offset=start, **kw)
         return self._prefill_out(attn, gate, x.dtype, params), (k, v)
 
     def decode(self, x, params, kv_cache, offset, kv_scales=None):
@@ -383,22 +442,16 @@ class TPAttention:
         if self.rope:
             cos, sin = rope_cos_sin(offset, self.head_dim,
                                     self.rope_theta)
-
-            def rope1(x_):  # x_: (B, H, 1, D); cos/sin: (B, D/2)
-                d2 = x_.shape[-1] // 2
-                c = cos[:, None, None, :].astype(jnp.float32)
-                s = sin[:, None, None, :].astype(jnp.float32)
-                x1, x2 = x_[..., :d2], x_[..., d2:]
-                return jnp.concatenate(
-                    [x1 * c - x2 * s, x2 * c + x1 * s],
-                    axis=-1).astype(x_.dtype)
-
-            q = rope1(q)
-            k = rope1(k)
+            # x: (B, H, 1, D); cos/sin: (B, D/2)
+            c = cos[:, None, None, :].astype(jnp.float32)
+            s = sin[:, None, None, :].astype(jnp.float32)
+            q = self._rotate(q, c, s)
+            k = self._rotate(k, c, s)
 
         assert (kv_scales is not None) == (k_pool.dtype == jnp.int8), (
             "int8 pools require kv_scales (and float pools must not "
             "pass them)")
+        assert not (self.window and kv_scales), "a window over int8 pools"
         bidx = jnp.arange(b)
         phys = page_table[bidx, offset // ps]       # (B,)
         within = offset % ps
@@ -419,7 +472,9 @@ class TPAttention:
         out, _ = flash_decode_paged(
             q.reshape(b, self.h_loc, self.head_dim), k_pool, v_pool,
             page_table, offset + 1, k_scale=k_sc, v_scale=v_sc,
-            interpret=self.interpret)
+            interpret=self.interpret,
+            **(dict(window=self.window, name=WINDOW_KERNELS["decode"])
+               if self.window else {}))
         attn = out.reshape(b, self.h_loc * self.head_dim)
         if gate is not None:
             attn = (attn * gate).astype(x.dtype)

@@ -27,4 +27,7 @@ def AutoLLM(config, mesh, **kw):
     if "nemotron_h" in arch or "nemotronh" in arch:
         from triton_distributed_tpu.models.nemotron_h import NemotronH
         return NemotronH(config, mesh, **kw)
+    if "cohere2_moe" in arch or "cohere2moe" in arch:
+        from triton_distributed_tpu.models.cohere2_moe import Cohere2Moe
+        return Cohere2Moe(config, mesh, **kw)
     raise ValueError(f"unknown architecture: {config.architecture}")

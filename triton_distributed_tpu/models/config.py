@@ -103,6 +103,22 @@ class ModelConfig:
     moe_act: str = "silu"
     moe_latent_size: Optional[int] = None
     moe_shared_intermediate_size: Optional[int] = None
+    # Window and full attention mixed (`models/cohere2_moe.py`): layer
+    # l is named by ``layer_types[l]`` — ``"sliding_attention"`` sees
+    # the last ``sliding_window`` tokens and rotates ADJACENT pairs
+    # (``rope_pairs``), ``"full_attention"`` is causal with no
+    # positions — in a PARALLEL block: ONE LayerNorm (``rms_norm_eps``
+    # its epsilon) feeds attention and the expert layer alike, and both
+    # are added to the residual.  The ``n_shared_experts`` are combined
+    # by ``moe_shared_combine`` (`layers.moe_mlp.SparseMoE`), the
+    # sigmoid router has a selection bias or none, the tied head's
+    # logits are scaled by ``logit_scale``.  (): not this family.
+    layer_types: tuple = ()
+    sliding_window: int = 0
+    rope_pairs: bool = False
+    moe_shared_combine: str = "sum"
+    moe_selection_bias: bool = True
+    logit_scale: float = 1.0
 
     @property
     def is_moe(self) -> bool:

@@ -30,6 +30,16 @@ the channels of x, B and C.  A hybrid model's cache holds both kinds:
 recurrent ones, each in layer order; a layer that is a feed-forward
 alone owns nothing here; pages, the page table and the radix cache
 concern the attention layers alone.
+
+A fourth kind keeps rows a token, but only a sequence's LAST ones: a
+SLIDING-WINDOW attention layer (`layers.tp_attn.TPAttention.window`).
+Its K and V ride in lists of their own, ``wks`` / ``wvs`` — in the
+paged layout pools of their own page count, sized by slots x (window +
+slack) and not by tokens, behind a page table of their own
+(``window_table``, indexed by the same logical page as ``page_table``;
+`NULL_PAGE` where a page behind the window went back to the pool:
+`serving.pages`).  ``ks`` / ``vs`` then list the model's FULL attention
+layers alone.
 """
 
 from __future__ import annotations
@@ -63,6 +73,10 @@ class KVCache:
     #: tail must not reach it; attention simply masks the tail later).
     #: `create` says the whole row.
     length: Optional[jnp.ndarray] = None
+    #: Sliding-window layers' rows, shaped like ``ks`` / ``vs`` (module
+    #: docstring); None: there are none.
+    wks: Optional[List[jnp.ndarray]] = None
+    wvs: Optional[List[jnp.ndarray]] = None
 
     @property
     def quantized(self) -> bool:
@@ -72,17 +86,24 @@ class KVCache:
     def create(cls, num_layers: int, batch: int, num_kv_heads: int,
                max_seq: int, head_dim: int, dtype=jnp.bfloat16,
                quantized: bool = False, latent: bool = False,
-               state_shapes=None):
+               state_shapes=None, window_layers: int = 0):
         """``latent``: one ``head_dim``-wide row a token and no V
         (``num_kv_heads`` must be 1).  ``state_shapes``: a recurrent
-        layer's (state, conv) shapes a row, one pair a layer."""
+        layer's (state, conv) shapes a row, one pair a layer.
+        ``window_layers``: sliding-window layers (``num_layers`` counts
+        the others)."""
         assert not latent or (num_kv_heads == 1 and not quantized)
+        assert not window_layers or not (latent or quantized)
         shape = (batch, num_kv_heads, max_seq, head_dim)
         if quantized:
             dtype = jnp.int8
         states, convs = (_state_pools(batch, state_shapes, dtype)
                          if state_shapes else (None, None))
+        windows = lambda: (                               # noqa: E731
+            [jnp.zeros(shape, dtype) for _ in range(window_layers)]
+            if window_layers else None)
         return cls(
+            wks=windows(), wvs=windows(),
             states=states, convs=convs,
             length=(jnp.full((batch,), max_seq, jnp.int32)
                     if state_shapes else None),
@@ -145,6 +166,13 @@ class KVCache:
             vss[layer] = vscale
             rep.update(kss=kss, vss=vss)
         return dataclasses.replace(self, **rep)
+
+    def write_window(self, layer: int, k, v):
+        """`write_prefill` for the ``layer``-th sliding-window layer."""
+        return _with_window(self, layer, *(
+            jax.lax.dynamic_update_slice(
+                dst[layer], src.astype(dst[layer].dtype), (0, 0, 0, 0))
+            for dst, src in ((self.wks, k), (self.wvs, v))))
 
     def set_state(self, layer: int, state, conv):
         """The ``layer``-th recurrent layer's state and inputs."""
@@ -212,6 +240,12 @@ def _with_state(cache, layer: int, state, conv):
     states[layer] = state
     convs[layer] = conv.astype(convs[layer].dtype)
     return dataclasses.replace(cache, states=states, convs=convs)
+
+
+def _with_window(cache, layer: int, k, v):
+    wks, wvs = list(cache.wks), list(cache.wvs)
+    wks[layer], wvs[layer] = k, v
+    return dataclasses.replace(cache, wks=wks, wvs=wvs)
 
 
 def zero_state_rows(states, convs, b):
@@ -290,6 +324,12 @@ class PagedKVCache:
     #: every decode step.  None: there are none.
     states: Optional[List[jnp.ndarray]] = None
     convs: Optional[List[jnp.ndarray]] = None
+    #: Sliding-window layers' pools (Pw, Hkv_loc, page, D) — a page
+    #: count of their own — and their page table (B, T), indexed like
+    #: ``page_table`` (module docstring).  None: there are none.
+    wks: Optional[List[jnp.ndarray]] = None
+    wvs: Optional[List[jnp.ndarray]] = None
+    window_table: Optional[jnp.ndarray] = None
     #: Tokens per page — static: it shapes the compiled programs.
     page_size: int = dataclasses.field(
         default=16, metadata=dict(static=True))
@@ -320,8 +360,12 @@ class PagedKVCache:
                num_kv_heads: int, page_size: int, head_dim: int,
                max_pages_per_seq: int, dtype=jnp.bfloat16,
                quantized: bool = False, latent: bool = False,
-               num_stats: int = 0, state_shapes=None):
-        """``num_pages`` INCLUDES the reserved null page 0 (usable
+               num_stats: int = 0, state_shapes=None,
+               window_layers: int = 0, window_pages: int = 0):
+        """``window_layers`` sliding-window layers share pools of
+        ``window_pages`` pages (the null page among them;
+        ``num_layers`` counts the others).
+        ``num_pages`` INCLUDES the reserved null page 0 (usable
         pages = num_pages - 1).  ``latent``: one pool a layer of
         ``head_dim``-wide rows and no V pool (``num_kv_heads`` 1).
         ``num_stats``: width of the model's `stats` vector.
@@ -334,7 +378,15 @@ class PagedKVCache:
             dtype = jnp.int8
         states, convs = (_state_pools(batch, state_shapes, dtype)
                          if state_shapes else (None, None))
+        assert not window_layers or (window_pages >= 2
+                                     and not (latent or quantized))
+        windows = lambda: (                               # noqa: E731
+            [jnp.zeros((window_pages, *shape[1:]), dtype)
+             for _ in range(window_layers)] if window_layers else None)
         return cls(
+            wks=windows(), wvs=windows(),
+            window_table=(jnp.zeros((batch, max_pages_per_seq), jnp.int32)
+                          if window_layers else None),
             states=states, convs=convs,
             ks=[jnp.zeros(shape, dtype) for _ in range(num_layers)],
             vs=(None if latent else
@@ -364,6 +416,12 @@ class PagedKVCache:
                 total += per_page * (ks_.dtype.itemsize
                                      + vs_.dtype.itemsize)
         return total
+
+    def window_bytes_per_page(self) -> int:
+        """HBM bytes one page of the sliding-window layers' pools pins
+        across those layers (0: the model has none)."""
+        return sum(math.prod(x.shape[1:]) * x.dtype.itemsize
+                   for x in (self.wks or []) + (self.wvs or []))
 
     def state_bytes_per_slot(self) -> int:
         """HBM bytes one slot's recurrent state pins across all layers
@@ -397,6 +455,10 @@ class PagedKVCache:
             rep.update(kss=kss, vss=vss)
         return dataclasses.replace(self, **rep)
 
+    def set_window_layer(self, layer: int, k, v):
+        """The ``layer``-th sliding-window layer's pools."""
+        return _with_window(self, layer, k, v)
+
     def inc_offset(self, n: int = 1):
         return dataclasses.replace(self, offset=self.offset + n)
 
@@ -414,11 +476,14 @@ class PagedKVCache:
             self, offset=jnp.broadcast_to(
                 jnp.asarray(value, jnp.int32), self.offset.shape))
 
-    def with_page_table(self, table):
+    def with_page_table(self, table, window_table=None):
         """Rebind the page table (host mirror → device) without
-        touching the donated pool buffers."""
-        return dataclasses.replace(
-            self, page_table=jnp.asarray(table, jnp.int32))
+        touching the donated pool buffers — and, where there are
+        sliding-window layers, theirs."""
+        rep = dict(page_table=jnp.asarray(table, jnp.int32))
+        if window_table is not None:
+            rep["window_table"] = jnp.asarray(window_table, jnp.int32)
+        return dataclasses.replace(self, **rep)
 
     def gather_logical(self, layer: int):
         """Debug/test helper: reassemble the logical (B, Hkv, T*page,

@@ -447,21 +447,32 @@ def make_paged_rows_fn():
     masked until its last chunk, which takes the insert proper).
     ``pools`` is ``(ks, vs, kss, vss)`` of the paged cache, None where
     it has none; donated, and every pool comes back placed as it went
-    in.  The cache's ``offset`` passes through unchanged: whoever asks
+    in.  ``wpools``, ``window_ids``: ``(wks, wvs)`` of a model with
+    sliding-window layers (donated too) and the pages of THEIR pools
+    the rows go into — the result then has those pools as a third
+    member.  The cache's ``offset`` passes through unchanged: whoever asks
     whether the program enqueued last has finished asks that array
     (`scheduler._starved`), so it has to be this program's output too.
     """
 
-    def rows(pools, offset, row: KVCache, page_ids):
+    def rows(pools, offset, row: KVCache, page_ids, wpools=None,
+             window_ids=None):
         ps = int(pools[0][0].shape[2])
-        return tuple(
+        out = tuple(
             dst if dst is None else _scatter_pages(
                 page_ids, ps, dst, src, scales)
             for dst, src, scales in zip(
                 pools, (row.ks, row.vs, row.kss, row.vss),
-                (False, False, True, True))), offset
+                (False, False, True, True)))
+        if wpools is None:
+            return out, offset
+        # sliding-window layers: their pools, their pages
+        wks, wvs = wpools
+        return out, offset, tuple(
+            _scatter_pages(window_ids, ps, dst, src, False)
+            for dst, src in ((wks, row.wks), (wvs, row.wvs)))
 
-    return jax.jit(rows, donate_argnums=(0, 1))
+    return jax.jit(rows, donate_argnums=(0, 1, 4))
 
 
 def make_paged_insert_fn(donate: bool = True):
@@ -482,7 +493,9 @@ def make_paged_insert_fn(donate: bool = True):
     entries equal to `NULL_PAGE` (0) discard that page's write into
     the reserved trash page — this is how shared prefix pages (owned
     by the radix cache, possibly mapped by other slots) are skipped
-    without recompiling.  The page TABLE is not touched here: it is
+    without recompiling.  ``window_ids``: the same for the pools of a
+    model's sliding-window layers (`models.kv_cache`), which have pages
+    of their own.  The page TABLE is not touched here: it is
     host-managed (`serving.pages.PagedKV`) and re-shipped wholesale
     before the next dispatch.
 
@@ -492,7 +505,8 @@ def make_paged_insert_fn(donate: bool = True):
     in ``page_ids``, so this program is oblivious to sharing.
     """
 
-    def insert(pool, keys, row: KVCache, key, slot, page_ids, offset):
+    def insert(pool, keys, row: KVCache, key, slot, page_ids, offset,
+               window_ids=None):
         scatter = functools.partial(_scatter_pages, page_ids,
                                     pool.page_size)
         rep = dict(ks=scatter(pool.ks, row.ks, False),
@@ -505,6 +519,12 @@ def make_paged_insert_fn(donate: bool = True):
         if pool.quantized:
             rep["kss"] = scatter(pool.kss, row.kss, True)
             rep["vss"] = scatter(pool.vss, row.vss, True)
+        if pool.wks is not None:
+            # sliding-window layers: their own pools, their own pages
+            rep["wks"] = _scatter_pages(window_ids, pool.page_size,
+                                        pool.wks, row.wks, False)
+            rep["wvs"] = _scatter_pages(window_ids, pool.page_size,
+                                        pool.wvs, row.wvs, False)
         if pool.states is not None:
             # recurrent layers: the row's state, whole, into the slot
             def put(dst_list, src_list):

@@ -32,6 +32,18 @@ host-side structures cooperate over one donated `PagedKVCache`:
   that pool is sized by slots, paid for out of the byte budget before
   any page, written whole by the insert and zeroed by `release`; pages,
   prefix sharing and spill concern its attention layers alone.
+  PAGES BY LAYER KIND: a model with sliding-window layers
+  (``model.window`` tokens) keeps THEIR K/V in a second pool behind a
+  second page table (`models.kv_cache`), sized by slots x (window + a
+  page) and paid for out of the byte budget like the state pool.  A
+  row holds there only the pages its next query can still see: what
+  lies behind the window goes back to that pool as the row grows — at
+  every decode dispatch (`ensure`) and between the pieces of a
+  prefill (`insert_rows`) — so that pool never runs dry.  Window pages
+  are private, always: the radix tree, spill and peer shipping know
+  the full layers' pages alone, and a prefix hit on such a model
+  shares those for storage while its prefill covers the prompt from
+  position 0 (`serving.scheduler`).
 
 - `SpillPool` — graceful degradation under KV pressure: when the
   radix cache must evict a refcount-0 prefix page, its CONTENT is
@@ -516,6 +528,10 @@ class PagedKV:
         self.block = int(getattr(model, "block_length", 0) or 0)
         assert self.block <= 1 or ps % self.block == 0, (
             "a block must not straddle a page", ps, self.block)
+        #: > 0: the model's sliding-window layers see that many tokens
+        #: back (module docstring: pages by layer kind).
+        self.window = int(getattr(model, "window", 0) or 0)
+        assert not (self.window and self.block > 1)
         self.max_seq = int(max_seq)
         self.pages_per_seq = t = pages_for(self.max_seq, ps)
         self.num_slots = int(num_slots)
@@ -523,12 +539,25 @@ class PagedKV:
         # parity (every slot can reach max_seq simultaneously).
         probe = model.create_paged_cache(1, 2, ps, 1)
         self.bytes_per_page = probe.bytes_per_page()
+        #: The window layers' pool: what a page of it pins across those
+        #: layers, and the most pages a slot ever holds there — the
+        #: window's and the one its edge crosses.  Sized by slots: no
+        #: allocation from it can fail.
+        self.window_bytes_per_page = (probe.window_bytes_per_page()
+                                      if self.window else 0)
+        self.window_pages_per_slot = (
+            min(pages_for(self.window, ps) + 1, t) if self.window else 0)
+        self.window_usable_pages = (self.num_slots
+                                    * self.window_pages_per_slot)
+        window_pool = (self.window_usable_pages
+                       * self.window_bytes_per_page)
         #: What a slot's recurrent layers hold whatever its length
         #: (0: the model has none).  That pool is sized by slots, not
         #: pages, and a byte budget pays for it first.
         self.state_bytes_per_slot = probe.state_bytes_per_slot()
         del probe
-        state_pool = self.num_slots * self.state_bytes_per_slot
+        state_pool = (self.num_slots * self.state_bytes_per_slot
+                      + window_pool)
         if num_pages is None:
             if kv_budget_bytes:
                 num_pages = int((kv_budget_bytes - state_pool)
@@ -543,7 +572,9 @@ class PagedKV:
         self.kv_budget_bytes = (self.usable_pages * self.bytes_per_page
                                 + state_pool)
         self.cache: PagedKVCache = model.create_paged_cache(
-            self.num_slots, 1 + self.usable_pages, ps, t)
+            self.num_slots, 1 + self.usable_pages, ps, t,
+            **(dict(window_pages=1 + self.window_usable_pages)
+               if self.window else {}))
         self.keys = jnp.zeros((self.num_slots, 2), jnp.uint32)
         self.pool = PagePool(1 + self.usable_pages)
         self.radix = (RadixCache(self.pool, ps) if prefix_cache
@@ -592,6 +623,20 @@ class PagedKV:
         #: slot -> (page-table row to be, prompt length) of the
         #: prefills under way (`begin_prefill` .. `finish_prefill`).
         self._prefilling: Dict[int, tuple] = {}
+        #: The window layers' side (``window`` > 0): their allocator;
+        #: the host mirror of their page table; slot -> its row-to-be
+        #: while its prefill is under way; and of each slot's logical
+        #: pages the first still held and the first not mapped yet
+        #: (everything between is mapped, everything below went back).
+        self.wpool = (PagePool(1 + self.window_usable_pages)
+                      if self.window else None)
+        self._wtable = np.zeros((self.num_slots, t), np.int32)
+        self._wprefilling: Dict[int, np.ndarray] = {}
+        self._wfirst = np.zeros(self.num_slots, np.int64)
+        self._wnext = np.zeros(self.num_slots, np.int64)
+        #: Window pages given back since the start
+        #: (``serving_window_pages_released_total``).
+        self.window_released = 0
         #: Work at the KV boundary since the start: pages `ensure`
         #: mapped, page-table rows `flush` uploaded — mirrored as
         #: ``serving_kv_pages_mapped_total`` /
@@ -657,7 +702,55 @@ class PagedKV:
         recurrent state of the slots in use) — not the max-context
         estimate `SlotKV` reports."""
         return (self.used_pages * self.bytes_per_page
-                + self.active_slots * self.state_bytes_per_slot)
+                + self.active_slots * self.state_bytes_per_slot
+                + self.window_pages_live * self.window_bytes_per_page)
+
+    @property
+    def window_pages_live(self) -> int:
+        """Pages of the window layers' pool that rows hold."""
+        return self.wpool.used_pages if self.wpool else 0
+
+    # -- the window layers' pages (module docstring) ----------------------
+
+    def _window_row(self, slot: int) -> np.ndarray:
+        """``slot``'s row of the window table: the one to be, while its
+        prefill is under way."""
+        row = self._wprefilling.get(slot)
+        return self._wtable[slot] if row is None else row
+
+    def _window_advance(self, slot: int, first: int, end: int) -> None:
+        """``slot`` holds window pages for exactly the logical pages
+        ``[first, end)`` from here on: those below ``first`` go back to
+        their pool (no query of the row sees a token of them again),
+        those up to ``end`` are mapped (never below ``first``: rows
+        nobody will read are not kept)."""
+        row = self._window_row(slot)
+        lo, nxt = int(self._wfirst[slot]), int(self._wnext[slot])
+        if first <= lo and end <= nxt:
+            return
+        if first > lo:
+            gone = [int(p) for p in row[lo:min(first, nxt)]]
+            self.wpool.decref(gone)
+            row[lo:min(first, nxt)] = NULL_PAGE
+            self._wfirst[slot] = first
+            self.window_released += len(gone)
+            if gone:
+                _count_metric("serving_window_pages_released_total",
+                              len(gone))
+        nxt = max(nxt, first)
+        if end > nxt:
+            ids = self.wpool.alloc(end - nxt)
+            assert ids is not None, "the window pool is sized by slots"
+            row[nxt:end] = ids
+            nxt = end
+        self._wnext[slot] = nxt
+        if slot not in self._wprefilling:
+            self._dirty = True
+
+    def prefill_window_pages(self, slot: int) -> np.ndarray:
+        """`prefill_pages` of the window layers' table: what a chunk's
+        program reads ITS predecessors' window rows through."""
+        return self._wprefilling[slot]
 
     def _reclaimable(self) -> int:
         return self.pool.free_pages + (
@@ -801,6 +894,11 @@ class PagedKV:
         if mapped:
             self.mapped_pages += mapped
             _count_metric("serving_kv_pages_mapped_total", mapped)
+        if self.window and self._mapped[slot] >= need:
+            # the dispatch's query stands at ``need_positions - 1``
+            self._window_advance(
+                slot, max(need_positions - self.window, 0)
+                // self.page_size, need)
         return bool(self._mapped[slot] >= need)
 
     def rollback(self, slot: int, keep_positions: int) -> None:
@@ -822,6 +920,7 @@ class PagedKV:
         owner's attention masks ``>= offset`` and its own writes
         precede its reads, the same argument that makes `release`'s
         data-left-in-place free."""
+        assert not self.window, "a rollback over pages given back"
         keep = pages_for(keep_positions, self.page_size)
         assert keep >= len(self._slot_path[slot]), (
             keep, len(self._slot_path[slot]))
@@ -844,7 +943,9 @@ class PagedKV:
         if self._dirty:
             # a copy: the step that gets this table may still be
             # running when the host next edits its mirror
-            self.cache = self.cache.with_page_table(self._table.copy())
+            self.cache = self.cache.with_page_table(
+                self._table.copy(),
+                *((self._wtable.copy(),) if self.window else ()))
             self._dirty = False
             self.flushed_rows += self.num_slots
             _count_metric("serving_kv_table_rows_flushed_total",
@@ -932,6 +1033,10 @@ class PagedKV:
         for i, p in enumerate(priv):
             row[c_pages + i] = p
         self._prefilling[slot] = (row, s)
+        if self.window:
+            # mapped piece by piece, as the rows go in (`insert_rows`)
+            self._wprefilling[slot] = np.full(self.pages_per_seq,
+                                              NULL_PAGE, np.int32)
         self._slot_pages[slot] = list(priv)
         self._slot_path[slot] = list(shared_path)
         return slot
@@ -965,18 +1070,38 @@ class PagedKV:
             g = row_start // ps + j
             if c_pages <= g < total_pages:
                 page_ids[j] = row[g]
+        window = ()
+        if self.window:
+            # The window layers keep of these rows what the row's NEXT
+            # query still sees — the next piece's first, or the first
+            # decode step's at ``s - 1`` — and give back what lies
+            # behind that.  (The piece that read those pages is
+            # enqueued already; their next owner's rows come later.)
+            end = min(row_start + bucket, s)
+            nxt = end if key is None else s - 1
+            first = max(nxt - self.window + 1, 0) // ps
+            self._window_advance(slot, first, pages_for(end, ps))
+            wrow = self._wprefilling[slot]
+            window_ids = np.full(n_row_pages, NULL_PAGE, np.int32)
+            g0 = row_start // ps
+            hi = min(n_row_pages, pages_for(end, ps) - g0)
+            window_ids[:hi] = wrow[g0:g0 + hi]
+            window = (jnp.asarray(window_ids),)
         if key is None:
             c = self.cache
-            (ks, vs, kss, vss), offset = self._put_rows(
+            (ks, vs, kss, vss), offset, *wpools = self._put_rows(
                 (c.ks, c.vs, c.kss, c.vss), c.offset, row_cache,
-                jnp.asarray(page_ids))
-            self.cache = dataclasses.replace(
-                c, ks=ks, vs=vs, kss=kss, vss=vss, offset=offset)
+                jnp.asarray(page_ids),
+                *(((c.wks, c.wvs),) + window if window else ()))
+            rep = dict(ks=ks, vs=vs, kss=kss, vss=vss, offset=offset)
+            if wpools:
+                rep["wks"], rep["wvs"] = wpools[0]
+            self.cache = dataclasses.replace(c, **rep)
             return
         self.cache, self.keys = self._insert(
             self.cache, self.keys, row_cache, key,
             jnp.int32(slot), jnp.asarray(page_ids),
-            jnp.int32(s - 1 if offset is None else offset))
+            jnp.int32(s - 1 if offset is None else offset), *window)
 
     def finish_prefill(self, slot: int, tokens: Sequence[int],
                        offset: Optional[int] = None) -> None:
@@ -990,6 +1115,8 @@ class PagedKV:
         priv = self._slot_pages[slot]
         c_pages = len(shared_path)
         self._table[slot] = row
+        if self.window:
+            self._wtable[slot] = self._wprefilling.pop(slot)
         self._mapped[slot] = pages_for(s, ps)
         self._dirty = True
         self._active[slot] = True
@@ -1081,6 +1208,13 @@ class PagedKV:
         # (a prefill under way is given up with its slot: the pages it
         # holds are the slot's, mapped or not)
         self._prefilling.pop(slot, None)
+        if self.window:
+            # everything the slot still holds of the window pool
+            self.wpool.decref([int(p) for p in self._window_row(slot)[
+                self._wfirst[slot]:self._wnext[slot]]])
+            self._wprefilling.pop(slot, None)
+            self._wtable[slot] = NULL_PAGE
+            self._wfirst[slot] = self._wnext[slot] = 0
         if self._slot_path[slot] and self.radix is not None:
             self.radix.release(self._slot_path[slot])
         self.pool.decref(self._slot_pages[slot])

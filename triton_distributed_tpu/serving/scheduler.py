@@ -457,6 +457,20 @@ class ContinuousBatchingScheduler:
         #: hand slots on every few steps, and a token gap that held a
         #: step and two or three prefills holds one.
         self._paced = bool(self._chunk or self._stateful)
+        #: The model has sliding-window layers (`serving.pages`: pages
+        #: by layer kind): their pages behind the window are gone, so —
+        #: as with a state — a resumed or prefix-sharing request
+        #: prefills from position 0 (the matched pages are shared for
+        #: the full layers' storage), and nothing rolls back.
+        self._windowed = bool(getattr(self.slots, "window", 0))
+        if self._windowed and cfg.spec_k:
+            raise ValueError("speculation over sliding-window layers "
+                             "is not built (no rollback of pages given "
+                             "back)")
+        #: Window pages given back / tokens a window-aware prefix hit
+        #: or resume would have saved, as of the last `serving.window`.
+        self._window_seen = [0, 0]
+        self._window_recomputed = 0
         #: What the decode program leaves in the cache's `stats`, by
         #: name (`_moe_phase`).
         self._stats_names = getattr(model, "STATS", ())
@@ -628,10 +642,12 @@ class ContinuousBatchingScheduler:
             if (full_prefill or not self.paged
                     or self._prefill_suffix is None):
                 return RejectReason.PROMPT_TOO_LONG
+            # some plan of pieces has to cover it (`_plan`): chunks on
+            # a model that chunks, else a cached prefix and the rest
+            # through a bucket
             shared = self.slots.match_prefix(req.prompt)
             c = len(shared) * self.config.page_size
-            if (c == 0 or pick_bucket(req.prompt_len - c,
-                                      self.buckets) is None):
+            if not self._pieces_cover(req.prompt_len, c):
                 return RejectReason.PROMPT_TOO_LONG
         if (req.prompt_len + req.max_new_tokens > self.max_seq + 1
                 and self._block <= 1):
@@ -652,6 +668,20 @@ class ContinuousBatchingScheduler:
             # queueing it would make drain() spin forever.
             return RejectReason.EXCEEDS_KV_CAPACITY
         return None
+
+    def _prefill_from(self, cached: int) -> int:
+        """Where the prefill of a prompt begins whose first ``cached``
+        tokens' pages matched: there — or at 0 on a model with a
+        recurrent state (no snapshot) or sliding-window layers (their
+        pages are nobody's to share)."""
+        return 0 if self._stateful or self._windowed else cached
+
+    def _pieces_cover(self, s: int, cached: int) -> bool:
+        """Does `_plan` find a plan of pieces for a prompt of ``s``
+        tokens past every bucket, ``cached`` of them matched?"""
+        lo = self._prefill_from(cached)
+        return bool((self._chunk and s - lo > self._chunk)
+                    or (lo > 0 and pick_bucket(s - lo, self.buckets)))
 
     def submit(self, req: Request) -> bool:
         """Enqueue; False = rejected with ``req.reject_reason`` set."""
@@ -1103,7 +1133,10 @@ class ContinuousBatchingScheduler:
             row = self._enqueue_piece(adm, sp)
             bucket = adm.pieces[adm.done][1]
             if sp is not NULL_SPAN:
-                sp.attrs["bucket"] = bucket
+                at = adm.pieces[adm.done][0]
+                sp.attrs.update(
+                    bucket=bucket, start=at,
+                    tokens=min(bucket, len(adm.tokens) - at))
                 if len(adm.pieces) > 1:
                     sp.attrs.update(chunk=adm.done,
                                     chunks=len(adm.pieces))
@@ -1221,7 +1254,9 @@ class ContinuousBatchingScheduler:
         chunks where more than the model's chunk length is left; else
         the whole prompt through its bucket.  A model with recurrent
         layers prefills from position 0 whatever matched (its state has
-        no snapshot: the matched pages are shared for storage alone).
+        no snapshot: the matched pages are shared for storage alone),
+        and so does one with sliding-window layers (the matched pages
+        are the full layers'; a window page is never shared).
         None: the request had to be retired at admission (a resumed
         stream that no longer fits any prefill bucket)."""
         tokens = req.resume_tokens or req.prompt
@@ -1230,7 +1265,7 @@ class ContinuousBatchingScheduler:
         c = len(shared) * self.config.page_size
         key = self._request_key(req)
         #: where the prefill begins
-        lo = 0 if self._stateful else c
+        lo = self._prefill_from(c)
         if req.shipped_kv is not None and req.resume_tokens is None:
             # Prefill-worker shipment: the full-prompt row arrives
             # precomputed; shared prefix pages (if any matched) are
@@ -1269,6 +1304,12 @@ class ContinuousBatchingScheduler:
             if reg:
                 reg.counter(
                     "serving_state_recomputed_tokens_total").inc(redone)
+        if self._windowed and redone and adm.row is None:
+            # a hit that kept the prefix's last window would have
+            self._window_recomputed += redone
+            if reg:
+                reg.counter(
+                    "serving_window_recomputed_tokens_total").inc(redone)
         if reg and self.paged:
             reg.counter("serving_prefix_cache_hit_tokens_total").inc(c)
             reg.counter("serving_prefix_cache_miss_tokens_total").inc(
@@ -1346,9 +1387,16 @@ class ContinuousBatchingScheduler:
         pages = (self.slots.prefill_pages(adm.slot)
                  if adm.slot is not None else self._no_pages)
         cache = self.slots.cache
+        pools = (cache.ks, cache.vs)
+        if self._windowed:
+            # the window layers' pools, and their pages in a second row
+            pools += (cache.wks, cache.wvs)
+            pages = np.stack([pages, (
+                self.slots.prefill_window_pages(adm.slot)
+                if adm.slot is not None else self._no_pages)])
         self._starved("prefill", sp)
         row = self._prefill_suffix(self.params, ids, jnp.int32(start),
-                                   row_in, (cache.ks, cache.vs), pages)
+                                   row_in, pools, pages)
         if self._stateful:
             adm.carry = row
         return row
@@ -1592,7 +1640,8 @@ class ContinuousBatchingScheduler:
                 x - y for x, y in zip(work(), before))
             sp.attrs.update(mapped=mapped, flushed_rows=flushed,
                             evicted=evicted, preempted=preempted,
-                            live_pages=slots.live_pages)
+                            live_pages=(slots.live_pages
+                                        + slots.window_pages_live))
         return mapped_all
 
     def _preempt(self, slot: int) -> None:
@@ -1890,6 +1939,8 @@ class ContinuousBatchingScheduler:
         discarded = len(flight.rows) - len(rows)
         if sync is not NULL_SPAN:
             self._read_record(sync, flight, rows, landed, early, late)
+        if self._windowed and reg:
+            self._window_phase(rows, reg)
         # One step's time: from its dispatch — or from when the step
         # before it landed, if that was later: the device runs one
         # step at a time — to its own tokens on the host.
@@ -2077,6 +2128,31 @@ class ContinuousBatchingScheduler:
                 resets=seen[0] - self._state_seen[0],
                 recomputed_tokens=seen[1] - self._state_seen[1])
         self._state_seen = seen
+
+    def _window_phase(self, rows, reg) -> None:
+        """A model with sliding-window layers: a `serving.window` span
+        beside the step's read — what each kind of layer held for the
+        rows of that step (pages, and the tokens its kernel read: the
+        last ``window`` of a row for a window layer, all of them for a
+        full one) and what the host gave back or recomputed since the
+        last one.  All the host's own: no sync."""
+        slots = self.slots
+        lengths = [req.prompt_len + len(req.generated) for _, req in rows]
+        seen = [slots.window_released, self._window_recomputed]
+        with span("serving.window") as sp:
+            sp.attrs.update(
+                window_pages_live=slots.window_pages_live,
+                full_pages_live=slots.live_pages,
+                window_pages_released=seen[0] - self._window_seen[0],
+                recomputed_tokens=seen[1] - self._window_seen[1],
+                window_tokens_live=sum(min(n, slots.window)
+                                       for n in lengths),
+                full_tokens_live=sum(lengths))
+        self._window_seen = seen
+        reg.gauge("serving_kv_pages_live", kind="window").set(
+            slots.window_pages_live)
+        reg.gauge("serving_kv_pages_live", kind="full").set(
+            slots.live_pages)
 
     def _spec_outcome(self, rows, accept_host, n_draft, now,
                       reg) -> None:
