@@ -23,6 +23,7 @@ recurrence itself are float32 on both sides: 1e-4.
 """
 
 import dataclasses
+import functools
 import os
 import subprocess
 import sys
@@ -33,16 +34,18 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
+from cellbench import correctness
 from cellbench.adapters import solar_open2 as adapter
 from cellbench.references import solar_open2 as reference
 from triton_distributed_tpu.kernels import kda
 from triton_distributed_tpu.layers.moe_mlp import HELD_STATS, SparseMoE
-from triton_distributed_tpu.models import AutoLLM, ModelConfig
+from triton_distributed_tpu.models import AutoLLM, ModelConfig, solar_open2
 from triton_distributed_tpu.models.solar_open2 import SolarOpen2
 from triton_distributed_tpu.serving import Request
 from triton_distributed_tpu.serving.engine_batched import (
     pad_prompt, pick_bucket)
 from triton_distributed_tpu.serving.pages import PagedKV
+from tests.test_nemotron_h import Enqueues
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LOGIT_TOL = 0.08
@@ -104,6 +107,12 @@ def _row_for(model, bucket, length):
         length=np.full((1,), length, np.int32))
 
 
+@functools.cache
+def _decode_fn(model):
+    """The jitted paged decode step, traced once for the file."""
+    return jax.jit(model.make_paged_decode_fn(page_size=16))
+
+
 def _serve_logits(system, prompts, steps, teacher):
     """Prefill each prompt through a PADDED bucket, insert it into the
     paged pool and the state pool, then ``steps`` decode steps of the
@@ -113,7 +122,7 @@ def _serve_logits(system, prompts, steps, teacher):
     slots = PagedKV(model, len(prompts), max_seq=128, page_size=16,
                     prefix_cache=False)
     prefill = jax.jit(model.make_prefill_fn())
-    decode = jax.jit(model.make_paged_decode_fn(page_size=16))
+    decode = _decode_fn(model)
     for p in prompts:
         bucket = pick_bucket(len(p), (16, 32, 64, 128))
         ids, s = pad_prompt(p, bucket)
@@ -206,6 +215,105 @@ def test_float8_control_fails_the_tolerance(decoded, row):
     assert (err > LOGIT_TOL).all() and np.median(err) > 2 * LOGIT_TOL
 
 
+#: Tokens a chunk of the tests below: the 21-token prompt goes in two
+#: pieces, the 50-token one in four — the last two tokens long, of
+#: which the state absorbs ONE.
+CHUNK = 16
+
+
+@pytest.fixture(scope="module")
+def decoded_in_chunks(system, decoded):
+    """`decoded`'s two prompts prefilled by suffix calls — each piece
+    over the pages its predecessors filled, from the state and tail
+    they returned — then the same teacher-forced decode steps."""
+    model, params = system.model, system.params
+    prompts, teacher, steps, *_ = decoded
+    slots = PagedKV(model, len(prompts), max_seq=128, page_size=16,
+                    prefix_cache=False)
+    suffix = jax.jit(model.make_prefill_suffix_fn())
+    decode = _decode_fn(model)
+    rows = []
+    for p in prompts:
+        s = len(p)
+        slot = slots.begin_prefill(s, [])
+        row = model.create_cache(1, CHUNK)
+        for at in range(0, s, CHUNK):
+            ids, _ = pad_prompt(p[at:at + CHUNK], CHUNK)
+            row = suffix(
+                params, ids, jnp.int32(at), dataclasses.replace(
+                    row, length=np.full(
+                        (1,), min(max(s - 1 - at, 0), CHUNK), np.int32)),
+                (slots.cache.ks, slots.cache.vs),
+                slots.prefill_pages(slot))
+            last = at + CHUNK >= s
+            slots.insert_rows(slot, row, at, *(
+                [jnp.zeros((2,), jnp.uint32)] if last else []))
+        slots.finish_prefill(slot, p)
+        rows.append(row)
+    got = []
+    tokens = np.asarray([p[-1] for p in prompts], np.int32)
+    for i in range(steps):
+        for b, p in enumerate(prompts):
+            assert slots.ensure(b, len(p) + i)
+        slots.flush()
+        logits, slots.cache = decode(params, jnp.asarray(tokens),
+                                     slots.cache)
+        got.append(np.asarray(logits))
+        tokens = np.asarray([t[i] for t in teacher], np.int32)
+    return np.stack(got), rows
+
+
+@pytest.mark.parametrize("row", [0, 1])
+def test_a_prompt_prefilled_in_chunks_decodes_like_the_whole_prefill(
+        system, decoded, decoded_in_chunks, row):
+    """State, tail and K/V rows after the last chunk are the whole
+    prefill's (the chunks cut the sequence elsewhere: float32 rounding
+    in the state, bfloat16 in what the later layers read), and the
+    decode steps behind them give the whole prefill's logits and the
+    float32 reference's within the file's tolerance — which the float8
+    control fails at every position
+    (`test_float8_control_fails_the_tolerance`, the same sequences)."""
+    prompts, teacher, steps, whole, _ = decoded
+    got, rows = decoded_in_chunks
+    p = prompts[row]
+    ids, s = pad_prompt(p, 64)
+    _, want = jax.jit(system.model.make_prefill_fn())(
+        system.params, ids, _row_for(system.model, 64, s - 1))
+    for a, b in zip(rows[row].states, want.states):
+        assert _close(a, b, 2e-2), float(jnp.abs(a - b).max())
+    for a, b in zip(rows[row].convs, want.convs):
+        assert float(jnp.abs(a.astype(jnp.float32)
+                             - b.astype(jnp.float32)).max()) < 0.1
+    err = np.abs(got[:, row] - whole[:, row]).max(axis=1)
+    assert np.median(err) < LOGIT_TOL / 2, err
+    assert (err > LOGIT_TOL).sum() <= FLIPS, err
+    seq = p + teacher[row][:steps - 1]
+    ref = _ref_logits(seq, len(p) - 1, steps)
+    err = np.abs(got[:, row] - ref).max(axis=1)
+    assert np.median(err) < LOGIT_TOL / 2, err
+    assert (err > LOGIT_TOL).sum() <= FLIPS, err
+
+
+def test_the_chunk_program_names_the_prefills_kernels(system):
+    """A device trace reads a chunk as a prefill: the program's name
+    starts like the whole prefill's and its kernels are the prefill's;
+    no logits, so the head is not in it."""
+    model = system.model
+    fn = jax.jit(model.make_prefill_suffix_fn())
+    cache = system.sched.slots.cache
+    args = (system.params, jnp.zeros((1, 32), jnp.int32), jnp.int32(32),
+            model.create_cache(1, 32), (cache.ks, cache.vs),
+            jnp.zeros((8,), jnp.int32))
+    assert fn.lower(*args).as_text().splitlines()[0].startswith(
+        "module @jit_prefill_shard")
+    text = str(jax.make_jaxpr(fn)(*args))
+    for name in ("kda_prefill_chunk", "moe_prefill_gate_up",
+                 "moe_prefill_down", "flash_attention_fwd"):
+        assert name in text
+    assert "kda_decode_step" not in text
+    assert " cos " not in text and " sin " not in text
+
+
 def test_decode_leaves_its_counts_in_the_cache(decoded, system):
     """`PagedKVCache.stats` after a step, in `SolarOpen2.STATS` order:
     the HELD experts' pairs and those routed elsewhere add up to rows x
@@ -260,6 +368,11 @@ def _delta_inputs(t, b=2, h=8, d=128, seed=0):
     return q, k, v, g, beta
 
 
+def _close(got, want, tol=1e-4):
+    return float(jnp.abs(got - want).max()) < tol * max(
+        1.0, float(jnp.abs(want).max()))
+
+
 def test_chunked_prefill_equals_the_recurrence_at_chunk_boundaries():
     """Two chunks: the outputs of every position, the state after the
     last, and — cut after the first chunk — the state AT the boundary."""
@@ -295,6 +408,110 @@ def test_a_length_that_is_no_multiple_of_the_chunk(system):
     assert (c == c_ref).all()
     _, s77, c77 = golden.prefill(x[:77], p, 1, n)
     assert float(jnp.abs(s - s77).max()) < 1e-4 and (c == c77).all()
+
+
+def test_the_chunked_kernel_starts_from_a_carried_state():
+    """From a state that is not zero: outputs and final state are the
+    recurrence's continued from it; and cut in two calls, the second
+    starting from what the first returned, the kernel gives bit for bit
+    what it gives in one.  Tokens with g = 0 and beta = 0 hand the
+    state back as it went in."""
+    c = kda.CHUNK
+    q, k, v, g, beta = _delta_inputs(2 * c, seed=4)
+    s0 = jax.random.normal(jax.random.key(11), (2, 8, 128, 128))
+    o_ref, s_ref = kda.kda_recurrent_reference(q, k, v, g, beta, s0)
+    o, s = kda.kda_prefill_chunk(q, k, v, g, beta, s0)
+    assert _close(o, o_ref) and _close(s, s_ref)
+    # and it is not the zero state's answer
+    o_zero, _ = kda.kda_recurrent_reference(q, k, v, g, beta)
+    assert not _close(o, o_zero, 1e-2)
+    cut = lambda lo, hi: (  # noqa: E731
+        *(a[:, :, lo:hi] for a in (q, k, v, g)), beta[:, :, lo:hi])
+    o1, s1 = kda.kda_prefill_chunk(*cut(0, c), s0)
+    o2, s2 = kda.kda_prefill_chunk(*cut(c, 2 * c), s1)
+    assert (jnp.concatenate([o1, o2], axis=2) == o).all()
+    assert (s2 == s).all()
+    _, same = kda.kda_prefill_chunk(q[:, :, :c], k[:, :, :c], v[:, :, :c],
+                                    0 * g[:, :, :c], 0 * beta[:, :, :c],
+                                    s1)
+    assert (same == s1).all()
+
+
+@pytest.mark.parametrize("carried", [False, True])
+def test_the_state_is_an_operand_only_where_one_is_carried(carried):
+    """``state=None`` is the whole prefill's program as it was, operand
+    for operand: five inputs, none of the state's shape (a zero state is
+    made in the kernel's scratch).  With a state there is one more, the
+    sixth, of a block of heads' states."""
+    shape = jax.ShapeDtypeStruct((1, 8, 2 * kda.CHUNK, 128), jnp.float32)
+    args = [shape] * 4 + [jax.ShapeDtypeStruct(shape.shape[:3],
+                                               jnp.float32)]
+    if carried:
+        args.append(jax.ShapeDtypeStruct((1, 8, 128, 128), jnp.float32))
+    jaxpr = jax.make_jaxpr(kda.kda_prefill_chunk)(*args)
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert call.params["name"] == "kda_prefill_chunk"
+    shapes = [v.aval.shape for v in call.invars]
+    assert len(shapes) == 5 + carried
+    assert shapes[:5] == [(1, 8, 128, 128)] * 5
+    mapping = call.params["grid_mapping"]
+    assert mapping.num_inputs == 5 + carried and mapping.num_outputs == 2
+    blocks = [bm.block_shape for bm in mapping.block_mappings]
+    assert len(blocks) == 7 + carried
+    # the estimate counts the state's read where there is one
+    cost = call.params["cost_estimate"]
+    seqs = 4 * 8 * 128 * 5 * 128
+    assert cost.bytes_accessed == seqs + carried * 4 * 8 * 128 * 128
+
+
+#: (tokens a piece, the prompt's tokens): the state absorbs all but the
+#: prompt's last token, so the last piece absorbs 35 of 64 (the rest a
+#: padded tail); 33 of 48 in three pieces — pieces that are no whole
+#: kernel chunks; 63 (a last piece that is full); then fewer than the
+#: convolution's 3 kept inputs — 2, 1 and 0.
+PIECES = {"two": (64, 100), "three": (48, 130), "full": (64, 128),
+          "absorbs_2": (64, 67), "absorbs_1": (64, 66),
+          "absorbs_0": (64, 65)}
+
+
+@pytest.mark.parametrize("mode", ["fused", "xla"])
+@pytest.mark.parametrize("case", sorted(PIECES))
+def test_a_prefill_in_pieces_equals_the_prefill_in_one(system, case, mode):
+    """`KDAttention.prefill` piece by piece — each from the state and
+    the convolution's tail the one before it returned — against one
+    prefill over the same rows: output, state and tail.  A last piece
+    that absorbs fewer tokens than the convolution keeps hands on
+    inputs of the piece BEFORE it."""
+    size, t = PIECES[case]
+    layer = dataclasses.replace(system.model.kda, mode=mode)
+    p = system.params["layers"][1]["attn"]
+    rows = -(-t // size) * size
+    x = jax.random.normal(jax.random.key(t), (rows, 128)).astype(
+        jnp.bfloat16)
+    n = t - 1
+    y_ref, s_ref, c_ref = layer.prefill(x, p, 1,
+                                        jnp.asarray([n], jnp.int32))
+    ys, kept = [], ()
+    for at in range(0, rows, size):
+        took = jnp.asarray([min(max(n - at, 0), size)], jnp.int32)
+        y, *kept = layer.prefill(x[at:at + size], p, 1, took, *kept)
+        ys.append(y)
+        if at >= n:                   # absorbed nothing: handed on
+            assert (kept[0] == before[0]).all()
+            assert (kept[1] == before[1]).all()
+        before = kept
+    s, c = kept
+    assert s.dtype == jnp.float32 and c.dtype == jnp.bfloat16
+    assert _close(s, s_ref)
+    assert (c == c_ref).all()
+    err = jnp.abs(jnp.concatenate(ys).astype(jnp.float32)
+                  - y_ref.astype(jnp.float32))
+    assert float(err.max()) < 2e-2, float(err.max())
+    # and the tail is the inputs at positions n-3 .. n-1, which for the
+    # short last pieces lie in the piece before
+    want = jnp.dot(x, p["wqkv"], preferred_element_type=jnp.float32
+                   ).astype(x.dtype)[n - 3:n]
+    assert (c.reshape(3, -1) == want).all()
 
 
 def test_a_prefill_that_continues_into_decode():
@@ -507,6 +724,233 @@ def test_a_preempted_request_resumes_with_the_tokens_it_would_have_had(
     assert resumed == straight
     # what a snapshot of the state would have saved
     assert redone == 14 + had
+
+
+# ---------------------------------------------------------------------------
+# a long prompt goes in by chunks that start from the carried state
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def chunking(devices):
+    """The adapter's system with the model's chunk at test size: the
+    model reads `PREFILL_CHUNK` when it is built."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(solar_open2, "PREFILL_CHUNK", CHUNK)
+        built = adapter.System(TINY, SEED, devices[:1])
+    return built
+
+
+@pytest.fixture(scope="module")
+def chunk_sched(chunking):
+    """ONE scheduler over the chunking model for the tests below (its
+    programs are traced once): four slots; every test leaves it
+    drained."""
+    from triton_distributed_tpu.serving import (
+        ContinuousBatchingScheduler, SchedulerConfig)
+    sched = ContinuousBatchingScheduler(
+        chunking.model, chunking.params, SchedulerConfig(
+            num_slots=4, max_seq=128, kv_layout="paged"))
+    assert sched._chunk == CHUNK and sched._stateful and sched._paced
+    return sched
+
+
+@pytest.fixture
+def watched(chunk_sched):
+    """(the scheduler, its enqueues): the programs wrapped for one test
+    and put back after it."""
+    kept = (chunk_sched._prefill_suffix, chunk_sched._prefill,
+            chunk_sched._step)
+    yield chunk_sched, Enqueues(chunk_sched)
+    assert not chunk_sched.has_work() and chunk_sched._underway is None
+    (chunk_sched._prefill_suffix, chunk_sched._prefill,
+     chunk_sched._step) = kept
+
+
+def _request(prompt, new):
+    return Request(list(prompt), new, eos_token_ids=(), seed=0)
+
+
+def _scored(rows):
+    """What was served against the reference (`cellbench.correctness`,
+    as the cell decides `correct`): limits between the two readings
+    (measured over the four tests that call this, 9-16 tokens each: the
+    program at most 0.0049 / 0.00044 — in three of them every served
+    token is the reference's first — the float8 control at least 0.068
+    / 0.0043)."""
+    sample = [{"index": i, "prompt": r.prompt, "prompt_len": len(r.prompt),
+               "tokens": list(r.generated), "ok": True}
+              for i, r in enumerate(rows)]
+    res = correctness.score(reference, reference.dims_of(TINY), SEED,
+                            sample, 128, 16, control=True)
+    limits = {"served_gap_max": 0.02, "served_gap_mean": 0.0015}
+    assert correctness.judge(res["program"], limits)[0], res
+    assert not correctness.judge(res["control"], limits)[0], res
+
+
+def test_the_model_names_its_chunk(system):
+    """The cell's length: a multiple of the kernel's chunk and of the
+    page, a constant of the model file the scheduler reads."""
+    from triton_distributed_tpu.serving import (
+        ContinuousBatchingScheduler, SchedulerConfig)
+    assert solar_open2.PREFILL_CHUNK % kda.CHUNK == 0
+    assert solar_open2.PREFILL_CHUNK % 16 == 0
+    assert system.model.prefill_chunk == solar_open2.PREFILL_CHUNK
+    sched = ContinuousBatchingScheduler(
+        system.model, system.params, SchedulerConfig(
+            num_slots=2, max_seq=128, kv_layout="paged"))
+    assert sched._chunk == solar_open2.PREFILL_CHUNK
+    assert sched._paced and sched._stateful
+
+
+def test_chunks_start_at_zero_despite_a_prefix_hit_and_carry_the_state(
+        watched):
+    """Two prompts that share their first 32 tokens, one after the
+    other.  The second finds two pages in the radix tree and shares
+    them — for storage: its chunks still cover the prompt from position
+    0 (a state has no snapshot; the tokens a snapshot would have saved
+    are counted), each starts from the state and tail the one before it
+    returned, the reusable zero row is never written, and what is
+    served is the reference's."""
+    from triton_distributed_tpu.observability import get_registry
+    sched, seen = watched
+    reg = get_registry()
+    reg.clear()
+    rng = np.random.default_rng(41)
+    shared = rng.integers(0, 256, 32).tolist()
+    prompts = [shared + rng.integers(0, 256, n).tolist() for n in (18, 9)]
+    kept = sched.slots.cached_prefix_pages
+    served = []
+    for p in prompts:
+        req = _request(p, 6)
+        sched.run([req])
+        served.append(req)
+    a, b = seen.chunks()[:4], seen.chunks()[4:]
+    assert [ev[1] for ev in a] == [0, 16, 32, 48]          # 50 tokens
+    assert [ev[1] for ev in b] == [0, 16, 32]              # 41, hit 32
+    # all but the prompt's last token, never a padded tail
+    assert [ev[2] for ev in a] == [16, 16, 16, 1]
+    assert [ev[2] for ev in b] == [16, 16, 8]
+    zero = sched._row_cache(CHUNK)
+    for pieces in (a, b):
+        assert pieces[0][3].states[0] is zero.states[0]
+        for before, after in zip(pieces, pieces[1:]):
+            for kind in ("states", "convs"):
+                for x, y in zip(getattr(before[4], kind),
+                                getattr(after[3], kind)):
+                    assert x is y
+    assert all(not np.asarray(x).any() for x in zero.states + zero.convs)
+    assert sum(ev == ("prefill",) for ev in seen.log) == 0
+    # the hit shared its pages and saved no compute
+    snap = reg.snapshot()["counters"]
+    assert snap["serving_state_recomputed_tokens_total"] == 32
+    assert snap["serving_prefix_cache_hit_tokens_total"] == 32
+    assert snap["serving_prefill_chunks_total"] == 7
+    # 49 // 16 pages of the first, none new of the second (40 // 16)
+    assert sched.slots.cached_prefix_pages == kept + 3 + 0
+    reg.clear()
+    _scored(served)
+
+
+def test_chunked_streams_equal_unchunked_ones(system, watched):
+    """The same requests through a scheduler that admits them whole
+    (the cell's chunk is longer than any of them) and through one that
+    admits them by chunks of 16, a piece a decode dispatch behind the
+    rows that run: token for token the same streams — a last chunk
+    that is full, one that is padded, one that absorbs nothing (49 =
+    3 x 16 + 1), a prompt of a chunk or less (admitted whole by both) —
+    the running rows get their token every call, and a slot in
+    mid-prefill is masked: the steps in between leave its state row as
+    its release left it."""
+    from triton_distributed_tpu.serving import (
+        ContinuousBatchingScheduler, SchedulerConfig)
+    rng = np.random.default_rng(59)
+    prompts = [rng.integers(0, 256, n).tolist()
+               for n in (9, 64, 45, 49, 16, 50)]
+    assert system.model.prefill_chunk > 64
+    whole = ContinuousBatchingScheduler(
+        system.model, system.params, SchedulerConfig(
+            num_slots=4, max_seq=128, kv_layout="paged"))
+    assert whole._chunk == system.model.prefill_chunk
+    reqs = [_request(p, 8) for p in prompts]
+    whole.run(reqs)
+    sched, seen = watched
+    again = [_request(p, 8) for p in prompts]
+    for r in again:
+        sched.submit(r)
+    mid = 0
+    while sched.has_work():
+        sched.step()
+        adm = sched._underway
+        if adm is not None and adm.slot is not None:
+            mid += 1
+            cache = sched.slots.cache
+            assert all(not np.asarray(x[adm.slot]).any()
+                       for x in cache.states + cache.convs)
+            assert adm.carry is not None
+    assert mid >= 8
+    assert [ev[:2] for ev in seen.log if ev[0] != "step"] == (
+        [("prefill",)] + [("chunk", at) for at in (0, 16, 32, 48)]
+        + [("chunk", at) for at in (0, 16, 32)]
+        + [("chunk", at) for at in (0, 16, 32, 48)] + [("prefill",)]
+        + [("chunk", at) for at in (0, 16, 32, 48)])
+    # at most one enqueue between two decode dispatches while rows run
+    between, n, running = [], 0, False
+    for ev in seen.log:
+        if ev[0] == "step":
+            if running:
+                between.append(n)
+            n, running = 0, True
+        else:
+            n += 1
+    assert max(between) == 1
+    assert [r.generated for r in again] == [r.generated for r in reqs]
+    _scored(again)
+
+
+def test_giving_up_in_mid_prefill_returns_slot_pages_and_carry(watched):
+    sched, seen = watched
+    rng = np.random.default_rng(53)
+    runner = _request(rng.integers(0, 256, 15).tolist(), 12)
+    long = _request(rng.integers(0, 256, 50).tolist(), 4)
+    sched.submit(runner)
+    sched.step()
+    sched.submit(long)
+    sched.step()
+    sched.step()
+    adm = sched._underway
+    assert adm is not None and adm.req is long and adm.done == 2
+    assert adm.carry is not None and adm.slot is not None
+    held = sched.slots.used_pages       # the runner's and 4 of 50 tokens
+    assert sched.slots.free_slots == 2
+    resets = sched.slots.state_resets
+    sched._give_up_underway()
+    assert sched._underway is None and adm.carry is None
+    assert sched.slots.used_pages == held - 4
+    assert sched.slots.free_slots == 3
+    assert sched.slots.state_resets == resets + 1
+    assert sched._queue[0] is long
+    # and it starts over, from position 0 and a zero state
+    del seen.log[:]
+    sched.drain()
+    assert [ev[1] for ev in seen.chunks()] == [0, 16, 32, 48]
+    assert seen.chunks()[0][3].states[0] is sched._row_cache(
+        CHUNK).states[0]
+    assert long.finish_reason == runner.finish_reason
+    assert len(long.generated) == 4 and long.preemptions == 0
+    _scored([runner, long])
+
+
+def test_no_kind_of_chunk_argument_is_first_met_after_warm_up(
+        devices, monkeypatch):
+    """`tests/test_serving_pipeline.py`'s case for this family, whose
+    chunks carry the delta rule's state: the benchmark's own `warm_up`
+    meets every kind of argument of the chunk program — the zero row
+    and a carried one, no pages and the slot's — and of the scatter and
+    the insert behind it; a window's chunked admissions then compile
+    nothing."""
+    from tests import test_serving_pipeline as pipeline
+    pipeline.chunk_arguments_are_met_in_warm_up(
+        "solar_open2", devices, pipeline.Compiled(), monkeypatch)
 
 
 def test_other_families_set_up_without_the_delta_rule_kernels():

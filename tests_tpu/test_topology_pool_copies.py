@@ -241,16 +241,9 @@ def test_no_pool_sized_copy_in_a_chunk_or_in_its_rows(topo_devices):
             assert "flash_attention_fwd" in text
 
 
-def test_no_state_sized_copy_in_step_insert_or_reset(topo_devices):
-    """The hybrid family (`models/solar_open2.py`): a decode step
-    rewrites every live slot's recurrent state — 4 MB a slot a layer —
-    through a kernel that aliases the pool, the insert and the reset
-    write one slot's rows on the leading dimension.  None of the three
-    programs may copy the state pool or the softmax layer's page pools.
-    (The convolution's kept inputs, 0.15 MB a slot a layer, are
-    rewritten whole by every step anyway — the shift — and are not
-    held to it.)"""
-    from triton_distributed_tpu.models.kv_cache import zero_state_rows
+def _solar(devices, layers=LAYERS):
+    """The delta-rule hybrid at the cell's own widths: a softmax layer
+    and ``layers - 1`` delta-rule layers behind it."""
     from triton_distributed_tpu.models.solar_open2 import SolarOpen2
 
     c = _config("solar-open2-250b-1c.json")
@@ -258,7 +251,7 @@ def test_no_state_sized_copy_in_step_insert_or_reset(topo_devices):
     cfg = ModelConfig(
         architecture=c["model_type"], vocab_size=c["vocab_size"],
         hidden_size=c["hidden_size"],
-        intermediate_size=c["intermediate_size"], num_layers=LAYERS,
+        intermediate_size=c["intermediate_size"], num_layers=layers,
         num_heads=c["num_attention_heads"],
         num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
         rms_norm_eps=c["rms_norm_eps"], qk_norm=False,
@@ -274,8 +267,22 @@ def test_no_state_sized_copy_in_step_insert_or_reset(topo_devices):
         kda_head_dim=lin["head_dim"],
         kda_conv_size=lin["short_conv_kernel_size"],
         kda_rank=lin["head_dim"])
-    model = SolarOpen2(cfg, Mesh(np.array(topo_devices[:1]), ("tp",)),
-                       mode="fused", interpret=False)
+    return SolarOpen2(cfg, Mesh(np.array(devices), ("tp",)),
+                      mode="fused", interpret=False)
+
+
+def test_no_state_sized_copy_in_step_insert_or_reset(topo_devices):
+    """The hybrid family (`models/solar_open2.py`): a decode step
+    rewrites every live slot's recurrent state — 4 MB a slot a layer —
+    through a kernel that aliases the pool, the insert and the reset
+    write one slot's rows on the leading dimension.  None of the three
+    programs may copy the state pool or the softmax layer's page pools.
+    (The convolution's kept inputs, 0.15 MB a slot a layer, are
+    rewritten whole by every step anyway — the shift — and are not
+    held to it.)"""
+    from triton_distributed_tpu.models.kv_cache import zero_state_rows
+
+    model = _solar(topo_devices[:1])
     slots = 16
     rep = NamedSharding(model.mesh, P())
     arg = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
@@ -316,6 +323,73 @@ def test_no_state_sized_copy_in_step_insert_or_reset(topo_devices):
             print(f"solar_open2 {name} {what} {shape}: {found}")
             assert not found["layout"], (name, what, found)
             assert len(found["staged"]) <= STAGED_MAX, (name, what, found)
+
+
+def test_the_delta_rule_chunk_reads_the_pools_and_copies_none(
+        topo_devices):
+    """A long prompt of the delta-rule hybrid in chunks
+    (`SolarOpen2.make_prefill_suffix_fn`) at the cell's widths, slots
+    and pool, and the model's own chunk length, for the described v5e:
+    Mosaic takes the chunked delta rule with a carried state as an
+    operand and the gated attention at a traced offset; the chunk
+    program reads the softmax layer's page pools through the request's
+    page ids — gathered rows, never a pool — and the scatter of a
+    middle chunk's rows writes the donated pools where they lie.  The
+    state pool (`f32[192,64,128,128]` a layer) is no argument of
+    either and nothing of its shape is made: a chunk's state rides in
+    the row cache, and the last chunk's insert (the whole prefill's,
+    pinned above) writes it."""
+    model = _solar(topo_devices[:1], layers=3)
+    c = _config("solar-open2-250b-1c.json")["serving"]
+    chunk, slots = model.prefill_chunk, c["num_slots"]
+    per_state = 3 * (64 * 128 * 128 * 4 + 3 * 3 * 8192 * 2)
+    #: the cell's own pool: what its budget leaves of pages beside 192
+    #: slots' states, and the trash page
+    pages = (c["kv_budget_bytes_per_chip"] - slots * per_state) // (
+        4096 * PAGE) + 1
+    table = c["max_seq"] // PAGE
+    assert chunk % PAGE == 0 and chunk < BUCKET and slots == 192
+    rep = NamedSharding(model.mesh, P())
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
+        shape, dt, sharding=rep)
+    pool = _shaped(model, functools.partial(
+        PagedKVCache.create, 1, pages, slots, 8, PAGE, 128, table,
+        model.dtype, num_stats=len(model.STATS),
+        state_shapes=model._state_shapes),
+        model._paged_cache_specs(PAGE))
+    assert pool.states[0].shape == (192, 64, 128, 128)
+    row = _shaped(model, functools.partial(
+        KVCache.create, 1, 1, 8, chunk, 128, model.dtype,
+        state_shapes=model._state_shapes), model._cache_specs())
+    params = _shaped(
+        model, lambda: model.init_params(jax.random.key(0)),
+        model.param_specs())
+    programs = {
+        "chunk": jax.jit(model.make_prefill_suffix_fn()).lower(
+            params, arg((1, chunk), jnp.int32), arg((), jnp.int32), row,
+            (pool.ks, pool.vs), arg((table,), jnp.int32)),
+        "rows": make_paged_rows_fn().lower(
+            (pool.ks, pool.vs, None, None), pool.offset, row,
+            arg((chunk // PAGE,), jnp.int32))}
+    shard = (pages, 8, PAGE, 128)
+    dims = ",".join(str(d) for d in shard)
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        assert f"[{dims}]" in text, name
+        assert "[192,64,128,128]" not in text, name
+        found = pool_copies(text, shard)
+        print(f"solar_open2 {name} of {chunk}, pool {shard}: {found}; "
+              f"temporaries "
+              f"{compiled.memory_analysis().temp_size_in_bytes >> 20} MB")
+        assert not found["layout"], (name, found)
+        assert len(found["staged"]) <= STAGED_MAX, (name, found)
+        if name == "chunk":
+            assert text.startswith("HloModule jit_prefill_shard"), text[:80]
+            for kernel in ("kda_prefill_chunk", "flash_attention_fwd",
+                           "moe_prefill_gate_up", "moe_prefill_down"):
+                assert kernel in text, kernel
+            assert "kda_decode_step" not in text
 
 
 def _nemotron(devices, pattern="MEM*E"):

@@ -13,7 +13,10 @@ u^T``.
 writes each live row's state once, in place, and touches no row that is
 not live.  `kda_prefill_chunk` is the chunked form: within a chunk of
 ``CHUNK`` tokens the WY / UT-transform products run on the MXU and the
-state is carried from chunk to chunk in VMEM.  With ``G_t`` the summed
+state is carried from chunk to chunk in VMEM — from zeros, or from the
+state a predecessor left (``state=``: a long prompt prefilled in
+pieces, each a multiple of ``CHUNK`` tokens; float32 in and out, so the
+pieces compute what one call would).  With ``G_t`` the summed
 log-decay from the chunk's start through ``t``, ``k+_t = k_t exp(G_t)``
 and ``k-_s = k_s exp(-G_s)``:
 
@@ -197,17 +200,19 @@ _NT = (((2,), (2,)), ((0,), (0,)))
 _TN = (((1,), (1,)), ((0,), (0,)))
 
 
-def _prefill_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, so_ref,
-                    s_scr):
+def _prefill_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, *rest):
     """Grid (B, H / hb, T / CHUNK), the chunks in order.  Blocks (1,
     hb, CHUNK, d); ``g`` holds G, the log-decay summed from the chunk's
-    start; kb = b k, vb = b v.  ``s_scr``: the heads' states."""
+    start; kb = b k, vb = b v.  ``rest``: [s0_ref (1, hb, dk, dv), where
+    the caller carries a state in,] o_ref, so_ref, s_scr — the heads'
+    states, which start from ``s0_ref``, else from zeros."""
+    *s0_ref, o_ref, so_ref, s_scr = rest
     c = pl.program_id(2)
     f32 = jnp.float32
 
     @pl.when(c == 0)
     def _():
-        s_scr[...] = jnp.zeros_like(s_scr)
+        s_scr[...] = s0_ref[0][0] if s0_ref else jnp.zeros_like(s_scr)
 
     q, k, kb, vb, g = (r[0] for r in (q_ref, k_ref, kb_ref, vb_ref,
                                       g_ref))
@@ -264,9 +269,12 @@ def _prefill_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, so_ref,
         so_ref[0] = s_new
 
 
-def kda_prefill_chunk(q, k, v, g, beta, *,
+def kda_prefill_chunk(q, k, v, g, beta, state=None, *,
                       interpret: Optional[bool] = None):
-    """The delta rule over whole sequences from a zero state.
+    """The delta rule over T tokens a sequence, from ``state`` — (B, H,
+    dk, dv) float32: what the sequence's earlier tokens left — or,
+    ``None``, from a zero state (a whole prompt; the program then has
+    no such operand).
 
     q, k, g: (B, H, T, dk) — ``g`` the log-decay of each token (<= 0);
     v: (B, H, T, dv); beta: (B, H, T); T a multiple of `CHUNK`.  A
@@ -289,6 +297,11 @@ def kda_prefill_chunk(q, k, v, g, beta, *,
         return pl.BlockSpec((1, hb, CHUNK, d),
                             lambda i, j, c: (i, j, c, 0))
 
+    st = pl.BlockSpec((1, hb, dk, dv), lambda i, j, c: (i, j, 0, 0))
+    carried = [] if state is None else [state]
+    for s0 in carried:
+        assert s0.shape == (b, h, dk, dv), (s0.shape, q.shape, v.shape)
+        assert s0.dtype == f32, s0.dtype
     nc = t // CHUNK
     # per head and chunk: A and QK (2 CHUNK^2 dk), the inverse (10
     # CHUNK^3), T times (b K+, b V), and five products with the state
@@ -302,16 +315,15 @@ def kda_prefill_chunk(q, k, v, g, beta, *,
                    jax.ShapeDtypeStruct((b, h, dk, dv), f32)),
         grid=(b, h // hb, nc),
         in_specs=[seq_spec(dk), seq_spec(dk), seq_spec(dk), seq_spec(dv),
-                  seq_spec(dk)],
-        out_specs=(seq_spec(dv),
-                   pl.BlockSpec((1, hb, dk, dv),
-                                lambda i, j, c: (i, j, 0, 0))),
+                  seq_spec(dk)] + [st] * len(carried),
+        out_specs=(seq_spec(dv), st),
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), f32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=2 * b * h * nc * macs,
-            bytes_accessed=4 * b * h * t * (3 * dk + 2 * dv),
+            bytes_accessed=4 * b * h * (t * (3 * dk + 2 * dv)
+                                        + len(carried) * dk * dv),
             transcendentals=b * h * t * dk * (CHUNK // SUB + 3)),
         interpret=default_interpret(interpret),
-    )(q, k, k * bt, v * bt, gsum)
+    )(q, k, k * bt, v * bt, gsum, *carried)

@@ -17,7 +17,12 @@ With ``x`` the layer's normed input, a row a token:
 The three rank-small input projections (``W_a1``, ``W_g1``, ``W_b``)
 ride side by side as ``w_low``.  The state recurrence is
 `kernels.kda`: the chunked kernel over a prefill, the one-token kernel
-in a decode step.  ONE device: nothing here is sharded.
+in a decode step.  A prefill starts a sequence — a zero state behind a
+convolution window of zeros — or CONTINUES one from the state and the
+kept inputs an earlier prefill returned (``state=``, ``conv_in=``): a
+long prompt goes in in pieces, and the state stays float32 and the kept
+inputs the layer's dtype from piece to piece as they do from step to
+step.  ONE device: nothing here is sharded.
 """
 
 from __future__ import annotations
@@ -123,19 +128,34 @@ class KDAttention:
         y = o.reshape(*o.shape[:-2], self.width) * gate
         return _dot(y.astype(dtype), params["wo"]).astype(dtype)
 
-    def prefill(self, x, params, batch: int, length):
+    def prefill(self, x, params, batch: int, length, state=None,
+                conv_in=None):
         """x: (B * T, hidden); ``length``: (B,) int32 — the tokens of
         each row the state absorbs (positions from there on, a padded
-        tail, leave it as it was).  Returns (y like x, state (B, H, d,
+        tail, leave it as it was).  ``state`` (B, H, d, d) float32 and
+        ``conv_in`` (B, (conv - 1) * 3 * width), both or neither: what
+        a prefill of the rows' EARLIER tokens returned — x then
+        continues those sequences (a long prompt prefilled in pieces);
+        without them the rows start a sequence, from a zero state
+        behind a window of zeros.  Returns (y like x, state (B, H, d,
         d) float32, conv inputs (B, (conv - 1) * 3 * width): the
         projections at positions ``length - conv + 1 .. length - 1``
-        side by side, oldest first, zeros before the start)."""
+        side by side, oldest first — cut from the window the
+        convolution ran over, so a piece that absorbs fewer than ``conv
+        - 1`` tokens hands on inputs of the piece before it, and one
+        that absorbs none hands on what it was given)."""
+        assert (state is None) == (conv_in is None), "both or neither"
         f32 = jnp.float32
         t = x.shape[0] // batch
         taps = self.conv
         proj = _dot(x, params["wqkv"]).astype(x.dtype).reshape(
             batch, t, -1)
-        padded = jnp.pad(proj, ((0, 0), (taps - 1, 0), (0, 0)))
+        if conv_in is None:
+            padded = jnp.pad(proj, ((0, 0), (taps - 1, 0), (0, 0)))
+        else:
+            padded = jnp.concatenate(
+                [conv_in.reshape(batch, taps - 1, -1).astype(proj.dtype),
+                 proj], axis=1)
         w = params["conv"].astype(f32)
         conved = sum(padded[:, i:i + t].astype(f32) * w[i]
                      for i in range(taps))
@@ -147,14 +167,15 @@ class KDAttention:
         seq = lambda a: jnp.moveaxis(a, 1, 2)       # noqa: E731
         q, k, v, g, beta = (seq(a) for a in (q, k, v, g, beta))
         if self.mode == "xla":
-            o, state = kda.kda_recurrent_reference(q, k, v, g, beta)
+            o, state = kda.kda_recurrent_reference(q, k, v, g, beta,
+                                                   state)
         else:
             pad = -t % kda.CHUNK
             if pad:     # whole chunks: g = 0, beta = 0 changes nothing
                 q, k, v, g = (jnp.pad(a, ((0, 0), (0, 0), (0, pad),
                                           (0, 0))) for a in (q, k, v, g))
                 beta = jnp.pad(beta, ((0, 0), (0, 0), (0, pad)))
-            o, state = kda.kda_prefill_chunk(q, k, v, g, beta,
+            o, state = kda.kda_prefill_chunk(q, k, v, g, beta, state,
                                              interpret=self.interpret)
             o = o[:, :, :t]
         y = self._output(seq(o), gate, params, x.dtype)

@@ -23,9 +23,18 @@ prefill reads ``cache.length``: the tokens of each row the state is to
 absorb (never a bucket's padded tail).  A decode step updates the
 state of the LIVE rows only (`PagedKVCache.live_rows`).
 
-ONE device (``tp`` of size 1); tensor parallelism for this family and
-the exchange that would make the held expert layer expert-parallel are
-not built (ROADMAP Reach).
+It also offers `make_prefill_suffix_fn`: a prefill of ONE CHUNK of a
+prompt that begins where its predecessor ended — the delta-rule layers
+from the state and the convolution's tail that one returned (they ride
+in and out in the row cache's ``states`` / ``convs``), the softmax
+layers attending the K/V rows already in the page pool — which is how
+the scheduler carries a long prompt out a chunk a step
+(``prefill_chunk`` tokens: `PREFILL_CHUNK`).  A state has no snapshot,
+so the chunks of a prompt always start at position 0.
+
+ONE device (``tp`` of size 1); tensor parallelism for this family, the
+exchange that would make the held expert layer expert-parallel and a
+snapshot of the state are not built (ROADMAP Reach).
 """
 
 from __future__ import annotations
@@ -45,7 +54,18 @@ from triton_distributed_tpu.layers.tp_attn import TPAttention, rms_norm
 from triton_distributed_tpu.models.config import ModelConfig
 from triton_distributed_tpu.models.kv_cache import KVCache, PagedKVCache
 
-__all__ = ["SolarOpen2"]
+__all__ = ["SolarOpen2", "PREFILL_CHUNK"]
+
+#: Tokens of a prompt the scheduler prefills between two decode steps
+#: (`make_prefill_suffix_fn`); a multiple of `kernels.kda.CHUNK` and of
+#: the page.  A chunk streams the held experts once, so a shorter one
+#: costs device time and a longer one lengthens the token gap of every
+#: running row: settled on the chip by PR 40's rule (of 256 / 512 /
+#: 1024 the shortest that keeps the tokens a second within 5% of the
+#: unchunked prefill's on two seeds: none of the three lost any, a
+#: chunk having no head, no last expert block and no bucket's padding;
+#: PERF.md section 5 has the sweep of PR 45).
+PREFILL_CHUNK = 256
 
 
 class SolarOpen2:
@@ -71,6 +91,7 @@ class SolarOpen2:
         self.mode = mode
         self.interpret = interpret
         self.dtype = jnp.dtype(config.dtype)
+        self.prefill_chunk = PREFILL_CHUNK
         self.attn = TPAttention(
             axis=axis, world_size=1, hidden=config.hidden_size,
             num_heads=config.num_heads,
@@ -182,15 +203,24 @@ class SolarOpen2:
     # per-device forward bodies (called inside shard_map)
     # ------------------------------------------------------------------
 
-    def _layer_fwd_prefill(self, x, lp, length, *, batch, gqa):
+    def _layer_fwd_prefill(self, x, lp, length, kept=(), page_ids=None,
+                           start=None, *, batch, gqa):
         """(x, what the layer leaves in the cache): (k, v) or (state,
-        conv inputs)."""
+        conv inputs).  ``kept``: nothing where the rows start their
+        sequences; for a chunk of one sequence what the layer reads of
+        its earlier tokens — the (k pool, v pool), whose pages
+        ``page_ids`` names, below position ``start``, or the (state,
+        conv inputs) the chunk before it returned."""
         eps = self.config.rms_norm_eps
         h = rms_norm(x, lp["ln1"], eps)
-        if gqa:
-            h, kept = self.attn.prefill(h, lp["attn"], batch)
+        if not gqa:
+            h, *kept = self.kda.prefill(h, lp["attn"], batch, length,
+                                        *kept)
+        elif kept:
+            h, kept = self.attn.prefill_suffix(h, lp["attn"], start, kept,
+                                               page_ids)
         else:
-            h, *kept = self.kda.prefill(h, lp["attn"], batch, length)
+            h, kept = self.attn.prefill(h, lp["attn"], batch)
         x = x + h
         h, _ = self.moe(rms_norm(x, lp["ln2"], eps), lp["mlp"],
                         phase="prefill")
@@ -243,6 +273,40 @@ class SolarOpen2:
         if cache is not None:
             cache = cache.set_offset(s)
         return logits, cache
+
+    def prefill_shard_suffix(self, params, input_ids, start,
+                             cache: KVCache, pools, page_ids):
+        """One chunk of one prompt.  input_ids: (1, C), the tokens at
+        positions ``start + arange(C)`` (a last chunk right-padded);
+        ``cache``: the single-row cache of `create_cache`, C long,
+        whose ``states`` / ``convs`` hold what the chunk before this
+        one returned (zeros in front of the first) and whose ``length``
+        says how many of THIS chunk's tokens the state absorbs;
+        ``pools``: the paged cache's (ks, vs), read and not written —
+        the softmax layers' rows below ``start`` lie there, at the
+        pages ``page_ids`` (T,) names in logical order.  Returns
+        ``cache`` holding the chunk's K/V rows at LOCAL positions
+        [0, C) — the paged insert puts them into their pages — and the
+        state and convolution tail after the chunk: the next chunk's
+        to start from, or the last one's insert's to write into the
+        slot.  No logits: the first decode step recomputes the prompt's
+        last position — so the last layer's expert block is read by
+        nothing and the compiler leaves it out with the head."""
+        b, s = input_ids.shape
+        assert b == 1, "a chunk is one sequence's"
+        ks, vs = pools
+        x = params["embed"][input_ids].reshape(s, -1)
+        start = jnp.asarray(start, jnp.int32).reshape(())
+        layer = self._per_layer(self._layer_fwd_prefill, batch=1)
+        for li, lp in enumerate(params["layers"]):
+            gqa, i = self.is_gqa(li), self._index[li]
+            kept = ((ks[i], vs[i]) if gqa
+                    else (cache.states[i], cache.convs[i]))
+            x, kept = layer[gqa](x, lp, cache.length, kept, page_ids,
+                                 start)
+            cache = (cache.write_prefill(i, *kept) if gqa
+                     else cache.set_state(i, *kept))
+        return cache.set_offset(s)
 
     def decode_shard(self, params, tokens, cache: PagedKVCache):
         """One decode step.  tokens: (B,).  Returns (logits (B, V),
@@ -303,6 +367,18 @@ class SolarOpen2:
                       self._cache_specs()),
             out_specs=(P(None, self.axis), self._cache_specs()),
             check_vma=False)
+
+    def make_prefill_suffix_fn(self):
+        """``(params, ids (1, C), start, row_cache, (ks, vs), page_ids
+        (T,)) -> row_cache``: `prefill_shard_suffix`.  The program's
+        name starts like the whole prefill's, and its kernels are the
+        prefill's, so a device trace reads both alike."""
+        pools = [P(None, None, None, None)] * self.num_gqa
+        return jax.shard_map(
+            self.prefill_shard_suffix, mesh=self.mesh,
+            in_specs=(self.param_specs(), P(None, None), P(),
+                      self._cache_specs(), (pools, pools), P(None)),
+            out_specs=self._cache_specs(), check_vma=False)
 
     def make_paged_decode_fn(self, page_size: int = 16):
         cspecs = self._paged_cache_specs(page_size)
