@@ -22,6 +22,7 @@ attention are float32 on both sides: 2e-5.
 """
 
 import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
@@ -135,28 +136,56 @@ def test_flash_attention_block_causal_against_a_dense_mask(cb, sq, bq,
     assert float(jnp.abs(plain - want).max()) > 0.1
 
 
+@pytest.mark.parametrize("folded", [False, True])
 @pytest.mark.parametrize("n", [4, 8])
-def test_flash_decode_paged_at_a_blocks_query_rows(n):
+def test_flash_decode_paged_at_a_blocks_query_rows(n, folded):
     """8 x n query rows a KV head, all seeing one row's keys through
-    the page table: against dense attention over the gathered pages."""
-    hkv, g, d, ps, t = 2, 8, 16, 8, 5
+    the page table: against dense attention over the gathered pages.
+    ``folded``: twice the rows, a finished block's in front of the
+    block in flight's, the last n keys hidden from the front half —
+    against the dense mask; the back half is bit for bit what the call
+    without the mask returns, and a call that spells the absent mask
+    out is the program it was.  The last row's length lies 2 keys past
+    a gather block's edge, so its hidden keys lie in two of them."""
+    hkv, g, d, ps, t = 2, 8, 16, 8, 66
     key = jax.random.key(n)
-    lens = jnp.asarray([n, 3 * ps + n, 2 * ps], jnp.int32)
+    lens = jnp.asarray([n, 3 * ps + n, 2 * ps, 64 * ps + 2], jnp.int32)
     b = lens.shape[0]
     pools = [jax.random.normal(jax.random.fold_in(key, i),
                                (1 + b * t, hkv, ps, d), jnp.float32)
              for i in (1, 2)]
     table = 1 + jnp.arange(b * t, dtype=jnp.int32).reshape(b, t)
-    q = jax.random.normal(key, (b, hkv * g * n, d), jnp.float32)
-    got, _ = flash_decode_paged(q, *pools, table, lens, interpret=True)
+    rows = g * n * (2 if folded else 1)
+    q = jax.random.normal(key, (b, hkv * rows, d), jnp.float32)
+    plain, _ = flash_decode_paged(q, *pools, table, lens, interpret=True)
+    got = plain
+    if folded:
+        got, _ = flash_decode_paged(q, *pools, table, lens,
+                                    front_hidden=n, interpret=True)
+    front = (np.arange(rows) < rows // 2) & folded
     for row in range(b):
         k, v = (p[table[row]].transpose(1, 0, 2, 3).reshape(hkv, -1, d)
                 [:, :int(lens[row])] for p in pools)
-        qr = q[row].reshape(hkv, g * n, d)
-        p = jax.nn.softmax(
-            jnp.einsum("hqd,hkd->hqk", qr, k) * d ** -0.5, axis=-1)
-        want = jnp.einsum("hqk,hkd->hqd", p, v).reshape(-1, d)
-        assert float(jnp.abs(got[row] - want).max()) < 2e-5
+        qr = q[row].reshape(hkv, rows, d)
+        sc = jnp.einsum("hqd,hkd->hqk", qr, k) * d ** -0.5
+        hidden = front[:, None] & (np.arange(int(lens[row]))[None, :]
+                                   >= int(lens[row]) - n)
+        p = jax.nn.softmax(jnp.where(hidden, -1e30, sc), axis=-1)
+        want = jnp.einsum("hqk,hkd->hqd", p, v)
+        have = got[row].reshape(hkv, rows, d)
+        if folded and int(lens[row]) == n:      # a front that sees nothing
+            want, have = want[:, rows // 2:], have[:, rows // 2:]
+            assert bool(jnp.isfinite(got[row]).all())
+        assert float(jnp.abs(have - want).max()) < 2e-5
+    if folded:
+        back = np.tile(~front, hkv)
+        assert (np.asarray(got)[:, back] == np.asarray(plain)[:, back]).all()
+        assert float(jnp.abs(got - plain).max()) > 1e-3     # it masks
+    a = jax.make_jaxpr(lambda q: flash_decode_paged(
+        q, *pools, table, lens, interpret=True))(q)
+    c = jax.make_jaxpr(lambda q: flash_decode_paged(
+        q, *pools, table, lens, front_hidden=None, interpret=True))(q)
+    assert str(a) == str(c)
 
 
 # ---------------------------------------------------------------------------
@@ -306,10 +335,12 @@ PATTERNS = {"sequential": [(), (0, 1)], "by_confidence": [(), (1, 3)]}
 def test_block_pass_logits_match_reference_at_every_denoise_state(
         system, pattern):
     """Two rows in one batch — a prompt of whole blocks (r = 0) and one
-    with a tail (r = 3) — through three consecutive blocks: two denoise
-    states and the commit pass of each, teacher-forced, K/V through
-    the pages; the logits of all four positions of each pass against
-    the reference's full forward over the sequence as it stands."""
+    with a tail (r = 3) — through three consecutive blocks: the two
+    denoise states of each, teacher-forced, K/V through the pages, the
+    finished block riding in front of the next block's first pass (its
+    commit) and a dead front half in the second; the logits of all
+    four positions of the block in flight against the reference's full
+    forward over the sequence as it stands."""
     rng = np.random.default_rng(2)
     prompts = [rng.integers(0, 255, n).tolist() for n in (12, 23)]
     teacher = [rng.integers(0, 255, 3 * N).tolist() for _ in prompts]
@@ -319,14 +350,15 @@ def test_block_pass_logits_match_reference_at_every_denoise_state(
     done = [list(p[:len(p) // N * N]) for p in prompts]
     tails = [p[len(p) // N * N:] for p in prompts]
     worst, ctrl = [], []
+    before = None
     for blk in range(3):
         full = []
         for b in range(2):
             fill = teacher[b][blk * N:(blk + 1) * N]
             tail = tails[b] if blk == 0 else []
             full.append(list(tail) + fill[len(tail):])
-        passes = PATTERNS[pattern] + [tuple(range(N))]     # + commit
-        for shown in passes:
+        for step, shown in enumerate(PATTERNS[pattern]):
+            folded = step == 0 and blk > 0
             fed = []
             for b in range(2):
                 keep = [j in shown or (blk == 0 and j < len(tails[b]))
@@ -337,8 +369,16 @@ def test_block_pass_logits_match_reference_at_every_denoise_state(
                 assert slots.ensure(b, len(done[b]) + N)
             slots.flush()
             logits, slots.cache = decode(
-                system.params, jnp.asarray(fed, jnp.int32), slots.cache,
-                active)
+                system.params, jnp.asarray(
+                    [(before[b] if folded else [MASK] * N) + fed[b]
+                     for b in range(2)], jnp.int32), slots.cache,
+                active, jnp.full((2,), folded))
+            assert logits.shape == (2, N, 256)
+            if folded:
+                # the pass has written the finished block: the cursor
+                # moves on
+                slots.cache = dataclasses.replace(
+                    slots.cache, offset=slots.cache.offset + N)
             for b in range(2):
                 state = done[b] + fed[b]
                 ref = _state_logits(state, len(done[b]), N)
@@ -347,51 +387,72 @@ def test_block_pass_logits_match_reference_at_every_denoise_state(
                 worst.append(err.max())
                 low = _state_logits(state, len(done[b]), N, "fp8")
                 ctrl.append(np.abs(low - ref).max(axis=-1))
-        # the commit pass has written the block: move the cursor on
-        slots.cache = dataclasses.replace(
-            slots.cache, offset=slots.cache.offset + N)
         for b in range(2):
             done[b] += full[b]
+        before = full
     # the control: float8 fails both halves of the tolerance
     ctrl = np.concatenate(ctrl)
     assert np.median(ctrl) > LOGIT_TOL and (ctrl > LOGIT_TOL).sum() \
         > FLIPS, ctrl
     assert system.model.STATS == MOE_STATS
     pairs, hit, load = np.asarray(slots.cache.stats)
-    assert pairs == 2 * N * 4 * 2 and 1 <= hit <= 32 and 0 < load <= 1
+    # (every row fed is counted, a dead half's too)
+    assert pairs == 2 * 2 * N * 4 * 2 and 1 <= hit <= 32 and 0 < load <= 1
 
 
-def test_the_commit_makes_the_blocks_kv_what_the_next_block_reads(system):
+def test_one_folded_pass_is_the_commit_and_the_next_blocks_first_pass(
+        system):
     """After a block's denoise passes its pages hold the K/V of a
-    half-masked block; only the commit pass leaves what the reference's
-    clean sequence gives — the next block's logits are right after it
-    and wrong without it."""
+    half-masked block.  ONE pass with the finished block in front of
+    the next block leaves the pages what a pass of the finished block
+    alone (a commit with a pass of its own) leaves them, and gives the
+    next block the logits that a pass after such a commit gives it —
+    the reference's over the clean sequence; without any commit they
+    are wrong."""
     rng = np.random.default_rng(3)
     prompt = rng.integers(0, 255, 16).tolist()
     block = rng.integers(0, 255, N).tolist()
     decode = jax.jit(system.model.make_paged_decode_fn(16))
-    active = jnp.ones((1,), bool)
-    ref = _state_logits(prompt + block + [MASK] * N, 16 + N, N)
-    errs = {}
-    for commit in (True, False):
+    active, no = jnp.ones((1,), bool), jnp.zeros((1,), bool)
+    dead, masked = [MASK] * N, [MASK] * N
+    ref = _state_logits(prompt + block + masked, 16 + N, N)
+    errs, logits, rows = {}, {}, {}
+
+    def run(slots, front, back, folded):
+        out, slots.cache = decode(
+            system.params, jnp.asarray([front + back], jnp.int32),
+            slots.cache, active, active if folded else no)
+        return np.asarray(out[0])
+
+    def move_on(slots):
+        slots.cache = dataclasses.replace(
+            slots.cache, offset=slots.cache.offset + N)
+
+    for how in ("folded", "apart", "never"):
         slots = _paged(system, [prompt])
         assert slots.ensure(0, 16 + 2 * N)
         slots.flush()
-        half = [block[0], block[1], MASK, MASK]
-        _, slots.cache = decode(system.params,
-                                jnp.asarray([half], jnp.int32),
-                                slots.cache, active)
-        if commit:
-            _, slots.cache = decode(system.params,
-                                    jnp.asarray([block], jnp.int32),
-                                    slots.cache, active)
-        slots.cache = dataclasses.replace(
-            slots.cache, offset=slots.cache.offset + N)
-        logits, _ = decode(system.params,
-                           jnp.asarray([[MASK] * N], jnp.int32),
-                           slots.cache, active)
-        errs[commit] = np.abs(np.asarray(logits[0]) - ref).max()
-    assert errs[True] < LOGIT_TOL < 2 * LOGIT_TOL < errs[False], errs
+        run(slots, dead, [block[0], block[1], MASK, MASK], False)
+        if how == "folded":
+            logits[how] = run(slots, block, masked, True)
+        else:
+            if how == "apart":
+                run(slots, dead, block, False)
+            move_on(slots)
+            logits[how] = run(slots, dead, masked, False)
+        errs[how] = np.abs(logits[how] - ref).max()
+        page = int(slots._table[0][1])              # positions 16..31
+        rows[how] = [np.asarray(pool[li][page, :, :2 * N], np.float32)
+                     for pool in (slots.cache.ks, slots.cache.vs)
+                     for li in range(2)]
+    assert errs["folded"] < LOGIT_TOL and errs["apart"] < LOGIT_TOL, errs
+    assert errs["never"] > 2 * LOGIT_TOL, errs
+    # the finished block's rows AND the next block's provisional ones
+    for a, c in zip(rows["folded"], rows["apart"]):
+        assert np.abs(a - c).max() < 1e-2, np.abs(a - c).max()
+    assert any(np.abs(a[:, :N] - c[:, :N]).max() > 0.05
+               for a, c in zip(rows["folded"], rows["never"]))
+    assert np.abs(logits["folded"] - logits["apart"]).max() < LOGIT_TOL / 4
 
 
 # ---------------------------------------------------------------------------
@@ -399,53 +460,66 @@ def test_the_commit_makes_the_blocks_kv_what_the_next_block_reads(system):
 # ---------------------------------------------------------------------------
 
 def _fake_decode(logits):
-    """A model half that returns given logits."""
-    def decode(params, tokens, cache, active):
-        return logits, cache
+    """A model half that returns given logits and leaves what it got
+    in the cache."""
+    def decode(params, tokens, cache, active, folded):
+        return logits, dataclasses.replace(cache, got=(tokens, folded))
     return decode
 
 
 @dataclasses.dataclass
 class _Cursor:
     offset: object
+    got: object = None
 
 
-jax.tree_util.register_dataclass(_Cursor, ["offset"], [])
+jax.tree_util.register_dataclass(_Cursor, ["offset", "got"], [])
 
 
 def test_the_reveal_order_under_low_confidence_static_is_by_probability():
-    """On given logits the pass reveals the masked positions whose
-    arg-max is most probable (ties to the left), never a revealed one,
-    and `sequential` the leftmost; a commit row reveals nothing, moves
-    its cursor and hands back an all-masked block; a dead row keeps
-    everything."""
+    """On given logits the pass reveals the masked positions of the
+    block in flight whose arg-max is most probable (ties to the left),
+    never a revealed one, and `sequential` the leftmost.  A row with a
+    finished block in front is folded: the model half is told so, its
+    cursor moves on and its front half is dead afterwards; a block that
+    the pass finishes moves to the front and leaves the next one all
+    masked; a dead row keeps everything."""
     rng = np.random.default_rng(5)
-    logits = jnp.asarray(rng.normal(size=(4, N, 16)) * 3, jnp.float32)
+    logits = jnp.asarray(rng.normal(size=(5, N, 16)) * 3, jnp.float32)
     prob = np.asarray(jax.nn.softmax(logits, -1).max(-1))
     best = np.asarray(logits.argmax(-1))
-    blk = np.zeros((4, 2, N), np.int32)
-    blk[0, :, 0] = (9, 1)                     # row 0: position 0 shown
-    blk[2] = [[5, 6, 7, 8], [1, 1, 1, 1]]     # row 2: finished block
-    args = (jnp.asarray(blk), _Cursor(jnp.asarray([8, 8, 8, 8])),
-            jnp.zeros_like(blk), jnp.zeros(4, bool),
-            jnp.asarray([True, True, True, False]),
-            jnp.asarray([False, False, True, False]),
-            jnp.asarray([2, 3, 0, 2], jnp.int32))
+    blk = np.zeros((5, 2, 2 * N), np.int32)
+    blk[0, :, N] = (9, 1)                     # row 0: position 0 shown
+    blk[2, :, :N] = [[5, 6, 7, 8], [1, 1, 1, 1]]    # row 2: folded
+    blk[3, :, :N] = [[5, 6, 7, 8], [1, 1, 1, 1]]    # row 3: not active
+    blk[4, :, N:N + 2] = [[3, 4], [1, 1]]     # row 4: finishes now
+    args = (jnp.asarray(blk), _Cursor(jnp.asarray([8, 8, 8, 8, 8])),
+            jnp.zeros_like(blk), jnp.zeros(5, bool),
+            jnp.asarray([True, True, True, False, True]),
+            jnp.asarray([2, 3, 2, 2, 2], jnp.int32))
     for how in ("low_confidence_static", "sequential"):
         out, cache = make_block_pass_fn(
             _fake_decode(logits), N, MASK, how, donate=False)(None, *args)
         out = np.asarray(out)
-        for row, k in ((0, 2), (1, 3)):
-            masked = np.flatnonzero(blk[row, 1] == 0)
+        fed, folded = map(np.asarray, cache.got)
+        assert list(folded) == [0, 0, 1, 0, 0]
+        assert list(fed[2]) == [5, 6, 7, 8] + [MASK] * N
+        assert list(fed[0]) == [MASK] * N + [9] + [MASK] * 3
+        for row, k in ((0, 2), (1, 3), (2, 2)):
+            masked = np.flatnonzero(blk[row, 1, N:] == 0)
             order = (masked if how == "sequential" else
                      masked[np.argsort(-prob[row, masked], kind="stable")])
             want = sorted(order[:k])
-            new = np.flatnonzero(out[row, 1] & ~blk[row, 1].astype(bool))
+            new = np.flatnonzero(out[row, 1, N:]
+                                 & ~blk[row, 1, N:].astype(bool))
             assert list(new) == want, (how, row)
-            assert (out[row, 0, new] == best[row, new]).all()
-        assert out[0, 0, 0] == 9 and out[0, 1, 0] == 1
-        assert (out[2, 1] == 0).all() and (out[3] == blk[3]).all()
-        assert list(np.asarray(cache.offset)) == [8, 8, 12, 8]
+            assert (out[row, 0, N + new] == best[row, new]).all()
+            assert (out[row, 1, :N] == 0).all()
+        assert out[0, 0, N] == 9 and out[0, 1, N] == 1
+        assert (out[3] == blk[3]).all()
+        assert list(out[4, 0, :N]) == [3, 4, best[4, 2], best[4, 3]]
+        assert (out[4, 1] == [1] * N + [0] * N).all()
+        assert list(np.asarray(cache.offset)) == [8, 8, 12, 8, 8]
 
 
 def _run(system, prompts, new, **kw):
@@ -499,9 +573,10 @@ def test_tokens_are_delivered_in_position_order_each_once(which, request):
 
 def test_a_batch_whose_rows_are_in_different_phases_equals_each_alone(
         system):
-    """Rows admitted at different steps denoise and commit side by
-    side in one program; every request's stream is what it is served
-    alone."""
+    """Rows admitted at different steps run side by side in one
+    program — a block's first pass, carrying the commit of the block
+    before it, beside another row's second — and every request's
+    stream is what it is served alone."""
     rng = np.random.default_rng(6)
     prompts = [rng.integers(0, 255, n).tolist() for n in (10, 16, 21)]
     new = [9, 12, 6]
@@ -520,10 +595,12 @@ def test_a_batch_whose_rows_are_in_different_phases_equals_each_alone(
         sched.step()
         sched.step()
         live = sched._by_slot.values()
-        phases.add(tuple(sorted(q.block_masked for q in live)))
+        phases.add(tuple(sorted((q.block_masked, q.block_pending)
+                                for q in live)))
     _drain(sched)
     assert [r.generated for r in reqs] == alone
     assert any(len(set(p)) > 1 for p in phases), phases
+    assert any(pending for p in phases for _, pending in p), phases
 
 
 @pytest.fixture
@@ -546,7 +623,10 @@ def test_pipelined_streams_equal_the_serial_loops(system, metrics):
     _drain(sched)
     snap = metrics.snapshot()["counters"]
     assert snap["serving_decode_overlapped_total"] > 4
-    assert snap['serving_diffusion_passes_total{phase="commit"}'] > 0
+    assert 'serving_diffusion_passes_total{phase="commit"}' not in snap
+    assert snap['serving_diffusion_passes_total{phase="folded"}'] > 0
+    assert (snap["serving_diffusion_blocks_committed_total"]
+            == snap['serving_diffusion_passes_total{phase="folded"}'])
     assert (snap["serving_diffusion_tokens_revealed_total"]
             >= sum(new))
     serial, sreqs, _ = _run(system, prompts, new)
@@ -557,14 +637,45 @@ def test_pipelined_streams_equal_the_serial_loops(system, metrics):
     assert [r.generated for r in sreqs] == [r.generated for r in reqs]
 
 
+def test_a_full_block_costs_its_denoise_steps_in_passes(system, metrics):
+    """Three whole blocks of two denoise steps each are six passes of
+    the row — the first of the second and of the third block carry the
+    commit of the block before them, the last block gets none — and
+    the span says so: no pass is a commit alone, and a dead front half
+    is not counted among the positions fed."""
+    from triton_distributed_tpu.observability.tracing import get_tracer
+    rng = np.random.default_rng(15)
+    sched, reqs, _ = _run(system, [rng.integers(0, 255, 8).tolist()],
+                          [3 * N])
+    t0 = time.perf_counter()
+    _drain(sched)
+    assert len(reqs[0].generated) == 3 * N
+    snap = metrics.snapshot()["counters"]
+    assert snap['serving_diffusion_passes_total{phase="denoise"}'] == 4
+    assert snap['serving_diffusion_passes_total{phase="folded"}'] == 2
+    assert 'serving_diffusion_passes_total{phase="commit"}' not in snap
+    assert snap["serving_diffusion_tokens_revealed_total"] == 3 * N
+    spans = [sp.attrs for sp in get_tracer().finished()
+             if sp.name == "serving.diffusion" and sp.t0 > t0]
+    assert [a["rows_folded"] for a in spans] == [0, 0, 1, 0, 1, 0]
+    assert all(a["rows_commit"] == 0 and a["rows_denoise"] == 1
+               and a["blocks_committed"] == a["rows_folded"]
+               and a["positions_fed"] == N * (1 + a["rows_folded"])
+               for a in spans), spans
+    assert int(sched.slots.cache.offset[0]) == 0        # released
+
+
 def test_preempt_and_resume_mid_block(system):
     """The block in flight is dropped and redone from the tokens
-    delivered: the resumed stream is the uninterrupted one."""
+    delivered — a finished block whose commit was still to come is
+    among them, and the resume's prefill writes it: the resumed stream
+    is the uninterrupted one."""
     rng = np.random.default_rng(12)
     prompts = [rng.integers(0, 255, n).tolist() for n in (9, 14)]
     sched, reqs, _ = _run(system, prompts, [16, 16])
     _drain(sched)
     straight = [r.generated for r in reqs]
+    pending = []
     for at in (4, 5, 6):
         sched, reqs, _ = _run(system, prompts, [16, 16])
         for _ in range(at):
@@ -572,10 +683,43 @@ def test_preempt_and_resume_mid_block(system):
         sched._read(sched._take_flight())
         had = len(reqs[1].generated)
         mid = reqs[1].block_masked
+        pending.append(reqs[1].block_pending)
         sched._preempt(reqs[1].slot)
         _drain(sched)
         assert reqs[1].preemptions == 1 and 0 < had < 16
         assert [r.generated for r in reqs] == straight, (at, had, mid)
+    assert True in pending and False in pending, pending
+
+
+def test_a_finished_uncommitted_block_is_private_until_its_commit(system):
+    """A finished block whose commit has not been dispatched is as
+    provisional as the block in flight: its page is the request's
+    alone, a preemption gives it back to the pool and not to the radix
+    tree, and the resume — which prefills that block with the rest —
+    continues the stream."""
+    rng = np.random.default_rng(19)
+    prompt = rng.integers(0, 255, 39).tolist()      # cursor 36: 2 pages
+    sched, reqs, _ = _run(system, [prompt], [12], slots=2)
+    _drain(sched)
+    straight = reqs[0].generated
+    sched, reqs, _ = _run(system, [prompt], [12], slots=2)
+    sched.step()
+    r = reqs[0]
+    # its first pass, revealing the one masked position, is out: block
+    # 36..39 is finished, the cursor still stands at it
+    assert r.block_pending and (r.block_start, r.block_masked) == (40, N)
+    assert int(sched.slots.cache.offset[r.slot]) == 36
+    sched._read(sched._take_flight())
+    assert len(r.generated) == 1
+    own = int(sched.slots._table[r.slot][2])        # positions 32..47
+    assert own in sched.slots._slot_pages[r.slot]
+    assert sched.slots.cached_prefix_pages == 2
+    free = sched.slots.pool.free_pages
+    sched._preempt(r.slot)
+    assert sched.slots.cached_prefix_pages == 2
+    assert sched.slots.pool.free_pages == free + 1
+    _drain(sched)
+    assert r.preemptions == 1 and r.generated == straight
 
 
 def test_a_blocks_provisional_rows_are_never_shared(system):
@@ -589,8 +733,10 @@ def test_a_blocks_provisional_rows_are_never_shared(system):
     sched.step()
     assert sched.slots.cached_prefix_pages == 2
     assert int(sched.slots.cache.offset[reqs[0].slot]) == 36
-    # (its first pass, revealing the one masked position, is out)
-    assert reqs[0].block_start == 36 and reqs[0].block_masked == 0
+    # (its first pass, revealing the one masked position, is out: the
+    # block at the cursor is finished, its commit rides on the next)
+    assert reqs[0].block_pending
+    assert reqs[0].block_start == 40 and reqs[0].block_masked == N
     twin = Request(list(prompt), 6, eos_token_ids=())
     assert sched.submit(twin)
     sched.step()

@@ -550,11 +550,13 @@ def test_the_state_space_chunk_reads_the_pools_and_copies_none(
 
 def test_no_pool_sized_copy_in_a_block_pass(topo_devices):
     """The block-diffusion family (`models/sdar_moe.py`): a pass
-    writes every row's block — four rows a slot a layer — into its
+    writes every row's two block-widths — the block it finished last
+    and its block in flight, eight rows a slot a layer — into its
     mapped pages through the same scatter as a decode step's one row,
-    and `flash_decode_paged` takes 32 query rows a KV head.  Compiled
-    at the published widths for the described v5e: Mosaic takes the
-    kernel at those rows and no pool is copied."""
+    and `flash_decode_paged` takes 64 query rows a KV head, the last
+    four keys hidden from the first half of them.  Compiled at the
+    published widths for the described v5e: Mosaic takes the kernel at
+    those rows and with that mask, and no pool is copied."""
     from triton_distributed_tpu.models.sdar_moe import SdarMoe
     from triton_distributed_tpu.serving.engine_batched import (
         make_block_pass_fn)
@@ -590,11 +592,12 @@ def test_no_pool_sized_copy_in_a_block_pass(topo_devices):
     params = _shaped(
         model, lambda: model.init_params(jax.random.key(0)),
         model.param_specs())
-    blk, flag = arg((slots, 2, n), jnp.int32), arg((slots,), jnp.bool_)
+    blk = arg((slots, 2, 2 * n), jnp.int32)
+    flag = arg((slots,), jnp.bool_)
     step = make_block_pass_fn(
         model.make_paged_decode_fn(PAGE), n, cfg.mask_token_id,
         cfg.remasking).lower(
-            params, blk, pool, blk, flag, flag, flag,
+            params, blk, pool, blk, flag, flag,
             arg((slots,), jnp.int32)).compile().as_text()
     shard = (pages, 4, PAGE, 128)
     for kernel in ("flash_decode_paged", "moe_decode_gate_up",

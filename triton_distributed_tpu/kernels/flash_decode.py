@@ -261,8 +261,8 @@ def _pages_per_block(t: int, hkv: int, ps: int, d: int, dtype) -> int:
 
 
 def _paged_decode_kernel(n, ps, scale, quantized, compute_dtype, window,
-                         kvlen_ref, ptab_ref, q_ref, k_hbm, v_hbm,
-                         *rest):
+                         front_hidden, kvlen_ref, ptab_ref, q_ref, k_hbm,
+                         v_hbm, *rest):
     """Grid: (B,).  One grid step is one row: all its KV heads, and
     only the pages below its length — with ``window``, only the pages
     that hold one of its last ``window`` positions.
@@ -284,6 +284,11 @@ def _paged_decode_kernel(n, ps, scale, quantized, compute_dtype, window,
     holds that position, copies no page below it (the table may map
     those anywhere: their owner gave them back), and masks the first
     block as it masks the last.
+
+    ``front_hidden`` (a static int; None: the program it always was):
+    the FIRST HALF of each KV head's query rows sees no key at or above
+    ``kv_len - front_hidden``; the second half sees them all.  Every
+    block that holds such a key is masked as the last one is.
 
     With ``quantized`` the per-token scales arrive as dense
     (1, Hkv, 1, T * page) rows (gathered by the wrapper) and are
@@ -330,6 +335,13 @@ def _paged_decode_kernel(n, ps, scale, quantized, compute_dtype, window,
             col_live = col < kv_len
             if window:
                 col_live = jnp.logical_and(col_live, col >= first)
+            s_live = col_live
+            if front_hidden:
+                g = q_ref.shape[2]
+                back = jax.lax.broadcasted_iota(
+                    jnp.int32, (g, rows), 0) >= g // 2
+                s_live = jnp.logical_and(col_live, jnp.logical_or(
+                    back, col < kv_len - front_hidden))
         # Unrolled over the KV heads: their chains are independent, and
         # a loop would leave each matmul's latency exposed.
         for h in range(hkv):
@@ -346,7 +358,7 @@ def _paged_decode_kernel(n, ps, scale, quantized, compute_dtype, window,
                 s = s * ks_ref[0, h, :, cols]           # (1, rows)
                 vs = vs_ref[0, h, :, cols]
             if masked:
-                s = jnp.where(col_live, s, NEG_INF)
+                s = jnp.where(s_live, s, NEG_INF)
                 # 0 x NaN: rows no copy wrote must not reach the sums.
                 v = zero_oob_rows(v, blk, rows, kv_len)
                 if window:
@@ -389,6 +401,9 @@ def _paged_decode_kernel(n, ps, scale, quantized, compute_dtype, window,
         edge = blk + 1 == nblk
         if window:
             edge = jnp.logical_or(edge, blk == blk0)
+        if front_hidden:
+            edge = jnp.logical_or(
+                edge, (blk + 1) * rows > kv_len - front_hidden)
 
         @pl.when(jnp.logical_not(edge))
         def _():
@@ -410,6 +425,7 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, kv_len, *,
                        k_scale=None, v_scale=None,
                        scale: Optional[float] = None,
                        window: Optional[int] = None,
+                       front_hidden: Optional[int] = None,
                        name: str = "flash_decode_paged",
                        interpret: Optional[bool] = None):
     """Single-position GQA decode over a PAGED KV pool
@@ -425,10 +441,19 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, kv_len, *,
     ``kv_len[b]`` keys, ``H / Hkv`` consecutive ones to a KV head: one
     position's heads (plain decode: ``G`` a KV head), or — a model that
     generates by blocks, `layers.tp_attn.TPAttention.block_paged` — the
-    heads of all ``n`` positions of the block in flight, laid out
-    ``(Hkv, G * n)``, whose K/V the caller has written into the pages
-    first.  Without a ``window`` nothing is masked inside a row's
-    length either way.
+    heads of the ``2 n`` positions of a finished block and the block in
+    flight, laid out ``(Hkv, 2, G * n)``, whose K/V the caller has
+    written into the pages first.  Without a ``window`` or a
+    ``front_hidden`` nothing is masked inside a row's length.
+
+    ``front_hidden`` (keys, static): the last ``front_hidden`` keys of
+    every row are hidden from the FIRST HALF of each KV head's query
+    rows (``H / Hkv`` even); the second half sees all ``kv_len[b]``.
+    That is the block-causal mask over two blocks in ONE read of the
+    pages: the finished block's queries in front, which must not see
+    the block in flight behind them.  A query row left with no key
+    returns finite numbers that mean nothing.  None compiles to the
+    program without the argument.
 
     ``window`` (tokens, static; a sliding-window layer): row b's query
     stands at position ``kv_len[b] - 1`` and sees key j iff
@@ -494,8 +519,10 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, kv_len, *,
         operands += [dense(k_scale), dense(v_scale)]
 
     window = int(window) if window else None
+    front_hidden = int(front_hidden) if front_hidden else None
+    assert not front_hidden or g % 2 == 0, (g, front_hidden)
     kernel = functools.partial(_paged_decode_kernel, n, ps, scale,
-                               quantized, q.dtype, window)
+                               quantized, q.dtype, window, front_hidden)
     # What the resource sanitizer bounds in place of a BlockSpec index
     # map (`analysis.resources.ManualBlocks`): the pages `gather`
     # copies for row `bb`.

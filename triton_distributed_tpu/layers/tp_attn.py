@@ -483,41 +483,56 @@ class TPAttention:
         return out_x, (k_pool, v_pool), scales
 
     def block_paged(self, x, params, kv_pools, page_table, cursor,
-                    active):
-        """One pass over the block in flight of every row
-        (``self.block`` positions a row): x ``(B * block, hidden)``, a
-        row's positions together; ``cursor`` (B,) int32 the block's
-        first position (a multiple of ``block``, so a block never
-        straddles a page); ``active`` (B,) bool.
+                    active, folded):
+        """One pass over TWO block-widths a row (``2 * self.block``
+        positions): the FRONT half is the block the row has just
+        finished (every token final), the BACK half its block in
+        flight.  x ``(B * 2 * block, hidden)``, a row's positions
+        together, front first; ``cursor`` (B,) int32 the row's first
+        position whose K/V is not final yet (a multiple of ``block``,
+        so a block never straddles a page); ``active``, ``folded`` (B,)
+        bool.  A ``folded`` row's front half stands at ``cursor`` and
+        its back half a block further on; a row whose previous block is
+        committed already (not ``folded``) has a DEAD front half — it
+        stands on the committed block below the cursor, writes nothing
+        and its output means nothing — and its back half at ``cursor``.
 
-        The block's K/V goes into the pages mapped at
-        ``cursor .. cursor + block - 1`` BEFORE attention — provisional
-        rows above the cursor, overwritten by every later pass of the
-        block and final only once the commit pass has written them (an
-        inactive row's go to the trash page) — and every query of the
-        block then sees the same ``cursor + block`` keys: the committed
-        prefix and the whole of its own block.  So the kernel needs no
-        mask: it is `flash_decode_paged` at ``G * block`` query rows a
-        KV head.  Returns (out like x, updated pools)."""
+        The K/V of both halves goes into the mapped pages BEFORE
+        attention: the front half's is FINAL (this is the finished
+        block's commit: it rides on the next block's pass), the back
+        half's provisional — rows above the cursor that every later
+        pass of the block overwrites; a dead front half's and an
+        inactive row's go to the trash page.  Then the front queries
+        see the keys up to the end of their own block and the back
+        queries those and their own block's — block-causal over two
+        blocks — in ONE call of `flash_decode_paged` at ``G * 2 *
+        block`` query rows a KV head, the front half's first, with the
+        back block's keys hidden from them (``front_hidden``), so the
+        pages are read once.  Returns (out like x, updated pools)."""
         from triton_distributed_tpu.models.kv_cache import (
             NULL_PAGE, write_token_rows)
 
         assert self.block > 1 and not self.gate, (self.block, self.gate)
         k_pool, v_pool = kv_pools
         b, n = cursor.shape[0], self.block
+        w = 2 * n
         ps = k_pool.shape[2]
         d, hkv, g = self.head_dim, self.hkv_loc, self.h_loc // self.hkv_loc
-        q, k, v = self._split_heads(self._project_qkv(x, params), b, n)
+        q, k, v = self._split_heads(self._project_qkv(x, params), b, w)
         if self.qk_norm:
             q = rms_norm(q, params["q_norm"])
             k = rms_norm(k, params["k_norm"])
-        pos = cursor[:, None] + jnp.arange(n, dtype=jnp.int32)  # (B, n)
+        first = jnp.where(folded, cursor, cursor - n)
+        place = jnp.arange(w, dtype=jnp.int32)
+        # (a dead front half below position 0 — a prompt shorter than
+        # a block — is no position: it is written nowhere, sees nothing)
+        pos = jnp.maximum(first[:, None] + place, 0)            # (B, 2n)
         if self.rope:
             cos, sin = rope_cos_sin(pos.reshape(-1), d, self.rope_theta)
-            cos = cos.reshape(b, 1, n, d // 2)
-            sin = sin.reshape(b, 1, n, d // 2)
+            cos = cos.reshape(b, 1, w, d // 2)
+            sin = sin.reshape(b, 1, w, d // 2)
 
-            def rope_rows(x_):      # x_: (B, H, n, D)
+            def rope_rows(x_):      # x_: (B, H, 2n, D)
                 x1, x2 = x_[..., :d // 2], x_[..., d // 2:]
                 return jnp.concatenate(
                     [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
@@ -525,19 +540,22 @@ class TPAttention:
 
             q = rope_rows(q)
             k = rope_rows(k)
+        written = active[:, None] & (folded[:, None] | (place >= n))
         phys = jnp.take_along_axis(page_table, pos // ps, axis=1)
-        phys = jnp.where(active[:, None], phys, NULL_PAGE).reshape(-1)
+        phys = jnp.where(written, phys, NULL_PAGE).reshape(-1)
         within = (pos % ps).reshape(-1)
 
-        def rows(t):                # (B, Hkv, n, D) -> (B * n, Hkv, D)
-            return t.transpose(0, 2, 1, 3).reshape(b * n, hkv, d)
+        def rows(t):                # (B, Hkv, 2n, D) -> (B * 2n, Hkv, D)
+            return t.transpose(0, 2, 1, 3).reshape(b * w, hkv, d)
 
         k_pool = write_token_rows(k_pool, phys, within, rows(k))
         v_pool = write_token_rows(v_pool, phys, within, rows(v))
-        # head h reads KV head h // G: a KV head's G * n queries together
+        # head h reads KV head h // G: a KV head's queries together,
+        # the front half's G * n ahead of the back half's
+        q = q.reshape(b, hkv, g, 2, n, d).transpose(0, 1, 3, 2, 4, 5)
         out, _ = flash_decode_paged(
-            q.reshape(b, hkv * g * n, d), k_pool, v_pool, page_table,
-            cursor + n, interpret=self.interpret)
-        attn = out.reshape(b, hkv * g, n, d).transpose(0, 2, 1, 3)
-        out_x = self._out_proj(attn.reshape(b * n, -1), x.dtype, params)
+            q.reshape(b, hkv * 2 * g * n, d), k_pool, v_pool, page_table,
+            first + w, front_hidden=n, interpret=self.interpret)
+        attn = out.reshape(b, hkv, 2, g, n, d).transpose(0, 2, 4, 1, 3, 5)
+        out_x = self._out_proj(attn.reshape(b * w, -1), x.dtype, params)
         return out_x, (k_pool, v_pool)

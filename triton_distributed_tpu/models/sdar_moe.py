@@ -16,29 +16,34 @@ A sequence grows a block of ``B`` positions at a time.  A new block is
 positions (which attend every committed earlier block through the
 pages, and the whole of their own block), takes the arg-max token and
 its probability at each still-masked position and reveals some of
-them; once none is masked a COMMIT pass runs the finished block once
-more, and only then is its K/V what later blocks read.  So a pass
-feeds ``B`` tokens a sequence and yields between 0 and ``B`` of them.
-No position's logits are shifted: position p's logits predict the
-token AT p.
+them.  Once none is masked the block is FINISHED, and later blocks may
+read its K/V only as its final tokens give it: the finished block goes
+through the layers once more — its COMMIT — and that rides on the next
+block's first denoise pass.  So a pass feeds TWO block-widths a
+sequence, the block just finished in front (where there is one: else
+that half is dead) and the block in flight behind it, and yields
+between 1 and ``B`` tokens; a block costs as many passes as it has
+denoise steps, and no pass is a commit alone.  No position's logits are
+shifted: position p's logits predict the token AT p.
 
 The model stands behind the entry points the scheduler calls on the
 other families (`make_prefill_fn`, `make_paged_decode_fn`,
 `create_paged_cache`, `create_cache`); ``block_length`` tells the
 scheduler that its paged step is a block pass
 (`serving.engine_batched.make_block_pass_fn` composes the reveal and
-the commit around `decode_shard`):
+the two halves' turn-over around `decode_shard`):
 
 - the prefill covers the prompt's WHOLE blocks only (the scheduler
   sets the cursor to ``(len // B) * B``; the tail enters the first
   block in flight already revealed: its K/V depends on the tokens
   generated beside it).  Under the block-causal mask those positions
   see nothing at or past the cursor, so the padded bucket is exact;
-- `decode_shard` is one pass: the block's tokens (mask id where not
-  revealed) through the layers, the block's K/V written into its
-  mapped pages and attention over ``cursor + B`` keys
-  (`TPAttention.block_paged`), the head over the ``B`` positions.  The
-  cursor is the engine's to move.
+- `decode_shard` is one pass: both halves' tokens (mask id where not
+  revealed) through the layers, their K/V written into the mapped
+  pages — the front half's final, the back half's provisional — and
+  attention block-causal over the two blocks in one read of the pages
+  (`TPAttention.block_paged`), the head over the ``B`` positions of
+  the block in flight alone.  The cursor is the engine's to move.
 
 ONE device (``tp`` of size 1); block generation at tp > 1 is not built
 (ROADMAP Reach).  Greedy only.
@@ -240,13 +245,14 @@ class SdarMoe:
                         phase="prefill")
         return x + h, kv
 
-    def _layer_fwd_block(self, x, lp, kv, page_table, cursor, active):
+    def _layer_fwd_block(self, x, lp, kv, page_table, cursor, active,
+                         folded):
         eps = self.config.rms_norm_eps
         h, kv = self.attn.block_paged(
             rms_norm(x, lp["ln1"], eps), lp["attn"], kv, page_table,
-            cursor, active)
+            cursor, active, folded)
         x = x + h
-        # (a pass's rows are today's decode rows times the block: the
+        # (a pass's rows are a decode step's times two blocks: the
         # grouped GEMMs keep the decode step's names)
         h, stats = self.moe(rms_norm(x, lp["ln2"], eps), lp["mlp"],
                             phase="decode")
@@ -275,26 +281,34 @@ class SdarMoe:
             cache = cache.set_offset(s)
         return logits, cache
 
-    def decode_shard(self, params, tokens, cache: PagedKVCache, active):
-        """One pass over every row's block in flight.  tokens: (B, n)
-        — the block as it is fed (mask id where not revealed); the
-        block's first position is ``cache.offset``, which does NOT
-        move here; ``active`` (B,) bool (an inactive row writes to the
-        trash page).  Returns (logits (B, n, V) float32, cache) — the
+    def decode_shard(self, params, tokens, cache: PagedKVCache, active,
+                     folded):
+        """One pass over every row's two block-widths.  tokens: (B, 2n)
+        — the block just finished in front, the block in flight behind
+        it, as they are fed (mask id where not revealed); ``folded``
+        (B,) bool: the row HAS a finished block in front, standing at
+        ``cache.offset`` with the block in flight a block further on —
+        else the front half is dead and the block in flight stands at
+        ``cache.offset``, which does NOT move here; ``active`` (B,)
+        bool (an inactive row writes to the trash page).  Returns
+        (logits (B, n, V) float32 OF THE BLOCK IN FLIGHT, cache) — the
         cache's `stats` hold what the expert layers counted
-        (`MOE_STATS`)."""
+        (`MOE_STATS`; every row fed, a dead half's too)."""
         cfg = self.config
-        b, n = tokens.shape
-        assert n == cfg.block_length, (n, cfg.block_length)
-        x = params["embed"][tokens].reshape(b * n, -1)
+        b, w = tokens.shape
+        n = cfg.block_length
+        assert w == 2 * n, (w, n)
+        x = params["embed"][tokens].reshape(b * w, -1)
         layer = jax.jit(self._layer_fwd_block)
         counted = []
         for li, lp in enumerate(params["layers"]):
             x, (k, v), stats = layer(
                 x, lp, (cache.ks[li], cache.vs[li]), cache.page_table,
-                cache.offset, active)
+                cache.offset, active, folded)
             cache = cache.set_layer(li, k, v)
             counted.append(stats)
+        # the finished block's tokens are known: no head over them
+        x = x.reshape(b, w, -1)[:, n:].reshape(b * n, -1)
         x = rms_norm(x, params["ln_f"], cfg.rms_norm_eps)
         logits = jnp.dot(x, params["lm_head"],
                          preferred_element_type=jnp.float32)
@@ -329,8 +343,9 @@ class SdarMoe:
             check_vma=False)
 
     def make_paged_decode_fn(self, page_size: int = 16):
-        """The block pass's model half: ``(params, tokens (B, n), cache,
-        active (B,)) -> (logits (B, n, V), cache)``."""
+        """The block pass's model half: ``(params, tokens (B, 2n),
+        cache, active (B,), folded (B,)) -> (logits (B, n, V),
+        cache)``."""
         assert page_size % self.block_length == 0, (
             "a block must not straddle a page", page_size,
             self.block_length)
@@ -338,7 +353,7 @@ class SdarMoe:
         return jax.shard_map(
             self.decode_shard, mesh=self.mesh,
             in_specs=(self.param_specs(), P(None, None), cspecs,
-                      P(None)),
+                      P(None), P(None)),
             out_specs=(P(None, None, self.axis), cspecs),
             check_vma=False)
 

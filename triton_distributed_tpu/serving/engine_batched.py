@@ -38,8 +38,10 @@ cursor and PRNG key chain rolled back in-program (drafters live in
 
 A fifth, `make_block_pass_fn`, is the step of a model that generates
 by diffusion over blocks (`models.sdar_moe`): every slot's block in
-flight through the model in one of two phases, denoise or commit —
-the block's tokens, its revealed flags and the phase all data.
+flight through the model for one denoise pass, with the block the slot
+finished last in front of it — a finished block's commit rides on the
+next block's first denoise pass, and no pass is a commit alone.  The
+two blocks' tokens and their revealed flags are data.
 """
 
 from __future__ import annotations
@@ -283,54 +285,65 @@ def make_spec_verify_fn(decode_fn, temperature: float = 0.0,
 def make_block_pass_fn(decode_fn, block: int, mask_id: int,
                        remasking: str = "sequential",
                        donate: bool = True):
-    """One pass of generation by diffusion over blocks, for every slot
-    and every mixture of phases: ONE jitted program, in which the
-    phase, the revealed flags, the reveal counts and the commit flag
-    are data.
+    """One pass of generation by diffusion over blocks, for every slot:
+    ONE jitted program, in which the revealed flags, the reveal counts
+    and whether a finished block rides along are data.
 
-    ``(params, blk (B, 2, n) int32, cache, host_blk (B, 2, n), fresh
-    (B,) bool, active (B,) bool, commit (B,) bool, n_reveal (B,) int32)
-    -> (blk (B, 2, n), cache)``
+    ``(params, blk (B, 2, 2n) int32, cache, host_blk (B, 2, 2n), fresh
+    (B,) bool, active (B,) bool, n_reveal (B,) int32) -> (blk (B, 2,
+    2n), cache)``
 
-    ``blk[b, 0]`` are the tokens of row b's block in flight and
-    ``blk[b, 1]`` whether each is REVEALED (a flag, never ``token ==
-    mask_id``: a prompt may hold the mask id and an arg-max may return
-    it); the block's first position is ``cache.offset[b]``.  ``blk``
-    stays on the device between passes — it is what the last pass
-    returned — and rows ``fresh`` take the host's (a newly admitted
-    row: the prompt's tail revealed, the rest masked).
+    A row's state is TWO block-widths.  The BACK half, ``blk[b, :,
+    n:]``, is its block in flight: ``blk[b, 0]`` the tokens and ``blk[b,
+    1]`` whether each is REVEALED (a flag, never ``token == mask_id``:
+    a prompt may hold the mask id and an arg-max may return it).  The
+    FRONT half, ``blk[b, :, :n]``, is the block the row finished last,
+    as long as its K/V in the pages is not final: all flags set — the
+    row is then FOLDED, this pass carries that block's commit — or all
+    clear, a dead half.  The first position that is not final is
+    ``cache.offset[b]``: the front half's where the row is folded,
+    else the back half's.  ``blk`` stays on the device between passes
+    — it is what the last pass returned — and rows ``fresh`` take the
+    host's (a newly admitted row: a dead front half, the prompt's tail
+    revealed in the back half, the rest masked).
 
-    ``decode_fn(params, tokens (B, n), cache, active) -> (logits (B, n,
-    V), cache)`` is fed the block with ``mask_id`` where a position is
-    not revealed; it writes the block's K/V into the pages mapped past
-    the cursor and attends over ``offset + n`` keys.  Then
+    ``decode_fn(params, tokens (B, 2n), cache, active, folded) ->
+    (logits (B, n, V), cache)`` is fed both halves with ``mask_id``
+    where a position is not revealed; it writes their K/V into the
+    pages mapped from the cursor on — a folded row's front half FINAL,
+    every back half provisional — attends block-causally over the two
+    blocks and returns the logits of the block in flight.  Then, for an
+    active row,
 
-    - a DENOISE row (``active & ~commit``) reveals ``n_reveal[b]`` of
-      its masked positions with their arg-max tokens: the leftmost
+    - the block in flight reveals ``n_reveal[b]`` of its masked
+      positions with their arg-max tokens: the leftmost
       (``"sequential"``) or those whose arg-max is most probable
       (``"low_confidence_static"``; ties to the left).  A revealed
       token is never masked again;
-    - a COMMIT row (``active & commit``; its block is fully revealed)
-      reveals nothing: the pass has just written the finished block's
-      K/V, its cursor moves on by ``n`` and the block it returns is
-      all masked — the next block;
-    - an inactive row keeps its block and its cursor.
+    - a folded row's cursor moves on by ``n`` — its finished block is
+      committed — and its front half is dead from here on;
+    - a block in flight with nothing masked left is finished: it moves
+      to the front half, to ride on the next pass, and the back half
+      it leaves is the next block, all masked.  (Where the request ends
+      with it, no pass follows and nothing ever reads its K/V.)
 
-    Greedy only.  The cache is donated (rebind to the returned one);
-    the block state is a few integers a row and is not.
+    An inactive row keeps its state and its cursor.  Greedy only.  The
+    cache is donated (rebind to the returned one); the block state is a
+    few integers a row and is not.
     """
     if remasking not in ("sequential", "low_confidence_static"):
         raise ValueError(f"unknown remasking {remasking!r}")
     n = int(block)
 
-    def block_pass(params, blk, cache, host_blk, fresh, active, commit,
-                   n_reveal):
+    def block_pass(params, blk, cache, host_blk, fresh, active, n_reveal):
         blk = jnp.where(fresh[:, None, None], host_blk, blk)
-        toks, shown = blk[:, 0], blk[:, 1] != 0
+        toks, flags = blk[:, 0], blk[:, 1] != 0
+        folded = active & flags[:, 0]
+        shown = flags[:, n:]
         cursor = cache.offset
         logits, cache = decode_fn(
-            params, jnp.where(shown, toks, jnp.int32(mask_id)), cache,
-            active)
+            params, jnp.where(flags, toks, jnp.int32(mask_id)), cache,
+            active, folded)
         best = jnp.argmax(logits, axis=-1).astype(jnp.int32)   # (B, n)
         place = jnp.arange(n, dtype=jnp.float32)
         if remasking == "sequential":
@@ -346,15 +359,19 @@ def make_block_pass_fn(decode_fn, block: int, mask_id: int,
                  | ((score[:, None, :] == score[:, :, None])
                     & (place[None, None, :] < place[None, :, None])))
         rank = ahead.sum(axis=-1)
-        denoise = active & ~commit
-        reveal = (~shown & (rank < n_reveal[:, None])
-                  & denoise[:, None])
-        toks = jnp.where(reveal, best, toks)
-        done = active & commit
-        shown = (shown | reveal) & ~done[:, None]
+        reveal = ~shown & (rank < n_reveal[:, None])
+        back = jnp.where(reveal, best, toks[:, n:])
+        shown = shown | reveal
+        done = shown.all(axis=1)[:, None]
+        after = jnp.stack([
+            jnp.concatenate([jnp.where(done, back, toks[:, :n]), back],
+                            axis=1),
+            jnp.concatenate([jnp.broadcast_to(done, shown.shape),
+                             shown & ~done], axis=1).astype(jnp.int32),
+        ], axis=1)
         cache = dataclasses.replace(
-            cache, offset=jnp.where(done, cursor + n, cursor))
-        return jnp.stack([toks, shown.astype(jnp.int32)], axis=1), cache
+            cache, offset=jnp.where(folded, cursor + n, cursor))
+        return jnp.where(active[:, None, None], after, blk), cache
 
     if donate:
         return jax.jit(block_pass, donate_argnums=(2,))

@@ -521,7 +521,8 @@ class PagedKV:
         #: (`models.sdar_moe`).  A request then has a BLOCK IN FLIGHT:
         #: the pages mapped at and past its cursor hold provisional
         #: rows that every pass of the block overwrites, final only
-        #: once the commit pass has written them — they are private
+        #: once the pass that carries the block's commit (the next
+        #: block's first) has written them — they are private
         #: (`ensure` allocates them to the slot alone; no radix node
         #: ever covers a position at or past the cursor:
         #: `insert_prefill`) and go back to the pool at `release`.

@@ -110,16 +110,21 @@ class Request:
     shipped_kv: Optional[object] = None
     #: A model that generates by blocks (`models.sdar_moe`): beside
     #: prompt + tokens delivered the request has a BLOCK IN FLIGHT —
-    #: ``block_start`` its first position (the slot's write cursor),
-    #: ``block_masked`` how many of its positions are still masked —
-    #: both AS THE LAST DISPATCH LEAVES THEM: the schedule is static,
-    #: so the host knows a row's next phase without reading a token
-    #: (`ContinuousBatchingScheduler._dispatch_block`).  The block's
-    #: K/V lies in pages past the cursor that nobody else may read
-    #: until the commit; a preemption drops the block and the resume
-    #: redoes it from the tokens delivered.  None: one token a step.
+    #: ``block_start`` its first position, ``block_masked`` how many of
+    #: its positions are still masked — and, where ``block_pending``,
+    #: the block before it FINISHED BUT NOT COMMITTED (the slot's write
+    #: cursor then stands at that block, else at the one in flight):
+    #: its commit rides on the request's next pass.  All three AS THE
+    #: LAST DISPATCH LEAVES THEM: the schedule is static, so the host
+    #: knows what a row's next pass carries without reading a token
+    #: (`ContinuousBatchingScheduler._dispatch_block`).  Both blocks'
+    #: K/V lies in pages at and past the cursor that nobody else may
+    #: read; a preemption drops the block in flight and the resume
+    #: prefills the pending one with the tokens delivered before it
+    #: and redoes the other.  None: one token a step.
     block_start: Optional[int] = None
     block_masked: int = 0
+    block_pending: bool = False
 
     # -- SLO timestamps (scheduler clock, seconds) ---------------------
     t_arrival: Optional[float] = None

@@ -19,13 +19,16 @@ Every `step()` is one scheduler iteration:
    enqueues.  Two paths yield something other than one token a row a
    dispatch.  BLOCK GENERATION (a model with ``block_length``,
    `models.sdar_moe`) is pipelined like the plain step: a dispatch
-   feeds every row's block in flight in one of two phases — denoise
-   (some masked positions revealed) or commit (the finished block's
-   K/V made final, the write cursor moved on by the block) — the
-   block's tokens, revealed flags and phase live on the device
-   between passes (`make_block_pass_fn`), and the reveal schedule is
-   static, so the host knows each row's next phase, its pages and its
-   last pass without reading a token; 0..block tokens a row are
+   feeds every row's block in flight for one denoise pass (some
+   masked positions revealed) and, in front of it, the block the row
+   finished in the pass before — that block's COMMIT (its K/V made
+   final, the write cursor moved on by the block) rides on the next
+   block's first denoise pass, so a block costs as many passes as it
+   has denoise steps and no pass is a commit alone.  Both blocks'
+   tokens and revealed flags live on the device between passes
+   (`make_block_pass_fn`), and the reveal schedule is static, so the
+   host knows what each row's next pass carries, its pages and its
+   last pass without reading a token; 1..block tokens a row are
    delivered a pass, in position order.  With ``spec_k`` set the
    scheduler stays SERIAL (a drafter needs the committed tokens on
    the host, and a verify round's yield is not known before it is
@@ -319,9 +322,11 @@ class _Flight:
     #: Verify round only: accept lengths (device), proposals (host).
     accept: object = None
     n_draft: object = None
-    #: Block pass only (``toks`` is then the (B, 2, n) block state it
-    #: returned): slot -> (first position of the row's block, positions
-    #: it reveals) of the DENOISE rows; the rest of ``rows`` committed.
+    #: Block pass only (``toks`` is then the (B, 2, 2n) block state it
+    #: returned): slot -> (first position of the row's block in
+    #: flight, positions it reveals, whether the pass also carried the
+    #: commit of the block before it — FOLDED —, whether it finished
+    #: the block: its tokens then stand in the state's front half).
     denoised: Optional[Dict[int, tuple]] = None
     #: (bucket, request) of the prefills enqueued since the dispatch
     #: before this one: the device runs them first, so the read of
@@ -491,10 +496,11 @@ class ContinuousBatchingScheduler:
             #: always the leftmost masked ones.
             self._reveal = self._block // gen.denoising_steps
             self._sequential = gen.remasking == "sequential"
-            #: The host's word for the block of a newly admitted row
-            #: (`_fresh`): the prompt's tail revealed, the rest masked.
+            #: The host's word for the two block-widths of a newly
+            #: admitted row (`_fresh`): a dead front half; in the back
+            #: half the prompt's tail revealed, the rest masked.
             self._blk_host = np.zeros(
-                (cfg.num_slots, 2, self._block), np.int32)
+                (cfg.num_slots, 2, 2 * self._block), np.int32)
             #: Revealed and not yet deliverable, as of the last read.
             self._held_back = 0
         else:
@@ -555,7 +561,7 @@ class ContinuousBatchingScheduler:
         #: with the model's parameters — so that the merged tokens
         #: reach `_step` placed alike from the first dispatch on and
         #: the decode program is not compiled once more for it.
-        # (A block pass returns, and takes, the (B, 2, n) block state.)
+        # (A block pass returns, and takes, the (B, 2, 2n) block state.)
         self._prev = jax.device_put(
             self._blk_host.copy() if self._block > 1
             else np.zeros(cfg.num_slots, np.int32),
@@ -1420,28 +1426,31 @@ class ContinuousBatchingScheduler:
 
     def _start_block(self, slot: int, req: Request, tokens) -> None:
         """Admission (or resume) of a block-generating request: the
-        prefill left the whole blocks of ``tokens`` below the cursor;
-        what is left of them enters the first block in flight already
-        revealed, the rest of it masked.  A resume drops the block
-        that was in flight and redoes it from the tokens delivered."""
+        prefill left the whole blocks of ``tokens`` below the cursor —
+        a finished block whose commit was still to come among them —
+        and what is left of them enters the first block in flight
+        already revealed, the rest of it masked.  A resume drops the
+        block that was in flight and redoes it from the tokens
+        delivered."""
         n = self._block
         start = len(tokens) // n * n
         tail = list(tokens[start:])
         blk = self._blk_host[slot]
         blk[:] = 0
-        blk[0, :len(tail)] = tail
-        blk[1, :len(tail)] = 1
+        blk[0, n:n + len(tail)] = tail
+        blk[1, n:n + len(tail)] = 1
         req.block_start = start
         req.block_masked = n - len(tail)
+        req.block_pending = False
 
     def _block_ends(self, req: Request) -> bool:
         """True once the passes DISPATCHED for ``req`` reveal its last
         token and every position before it: no further pass is
-        dispatched, and the last block gets no commit pass (nothing
-        will read it).  Sequential reveals leave a revealed prefix, so
-        that is known position by position; otherwise only a block
-        with nothing masked is known to hold its every token — the
-        last block then runs whole."""
+        dispatched, so no pass carries the last block's commit
+        (nothing will read it).  Sequential reveals leave a revealed
+        prefix, so that is known position by position; otherwise only
+        a block with nothing masked is known to hold its every token —
+        the last block then runs whole."""
         end = req.prompt_len + req.max_new_tokens
         edge = req.block_start + self._block
         if self._sequential:
@@ -1450,36 +1459,39 @@ class ContinuousBatchingScheduler:
 
     def _dispatch_block(self, rows: Dict[int, Request], t0: float,
                         inflight: bool) -> _Flight:
-        """Enqueue one block pass for ``rows``: each row in the phase
-        its own schedule says — commit once nothing of its block is
-        masked, else a denoise pass revealing its next share — and
-        move the host's picture of each row on.  Nothing here reads
-        the device."""
+        """Enqueue one block pass for ``rows`` — each row's block in
+        flight reveals its next share, and a row whose block before it
+        is finished and not committed yet (`Request.block_pending`)
+        has that commit carried along — and move the host's picture of
+        each row on as the program moves its own: a block that this
+        pass finishes becomes the pending one and the next block, all
+        masked, the one in flight, unless the request ends with it.
+        Nothing here reads the device."""
         n = self._block
         slots = self.config.num_slots
         active = np.zeros(slots, bool)
-        commit = np.zeros(slots, bool)
         n_reveal = np.zeros(slots, np.int32)
         denoised = {}
         self._count_dispatch(inflight)
         for slot, req in rows.items():
             active[slot] = True
-            if req.block_masked == 0:
-                commit[slot] = True
+            k = min(self._reveal, req.block_masked)
+            n_reveal[slot] = k
+            req.block_masked -= k
+            finished = req.block_masked == 0
+            denoised[slot] = (req.block_start, k, req.block_pending,
+                              finished)
+            req.block_pending = finished and not self._block_ends(req)
+            if req.block_pending:
                 req.block_start += n
                 req.block_masked = n
-            else:
-                k = min(self._reveal, req.block_masked)
-                n_reveal[slot] = k
-                req.block_masked -= k
-                denoised[slot] = (req.block_start, k)
         with span("serving.dispatch", k=n, spec=False,
                   inflight=int(inflight)) as sp:
             self._starved("step", sp)
             blk, cache = self._step(
                 self.params, self._prev, self.slots.cache,
                 self._blk_host.copy(), self._fresh.copy(), active,
-                commit, n_reveal)
+                n_reveal)
             self.slots.cache = cache
         self._prev = blk
         self._fresh[:] = False
@@ -1491,16 +1503,15 @@ class ContinuousBatchingScheduler:
         """Deliver what one block pass revealed: a token reaches its
         request as soon as it and every earlier position are revealed
         — in position order, each once; positions past the request's
-        length are never delivered.  A commit row delivers nothing.
+        length are never delivered.  (A block the pass finished stands
+        in the front half of the state it returned.)
         Returns (rows retired, tokens delivered)."""
         n = self._block
         retired = generated = held = 0
         for slot, req in rows:
-            at = flight.denoised.get(slot)
-            if at is None:
-                continue
-            start = at[0]
-            toks, shown = blk_host[slot]
+            start, _, _, finished = flight.denoised[slot]
+            half = slice(0, n) if finished else slice(n, 2 * n)
+            toks, shown = blk_host[slot][:, half]
             j = req.prompt_len + len(req.generated) - start
             done = False
             while j < n and shown[j] and not done:
@@ -1520,26 +1531,32 @@ class ContinuousBatchingScheduler:
                          reg) -> None:
         """After a block pass's host sync and commit: what the pass
         was, as a `serving.diffusion` span's attributes and as metrics
-        (all the host's own counts: no sync of its own)."""
+        (all the host's own counts: no sync of its own).  Every row's
+        pass is a denoise pass; ``rows_folded`` of them carried the
+        commit of the block before theirs, and none is a commit alone
+        (``rows_commit`` stands at 0: the benchmark's reader of tokens
+        a pass sums it with ``rows_denoise``).  ``positions_fed``
+        counts the positions that carry work: a dead front half is
+        padding."""
         n = self._block
         denoise = len(flight.denoised)
-        commits = len(flight.rows) - denoise
-        revealed = sum(k for _, k in flight.denoised.values())
+        folded = sum(f for _, _, f, _ in flight.denoised.values())
+        revealed = sum(k for _, k, _, _ in flight.denoised.values())
         with span("serving.diffusion") as sp:
             sp.attrs.update(
-                rows_denoise=denoise, rows_commit=commits,
-                positions_fed=len(flight.rows) * n,
+                rows_denoise=denoise, rows_folded=folded, rows_commit=0,
+                positions_fed=n * (denoise + folded),
                 tokens_revealed=revealed, tokens_delivered=delivered,
-                blocks_committed=commits)
+                blocks_committed=folded)
         if reg:
             reg.counter("serving_diffusion_passes_total",
-                        phase="denoise").inc(denoise)
+                        phase="denoise").inc(denoise - folded)
             reg.counter("serving_diffusion_passes_total",
-                        phase="commit").inc(commits)
+                        phase="folded").inc(folded)
             reg.counter(
                 "serving_diffusion_tokens_revealed_total").inc(revealed)
             reg.counter(
-                "serving_diffusion_blocks_committed_total").inc(commits)
+                "serving_diffusion_blocks_committed_total").inc(folded)
             reg.gauge("serving_diffusion_tokens_held_back").set(
                 self._held_back)
 
@@ -1586,9 +1603,9 @@ class ContinuousBatchingScheduler:
                 # page) and its token is discarded.  Kept tokens only
                 # ever attend KV below the horizon, so this is exact.
                 if self._block > 1:
-                    # the block this dispatch runs (a commit pass
-                    # moves the cursor only afterwards): mapped whole,
-                    # a block ahead of the cursor
+                    # the block in flight, mapped whole (a finished
+                    # block before it, whose commit this dispatch
+                    # carries, is mapped already)
                     need = min(req.block_start + self._block,
                                self.max_seq)
                 else:
