@@ -2,7 +2,7 @@
 
 `cellbench/tests` lies outside `pytest tests/`, so the yardstick's own
 unit tests broke unseen (one has been red since PR 28).  This file
-brings the cases of its fourteen subprocess-free files into tier-1, each
+brings the cases of its sixteen subprocess-free files into tier-1, each
 under its own id (`test_<file>__<case>`): the test functions and the
 fixtures they ask for are imported, nothing is copied and nothing
 under `cellbench/` is edited.
@@ -36,7 +36,8 @@ FILES = ("test_model_math", "test_model_math_glm4_moe_lite",
          "test_model_math_nemotron_h", "test_stats",
          "test_traffic_gen", "test_trace_reduce", "test_span_readers",
          "test_nemotron_h_readers", "test_trace_bound", "test_gap_spans",
-         "test_model_math_cohere2_moe", "test_cohere2_moe_readers")
+         "test_model_math_cohere2_moe", "test_cohere2_moe_readers",
+         "test_model_math_smallthinker", "test_smallthinker_readers")
 #: ids of this file's, see the docstring
 KNOWN_RED = {
     "test_span_readers__the_benchmark_names_the_six_readers_and_the_new_cell",

@@ -19,7 +19,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from triton_distributed_tpu.models import AutoLLM, ModelConfig
 from triton_distributed_tpu.models import (
-    cohere2_moe, glm4_moe_lite, nemotron_h, solar_open2)
+    cohere2_moe, glm4_moe_lite, nemotron_h, smallthinker, solar_open2)
 from triton_distributed_tpu.models.base import ServedModel
 from triton_distributed_tpu.models.kv_cache import KVCache, PagedKVCache
 from triton_distributed_tpu.serving import (
@@ -40,6 +40,13 @@ def _tiny_cohere2_moe():
         moe_shared_combine="average", moe_selection_bias=False,
         layer_types=("sliding_attention",) * 3 + ("full_attention",),
         sliding_window=16)
+
+
+def _tiny_smallthinker():
+    """One period of the layer pattern (full first) at test size, from
+    the published keys of `tests/test_smallthinker.py`."""
+    from tests.test_smallthinker import TINY
+    return ModelConfig.from_smallthinker(TINY)
 
 
 class Family(NamedTuple):
@@ -68,6 +75,8 @@ FAMILIES = {
                          "jit_prefill_shard"),
     "cohere2_moe": Family(_tiny_cohere2_moe, cohere2_moe, 1024,
                           "jit_prefill_shard"),
+    "smallthinker": Family(_tiny_smallthinker, smallthinker, 1024,
+                           "jit_prefill_shard"),
     "toy": Family(None, None, 0, "jit_prefill"),
 }
 #: The chunk the lowered chunk programs are cut to.
@@ -92,7 +101,8 @@ def test_declares_what_the_scheduler_reads(family, devices):
     model = _model(family, devices)
     assert isinstance(model, ServedModel)
     assert (model.block_length > 1) == (family == "sdar_moe")
-    assert (model.window > 0) == (family == "cohere2_moe")
+    assert (model.window > 0) == (family in ("cohere2_moe",
+                                             "smallthinker"))
     assert model.prefill_chunk == FAMILIES[family].chunk
     # a chunk length is named exactly where there is a chunk program
     # (the toy's serves a prefix hit's suffix too, chunk or none)

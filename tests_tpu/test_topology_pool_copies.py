@@ -636,17 +636,34 @@ def _cohere(devices, kinds=None):
                       mode="fused", interpret=False), c
 
 
-def test_window_and_full_pools_step_chunk_and_inserts(topo_devices):
-    """The window / full hybrid's four programs at the cell's widths,
-    slots and pools, for the described v5e: the decode step over TWO
-    page tables (Mosaic takes the paged kernel with a window's lower
-    bound), a chunk of a long prompt (the windowed rectangular grid at
-    a traced offset over a window's gathered pages, the causal one over
-    the full layer's), its rows' scatter into both kinds of pool, and
-    the insert.  No program copies a pool of either kind."""
-    model, c = _cohere(topo_devices[:1])
+def _smallthinker(devices):
+    """The full / window hybrid with 7 query heads a KV head, at the
+    cell's own widths and depth."""
+    from triton_distributed_tpu.models.smallthinker import SmallThinker
+
+    c = _config("smallthinker-21b-1c.json")
+    cfg = ModelConfig.from_smallthinker(
+        c, max_seq_len=c["serving"]["max_seq"], dtype=c["torch_dtype"])
+    return SmallThinker(cfg, Mesh(np.array(devices), ("tp",)),
+                        mode="fused", interpret=False), c
+
+
+@pytest.mark.parametrize("family", ["cohere2_moe", "smallthinker"])
+def test_window_and_full_pools_step_chunk_and_inserts(topo_devices,
+                                                      family):
+    """A window / full hybrid's programs at its cell's widths, slots and
+    pools, for the described v5e: the decode step over TWO page tables
+    (Mosaic takes the paged kernel with a window's lower bound — and,
+    for `smallthinker`, 7 query rows a KV head in every kernel), a chunk
+    of a long prompt (the windowed rectangular grid at a traced offset
+    over a window's gathered pages, the causal one over the full
+    layer's), its rows' scatter into both kinds of pool, and the
+    insert.  No program copies a pool of either kind."""
+    model, c = (_cohere if family == "cohere2_moe" else _smallthinker)(
+        topo_devices[:1])
     serving = c["serving"]
     slots, chunk = serving["num_slots"], model.prefill_chunk
+    hkv, nwin = model.kv_row[0], model.num_window
     t = serving["max_seq"] // PAGE
     wpages = slots * (c["sliding_window"] // PAGE + 1) + 1
     pages = slots * t + 1
@@ -654,12 +671,12 @@ def test_window_and_full_pools_step_chunk_and_inserts(topo_devices):
     arg = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
         shape, dt, sharding=rep)
     pool = _shaped(model, functools.partial(
-        PagedKVCache.create, 1, pages, slots, 8, PAGE, 128, t,
-        model.dtype, num_stats=len(model.STATS), window_layers=3,
+        PagedKVCache.create, model.num_full, pages, slots, hkv, PAGE, 128,
+        t, model.dtype, num_stats=len(model.STATS), window_layers=nwin,
         window_pages=wpages), model._cache_specs(PAGE))
     row = _shaped(model, functools.partial(
-        KVCache.create, 1, 1, 8, chunk, 128, model.dtype,
-        window_layers=3), model._cache_specs())
+        KVCache.create, model.num_full, 1, hkv, chunk, 128, model.dtype,
+        window_layers=nwin), model._cache_specs())
     params = _shaped(
         model, lambda: model.init_params(jax.random.key(0)),
         model.param_specs())
@@ -681,12 +698,12 @@ def test_window_and_full_pools_step_chunk_and_inserts(topo_devices):
         "insert": make_paged_insert_fn().lower(
             pool, keys, row, arg((2,), jnp.uint32), arg((), jnp.int32),
             ids, arg((), jnp.int32), ids)}
-    shards = ((pages, 8, PAGE, 128), (wpages, 8, PAGE, 128))
+    shards = ((pages, hkv, PAGE, 128), (wpages, hkv, PAGE, 128))
     for name, lowered in programs.items():
         compiled = lowered.compile()
         text = compiled.as_text()
         found = [pool_copies(text, shard) for shard in shards]
-        print(f"cohere2_moe {name}: {found}; temporaries "
+        print(f"{family} {name}: {found}; temporaries "
               f"{compiled.memory_analysis().temp_size_in_bytes >> 20} MB")
         for f in found:
             assert not f["layout"], (name, f)

@@ -507,7 +507,12 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     interpret: Optional[bool] = None,
                     _max_packed_steps: Optional[int] = None):
     """q: (B, H, Sq, D); k, v: (B, Hkv, Sk, D) → (B, H, Sq, D)
-    [, lse (B, H, Sq)].
+    [, lse (B, H, Sq)].  Query head h reads KV head ``h // (H / Hkv)``
+    through its block's index map, one query head a grid step in every
+    schedule (packed, single-diagonal, rectangular; windowed or
+    shifted by ``kv_offset``): ``H / Hkv`` is any whole number (7 is
+    served as it is), nothing is padded or masked for the group, and
+    no head is dropped or doubled.
 
     `causal_block` > 1 with ``causal``: the BLOCK-causal mask of a
     model that generates by blocks — query row i attends kv cols

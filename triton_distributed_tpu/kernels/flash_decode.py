@@ -444,7 +444,12 @@ def flash_decode_paged(q, k_pool, v_pool, page_table, kv_len, *,
     heads of the ``2 n`` positions of a finished block and the block in
     flight, laid out ``(Hkv, 2, G * n)``, whose K/V the caller has
     written into the pages first.  Without a ``window`` or a
-    ``front_hidden`` nothing is masked inside a row's length.
+    ``front_hidden`` nothing is masked inside a row's length.  ``G`` is
+    ANY whole number (7 query heads a KV head is served as it is): the
+    ``(Hkv, G, D)`` block is the whole of its axes, nothing here pads
+    or masks the group, and where ``G`` is no multiple of the 8-row
+    sublane tile Mosaic pads the tile's rows in VMEM — rows no output
+    row is read from; no head is dropped or doubled.
 
     ``front_hidden`` (keys, static): the last ``front_hidden`` keys of
     every row are hidden from the FIRST HALF of each KV head's query

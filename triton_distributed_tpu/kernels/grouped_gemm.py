@@ -737,10 +737,29 @@ def _gate_up_kernel(bexp_ref, nblk_ref, x_ref, g_ref, u_ref, o_ref):
         o_ref[...] = (g * jax.nn.sigmoid(g) * u).astype(o_ref.dtype)
 
 
+def _relu_gate_up_kernel(bexp_ref, nblk_ref, x_ref, g_ref, u_ref, o_ref):
+    @pl.when(pl.program_id(1) < nblk_ref[0])
+    def _():
+        x = x_ref[...]
+        dims = (((1,), (0,)), ((), ()))
+        g = jax.lax.dot_general(x, g_ref[0], dims,
+                                preferred_element_type=jnp.float32)
+        u = jax.lax.dot_general(x, u_ref[0], dims,
+                                preferred_element_type=jnp.float32)
+        o_ref[...] = (jnp.maximum(g, 0.0) * u).astype(o_ref.dtype)
+
+
+#: The gated up-projection's kernel by the gate's activation.
+_GATED = {"silu": _gate_up_kernel, "relu": _relu_gate_up_kernel}
+
+
 def packed_expert_gate_up(x_rows, w_gate, w_up, block_expert, n_blocks,
                           *, block: int, name: str = "moe_gate_up",
+                          act: str = "silu",
                           interpret: Optional[bool] = None):
-    """``silu(x W_gate[e]) * (x W_up[e])`` for rows packed by expert.
+    """``act(x W_gate[e]) * (x W_up[e])`` for rows packed by expert;
+    ``act``: the gate's activation, "silu" or "relu" (a kernel each:
+    the default is the program it was).
 
     x_rows: (T * block, h) — `PackedPlan` row order, padding rows
     zero; w_gate / w_up: (E, h, f); block_expert: (T,); n_blocks: ().
@@ -757,7 +776,7 @@ def packed_expert_gate_up(x_rows, w_gate, w_up, block_expert, n_blocks,
                          lambda j, t, bexp, nblk: (bexp[t], 0, j),
                          memory_space=pltpu.VMEM)
     return _packed_call(
-        _gate_up_kernel, name, [x_rows, w_gate, w_up],
+        _GATED[act], name, [x_rows, w_gate, w_up],
         [wspec, wspec], (block_expert, n_blocks), rows=rows,
         block=block, k=h, n=f, tn=tn, out_dtype=x_rows.dtype,
         interpret=interpret)

@@ -318,9 +318,19 @@ class SparseMoE:
     layer runs without its exchange).  None: every expert is here.
 
     ``act``: the expert's form.  ``"silu"``: ``silu(x W_g) * (x W_u)``
-    through ``W_d``, three matrices; ``"relu2"``: ``relu(x W_u) ** 2``
-    through ``W_d``, two, and no ``gate`` weight.  The shared expert
-    (``shared_ffn`` wide; None: ``ffn * n_shared``) follows the form.
+    through ``W_d``, three matrices; ``"relu"``: ``relu(x W_g) * (x
+    W_u)`` through ``W_d``, the same three under another gate (the
+    kernels keep the gated form's names in a device trace);
+    ``"relu2"``: ``relu(x W_u) ** 2`` through ``W_d``, two, and no
+    ``gate`` weight.  The shared expert (``shared_ffn`` wide; None:
+    ``ffn * n_shared``) follows the form (``"relu"`` has none: the one
+    model of that form has no shared expert, so none is built).
+
+    The ROUTE'S SOURCE is the call's: ``route_from`` (N, hidden) is
+    what the router scores where that is not the experts' input — a
+    model whose router reads the stream as it enters the layer, ahead
+    of the attention, while its experts read the post-attention norm.
+    None: the router reads ``x``, as the experts do.
 
     ``latent``: the routed experts work in a space of that width
     behind ONE shared down-projection ``latent_down`` (hidden x
@@ -341,7 +351,7 @@ class SparseMoE:
     interpret: Optional[bool] = None
     held: Optional[tuple] = None   # (lo, hi) of num_experts
     scoring: str = "sigmoid"       # sigmoid (+ selection bias) | softmax
-    act: str = "silu"              # silu (gated) | relu2 (no gate)
+    act: str = "silu"              # silu | relu (gated) | relu2 (no gate)
     latent: Optional[int] = None   # the routed experts' width
     shared_ffn: Optional[int] = None
     shared_combine: str = "sum"    # sum | average (of n_shared experts)
@@ -353,8 +363,10 @@ class SparseMoE:
                 f"unknown shared_combine {self.shared_combine!r}")
         if self.scoring not in ("sigmoid", "softmax"):
             raise ValueError(f"unknown scoring {self.scoring!r}")
-        if self.act not in ("silu", "relu2"):
+        if self.act not in ("silu", "relu", "relu2"):
             raise ValueError(f"unknown expert form {self.act!r}")
+        if self.act == "relu" and self.n_shared:
+            raise ValueError("a gated-ReLU shared expert is not built")
 
     @property
     def num_held(self) -> int:
@@ -457,7 +469,8 @@ class SparseMoE:
         else:
             g = jnp.einsum("nh,ehf->enf", x, params["gate"],
                            preferred_element_type=jnp.float32)
-            act = (jax.nn.silu(g) * u).astype(x.dtype)
+            gate = jax.nn.relu if self.act == "relu" else jax.nn.silu
+            act = (gate(g) * u).astype(x.dtype)
         y = jnp.einsum("enf,efh->enh", act, params["down"],
                        preferred_element_type=jnp.float32)
         return jnp.einsum("enh,ne->nh", y, dense_w)
@@ -480,7 +493,8 @@ class SparseMoE:
             down = f"moe_{phase}_down"
             act = packed_expert_gate_up(
                 rows, params["gate"], params["up"], *tables, block=block,
-                name=f"moe_{phase}_gate_up", interpret=self.interpret)
+                name=f"moe_{phase}_gate_up", act=self.act,
+                interpret=self.interpret)
         out = packed_expert_down(
             act, params["down"], plan.row_weight, *tables, block=block,
             name=down, interpret=self.interpret)
@@ -494,8 +508,11 @@ class SparseMoE:
                                picked, 0.0)
         return picked.sum(axis=1)
 
-    def __call__(self, x, params, phase: str = "prefill"):
-        """x: (N, hidden).  Returns (y (N, hidden), stats (3,) f32 in
+    def __call__(self, x, params, phase: str = "prefill",
+                 route_from=None):
+        """x: (N, hidden); ``route_from`` (N, hidden) or None: what the
+        router scores in place of ``x`` (the class's docstring).
+        Returns (y (N, hidden), stats (3,) f32 in
         `MOE_STATS` order: pairs computed, experts with at least one
         row, the busiest expert's share of the pairs — of the held
         experts, and in `HELD_STATS` order, where `held`).  ``phase``
@@ -504,7 +521,8 @@ class SparseMoE:
         (`moe_<phase>_relu2_up`, `moe_<phase>_relu2_down` where the
         expert is of that form)."""
         n = x.shape[0]
-        ids, w = self.route(x, params)
+        ids, w = self.route(x if route_from is None else route_from,
+                            params)
         block = _pack_block(n * self.topk, self.num_experts)
         plan = moe_utils.pack_by_expert(ids, w, self.num_experts, block,
                                         held=self.held)

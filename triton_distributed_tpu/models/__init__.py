@@ -31,4 +31,7 @@ def AutoLLM(config, mesh, **kw):
     if "cohere2_moe" in arch or "cohere2moe" in arch:
         from triton_distributed_tpu.models.cohere2_moe import Cohere2Moe
         return Cohere2Moe(config, mesh, **kw)
+    if "smallthinker" in arch:
+        from triton_distributed_tpu.models.smallthinker import SmallThinker
+        return SmallThinker(config, mesh, **kw)
     raise ValueError(f"unknown architecture: {config.architecture}")

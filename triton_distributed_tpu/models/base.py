@@ -2,8 +2,11 @@
 
 The serving path (`serving.scheduler`, `serving.pages`,
 `serving.engine_batched`) and `models.engine.Engine` know a model
-through this class alone.  The six families (`Qwen3`, `Glm4MoeLite`,
-`SolarOpen2`, `SdarMoe`, `NemotronH`, `Cohere2Moe`) derive from it; the
+through this class alone.  The seven families (`Qwen3`, `Glm4MoeLite`,
+`SolarOpen2`, `SdarMoe`, `NemotronH`, and — through
+`models.window_layers.WindowAndFullLayers`, which writes the programs
+of a model with window and full layers once — `Cohere2Moe` and
+`SmallThinker`) derive from it; the
 tests' fakes (`serving.toy.ToyModel`, `analysis.serving_model`'s stub)
 take its defaults and write the entry points themselves.
 

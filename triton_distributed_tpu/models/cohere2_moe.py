@@ -16,13 +16,12 @@ experts of the layer this chip holds (``config.experts_held``).  Final
 LayerNorm; the head is the embedding, tied, over the rows of the
 vocabulary this chip holds, times ``logit_scale``.
 
-It is a `models.base.ServedModel` and writes `prefill_shard_suffix`.  Its
-cache holds TWO kinds of attention state (`models.kv_cache`): pages of
-the full layers in ``ks`` / ``vs`` behind ``page_table``, and pages of
-the window layers in ``wks`` / ``wvs`` behind ``window_table`` — a
-pool sized by slots x window, whose pages behind a row's window the
-page manager takes back as the row grows (`serving.pages`).  ``window``
-tells the scheduler so; nothing else is a knob.
+It is a `models.window_layers.WindowAndFullLayers`: its cache holds
+TWO kinds of attention state, and the pools by layer kind, the three
+per-device programs that walk the layers and the chunk program are
+written THERE, once, for this family and `models.smallthinker`.  Here
+are its parameters, its block and its head.  ``window`` tells the
+scheduler of the window layers' pool; nothing else is a knob.
 
 ONE device (``tp`` of size 1); tensor parallelism for this family (the
 window pools sharded by KV head), the exchange that would make the held
@@ -32,8 +31,6 @@ pool under a window are not built (ROADMAP Reach).
 
 from __future__ import annotations
 
-import dataclasses
-import functools
 from typing import Optional
 
 import jax
@@ -42,15 +39,12 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from triton_distributed_tpu.kernels.matmul import MatmulConfig
 from triton_distributed_tpu.layers.moe_mlp import HELD_STATS, SparseMoE
-from triton_distributed_tpu.layers.tp_attn import TPAttention, layer_norm
-from triton_distributed_tpu.models.base import ServedModel
+from triton_distributed_tpu.layers.tp_attn import layer_norm
 from triton_distributed_tpu.models.config import ModelConfig
-from triton_distributed_tpu.models.kv_cache import KVCache, PagedKVCache
+from triton_distributed_tpu.models.window_layers import (
+    FULL, WindowAndFullLayers)
 
 __all__ = ["Cohere2Moe", "PREFILL_CHUNK"]
-
-#: A layer's kind, as the published ``layer_types`` write it.
-SLIDING, FULL = "sliding_attention", "full_attention"
 
 #: Tokens of a prompt the scheduler prefills between two decode steps
 #: (`prefill_shard_suffix`).  A chunk streams the held experts once,
@@ -60,7 +54,7 @@ SLIDING, FULL = "sliding_attention", "full_attention"
 PREFILL_CHUNK = 1024
 
 
-class Cohere2Moe(ServedModel):
+class Cohere2Moe(WindowAndFullLayers):
     #: What a decode step leaves in the cache's `stats`: the held
     #: experts' counters summed over the layers (the busiest expert's
     #: share in the worst).
@@ -69,28 +63,11 @@ class Cohere2Moe(ServedModel):
     def __init__(self, config: ModelConfig, mesh: Mesh, axis: str = "tp",
                  mode: str = "fused", interpret: Optional[bool] = None,
                  gemm: Optional[MatmulConfig] = None):
-        kinds = tuple(config.layer_types)
-        assert len(kinds) == config.num_layers, (kinds, config)
-        assert FULL in kinds and set(kinds) <= {SLIDING, FULL}, kinds
-        assert SLIDING not in kinds or config.sliding_window > 0
         assert config.experts_held is not None, "which experts are here?"
-        assert not config.quantize_kv_cache, "no int8 pool under a window"
         super().__init__(config, mesh, axis, mode, interpret)
+        self._set_layer_kinds(config.layer_types, config.sliding_window,
+                              gemm)
         self.prefill_chunk = PREFILL_CHUNK
-        self.layer_kinds = kinds
-        #: Tokens a window layer sees back (0: the cut kept none): the
-        #: page manager keeps that layer kind's pages by it.
-        self.window = config.sliding_window if SLIDING in kinds else 0
-        attention = functools.partial(
-            TPAttention, axis=axis, world_size=1,
-            hidden=config.hidden_size, num_heads=config.num_heads,
-            num_kv_heads=config.num_kv_heads, head_dim=config.head_dim,
-            rope_theta=config.rope_theta, qk_norm=False, mode=mode,
-            gemm=gemm or MatmulConfig(), interpret=interpret)
-        self.attn = {
-            SLIDING: attention(rope=True, rope_pairs=config.rope_pairs,
-                               window=config.sliding_window),
-            FULL: attention(rope=False)}
         self.moe = SparseMoE(
             hidden=config.hidden_size, ffn=config.moe_intermediate_size,
             num_experts=config.num_experts,
@@ -101,16 +78,6 @@ class Cohere2Moe(ServedModel):
             interpret=interpret, held=tuple(config.experts_held),
             shared_combine=config.moe_shared_combine,
             selection_bias=config.moe_selection_bias)
-        #: Each layer's place among the layers of its kind: the index
-        #: of its pools (``wks`` / ``wvs``, or ``ks`` / ``vs``).
-        self._index = [kinds[:i].count(k) for i, k in enumerate(kinds)]
-        self.num_window, self.num_full = kinds.count(SLIDING), kinds.count(FULL)
-
-    def cache_layout(self) -> dict:
-        """Pages of the full layers, and pages of their own for the
-        window layers."""
-        return dict(num_layers=self.num_full,
-                    window_layers=self.num_window)
 
     # ------------------------------------------------------------------
     # parameters
@@ -173,83 +140,3 @@ class Cohere2Moe(ServedModel):
             x, params["embed"], (((x.ndim - 1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         return logits * self.config.logit_scale
-
-    @staticmethod
-    def _put(cache, kind, i, k, v):
-        """A prefill's rows of layer ``i`` of its kind."""
-        return (cache.write_window(i, k, v) if kind == SLIDING
-                else cache.write_prefill(i, k, v))
-
-    def prefill_shard(self, params, input_ids, cache: Optional[KVCache]):
-        """input_ids: (B, S).  Returns (logits (B, V) float32 of each
-        sequence's last position, cache)."""
-        b, s = input_ids.shape
-        x = params["embed"][input_ids].reshape(b * s, -1)
-        layer = self._per_layer(self._layer_fwd_prefill, batch=b)
-        for li, (kind, lp) in enumerate(zip(self.layer_kinds,
-                                            params["layers"])):
-            x, kept = layer[kind](x, lp)
-            if cache is not None:
-                cache = self._put(cache, kind, self._index[li], *kept)
-        logits = self._logits(x.reshape(b, s, -1)[:, -1], params)
-        if cache is not None:
-            cache = cache.set_offset(s)
-        return logits, cache
-
-    def prefill_shard_suffix(self, params, input_ids, start,
-                             cache: KVCache, pools, page_ids):
-        """One chunk of one prompt.  input_ids: (1, C), the tokens at
-        positions ``start + arange(C)`` (a last chunk right-padded);
-        ``cache``: the single-row cache of `create_cache`, C long;
-        ``pools``: the paged cache's (ks, vs, wks, wvs), read and not
-        written; ``page_ids`` (2, T): the sequence's pages in logical
-        order — row 0 in the full layers' pools, row 1 in the window
-        layers' (NULL where a page went back).  Returns ``cache``
-        holding the chunk's K/V rows of every layer at LOCAL positions
-        [0, C): the paged insert puts each kind's into its own pages.
-        No logits: the first decode step recomputes the prompt's last
-        position."""
-        b, s = input_ids.shape
-        assert b == 1, "a chunk is one sequence's"
-        ks, vs, wks, wvs = pools
-        x = params["embed"][input_ids].reshape(s, -1)
-        start = jnp.asarray(start, jnp.int32).reshape(())
-        layer = self._per_layer(self._layer_fwd_suffix)
-        for li, (kind, lp) in enumerate(zip(self.layer_kinds,
-                                            params["layers"])):
-            i = self._index[li]
-            kept, ids = (((wks[i], wvs[i]), page_ids[1])
-                         if kind == SLIDING else
-                         ((ks[i], vs[i]), page_ids[0]))
-            x, kept = layer[kind](x, lp, kept, ids, start)
-            cache = self._put(cache, kind, i, *kept)
-        return cache.set_offset(s)
-
-    def decode_shard(self, params, tokens, cache: PagedKVCache):
-        """One decode step.  tokens: (B,).  Returns (logits (B, V),
-        cache) — the cache's `stats` hold what the step counted
-        (`STATS`)."""
-        x = params["embed"][tokens]
-        layer = self._per_layer(self._layer_fwd_decode)
-        counted = []
-        for li, (kind, lp) in enumerate(zip(self.layer_kinds,
-                                            params["layers"])):
-            i = self._index[li]
-            if kind == SLIDING:
-                x, kept, stats = layer[kind](
-                    x, lp, (cache.wks[i], cache.wvs[i]),
-                    cache.window_table, cache.offset)
-                cache = cache.set_window_layer(i, *kept)
-            else:
-                x, kept, stats = layer[kind](
-                    x, lp, (cache.ks[i], cache.vs[i]), cache.page_table,
-                    cache.offset)
-                cache = cache.set_layer(i, *kept)
-            counted.append(stats)
-        logits = self._logits(x, params)
-        if cache.stats is not None:
-            c = jnp.stack(counted)                      # (layers, 4)
-            cache = dataclasses.replace(cache, stats=jnp.concatenate(
-                [c[:, :2].sum(axis=0), c[:, 2:3].max(axis=0),
-                 c[:, 3:].sum(axis=0)]))
-        return logits, cache.inc_offset(1)

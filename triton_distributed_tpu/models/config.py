@@ -272,6 +272,44 @@ class ModelConfig:
         return cls(**d)
 
     @classmethod
+    def from_smallthinker(cls, d: dict, **kw):
+        """From the SmallThinker family's published `config.json` keys
+        (``d``), mapped onto the fields the layers read: the layer
+        kinds from ``sliding_window_layout`` (1: a window layer, 0: a
+        full one) — ``rope_layout`` is the same list: a window layer
+        rotates, a full one has no positions —, the gated-ReLU expert
+        layer (whose router the model class hands the layer's INPUT) from
+        the ``moe_*primary*`` keys (softmax over the chosen =
+        ``moe_primary_router_apply_softmax`` + ``norm_topk_prob``)."""
+        layout = [int(x) for x in d["sliding_window_layout"]]
+        n = d["num_hidden_layers"]
+        assert [int(x) for x in d["rope_layout"]] == layout, (
+            "a window layer rotates and a full layer does not")
+        assert len(layout) >= n, (layout, n)
+        assert d["moe_primary_router_apply_softmax"]
+        assert d.get("rope_scaling") is None
+        fields = dict(
+            architecture="smallthinker", vocab_size=d["vocab_size"],
+            hidden_size=d["hidden_size"],
+            intermediate_size=d["moe_ffn_hidden_size"], num_layers=n,
+            num_heads=d["num_attention_heads"],
+            num_kv_heads=d["num_key_value_heads"],
+            head_dim=d["head_dim"], rms_norm_eps=d["rms_norm_eps"],
+            rope_theta=d["rope_theta"], qk_norm=False, rope_pairs=False,
+            tie_word_embeddings=d["tie_word_embeddings"],
+            max_seq_len=d["max_position_embeddings"],
+            num_experts=d["moe_num_primary_experts"],
+            num_experts_per_tok=d["moe_num_active_primary_experts"],
+            moe_intermediate_size=d["moe_ffn_hidden_size"],
+            n_shared_experts=0, norm_topk_prob=d["norm_topk_prob"],
+            moe_scoring="softmax", moe_act="relu",
+            layer_types=tuple("sliding_attention" if x else
+                              "full_attention" for x in layout[:n]),
+            sliding_window=d["sliding_window_size"])
+        fields.update(kw)
+        return cls(**fields)
+
+    @classmethod
     def from_hf(cls, model_name_or_path: str):
         """Build from a HuggingFace config (reference loads HF weights;
         here we map the config; weights via `Qwen3.load_hf_weights`)."""

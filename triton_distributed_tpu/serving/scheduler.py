@@ -1950,7 +1950,7 @@ class ContinuousBatchingScheduler:
         if not spec and not block:
             toks_host = toks_host[:, None]
         if flight.counted is not None:
-            self._moe_phase(flight.counted, reg)
+            self._moe_phase(flight.counted, len(flight.rows), reg)
         # A row that ended by EOS one step ago ran in this step too
         # (its slot may already hold another request): its token is
         # discarded.
@@ -2122,15 +2122,17 @@ class ContinuousBatchingScheduler:
         counted.copy_to_host_async()
         return counted
 
-    def _moe_phase(self, counted, reg) -> None:
+    def _moe_phase(self, counted, rows: int, reg) -> None:
         """After the step's host sync: the counters as a `serving.moe`
         span's attributes and as metrics — and, where the model keeps
-        a recurrent state, a `serving.state` span beside it."""
+        a recurrent state, a `serving.state` span beside it.  ``rows``:
+        the live rows the step carried (the host's own count: what
+        turns `experts_hit` into bytes a token)."""
         read = dict(zip(self._stats_names,
                         (float(v) for v in np.asarray(counted))))
         live = read.pop("live_slots", None)
         with span("serving.moe") as sp:
-            sp.attrs.update(read)
+            sp.attrs.update(read, rows=rows)
         reg.counter("serving_moe_pairs_total").inc(read["pairs"])
         reg.counter("serving_moe_experts_hit_total").inc(
             read["experts_hit"])
