@@ -1,6 +1,7 @@
 """Layer tests (reference: `test/nvidia/test_tp_mlp.py`,
 `test_tp_attn.py`, `test_ep_a2a.py`)."""
 
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -307,6 +308,77 @@ def test_tp_attn_prefill_suffix_over_the_pools_pages(devices, mode, form):
                     pool_of(k, 2 * chunk), pool_of(v, 2 * chunk))
     assert_allclose(got[:5], out[2 * chunk:2 * chunk + 5], atol=2e-3,
                     rtol=2e-3, name="padded-last-chunk")
+
+
+@pytest.mark.parametrize("mode", ["xla", "fused"])
+@pytest.mark.parametrize("chunks_in", [0, 1, 3])
+def test_tp_attn_prefill_suffix_under_the_block_causal_mask(
+        devices, mode, chunks_in):
+    """`prefill_suffix` of a layer built with ``block=4``: a chunk that
+    starts ``chunks_in`` chunks into the sequence (a TRACED start)
+    against `attention_reference(causal_block=4)` over the whole
+    sequence — row i at ``start + i`` sees column j iff ``j // 4 <=
+    (start + i) // 4``: every row below ``start`` and its own block to
+    the end.  The pool's rows at and past ``start`` and the page ids
+    past the chunk's own are NaN (another owner's rows, the trash
+    page): nothing there is read.  Under the plain causal mask the
+    answer is another."""
+    hidden, heads, kv_heads, d, ps, chunk, blk = 128, 8, 2, 16, 16, 32, 4
+    attn = TPAttention(axis="tp", world_size=1, hidden=hidden,
+                       num_heads=heads, num_kv_heads=kv_heads, head_dim=d,
+                       block=blk, mode=mode, gemm=MatmulConfig(32, 64, 128))
+    params = attn.init_params(jax.random.key(24), jnp.float32)
+    mesh = jax.sharding.Mesh(devices[:1], ("tp",))
+    s = 4 * chunk                   # 128 positions: the pool's 8 pages
+    x = jax.random.normal(jax.random.key(25), (s, hidden)) / 8
+    rep = jax.tree_util.tree_map(lambda _: P(), params)
+    golden = dataclasses.replace(attn, mode="xla")
+
+    def whole(xx, pp, cb):
+        q, k, v, gate = golden._prefill_heads(xx, pp, 1)
+        ref = attention_reference(q, k, v, causal=True, causal_block=cb)
+        return golden._prefill_out(ref, gate, xx.dtype, pp), (k, v)
+
+    out, (k, v) = jax.jit(shard_map_op(
+        lambda xx, pp: whole(xx, pp, blk), mesh, in_specs=(P(), rep),
+        out_specs=(P(), (P(), P()))))(x, params)
+    plain, _ = jax.jit(shard_map_op(
+        lambda xx, pp: whole(xx, pp, 0), mesh, in_specs=(P(), rep),
+        out_specs=(P(), (P(), P()))))(x, params)
+    pages = jnp.asarray([5, 2, 7, 0, 3, 6, 8, 1], jnp.int32)
+    suffix = jax.jit(shard_map_op(
+        lambda xx, pp, at, kp, vp, ids: attn.prefill_suffix(
+            xx, pp, at, (kp, vp), ids), mesh,
+        in_specs=(P(), rep, P(), P(), P(), P()),
+        out_specs=(P(), (P(), P()))))
+    at = chunks_in * chunk
+
+    def pool_of(rows):
+        """(10 pages, Hkv, 16, D): the sequence's rows below ``at`` at
+        their pages, NaN everywhere else."""
+        pool = jnp.full((10, kv_heads, ps, d), jnp.nan, jnp.float32)
+        for j in range(at // ps):
+            pool = pool.at[pages[j]].set(rows[0, :, j * ps:(j + 1) * ps])
+        return pool
+
+    # the ids past the rows below the chunk name the NaN page 9
+    ids = jnp.where(jnp.arange(8) < at // ps, pages, 9)
+    got, (ck, cv) = suffix(x[at:at + chunk], params, jnp.int32(at),
+                           pool_of(k), pool_of(v), ids)
+    assert bool(jnp.isfinite(got).all())
+    assert_allclose(got, out[at:at + chunk], atol=2e-3, rtol=2e-3,
+                    name=f"block-suffix-{mode}-at-{at}")
+    assert_allclose(ck, k[:, :, at:at + chunk], atol=1e-5, rtol=1e-5,
+                    name="chunk-k")
+    assert_allclose(cv, v[:, :, at:at + chunk], atol=1e-5, rtol=1e-5,
+                    name="chunk-v")
+    # (the mask is the block-causal one: a block's first rows see its
+    # last, which the plain causal mask hides from them)
+    assert float(jnp.abs(got - plain[at:at + chunk]).max()) > 3 * 2e-3
+    # a chunk that holds no whole block is refused where that is static
+    with pytest.raises(AssertionError, match="whole blocks"):
+        suffix(x[:chunk - 2], params, jnp.int32(0), pool_of(k),
+               pool_of(v), ids)
 
 
 def test_ep_a2a_layer(ep4_mesh):

@@ -19,7 +19,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from triton_distributed_tpu.models import AutoLLM, ModelConfig
 from triton_distributed_tpu.models import (
-    cohere2_moe, glm4_moe_lite, nemotron_h, smallthinker, solar_open2)
+    cohere2_moe, glm4_moe_lite, nemotron_h, sdar_moe, smallthinker,
+    solar_open2)
 from triton_distributed_tpu.models.base import ServedModel
 from triton_distributed_tpu.models.kv_cache import KVCache, PagedKVCache
 from triton_distributed_tpu.serving import (
@@ -69,7 +70,7 @@ FAMILIES = {
                             2048, "jit_prefill_shard"),
     "solar_open2": Family(ModelConfig.tiny_solar_open2, solar_open2, 256,
                           "jit_prefill_shard"),
-    "sdar_moe": Family(ModelConfig.tiny_sdar_moe, None, 0,
+    "sdar_moe": Family(ModelConfig.tiny_sdar_moe, sdar_moe, 512,
                        "jit_prefill_shard"),
     "nemotron_h": Family(ModelConfig.tiny_nemotron_h, nemotron_h, 512,
                          "jit_prefill_shard"),
@@ -223,7 +224,7 @@ def test_program_names_the_benchmark_reads(family, devices):
     assert first(jax.jit(model.make_prefill_fn()).lower(
         params, ids, row)).startswith(f"module @{name} ")
     if model.make_prefill_suffix_fn is None:
-        assert family in ("qwen3", "sdar_moe")
+        assert family == "qwen3"
         return
     pool = jax.eval_shape(lambda: model.create_paged_cache(2, 9, 16, 4))
     pools, pages = (pool.ks, pool.vs), jnp.zeros((4,), jnp.int32)

@@ -351,8 +351,15 @@ def test_no_program_and_no_kind_of_argument_is_first_met_after_warm_up(
             # a model that prefills in chunks, or one with a recurrent
             # state: one enqueue a decode dispatch, so y was admitted
             # a call after x
-            assert y.finish_reason is None
-            system.step()
+            assert y.t_admitted > x.t_admitted
+            if sched._block > 1:
+                # (a block pass yields 1..4 tokens a row: y's whole
+                # block may be out before x's tail and block are)
+                while y.finish_reason is None:
+                    system.step()
+            else:
+                assert y.finish_reason is None
+                system.step()
         assert y.finish_reason is not None and long.finish_reason is None
         send(6, 2), send(6, 5), send(30, 1)
         run_dry()

@@ -167,6 +167,179 @@ def test_prefill_then_blocks_through_the_pages(system, pattern):
     assert all(all(x[1:]) for x in bad), bad
 
 
+def test_a_long_prompt_in_chunks_against_the_same_prompt_whole(system):
+    """The 1703-token prompt (a tail of 3) prefilled whole through the
+    2048 bucket into one slot and by the model's own chunks
+    (`SdarMoe.prefill_chunk`; each over the pages its predecessors
+    filled, under the block-causal mask at a traced offset — the
+    rectangular grid where the whole prefill runs the packed schedule)
+    into the other: the pool rows of every prompt position, the tail's
+    at and past the cursor among them, are the whole prefill's to
+    bfloat16 rounding and a flipped routing near-tie's reach; the first
+    block pass behind either — a dead front half, the tail revealed —
+    is held to the float32 reference by the file's tolerance, the
+    float8 control fails it, and chunked against whole lies inside
+    either's distance from the reference.  Prints what a chunk and a
+    whole bucket take (`-s`)."""
+    import time
+    cfg, sysm = system
+    model, params = sysm.model, sysm.params
+    dims = reference.dims_of(cfg)
+    mask = cfg["mask_token_id"]
+    rng = np.random.default_rng(49)
+    p = rng.integers(0, cfg["vocab_size"], 1703).tolist()
+    chunk = model.prefill_chunk
+    assert chunk and len(p) > chunk and chunk % 16 == 0 and chunk % N == 0
+    slots = PagedKV(model, 2, max_seq=2048, page_size=16,
+                    prefix_cache=False)
+    key = jnp.zeros((2,), jnp.uint32)
+    cursor = len(p) // N * N
+    prefill = jax.jit(model.make_prefill_fn())
+    suffix = jax.jit(model.make_prefill_suffix_fn())
+    bucket = pick_bucket(len(p), sysm.buckets)
+    ids, s = pad_prompt(p, bucket)
+    _, whole = prefill(params, ids, model.create_cache(1, bucket))
+    assert slots.insert_prefill(whole, p, s, key, [], offset=cursor) == 0
+    slot = slots.begin_prefill(s, [])
+    row = model.create_cache(1, chunk)
+    for at in range(0, s, chunk):
+        ids, _ = pad_prompt(p[at:at + chunk], chunk)
+        out = suffix(params, ids, jnp.int32(at), row,
+                     (slots.cache.ks, slots.cache.vs),
+                     slots.prefill_pages(slot))
+        if at + chunk >= s:
+            slots.insert_rows(slot, out, at, key, cursor)
+        else:
+            slots.insert_rows(slot, out, at)
+    slots.finish_prefill(slot, p, cursor)
+    assert [int(x) for x in slots.cache.offset] == [cursor, cursor]
+    n_pages = -(-s // 16)
+    worst = []
+    for pool in (slots.cache.ks, slots.cache.vs):
+        for li in range(len(pool)):
+            heads, _, width = pool[li].shape[1:]
+            a, b = (np.asarray(
+                pool[li][np.asarray(slots._table[r][:n_pages])],
+                np.float32).transpose(1, 0, 2, 3).reshape(
+                    heads, -1, width)[:, :s] for r in (0, 1))
+            worst.append((np.abs(a - b).max() / np.abs(a).max(),
+                          np.median(np.abs(a - b)) / np.abs(a).max()))
+    print("rows, chunked against whole, of each pool's largest: max "
+          f"{max(w[0] for w in worst):.4f}, median "
+          f"{max(w[1] for w in worst):.2e}")
+    assert max(w[1] for w in worst) < 1e-2, worst
+    decode = jax.jit(model.make_paged_decode_fn(page_size=16))
+    tail = p[cursor:]
+    fed = tail + [mask] * (N - len(tail))
+    for b in range(2):
+        assert slots.ensure(b, cursor + N)
+    slots.flush()
+    logits, _ = decode(params, jnp.asarray([[mask] * N + fed] * 2,
+                                           jnp.int32),
+                       slots.cache, jnp.ones((2,), bool),
+                       jnp.zeros((2,), bool))
+    logits = np.asarray(logits)
+    state = np.asarray(p[:cursor] + fed, np.int64)
+    ref = np.asarray(reference.forward(dims, SEED, _padded(state),
+                                       cursor, N))
+    low = np.asarray(reference.forward(dims, SEED, _padded(state),
+                                       cursor, N, precision="fp8"))
+    spread = ref.std(axis=1, keepdims=True)
+    err = [(np.abs(logits[b] - ref) / spread).max(axis=1)
+           for b in range(2)]
+    ctl = (np.abs(low - ref) / spread).max(axis=1)
+    apart = (np.abs(logits[1] - logits[0]) / spread).max(axis=1)
+    print(f"first block pass, worst logit of each position in units of "
+          f"its spread: whole {err[0].round(4)}, chunked "
+          f"{err[1].round(4)}, chunked against whole {apart.round(4)}, "
+          f"float8 control {ctl.round(4)}")
+    for e in err:
+        assert np.median(e) < MEDIAN_TOL and (e > LOGIT_TOL).mean() \
+            <= PAST, err
+    assert np.median(ctl) > LOGIT_TOL, ctl
+    assert np.median(apart) <= max(np.median(e) for e in err) * 1.5 \
+        + 0.02, (apart, err)
+    # what the pieces take, warm: a chunk where it stands, a bucket whole
+    took = {}
+    for at in range(0, s, chunk):
+        ids, _ = pad_prompt(p[at:at + chunk], chunk)
+        t0 = time.perf_counter()
+        for _ in range(5):
+            out = suffix(params, ids, jnp.int32(at), row,
+                         (slots.cache.ks, slots.cache.vs),
+                         np.asarray(slots._table[1]))
+        jax.block_until_ready(out)
+        took[f"chunk@{at}"] = (time.perf_counter() - t0) / 5 * 1e3
+    for bucket in (b for b in sysm.buckets if b >= 512):
+        ids, _ = pad_prompt(p[:bucket], bucket)
+        rowb = model.create_cache(1, bucket)
+        jax.block_until_ready(prefill(params, ids, rowb))
+        t0 = time.perf_counter()
+        for _ in range(5):
+            out = prefill(params, ids, rowb)
+        jax.block_until_ready(out)
+        took[f"bucket{bucket}"] = (time.perf_counter() - t0) / 5 * 1e3
+    print(f"ms a program (5 back to back, chunk {chunk}): "
+          + json.dumps({k: round(v, 2) for k, v in took.items()}),
+          flush=True)
+
+
+def test_the_chunk_program_compiles_for_a_described_v5e():
+    """The chunk program at the published widths, the cell's own cut
+    (7 layers, 128 experts) and the model's own chunk length under the
+    real Mosaic / XLA:TPU compiler for a DESCRIBED v5e — nothing
+    executes, so it also runs without the chip (`--noconftest`):
+    Mosaic takes `flash_attention` at a traced offset under the
+    block-causal mask, and the program is a prefill by its name.
+    (`test_topology_pool_copies.py` reads the same program, two layers
+    deep, for copies of the pool.)"""
+    import functools
+    import time
+
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tests_tpu.test_topology_pool_copies import _sdar, _shaped
+    from triton_distributed_tpu.models.kv_cache import (
+        KVCache, PagedKVCache)
+
+    with open(os.path.join(ROOT, "cellbench", "configs",
+                           "sdar-30b-a3b-1c.json")) as f:
+        cfg = json.load(f)
+    serving, layers = cfg["serving"], cfg["num_hidden_layers"]
+    devices = topologies.get_topology_desc("v5e:2x2", "tpu").devices
+    model, _ = _sdar(devices[:1], layers=layers)
+    chunk = model.prefill_chunk
+    table = serving["max_seq"] // 16
+    pages = serving["num_slots"] * table + 1
+    rep = NamedSharding(model.mesh, P())
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
+        shape, dt, sharding=rep)
+    pool = _shaped(model, functools.partial(
+        PagedKVCache.create, layers, pages, serving["num_slots"], 4, 16,
+        128, table, model.dtype, num_stats=len(model.STATS)),
+        model._cache_specs(16))
+    row = _shaped(model, functools.partial(
+        KVCache.create, layers, 1, 4, chunk, 128, model.dtype),
+        model._cache_specs())
+    params = _shaped(
+        model, lambda: model.init_params(jax.random.key(0)),
+        model.param_specs())
+    t0 = time.perf_counter()
+    compiled = jax.jit(model.make_prefill_suffix_fn()).lower(
+        params, arg((1, chunk), jnp.int32), arg((), jnp.int32), row,
+        (pool.ks, pool.vs), arg((table,), jnp.int32)).compile()
+    text = compiled.as_text()
+    print(f"chunk of {chunk} at {layers} layers: compiled in "
+          f"{time.perf_counter() - t0:.1f} s, temporaries "
+          f"{compiled.memory_analysis().temp_size_in_bytes >> 20} MB")
+    assert text.startswith("HloModule jit_prefill_shard_suffix"), text[:80]
+    for kernel in ("flash_attention_fwd", "moe_prefill_gate_up",
+                   "moe_prefill_down"):
+        assert kernel in text, kernel
+    assert "moe_decode" not in text and "flash_decode" not in text
+
+
 def _padded(state, to=256):
     """One compiled length a prompt: whole 256s of positions (what lies
     past the block read is masked or in later blocks)."""

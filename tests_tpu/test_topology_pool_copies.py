@@ -548,25 +548,17 @@ def test_the_state_space_chunk_reads_the_pools_and_copies_none(
             assert "mamba2_decode_step" not in text
 
 
-def test_no_pool_sized_copy_in_a_block_pass(topo_devices):
-    """The block-diffusion family (`models/sdar_moe.py`): a pass
-    writes every row's two block-widths — the block it finished last
-    and its block in flight, eight rows a slot a layer — into its
-    mapped pages through the same scatter as a decode step's one row,
-    and `flash_decode_paged` takes 64 query rows a KV head, the last
-    four keys hidden from the first half of them.  Compiled at the
-    published widths for the described v5e: Mosaic takes the kernel at
-    those rows and with that mask, and no pool is copied."""
+def _sdar(devices, layers=LAYERS):
+    """The block-diffusion family at the cell's widths, ``layers``
+    deep: (the model, its configuration's ``generation``)."""
     from triton_distributed_tpu.models.sdar_moe import SdarMoe
-    from triton_distributed_tpu.serving.engine_batched import (
-        make_block_pass_fn)
 
     c = _config("sdar-30b-a3b-1c.json")
     gen = c["generation"]
     cfg = ModelConfig(
         architecture=c["model_type"], vocab_size=c["vocab_size"],
         hidden_size=c["hidden_size"],
-        intermediate_size=c["intermediate_size"], num_layers=LAYERS,
+        intermediate_size=c["intermediate_size"], num_layers=layers,
         num_heads=c["num_attention_heads"],
         num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
         rms_norm_eps=c["rms_norm_eps"], rope_theta=c["rope_theta"],
@@ -578,8 +570,25 @@ def test_no_pool_sized_copy_in_a_block_pass(topo_devices):
         moe_scoring="softmax", block_length=gen["block_length"],
         denoising_steps=gen["denoising_steps"],
         remasking=gen["remasking"], mask_token_id=c["mask_token_id"])
-    model = SdarMoe(cfg, Mesh(np.array(topo_devices[:1]), ("tp",)),
-                    mode="fused", interpret=False)
+    model = SdarMoe(cfg, Mesh(np.array(devices), ("tp",)), mode="fused",
+                    interpret=False)
+    return model, gen
+
+
+def test_no_pool_sized_copy_in_a_block_pass(topo_devices):
+    """The block-diffusion family (`models/sdar_moe.py`): a pass
+    writes every row's two block-widths — the block it finished last
+    and its block in flight, eight rows a slot a layer — into its
+    mapped pages through the same scatter as a decode step's one row,
+    and `flash_decode_paged` takes 64 query rows a KV head, the last
+    four keys hidden from the first half of them.  Compiled at the
+    published widths for the described v5e: Mosaic takes the kernel at
+    those rows and with that mask, and no pool is copied."""
+    from triton_distributed_tpu.serving.engine_batched import (
+        make_block_pass_fn)
+
+    model, gen = _sdar(topo_devices[:1])
+    cfg = model.config
     slots, n = 64, gen["block_length"]
     pages = slots * 3584 // PAGE + 1
     rep = NamedSharding(model.mesh, P())
@@ -608,6 +617,73 @@ def test_no_pool_sized_copy_in_a_block_pass(topo_devices):
     print(f"sdar_moe block pass, pool {shard}: {found}")
     assert not found["layout"] and len(found["staged"]) <= STAGED_MAX, (
         found)
+
+
+def test_the_block_causal_chunk_reads_the_pools_and_copies_none(
+        topo_devices):
+    """A long prompt of the block-diffusion family in chunks
+    (`SdarMoe.make_prefill_suffix_fn`) at the cell's widths, slots and
+    pool, and the model's own chunk length, for the described v5e:
+    Mosaic takes the attention at a traced offset UNDER THE
+    BLOCK-CAUSAL MASK; the chunk program reads the page pools through
+    the request's page ids — gathered rows, never a pool — and the
+    scatter of a middle chunk's rows and the insert of the last one's
+    (with the cursor the scheduler gives a block model) write the
+    donated pools where they lie."""
+    model, gen = _sdar(topo_devices[:1])
+    c = _config("sdar-30b-a3b-1c.json")["serving"]
+    chunk, slots = model.prefill_chunk, c["num_slots"]
+    table = c["max_seq"] // PAGE
+    pages = slots * c["max_seq"] // PAGE + 1
+    assert chunk % PAGE == 0 and chunk % gen["block_length"] == 0
+    assert chunk < BUCKET and slots == 64
+    rep = NamedSharding(model.mesh, P())
+    arg = lambda shape, dt: jax.ShapeDtypeStruct(     # noqa: E731
+        shape, dt, sharding=rep)
+    pool = _shaped(model, functools.partial(
+        PagedKVCache.create, LAYERS, pages, slots, 4, PAGE, 128, table,
+        model.dtype, num_stats=len(model.STATS)),
+        model._cache_specs(PAGE))
+    row = _shaped(model, functools.partial(
+        KVCache.create, LAYERS, 1, 4, chunk, 128, model.dtype),
+        model._cache_specs())
+    params = _shaped(
+        model, lambda: model.init_params(jax.random.key(0)),
+        model.param_specs())
+    programs = {
+        "chunk": jax.jit(model.make_prefill_suffix_fn()).lower(
+            params, arg((1, chunk), jnp.int32), arg((), jnp.int32), row,
+            (pool.ks, pool.vs), arg((table,), jnp.int32)),
+        "rows": make_paged_rows_fn().lower(
+            (pool.ks, pool.vs, None, None), pool.offset, row,
+            arg((chunk // PAGE,), jnp.int32)),
+        "insert": make_paged_insert_fn().lower(
+            pool, arg((slots, 2), jnp.uint32), row, arg((2,), jnp.uint32),
+            arg((), jnp.int32), arg((chunk // PAGE,), jnp.int32),
+            arg((), jnp.int32))}
+    shard = (pages, 4, PAGE, 128)
+    dims = ",".join(str(d) for d in shard)
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        assert f"[{dims}]" in text, name
+        found = pool_copies(text, shard)
+        print(f"sdar_moe {name} of {chunk}, pool {shard}: {found}; "
+              f"temporaries "
+              f"{compiled.memory_analysis().temp_size_in_bytes >> 20} MB")
+        assert not found["layout"], (name, found)
+        assert len(found["staged"]) <= STAGED_MAX, (name, found)
+        if name == "chunk":
+            assert text.startswith("HloModule jit_prefill_shard"), text[:80]
+            for kernel in ("flash_attention_fwd", "moe_prefill_gate_up",
+                           "moe_prefill_down"):
+                assert kernel in text, kernel
+            assert "moe_decode" not in text
+            # (no logits: the LAST layer's expert block is read by
+            # nothing and is not in the program — one call of each
+            # grouped GEMM at two layers)
+            assert text.count("custom_call_target=\"tpu_custom_call\"") \
+                >= 3
 
 
 def _cohere(devices, kinds=None):

@@ -299,10 +299,14 @@ class TPAttention:
         ``window`` tokens' pages below ``start`` (``start`` a multiple
         of the page; ``page_ids`` its own table's row, NULL where a
         page was given back: nothing there is visible) and runs the
-        windowed kernel over that buffer and the chunk.  Returns (out
-        like x, the chunk's (k, v), each (1, Hkv_loc, C, D), for the
-        cache)."""
-        assert self.block <= 1, "a chunk under a block-causal mask"
+        windowed kernel over that buffer and the chunk.  A BLOCK layer
+        (``block`` > 1) runs the kernel under the block-causal mask:
+        row i sees column j iff ``j // block <= (start + i) // block``
+        — ``start`` is the caller's to keep a multiple of the block
+        (it is one of the chunk or of the page, which both hold whole
+        blocks): all below it is whole earlier blocks, and a row's own
+        block ends inside the chunk.  Returns (out like x, the chunk's
+        (k, v), each (1, Hkv_loc, C, D), for the cache)."""
         k_pool, v_pool = kv_pools
         assert k_pool.dtype != jnp.int8, "chunks over an int8 pool"
         m = x.shape[0] * self.world_size
@@ -320,6 +324,10 @@ class TPAttention:
             kw = dict(window=self.window)
             if self.mode != "xla":
                 kw["name"] = WINDOW_KERNELS["prefill"]
+        elif self.block > 1:
+            assert m % self.block == 0 and ps % self.block == 0, (
+                "a chunk and a page hold whole blocks", m, ps, self.block)
+            kw = dict(causal_block=self.block)
         span = page_ids.shape[0] * ps
         # whole query blocks, and room for a chunk that starts at the
         # last row the pages reach

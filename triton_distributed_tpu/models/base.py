@@ -54,7 +54,9 @@ A family MAY write
 - ``block_length`` > 1: the model generates by blocks, and
   `decode_shard(params, tokens (B, 2n), cache, active, folded) ->
   (logits (B, n, V), cache)` is a block pass's model half
-  (`models.sdar_moe`);
+  (`models.sdar_moe`, which chunks too: a chunk and a page hold whole
+  blocks, and its `prefill_shard_suffix` attends the pool under the
+  block-causal mask);
 - ``window`` > 0: its ``window_layers`` see that many tokens back and
   the page manager keeps their pages by it;
 - ``latent_bytes_per_token``: the bytes of a cached token that carry

@@ -36,6 +36,13 @@ the two halves' turn-over around `decode_shard`):
   block in flight already revealed: its K/V depends on the tokens
   generated beside it).  Under the block-causal mask those positions
   see nothing at or past the cursor, so the padded bucket is exact;
+- `prefill_shard_suffix` is the prefill of ONE CHUNK of a prompt over
+  the rows its earlier chunks (or a prefix hit) left in the page pool,
+  under the same mask (`TPAttention.prefill_suffix`): the scheduler
+  carries a prompt longer than ``prefill_chunk`` tokens
+  (`PREFILL_CHUNK`) out a chunk between two block passes, one piece a
+  pass, and prefills only what a prefix hit left.  A chunk and a page
+  hold whole blocks, so every chunk starts at a block's edge;
 - `decode_shard` is one pass: both halves' tokens (mask id where not
   revealed) through the layers, their K/V written into the mapped
   pages — the front half's final, the back half's provisional — and
@@ -63,7 +70,19 @@ from triton_distributed_tpu.models.base import ServedModel
 from triton_distributed_tpu.models.config import ModelConfig
 from triton_distributed_tpu.models.kv_cache import KVCache, PagedKVCache
 
-__all__ = ["SdarMoe"]
+__all__ = ["SdarMoe", "PREFILL_CHUNK"]
+
+#: Tokens of a prompt the scheduler prefills between two block passes
+#: (`prefill_shard_suffix`); a multiple of the page and of the block.
+#: A chunk streams the experts once, so a shorter one costs device time
+#: and a longer one lengthens the token gap of every running row:
+#: settled on the chip by PR 40's rule (of 256 / 384 / 512 the shortest
+#: that keeps the tokens a second within 5% of the unchunked prefill's
+#: on two seeds: 512 lost 3.3-3.8%, 256 lost 12%, and 384 is no prefill
+#: bucket — prompts of 257-384 tokens then run the 512 bucket WHOLE,
+#: a program the benchmark's warm-up, which sends each bucket's full
+#: length, never meets; PERF.md section 5 has the sweep of PR 49).
+PREFILL_CHUNK = 512
 
 #: Published (HF) parameter names of one layer -> where they go here;
 #: `{i}` the layer, `{e}` the expert.  Projections are stored
@@ -107,6 +126,7 @@ class SdarMoe(ServedModel):
         #: config's (``denoising_steps``, ``remasking``,
         #: ``mask_token_id``).
         self.block_length = config.block_length
+        self.prefill_chunk = PREFILL_CHUNK
         self.attn = TPAttention(
             axis=axis, world_size=1, hidden=config.hidden_size,
             num_heads=config.num_heads,
@@ -190,10 +210,19 @@ class SdarMoe(ServedModel):
     # per-device forward bodies (called inside shard_map)
     # ------------------------------------------------------------------
 
-    def _layer_fwd_prefill(self, x, lp, *, batch):
+    def _layer_fwd_prefill(self, x, lp, kept=(), page_ids=None,
+                           start=None, *, batch):
+        """(x, the layer's (k, v) for the cache).  ``kept``: nothing
+        where the rows start their sequences; for a chunk of one
+        sequence the layer's (k pool, v pool), whose pages ``page_ids``
+        names hold its rows below position ``start``."""
         eps = self.config.rms_norm_eps
-        h, kv = self.attn.prefill(rms_norm(x, lp["ln1"], eps),
-                                  lp["attn"], batch)
+        h = rms_norm(x, lp["ln1"], eps)
+        if kept:
+            h, kv = self.attn.prefill_suffix(h, lp["attn"], start, kept,
+                                             page_ids)
+        else:
+            h, kv = self.attn.prefill(h, lp["attn"], batch)
         x = x + h
         h, _ = self.moe(rms_norm(x, lp["ln2"], eps), lp["mlp"],
                         phase="prefill")
@@ -233,6 +262,35 @@ class SdarMoe(ServedModel):
         if cache is not None:
             cache = cache.set_offset(s)
         return logits, cache
+
+    def prefill_shard_suffix(self, params, input_ids, start,
+                             cache: KVCache, pools, page_ids):
+        """One chunk of one prompt.  input_ids: (1, C), the tokens at
+        positions ``start + arange(C)`` (a last chunk right-padded), C
+        and ``start`` multiples of the block length; ``cache``: the
+        single-row cache of `create_cache`, C long; ``pools``: the
+        paged cache's (ks, vs), read and not written — the prompt's
+        rows below ``start`` lie there, at the pages ``page_ids`` (T,)
+        names in logical order.  Under the block-causal mask a row
+        sees all of them and its own block, which ends inside the
+        chunk: what lies behind a prompt's last whole block (its tail,
+        the padding) is seen by no row below the cursor, as in the
+        padded bucket of `prefill_shard`.  Returns ``cache`` holding
+        the chunk's K/V rows at LOCAL positions [0, C) — the paged
+        insert puts them into their pages.  No logits: a request's
+        first tokens come from its first block pass — so the last
+        layer's expert block is read by nothing and the compiler
+        leaves it out with the head."""
+        b, s = input_ids.shape
+        assert b == 1, "a chunk is one sequence's"
+        ks, vs = pools
+        x = params["embed"][input_ids].reshape(s, -1)
+        start = jnp.asarray(start, jnp.int32).reshape(())
+        layer = self._per_layer(self._layer_fwd_prefill, batch=1)
+        for li, lp in enumerate(params["layers"]):
+            x, (k, v) = layer(x, lp, (ks[li], vs[li]), page_ids, start)
+            cache = cache.write_prefill(li, k, v)
+        return cache.set_offset(s)
 
     def decode_shard(self, params, tokens, cache: PagedKVCache, active,
                      folded):
