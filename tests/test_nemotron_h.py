@@ -656,6 +656,55 @@ def test_churn_hands_a_zeroed_state_row_to_the_next_owner(system):
     assert reference.fp8_change(DIMS, SEED) > 0.01
 
 
+def test_a_row_behind_a_retiring_one_has_its_token_before_the_reset(
+        system, monkeypatch):
+    """Releasing a slot of this model dispatches a program (the state
+    row's zeroing).  In the read that ends the first row of the loop
+    the second row has its token before `slots.release` is called; the
+    row ends with the read's own `now`, its state row is zeroed in
+    that call, and the slot's next owner and both neighbours are
+    served what each is served alone."""
+    from tests.test_serving_pipeline import (
+        CommitLog, assert_delivered_then_retired)
+    rng = np.random.default_rng(33)
+    sched = system.sched
+    prompts = [rng.integers(0, 256, n).tolist() for n in (11, 19, 14)]
+    news = (3, 8, 5)
+
+    def serve(which, on_token=None):
+        reqs = []
+        for i in which:
+            req, why = system.submit(prompts[i], news[i], 0.0, on_token)
+            assert req is not None, why
+            reqs.append(req)
+        return reqs
+
+    alone = []
+    for i in range(3):
+        (req,) = serve([i])
+        while system.has_work():
+            system.step()
+        alone.append(req.generated)
+    log = CommitLog(sched, monkeypatch)
+    reqs = serve(range(3), log.on_token)
+    first, second, third = reqs
+    resets = sched.slots.state_resets
+    while first.finish_reason is None:
+        out, events, _ = log.step()
+    # paced: the second row was admitted a call after the first, so it
+    # goes on; the third waits for the slot
+    assert out == {"admitted": 0, "active": 2, "retired": 1}
+    assert_delivered_then_retired(events, [first, second], [first.slot])
+    assert sched.slots.state_resets == resets + 1
+    assert system.finished_ok(first, news[0])
+    assert first.t_finish == first.t_last_token == second.t_last_token
+    _, _, admitted = log.step()
+    assert admitted == [third] and third.slot == first.slot
+    while system.has_work():
+        log.step()
+    assert [r.generated for r in reqs] == alone
+
+
 def test_admissions_are_paced_while_rows_run(system):
     """A model with recurrent layers is paced: with rows running at
     most ONE prefill — here a short prompt's whole prefill, the chunk

@@ -603,6 +603,54 @@ def test_a_batch_whose_rows_are_in_different_phases_equals_each_alone(
     assert any(pending for p in phases for _, pending in p), phases
 
 
+@pytest.mark.parametrize("news,n_end", [((3, 10, 10), 1),
+                                        ((5, 3, 10), 2)])
+def test_a_pass_delivers_every_rows_tokens_before_it_retires_a_row(
+        system, monkeypatch, news, n_end):
+    """`_commit_block`: in the pass that ends the first row (the first
+    two) of the loop, the rows behind have the tokens it revealed
+    before `slots.release` is first called, releases come in the loop's
+    order with the read's own `now`, the freed slot goes to the request
+    that waited, and every stream is what it is served alone.  (Rows
+    are admitted a call apart: where one ends, the third is admitted by
+    the call whose early read ends it.)"""
+    from tests.test_serving_pipeline import (
+        CommitLog, assert_delivered_then_retired)
+    rng = np.random.default_rng(12)
+    sched = system.sched
+    prompts = [rng.integers(0, 255, n).tolist() for n in (8, 12, 16, 9)]
+    news = news + (6,)
+
+    def serve(which, on_token=None):
+        reqs = []
+        for i in which:
+            req, why = system.submit(prompts[i], news[i], 0.0, on_token)
+            assert req is not None, why
+            reqs.append(req)
+        return reqs
+
+    alone = []
+    for i in range(4):
+        (req,) = serve([i])
+        _drain(sched)
+        alone.append(req.generated)
+    log = CommitLog(sched, monkeypatch)
+    reqs = serve(range(4), log.on_token)
+    while reqs[0].finish_reason is None:
+        out, events, admitted = log.step()
+    assert out == {"admitted": 2 - n_end, "active": 3, "retired": n_end}
+    assert admitted == reqs[2:4 - n_end]
+    assert_delivered_then_retired(events, reqs[:1 + n_end],
+                                  [r.slot for r in reqs[:n_end]])
+    for r in reqs[:n_end]:
+        assert r.finish_reason == FinishReason.LENGTH
+        assert r.t_finish == r.t_last_token == reqs[n_end].t_last_token
+    _, _, admitted = log.step()
+    assert admitted == reqs[3:] and reqs[3].slot == reqs[0].slot
+    _drain(sched)
+    assert [r.generated for r in reqs] == alone
+
+
 @pytest.fixture
 def metrics():
     from triton_distributed_tpu.observability import get_registry

@@ -6,6 +6,8 @@ admission are exercised token-for-token against the slot engine here;
 the Pallas kernel itself is covered in test_flash_decode.py.
 All tier-1 (`not slow`)."""
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -649,9 +651,43 @@ def test_one_step_leaves_the_phase_spans(toy, tracer, layout):
         "landed": sync.attrs["landed"],
         "prefill_request_ids": [r.request_id for r in reqs]}
     (commit,) = spans["serving.commit"]
-    assert commit.attrs == {"tokens": 2, "retired": 0, "discarded": 0}
+    assert commit.attrs == {"tokens": 2, "retired": 0, "discarded": 0,
+                            "deliver_ms": commit.attrs["deliver_ms"],
+                            "retire_ms": 0.0}
     assert all(len(r.generated) == 1 for r in reqs)
     sched.drain()
+
+
+@pytest.mark.parametrize("layout", ["paged", "slots"])
+def test_the_commit_span_splits_into_delivery_and_retirement(
+        toy, tracer, layout):
+    """`deliver_ms` runs from the span's start until the last row of
+    the read has its token, `retire_ms` is the rest: they add up to
+    the span (less the attributes' own writing, on the span's clock),
+    a commit that retires nothing is all delivery, and one that
+    retires spends its `slots.release` calls in `retire_ms`."""
+    model, params = toy
+    sched, _ = make_sched(model, params, layout)
+    release = sched.slots.release
+    took = []
+
+    def releasing(slot):
+        t0 = time.perf_counter()
+        release(slot)
+        took.append((time.perf_counter() - t0) * 1e3)
+    sched.slots.release = releasing
+    sched.run([Request(prompt=p, max_new_tokens=n)
+               for p, n in zip(rand_prompts(4, seed=6), (2, 5, 5, 3))])
+    commits = [s for s in tracer.finished() if s.name == "serving.commit"]
+    assert sum(c.attrs["retired"] for c in commits) == 4
+    assert {c.attrs["retired"] for c in commits} >= {0, 1}
+    for c in commits:
+        deliver, retire = c.attrs["deliver_ms"], c.attrs["retire_ms"]
+        assert deliver > 0.0 and retire >= 0.0
+        assert (retire > 0.0) == (c.attrs["retired"] > 0)
+        # the span closes a few microseconds after its attributes
+        assert 0.0 <= c.dur * 1e3 - (deliver + retire) < 1.0
+    assert sum(c.attrs["retire_ms"] for c in commits) >= sum(took) > 0.0
 
 
 def test_step_self_time_is_duration_less_its_phases(toy, tracer):

@@ -1463,3 +1463,130 @@ def test_with_observability_off_nothing_is_asked_and_streams_are_the_same(
     off = serve()
     assert asked == [] and tracer.finished() == []
     assert off == on
+
+
+# ---------------------------------------------------------------------------
+# 5. a read delivers every row's tokens, then retires the rows that ended
+# ---------------------------------------------------------------------------
+
+class CommitLog:
+    """Every token handed to a request and every `slots.release` of one
+    scheduler, through one list, call by call."""
+
+    def __init__(self, sched, monkeypatch):
+        self.sched, self.events = sched, []
+        release = sched.slots.release
+
+        def releasing(slot):
+            self.events.append(("release", slot))
+            release(slot)
+        monkeypatch.setattr(sched.slots, "release", releasing)
+
+    def on_token(self, req, token):
+        self.events.append(("token", req.request_id))
+
+    def step(self):
+        """One call: (what it returned, its events, the requests it
+        gave a slot)."""
+        seen = len(self.events)
+        waiting = list(self.sched._queue)
+        out = self.sched.step()
+        return (out, self.events[seen:],
+                [r for r in waiting if r.slot is not None])
+
+
+def assert_delivered_then_retired(events, running, ended):
+    """``events`` of one call: every row of the read — ``running``, in
+    the loop's order — has its tokens before the first release, and the
+    releases are the ``ended`` rows' slots, in the loop's order."""
+    tokens = [e for e in events if e[0] == "token"]
+    releases = [e for e in events if e[0] == "release"]
+    assert events == tokens + releases
+    assert list(dict.fromkeys(rid for _, rid in tokens)) == [
+        r.request_id for r in running]
+    assert releases == [("release", slot) for slot in ended]
+
+
+#: The answers of the three rows that run (admitted in this order: the
+#: loop's) and how many of them end in one read.
+ENDINGS = {
+    "first_of_three": ((3, 9, 9), 1),
+    "two_of_three": ((3, 3, 9), 2),
+}
+#: Layout, options, the calls before the one whose read ends the short
+#: rows, and the calls after it until the freed slot 0 is handed on: the
+#: SAME call where the read is an admitting call's early one, the next
+#: where the scheduler is serial (it admits before it dispatches).
+PATHS = {
+    "paged": ("paged", {}, 3, 0),
+    "slots": ("slots", {}, 3, 0),
+    "spec_verify": ("paged", dict(spec_k=2), 2, 1),
+}
+
+
+@pytest.fixture(scope="module")
+def served_alone(toy):
+    """The five prompts of the cases below, each served alone by one
+    serial scheduler."""
+    model, params = toy
+    prompts = rand_prompts(5, seed=3)
+    sched = make_sched(model, params, num_slots=1, spec_k=2,
+                       spec_drafter=lambda s: _NoDrafts())
+    streams = []
+    for p in prompts:
+        req = Request(prompt=p, max_new_tokens=9)
+        sched.run([req])
+        streams.append(req.generated)
+    return prompts, streams
+
+
+@pytest.mark.parametrize("ending", sorted(ENDINGS))
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_every_row_has_its_token_before_any_row_is_retired(
+        toy, served_alone, monkeypatch, path, ending):
+    """Three rows run on four slots and two requests arrive for the
+    call whose read ends the first row (the first two): the rows behind
+    get their tokens before `slots.release` is first called, releases
+    come in the loop's order, and the call's counts, `finish_reason`,
+    `t_finish`, the freed slot's next owner and every stream are what
+    the one-pass loop gave."""
+    model, params = toy
+    layout, kw, at, later = PATHS[path]
+    news, n_end = ENDINGS[ending]
+    prompts, alone = served_alone
+    sched = make_sched(model, params, layout, num_slots=4, **kw)
+    log = CommitLog(sched, monkeypatch)
+    reqs = [Request(prompt=p, max_new_tokens=n, on_token=log.on_token)
+            for p, n in zip(prompts, news + (5, 5))]
+    for r in reqs[:3]:
+        assert sched.submit(r)
+    for _ in range(at):
+        sched._clock_advance(1.0)
+        out, events, _ = log.step()
+        assert out["retired"] == 0 and "release" not in dict(events)
+    for r in reqs[3:]:
+        assert sched.submit(r)
+    sched._clock_advance(1.0)
+    out, events, admitted = log.step()
+    # the early read of an admitting call: the fourth slot and the
+    # first one freed are filled in this call; a serial call admits
+    # first (its newcomer is in the read), reads last, and hands the
+    # freed slot on a call later
+    assert_delivered_then_retired(events, reqs[:4 if later else 3],
+                                  range(n_end))
+    assert out == {"admitted": 1 if later else 2,
+                   "active": 4 if later else 5, "retired": n_end}
+    assert admitted == (reqs[3:4] if later else reqs[3:])
+    for r in reqs[:n_end]:
+        assert r.finish_reason == FinishReason.LENGTH
+        assert r.t_finish == sched.clock() == at + 1.0
+    if later:
+        _, _, admitted = log.step()
+        assert admitted == reqs[4:]
+    assert (reqs[3].slot, reqs[4].slot) == (3, 0)
+    while sched.has_work():
+        _, events, _ = log.step()
+        tokens = [e for e in events if e[0] == "token"]
+        assert events[:len(tokens)] == tokens
+    assert [r.generated for r in reqs] == [
+        s[:r.max_new_tokens] for r, s in zip(reqs, alone)]

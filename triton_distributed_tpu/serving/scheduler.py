@@ -47,10 +47,18 @@ Every `step()` is one scheduler iteration:
    streamed via ``on_token``, and rows that hit EOS /
    ``max_new_tokens`` / the KV horizon release their slot (and,
    paged, their private pages — prompt pages stay cached for future
-   prefix hits).  A row that ends by length or horizon is known to
-   end before its last step is read and is simply not in the next
-   dispatch; a row that ends by EOS is seen one step late — the
-   token of the step that ran for it is discarded and counted.
+   prefix hits).  The commit is TWO PASSES — deliver, then retire:
+   every row of the read has its token(s) before the first row is
+   retired, and the rows that ended are then retired in the order the
+   loop met them, with the read's own ``now``, before the call's
+   admissions and its next dispatch.  A release is milliseconds of
+   host work (radix, pages, a stateful model's reset program); inside
+   the loop every row behind the retiring one got its token — the
+   moment a client stamps — that much later.  A row that ends by
+   length or horizon is known to end before its last step is read
+   and is simply not in the next dispatch; a row that ends by EOS is
+   seen one step late — the token of the step that ran for it is
+   discarded and counted.
 
 Backpressure is at `submit`: a bounded queue and static feasibility
 checks reject with a typed reason instead of queueing unservable work.
@@ -66,8 +74,9 @@ timeline, and a span around each phase of a step (`serving.step` >
 admission's two halves; `serving.pages`, `serving.dispatch`,
 `serving.sync`, `serving.moe` (a sparse model's expert counters),
 `serving.diffusion` (a block pass's rows by phase and tokens),
-`serving.commit`, `serving.gauges`) that says where the host's time in
-a step went.
+`serving.commit` — with ``deliver_ms``, span start to the last
+``on_token``'s return, and ``retire_ms``, the rest —
+`serving.gauges`) that says where the host's time in a step went.
 
 An admission joins the pipeline: the host never waits for a prefill.
 It is made in two halves — the first (`_admit_front`) matches the
@@ -1507,28 +1516,30 @@ class ContinuousBatchingScheduler:
         request as soon as it and every earlier position are revealed
         — in position order, each once; positions past the request's
         length are never delivered.  (A block the pass finished stands
-        in the front half of the state it returned.)
-        Returns (rows retired, tokens delivered)."""
+        in the front half of the state it returned.)  Every row has
+        its tokens before any row is retired, as in `_commit_tokens`.
+        Returns (rows ended: [(slot, reason)], tokens delivered)."""
         n = self._block
-        retired = generated = held = 0
+        generated = held = 0
+        ended = []
         for slot, req in rows:
             start, _, _, finished = flight.denoised[slot]
             half = slice(0, n) if finished else slice(n, 2 * n)
             toks, shown = blk_host[slot][:, half]
             j = req.prompt_len + len(req.generated) - start
-            done = False
-            while j < n and shown[j] and not done:
-                done = self._emit_token(slot, req, int(toks[j]), now,
-                                        reg)
+            reason = None
+            while j < n and shown[j] and reason is None:
+                reason = self._emit_token(slot, req, int(toks[j]), now,
+                                          reg)
                 generated += 1
                 j += 1
-            if done:
-                retired += 1
+            if reason is not None:
+                ended.append((slot, reason))
             else:
                 last = req.prompt_len + req.max_new_tokens - start
                 held += int(shown[j:last].sum())
         self._held_back = held
-        return retired, generated
+        return ended, generated
 
     def _diffusion_phase(self, flight: _Flight, delivered: int,
                          reg) -> None:
@@ -2033,15 +2044,33 @@ class ContinuousBatchingScheduler:
             if spec:
                 self._spec_outcome(rows, accept_host, flight.n_draft,
                                    now, reg)
+            # Deliver, then retire: every row of the read has its
+            # token(s) before the first slot is released, so no
+            # stream waits out a neighbour's retirement.
+            outcomes = ()
             if block:
-                retired, generated = self._commit_block(
+                ended, generated = self._commit_block(
                     rows, flight, toks_host, now, reg)
             else:
-                retired, generated = self._commit_tokens(
+                ended, generated, outcomes = self._commit_tokens(
                     rows, toks_host, accept_host, now, reg)
+            delivered = time.perf_counter()
+            for slot, reason in ended:
+                self._retire(slot, now, reason)
+            if outcomes:
+                self.drafter.commit_batched(outcomes)
+            retired = len(ended)
             if sp is not NULL_SPAN:
-                sp.attrs.update(tokens=generated, retired=retired,
-                                discarded=discarded)
+                # The span's two halves on its own clock.  A commit
+                # that retires nothing is all delivery.
+                done = time.perf_counter()
+                if not ended:
+                    delivered = done
+                sp.attrs.update(
+                    tokens=generated, retired=retired,
+                    discarded=discarded,
+                    deliver_ms=(delivered - sp.t0) * 1e3,
+                    retire_ms=(done - delivered) * 1e3)
         if block:
             self._diffusion_phase(flight, generated, reg)
         if reg:
@@ -2216,31 +2245,42 @@ class ContinuousBatchingScheduler:
 
     def _commit_tokens(self, rows, toks_host, accept_host, now, reg):
         """Append one dispatch's tokens to their requests: stream via
-        ``on_token``, check EOS / budget / KV horizon, retire, and
-        (speculative mode) reconcile the drafter with what was
-        actually committed.  A row emits ``accept + 1`` tokens under
-        speculation, else one; tokens decoded past a retirement
-        reason are discarded — bounded over-generation."""
-        retired = 0
+        ``on_token``, check EOS / budget / KV horizon and (speculative
+        mode) note what the drafter has to reconcile with.  A row
+        emits ``accept + 1`` tokens under speculation, else one;
+        tokens decoded past a retirement reason are discarded —
+        bounded over-generation.
+
+        This is the DELIVERY half of a commit and retires nothing: a
+        row that ended is only noted, and the caller retires the noted
+        rows — in this loop's order, with this read's ``now`` — once
+        the last row has its token.  Releasing a slot is milliseconds
+        of host work (radix, pages, a stateful model's reset program);
+        done inside the loop, every row behind the retiring one got
+        its token that much later, and its client saw one gap a
+        retirement too long.
+        Returns (rows ended: [(slot, reason)], tokens delivered, a
+        batched drafter's outcomes to commit AFTER the retirements)."""
         generated = 0
+        ended = []
         batched = getattr(self.drafter, "batched", False)
         outcomes = []
         for slot, req in rows:
             count = (int(accept_host[slot]) + 1
                      if accept_host is not None else 1)
             committed = []
-            done = False
+            reason = None
             for j in range(count):
                 token = int(toks_host[slot, j])
                 committed.append(token)
                 generated += 1
-                if self._emit_token(slot, req, token, now, reg):
+                reason = self._emit_token(slot, req, token, now, reg)
+                if reason is not None:
                     # Tokens decoded past this point are discarded —
                     # bounded over-generation.
-                    retired += 1
-                    done = True
+                    ended.append((slot, reason))
                     break
-            if not done:
+            if reason is None:
                 self._tokens[slot] = int(toks_host[slot, count - 1])
                 if (self.drafter is not None
                         and not self._spec_throttled):
@@ -2248,22 +2288,24 @@ class ContinuousBatchingScheduler:
                     # the committed outcome (accepted prefix kept,
                     # rejected tail rolled back; a plain-step commit
                     # is accept=0 with one token).  Batched drafters
-                    # reconcile every row in one dispatch set below.
+                    # reconcile every row in one dispatch set, behind
+                    # the retirements.
                     acc = (count - 1 if accept_host is not None
                            else 0)
                     if batched:
                         outcomes.append((req, acc, committed))
                     else:
                         self.drafter.commit(req, acc, committed)
-        if outcomes:
-            self.drafter.commit_batched(outcomes)
-        return retired, generated
+        return ended, generated, outcomes
 
     def _emit_token(self, slot: int, req: Request, token: int,
-                    now: float, reg) -> bool:
+                    now: float, reg) -> Optional[FinishReason]:
         """One token to its request: appended, timed, streamed via
         ``on_token``, then checked against EOS / budget / KV horizon.
-        True: it was the request's last and the row is retired."""
+        Returns the reason if it was the request's last, else None;
+        the row is NOT retired here (it reads and writes nothing a
+        retirement touches, so the commit can deliver a whole read
+        first: `_commit_tokens`)."""
         req.generated.append(token)
         if req.t_first_token is None:
             req.t_first_token = now
@@ -2285,21 +2327,17 @@ class ContinuousBatchingScheduler:
         req.t_last_token = now
         if req.on_token is not None:
             req.on_token(req, token)
-        reason = None
         if token in req.eos_token_ids:
-            reason = FinishReason.EOS
-        elif len(req.generated) >= req.max_new_tokens:
-            reason = FinishReason.LENGTH
-        elif req.prompt_len + len(req.generated) > self.max_seq:
+            return FinishReason.EOS
+        if len(req.generated) >= req.max_new_tokens:
+            return FinishReason.LENGTH
+        if req.prompt_len + len(req.generated) > self.max_seq:
             # The NEXT step would write KV at offset
             # prompt+generated-1 > max_seq-1; the admission
             # rule mirrors this (the final token needs no KV
             # write of its own).
-            reason = FinishReason.KV_CAPACITY
-        if reason is None:
-            return False
-        self._retire(slot, now, reason)
-        return True
+            return FinishReason.KV_CAPACITY
+        return None
 
     def _retire(self, slot: int, now: float,
                 reason: FinishReason) -> None:
